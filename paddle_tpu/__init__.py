@@ -13,7 +13,11 @@ Architecture (see SURVEY.md for the full blueprint):
     graph replication; collective ops lower to psum/all_gather/ppermute.
 """
 
-from . import initializer, layers, optimizer, regularizer  # noqa: F401
+from .core.compile_cache import enable_compile_cache as _enable_cache
+
+_enable_cache()     # persistent XLA compile cache, one fixed directory
+
+from . import initializer, layers, optimizer, regularizer  # noqa: F401,E402
 from . import clip  # noqa: F401
 from . import io  # noqa: F401
 from . import amp  # noqa: F401

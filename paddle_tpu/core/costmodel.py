@@ -86,54 +86,54 @@ def is_oom_error(err: BaseException) -> bool:
 
 
 # -- device table -------------------------------------------------------------
-# (peak dense flops/s, peak HBM bytes/s) by device_kind substring, first
-# match wins. The flops column mirrors tools/bench_models.py's historical
-# table (which now delegates here) so BENCH MFU numbers are unchanged;
-# unknown kinds (incl. the CPU CI backend) fall through to the v5e row —
-# override with FLAGS_device_peak_flops / FLAGS_device_peak_bw.
+# (peak dense bf16 flops/s, peak HBM bytes/s) of one chip by device_kind
+# substring, first match wins. Source: Google Cloud TPU documentation
+# (system architecture pages per generation). A kind that is not here —
+# the CPU included, it is not a v5e — has no peak: set
+# FLAGS_device_peak_flops / FLAGS_device_peak_bw to score against one.
 _DEVICE_TABLE: List[Tuple[str, float, float]] = [
+    ("v5 lite", 197e12, 819e9),      # what a v5e chip reports
+    ("v5e", 197e12, 819e9),
     ("v5p", 459e12, 2765e9),
     ("v5 p", 459e12, 2765e9),
     ("v4", 275e12, 1228e9),
     ("v6", 918e12, 1640e9),
     ("trillium", 918e12, 1640e9),
 ]
-_DEFAULT_PEAK = (197e12, 819e9)  # v5e / v5 lite / unknown
 
 
-def _device_kind() -> str:
-    try:
-        import jax
+class UnknownDevicePeakError(LookupError):
+    """The device's kind is not in the peak table and no FLAGS_device_peak_*
+    override is set: there is no peak to score against."""
 
-        return jax.devices()[0].device_kind.lower()
-    except Exception:
-        return "unknown"
+
+def _table_peaks() -> Tuple[float, float]:
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    lowered = kind.lower()
+    for sub, flops, bw in _DEVICE_TABLE:
+        if sub in lowered:
+            return flops, bw
+    raise UnknownDevicePeakError(
+        f"no peak flops/bandwidth known for device_kind {kind!r}; set "
+        f"FLAGS_device_peak_flops / FLAGS_device_peak_bw to score "
+        f"against a peak of your choosing")
 
 
 def peak_device_flops() -> float:
     """Peak dense flops/s of one device — FLAGS_device_peak_flops wins
-    when > 0, else the device table keyed on jax device_kind."""
+    when > 0, else the device table keyed on jax device_kind; a kind the
+    table lacks raises UnknownDevicePeakError."""
     override = float(_flag("device_peak_flops"))
-    if override > 0:
-        return override
-    kind = _device_kind()
-    for sub, flops, _bw in _DEVICE_TABLE:
-        if sub in kind:
-            return flops
-    return _DEFAULT_PEAK[0]
+    return override if override > 0 else _table_peaks()[0]
 
 
 def peak_device_bandwidth() -> float:
     """Peak HBM bytes/s of one device (roofline denominator) —
     FLAGS_device_peak_bw wins when > 0, else the device table."""
     override = float(_flag("device_peak_bw"))
-    if override > 0:
-        return override
-    kind = _device_kind()
-    for sub, _flops, bw in _DEVICE_TABLE:
-        if sub in kind:
-            return bw
-    return _DEFAULT_PEAK[1]
+    return override if override > 0 else _table_peaks()[1]
 
 
 # -- cost-analysis key handling ----------------------------------------------
@@ -206,7 +206,10 @@ class ProgramCost:
     def roofline(self) -> str:
         if not self.flops or not self.bytes_accessed:
             return "unknown"
-        ridge = peak_device_flops() / max(peak_device_bandwidth(), 1.0)
+        try:
+            ridge = peak_device_flops() / max(peak_device_bandwidth(), 1.0)
+        except UnknownDevicePeakError:
+            return "unknown"        # no peak, no verdict
         return "compute_bound" if self.intensity() >= ridge \
             else "memory_bound"
 
@@ -474,21 +477,28 @@ def book_dispatch(rec: Optional[ProgramCost], steps: int = 1):
     now = time.time()
     if now - _last_mfu_set[0] >= 1.0:   # 1 Hz gauge refresh, not per step
         _last_mfu_set[0] = now
-        # no rounding: CPU-CI MFU values live around 1e-7 and must stay
-        # nonzero in the log/gauge
-        telemetry.gauge_set("cost.live_mfu", float(live_mfu()))
+        # no rounding: small-model MFU values live around 1e-7 and must
+        # stay nonzero in the log/gauge
+        mfu = live_mfu()
+        if mfu is not None:         # no peak known: the gauge is omitted
+            telemetry.gauge_set("cost.live_mfu", mfu)
 
 
-def live_mfu(window_s: Optional[float] = None) -> float:
+def live_mfu(window_s: Optional[float] = None) -> Optional[float]:
     """Live model-flops utilization: windowed achieved flops/s (the
     cost.dispatch_flops rolling rate) ÷ peak device flops. The PaLM-
     style MFU discipline as a runtime gauge instead of an offline bench
-    formula."""
+    formula. None where the device kind has no known peak — this is read
+    inside Executor.run and bench finalizers, which omit the figure."""
+    try:
+        peak = peak_device_flops()
+    except UnknownDevicePeakError:
+        return None
     win = telemetry.windowed(window_s)
     wc = win["counters"].get("cost.dispatch_flops")
     if not wc:
         return 0.0
-    return float(wc["rate"]) / max(peak_device_flops(), 1.0)
+    return float(wc["rate"]) / max(peak, 1.0)
 
 
 # -- OOM forensics ------------------------------------------------------------

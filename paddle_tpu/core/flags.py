@@ -263,8 +263,9 @@ define_flag("cost_capture", "auto",
 define_flag("device_peak_flops", 0.0,
             "peak dense flops/s of one device for the live MFU gauge "
             "and roofline verdicts (core/costmodel.py); <= 0 uses the "
-            "built-in device table keyed on jax device_kind (unknown "
-            "kinds fall back to the v5e figure)")
+            "built-in device table keyed on jax device_kind (a kind "
+            "the table lacks, the CPU included, has no peak: the gauge "
+            "and verdicts are omitted and peak_device_flops() raises)")
 define_flag("device_peak_bw", 0.0,
             "peak HBM bytes/s of one device for the roofline ridge "
             "point (core/costmodel.py); <= 0 uses the built-in device "

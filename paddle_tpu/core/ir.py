@@ -31,7 +31,7 @@ import numpy as np
 from . import unique_name
 from .types import VarType, convert_dtype
 
-# Op role taxonomy (reference: framework/op_proto_maker.h OpRole)
+# Op role classes (reference: framework/op_proto_maker.h OpRole)
 class OpRole:
     Forward = 0
     Backward = 1

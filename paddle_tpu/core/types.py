@@ -1,8 +1,8 @@
-"""Core type taxonomy for the TPU-native framework.
+"""Core type system for the TPU-native framework.
 
 Mirrors the capability of the reference's VarType proto
 (paddle/fluid/framework/framework.proto:104 — 21 var kinds) and the Place
-taxonomy (paddle/fluid/platform/place.h:26-125), re-designed for JAX/XLA:
+hierarchy (paddle/fluid/platform/place.h:26-125), re-designed for JAX/XLA:
 a Place wraps a `jax.Device` set, and dtypes are numpy/jax dtypes rather
 than a proto enum.
 """
@@ -77,7 +77,7 @@ def bf16() -> np.dtype:
     return convert_dtype("bfloat16")
 
 
-# Place taxonomy -------------------------------------------------------------
+# Place kinds ----------------------------------------------------------------
 
 
 @dataclass(frozen=True)
@@ -132,11 +132,8 @@ def XLAPlace(device_id: int = 0) -> Place:
 def default_place() -> Place:
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    if backend == "cpu":
+    # a backend that fails to initialise is an error, not a CPUPlace
+    if jax.default_backend() == "cpu":
         return CPUPlace()
     return TPUPlace(0)
 

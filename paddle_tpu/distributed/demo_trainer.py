@@ -36,8 +36,6 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np
 
 

@@ -131,7 +131,7 @@ class RestartBudget:
 
 # error types worth a restart: transport failures (RpcError covers
 # RpcDeadlineError/RpcRemoteError — retries exhausted, deadlines blown,
-# barrier stalls relayed from a pserver) and the OS-level network/device
+# barrier stalls reported by a pserver) and the OS-level network/device
 # errors underneath them. Plain RuntimeError is deliberately NOT here —
 # it swallowed programming errors; raise one of these (or subclass) from
 # custom step_fns that want a restart. In particular core.verify's

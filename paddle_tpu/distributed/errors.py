@@ -14,7 +14,7 @@ gives the transport its own hierarchy so recovery policy can be precise:
                         TimeoutError so pre-existing timeout handling
                         still matches. Recoverable.
 * RpcRemoteError      — the remote handler raised and the error was
-                        relayed over the wire (the '__err__' status).
+                        passed back over the wire (the '__err__' status).
                         Kept under RpcError because the dominant causes
                         (sync-barrier stalls, checkpoint races) are
                         transient cluster conditions, not local bugs.

@@ -4,7 +4,12 @@ with PADDLE_TRAINER_ID/... env; heart_beat_monitor.h + the
 listen_and_serv respawn paths are its supervision story).
 
 TPU-native: one process per HOST (each owns all local chips); multi-host
-rendezvous via jax.distributed's coordination service. Usage:
+rendezvous via jax.distributed's coordination service. ``--nproc N`` > 1
+is for CPU children (``JAX_PLATFORMS=cpu`` in their environment): on a
+host with an accelerator it is refused with a typed
+core/chips.ChipContentionError before anything is spawned, as is any
+spawn from a launcher process that has itself initialised the
+accelerator backend. Usage:
 
   python -m paddle_tpu.distributed.launch train.py args...            # local
   python -m paddle_tpu.distributed.launch --nproc 2 train.py ...      # multi-proc (CPU testing)
@@ -43,6 +48,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from ..core import chips
 from ..core import flags as _flags
 from ..core import telemetry
 
@@ -278,6 +284,8 @@ class Orchestrator:
         """Provision the pserver tier first (trainers need the
         announced endpoints), then the trainer world; block until every
         child has announced ready."""
+        chips.check_spawn(self.world, self.env,
+                          f"Orchestrator({self.world} trainers)")
         with self._lock:
             for idx in range(self.n_pservers):
                 self.pservers.append(self._spawn_pserver(idx))
@@ -391,6 +399,8 @@ class Orchestrator:
         old_world = self.world
         if new_world < 1 or new_world == old_world:
             return
+        chips.check_spawn(new_world, self.env,
+                          f"Orchestrator.execute_scale({new_world})")
         telemetry.counter_add("orch.drains", 1, world=old_world)
         with self._lock:
             draining = list(self.trainers)
@@ -488,6 +498,8 @@ def main(argv=None):
 
     from .parallel import cluster_env
 
+    chips.check_spawn(args.nproc, os.environ,
+                      f"launch --nproc {args.nproc}")
     procs = []
     for rank in range(args.nproc):
         env = dict(os.environ)

@@ -18,7 +18,7 @@ Async mode (reference AsyncCommunicator, Downpour-style): every received
   value, no barriers.
 
 Fault tolerance: sync-mode recv waits are bounded by
-FLAGS_ps_sync_barrier_timeout (BarrierTimeoutError relayed to the
+FLAGS_ps_sync_barrier_timeout (BarrierTimeoutError passed back to the
 trainer); with FLAGS_ps_degrade_to_survivors, a trainer the
 HeartBeatMonitor declares dead is dropped from the barrier — updates
 become the mean over survivors (ps.barrier_degraded telemetry) and a
@@ -354,7 +354,7 @@ class PServer:
                                               timeout=timeout)
                     if not ok:
                         # surface the stalled barrier instead of silently
-                        # serving a stale parameter (the RPC layer relays
+                        # serving a stale parameter (the RPC layer returns
                         # this to the trainer as an error status)
                         dead = (sorted(self.monitor.dead)
                                 if self.monitor else None)
@@ -427,7 +427,7 @@ class PServer:
         """Verified restore: the snapshot's manifest (file sha256 +
         per-array CRC32) must check out before any byte enters the
         server scope — a torn snapshot raises CheckpointCorruptError
-        (relayed to the notifier as an RPC error) instead of silently
+        (returned to the notifier as an RPC error) instead of silently
         serving wrong parameters.
 
         rebalance=(server_index, num_servers): restore into a CHANGED

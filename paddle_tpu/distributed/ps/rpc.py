@@ -216,7 +216,7 @@ class RPCServer:
         """Run the handler behind the `ps.handler` fault site. An
         injected ConnectionError/OSError drops the connection (the
         client retries); any other exception — injected or real — is
-        relayed to the caller as an '__err__' status."""
+        returned to the caller as an '__err__' status."""
         try:
             faults.maybe_fail("ps.handler", method=method)
         except (ConnectionError, OSError):

@@ -4,20 +4,7 @@ plus word2vec and the seq2seq machine-translation book model.
 """
 
 from . import bert, lenet  # noqa: F401
-
-try:
-    from . import resnet  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import transformer  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import seq2seq  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import word2vec  # noqa: F401
-except ImportError:
-    pass
+from . import resnet  # noqa: F401
+from . import transformer  # noqa: F401
+from . import seq2seq  # noqa: F401
+from . import word2vec  # noqa: F401

@@ -6,51 +6,17 @@ JAX lowering in the registry (see core/registry.py).
 """
 
 from . import lr_ops, math_ops, nn_ops, optimizer_ops, tensor_ops  # noqa: F401
-
-try:  # modules added as the build widens
-    from . import amp_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import collective_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import control_flow_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import sequence_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import attention_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import pipeline_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import extra_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import rnn_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import quant_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import moe_ops  # noqa: F401
-except ImportError:
-    pass
-try:
-    from . import ps_ops  # noqa: F401
-except ImportError:
-    pass
+from . import amp_ops  # noqa: F401
+from . import collective_ops  # noqa: F401
+from . import control_flow_ops  # noqa: F401
+from . import sequence_ops  # noqa: F401
+from . import attention_ops  # noqa: F401
+from . import pipeline_ops  # noqa: F401
+from . import extra_ops  # noqa: F401
+from . import rnn_ops  # noqa: F401
+from . import quant_ops  # noqa: F401
+from . import moe_ops  # noqa: F401
+from . import ps_ops  # noqa: F401
 from . import beam_search_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import extra_ops2  # noqa: F401

@@ -55,18 +55,6 @@ def _in_spmd(axis) -> bool:
     return bool(_bound_axes(axis))
 
 
-def _axis_size(ax) -> int:
-    """Bound SPMD axis size across jax versions (jax.lax.axis_size only
-    exists in newer releases; psum of the literal 1 is the portable
-    spelling — jax folds it to the static axis size)."""
-    import jax
-
-    try:
-        return jax.lax.axis_size(ax)
-    except AttributeError:
-        return jax.lax.psum(1, ax)
-
-
 def _allreduce(reduce_fn):
     def lowering(ins, attrs):
         import jax
@@ -167,7 +155,7 @@ def c_split(ins, attrs):
     ax = _axis_name(attrs)
     if _in_spmd(ax):
         idx = jax.lax.axis_index(ax)
-        n = _axis_size(ax)
+        n = jax.lax.axis_size(ax)
         per = x.shape[-1] // n
         x = jax.lax.dynamic_slice_in_dim(x, idx * per, per, axis=x.ndim - 1)
     return {"Out": x}
@@ -183,7 +171,7 @@ def c_ppermute(ins, attrs):
     ax = _axis_name(attrs)
     shift = int(attrs.get("shift", 1))
     if _in_spmd(ax):
-        n = _axis_size(ax)
+        n = jax.lax.axis_size(ax)
         perm = [(i, (i + shift) % n) for i in range(n)]
         x = jax.lax.ppermute(x, ax, perm)
     return {"Out": x}
@@ -294,7 +282,7 @@ def c_scatter(ins, attrs):
     x = ins["X"][0]
     ax = _axis_name(attrs)
     if _in_spmd(ax):
-        n = _axis_size(ax)
+        n = jax.lax.axis_size(ax)
         idx = jax.lax.axis_index(ax)
         chunk = x.shape[0] // n
         x = jax.lax.dynamic_slice_in_dim(x, idx * chunk, chunk, axis=0)

@@ -3,8 +3,8 @@
 Capability mirror of paddle/fluid/operators/ conv_op.cc (+conv_cudnn),
 pool_op.cc, batch_norm_op.cc, layer_norm_op.{cc,cu}, dropout_op.cc,
 conv_transpose_op.cc, group_norm_op.cc. Convs lower to
-lax.conv_general_dilated (NCHW, fluid's default layout — XLA relayouts for
-the MXU internally); norms are jnp compositions XLA fuses into one kernel.
+lax.conv_general_dilated (NCHW, fluid's default layout — XLA changes
+layout for the MXU internally); norms are jnp compositions XLA fuses into one kernel.
 """
 
 from __future__ import annotations

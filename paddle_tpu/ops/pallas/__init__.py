@@ -19,14 +19,36 @@ Mode selection (``kernel_mode()``):
               against the jnp references),
   'off'       pure-jnp reference (XLA still fuses well; default on CPU).
 Env override: PT_PALLAS=off|interpret|auto.
+
+Mosaic kernels cannot be partitioned by XLA ("wrap the call in a
+shard_map"): a step that jit partitions itself over a multi-device mesh
+traces inside ``auto_partitioned()``, where mode 'tpu' reads 'off' and
+flash attention takes its XLA route. Programs that run under shard_map
+(explicit collectives) see per-shard shapes and keep their kernels.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
+
+_trace_scope = threading.local()
 
 
-def kernel_mode() -> str:
+@contextlib.contextmanager
+def auto_partitioned():
+    """Trace scope of a program XLA partitions itself (core/executor.py's
+    jit-with-shardings path over more than one device)."""
+    prior = getattr(_trace_scope, "auto_partitioned", False)
+    _trace_scope.auto_partitioned = True
+    try:
+        yield
+    finally:
+        _trace_scope.auto_partitioned = prior
+
+
+def _requested_mode() -> str:
     env = os.environ.get("PT_PALLAS", "auto").lower()
     if env in ("off", "0", "false"):
         return "off"
@@ -34,11 +56,19 @@ def kernel_mode() -> str:
         return "interpret"
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return "off"
-    return "tpu" if backend == "tpu" else "off"
+    # a backend that fails to initialise is an error, not mode 'off'
+    return "tpu" if jax.default_backend() == "tpu" else "off"
+
+
+def mosaic_withheld() -> bool:
+    """True where kernel_mode() reads 'off' ONLY because the trace is
+    auto-partitioned: compiled kernels were wanted and cannot be had."""
+    return getattr(_trace_scope, "auto_partitioned", False) \
+        and _requested_mode() == "tpu"
+
+
+def kernel_mode() -> str:
+    return "off" if mosaic_withheld() else _requested_mode()
 
 
 def use_pallas() -> bool:

@@ -1483,7 +1483,7 @@ def _dispatch_plan(q, k, bias):
     is None, or on the reference_general route which keeps the raw bias).
     Shared by the forward, the op layer and the flash_attention_grad
     lowering so the grad op's route always matches its forward's."""
-    from . import kernel_mode
+    from . import kernel_mode, mosaic_withheld
 
     bias_kv = None
     if bias is not None:
@@ -1495,7 +1495,10 @@ def _dispatch_plan(q, k, bias):
             return "reference_general", None
     mode = kernel_mode()
     if mode == "off":
-        return "reference", bias_kv
+        # a step XLA partitions itself cannot hold Mosaic kernels: the
+        # O(S)-residual XLA recompute route stands in, not the
+        # probs-saving reference
+        return ("xla" if mosaic_withheld() else "reference"), bias_kv
     if mode == "tpu" and _impl_choice(q, k) == "xla":
         return "xla", bias_kv
     if not _supported(q, k, bias_kv):

@@ -111,16 +111,18 @@ def _pallas_int8_gemm(x2, w8, scale, bias, act, interpret):
     np_ = -(-n // bn) * bn
     x2 = _pad_axis(x2, 0, mp)
     w8 = _pad_axis(w8, 1, np_)
-    scale = _pad_axis(scale.reshape(-1), 0, np_)
+    # scale/bias ride as (1, N) rows: Mosaic tiles a 1-D f32[N] operand
+    # differently from XLA's HBM layout and refuses the call
+    scale = _pad_axis(scale.reshape(1, -1), 1, np_)
     if bias is not None:
-        bias = _pad_axis(bias.reshape(-1), 0, np_)
+        bias = _pad_axis(bias.reshape(1, -1), 1, np_)
     grid = (mp // bm, np_ // bn)
     in_specs = [pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
                 pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-                pl.BlockSpec((bn,), lambda i, j: (j,))]
+                pl.BlockSpec((1, bn), lambda i, j: (0, j))]
     args = [x2, w8, scale]
     if bias is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j: (j,)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
         args.append(bias)
     out = pl.pallas_call(
         functools.partial(_gemm_kernel, n_in=len(args),
@@ -129,9 +131,8 @@ def _pallas_int8_gemm(x2, w8, scale, bias, act, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         cost_estimate=pl.CostEstimate(
-            flops=2.0 * mp * k * np_,
-            bytes_accessed=float(mp * k * 4 + k * np_ + mp * np_ * 4
-                                 + np_ * 4),
+            flops=2 * mp * k * np_,
+            bytes_accessed=mp * k * 4 + k * np_ + mp * np_ * 4 + np_ * 4,
             transcendentals=0),
         interpret=interpret)(*args)
     return out[:m, :n]

@@ -30,8 +30,8 @@ def _ln_kernel(x_ref, scale_ref, bias_ref, y_ref, mean_ref, rstd_ref, *, eps):
     if bias_ref is not None:
         y = y + bias_ref[...].astype(jnp.float32)
     y_ref[...] = y.astype(y_ref.dtype)
-    mean_ref[...] = mean[:, 0]
-    rstd_ref[...] = rstd[:, 0]
+    mean_ref[...] = mean
+    rstd_ref[...] = rstd
 
 
 def _ln_pallas(x2, scale, bias, eps, interpret):
@@ -41,19 +41,23 @@ def _ln_pallas(x2, scale, bias, eps, interpret):
     rows = BLOCK_ROWS
     while n % rows:
         rows //= 2
-    rows = max(rows, 1)
+    if rows < 16:
+        # a block must be sublane-aligned (8 rows f32, 16 bf16) or span
+        # the whole axis; a row count with no such divisor runs as one
+        rows = n
     grid = (n // rows,)
+    # statistics and affine vectors ride as 2-D columns/rows: Mosaic tiles
+    # a 1-D f32[n] operand differently from XLA's layout and refuses it
     in_specs = [pl.BlockSpec((rows, h), lambda i: (i, 0))]
     args = [x2]
     n_in = 1
-    kern = _ln_kernel
     if scale is not None:
-        in_specs.append(pl.BlockSpec((h,), lambda i: (0,)))
-        args.append(scale)
+        in_specs.append(pl.BlockSpec((1, h), lambda i: (0, 0)))
+        args.append(scale.reshape(1, h))
         n_in += 1
     if bias is not None:
-        in_specs.append(pl.BlockSpec((h,), lambda i: (0,)))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((1, h), lambda i: (0, 0)))
+        args.append(bias.reshape(1, h))
         n_in += 1
 
     def kernel(*refs, eps):
@@ -72,13 +76,13 @@ def _ln_pallas(x2, scale, bias, eps, interpret):
         functools.partial(kernel, eps=eps),
         grid=grid, in_specs=in_specs,
         out_specs=[pl.BlockSpec((rows, h), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,)),
-                   pl.BlockSpec((rows,), lambda i: (i,))],
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
-                   jax.ShapeDtypeStruct((n,), jnp.float32),
-                   jax.ShapeDtypeStruct((n,), jnp.float32)],
+                   jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         interpret=interpret)(*args)
-    return y, mean, rstd
+    return y, mean[:, 0], rstd[:, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -43,12 +43,6 @@ def _pipeline_env(ins, attrs):
 
 
 def _check_ring(axis, n):
-    # NOTE: jax.lax.axis_size is missing from this container's jax build
-    # (the pipeline tier-1 tests fail fast on it, pre-existing list). The
-    # portable _axis_size shim exists in collective_ops, but routing the
-    # oracle's per-op pipeline dispatch through it makes those suites run
-    # for minutes on the 8-device CPU mesh — out of the tier-1 budget, so
-    # the seed behavior is kept until a faster oracle lands.
     from jax import lax
 
     nranks = lax.axis_size(axis)

@@ -20,16 +20,7 @@ send_barrier/fetch_barrier exist as explicit no-op markers).
 
 from __future__ import annotations
 
-from ..core.executor import _PS_IO_TYPES
 from ..core.registry import register_op
-
-PS_IO_OPS = ("send", "recv", "send_barrier", "fetch_barrier",
-             "listen_and_serv", "save", "load", "save_combine",
-             "load_combine", "checkpoint_notify", "py_func")
-# the executor keeps its own copy (core cannot import ops without a
-# cycle); fail loudly if the two ever drift
-assert set(PS_IO_OPS) == set(_PS_IO_TYPES), \
-    "ops/ps_ops.PS_IO_OPS and core/executor._PS_IO_TYPES must match"
 
 
 @register_op("send", skip_infer_shape=True)

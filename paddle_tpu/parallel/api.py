@@ -213,24 +213,11 @@ def spec_for_var(var, mesh, default=None, use_rules=True):
 
 
 def get_shard_map():
-    """shard_map entry point + its replication-check kwarg, across jax
-    versions. Returns (shard_map_fn, {kwarg: False})."""
-    import inspect
-
+    """shard_map entry point + the kwarg that turns its replication
+    check off: (jax.shard_map, {"check_vma": False})."""
     import jax
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
-    kwargs = {}
-    sig = inspect.signature(shard_map)
-    if "check_vma" in sig.parameters:
-        kwargs["check_vma"] = False
-    elif "check_rep" in sig.parameters:
-        kwargs["check_rep"] = False
-    return shard_map, kwargs
+    return jax.shard_map, {"check_vma": False}
 
 
 def named_sharding_for(var, mesh, default_spec=None):

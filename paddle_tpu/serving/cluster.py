@@ -28,7 +28,16 @@ Two replica backends share every code path above:
   chaos gate (tools/chaos_check.py --cluster, SIGKILL mid-load) use;
 * ``inprocess=True`` — engine + HTTP server threads in THIS process;
   same wire surface on real sockets, a fraction of the startup cost —
-  what most tier-1 tests use.
+  what most tier-1 tests use, and the way to run N replicas on one
+  accelerator host today.
+
+One process per chip: a chip belongs to one process, and an unpinned
+replica process claims every chip of its host. On a host with an
+accelerator the subprocess backend therefore refuses (typed
+core/chips.ChipContentionError, before anything is launched) a replica
+set of more than one process, and any spawn from a controller process
+that has itself initialised the accelerator backend. Replicas whose
+environment pins ``JAX_PLATFORMS=cpu`` are never limited.
 """
 
 from __future__ import annotations
@@ -44,7 +53,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from .. import checkpoint as _ckpt
-from ..core import fleetobs, retry, telemetry
+from ..core import chips, fleetobs, retry, telemetry
 from ..core.analysis import lockdep
 from ..core.flags import flag as _flag
 from .router import Router, RouterHTTPServer, _http_json
@@ -371,6 +380,16 @@ class ClusterController:
     def url(self) -> str:
         return self.router_server.url
 
+    def _check_chips(self, n_replicas: int):
+        """One process per chip (core/chips.py): fail loudly instead of
+        letting every replica process claim the same chip."""
+        if not self.inprocess:
+            chips.check_spawn(
+                n_replicas,
+                os.environ if self.replica_env is None
+                else self.replica_env,
+                f"ClusterController({n_replicas} subprocess replicas)")
+
     def _make_replica(self, index: int, role: Optional[str] = None):
         name = f"replica-{index}"
         log = ""
@@ -419,6 +438,7 @@ class ClusterController:
             # same lock
             with self._swap_lock:
                 self.current_version = newest[0]
+        self._check_chips(self.n_replicas)
         for _ in range(self.n_replicas):
             replica = self._make_replica(self._next_index)
             self._next_index += 1
@@ -736,6 +756,7 @@ class ClusterController:
             if n == old:
                 return old
             if n > old:
+                self._check_chips(n)
                 for _ in range(n - old):
                     replica = self._make_replica(self._next_index)
                     self._next_index += 1
@@ -812,6 +833,7 @@ class ClusterController:
             if n == old:
                 return old
             if n > old:
+                self._check_chips(len(self.replicas) + n - old)
                 for _ in range(n - old):
                     replica = self._make_replica(self._next_index,
                                                  role=role)

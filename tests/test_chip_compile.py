@@ -1,0 +1,179 @@
+"""AOT-compile the main-path Pallas kernels for a described v5e chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached: what Mosaic would refuse on the machine
+with the chip it refuses here, at no chip time. Each case compiles one
+kernel at a real width and asserts the compiled module carries the
+kernel (``tpu_custom_call``) — a compile that passes is not a chip run.
+
+This is the ONLY file that describes the chip, and it does so inside the
+``topo`` fixture: one process may load libtpu at a time, so nothing here
+runs at import, in ``skipif`` or in ``parametrize``.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one: keep the
+    # cache off around these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_mode(monkeypatch):
+    """The dispatchers ask jax.default_backend(), which is the CPU here:
+    steer them onto their compiled-kernel route for the trace."""
+    import paddle_tpu.ops.pallas as pallas
+
+    monkeypatch.delenv("PT_FLASH_IMPL", raising=False)
+    monkeypatch.delenv("PT_PALLAS", raising=False)
+    monkeypatch.setattr(pallas, "_requested_mode", lambda: "tpu")
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+
+@pytest.mark.parametrize("m,k,n", [(8, 2048, 8192), (8, 8192, 2048)])
+def test_int8_gemm_bias_relu(one_chip, tpu_mode, m, k, n):
+    from paddle_tpu.ops.pallas.int8_gemm import int8_weight_only_gemm
+
+    _compile(lambda x, w, s, b: int8_weight_only_gemm(x, w, s, b, "relu"),
+             one_chip, ((m, k), F32), ((k, n), I8), ((n,), F32),
+             ((n,), F32))
+
+
+def test_paged_attention_multi_chunk(one_chip, tpu_mode):
+    """b8, 16 heads x 128, page 16, 128 pages per row (2048-token
+    context) at the default chunk: the online-softmax branch."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    b, n, hd, page, mp, pool = 8, 16, 128, 16, 128, 1024
+    assert pa._chunk_pages(page, mp, n * hd) < mp      # multi-chunk
+    _compile(lambda q, pk, pv, t, p: pa.paged_decode_attention(
+        q, pk, pv, t, p, n, hd, hd ** -0.5), one_chip,
+        ((b, n * hd), F32), ((pool, page, n * hd), F32),
+        ((pool, page, n * hd), F32), ((b, mp), I32), ((b,), I32))
+
+
+def test_layer_norm_fwd_bwd(one_chip, tpu_mode):
+    from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
+
+    def loss(x, s, b):
+        return jnp.sum(fused_layer_norm(x, s, b)[0].astype(F32))
+
+    _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+             ((20480, 1024), BF16), ((1024,), F32), ((1024,), F32))
+
+
+# ERNIE-large attention: batch 40, 16 heads, seq 512, head dim 64, bf16,
+# key-padding bias and attention-probs dropout on
+_B, _H, _S, _D = 40, 16, 512, 64
+
+
+@pytest.mark.parametrize("layout", ["bnsd", "packed"])
+def test_flash_attention_fwd_bwd(one_chip, tpu_mode, layout):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    if layout == "bnsd":
+        qkv, heads = (_B, _H, _S, _D), None
+    else:
+        qkv, heads = (_B, _S, _H * _D), _H
+
+    def loss(q, k, v, bias, seed):
+        out = flash_attention(q, k, v, bias=bias, dropout_rate=0.1,
+                              dropout_seed=seed, num_heads=heads)
+        return jnp.sum(out.astype(F32))
+
+    text = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                    (qkv, BF16), (qkv, BF16), (qkv, BF16),
+                    ((_B, _S), F32), ((), jnp.uint32))
+    assert text.count("tpu_custom_call") >= 2          # fwd and bwd
+
+
+def test_fused_adamw(one_chip, tpu_mode):
+    from paddle_tpu.ops.pallas.fused_adam import fused_adamw
+
+    w = ((1024, 4096), F32)
+    _compile(lambda p, g, m, v, lr, b1, b2: fused_adamw(
+        p, g, m, v, lr, 0.9, 0.999, 1e-8, 0.01, b1, b2), one_chip,
+        w, w, w, w, ((), F32), ((), F32), ((), F32))
+
+
+def test_sharded_step_compiles_for_four_chips(topo, tpu_mode):
+    """The step jit partitions over a dp=2 x mp=2 mesh of the described
+    chips: Mosaic kernels cannot be partitioned automatically, so the
+    step must trace on the XLA lowerings (ops/pallas.auto_partitioned) —
+    with collectives, without a custom call."""
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel import create_mesh
+    from paddle_tpu.parallel.mesh import set_mesh
+
+    cfg = bert.ernie_large()
+    cfg.num_hidden_layers, cfg.dtype = 1, "bfloat16"
+    cfg.use_flash_attention = True
+    main, startup, _, fetches = bert.build_pretraining_program(
+        cfg, seq_len=512, optimizer_name="adamw",
+        max_predictions_per_seq=80)
+    exe, scope = pt.Executor(pt.CPUPlace()), pt.Scope()
+    exe.run(startup, scope=scope, use_compiled=False)
+    data = bert.synthetic_pretraining_batch(cfg, 8, 512,
+                                            max_predictions_per_seq=80)
+    feed_names = tuple(sorted(data))
+    mesh = create_mesh({"dp": 2, "mp": 2}, devices=topo.devices)
+    try:
+        entry = exe._compile(
+            main, main.global_block(), feed_names,
+            (fetches["loss"].name,), scope, mesh, None,
+            {n: True for n in feed_names})
+    finally:
+        set_mesh(None)
+
+    def shape(v):
+        v = np.asarray(v)
+        return jax.ShapeDtypeStruct(
+            v.shape, np.int32 if v.dtype == np.int64 else v.dtype)
+
+    text = entry.jitted.lower(
+        {n: shape(scope.find_var(n)) for n in entry.state_names},
+        {n: shape(scope.find_var(n)) for n in entry.ro_names},
+        {n: shape(data[n]) for n in feed_names},
+        jax.ShapeDtypeStruct((), I32)).compile().as_text()
+    assert "all-reduce" in text and "tpu_custom_call" not in text

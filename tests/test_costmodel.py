@@ -36,7 +36,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers
-from paddle_tpu.core import costmodel, telemetry
+from paddle_tpu.core import costmodel, flags, telemetry
 from paddle_tpu.core.flags import set_flags
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -48,9 +48,11 @@ def _fresh_plane():
     telemetry.reset()
     costmodel.reset()
     set_flags({"cost_capture": "auto"})
-    yield
-    set_flags({"cost_capture": "auto", "device_peak_flops": 0.0,
-               "device_peak_bw": 0.0})
+    # the CPU is in no peak table: the verdict/MFU cases score against a
+    # peak they name themselves (the v5e figures)
+    with flags.overrides(device_peak_flops=197e12, device_peak_bw=819e9):
+        yield
+    set_flags({"cost_capture": "auto"})
     telemetry.configure(None)
     telemetry.reset()
     costmodel.reset()
@@ -168,10 +170,37 @@ class TestCaptureExecutor:
         assert recs[4].flops == pytest.approx(recs[1].flops, rel=0.25)
 
     def test_peak_flops_override(self):
-        set_flags({"device_peak_flops": 123.0})
-        assert costmodel.peak_device_flops() == 123.0
-        set_flags({"device_peak_flops": 0.0})
-        assert costmodel.peak_device_flops() > 1e12   # table fallback
+        with flags.overrides(device_peak_flops=123.0):
+            assert costmodel.peak_device_flops() == 123.0
+
+    def test_unknown_device_kind_has_no_peak(self, scope, tmp_path):
+        """A device_kind the table lacks — the CPU is not a v5e — raises
+        a typed error; the in-loop callers omit their figure instead of
+        raising out of Executor.run."""
+        with flags.overrides(device_peak_flops=0.0, device_peak_bw=0.0):
+            with pytest.raises(costmodel.UnknownDevicePeakError,
+                               match="cpu"):
+                costmodel.peak_device_flops()
+            with pytest.raises(costmodel.UnknownDevicePeakError):
+                costmodel.peak_device_bandwidth()
+            _run_steps(scope, n=2, log=tmp_path / "run.jsonl")
+            rec = costmodel.programs()[0]
+            assert rec.flops > 0 and rec.roofline() == "unknown"
+            assert costmodel.live_mfu() is None
+            assert "cost.live_mfu" not in telemetry.gauges()
+
+    @pytest.mark.parametrize("kind,flops", [
+        ("TPU v5 lite", 197e12), ("TPU v5e", 197e12), ("TPU v5p", 459e12),
+        ("TPU v4", 275e12), ("TPU v6 lite", 918e12)])
+    def test_device_table_rows(self, monkeypatch, kind, flops):
+        import types
+
+        import jax
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [
+            types.SimpleNamespace(device_kind=kind)])
+        with flags.overrides(device_peak_flops=0.0, device_peak_bw=0.0):
+            assert costmodel.peak_device_flops() == flops
 
     def test_normalize_cost_analysis_shapes(self):
         """One place knows XLA's key spelling — list-vs-dict and the

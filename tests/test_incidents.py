@@ -560,15 +560,27 @@ class TestCLIs:
         assert v["verdict"] == "regress"
         assert any(not c["ok"] for c in v["checks"])
 
-    def test_slo_check_cli_smoke_against_repo_history(self):
-        """The committed BENCH history judges its own best row: PASS."""
+    def test_slo_check_cli_smoke(self, tmp_path):
+        """The CLI judges a row against a history that holds its equal:
+        PASS; against the repo root, which commits no history: PASS as
+        no_baseline, not a failure."""
+        row = {"metric": "m1", "value": 100.0, "unit": "tokens/s",
+               "extra": {"mfu": 0.5, "ms_per_step": 10.0}}
+        (tmp_path / "BENCH_r01.json").write_text(
+            json.dumps({"parsed": row}))
+        fresh = tmp_path / "fresh.json"
+        fresh.write_text(json.dumps(row))
+        cli = [sys.executable, os.path.join(REPO_ROOT, "tools",
+                                            "slo_check.py"), str(fresh)]
         r = subprocess.run(
-            [sys.executable, os.path.join(REPO_ROOT, "tools",
-                                          "slo_check.py"),
-             os.path.join(REPO_ROOT, "BENCH_r05.json")],
+            cli + ["--prior", str(tmp_path / "BENCH_r*.json")],
             capture_output=True, text=True, timeout=60, cwd=REPO_ROOT)
         assert r.returncode == 0, r.stdout + r.stderr
-        assert "PASS" in r.stdout
+        assert "1 prior row(s): PASS" in r.stdout
+        r = subprocess.run(cli, capture_output=True, text=True,
+                           timeout=60, cwd=REPO_ROOT)
+        assert r.returncode == 0, r.stdout + r.stderr
+        assert "0 prior row(s): NO_BASELINE" in r.stdout
 
     @pytest.mark.chaos
     def test_chaos_slo_fault_and_clean_legs(self):

@@ -142,7 +142,6 @@ import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def build_net(lr):

@@ -24,7 +24,7 @@ into ``extra.slo`` via :func:`embed_verdict` (finalize_bench_result), so
 committed BENCH rows are self-judging.
 
 Usage:
-    python tools/slo_check.py BENCH_r05.json                 # vs repo history
+    python tools/slo_check.py row.json                       # vs rows in the repo root (none committed: no_baseline)
     python tools/slo_check.py row.json --prior 'BENCH_r*.json'
     python tools/slo_check.py row.json --tol-throughput 0.1 --json
 
@@ -161,18 +161,15 @@ def slo_verdict(row, prior_rows, tolerances=None):
 
 def embed_verdict(row, bench_dir=None):
     """The verdict finalize_bench_result embeds as ``extra.slo``:
-    judged against the committed BENCH_r*.json history next to
-    BASELINE.json. Never raises (a bench run must not die on a gate)."""
-    try:
-        root = bench_dir or os.environ.get("PT_BENCH_DIR") or REPO_ROOT
-        prior = load_prior_rows([os.path.join(root, "BENCH_r*.json"),
-                                 os.path.join(root, "MULTICHIP_r*.json")])
-        v = slo_verdict(row, prior)
-        return {"verdict": v["verdict"], "peers": v["peers"],
-                "failed": [c["metric"] for c in v["checks"]
-                           if not c["ok"]]}
-    except Exception as e:
-        return {"verdict": "error", "error": f"{type(e).__name__}: {e}"}
+    judged against the BENCH_r*.json / MULTICHIP_r*.json rows in
+    ``bench_dir`` (PT_BENCH_DIR, else the repo root). A directory that
+    holds no history gives ``no_baseline``."""
+    root = bench_dir or os.environ.get("PT_BENCH_DIR") or REPO_ROOT
+    prior = load_prior_rows([os.path.join(root, "BENCH_r*.json"),
+                             os.path.join(root, "MULTICHIP_r*.json")])
+    v = slo_verdict(row, prior)
+    return {"verdict": v["verdict"], "peers": v["peers"],
+            "failed": [c["metric"] for c in v["checks"] if not c["ok"]]}
 
 
 def main(argv=None):
