@@ -1,0 +1,7 @@
+"""Time per output token after the first, per request, 90th percentile."""
+
+from benchmark.readers._latency import pct, tpot_ms
+
+
+def read(ctx):
+    return pct(ctx, tpot_ms, 90, need=lambda r: r["tokens"] > 1)
