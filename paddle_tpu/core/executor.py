@@ -103,12 +103,16 @@ def run_op(op: OpDesc, env: Dict[str, Any], step=None, axis_coords=None):
     if axis_coords:
         attrs["__axis_coords__"] = axis_coords
     try:
+        import jax
+
         from .. import profiler as _prof
 
         # per-op host span (reference: RecordEvent around op->Run,
         # framework/operator.cc:195); only the interpreting path reaches
         # here per step — under jit this runs once at trace time
-        with _prof.RecordEvent(op.type):
+        # the named scope puts the op's type into the metadata of every
+        # operation its lowering emits (trace time only under jit)
+        with _prof.RecordEvent(op.type), jax.named_scope(op.type):
             outs = registry.normalize_outputs(opdef.forward(ins, attrs))
     except ExecutionError:
         raise
@@ -357,10 +361,12 @@ class Executor:
             telemetry.counter_add("executor.feed_host_bytes",
                                   int(feed_host_bytes))
 
+        phases: Dict[str, float] = {}
         with trace.span("executor.run", program=program.uid):
             block = program.global_block()
             # cast feeds to declared dtypes
-            with trace.span("executor.feed", feeds=len(feed)):
+            with trace.span("executor.feed", feeds=len(feed)), \
+                    telemetry.timer("executor.feed_ms", into=phases):
                 for name in list(feed):
                     dtype = None
                     if block.has_var(name):
@@ -381,15 +387,34 @@ class Executor:
                 with trace.span("executor.dispatch", compiled=True):
                     fetched = self._run_compiled(program, block, feed,
                                                  fetch_names, scope,
-                                                 mesh, in_shardings)
+                                                 mesh, in_shardings, phases)
             else:
                 with telemetry.timer("executor.interpret_ms"), \
                         trace.span("executor.dispatch", compiled=False):
                     fetched = self._run_interpreted(program, block, feed,
                                                     fetch_names, scope, mesh)
-            with trace.span("executor.fetch", sync=sync_fetch):
-                return self._materialize_fetches(fetched, return_numpy,
-                                                 sync_fetch)
+            with trace.span("executor.fetch", sync=sync_fetch), \
+                    telemetry.timer("executor.writeback_ms", into=phases):
+                out = self._materialize_fetches(fetched, return_numpy,
+                                                sync_fetch)
+            self._observe_phases(phases)
+            return out
+
+    @staticmethod
+    def _observe_phases(phases: Dict[str, float]):
+        """A steady-state compiled run's phase times into their histograms:
+        executor.feed_ms (cast + host-to-device of the feeds), state_ms
+        (cache key, state gathered from the scope, the donation check),
+        call_ms (the jitted call RETURNING: dispatch is asynchronous, this
+        is not the device's time), book_ms (cost booking, collective
+        accounting, watchdog and goodput ticks), writeback_ms (new state
+        into the scope, the fetches handed back: with sync_fetch the wait
+        for the device is here). Histograms only: executor.run_ms is the
+        run log's record of the step. An interpreted run or one that
+        compiled has no call_ms and records nothing."""
+        if "executor.call_ms" in phases:
+            for name, ms in phases.items():
+                telemetry.observe_quiet(name, ms)
 
     @staticmethod
     def _materialize_fetches(fetched, return_numpy, sync_fetch):
@@ -464,11 +489,13 @@ class Executor:
             telemetry.counter_add("executor.feed_host_bytes",
                                   int(feed_host_bytes))
 
+        phases: Dict[str, float] = {}
         with trace.span("executor.run_steps", program=program.uid, k=k):
             block = program.global_block()
             # cast stacked feeds to declared per-step dtypes (the leading k
             # axis does not change dtype)
-            with trace.span("executor.feed", feeds=len(feed)):
+            with trace.span("executor.feed", feeds=len(feed)), \
+                    telemetry.timer("executor.feed_ms", into=phases):
                 for name in list(feed):
                     dtype = None
                     if block.has_var(name):
@@ -501,10 +528,13 @@ class Executor:
             with trace.span("executor.dispatch", compiled=True, k=k):
                 fetched = self._run_compiled(program, block, feed,
                                              fetch_names, scope, mesh,
-                                             in_shardings, scan_k=k)
-            with trace.span("executor.fetch", sync=sync_fetch):
-                return self._materialize_fetches(fetched, return_numpy,
-                                                 sync_fetch)
+                                             in_shardings, phases, scan_k=k)
+            with trace.span("executor.fetch", sync=sync_fetch), \
+                    telemetry.timer("executor.writeback_ms", into=phases):
+                out = self._materialize_fetches(fetched, return_numpy,
+                                                sync_fetch)
+            self._observe_phases(phases)
+            return out
 
     # -- interpreting path ---------------------------------------------------
     def _run_interpreted(self, program, block, feed, fetch_names, scope,
@@ -777,220 +807,221 @@ class Executor:
         return out
 
     # -- compiling path ------------------------------------------------------
-    def _run_compiled(self, program, block, feed, fetch_names, scope, mesh=None,
-                      in_shardings=None, scan_k=None):
-        import jax
+    def _run_compiled(self, program, block, feed, fetch_names, scope, mesh,
+                      in_shardings, phases, scan_k=None):
+        """One dispatch of the program's jitted step. ``phases`` takes this
+        run's phase times (``executor.state_ms``, ``call_ms``, ``book_ms``,
+        the scope's part of ``writeback_ms``) for the caller to observe; a
+        run that compiles hands it back empty."""
+        with telemetry.timer("executor.state_ms", into=phases):
+            feed_names = tuple(sorted(feed))
+            # default batch-sharding of a feed is only safe when its batch dim
+            # divides the mesh's batch axis (rule-table driven, 'dp' under the
+            # default table); partial batches compile a replicated entry.
+            # Under K-step fusion the per-step batch dim sits BEHIND the
+            # stacked [k] axis (dim 1)
+            from ..parallel import axis_rules
 
-        feed_names = tuple(sorted(feed))
-        # default batch-sharding of a feed is only safe when its batch dim
-        # divides the mesh's batch axis (rule-table driven, 'dp' under the
-        # default table); partial batches compile a replicated entry.
-        # Under K-step fusion the per-step batch dim sits BEHIND the
-        # stacked [k] axis (dim 1)
-        from ..parallel import axis_rules
+            batch_dim = 1 if scan_k else 0
+            batch_axis = axis_rules.batch_mesh_axis(mesh)
+            dp = mesh.shape.get(batch_axis) if batch_axis else None
+            dp_ok = {}
+            if dp:
+                for n in feed_names:
+                    v = feed[n]
+                    dp_ok[n] = bool(getattr(v, "ndim", 0) >= batch_dim + 1
+                                    and v.shape[batch_dim] % dp == 0)
+            from .. import profiler as _prof
 
-        batch_dim = 1 if scan_k else 0
-        batch_axis = axis_rules.batch_mesh_axis(mesh)
-        dp = mesh.shape.get(batch_axis) if batch_axis else None
-        dp_ok = {}
-        if dp:
-            for n in feed_names:
-                v = feed[n]
-                dp_ok[n] = bool(getattr(v, "ndim", 0) >= batch_dim + 1
-                                and v.shape[batch_dim] % dp == 0)
-        from .. import profiler as _prof
+            # mesh keyed by content (axes/topology), program/scope by uid —
+            # id() could alias a GC'd object (VERDICT r1 weak #8)
+            mesh_key = None
+            if mesh is not None:
+                mesh_key = (tuple(mesh.axis_names), mesh.devices.shape,
+                            tuple(d.id for d in mesh.devices.flat))
+            # the rule table resolves shardings at trace time, so its content
+            # hash MUST key the cache (a swapped table recompiles instead of
+            # reusing stale shardings); zero_stage names the ZeRO config in
+            # recompile-cause diagnostics
+            rules_fp = axis_rules.fingerprint() if mesh is not None else None
+            zero_stage = getattr(program, "_zero_stage", None)
+            # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
+            # geometry, ops/pallas.kernels_fingerprint) is read at TRACE
+            # time by the kernel dispatchers — a mid-process mode flip or
+            # chunk-flag change must recompile, not reuse an entry lowered
+            # for the other kernel variant (and the PR 10 cost capture then
+            # attributes flops/bytes per variant)
+            from ..ops import pallas as _pallas
 
-        # mesh keyed by content (axes/topology), program/scope by uid —
-        # id() could alias a GC'd object (VERDICT r1 weak #8)
-        mesh_key = None
-        if mesh is not None:
-            mesh_key = (tuple(mesh.axis_names), mesh.devices.shape,
-                        tuple(d.id for d in mesh.devices.flat))
-        # the rule table resolves shardings at trace time, so its content
-        # hash MUST key the cache (a swapped table recompiles instead of
-        # reusing stale shardings); zero_stage names the ZeRO config in
-        # recompile-cause diagnostics
-        rules_fp = axis_rules.fingerprint() if mesh is not None else None
-        zero_stage = getattr(program, "_zero_stage", None)
-        # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
-        # geometry, ops/pallas.kernels_fingerprint) is read at TRACE
-        # time by the kernel dispatchers — a mid-process mode flip or
-        # chunk-flag change must recompile, not reuse an entry lowered
-        # for the other kernel variant (and the PR 10 cost capture then
-        # attributes flops/bytes per variant)
-        from ..ops import pallas as _pallas
-
-        pallas_fp = _pallas.kernels_fingerprint()
-        key = (program.uid, program.version, scope.uid, feed_names,
-               tuple(fetch_names), mesh_key, tuple(sorted(dp_ok.items())),
-               scan_k, rules_fp, zero_stage, pallas_fp)
-        entry = self._cache.get(key)
-        compile_cause = None
-        t_compile = None
-        if entry is None:
-            # recompile-cause diagnostic: name the key component that
-            # changed vs the nearest cached entry BEFORE inserting, so a
-            # silent retrace shows up as e.g. cause="dp_divisibility"
-            compile_cause = _recompile_cause(key, self._cache)
-            telemetry.counter_add("executor.cache_misses", 1)
-            t_compile = time.perf_counter()
-            with _prof.RecordEvent("executor::compile"):
-                entry = self._compile(program, block, feed_names, fetch_names,
-                                      scope, mesh, in_shardings, dp_ok,
-                                      scan_k=scan_k)
-            self._cache[key] = entry
-        else:
-            telemetry.counter_add("executor.cache_hits", 1)
-
-        state = {}
-        seen_bufs: Dict[int, str] = {}
-        for n in entry.state_names:
-            v = scope.find_var(n)
-            if v is None:
-                raise ExecutionError(
-                    f"persistable var '{n}' not initialised in scope — "
-                    f"did you run the startup program?")
-            # state buffers are donated: two names aliasing one device
-            # buffer would fail Execute(); copy the duplicate. The buffer
-            # pointer is the key (it works on the CPU and the TPU
-            # runtime); a value without a single pointer (numpy, a
-            # sharded or deleted array) keys on object identity, which
-            # still catches same-array-two-names aliasing
-            try:
-                bkey = v.unsafe_buffer_pointer()
-            except (AttributeError, ValueError, RuntimeError):
-                bkey = id(v)
-            if bkey in seen_bufs:
-                import jax.numpy as jnp
-
-                v = jnp.copy(v)
-                telemetry.counter_add("executor.donation_copies", 1,
-                                      var=n, aliases=seen_bufs[bkey])
+            pallas_fp = _pallas.kernels_fingerprint()
+            key = (program.uid, program.version, scope.uid, feed_names,
+                   tuple(fetch_names), mesh_key, tuple(sorted(dp_ok.items())),
+                   scan_k, rules_fp, zero_stage, pallas_fp)
+            entry = self._cache.get(key)
+            compile_cause = None
+            t_compile = None
+            if entry is None:
+                # recompile-cause diagnostic: name the key component that
+                # changed vs the nearest cached entry BEFORE inserting, so a
+                # silent retrace shows up as e.g. cause="dp_divisibility"
+                compile_cause = _recompile_cause(key, self._cache)
+                telemetry.counter_add("executor.cache_misses", 1)
+                t_compile = time.perf_counter()
+                with _prof.RecordEvent("executor::compile"):
+                    entry = self._compile(program, block, feed_names,
+                                          fetch_names, scope, mesh,
+                                          in_shardings, dp_ok, scan_k=scan_k)
+                self._cache[key] = entry
             else:
-                seen_bufs[bkey] = n
-            state[n] = v
-        ro = {n: scope.find_var(n) for n in entry.ro_names}
-        step = scope.find_var("@STEP_COUNTER@")
-        if step is None:
-            step = _as_device_array(0, np.int32)
+                telemetry.counter_add("executor.cache_hits", 1)
 
-        # per-compile cost/memory capture (core/costmodel.py): the AOT
-        # analyses run against THIS cache entry's lowering before state
-        # buffers are donated; lower() shares the trace cache with the
-        # first execution, so 'cost' level adds ~no work. Degrades by
-        # counting (costmodel.unavailable), never by raising.
-        if compile_cause is not None and \
-                costmodel.capture_mode() != "off":
-            entry.cost = costmodel.capture(
-                lambda: entry.jitted.lower(state, ro, feed, step),
-                key_id=costmodel.key_id_for(key), kind="executor",
-                program=f"{program.uid}v{program.version}",
-                steps_per_dispatch=scan_k or 1)
-            # HBM ledger: persistable split of this program's resident
-            # state (params vs optimizer/run state)
-            names = list(entry.state_names) + list(entry.ro_names)
-            vals = [state.get(n, ro.get(n)) for n in names]
-            pb, ob = costmodel.split_persistable_bytes(block, names, vals)
-            costmodel.record_model_bytes(pb, ob)
+            state = {}
+            seen_bufs: Dict[int, str] = {}
+            for n in entry.state_names:
+                v = scope.find_var(n)
+                if v is None:
+                    raise ExecutionError(
+                        f"persistable var '{n}' not initialised in scope — "
+                        f"did you run the startup program?")
+                # state buffers are donated: two names aliasing one device
+                # buffer would fail Execute(); copy the duplicate. The buffer
+                # pointer is the key (it works on the CPU and the TPU
+                # runtime); a value without a single pointer (numpy, a
+                # sharded or deleted array) keys on object identity, which
+                # still catches same-array-two-names aliasing
+                try:
+                    bkey = v.unsafe_buffer_pointer()
+                except (AttributeError, ValueError, RuntimeError):
+                    bkey = id(v)
+                if bkey in seen_bufs:
+                    import jax.numpy as jnp
+
+                    v = jnp.copy(v)
+                    telemetry.counter_add("executor.donation_copies", 1,
+                                          var=n, aliases=seen_bufs[bkey])
+                else:
+                    seen_bufs[bkey] = n
+                state[n] = v
+            ro = {n: scope.find_var(n) for n in entry.ro_names}
+            step = scope.find_var("@STEP_COUNTER@")
+            if step is None:
+                step = _as_device_array(0, np.int32)
+
+            # per-compile cost/memory capture (core/costmodel.py): the AOT
+            # analyses run against THIS cache entry's lowering before state
+            # buffers are donated; lower() shares the trace cache with the
+            # first execution, so 'cost' level adds ~no work. Degrades by
+            # counting (costmodel.unavailable), never by raising.
+            if compile_cause is not None and \
+                    costmodel.capture_mode() != "off":
+                entry.cost = costmodel.capture(
+                    lambda: entry.jitted.lower(state, ro, feed, step),
+                    key_id=costmodel.key_id_for(key), kind="executor",
+                    program=f"{program.uid}v{program.version}",
+                    steps_per_dispatch=scan_k or 1)
+                # HBM ledger: persistable split of this program's resident
+                # state (params vs optimizer/run state)
+                names = list(entry.state_names) + list(entry.ro_names)
+                vals = [state.get(n, ro.get(n)) for n in names]
+                pb, ob = costmodel.split_persistable_bytes(block, names, vals)
+                costmodel.record_model_bytes(pb, ob)
 
         t_run = time.perf_counter()
         t_run_wall = time.time()
-        try:
-            with _prof.RecordEvent("executor::run"):
-                fetches, new_state, new_step = entry.jitted(state, ro,
-                                                            feed, step)
-        except Exception as e:
-            # allocation failure: land the OOM forensics record (ledger
-            # snapshot + top cached programs by peak bytes + this
-            # program's id) in the run log, then raise typed
-            if costmodel.is_oom_error(e):
-                raise costmodel.oom_forensics(
-                    f"{program.uid}v{program.version}", e,
-                    where="executor.dispatch") from e
-            raise
-        # device-compute wall of the jitted call (goodput ledger's
-        # "productive" phase; the post-call booking below is host time)
-        t_dev_end = time.perf_counter()
-        costmodel.book_dispatch(entry.cost, steps=scan_k or 1)
-        # sharded-training collective accounting: the ShardingOptimizer
-        # (fleet/meta_optimizers.py) precomputes the per-step dp-collective
-        # payloads of the program; every dispatch books them (×k under
-        # fusion) and, when tracing, a child span puts the collectives on
-        # the trace_view critical path
-        sbytes = getattr(program, "_sharding_bytes", None)
-        if sbytes:
-            k_mult = scan_k or 1
-            for cname, nbytes in sbytes.items():
-                if nbytes:
-                    telemetry.counter_add(f"sharding.{cname}_bytes",
-                                          int(nbytes) * k_mult)
-            parent = trace.current()
-            if parent is not None:
-                # span timebase is epoch seconds (trace._Span.start)
-                trace.record("sharding.collectives", parent, t_run_wall,
-                             time.time(), zero_stage=zero_stage,
-                             steps=k_mult,
-                             **{f"{cn}_bytes": int(nb)
-                                for cn, nb in sbytes.items() if nb})
-        if scan_k:
-            telemetry.counter_add("executor.fused_dispatches", 1)
-            telemetry.counter_add("executor.fused_steps", scan_k)
-        if compile_cause is not None:
-            # jax.jit compiles lazily — the first execution carries the
-            # trace + XLA compile, so compile wall time is measured through
-            # it (and excluded from the run_ms step-time histogram)
-            compile_ms = (time.perf_counter() - t_compile) * 1e3
-            telemetry.counter_add("executor.compiles", 1)
-            telemetry.counter_add("executor.compile_ms",
-                                  round(compile_ms, 3))
-            telemetry.gauge_set("executor.cache_size", len(self._cache))
-            telemetry.event(
-                "compile", "executor", round(compile_ms, 3),
-                {"cause": compile_cause, "cache_size": len(self._cache),
-                 "program": program.uid, "program_version": program.version,
-                 "feed_names": list(feed_names),
-                 "fetch_names": list(fetch_names),
-                 "mesh": None if mesh_key is None else list(mesh_key[0]),
-                 "dp_divisibility": sorted(dp_ok.items()),
-                 "steps_per_dispatch": scan_k or 1,
-                 "axis_rules": rules_fp, "zero_stage": zero_stage,
-                 "pallas_kernels": pallas_fp})
-        else:
-            # host-side dispatch wall time (device dispatch is async —
-            # these are the step-time percentiles in the run log).
-            # Fused dispatches land in their own histogram: one sample
-            # covers scan_k device steps
-            run_ms = (time.perf_counter() - t_run) * 1e3
-            telemetry.observe(
-                "executor.run_steps_ms" if scan_k else "executor.run_ms",
-                run_ms, kind="timer")
-            # goodput-ledger split of the same wall: the jitted call is
-            # the productive device-compute phase, everything after it
-            # (cost booking, collective accounting) is host dispatch
-            dev_ms = (t_dev_end - t_run) * 1e3
-            telemetry.observe("executor.device_ms", dev_ms, kind="timer")
-            telemetry.observe("executor.host_dispatch_ms",
-                              max(0.0, run_ms - dev_ms), kind="timer")
-        # SLO watchdog hook: evaluates the rule set at most every
-        # FLAGS_slo_eval_s while armed, one boolean read otherwise
-        incidents.tick()
-        # goodput-ledger refresh (goodput.ratio live on /metrics) —
-        # throttled to FLAGS_goodput_publish_s, inert without a window
-        goodput.tick()
-        from .flags import flag as _flag
+        with telemetry.timer("executor.call_ms", into=phases):
+            try:
+                with _prof.RecordEvent("executor::run"):
+                    fetches, new_state, new_step = entry.jitted(state, ro,
+                                                                feed, step)
+            except Exception as e:
+                # allocation failure: land the OOM forensics record (ledger
+                # snapshot + top cached programs by peak bytes + this
+                # program's id) in the run log, then raise typed
+                if costmodel.is_oom_error(e):
+                    raise costmodel.oom_forensics(
+                        f"{program.uid}v{program.version}", e,
+                        where="executor.dispatch") from e
+                raise
+        with telemetry.timer("executor.book_ms", into=phases):
+            costmodel.book_dispatch(entry.cost, steps=scan_k or 1)
+            # sharded-training collective accounting: the ShardingOptimizer
+            # (fleet/meta_optimizers.py) precomputes the per-step dp-collective
+            # payloads of the program; every dispatch books them (×k under
+            # fusion) and, when tracing, a child span puts the collectives on
+            # the trace_view critical path
+            sbytes = getattr(program, "_sharding_bytes", None)
+            if sbytes:
+                k_mult = scan_k or 1
+                for cname, nbytes in sbytes.items():
+                    if nbytes:
+                        telemetry.counter_add(f"sharding.{cname}_bytes",
+                                              int(nbytes) * k_mult)
+                parent = trace.current()
+                if parent is not None:
+                    # span timebase is epoch seconds (trace._Span.start)
+                    trace.record("sharding.collectives", parent, t_run_wall,
+                                 time.time(), zero_stage=zero_stage,
+                                 steps=k_mult,
+                                 **{f"{cn}_bytes": int(nb)
+                                    for cn, nb in sbytes.items() if nb})
+            if scan_k:
+                telemetry.counter_add("executor.fused_dispatches", 1)
+                telemetry.counter_add("executor.fused_steps", scan_k)
+            if compile_cause is not None:
+                # jax.jit compiles lazily — the first execution carries the
+                # trace + XLA compile, so compile wall time is measured through
+                # it (and excluded from the run_ms step-time histogram)
+                compile_ms = (time.perf_counter() - t_compile) * 1e3
+                telemetry.counter_add("executor.compiles", 1)
+                telemetry.counter_add("executor.compile_ms",
+                                      round(compile_ms, 3))
+                telemetry.gauge_set("executor.cache_size", len(self._cache))
+                telemetry.event(
+                    "compile", "executor", round(compile_ms, 3),
+                    {"cause": compile_cause, "cache_size": len(self._cache),
+                     "program": program.uid, "program_version": program.version,
+                     "feed_names": list(feed_names),
+                     "fetch_names": list(fetch_names),
+                     "mesh": None if mesh_key is None else list(mesh_key[0]),
+                     "dp_divisibility": sorted(dp_ok.items()),
+                     "steps_per_dispatch": scan_k or 1,
+                     "axis_rules": rules_fp, "zero_stage": zero_stage,
+                     "pallas_kernels": pallas_fp})
+            else:
+                # host-side dispatch wall time (device dispatch is async —
+                # these are the step-time percentiles in the run log).
+                # Fused dispatches land in their own histogram: one sample
+                # covers scan_k device steps
+                run_ms = (time.perf_counter() - t_run) * 1e3
+                telemetry.observe(
+                    "executor.run_steps_ms" if scan_k else "executor.run_ms",
+                    run_ms, kind="timer")
+            # SLO watchdog hook: evaluates the rule set at most every
+            # FLAGS_slo_eval_s while armed, one boolean read otherwise
+            incidents.tick()
+            # goodput-ledger refresh (goodput.ratio live on /metrics) —
+            # throttled to FLAGS_goodput_publish_s, inert without a window
+            goodput.tick()
+        with telemetry.timer("executor.writeback_ms", into=phases):
+            from .flags import flag as _flag
 
-        if _flag("check_nan_inf"):
-            # fused on-device isfinite reduction, one host sync of the
-            # verdict vector — debug flag semantics without a full state
-            # download (reference: FLAGS_check_nan_inf,
-            # nan_inf_utils_detail.cc)
-            _assert_all_finite(
-                list(new_state.items()) + list(zip(entry.fetch_names,
-                                                   fetches)),
-                "run_steps" if scan_k else "run")
-        for n, v in new_state.items():
-            scope.set(n, v)
-        scope.set("@STEP_COUNTER@", new_step)
+            if _flag("check_nan_inf"):
+                # fused on-device isfinite reduction, one host sync of the
+                # verdict vector — debug flag semantics without a full state
+                # download (reference: FLAGS_check_nan_inf,
+                # nan_inf_utils_detail.cc)
+                _assert_all_finite(
+                    list(new_state.items()) + list(zip(entry.fetch_names,
+                                                       fetches)),
+                    "run_steps" if scan_k else "run")
+            for n, v in new_state.items():
+                scope.set(n, v)
+            scope.set("@STEP_COUNTER@", new_step)
+            # the donated arrays die here, inside the phase that replaced
+            # them, not when this frame is torn down after the last timer
+            state.clear()
+        if compile_cause is not None:
+            phases.clear()     # a run that compiled stays out of the phases
         return list(fetches)
 
     def _compile(self, program, block, feed_names, fetch_names, scope, mesh,
@@ -1127,6 +1158,12 @@ class Executor:
                 def fn(*args):
                     with _pallas.auto_partitioned():
                         return unpartitioned(*args)
+        # the program's name in the profiler's trace (XLA Modules reads
+        # jit_train_step) and in the compile cache's key; no uid in it, which
+        # would miss the cache whenever another program is built first
+        fn.__name__ = fn.__qualname__ = (
+            ("train_step" if state_names else "infer_step")
+            + (f"s_k{scan_k}" if scan_k is not None else ""))
         jitted = jax.jit(fn, **jit_kwargs)
         return _CompiledEntry(jitted, state_names, ro_names, fetch_tuple,
                               bool(state_names))

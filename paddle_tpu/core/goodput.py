@@ -8,14 +8,17 @@ metric. This module is the ledger: it attributes the wall-clock of a
 training run to phases using the timers the framework already emits
 plus two new instrumentation points:
 
-* ``productive`` — device compute: the ``executor.device_ms`` wall of
-  the jitted dispatch (executor.py measures it around the compiled
-  callable on every cache-hit dispatch);
+* ``productive`` — the jitted step call returning
+  (``executor.call_ms``, around the compiled callable on every cache-hit
+  dispatch). Dispatch is asynchronous: this is the device's time only
+  where the caller fetches synchronously or the device's queue is full,
+  and the enqueue alone where the host runs ahead;
 * ``data_wait`` — the training loop blocked on the reader/feed path
   (``reader.data_wait_ms``: the DataLoader consumer's queue wait and
   train_from_dataset's batch-iterator wait);
-* ``host_dispatch`` — host-side dispatch overhead around the device
-  call (``executor.host_dispatch_ms`` = run wall minus device wall);
+* ``host_dispatch`` — host-side booking after the call returns
+  (``executor.book_ms``: cost booking, collective accounting, watchdog
+  and goodput ticks);
 * ``compile`` — trace+XLA compile (``executor.compile_ms``, PR 1);
 * ``checkpoint`` — crash-consistent saves (``ckpt.save_ms``, PR 5);
 * ``collective`` — host-measured collective time when a backend
@@ -53,14 +56,14 @@ from . import telemetry
 #: counter. Order is the render order.
 BADPUT_SOURCES = (
     ("data_wait", "hist", "reader.data_wait_ms"),
-    ("host_dispatch", "hist", "executor.host_dispatch_ms"),
+    ("host_dispatch", "hist", "executor.book_ms"),
     ("compile", "counter", "executor.compile_ms"),
     ("checkpoint", "hist", "ckpt.save_ms"),
     ("collective", "hist", "sharding.collective_ms"),
     ("recovery", "hist", "ckpt.restore_ms"),
 )
 
-PRODUCTIVE_SOURCE = ("hist", "executor.device_ms")
+PRODUCTIVE_SOURCE = ("hist", "executor.call_ms")
 
 PHASES = tuple(p for p, _k, _m in BADPUT_SOURCES) + ("other",)
 

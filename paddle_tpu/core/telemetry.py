@@ -143,6 +143,25 @@ def bucket_quantile(counts: Sequence[int], q: float) -> float:
     return HIST_BUCKET_BOUNDS[-1]
 
 
+_TRACE_ANNOTATION = [None]   # jax.profiler.TraceAnnotation, once resolved
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+    """A profiler annotation for one timed region; a null context in a
+    process that has not imported jax: a pserver or a tool that only counts
+    must not start to. An annotation outside a running trace costs one
+    TraceMe check."""
+    cls = _TRACE_ANNOTATION[0]
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation as cls
+
+        _TRACE_ANNOTATION[0] = cls
+    return cls(name)
+
+
 class _Hist:
     """Running histogram: exact count/sum/min/max + a bounded sample ring
     for percentile estimates (recent-window semantics once full) + fixed
@@ -411,14 +430,41 @@ class TelemetryRegistry:
             dq.append((time.time(), float(value)))
         self.emit(kind, name, round(float(value), 4), attrs)
 
+    def observe_quiet(self, name: str, value):
+        """Histogram-only observation: no JSONL record, nothing in the
+        flight recorder, no sample in the rolling window (/metrics shows
+        the cumulative sum, count and buckets, no window quantiles). For
+        samples a hot loop takes many times a step (the decode engine's
+        phases and token gaps, the executor's phases): as records they
+        would crowd the run log and the recorder's ring out, and as window
+        samples every ``windowed()`` pass of the SLO watchdog would sort
+        them under this lock. ``counter_quiet``'s twin."""
+        with self._lock:
+            h = self._hists.get(name)
+            if h is None:
+                h = self._hists[name] = _Hist()
+            h.observe(value)
+
     @contextlib.contextmanager
-    def timer(self, name: str, **attrs):
+    def timer(self, name: str, into: Optional[Dict[str, float]] = None,
+              **attrs):
+        """Times the region into the histogram ``name`` (ms) and, while a
+        jax profiler trace is running, marks it there as a
+        ``TraceAnnotation`` of the same name, on the clock of the device's
+        own lines. ``into`` defers the sample: the ms are added to that dict
+        under ``name`` instead of the histogram, for a caller that decides
+        at the end of an iteration whether it counts (DecodeEngine._loop,
+        Executor.run)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with _annotation(name):
+                yield
         finally:
-            self.observe(name, (time.perf_counter() - t0) * 1e3,
-                         kind="timer", **attrs)
+            ms = (time.perf_counter() - t0) * 1e3
+            if into is None:
+                self.observe(name, ms, kind="timer", **attrs)
+            else:
+                into[name] = into.get(name, 0.0) + ms
 
     # -- snapshots -----------------------------------------------------------
     def counters(self) -> Dict[str, Any]:
@@ -724,8 +770,12 @@ def observe(name: str, value, kind: str = "hist", **attrs):
     return _reg().observe(name, value, kind=kind, **attrs)
 
 
-def timer(name: str, **attrs):
-    return _reg().timer(name, **attrs)
+def observe_quiet(name: str, value):
+    return _reg().observe_quiet(name, value)
+
+
+def timer(name: str, into: Optional[Dict[str, float]] = None, **attrs):
+    return _reg().timer(name, into=into, **attrs)
 
 
 def event(kind: str, name: str, value=None, attrs=None):

@@ -446,7 +446,7 @@ def _fwd_pallas_fused(q, k, v, bias_kv, causal, scale, interpret,
                    pl.BlockSpec((1, 1, sq), lambda bi: (bi, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32)],
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_fwd_fused")(*args)
     return o3.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
@@ -485,7 +485,7 @@ def _fwd_pallas_fused_g(q, k, v, bias_kv, causal, scale, interpret, g,
                    pl.BlockSpec((g, 1, sq), lambda bi: (bi, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32)],
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_fwd_fused_g")(*args)
     return o3.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
@@ -539,7 +539,7 @@ def _bwd_pallas_fused_g(q, k, v, bias_kv, causal, scale, interpret, g,
                                 dq, dk, dv, None, **kw)
     outs = pl.pallas_call(
         kernel, grid=(bh // g,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*args)
+        out_shape=out_shape, interpret=interpret, name="flash_bwd_fused_g")(*args)
     if has_bias:
         dq3, dk3, dv3, dbias3 = outs
         dbias = jnp.sum(dbias3.reshape(b, h // g, sk), axis=1)
@@ -617,7 +617,7 @@ def _fwd_pallas(q, k, v, bias_kv, causal, scale, interpret,
                           rate=rate, n_heads=h, sq_g=sq, sk_g=sk),
         grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_fwd_2pass")(*args)
     return o3.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
@@ -1071,7 +1071,7 @@ def _fwd_pallas_packed(q3, k3, v3, bias_kv, causal, scale, interpret,
                                 lambda c, _n=npg: (c // _n, c % _n, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, sq, htot), q3.dtype),
                    jax.ShapeDtypeStruct((b, n_heads, sq), jnp.float32)],
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_fwd_packed")(*args)
     return o3, lse
 
 
@@ -1114,7 +1114,7 @@ def _bwd_pallas_packed(q3, k3, v3, bias_kv, causal, scale, interpret,
                                dq, dk, dv, None, **kw)
     outs = pl.pallas_call(
         kernel, grid=(b * npg,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*args)
+        out_shape=out_shape, interpret=interpret, name="flash_bwd_packed")(*args)
     if has_bias:
         dq3, dk3, dv3, dbias3 = outs
         dbias = jnp.sum(dbias3.reshape(b, npg, sq), axis=1)
@@ -1236,7 +1236,7 @@ def _bwd_pallas_fused(q, k, v, bias_kv, causal, scale, interpret, o, lse,
                               dq, dk, dv, None, **kw)
     outs = pl.pallas_call(
         kernel, grid=(bh,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*args)
+        out_shape=out_shape, interpret=interpret, name="flash_bwd_fused")(*args)
     if has_bias:
         dq3, dk3, dv3, dbias3 = outs
         dbias = jnp.sum(dbias3.reshape(b, h, sk), axis=1)
@@ -1323,7 +1323,7 @@ def _bwd_pallas(q, k, v, bias_kv, causal, scale, interpret, o, lse, do,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_bwd_dkv")(*args)
     if has_bias:
         dk3, dv3, dbias3 = outs
         dbias = jnp.sum(dbias3.reshape(b, h, sk), axis=1)
@@ -1359,7 +1359,7 @@ def _bwd_pallas(q, k, v, bias_kv, causal, scale, interpret, o, lse, do,
         out_specs=pl.BlockSpec((1, bq, d), lambda bi, i, j: (bi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret)(*args)
+        interpret=interpret, name="flash_bwd_dq")(*args)
 
     return (dq3.reshape(q.shape), dk3.reshape(k.shape), dv3.reshape(v.shape),
             dbias)

@@ -92,7 +92,7 @@ def fused_adamw(param, grad, m, v, lr, beta1, beta2, eps, weight_decay,
         out_shape=[jax.ShapeDtypeStruct((rows_pad, _LANES), param.dtype),
                    jax.ShapeDtypeStruct((rows_pad, _LANES), m.dtype),
                    jax.ShapeDtypeStruct((rows_pad, _LANES), v.dtype)],
-        interpret=mode == "interpret",
+        interpret=mode == "interpret", name="fused_adam",
     )(flat(param), flat(grad), flat(m), flat(v), scalars)
 
     def unflat(t2, like):

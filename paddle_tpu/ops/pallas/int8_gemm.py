@@ -134,7 +134,7 @@ def _pallas_int8_gemm(x2, w8, scale, bias, act, interpret):
             flops=2 * mp * k * np_,
             bytes_accessed=mp * k * 4 + k * np_ + mp * np_ * 4 + np_ * 4,
             transcendentals=0),
-        interpret=interpret)(*args)
+        interpret=interpret, name="int8_gemm")(*args)
     return out[:m, :n]
 
 

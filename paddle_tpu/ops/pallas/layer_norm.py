@@ -81,7 +81,7 @@ def _ln_pallas(x2, scale, bias, eps, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
-        interpret=interpret)(*args)
+        interpret=interpret, name="layer_norm")(*args)
     return y, mean[:, 0], rstd[:, 0]
 
 

@@ -198,8 +198,8 @@ def _pallas_paged_attention(q, pool_k, pool_v, table, pos, n, hd, scale,
             flops=4 * b * n * s_tok * hd,
             bytes_accessed=2 * b * s_tok * n * hd * 4 + 2 * b * n * hd * 4,
             transcendentals=b * n * s_tok),
-        interpret=interpret)(table, pos, q.reshape(b, n, 1, hd),
-                             pool_k, pool_v)
+        interpret=interpret, name="paged_attention")(
+            table, pos, q.reshape(b, n, 1, hd), pool_k, pool_v)
     return out.reshape(b, n * hd)
 
 

@@ -204,28 +204,46 @@ def trace(log_dir: str):
 #
 # The jax profiler's device trace is what located the 183 ms attention
 # backward in round 4, so the framework exposes it as a first-class
-# tool: run a program a few steps under the trace and attribute EXCLUSIVE device time to the framework source line
-# (= the op lowering) each XLA fusion came from. Reference analog: the
+# tool: run a program a few steps under the trace and attribute EXCLUSIVE
+# device time to each XLA operation by name. Reference analog: the
 # profiler's per-op device tables + tools/timeline.py.
 
-def _device_events(log_dir: str):
+def _device_ops(log_dir: str):
+    """The device's operation events in the newest ``.xplane.pb`` under
+    ``log_dir`` (what jax.profiler writes), read with
+    ``jax.profiler.ProfileData``: on a TPU the ``XLA Ops`` line of every
+    ``/device:TPU:<n>`` plane, on the CPU the events of XLA's worker
+    threads (``tf_XLA*`` lines of ``/host:CPU``) that carry an ``hlo_op``.
+    One dict an event: ``pid`` (plane), ``tid`` (line), ``name``, ``ts``
+    and ``dur`` in microseconds, ``args`` (the event's stats)."""
     import glob
-    import gzip
-    import json as _json
+    import os
 
-    paths = sorted(glob.glob(
-        f"{log_dir}/plugins/profile/*/*.trace.json.gz"))
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb"),
+                   key=os.path.getmtime)
     if not paths:
         raise RuntimeError(
-            f"device_profile: no trace file under {log_dir} — the jax "
+            f"device_profile: no .xplane.pb under {log_dir} — the jax "
             f"profiler produced no dump (trace layout change, or "
             f"start_trace failed)")
-    doc = _json.load(gzip.open(paths[-1]))
-    ev = doc.get("traceEvents", [])
-    dev_pids = {e["pid"] for e in ev
-                if e.get("ph") == "M" and e.get("name") == "process_name"
-                and "/device:" in str(e["args"].get("name"))}
-    return [e for e in ev if e.get("ph") == "X" and e["pid"] in dev_pids]
+    out = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        on_tpu = plane.name.startswith("/device:TPU:")
+        if not on_tpu and plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            if not (line.name == "XLA Ops" if on_tpu
+                    else line.name.startswith("tf_XLA")):
+                continue
+            for ev in line.events:
+                stats = dict(ev.stats)
+                if on_tpu or "hlo_op" in stats:
+                    out.append({"pid": plane.name, "tid": line.name,
+                                "name": ev.name, "ts": ev.start_ns / 1e3,
+                                "dur": ev.duration_ns / 1e3, "args": stats})
+    return out
 
 
 def _exclusive_times(events):
@@ -265,12 +283,16 @@ def _exclusive_times(events):
 def device_profile(run_step, steps: int = 3, log_dir: Optional[str] = None):
     """Profile `run_step()` (any callable that executes one device step —
     typically a closure over Executor.run) and return rows attributing
-    exclusive device time to framework source locations.
+    exclusive device time to the framework ops it came from.
 
-    Returns {"ms_per_step": float, "rows": [(source, ms_per_step), ...]}
-    sorted by cost. Source is the op lowering's file:line carried by XLA
-    metadata; synthetic events (dispatch wrappers) aggregate under their
-    event name."""
+    Returns {"ms_per_step": float, "rows": [(op, ms_per_step), ...]}
+    sorted by cost. A row is keyed by the event's own name, the HLO
+    instruction with its number folded: ``dot_general``, ``fusion``, and a
+    Mosaic kernel under its ``name=`` (``paged_attention``,
+    ``flash_fwd_packed``). The scope path ``run_op``'s ``jax.named_scope``
+    gives each operation (``jit(train_step)/mul/dot_general``) is in the
+    HLO's ``op_name`` metadata, where ``compiled.as_text()`` shows it, and
+    in no stat of jax 0.9.0's plane events, on the CPU or on a v5e."""
     import re
     import shutil
     import tempfile
@@ -283,23 +305,19 @@ def device_profile(run_step, steps: int = 3, log_dir: Optional[str] = None):
         with trace(log_dir):
             for _ in range(steps):
                 run_step()
-        events = _device_events(log_dir)
+        events = _device_ops(log_dir)
     finally:
         if cleanup:
             shutil.rmtree(log_dir, ignore_errors=True)
     excl = _exclusive_times(events)
-    by_src = _c.defaultdict(float)
+    by_op = _c.defaultdict(float)
     total = 0.0
     for e in events:
-        a = e.get("args") or {}
-        name = a.get("long_name") or e.get("name", "")
-        if name.startswith("jit_") or re.fullmatch(r"\d+",
-                                                   e.get("name", "")):
-            continue  # whole-module / step envelope events
-        d = excl.get(id(e), e.get("dur", 0))
-        src = a.get("source") or e.get("name", "?")[:60]
-        by_src[src] += d
+        d = excl.get(id(e), e["dur"])
+        # on a TPU the name is the instruction's text: `%fusion.4 = f32[..`
+        head = e["name"].split(" = ", 1)[0].lstrip("%")
+        by_op[re.sub(r"(\.\d+)+$", "", head)] += d
         total += d
-    rows = sorted(((k, v / 1e3 / steps) for k, v in by_src.items()),
+    rows = sorted(((k, v / 1e3 / steps) for k, v in by_op.items()),
                   key=lambda kv: -kv[1])
     return {"ms_per_step": total / 1e3 / steps, "rows": rows}

@@ -55,7 +55,13 @@ kv_refusals / kv_pages_allocated / kv_pages_freed counters,
 decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
 histogram, decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
-section and /v1/stats.
+section and /v1/stats. Every loop iteration that runs a step also records
+its phases, which add up to it: decode.loop_ms = decode.admit_ms +
+decode.feed_ms + decode.step_ms (of which decode.fetch_ms is the logits'
+device-to-host copy) + decode.sample_ms + decode.retire_ms +
+decode.other_ms; per token decode.token_gap_ms, per request
+decode.queue_wait_ms (submit to the start of its prefill). Each timer is
+also a ``TraceAnnotation`` of the same name in a running profiler trace.
 """
 
 from __future__ import annotations
@@ -80,6 +86,15 @@ from .admission import (AdmissionQueue, DeadlineExceededError,
 from .health import DRAINING, READY, STOPPED, HealthState
 from .kv_cache import KVPagePool
 from .prefix_store import PrefixStore
+
+
+# the phases that tile one iteration of DecodeEngine._loop, with
+# decode.other_ms for what lies between them (decode.fetch_ms is a child of
+# decode.step_ms, decode.prefill_ms one of decode.admit_ms; the spans of
+# decode.retire_ms lie inside decode.sample_ms's, whose histogram holds the
+# sampling without them)
+_LOOP_PHASES = ("decode.admit_ms", "decode.feed_ms", "decode.step_ms",
+                "decode.sample_ms", "decode.retire_ms")
 
 
 def _pow2_ladder(lo: int, hi: int) -> List[int]:
@@ -547,6 +562,12 @@ class DecodeEngine:
             run_block(block, env)
             return env["logits"], {n: env[n + "_out"] for n in pool_names}
 
+        # the program's own name in the profiler's trace and in the compile
+        # cache's key: decode_step_b8, prefill_p256, chunk_p128
+        fn.__name__ = fn.__qualname__ = {
+            "step": "decode_step_b", "prefill": "prefill_p",
+            "chunk": "chunk_p"}[phase] + str(bucket)
+
         from ..ops import pallas as _pallas
 
         entry = jax.jit(fn, donate_argnums=(1,))
@@ -608,24 +629,41 @@ class DecodeEngine:
                     if self.queue.closed:
                         return
                     continue
-            try:
-                self._admit()
-                if self._active:
-                    self._run_step()
-                    self._journal_tick()
-            except BaseException as e:   # the loop must outlive any step
-                telemetry.counter_add("decode.errors",
-                                      max(1, len(self._active)),
-                                      exc=type(e).__name__)
-                err = e if isinstance(e, ServingError) else ServingError(
-                    f"decode step failed: {e!r}")
-                for req in self._active:
-                    self._retire(req, error=err)
-                self._active = []
-            telemetry.gauge_set("decode.active_slots", len(self._active))
-            # SLO watchdog hook (core/incidents.py): queue saturation /
-            # step-time regression rules evaluate on the step cadence
-            incidents.tick()
+            # this iteration's phase times in ms, by histogram name; they
+            # reach the histograms only if the iteration ran a step
+            it: Dict[str, float] = {}
+            with telemetry.timer("decode.loop_ms", into=it):
+                try:
+                    with telemetry.timer("decode.admit_ms", into=it):
+                        self._admit()
+                    if self._active:
+                        self._run_step(it)
+                        self._journal_tick()
+                except BaseException as e:   # the loop must outlive any step
+                    telemetry.counter_add("decode.errors",
+                                          max(1, len(self._active)),
+                                          exc=type(e).__name__)
+                    err = e if isinstance(e, ServingError) else ServingError(
+                        f"decode step failed: {e!r}")
+                    for req in self._active:
+                        self._retire(req, error=err)
+                    self._active = []
+                telemetry.gauge_set("decode.active_slots", len(self._active))
+                # SLO watchdog hook (core/incidents.py): queue saturation /
+                # step-time regression rules evaluate on the step cadence
+                incidents.tick()
+            if "decode.step_ms" in it:
+                # what the iteration spent outside its phases: the deadline
+                # scan, the journal tick, the gauge, the watchdog, the timers
+                it["decode.other_ms"] = it["decode.loop_ms"] - sum(
+                    it.get(name, 0.0) for name in _LOOP_PHASES)
+                # decode.step_ms stays in the run log as it was; the new
+                # phases are histograms only (eight more records a step
+                # would crowd the log and the flight recorder out)
+                telemetry.observe("decode.step_ms", it.pop("decode.step_ms"),
+                                  kind="timer")
+                for name, ms in it.items():
+                    telemetry.observe_quiet(name, ms)
 
     def _admit(self):
         """Seat queued requests into free slots at the step boundary.
@@ -684,6 +722,8 @@ class DecodeEngine:
                     self.prefix_store.release(hashes)
                 unseated.append(req)   # no headroom NOW — wait for frees
                 continue
+            telemetry.observe("decode.queue_wait_ms",
+                              (time.monotonic() - req.t_submit) * 1e3)
             try:
                 self._prefill(req, pages, hashes, shared)
             except BaseException as e:
@@ -894,6 +934,8 @@ class DecodeEngine:
             req.table_row = row
             telemetry.counter_add("disagg.installs", 1)
             telemetry.counter_add("decode.prefills", 1)
+            telemetry.observe("decode.queue_wait_ms",
+                              (time.monotonic() - req.t_submit) * 1e3)
             self._append_token(req, np.asarray(ship["logits"]))
             req.pos_next = L
             if req.finished():
@@ -908,9 +950,10 @@ class DecodeEngine:
                                   exc=type(e).__name__)
             return False
 
-    def _run_step(self):
+    def _run_step(self, it: Dict[str, float]):
         """DECODE phase: one fixed-shape step over the padded slot
-        array; per-request deadlines checked here, at step granularity."""
+        array; per-request deadlines checked here, at step granularity.
+        The phases' times go into ``it`` (see ``_loop``)."""
         import jax.numpy as jnp
 
         delay_ms = float(_flag("decode_step_delay_ms"))
@@ -929,32 +972,42 @@ class DecodeEngine:
         active = self._active
         bucket = self.config.bucket(len(active))
         faults.maybe_fail("decode.step", active=len(active), bucket=bucket)
-        tokens = np.zeros(bucket, np.int32)
-        positions = np.zeros(bucket, np.int32)
-        table = np.zeros((bucket, self._mp), np.int32)
-        for i, req in enumerate(active):
-            tokens[i] = req.last_token
-            positions[i] = req.pos_next
-            table[i] = req.table_row
-        feed = {"tokens": jnp.asarray(tokens),
-                "positions": jnp.asarray(positions),
-                "page_table": jnp.asarray(table)}
+        with telemetry.timer("decode.feed_ms", into=it):
+            tokens = np.zeros(bucket, np.int32)
+            positions = np.zeros(bucket, np.int32)
+            table = np.zeros((bucket, self._mp), np.int32)
+            for i, req in enumerate(active):
+                tokens[i] = req.last_token
+                positions[i] = req.pos_next
+                table[i] = req.table_row
+            feed = {"tokens": jnp.asarray(tokens),
+                    "positions": jnp.asarray(positions),
+                    "page_table": jnp.asarray(table)}
         entry = self._entry("step", bucket)
-        with telemetry.timer("decode.step_ms"):
+        with telemetry.timer("decode.step_ms", into=it):
             logits, self._pools = entry(self._params, self._pools, feed)
-            logits = np.asarray(logits)
+            with telemetry.timer("decode.fetch_ms", into=it):
+                logits = np.asarray(logits)
         telemetry.counter_add("decode.steps", 1)
         telemetry.counter_add("decode.tokens", len(active))
         telemetry.observe("decode.batch_occupancy", len(active) / bucket)
-        still = []
-        for i, req in enumerate(active):
-            self._append_token(req, logits[i])
-            req.pos_next += 1
-            if req.finished():
-                self._retire(req)
-            else:
-                still.append(req)
-        self._active = still
+        # one span for the step's sampling. A request that finishes is
+        # retired at once, in a child span: its caller is answered, and
+        # sends its next request, while numpy samples the other rows
+        with telemetry.timer("decode.sample_ms", into=it):
+            still = []
+            for i, req in enumerate(active):
+                self._append_token(req, logits[i])
+                req.pos_next += 1
+                if req.finished():
+                    with telemetry.timer("decode.retire_ms", into=it):
+                        self._retire(req)
+                else:
+                    still.append(req)
+            self._active = still
+        # the histograms hold disjoint phases: sampling less the retiring
+        it.setdefault("decode.retire_ms", 0.0)
+        it["decode.sample_ms"] -= it["decode.retire_ms"]
 
     def _journal_tick(self):
         """Replicate session snapshots to the router at step-boundary
@@ -988,6 +1041,9 @@ class DecodeEngine:
         now = time.monotonic()
         if req.t_first is None:
             req.t_first = now
+        else:
+            telemetry.observe_quiet("decode.token_gap_ms",
+                                    (now - req.token_walls[-1]) * 1e3)
         req.tokens.append(tok)
         req.token_walls.append(now)
         req.last_token = tok

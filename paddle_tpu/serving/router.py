@@ -824,6 +824,8 @@ class Router:
                     payload.get("tokens", []))
                 payload["num_tokens"] = len(payload["tokens"])
                 payload["resumed"] = True
+                # the dead replica's token times died with it
+                payload.pop("token_ms", None)
             if failed_over:
                 payload["failed_over"] = True
             payload.setdefault("request_id", rid)

