@@ -39,9 +39,18 @@ caching, on the repo's frozen-program stack:
   mid-flight with ``DeadlineExceededError`` and frees its pages without
   draining the batch.
 
-Sampling happens host-side per row (greedy argmax, or temperature
-sampling driven by a per-request pinned ``np.random.RandomState``), so
-token selection is a pure function of the row's logits bits and the
+Who samples what: the decode-step program ends in ``sampling.
+sample_tokens`` and returns ``[slots]`` int32 — every token after a
+request's first is chosen on the device (greedy argmax, or softmax at the
+row's temperature and the inverse CDF at the row's uniform), and the
+``[slots, vocab]`` logits never cross to the host. The uniform is drawn on
+the host while the step's feed is built, one per token in token order,
+from the request's own pinned ``np.random.RandomState``, so the random
+stream, the journal's ``rng_state`` and the seed contract are the host's
+as before. A request's FIRST token is still chosen on the host
+(``GenerationRequest.sample``) from the logits row that the prefill,
+chunked-prefill and shipped-prefill paths hand over. Either way token
+selection is a function of the row's own logits, temperature and the
 request's own seed — scheduling cannot perturb it.
 
 Fault sites (core/faults.py, tools/chaos_check.py --decode):
@@ -57,8 +66,9 @@ histogram, decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
 section and /v1/stats. Every loop iteration that runs a step also records
 its phases, which add up to it: decode.loop_ms = decode.admit_ms +
-decode.feed_ms + decode.step_ms (of which decode.fetch_ms is the logits'
-device-to-host copy) + decode.sample_ms + decode.retire_ms +
+decode.feed_ms + decode.step_ms (of which decode.fetch_ms is the chosen
+tokens' fetch: the host waits out the step program there) +
+decode.sample_ms (accepting the tokens row by row) + decode.retire_ms +
 decode.other_ms; per token decode.token_gap_ms, per request
 decode.queue_wait_ms (submit to the start of its prefill). Each timer is
 also a ``TraceAnnotation`` of the same name in a running profiler trace.
@@ -92,7 +102,7 @@ from .prefix_store import PrefixStore
 # decode.other_ms for what lies between them (decode.fetch_ms is a child of
 # decode.step_ms, decode.prefill_ms one of decode.admit_ms; the spans of
 # decode.retire_ms lie inside decode.sample_ms's, whose histogram holds the
-# sampling without them)
+# accepting of the step's tokens without them)
 _LOOP_PHASES = ("decode.admit_ms", "decode.feed_ms", "decode.step_ms",
                 "decode.sample_ms", "decode.retire_ms")
 
@@ -247,11 +257,14 @@ class GenerationRequest(InferenceRequest):
         return (self.t_first - self.t_submit) * 1e3
 
     def sample(self, logits_row: np.ndarray) -> int:
-        """Host-side token choice — a pure function of the row's logits
-        bits and this request's own RNG stream, so batching/scheduling
-        cannot perturb it. Greedy when temperature <= 0 (argmax, lowest
-        index on ties); else softmax-at-temperature inverse-CDF driven
-        by the pinned per-request RandomState."""
+        """Host-side choice of this request's FIRST token, from the logits
+        row a prefill (local, chunked or shipped) hands over; every later
+        token is chosen inside the step program by
+        ``sampling.sample_tokens``, which states the same mathematics in
+        float32. Greedy when temperature <= 0 (argmax, lowest index on
+        ties); else softmax-at-temperature inverse-CDF driven by the
+        pinned per-request RandomState, whose next draws ``_run_step``
+        feeds to the device one per token."""
         if self.temperature <= 0.0:
             return int(np.argmax(logits_row))
         if self._rng is None:
@@ -530,10 +543,12 @@ class DecodeEngine:
 
     # -- program compilation -------------------------------------------------
     def _entry(self, phase: str, bucket: int):
-        """One jitted (params, pools, feed) -> (logits, new_pools) entry
+        """One jitted (params, pools, feed) -> (out, new_pools) entry
         per (phase, bucket), pools donated so XLA updates the KV arrays
-        in place; compile wall time + XLA cost capture accounted like
-        the predictor's cache."""
+        in place; ``out`` is the chosen tokens, int32 [bucket], for the
+        step (its logits stay on the device) and the first-token logits
+        row for the prefills. Compile wall time + XLA cost capture
+        accounted like the predictor's cache."""
         key = (phase, bucket)
         entry = self._entries.get(key)
         if entry is not None:
@@ -541,6 +556,7 @@ class DecodeEngine:
         import jax
 
         from ..core.executor import run_block
+        from .sampling import sample_tokens
 
         cfg, cc = self.model_cfg, self.config
         if phase == "step":
@@ -560,7 +576,11 @@ class DecodeEngine:
             env.update(pools)
             env.update(feed)
             run_block(block, env)
-            return env["logits"], {n: env[n + "_out"] for n in pool_names}
+            out = env["logits"]
+            if phase == "step":
+                out = sample_tokens(out, feed["sampling"][:, 0],
+                                    feed["sampling"][:, 1])
+            return out, {n: env[n + "_out"] for n in pool_names}
 
         # the program's own name in the profiler's trace and in the compile
         # cache's key: decode_step_b8, prefill_p256, chunk_p128
@@ -605,7 +625,8 @@ class DecodeEngine:
         if phase == "step":
             return {"tokens": jnp.zeros((bucket,), jnp.int32),
                     "positions": jnp.zeros((bucket,), jnp.int32),
-                    "page_table": jnp.zeros((bucket, self._mp), jnp.int32)}
+                    "page_table": jnp.zeros((bucket, self._mp), jnp.int32),
+                    "sampling": jnp.zeros((bucket, 2), jnp.float32)}
         oh = np.zeros((1, bucket), np.float32)
         oh[0, 0] = 1.0
         if phase == "chunk":
@@ -772,7 +793,7 @@ class DecodeEngine:
             logits = np.asarray(logits)
         telemetry.counter_add("decode.prefills", 1)
         telemetry.counter_add("decode.prefill_tokens", L)
-        self._append_token(req, logits[0])
+        self._first_token(req, logits[0])
         req.pos_next = L
         if req.finished():
             self._retire(req)
@@ -835,7 +856,7 @@ class DecodeEngine:
             row[k:n_full] = canon
             req.shared_blocks.extend(held)
             req.pages = pages[n_full - k:]
-        self._append_token(req, logits[0])
+        self._first_token(req, logits[0])
         req.pos_next = L
         if req.finished():
             self._retire(req)
@@ -936,7 +957,7 @@ class DecodeEngine:
             telemetry.counter_add("decode.prefills", 1)
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
-            self._append_token(req, np.asarray(ship["logits"]))
+            self._first_token(req, np.asarray(ship["logits"]))
             req.pos_next = L
             if req.finished():
                 self._retire(req)
@@ -976,28 +997,37 @@ class DecodeEngine:
             tokens = np.zeros(bucket, np.int32)
             positions = np.zeros(bucket, np.int32)
             table = np.zeros((bucket, self._mp), np.int32)
+            # a row's (temperature, uniform) for the program's sampler: the
+            # request's own stream gives one draw per sampled token, here,
+            # in token order (a first token's draw came before, on the host)
+            sampling = np.zeros((bucket, 2), np.float32)
             for i, req in enumerate(active):
                 tokens[i] = req.last_token
                 positions[i] = req.pos_next
                 table[i] = req.table_row
+                if req.temperature > 0.0:
+                    sampling[i] = (req.temperature,
+                                   req._rng.random_sample())
             feed = {"tokens": jnp.asarray(tokens),
                     "positions": jnp.asarray(positions),
-                    "page_table": jnp.asarray(table)}
+                    "page_table": jnp.asarray(table),
+                    "sampling": jnp.asarray(sampling)}
         entry = self._entry("step", bucket)
         with telemetry.timer("decode.step_ms", into=it):
-            logits, self._pools = entry(self._params, self._pools, feed)
+            chosen, self._pools = entry(self._params, self._pools, feed)
+            # [bucket] int32; the host waits out the step program here
             with telemetry.timer("decode.fetch_ms", into=it):
-                logits = np.asarray(logits)
+                chosen = np.asarray(chosen)
         telemetry.counter_add("decode.steps", 1)
         telemetry.counter_add("decode.tokens", len(active))
+        telemetry.counter_add("decode.tokens_device_sampled", len(active))
         telemetry.observe("decode.batch_occupancy", len(active) / bucket)
-        # one span for the step's sampling. A request that finishes is
-        # retired at once, in a child span: its caller is answered, and
-        # sends its next request, while numpy samples the other rows
+        # one span for accepting the step's tokens. A request that finishes
+        # is retired at once, in a child span
         with telemetry.timer("decode.sample_ms", into=it):
             still = []
             for i, req in enumerate(active):
-                self._append_token(req, logits[i])
+                self._accept_token(req, int(chosen[i]))
                 req.pos_next += 1
                 if req.finished():
                     with telemetry.timer("decode.retire_ms", into=it):
@@ -1005,7 +1035,7 @@ class DecodeEngine:
                 else:
                     still.append(req)
             self._active = still
-        # the histograms hold disjoint phases: sampling less the retiring
+        # the histograms hold disjoint phases: accepting less the retiring
         it.setdefault("decode.retire_ms", 0.0)
         it["decode.sample_ms"] -= it["decode.retire_ms"]
 
@@ -1036,8 +1066,14 @@ class DecodeEngine:
             telemetry.counter_add("session.journal_errors", 1,
                                   exc=type(e).__name__)
 
-    def _append_token(self, req: GenerationRequest, logits_row: np.ndarray):
+    def _first_token(self, req: GenerationRequest, logits_row: np.ndarray):
+        """A request's first token, chosen on the host from the logits row
+        its prefill handed over."""
         tok = req.sample(logits_row)
+        telemetry.counter_add("decode.tokens_host_sampled", 1)
+        self._accept_token(req, tok)
+
+    def _accept_token(self, req: GenerationRequest, tok: int):
         now = time.monotonic()
         if req.t_first is None:
             req.t_first = now

@@ -3,8 +3,12 @@ death of the replica running it.
 
 A decode replica is pure state: the KV pages are rebuildable from the
 token ids (chunked prefill is bitwise-identical to the cold run by
-construction — serving/decode.py), and sampling is a pure function of
-(logits bits, per-request RandomState). So the ONLY durable facts a
+construction — serving/decode.py), and sampling is a function of the
+row's logits and the request's own RandomState, whose uniforms are drawn on
+the host one per token (the survivor chooses its first token on the host in
+float64 where the dead replica's step program would have chosen it in
+float32: the same softmax at the same uniform, so the same token unless the
+uniform lies within ~1e-7 of a CDF boundary). So the ONLY durable facts a
 generation owns are tiny and host-side: the prompt, the accepted token
 ids, the sampler RNG state after those draws, and the deadline
 remainder. This module is that record plus the router-side store it

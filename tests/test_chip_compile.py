@@ -134,6 +134,23 @@ def test_fused_adamw(one_chip, tpu_mode):
         w, w, w, w, ((), F32), ((), F32), ((), F32))
 
 
+def test_step_sampler_at_the_xglm_vocabulary(one_chip):
+    """The decode step's last stage (serving/sampling.py) over the b8 x
+    256,008 logits of the serving cell: plain XLA, no temporaries beyond
+    the logits' own size, tokens out."""
+    from paddle_tpu.serving.sampling import sample_tokens
+
+    b, v = 8, 256008
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((b, v), (b,), (b,))]
+    compiled = jax.jit(sample_tokens).lower(*args).compile()
+    assert compiled.out_info.shape == (b,)
+    assert compiled.out_info.dtype == I32
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 2 * b * v * 4
+    assert "f64" not in compiled.as_text()
+
+
 def test_sharded_step_compiles_for_four_chips(topo, tpu_mode):
     """The step jit partitions over a dp=2 x mp=2 mesh of the described
     chips: Mosaic kernels cannot be partitioned automatically, so the

@@ -5,7 +5,7 @@ Contracts under test:
 * continuous-batched generation is BITWISE-identical to sequential
   one-request-at-a-time decode — greedy and temperature-sampled with
   pinned per-request RNG — because the step program runs at fixed
-  slot-array shapes and sampling is host-side per row;
+  slot-array shapes and chooses each row's token from that row alone;
 * the KV page pool's alloc/free accounting is exact under admit/retire
   churn (no double allocation, no leak, high-water tracked) and returns
   to baseline after every request resolves;
@@ -106,7 +106,8 @@ class TestBitwiseIdentity:
 
     def test_sampled_pinned_rng_equals_sequential(self, engines, workload):
         """Temperature sampling with per-request seeds: token choice is
-        a host-side pure function of (logits bits, own RNG stream), so
+        a function of the row's own logits, temperature and the request's
+        own RNG stream (drawn on the host, one uniform a token), so
         scheduling must not perturb it either."""
         cont, seq = engines
         prompts, max_news = workload
