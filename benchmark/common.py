@@ -1,4 +1,5 @@
-"""Pieces every runner shares: device facts, compile watching, percentiles.
+"""Pieces every runner shares: device facts, compile watching, percentiles,
+and the one HTTP call a serving run and a family's check both make.
 
 `CacheWatch` and `peak_hbm` are copies of chip_smoke.py's (checked on the
 chip in PR 21); the yardstick may not move with the program, so they live
@@ -9,6 +10,8 @@ from __future__ import annotations
 
 import json
 import time
+import urllib.error
+import urllib.request
 
 
 _T0 = time.perf_counter()     # run.py imports this module first of all
@@ -23,6 +26,20 @@ def log(phase: str, **fields):
     """A free-form progress line on stdout (never the last one)."""
     print(json.dumps({"phase": phase, "t": round(since_start(), 2),
                       **fields}), flush=True)
+
+
+def post(url: str, doc: dict, timeout: float = 600.0):
+    """-> (status, body dict); a refusal's status and body, not a raise."""
+    req = urllib.request.Request(
+        url, data=json.dumps(doc).encode(),
+        headers={"Content-Type": "application/json"})
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, {"error": e.read().decode(errors="replace")[:200]}
+    except OSError as e:
+        return 0, {"error": repr(e)[:200]}
 
 
 def device_info() -> dict:

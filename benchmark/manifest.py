@@ -6,13 +6,17 @@ is a file of its own, found by the name in BENCHMARK.json:
   configuration  the `file` its entry gives (benchmark/configs/<name>.json)
   traffic mix    <data>/traffic/<traffic>.json, read by the generator it names
   metric         <data>/readers/<metric>.py with a `read(ctx)`
+  family         <data>/families/<family>.py with FAMILY_FUNCTIONS: what a
+                 serving configuration's `family` key names
 
-`<data>` is the first directory of `paths`. A later PR adds files and
+`<data>` is the first directory of `paths`. A configuration's `kind` picks
+the runner, `runners/<kind>.py` beside this file. A later PR adds files and
 entries and edits nothing here.
 """
 
 from __future__ import annotations
 
+import ast
 import importlib.util
 import json
 import os
@@ -24,6 +28,10 @@ UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer"}
+RUNNERS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "runners")
+FAMILY_FUNCTIONS = ("model_config", "make_params", "make_engine", "slots",
+                    "traffic_vocab", "check_correct", "step_bytes")
 
 
 class ManifestError(ValueError):
@@ -61,12 +69,14 @@ class Manifest:
         return os.path.join(self.data_dir, "readers", metric + ".py")
 
     def reader(self, metric: str):
-        path = self.reader_path(metric)
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_reader_" + re.sub(r"\W", "_", metric), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load("reader", metric, self.reader_path(metric)).read
+
+    def family_path(self, family: str) -> str:
+        return os.path.join(self.data_dir, "families", family + ".py")
+
+    def family(self, family: str):
+        """The module of a served model's family (FAMILY_FUNCTIONS)."""
+        return _load("family", family, self.family_path(family))
 
     def metrics_of(self, cell: str, group: str) -> List[dict]:
         """The `end_to_end` or `per_layer` metrics this cell reports."""
@@ -151,7 +161,45 @@ class Manifest:
                 out.append(f"config {c['name']}: file outside paths")
             out += [f"config {c['name']}: bad reduced key {k!r}"
                     for k in c["reduced"] if not NAME_RE.match(k)]
+            if os.path.isfile(os.path.join(self.root, c["file"])):
+                out += self._runner_problems(c["name"])
         four = sum(1 for w in d["workloads"] if w["chips"] == 4)
         if four > max(1, len(d["workloads"]) // 4):
             out.append(f"{four} four-chip cells of {len(d['workloads'])}")
         return out
+
+    def _runner_problems(self, config: str) -> List[str]:
+        """A configuration's `kind` needs its runner file and, served, its
+        family file with every function the runner calls: found here, not
+        when a chip run dies."""
+        doc = self.config_doc(config)
+        kind = str(doc.get("kind"))
+        if not (NAME_RE.match(kind) and os.path.isfile(
+                os.path.join(RUNNERS_DIR, kind + ".py"))):
+            return [f"config {config}: no runner file runners/{kind}.py "
+                    f"for kind {kind!r}"]
+        if kind != "serve":
+            return []
+        family = doc.get("family")
+        if not (isinstance(family, str) and NAME_RE.match(family)):
+            return [f"config {config}: a served configuration names its "
+                    f"`family`, this one has {family!r}"]
+        path = self.family_path(family)
+        if not os.path.isfile(path):
+            return [f"config {config}: no family file "
+                    f"families/{family}.py"]
+        with open(path) as f:
+            defined = {node.name for node in ast.parse(f.read()).body
+                       if isinstance(node, ast.FunctionDef)}
+        return [f"config {config}: family {family} lacks {fn}()"
+                for fn in FAMILY_FUNCTIONS if fn not in defined]
+
+
+def _load(what: str, name: str, path: str):
+    """A reader's or a family's module, by its file: the file may lie in
+    any root, so it is no member of a package."""
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{what}_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

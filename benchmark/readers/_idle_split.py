@@ -1,9 +1,11 @@
 """The device's idle time in a traced window, split by the program's own
 spans: every instant in which no operation runs on the device goes to the
 innermost span the program had open then (its `telemetry.timer`s, which are
-`TraceAnnotation`s of the same name), or to no span at all. Not the
-winner-takes-the-gap rule of `trace_reduce.attribute`: one gap of a decode
-step runs across fetch, sample, retire, admit and feed.
+`TraceAnnotation`s of the same name), or to no span at all: one gap of a
+decode step runs across fetch, sample, retire, admit and feed. The rule is
+`trace_reduce.innermost`'s, as for the breakdown's `idle_gaps`; here the
+benchmark's own `bench.` spans are left out, so that what a program span
+does not cover is under no span.
 
 `ctx` carries neither the cell's name nor the trace's path, so the run's
 `.xplane.pb` is looked for: newest first under `run.WORK_DIR/*/trace`, the
@@ -16,45 +18,14 @@ from __future__ import annotations
 import functools
 import glob
 import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 from benchmark import trace_reduce
 from benchmark.common import log
+from benchmark.trace_reduce import innermost, split
 
 PROGRAM_PREFIXES = ("decode.", "executor.")
 NO_SPAN = "no program span"
-Segment = Tuple[int, int, Optional[str]]      # start_ns, end_ns, span name
-
-
-def innermost(spans: Sequence[trace_reduce.Event], lo: int,
-              hi: int) -> List[Segment]:
-    """[lo, hi] cut at every span edge; each piece named for the open span
-    that started last (of two that started together, the one that ends
-    first), or None where no span is open."""
-    cuts = sorted({lo, hi} | {t for _, s, e in spans for t in (s, e)
-                              if lo < t < hi})
-    out = []
-    for a, b in zip(cuts, cuts[1:]):
-        open_now = [(s, -e, name) for name, s, e in spans
-                    if s <= a and e >= b]
-        out.append((a, b, max(open_now)[2] if open_now else None))
-    return out
-
-
-def split(idle: Sequence[Tuple[int, int]],
-          segments: Sequence[Segment]) -> Dict[Optional[str], int]:
-    """ns of the sorted, disjoint `idle` intervals under each segment name."""
-    sums: Dict[Optional[str], int] = {}
-    i = 0
-    for a, b, name in segments:
-        while i < len(idle) and idle[i][1] <= a:
-            i += 1
-        j = i
-        while j < len(idle) and idle[j][0] < b:   # one that crosses b stays
-            sums[name] = (sums.get(name, 0)
-                          + min(idle[j][1], b) - max(idle[j][0], a))
-            j += 1
-    return sums
 
 
 def idle_shares(device: Dict[str, List[trace_reduce.Event]],
@@ -99,8 +70,7 @@ def _shares_of_run(platform: str, window_s: float):
     for path in sorted(paths, key=os.path.getmtime, reverse=True):
         try:
             device, _, spans = trace_reduce.read_events(
-                path, span_prefix=(trace_reduce.SPAN_PREFIX,)
-                + PROGRAM_PREFIXES, **_planes(platform))
+                path, **_planes(platform))
         except Exception:          # another worker's file, half written
             continue
         windows = [(s, e) for name, s, e in spans

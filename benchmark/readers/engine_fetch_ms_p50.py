@@ -1,6 +1,7 @@
-"""The logits' device-to-host copy alone (`decode.fetch_ms`, the closing part
-of `decode.step_ms`): it waits for the step program, then moves the rows.
-Median over the window."""
+"""The fetch of the step's chosen tokens alone (`decode.fetch_ms`, the closing
+part of `decode.step_ms`): `[slots]` int32, 32 bytes at 8 slots, so it is
+where the host waits out the step program, sampler included. Median over
+the window."""
 
 from benchmark.readers._telemetry import hist
 

@@ -1,6 +1,6 @@
 """Share of the traced window in which no operation runs on the device and
 `decode.sample_ms` is the innermost span the engine has open: the device
-waiting for the host to sample the step's tokens."""
+waiting while the host accepts the step's tokens."""
 
 from benchmark.readers._idle_split import share
 

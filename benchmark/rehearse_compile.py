@@ -6,8 +6,9 @@
 
 Run by hand before the first chip call of a cell: what the chip's compiler
 refuses (a kernel, a program that does not fit) it refuses here, at no chip
-time. For a serving configuration it compiles, at the real sizes, the decode
-step and every prefill bucket as `DecodeEngine._entry` builds them; for a
+time. For a serving configuration of the `decoder_lm` family it compiles, at
+the real sizes, the decode step (ending in the sampler, fed `sampling`) and
+every prefill bucket as `DecodeEngine._entry` builds them; for a
 training configuration the Executor's step under a traffic file's batch, on
 one described chip or on the configuration's mesh over four. It prints each
 program's `memory_analysis()`. Nothing runs: a compile that passes is a
@@ -115,8 +116,9 @@ def main(argv=None) -> int:
     import paddle_tpu.ops.pallas as pallas
     from paddle_tpu.core.executor import run_block
     from paddle_tpu.models import decoder_lm as dl
+    from paddle_tpu.serving.sampling import sample_tokens
 
-    from .runners import serve
+    from .families import decoder_lm as family
 
     with open(os.path.join(CHECKOUT, "benchmark", "configs",
                            name + ".json")) as f:
@@ -131,13 +133,13 @@ def main(argv=None) -> int:
         rehearse_train(config, traffic, int(args[2]) if len(args) > 2 else 1,
                        topo)
         return 0
-    cfg, eng = serve.model_config(config), config["engine"]
+    cfg, eng = family.model_config(config), config["engine"]
     chip = SingleDeviceSharding(topo.devices[0])
 
     def shape(s, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(tuple(s), dtype, sharding=chip)
 
-    params = {n: shape(s) for n, (s, _) in serve.param_specs(cfg).items()}
+    params = {n: shape(s) for n, (s, _) in family.param_specs(cfg).items()}
     params["lm_pos_enc"] = shape((cfg.max_seq_len, cfg.d_model))
     pool = (config["kv_pages"], eng["page_size"], cfg.d_model)
     pools = {f"kv_{kv}_{i}": shape(pool)
@@ -152,7 +154,8 @@ def main(argv=None) -> int:
                 eng["weight_quant"])
             feed = {"tokens": shape((bucket,), jnp.int32),
                     "positions": shape((bucket,), jnp.int32),
-                    "page_table": shape((bucket, mp), jnp.int32)}
+                    "page_table": shape((bucket, mp), jnp.int32),
+                    "sampling": shape((bucket, 2))}
         else:
             program, _, _ = dl.build_prefill_program(
                 cfg, 1, bucket, config["kv_pages"], eng["page_size"],
@@ -163,10 +166,14 @@ def main(argv=None) -> int:
                     "page_table": shape((1, mp), jnp.int32)}
         block = program.global_block()
 
-        def fn(params, pools, feed, block=block):
+        def fn(params, pools, feed, block=block, phase=phase):
             env = {**params, **pools, **feed}
             run_block(block, env)
-            return env["logits"], {n: env[n + "_out"] for n in sorted(pools)}
+            out = env["logits"]
+            if phase == "step":       # [slots] int32 leave the device
+                out = sample_tokens(out, feed["sampling"][:, 0],
+                                    feed["sampling"][:, 1])
+            return out, {n: env[n + "_out"] for n in sorted(pools)}
 
         t0 = time.perf_counter()
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(

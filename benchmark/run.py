@@ -26,9 +26,15 @@ NO_DEVICE_EXIT = 4
 
 def _prepare_environment():
     """Before JAX is imported: the compile cache at a fixed path inside the
-    checkout unless the machine names one, and every program in it."""
+    checkout unless the machine names one, every program in it, and none
+    thrown out of it. A size cap under one cell's programs makes JAX's LRU
+    eviction drop each program shortly before the next run asks for it: at
+    a machine's 192 MiB the four-chip cell missed all 87 programs in each of
+    three runs of one checkout, 630 s of set-up every time (my chip runs,
+    PR 27)."""
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           os.path.join(CHECKOUT, ".jax_cache"))
+    os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -70,7 +76,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     job = SimpleNamespace(
         cell=cell, config=config, traffic=traffic, seed=seed,
         seconds=float(seconds), trace=bool(trace), chips=cell["chips"],
-        clock=since_start, work_dir=work_dir, platform=device["platform"], watch=CompileWatch(), peaks=peaks)
+        clock=since_start, work_dir=work_dir, platform=device["platform"],
+        watch=CompileWatch(), peaks=peaks, manifest=man)
     ctx = _on_a_fresh_stack(runners.load(config["kind"]).run, job)
     ctx.config, ctx.traffic, ctx.peaks = config, traffic, peaks
     ctx.chips, ctx.device, ctx.cache = cell["chips"], device, job.watch.snapshot()
@@ -78,6 +85,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         **ctx.cache)
     for note in ctx.notes:
         log("incorrect", why=note)
+    if ctx.trace:
+        log("trace.op_seconds", **ctx.trace["op_seconds"])
 
     group = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -94,7 +103,18 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         out["device"]["window_s"] = ctx.trace["window_s"]
         out["breakdown"] = {"device_ops": ctx.trace["device_ops"],
                             "idle_gaps": ctx.trace["idle_gaps"]}
+    _say_what_was_compared(ctx)
     return out
+
+
+def _say_what_was_compared(ctx):
+    """The last lines of stderr: each number the check compared beside its
+    limit, then why the run is not correct, if it is not."""
+    for name, value, limit in ctx.compared:
+        print(f"compared {name} = {value} (limit {limit})", file=sys.stderr)
+    for note in ctx.notes:
+        print(f"incorrect: {note}", file=sys.stderr)
+    sys.stderr.flush()
 
 
 def _on_a_fresh_stack(fn, *args):

@@ -1,11 +1,16 @@
 """Serving cells: the decode engine behind its HTTP server, under generated load.
 
-The server side is chip_smoke.py's `_serve_one` (run on the chip in PR 21)
-without the trip through the disk: weights are made on the device from the
-seed in one jitted call and handed to `DecodeEngine(cfg, params, config)`;
+What is served comes from the configuration's family file
+(`<data>/families/<family>.py`): the model's sizes, its seeded weights, its
+engine and how many slots it steps, the ids its traffic may draw, its
+comparison with its own plain reference, limits and all, and the bytes a
+decode step must read. Everything here is the yardstick and the same for
+every family: the load, the window, the traced sub-window, the records and
+what counts.
 `ServingHTTPServer(None, decode_engine=...)` and the watchdog are all that
-`serving.server.serve_decode` adds to that. Engine, HTTP threads and the
-load generator share this one process, because one process holds the chip.
+`serving.server.serve_decode` adds to the family's engine. Engine, HTTP
+threads and the load generator share this one process, because one process
+holds the chip.
 
 Clocks: a request's record holds when it was due, when it was sent and when
 its response was read, on this process's perf_counter, beside the server's
@@ -15,92 +20,19 @@ own `ttft_ms` and `latency_ms` from the response body.
 from __future__ import annotations
 
 import itertools
-import json
 import os
 import queue
 import threading
 import time
-import urllib.error
-import urllib.request
 
-from .. import flops, generators, reference, trace_reduce
-from ..common import held_hbm, log, peak_hbm
-from ..generators.requests import FIRST_TOKEN_ID
+from .. import generators, trace_reduce
+from ..common import held_hbm, log, peak_hbm, post
 from . import result
 
 TRACE_AFTER_S = 2.0       # the profiler's sub-window opens this far in
 TRACE_SECONDS = 3.0
 DRAIN_LIMIT_S = 60.0      # after the window, for requests due inside it
-ENGINE_GAP = "engine loop (fetch + sample + feed, unsplit)"
-
-
-def model_config(config: dict):
-    from paddle_tpu.models import decoder_lm as dl
-
-    return dl.DecoderLMConfig(
-        vocab_size=config["vocab_size"], d_model=config["d_model"],
-        n_head=config["attention_heads"], n_layers=config["num_layers"],
-        d_inner=config["ffn_dim"],
-        max_seq_len=config["max_position_embeddings"])
-
-
-def param_specs(cfg):
-    """name -> (shape, kind), as models/decoder_lm.decoder_lm_params lays
-    them out (a CPU test compares the two)."""
-    from paddle_tpu.models import decoder_lm as dl
-
-    specs = {"lm_tok_emb": ((cfg.vocab_size, cfg.d_model), "normal")}
-    for i in range(cfg.n_layers):
-        for suffix, d_in, d_out in dl._dense_specs(cfg):
-            specs[f"lm_l{i}_{suffix}_w"] = ((d_in, d_out), "normal")
-            specs[f"lm_l{i}_{suffix}_b"] = ((d_out,), "zeros")
-        for ln in ("ln1", "ln2"):
-            specs[f"lm_l{i}_{ln}_scale"] = ((cfg.d_model,), "ones")
-            specs[f"lm_l{i}_{ln}_bias"] = ((cfg.d_model,), "zeros")
-    return specs
-
-
-def make_params(cfg, seed: int):
-    """Seeded float32 weights, made on the device in one jitted call."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import decoder_lm as dl
-
-    specs = param_specs(cfg)
-    names = sorted(specs)
-    std = cfg.d_model ** -0.5
-
-    def make(key):
-        out = {}
-        for j, name in enumerate(names):
-            shape, kind = specs[name]
-            if kind == "normal":
-                out[name] = std * jax.random.normal(
-                    jax.random.fold_in(key, j), shape, jnp.float32)
-            else:
-                out[name] = jnp.full(shape, float(kind == "ones"),
-                                     jnp.float32)
-        return out
-
-    params = jax.jit(make)(jax.random.PRNGKey(seed % (2 ** 31)))
-    params["lm_pos_enc"] = jnp.asarray(
-        dl._sinusoid_table(cfg.max_seq_len, cfg.d_model))
-    return params
-
-
-def post(url: str, doc: dict, timeout: float = 600.0):
-    """-> (status, body dict); a refusal's status and body, not a raise."""
-    req = urllib.request.Request(
-        url, data=json.dumps(doc).encode(),
-        headers={"Content-Type": "application/json"})
-    try:
-        with urllib.request.urlopen(req, timeout=timeout) as r:
-            return r.status, json.loads(r.read())
-    except urllib.error.HTTPError as e:
-        return e.code, {"error": e.read().decode(errors="replace")[:200]}
-    except OSError as e:
-        return 0, {"error": repr(e)[:200]}
+NO_SPAN_GAP = "engine thread outside its loop's spans"
 
 
 def send(url: str, req: dict, rec: dict):
@@ -181,66 +113,22 @@ class Load:
         return not any(th.is_alive() for th in self._threads)
 
 
-def check_correct(url, params, cfg, check: dict, seed: int):
-    """Greedy requests over HTTP, teacher-forced through the reference."""
-    import numpy as np
-
-    rng = np.random.RandomState((seed + 7919) % (2 ** 32))
-    worst, notes, by_prompt = 0.0, [], {}
-    for n in check["prompt_tokens"]:
-        prompt = rng.randint(FIRST_TOKEN_ID, cfg.vocab_size, n)
-        status, body = post(url + "/v1/generate", {
-            "prompt_ids": prompt.tolist(), "stop_at_eos": False,
-            "max_new_tokens": check["new_tokens"]})
-        if status != 200 or body.get("num_tokens") != check["new_tokens"]:
-            notes.append(f"check request answered {status}: {body}")
-            continue
-        ok, gap, by_prompt[n] = reference.check_greedy(
-            params, cfg.n_layers, cfg.n_head, prompt, body["tokens"],
-            pad_to=check["pad_to"])
-        worst = max(worst, gap)
-        if not ok:
-            notes.append(f"engine's greedy token {gap:.4f} under the "
-                         f"reference's maximum logit (margin "
-                         f"{reference.MARGIN}) at prompt length {n}")
-    return worst, notes, by_prompt
-
-
-def engine_config(config: dict, traffic: dict) -> dict:
-    """DecodeConfig's arguments; refuses a pool, a traffic mix or a check
-    that does not fit the configuration's `max_context`."""
-    eng = dict(config["engine"], kv_pages=config["kv_pages"])
-    per_slot = -(-config["max_context"] // eng["page_size"])
-    if eng["kv_pages"] < eng["max_slots"] * per_slot + 1:
-        raise ValueError(
-            f"kv_pages {eng['kv_pages']} hold no {config['max_context']} "
-            f"tokens for each of {eng['max_slots']} slots")
-    check = config["check"]
-    longest = max(traffic["max_context"],
-                  max(check["prompt_tokens"]) + check["new_tokens"])
-    if longest > config["max_context"]:
-        raise ValueError(f"a context of {longest} tokens is over the "
-                         f"configuration's max_context")
-    return eng
-
-
 def run(job):
     import jax
 
     from paddle_tpu.core import incidents, telemetry
-    from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
     from paddle_tpu.serving.server import ServingHTTPServer
 
     config, traffic = job.config, job.traffic
-    cfg = model_config(config)
-    eng = engine_config(config, traffic)
+    family = job.manifest.family(config["family"])
+    cfg = family.model_config(config)
     t0 = time.perf_counter()
-    params = make_params(cfg, job.seed)
+    params = family.make_params(cfg, job.seed)
     jax.block_until_ready(params)
     log("serve.weights", seconds=round(time.perf_counter() - t0, 2))
 
     t0 = time.perf_counter()
-    engine = DecodeEngine(cfg, params, DecodeConfig(**eng))
+    engine = family.make_engine(cfg, params, config, traffic)
     engine.start(warmup=True)
     incidents.start_watchdog()
     server = ServingHTTPServer(None, decode_engine=engine).start()
@@ -249,13 +137,14 @@ def run(job):
     notes, trace = [], None
     try:
         t0 = time.perf_counter()
-        worst_gap, notes, gaps = check_correct(server.url, params, cfg,
-                                               config["check"], job.seed)
+        compared, notes, detail = family.check_correct(
+            server.url, engine, params, cfg, config["check"], job.seed)
         log("serve.check", seconds=round(time.perf_counter() - t0, 2),
-            worst_gap=worst_gap, margin=reference.MARGIN, gaps=gaps)
+            compared=compared, **detail)
 
         plan = generators.load(traffic["generator"]).make(
-            traffic, job.seed, job.seconds, cfg.vocab_size)
+            traffic, job.seed, job.seconds,
+            family.traffic_vocab(cfg, config))
         telemetry.reset()              # the window's own counters and hists
         job.watch.mark()
         t_load = time.perf_counter() + 0.05
@@ -273,7 +162,8 @@ def run(job):
                 time.sleep(min(TRACE_SECONDS, 0.5 * job.seconds))
             jax.profiler.stop_trace()
             after = dict(telemetry.counters())
-            trace = trace_reduce.reduce_trace(trace_dir, ENGINE_GAP, job.platform)
+            trace = trace_reduce.reduce_trace(trace_dir, NO_SPAN_GAP,
+                                              job.platform)
             trace["counters"] = {
                 k: after.get(k, 0) - before.get(k, 0)
                 for k in ("decode.steps", "decode.prefills", "decode.tokens")}
@@ -330,9 +220,7 @@ def run(job):
     mean_ctx = sum(r["tokens"] * (r["prompt"] + (r["tokens"] + 1) / 2)
                    for r in good) / steps_live
     occ = (snap["hists"].get("decode.batch_occupancy") or {}).get("avg", 0)
-    live_ctx = occ * eng["max_slots"] * mean_ctx
-    sizes = dict(d_model=cfg.d_model, layers=cfg.n_layers, ffn=cfg.d_inner,
-                 vocab=cfg.vocab_size)
+    live_ctx = occ * family.slots(config) * mean_ctx
     log("serve.window", attempted=attempted, failed=failed,
         window_s=round(window_s, 3), backlog_at_close=backlog,
         completed_per_s=round(len(good) / window_s, 4),
@@ -341,10 +229,12 @@ def run(job):
         prefills=snap["counters"].get("decode.prefills"))
     return result(
         kind="serve", correct=not notes, attempted=attempted, failed=failed,
-        notes=notes, setup_s=setup_s, window_s=window_s, requests=good,
+        notes=notes, compared=list(compared) + [
+            ["failed_requests", failed, 0],
+            ["compiles_in_window", compiled + job.watch.since_mark(), 0]],
+        setup_s=setup_s, window_s=window_s, requests=good,
         answered=[r for r in records if r["ok"]],
         window=(t_open, load.t_close),
         telemetry=snap, peak_hbm_bytes=peak_hbm(job.chips),
-        window_hbm_bytes=held, trace=trace,
-        step_bytes=flops.decoder_step_bytes(
-            live_context_tokens=live_ctx, **sizes))
+        window_hbm_bytes=held, trace=trace, live_context_tokens=live_ctx,
+        step_bytes=family.step_bytes(cfg, config, live_ctx, snap))

@@ -10,6 +10,7 @@ of host batches.
 from __future__ import annotations
 
 import collections
+import gc
 import math
 import os
 import time
@@ -48,18 +49,39 @@ def build(config: dict, traffic: dict, **changed):
     return cfg, main, startup, fetches["loss"]
 
 
-def check_against_reference(config: dict, traffic: dict, seed: int):
+def mesh_of(config: dict, chips: int):
+    """-> (mesh, data-parallel replicas) as `runner.mesh_by_chips` gives
+    them for this many chips; (None, 1) where it names none."""
+    axes = config["runner"].get("mesh_by_chips", {}).get(str(chips))
+    if not axes:
+        return None, 1
+    import jax
+
+    from paddle_tpu.parallel import create_mesh
+
+    mesh = create_mesh(dict(axes), devices=jax.devices()[:chips])
+    return mesh, int(axes.get("dp", 1))
+
+
+def check_against_reference(config: dict, traffic: dict, seed: int,
+                            mesh=None, replicas: int = 1):
     """One step of the same program at the check's depth with dropout off,
-    through the same Executor, kernels and dtype, held against the plain
-    float32 reference: the loss and the gradients the check names. Built
-    under a name guard, so that the cell's own program gets the names (and
-    the compile-cache keys) it would get alone."""
+    through the same Executor and dtype and down the path the cell's own
+    steps take (one device and its kernels, or `mesh` and the partitioned
+    step with its collectives), held against the plain float32 reference:
+    the loss and the gradients the check names. Built under a name guard,
+    so that the cell's own program gets the names (and the compile-cache
+    keys) it would get alone. Everything it put on the devices dies with
+    this frame, before the cell's program is built."""
     import numpy as np
 
     import paddle_tpu as pt
     from paddle_tpu.core import unique_name
 
     check = config["check"]
+    if check["batch"] % replicas:
+        raise ValueError(f"the check's batch of {check['batch']} rows does "
+                         f"not divide over {replicas} replicas")
     with unique_name.guard():
         cfg, main, startup, loss_v = build(
             config, traffic, num_hidden_layers=check["num_hidden_layers"],
@@ -72,7 +94,7 @@ def check_against_reference(config: dict, traffic: dict, seed: int):
     params = {p.name: np.array(scope.find_var(p.name))   # copies: the step
               for p in main.all_parameters()}            # donates its state
     loss, *grads = exe.run(
-        main, feed=batch, scope=scope,
+        main, feed=batch, scope=scope, mesh=mesh,
         fetch_list=[loss_v] + [n + "@GRAD" for n in check["grads"]])
     return reference.check_train_step(
         params, batch, cfg.num_hidden_layers, cfg.num_attention_heads,
@@ -88,17 +110,13 @@ def run(job):
 
     config, traffic = job.config, job.traffic
     t0 = time.perf_counter()
-    notes, facts = check_against_reference(config, traffic, job.seed)
-    log("train.check", seconds=round(time.perf_counter() - t0, 2), **facts)
+    mesh, replicas = mesh_of(config, job.chips)
+    notes, facts = check_against_reference(config, traffic, job.seed, mesh,
+                                           replicas)
+    gc.collect()               # the check's Executor, Scope and arrays
+    log("train.check", seconds=round(time.perf_counter() - t0, 2),
+        mesh=dict(mesh.shape) if mesh is not None else None, **facts)
     cfg, main, startup, loss_v = build(config, traffic)
-    mesh, replicas = None, 1
-    mesh_axes = config["runner"].get("mesh_by_chips", {}).get(str(job.chips))
-    if mesh_axes:
-        from paddle_tpu.parallel import create_mesh
-
-        mesh = create_mesh(dict(mesh_axes),
-                           devices=jax.devices()[:job.chips])
-        replicas = int(mesh_axes.get("dp", 1))
     ring = generators.load(traffic["generator"]).make(
         traffic, job.seed, cfg.vocab_size, cfg.type_vocab_size, replicas)
     tokens_per_step = ring[0]["src_ids"].size
@@ -206,7 +224,13 @@ def run(job):
         set_mesh(None)
     return result(
         kind="train", correct=not notes, attempted=steps, failed=0,
-        notes=notes, setup_s=setup_s, window_s=window_s, steps=steps,
+        notes=notes, compared=[
+            ["loss_rel_err", abs(facts["loss"] / facts["reference_loss"] - 1),
+             reference.LOSS_TOL]] + [
+            [f"grad_rel_err.{n}", err, reference.GRAD_TOL]
+            for n, err in facts["grad_rel_err"].items()] + [
+            ["compiles_in_window", moved + job.watch.since_mark(), 0]],
+        setup_s=setup_s, window_s=window_s, steps=steps,
         tokens_per_step=tokens_per_step, first_step_s=first_step_s,
         step_ms=step_ms, dispatch_ms=dispatch_ms, flops_per_token=fpt,
         peak_hbm_bytes=peak_hbm(job.chips), trace=trace)

@@ -7,8 +7,10 @@ which planes and lines count as "the device" is a parameter.
 
 On a TPU the device planes are named `/device:TPU:<n>`; their `XLA Ops`
 line holds one event per executed operation and `XLA Modules` one per
-program execution. Host spans are the `jax.profiler.TraceAnnotation`s the
-benchmark wraps around its own calls (names starting with `bench.`).
+program execution. Host spans are `jax.profiler.TraceAnnotation`s: the
+benchmark's around its own calls (`bench.`) and the program's
+`telemetry.timer`s, which are annotations of the same name (`decode.`,
+`executor.`). Every idle instant goes to the innermost span open then.
 """
 
 from __future__ import annotations
@@ -23,8 +25,9 @@ Event = Tuple[str, int, int]          # name, start_ns, end_ns
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "decode.", "executor.")
 WINDOW_SPAN = "bench.window"
+Segment = Tuple[int, int, Optional[str]]      # start_ns, end_ns, span name
 _NUMBER_SUFFIX = re.compile(r"(\.\d+)+$")
 
 
@@ -49,8 +52,7 @@ def newest_xplane(trace_dir: str) -> str:
 
 def read_events(path: str, is_device_plane: Callable[[str], bool],
                 is_ops_line: Callable[[str], bool],
-                is_modules_line: Callable[[str], bool] = lambda n: False,
-                span_prefix: str = SPAN_PREFIX):
+                is_modules_line: Callable[[str], bool] = lambda n: False):
     """-> (device: {plane: [Event]}, modules: {plane: [Event]}, spans: [Event])"""
     from jax.profiler import ProfileData
 
@@ -72,7 +74,7 @@ def read_events(path: str, is_device_plane: Callable[[str], bool],
                 end = start + int(ev.duration_ns)
                 if bucket is not None:
                     bucket.append((ev.name, start, end))
-                elif ev.name.startswith(span_prefix):
+                elif ev.name.startswith(SPAN_PREFIXES):
                     spans.append((ev.name, start, end))
     return device, modules, spans
 
@@ -108,18 +110,34 @@ def gaps(busy: Sequence[Tuple[int, int]], lo: int, hi: int):
     return out
 
 
-def attribute(gap: Tuple[int, int], spans: Sequence[Event],
-              default: str) -> str:
-    """The host span that covers most of the gap, if it covers over half
-    of it; the window span itself never names a gap."""
-    best, best_ns = default, 0
-    for name, s, e in spans:
-        if name == WINDOW_SPAN:
-            continue
-        ov = min(e, gap[1]) - max(s, gap[0])
-        if ov > best_ns:
-            best, best_ns = name, ov
-    return best if 2 * best_ns > gap[1] - gap[0] else default
+def innermost(spans: Sequence[Event], lo: int, hi: int) -> List[Segment]:
+    """[lo, hi] cut at every span edge; each piece named for the open span
+    that started last (of two that started together, the one that ends
+    first), or None where no span is open."""
+    cuts = sorted({lo, hi} | {t for _, s, e in spans for t in (s, e)
+                              if lo < t < hi})
+    out = []
+    for a, b in zip(cuts, cuts[1:]):
+        open_now = [(s, -e, name) for name, s, e in spans
+                    if s <= a and e >= b]
+        out.append((a, b, max(open_now)[2] if open_now else None))
+    return out
+
+
+def split(idle: Sequence[Tuple[int, int]],
+          segments: Sequence[Segment]) -> Dict[Optional[str], int]:
+    """ns of the sorted, disjoint `idle` intervals under each segment name."""
+    sums: Dict[Optional[str], int] = {}
+    i = 0
+    for a, b, name in segments:
+        while i < len(idle) and idle[i][1] <= a:
+            i += 1
+        j = i
+        while j < len(idle) and idle[j][0] < b:   # one that crosses b stays
+            sums[name] = (sums.get(name, 0)
+                          + min(idle[j][1], b) - max(idle[j][0], a))
+            j += 1
+    return sums
 
 
 def op_family(name: str) -> str:
@@ -130,22 +148,29 @@ def op_family(name: str) -> str:
     return _NUMBER_SUFFIX.sub("", head) or name
 
 
-def top_sums(events: Sequence[Event], lo: int, hi: int, n: int = 10):
+def family_sums(events: Sequence[Event], lo: int, hi: int) -> Dict[str, int]:
+    """ns inside [lo, hi] of every family of operations."""
     sums: Dict[str, int] = {}
     for name, s, e in events:
         d = min(e, hi) - max(s, lo)
         if d > 0:
             key = op_family(name)
             sums[key] = sums.get(key, 0) + d
-    ranked = sorted(sums.items(), key=lambda kv: (-kv[1], kv[0]))[:n]
-    return [[k, v / 1e9] for k, v in ranked]
+    return sums
+
+
+def _by_size(item):
+    return -item[1], item[0]
 
 
 def reduce_events(device: Dict[str, List[Event]], spans: Sequence[Event],
                   modules: Optional[Dict[str, List[Event]]] = None,
                   default_gap_label: str = "host, no benchmark span",
                   window: Optional[Tuple[int, int]] = None) -> dict:
-    """Busy and idle over the traced window, averaged over device planes.
+    """Busy and idle over the traced window, averaged over device planes:
+    `op_seconds` of every family of operations (`device_ops`: the ten
+    largest), and the idle time by the innermost span open then, the window
+    span never naming any (`idle_gaps`).
 
     The window is the `bench.window` span when the trace holds one, else the
     `window` argument, else first start to last end of the device events."""
@@ -158,22 +183,26 @@ def reduce_events(device: Dict[str, List[Event]], spans: Sequence[Event],
         window = (min(s for evs in device.values() for _, s, _ in evs),
                   max(e for evs in device.values() for _, _, e in evs))
     lo, hi = window
+    segments = innermost([ev for ev in spans if ev[0] != WINDOW_SPAN],
+                         lo, hi)
     busy_s, all_ops, gap_sums = [], [], {}
     for plane in sorted(device):
         evs = device[plane]
         merged = union(clip([(s, e) for _, s, e in evs], lo, hi))
         busy_s.append(sum(e - s for s, e in merged) / 1e9)
         all_ops.extend(evs)
-        for g in gaps(merged, lo, hi):
-            label = attribute(g, spans, default_gap_label)
-            gap_sums[label] = gap_sums.get(label, 0) + (g[1] - g[0])
+        for name, ns in split(gaps(merged, lo, hi), segments).items():
+            label = default_gap_label if name is None else name
+            gap_sums[label] = gap_sums.get(label, 0) + ns
     n_dev = len(device)
-    ranked_gaps = sorted(gap_sums.items(), key=lambda kv: (-kv[1], kv[0]))
+    ranked_ops = sorted(family_sums(all_ops, lo, hi).items(), key=_by_size)
+    ranked_gaps = sorted(gap_sums.items(), key=_by_size)
     out = {
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy_s) / n_dev,
         "busy_s_per_device": busy_s,
-        "device_ops": [[k, v / n_dev] for k, v in top_sums(all_ops, lo, hi)],
+        "op_seconds": {k: v / 1e9 / n_dev for k, v in ranked_ops},
+        "device_ops": [[k, v / 1e9 / n_dev] for k, v in ranked_ops[:10]],
         "idle_gaps": [[k, v / 1e9 / n_dev] for k, v in ranked_gaps[:10]],
         "devices": n_dev,
     }

@@ -1,5 +1,6 @@
 """BENCHMARK.json and the files it names: the rules the harness depends on,
-and that a cell, a configuration and a metric are added by files alone."""
+and that a cell, a configuration and a metric are added by files alone
+(a served model of another family: test_bench_families.py)."""
 
 import json
 import os
@@ -7,7 +8,7 @@ import re
 
 import pytest
 
-from benchmark.manifest import Manifest
+from benchmark.manifest import FAMILY_FUNCTIONS, RUNNERS_DIR, Manifest
 
 from . import toy
 
@@ -48,7 +49,13 @@ def test_every_layer_metric_moves_a_metric_its_cells_report(real):
 def test_every_named_file_exists(real):
     for c in real.doc["configs"]:
         doc = real.config_doc(c["name"])
-        assert doc["kind"] in ("train", "serve")
+        # README: a configuration of a new kind brings runners/<kind>.py,
+        # a served model of another family brings families/<family>.py
+        assert os.path.isfile(os.path.join(RUNNERS_DIR, doc["kind"] + ".py"))
+        if doc["kind"] == "serve":
+            family = real.family(doc["family"])
+            for fn in FAMILY_FUNCTIONS:
+                assert callable(getattr(family, fn)), (c["name"], fn)
         assert doc["reduced"] == c["reduced"]
         for key in ("source", "assumed", "departures"):
             assert doc[key], (c["name"], key)
@@ -100,3 +107,52 @@ def test_a_broken_manifest_is_named(tmp_path, breakage, said):
     with open(path, "w") as f:
         json.dump(doc, f)
     assert said in " | ".join(Manifest(root).problems())
+
+
+def _rewrite_config(root, name, change):
+    path = os.path.join(root, "benchmark", "configs", name + ".json")
+    with open(path) as f:
+        doc = json.load(f)
+    doc.update(change)
+    with open(path, "w") as f:
+        json.dump(doc, f)
+
+
+@pytest.mark.parametrize("config, change, family_source, said", [
+    ("toy_bert", {"kind": "rank"}, None,
+     "config toy_bert: no runner file runners/rank.py"),
+    ("toy_lm", {"family": "nowhere"}, None,
+     "config toy_lm: no family file families/nowhere.py"),
+    ("toy_lm", {"family": "half"},
+     "def model_config(config):\n    pass\n\n\nstep_bytes = 7\n",
+     "family half lacks step_bytes()"),
+])
+def test_a_configuration_without_its_runner_or_family_is_named(
+        tmp_path, config, change, family_source, said):
+    """What a chip run would die of is a problem of the manifest."""
+    root = toy.make_root(str(tmp_path))
+    assert Manifest(root).problems() == []
+    _rewrite_config(root, config, change)
+    if family_source:
+        with open(os.path.join(root, "benchmark", "families",
+                               change["family"] + ".py"), "w") as f:
+            f.write(family_source)
+    problems = Manifest(root).problems()
+    assert said in " | ".join(problems)
+    if family_source:      # every missing function is named, none that is there
+        lacking = {p.split(" lacks ")[1] for p in problems if " lacks " in p}
+        assert lacking == {fn + "()" for fn in FAMILY_FUNCTIONS} \
+            - {"model_config()"}
+
+
+def test_a_served_configuration_names_its_family(tmp_path):
+    """No default: the file says what it runs."""
+    root = toy.make_root(str(tmp_path))
+    path = os.path.join(root, "benchmark", "configs", "toy_lm.json")
+    with open(path) as f:
+        doc = json.load(f)
+    del doc["family"]
+    with open(path, "w") as f:
+        json.dump(doc, f)
+    assert ("config toy_lm: a served configuration names its `family`, "
+            "this one has None") in Manifest(root).problems()

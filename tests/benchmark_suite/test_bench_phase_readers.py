@@ -1,9 +1,7 @@
 """The readers of the engine's and the executor's phase timers, and the
 split of the device's idle time by the program's own spans."""
 
-import json
 import math
-import os
 import time
 
 import pytest
@@ -37,10 +35,12 @@ NEW = tuple(SERVE_TIMERS) + IDLE + tuple(TRAIN_TIMERS)
 def test_the_manifest_is_sound_with_the_twelve_entries():
     assert REAL.problems() == []
     by_name = {m["name"]: m for m in REAL.doc["per_layer"]}
-    assert [m["name"] for m in REAL.doc["per_layer"]][-12:] == list(NEW)
+    names = list(by_name)
+    first = names.index(NEW[0])
+    assert names[first:first + 12] == list(NEW)
     for name in NEW:
-        cell, = by_name[name]["workloads"]
-        kind = REAL.config_doc(REAL.cell(cell)["config"])["kind"]
+        kind, = {REAL.config_doc(REAL.cell(cell)["config"])["kind"]
+                 for cell in by_name[name]["workloads"]}
         assert kind == ("train" if name.endswith(".train") else "serve")
         assert by_name[name]["moves"] == (
             "train_tokens_per_s" if kind == "train" else "tpot_p90_ms")
@@ -192,20 +192,9 @@ def root(tmp_path_factory):
     """The toy root with cell names of this file's own: the work directory
     is <checkout>/.bench_work/<cell>, and test_bench_runners.py may be
     running toy_open on another worker."""
-    root = toy.make_root(str(tmp_path_factory.mktemp("phase_root")))
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        doc = json.load(f)
-    names = {"toy_train": "phase_train", "toy_closed": "phase_closed"}
-    doc["workloads"] = [dict(w, name=names[w["name"]])
-                        for w in doc["workloads"] if w["name"] in names]
-    for group in ("end_to_end", "per_layer"):
-        for m in doc[group]:
-            if "workloads" in m:
-                m["workloads"] = [names[w] for w in m["workloads"]
-                                  if w in names]
-    with open(path, "w") as f:
-        json.dump(doc, f)
+    root = toy.keep_cells(
+        toy.make_root(str(tmp_path_factory.mktemp("phase_root"))),
+        {"toy_train": "phase_train", "toy_closed": "phase_closed"})
     assert Manifest(root).problems() == []
     return root
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from benchmark import reference, run
-from benchmark.runners import serve
+from benchmark.families import decoder_lm as family
 
 from . import toy
 
@@ -39,12 +39,17 @@ def check_line(out, expected_metrics):
     ("toy_closed", {"serve_tokens_per_s", "tpot_p90_ms", "setup_s"}),
     ("toy_open", {"serve_tokens_per_s", "tpot_p90_ms", "setup_s"}),
 ])
-def test_an_end_to_end_run_prints_the_contracts_line(root, cell, metrics):
+def test_an_end_to_end_run_prints_the_contracts_line(root, cell, metrics,
+                                                     capsys):
     out = run.run_cell(root, cell, seed=2 ** 31 + 11, seconds=1.5,
                        trace=False, require_platform=None)
     check_line(out, metrics)
     assert set(out["metrics"]) == metrics
     assert "breakdown" not in out
+    # stderr ends with each number the check compared, beside its limit
+    last = capsys.readouterr().err.strip().splitlines()[-3:]
+    assert all(line.startswith("compared ") and "(limit " in line
+               for line in last), last
 
 
 @pytest.mark.parametrize("cell, metrics", [
@@ -67,32 +72,68 @@ def test_a_traced_run_reports_layer_metrics_and_a_breakdown(root, cell,
         assert all(isinstance(n, str) and s >= 0 for n, s in rows)
 
 
-def test_a_four_chip_cell_trains_on_the_configurations_mesh(tmp_path):
-    """The path a dp2 x mp2 cell would take, on four of the suite's virtual
-    CPU devices: the mesh from `mesh_by_chips`, the batch times dp."""
+@pytest.mark.parametrize("planted", [False, True])
+def test_a_four_chip_cell_trains_and_is_checked_on_the_configurations_mesh(
+        tmp_path, monkeypatch, capsys, planted):
+    """The path a dp2 x mp2 cell takes, on four of the suite's virtual CPU
+    devices: the mesh from `mesh_by_chips`, the batch times dp, and the
+    reference check through the partitioned step, not beside it. Planted:
+    every step on the mesh sees the first replica's rows twice, as a
+    gradient averaged over one replica would; the check's gradients then
+    leave the reference's and the run is not correct."""
     import jax
+
+    import paddle_tpu as pt
 
     if len(jax.devices()) < 4:
         pytest.skip("needs four (virtual) devices")
-    root = toy.make_root(str(tmp_path), chips={"toy_train": 4}, mesh={
-        "4": {"dp": 2, "mp": 2}})
-    out = run.run_cell(root, "toy_train", seed=5, seconds=1.0, trace=False,
-                       require_platform=None)
-    check_line(out, {"train_tokens_per_s", "setup_s"})
+    root = toy.keep_cells(
+        toy.make_root(str(tmp_path), chips={"toy_train": 4},
+                      mesh={"4": {"dp": 2, "mp": 2}}),
+        {"toy_train": f"mesh_train_{int(planted)}"})
+    seen = []
+    run_step = pt.Executor.run
+
+    def spy(self, program=None, feed=None, fetch_list=None, **kw):
+        if kw.get("mesh") is not None:
+            seen.append([str(getattr(v, "name", v)) for v in fetch_list])
+            if planted:
+                half = len(feed["src_ids"]) // 2
+                feed = {k: np.concatenate([v[:half], v[:half]])
+                        for k, v in feed.items()}
+        return run_step(self, program, feed=feed, fetch_list=fetch_list, **kw)
+
+    monkeypatch.setattr(pt.Executor, "run", spy)
+    out = run.run_cell(root, f"mesh_train_{int(planted)}", seed=5,
+                       seconds=1.0, trace=False, require_platform=None)
     assert out["device"]["count"] == 4
+    # the first step on the mesh is the check's: it fetches the gradients
+    assert seen[0][1:] == [n + "@GRAD" for n in
+                           toy.TRAIN_CONFIG["check"]["grads"]]
+    err = capsys.readouterr().err
+    assert "compared grad_rel_err.layer_0_attn_q_w = " in err
+    if planted:
+        assert out["correct"] is False
+        assert "incorrect: gradient of " in err
+    else:
+        check_line(out, {"train_tokens_per_s", "setup_s"})
     from paddle_tpu.parallel.mesh import get_mesh
 
     assert get_mesh() is None            # the runner leaves no global mesh
 
 
 def test_a_compile_inside_the_window_makes_the_run_incorrect(root,
-                                                             monkeypatch):
+                                                             monkeypatch,
+                                                             capsys):
     from benchmark.common import CompileWatch
 
     monkeypatch.setattr(CompileWatch, "since_mark", lambda self: 1)
     out = run.run_cell(root, "toy_train", seed=1, seconds=0.5, trace=False,
                        require_platform=None)
     assert out["correct"] is False
+    err = capsys.readouterr().err.strip().splitlines()
+    assert "compared compiles_in_window = 1 (limit 0)" in err
+    assert err[-1].startswith("incorrect: compiled inside the window")
 
 
 def test_the_command_refuses_a_cpu():
@@ -107,15 +148,35 @@ def test_the_command_refuses_a_cpu():
     assert "no result" in proc.stderr
 
 
+@pytest.mark.parametrize("given", [None, "/somewhere/the/machine/keeps"])
+def test_the_compile_cache_keeps_its_place_and_loses_its_size_cap(
+        monkeypatch, given):
+    """A machine's directory is taken as given, else one inside the
+    checkout; a machine's size cap is lifted (one cell's programs can be
+    larger than it, and then no run ever finds one in the cache)."""
+    if given:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_MAX_SIZE", "201326592")
+    for name in ("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                 "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "TPU_LOG_DIR"):
+        monkeypatch.setenv(name, os.environ.get(name, "unset"))
+    run._prepare_environment()
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == (
+        given or os.path.join(toy.REPO, ".jax_cache"))
+    assert os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] == "-1"
+
+
 def toy_lm():
-    return serve.model_config(toy.SERVE_CONFIG)
+    return family.model_config(toy.SERVE_CONFIG)
 
 
 def test_device_made_parameters_match_decoder_lm_params():
     from paddle_tpu.models import decoder_lm as dl
 
     cfg = toy_lm()
-    ours = serve.make_params(cfg, 2 ** 31 + 11)
+    ours = family.make_params(cfg, 2 ** 31 + 11)
     theirs = dl.decoder_lm_params(cfg, 0)
     assert set(ours) == set(theirs)
     for name, v in theirs.items():
@@ -127,17 +188,15 @@ def test_device_made_parameters_match_decoder_lm_params():
     assert np.asarray(ours["lm_l0_ln1_scale"]).min() == 1.0
     w = np.asarray(ours["lm_l1_fc1_w"])
     assert abs(w.std() - cfg.d_model ** -0.5) < 0.02
-    again = serve.make_params(cfg, 2 ** 31 + 11)
+    again = family.make_params(cfg, 2 ** 31 + 11)
     assert np.array_equal(w, np.asarray(again["lm_l1_fc1_w"]))
 
 
 def test_the_reference_agrees_with_the_engines_greedy_tokens():
-    from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
-
     cfg = toy_lm()
-    params = serve.make_params(cfg, 5)
-    engine = DecodeEngine(cfg, params, DecodeConfig(**serve.engine_config(
-        toy.SERVE_CONFIG, toy.TRAFFIC["toy_closed"])))
+    params = family.make_params(cfg, 5)
+    engine = family.make_engine(cfg, params, toy.SERVE_CONFIG,
+                                toy.TRAFFIC["toy_closed"])
     engine.start(warmup=False)
     try:
         rng = np.random.RandomState(0)
@@ -158,7 +217,7 @@ def test_the_reference_agrees_with_the_engines_greedy_tokens():
 
 def test_the_references_rows_are_rows_of_its_full_logits():
     cfg = toy_lm()
-    params = serve.make_params(cfg, 9)
+    params = family.make_params(cfg, 9)
     tokens = np.random.RandomState(1).randint(3, cfg.vocab_size, 16)
     full = np.asarray(reference.decoder_logits(
         params, tokens, cfg.n_layers, cfg.n_head))
@@ -234,8 +293,8 @@ def test_the_bert_reference_refuses_a_step_that_is_off(toy_step, what, said):
 def test_a_pool_or_a_mix_that_does_not_fit_the_context_is_refused(change,
                                                                   said):
     with pytest.raises(ValueError, match=said):
-        serve.engine_config(dict(toy.SERVE_CONFIG, **change),
-                            toy.TRAFFIC["toy_closed"])
+        family.engine_config(dict(toy.SERVE_CONFIG, **change),
+                             toy.TRAFFIC["toy_closed"])
 
 
 def test_memory_in_the_window_is_read_apart_from_the_peak():

@@ -21,12 +21,26 @@ def test_gaps_are_the_complement_inside_the_window():
     assert tr.gaps([(0, 10)], 0, 10) == []
 
 
-def test_a_gap_takes_the_span_that_covers_most_of_it():
-    spans = [("bench.window", 0, 100), ("bench.feed", 10, 19),
-             ("bench.block", 19, 22)]
-    assert tr.attribute((10, 20), spans, "other") == "bench.feed"
-    assert tr.attribute((40, 60), spans, "other") == "other"
-    assert tr.attribute((15, 35), spans, "other") == "other"   # under half
+def test_every_idle_instant_goes_to_the_innermost_span_open_then():
+    """One gap that runs across three spans is split among them; the window
+    span names nothing, and what no span covers takes the default label."""
+    spans = [("bench.window", 0, 100), ("decode.loop_ms", 10, 80),
+             ("decode.step_ms", 20, 60), ("decode.fetch_ms", 40, 60)]
+    device = {"/device:TPU:0": [("fusion.1", 20, 50)]}
+    r = tr.reduce_events(device, spans, default_gap_label="host")
+    assert dict(r["idle_gaps"]) == pytest.approx({
+        "host": 30e-9,                  # 0-10 and 80-100
+        "decode.loop_ms": 30e-9,        # 10-20 and 60-80
+        "decode.fetch_ms": 10e-9})      # 50-60; none under step_ms itself
+    assert r["idle_gaps"][0][0] == "decode.loop_ms"     # ties: by name
+    assert sum(v for _, v in r["idle_gaps"]) + r["busy_s"] \
+        == pytest.approx(r["window_s"])
+
+
+def test_the_trace_is_read_for_the_programs_spans_too():
+    assert tr.SPAN_PREFIXES == ("bench.", "decode.", "executor.")
+    assert "decode.sample_ms".startswith(tr.SPAN_PREFIXES)
+    assert not "jit_train_step".startswith(tr.SPAN_PREFIXES)
 
 
 def test_op_names_fold_into_families():
@@ -49,10 +63,15 @@ def test_reduce_events_on_two_devices_by_hand():
     assert r["busy_s_per_device"] == pytest.approx([70e-9, 40e-9])
     assert r["busy_s"] == pytest.approx(55e-9)
     assert r["device_ops"][0] == ["a", pytest.approx(40e-9)]
+    # every family, not the ten largest: a reader asks for one by name
+    assert r["op_seconds"] == pytest.approx({
+        "a": 40e-9, "b": 10e-9, "all-reduce": 10e-9})
+    assert [k for k, _ in r["device_ops"]] == list(r["op_seconds"])
     gaps = dict((k, v) for k, v in r["idle_gaps"])
-    # device 0 idles 70-100 under bench.feed; device 1 0-10 and 30-80
-    assert gaps["bench.feed"] == pytest.approx(15e-9)
-    assert gaps["host"] == pytest.approx(30e-9)
+    # device 0 idles 70-100 under bench.feed; device 1 0-10 and 30-70 under
+    # no span and 70-80 under bench.feed
+    assert gaps["bench.feed"] == pytest.approx(20e-9)
+    assert gaps["host"] == pytest.approx(25e-9)
     step = r["programs"]["jit_step(1)"]
     assert step["runs"] == pytest.approx((1 + 0.5 + 1) / 2)
     assert step["seconds"] == pytest.approx((70 + 10 + 20) / 2 * 1e-9)
@@ -80,8 +99,13 @@ def test_the_recorded_trace_reduces_to_its_pinned_numbers(recorded):
     assert r["busy_s"] == pytest.approx(0.004488471, abs=1e-9)
     assert r["device_ops"][0] == ["dot_general",
                                   pytest.approx(0.004091849, abs=1e-9)]
-    assert r["idle_gaps"][0] == ["bench.feed",
-                                 pytest.approx(0.010741086, abs=1e-9)]
+    assert r["op_seconds"]["wrapped_tanh"] == pytest.approx(0.000198361,
+                                                            abs=1e-9)
+    assert len(r["op_seconds"]) == 9 and len(r["device_ops"]) == 9
+    assert [[k, pytest.approx(v, abs=1e-9)] for k, v in [
+        ("bench.feed", 0.009731805), ("bench.exe_run", 0.000915351),
+        ("bench.block", 0.000194092), ("host", 0.000087109)]] \
+        == r["idle_gaps"]
 
 
 def test_the_recorded_union_agrees_with_a_sweep_over_endpoints(recorded):

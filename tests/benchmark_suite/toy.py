@@ -1,6 +1,6 @@
 """A toy benchmark root in a temporary directory: the real readers,
-generators and runners, with tiny configurations, cells and traffic added
-as files and BENCHMARK.json entries only."""
+families, generators and runners, with tiny configurations, cells and
+traffic added as files and BENCHMARK.json entries only."""
 
 import json
 import os
@@ -19,7 +19,8 @@ TRAIN_CONFIG = {
     "check": {"num_hidden_layers": 1, "batch": 2,
               "grads": ["layer_0_attn_q_w", "word_embedding"]}}
 SERVE_CONFIG = {
-    "name": "toy_lm", "kind": "serve", "source": "none: a test preset",
+    "name": "toy_lm", "kind": "serve", "family": "decoder_lm",
+    "source": "none: a test preset",
     "vocab_size": 97, "d_model": 32, "attention_heads": 4, "num_layers": 2,
     "ffn_dim": 64, "max_position_embeddings": 64,
     "max_context": 64, "kv_pages": 65,
@@ -57,9 +58,10 @@ def make_root(tmp, extra_metric=None, chips=None, mesh=None):
     data = os.path.join(tmp, "benchmark")
     os.makedirs(os.path.join(data, "configs"))
     os.makedirs(os.path.join(data, "traffic"))
-    shutil.copytree(os.path.join(REPO, "benchmark", "readers"),
-                    os.path.join(data, "readers"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for files in ("readers", "families"):
+        shutil.copytree(os.path.join(REPO, "benchmark", files),
+                        os.path.join(data, files),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     kinds = {}
     for cfg in (TRAIN_CONFIG, SERVE_CONFIG):
         if mesh and cfg["kind"] == "train":
@@ -112,3 +114,26 @@ def make_root(tmp, extra_metric=None, chips=None, mesh=None):
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
     return tmp
+
+
+def keep_cells(root, names):
+    """Cuts the toy root's BENCHMARK.json to the cells in `names` (old name
+    -> new name) and the configurations they use. A test file gives its
+    cells names of its own, because a run's work directory goes by the
+    cell's name and another file's tests may run beside it."""
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        doc = json.load(f)
+    doc["workloads"] = [dict(w, name=names[w["name"]])
+                        for w in doc["workloads"] if w["name"] in names]
+    used = {w["config"] for w in doc["workloads"]}
+    doc["configs"] = [c for c in doc["configs"] if c["name"] in used]
+    for group in ("end_to_end", "per_layer"):
+        for m in doc[group]:
+            if "workloads" in m:
+                m["workloads"] = [names[w] for w in m["workloads"]
+                                  if w in names]
+        doc[group] = [m for m in doc[group] if m.get("workloads", True)]
+    with open(path, "w") as f:
+        json.dump(doc, f)
+    return root
