@@ -29,6 +29,15 @@ the ``dequantize_weight`` op (ops/quant_ops.py) — XLA fuses the dequant
 into the consuming matmul read, halving weight bytes; activations, KV
 cache and layer norms stay fp32. ``quantize_decoder_lm_params``
 converts a trained fp32 param dict into that layout.
+
+Number format: THIS block's weights, activations and K/V pages are
+float32 (int8 weights apart), and its heads are plain multi-head
+(``kvdim == n_head x head_dim``), every layer holding a context's pages.
+That is this model's stated format, not the engine's limit: the engine
+takes any model through ``serving/served_model.py`` (``DecoderLMServed``
+below is this model's side of it), and models/afmoe.py serves bfloat16
+weights and pages, grouped heads and window layers on a ring of pages
+through the same engine.
 """
 
 from __future__ import annotations
@@ -43,6 +52,8 @@ from .. import layers
 from ..core.ir import Program, program_guard
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
+from ..serving.kv_cache import LayerCache
+from ..serving.served_model import ServedModel
 
 PARAMS_FILE = "decoder_lm_params.npz"
 CONFIG_FILE = "decoder_lm_config.json"
@@ -67,6 +78,9 @@ class DecoderLMConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
+
+    def served(self) -> "DecoderLMServed":
+        return DecoderLMServed(self)
 
 
 # dense sublayers per block, in program order: (suffix, d_in, d_out)
@@ -387,3 +401,32 @@ def build_prefill_program(cfg: DecoderLMConfig, batch: int, prompt_len: int,
             {"transpose_Y": True})
     feeds = ["tokens", "lengths", "last_onehot", "page_table"]
     return main, feeds, ["logits"] + pool_outs
+
+
+class DecoderLMServed(ServedModel):
+    """This model's side of the engine's seam (serving/served_model.py):
+    the three builders above, float32 pages, every layer a context's
+    pages of width d_model, int8 weight-only as the one quantization."""
+
+    def cache_layout(self):
+        return [LayerCache(self.cfg.d_model)] * self.cfg.n_layers
+
+    def prepare_params(self, params, weight_quant: str):
+        if weight_quant == "int8":
+            return quantize_decoder_lm_params(params, self.cfg)
+        return params
+
+    def build_step_program(self, batch, kv, weight_quant="none"):
+        return build_step_program(self.cfg, batch, kv.context.num_pages,
+                                  kv.page_size, weight_quant)
+
+    def build_prefill_program(self, prompt_len, kv, weight_quant="none"):
+        return build_prefill_program(self.cfg, 1, prompt_len,
+                                     kv.context.num_pages, kv.page_size,
+                                     weight_quant)
+
+    def build_chunk_prefill_program(self, chunk_len, kv,
+                                    weight_quant="none"):
+        return build_chunk_prefill_program(
+            self.cfg, 1, chunk_len, kv.context.num_pages, kv.page_size,
+            weight_quant)

@@ -16,6 +16,7 @@ from . import extra_ops  # noqa: F401
 from . import rnn_ops  # noqa: F401
 from . import quant_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import llm_ops  # noqa: F401
 from . import ps_ops  # noqa: F401
 from . import beam_search_ops  # noqa: F401
 from . import crf_ops  # noqa: F401

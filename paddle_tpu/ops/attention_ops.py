@@ -179,7 +179,11 @@ def kv_cache_write_op(ins, attrs):
     page PageTable[b, s // P], offset s % P. Positions at or past the
     row's length are routed to page 0 — the pool's reserved scratch page
     (never allocated to a request) — so padded prompt tail writes can
-    never corrupt another request's pages."""
+    never corrupt another request's pages.
+
+    Attr ``ring`` (present: the pool is of a page class, any dtype): when
+    true the table is a slot's ring and token s lands at index
+    ``s mod (MP x P)``; see ``_kv_cache_write_classed``."""
     import jax.numpy as jnp
 
     k, v = ins["K"][0], ins["V"][0]
@@ -191,6 +195,9 @@ def kv_cache_write_op(ins, attrs):
     b, s, _ = k.shape
     page = int(pool_k.shape[1])
     pos = jnp.arange(s, dtype=jnp.int32)                       # [S]
+    if "ring" in attrs:
+        return _kv_cache_write_classed(k, v, pool_k, pool_v, table, lengths,
+                                       bool(attrs["ring"]))
     logical = pos // page                                      # [S]
     phys = jnp.take_along_axis(
         table, jnp.broadcast_to(logical[None, :], (b, s)), axis=1)
@@ -199,6 +206,34 @@ def kv_cache_write_op(ins, attrs):
     off = jnp.broadcast_to((pos % page)[None, :], (b, s)).reshape(-1)
     pool_k = pool_k.at[phys, off].set(k.reshape(b * s, -1))
     pool_v = pool_v.at[phys, off].set(v.reshape(b * s, -1))
+    return {"PoolKOut": pool_k, "PoolVOut": pool_v}
+
+
+def _kv_cache_write_classed(k, v, pool_k, pool_v, table, lengths, ring):
+    """`kv_cache_write` for a pool of either class and any dtype (attr
+    `ring` present). A ring's table holds `cap = MP x P` tokens: token s
+    lands at index s mod cap, and of a prompt longer than the ring only
+    the last `cap` tokens are written (the earlier ones would land on the
+    same indices; they go to the scratch page)."""
+    import jax.numpy as jnp
+
+    b, s, _ = k.shape
+    page = int(pool_k.shape[1])
+    cap = int(table.shape[1]) * page
+    pos = jnp.arange(s, dtype=jnp.int32)
+    valid = pos[None, :] < lengths[:, None]                    # [B, S]
+    idx = pos
+    if ring:
+        valid &= pos[None, :] >= lengths[:, None] - cap
+        idx = pos % cap
+    phys = jnp.take_along_axis(
+        table, jnp.broadcast_to((idx // page)[None, :], (b, s)), axis=1)
+    phys = jnp.where(valid, phys, 0).reshape(-1)
+    off = jnp.broadcast_to((idx % page)[None, :], (b, s)).reshape(-1)
+    pool_k = pool_k.at[phys, off].set(
+        k.reshape(b * s, -1).astype(pool_k.dtype))
+    pool_v = pool_v.at[phys, off].set(
+        v.reshape(b * s, -1).astype(pool_v.dtype))
     return {"PoolKOut": pool_k, "PoolVOut": pool_v}
 
 
@@ -232,7 +267,15 @@ def cached_kv_attention_op(ins, attrs):
 
     Outputs: Out [B, nh*hd], PoolKOut, PoolVOut (the engine threads the
     pools through the step program and donates them to the jit so XLA
-    can update in place)."""
+    can update in place).
+
+    Attr ``num_kv_heads`` (present: grouped heads, K/V [B, nkv*hd], pages
+    of any dtype) routes to ops/pallas/paged_gqa_attention.py; with it
+    ``window`` (keys at pos - window < s <= pos only) and ``ring`` (the
+    table is a slot's ring: the step's K/V land at ``pos mod (MP x P)``
+    and keys are masked by their TRUE position). Without the attr this is
+    the float32 multi-head op it always was, kernel and lowering
+    unchanged."""
     import jax.numpy as jnp
 
     from .pallas.paged_attention import paged_decode_attention
@@ -247,6 +290,23 @@ def cached_kv_attention_op(ins, attrs):
     hd = int(attrs["head_dim"])
     scale = float(attrs.get("scale") or hd ** -0.5)
     page = int(pool_k.shape[1])
+    if "num_kv_heads" in attrs:
+        # grouped heads, a window, a ring of pages, pages of another dtype
+        # (ops/pallas/paged_gqa_attention.py): the step's K/V land at the
+        # position's index in the row's pages, a ring's modulo its size
+        from .pallas.paged_gqa_attention import paged_gqa_decode_attention
+
+        ring = bool(attrs.get("ring", False))
+        idx = pos % (int(table.shape[1]) * page) if ring else pos
+        phys = jnp.take_along_axis(table, (idx // page)[:, None],
+                                   axis=1)[:, 0]
+        pool_k = pool_k.at[phys, idx % page].set(k.astype(pool_k.dtype))
+        pool_v = pool_v.at[phys, idx % page].set(v.astype(pool_v.dtype))
+        out = paged_gqa_decode_attention(
+            q, pool_k, pool_v, table, pos, num_heads=n,
+            num_kv_heads=int(attrs["num_kv_heads"]), head_dim=hd,
+            scale=scale, window=int(attrs.get("window", 0)), ring=ring)
+        return {"Out": out, "PoolKOut": pool_k, "PoolVOut": pool_v}
     # write the step's K/V into each row's current page
     phys = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0]
     pool_k = pool_k.at[phys, pos % page].set(k)
@@ -284,7 +344,12 @@ def chunk_cached_attention_op(ins, attrs):
     reserved scratch page 0; a SHARED page is protected by pointing the
     chunk's own page-table entry at 0 (attention never reads the
     current chunk through the pool, so absorbing its write into scratch
-    is free). Outputs: Out [B, C, kvdim], PoolKOut, PoolVOut."""
+    is free). Outputs: Out [B, C, kvdim], PoolKOut, PoolVOut.
+
+    Attr ``num_kv_heads`` (present) selects grouped heads over context
+    pages of any dtype: ``_chunk_cached_attention_classed``. A ring of
+    pages is refused: the prefix store shares a context's pages, and
+    nothing chunk-prefills a window layer yet."""
     import jax
     import jax.numpy as jnp
 
@@ -301,6 +366,14 @@ def chunk_cached_attention_op(ins, attrs):
     scale = float(attrs.get("scale") or hd ** -0.5)
     page = int(pool_k.shape[1])
     mp = int(table.shape[1])
+    if "num_kv_heads" in attrs:
+        if attrs.get("ring") or attrs.get("window"):
+            raise NotImplementedError(
+                "chunk_cached_attention over a window layer's ring of "
+                "pages: the prefix store handles context pages only")
+        return _chunk_cached_attention_classed(
+            q, k, v, pool_k, pool_v, table, start, lengths, n,
+            int(attrs["num_kv_heads"]), hd, scale)
     # prior context is gathered from the PRE-write pools: positions
     # < ChunkStart are untouched by this chunk's writes by construction
     s_ctx = mp * page
@@ -329,6 +402,50 @@ def chunk_cached_attention_op(ins, attrs):
     probs = jax.nn.softmax(jnp.concatenate([sc_ctx, sc_chk], -1), axis=-1)
     out = jnp.einsum("bnqs,bsnh->bqnh", probs[..., :s_ctx], ctx_v) \
         + jnp.einsum("bnqs,bsnh->bqnh", probs[..., s_ctx:], vh)
+    return {"Out": out.reshape(b, c, n * hd),
+            "PoolKOut": pool_k_out, "PoolVOut": pool_v_out}
+
+
+def _chunk_cached_attention_classed(q, k, v, pool_k, pool_v, table, start,
+                                    lengths, n, nkv, hd, scale):
+    """`chunk_cached_attention` for grouped heads over context pages of
+    the pool's dtype (attr `num_kv_heads` present). The prior context is
+    read from the PRE-write pools, float32 accumulation throughout."""
+    import jax
+    import jax.numpy as jnp
+
+    b, c, _ = k.shape
+    page = int(pool_k.shape[1])
+    cap = int(table.shape[1]) * page
+    g = n // nkv
+    dt = pool_k.dtype
+    ctx_k = pool_k[table].reshape(b, cap, nkv, hd)
+    ctx_v = pool_v[table].reshape(b, cap, nkv, hd)
+    pos = jnp.arange(c, dtype=jnp.int32)
+    gpos = start[:, None] + pos[None, :]                       # [B, C]
+    phys = jnp.take_along_axis(table, gpos // page, axis=1)
+    valid = pos[None, :] < lengths[:, None]
+    phys = jnp.where(valid, phys, 0).reshape(-1)
+    off = (gpos % page).reshape(-1)
+    pool_k_out = pool_k.at[phys, off].set(k.reshape(b * c, -1).astype(dt))
+    pool_v_out = pool_v.at[phys, off].set(v.reshape(b * c, -1).astype(dt))
+    m_ctx = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+             < start[:, None])[:, None, :]                     # [B,1,cap]
+    causal = (pos[None, :] <= pos[:, None])[None]              # [1,Cq,Ck]
+    qh = q.reshape(b, c, nkv, g, hd).astype(dt)
+    kh = k.reshape(b, c, nkv, hd).astype(dt)
+    vh = v.reshape(b, c, nkv, hd).astype(dt)
+    sc_ctx = jnp.einsum("bqkgh,bskh->bkgqs", qh, ctx_k,
+                        preferred_element_type=jnp.float32) * scale
+    sc_chk = jnp.einsum("bqkgh,bskh->bkgqs", qh, kh,
+                        preferred_element_type=jnp.float32) * scale
+    sc_ctx = jnp.where(m_ctx[:, None, None], sc_ctx, -1e9)
+    sc_chk = jnp.where(causal[:, None, None], sc_chk, -1e9)
+    probs = jax.nn.softmax(jnp.concatenate([sc_ctx, sc_chk], -1), axis=-1)
+    out = jnp.einsum("bkgqs,bskh->bqkgh", probs[..., :cap].astype(dt),
+                     ctx_v, preferred_element_type=jnp.float32) \
+        + jnp.einsum("bkgqs,bskh->bqkgh", probs[..., cap:].astype(dt), vh,
+                     preferred_element_type=jnp.float32)
     return {"Out": out.reshape(b, c, n * hd),
             "PoolKOut": pool_k_out, "PoolVOut": pool_v_out}
 

@@ -29,3 +29,26 @@ def switch_moe_op(ins, attrs):
         activation=attrs.get("activation", "gelu"),
         tokens_sharded=bool(attrs.get("tokens_sharded", False)))
     return {"Out": out.reshape(x.shape), "AuxLoss": aux}
+
+
+@register_op("routed_experts",
+             required_attrs=("top_k", "held_lo"))
+def routed_experts_op(ins, attrs):
+    """One chip's share of a dropless top-k sigmoid-routed expert layer
+    (parallel/moe.py routed_experts_share): X [..., H] float32, RouterW
+    [H, E] over ALL experts, SelectBias [E], W1/W3 [E_held, H, F] and W2
+    [E_held, F, H] of the experts `held_lo` .. held here, optional Live
+    bool, one a row of X (the rows that carry a token; the rest join no
+    group). Out like X, float32; Counts int32 [3] (live pairs, those on
+    held experts, held experts hit)."""
+    from ..parallel.moe import routed_experts_share
+
+    live = ins["Live"][0] if ins.get("Live") else None
+    x = ins["X"][0]
+    out, counts = routed_experts_share(
+        x.reshape(-1, x.shape[-1]), ins["RouterW"][0], ins["SelectBias"][0],
+        ins["W1"][0], ins["W3"][0], ins["W2"][0],
+        top_k=int(attrs["top_k"]), held_lo=int(attrs["held_lo"]),
+        route_scale=float(attrs.get("route_scale", 1.0)),
+        route_norm=bool(attrs.get("route_norm", True)), live=live)
+    return {"Out": out.reshape(x.shape), "Counts": counts}

@@ -119,3 +119,101 @@ def switch_moe(x, gate_w, w1, b1, w2, b2, capacity_factor: float = 1.25,
         exp_out = experts(exp_in)
         out = jnp.einsum("tec,ech->th", combine, exp_out)
     return out.astype(x.dtype), aux.astype(jnp.float32)
+
+
+def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
+                         top_k: int, held_lo: int, route_scale: float = 1.0,
+                         route_norm: bool = True, live=None):
+    """One chip's share of a dropless top-k routed expert layer with
+    sigmoid scores (the serving form; `switch_moe` above is the trained
+    top-1 layer with a capacity).
+
+    x           [T, H]   tokens (any float dtype)
+    router_w    [H, E]   the router over ALL E experts, as published
+    select_bias [E]      added to the scores for SELECTION only
+    w1, w3      [E_held, H, F]   gate and up projections of the experts
+    w2          [E_held, F, H]   held here: experts held_lo .. held_lo+E_held
+    live        [T] bool, optional: rows that carry a token. An engine's
+                empty slots and a padded prompt's tail are rows too; their
+                pairs join no expert's group (a pad's thousand copies of
+                one token would all land on the same four experts), are
+                not counted, and their output is zero
+
+    Every token scores all E experts in float32, keeps the ``top_k``
+    largest of ``score + select_bias`` and weighs them by
+    ``score / (sum of the kept scores + 1e-20) * route_scale`` (no
+    renormalisation when ``route_norm`` is false). This chip then computes,
+    without dropping a pair, ``sum_i w_i * Expert_i(x)`` over the kept
+    pairs whose expert it holds: the pairs are sorted by expert and the
+    held experts' SwiGLUs run as three grouped products
+    (``jax.lax.ragged_dot``) over the sorted rows, so an expert no token
+    chose costs no weight read. The products run over the leading rows
+    that hold the held pairs when those are few, as they nearly always
+    are, and over every row otherwise (one ``lax.cond``). What the absent experts would have added
+    is left out; nothing stands in for their chips or the exchange.
+
+    Returns (out [T, H] float32, counts int32 [3]): the kept pairs of live
+    rows, those of them on held experts, and the held experts with at
+    least one live pair."""
+    import jax
+    import jax.numpy as jnp
+
+    t, h = x.shape
+    e_held = w1.shape[0]
+    xf = x.astype(jnp.float32)
+    scores = jax.nn.sigmoid(jnp.matmul(
+        xf, router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))                    # [T, E]
+    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    kept = jnp.take_along_axis(scores, idx, axis=1)              # [T, k]
+    weight = kept
+    if route_norm:
+        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + 1e-20)
+    weight = weight * route_scale
+    local = idx - held_lo
+    alive = jnp.ones((t,), bool) if live is None \
+        else live.reshape(-1).astype(bool)
+    held = (local >= 0) & (local < e_held) & alive[:, None]
+    # pairs sorted by held expert; pairs of absent experts and of dead
+    # rows sort behind every group and lie outside the groups' rows
+    key = jnp.where(held, local, e_held).reshape(-1)             # [T*k]
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
+                    axis=0)[:e_held]
+    rows = (order // top_k).astype(jnp.int32)
+    w_sorted = jnp.where(held, weight, 0.0).reshape(-1)[order]
+
+    def experts(n_rows):
+        """The held experts over the first `n_rows` sorted pairs."""
+        r, w = rows[:n_rows], w_sorted[:n_rows]
+        xs = x[r].astype(w1.dtype)                               # [n, H]
+
+        def grouped(a, wts):
+            return jax.lax.ragged_dot(a, wts, sizes,
+                                      preferred_element_type=jnp.float32)
+
+        mid = jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)
+        ys = grouped(mid.astype(w2.dtype), w2)                   # [n, H]
+        # rows past the groups hold nothing of a held expert: weight 0,
+        # and a `where` so that whatever the grouped product left there
+        # stays out
+        ys = jnp.where(w[:, None] > 0, ys * w[:, None], 0.0)
+        return jnp.zeros((t, h), jnp.float32).at[r].add(ys)
+
+    # the pairs on held experts sort first, and with evenly spread routing
+    # they are e_held / E of all pairs. Twice that share (and a margin)
+    # of the sorted rows holds them nearly always, and the grouped
+    # products, the gather and the scatter then run over that many rows
+    # (on the chip a grouped product over 64 rows took 1.2 ms where 256
+    # took 1.5, PR 28); when more pairs land here than that, every row is
+    # processed: no pair is ever dropped.
+    pairs = t * top_k
+    few = -(-(2 * pairs * e_held // router_w.shape[1] + 32) // 64) * 64
+    if few < pairs:
+        out = jax.lax.cond(jnp.sum(sizes) <= few, lambda: experts(few),
+                           lambda: experts(pairs))
+    else:
+        out = experts(pairs)
+    counts = jnp.stack([jnp.sum(alive) * top_k, jnp.sum(sizes),
+                        jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return out, counts

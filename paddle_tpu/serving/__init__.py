@@ -56,7 +56,7 @@ from .disagg import (ShipmentCRCError, ShipmentError, fetch_prefill,
                      pack_shipment, unpack_shipment)
 from .engine import ServingConfig, ServingEngine
 from .health import HealthState
-from .kv_cache import KVPagePool
+from .kv_cache import KVPagePool, LayerCache, PagedKVCache
 from .prefix_store import PrefixStore, prefix_chain_hash
 from .router import (NoReplicaAvailableError, ReplicaHandle, Router,
                      RouterHTTPServer)
@@ -67,8 +67,8 @@ __all__ = [
     "DeadlineExceededError", "DecodeConfig", "DecodeEngine",
     "EngineClosedError", "GenerationRequest", "HealthState",
     "InferenceRequest", "InprocReplica", "KVCacheExhaustedError",
-    "KVPagePool", "LocalClient", "NoReplicaAvailableError",
-    "PrefixStore", "ReplicaHandle", "ReplicaProcess", "Router",
+    "KVPagePool", "LayerCache", "LocalClient", "NoReplicaAvailableError",
+    "PagedKVCache", "PrefixStore", "ReplicaHandle", "ReplicaProcess", "Router",
     "RouterHTTPServer", "ServerOverloadedError", "ServingConfig",
     "ServingEngine", "ServingError", "ServingHTTPServer",
     "ShipPrefillRequest", "ShipmentCRCError", "ShipmentError",

@@ -7,8 +7,14 @@ serving traffic: many concurrent requests each producing tokens one
 step at a time. Orca-style continuous batching + vLLM-style paged KV
 caching, on the repo's frozen-program stack:
 
+* **The served model** comes through one seam (served_model.py):
+  ``model_cfg.served()`` gives the three program builders, the parameter
+  layout and, layer by layer, what the model keeps of a sequence — a
+  context's pages, or for a window layer a ring of pages (kv_cache.py).
+  models/decoder_lm.py and models/afmoe.py both come this way; the engine
+  has no second path.
 * **Phase split.** An admitted request first runs ONE prefill program
-  (models/decoder_lm.build_prefill_program, padded to a prompt-length
+  (the model's ``build_prefill_program``, padded to a prompt-length
   bucket) that writes the whole prompt's K/V into its pool pages and
   yields the first sampled token; from then on it only rides the shared
   decode step.
@@ -62,7 +68,11 @@ Telemetry: decode.requests/rejects/deadline_expired (admission),
 decode.prefills / prefill_tokens / steps / tokens / retired / errors /
 kv_refusals / kv_pages_allocated / kv_pages_freed counters,
 decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
-histogram, decode.active_slots + decode.queue_depth +
+histogram; for a model with ring layers decode.rows_past_window and
+decode.kv_tokens_attended (keys read a step, over rows and layers: a ring
+layer reads min(context, window)), and whatever counters the model's step
+program returns beside its tokens (``ServedModel.step_counters``: the
+routed-expert counts of models/afmoe.py), fetched in the step's one fetch; decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
 section and /v1/stats. Every loop iteration that runs a step also records
 its phases, which add up to it: decode.loop_ms = decode.admit_ms +
@@ -85,16 +95,11 @@ import numpy as np
 from ..core import costmodel, faults, incidents, telemetry
 from ..core import flags as _flags
 from ..core.flags import flag as _flag
-from ..models.decoder_lm import (DecoderLMConfig,
-                                 build_chunk_prefill_program,
-                                 build_prefill_program,
-                                 build_step_program, decoder_lm_params,
-                                 quantize_decoder_lm_params)
 from .admission import (AdmissionQueue, DeadlineExceededError,
                         EngineClosedError, InferenceRequest,
                         KVCacheExhaustedError, ServingError)
 from .health import DRAINING, READY, STOPPED, HealthState
-from .kv_cache import KVPagePool
+from .kv_cache import PagedKVCache
 from .prefix_store import PrefixStore
 
 
@@ -127,6 +132,7 @@ class DecodeConfig:
                  buckets: Optional[Sequence[int]] = None,
                  page_size: Optional[int] = None,
                  kv_pages: Optional[int] = None,
+                 kv_ring_pages: Optional[int] = None,
                  max_queue_depth: Optional[int] = None,
                  default_deadline_ms: Optional[float] = None,
                  max_new_tokens: Optional[int] = None,
@@ -157,6 +163,10 @@ class DecodeConfig:
                              else page_size)
         self.kv_pages = int(_flag("decode_kv_pages") if kv_pages is None
                             else kv_pages)
+        # pages of the ring class, for a model with window layers
+        # (kv_cache.PagedKVCache); None: a ring for every slot
+        self.kv_ring_pages = None if kv_ring_pages is None \
+            else int(kv_ring_pages)
         self.max_queue_depth = int(
             _flag("decode_max_queue_depth") if max_queue_depth is None
             else max_queue_depth)
@@ -211,7 +221,7 @@ class GenerationRequest(InferenceRequest):
                  "eos_id", "tokens", "token_walls", "t_submit", "t_first",
                  "pages", "table_row", "pos_next", "last_token",
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
-                 "stop_at_eos")
+                 "stop_at_eos", "ring_pages", "ring_row", "first_logits")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -242,6 +252,12 @@ class GenerationRequest(InferenceRequest):
         # engine-side slot state (worker-thread-owned once admitted)
         self.pages: List[int] = []
         self.table_row: Optional[np.ndarray] = None
+        # the ring class's pages and table row (window layers), if any
+        self.ring_pages: List[int] = []
+        self.ring_row: Optional[np.ndarray] = None
+        # False, or True to keep the prefill's logits row (float32) here:
+        # how a check compares logits where the engine gives them out
+        self.first_logits: Any = False
         self.pos_next = 0
         self.last_token = 0
         # prefix-store block hashes this request holds a reference on
@@ -335,22 +351,39 @@ class DecodeEngine:
     ``submit``/``generate`` → ``close(drain=True)``. One worker thread
     owns the slot array, the pools and every program run."""
 
-    def __init__(self, model_cfg: DecoderLMConfig, params: Dict[str, Any],
+    def __init__(self, model_cfg: Any, params: Dict[str, Any],
                  config: Optional[DecodeConfig] = None, version: int = 0):
         import jax.numpy as jnp
 
         self.model_cfg = model_cfg
+        self.model = model_cfg.served()
         self.config = config or DecodeConfig()
+        params = self.model.prepare_params(params, self.config.weight_quant)
         if self.config.weight_quant == "int8":
-            params = quantize_decoder_lm_params(params, model_cfg)
             telemetry.counter_add("decode.int8_weight_tensors",
                                   sum(1 for n in params
                                       if n.endswith("_w_i8")))
         self._params = {n: jnp.asarray(v) for n, v in params.items()}
-        self.pool = KVPagePool(model_cfg.n_layers, self.config.kv_pages,
-                               self.config.page_size, model_cfg.d_model)
-        self._pools = self.pool.make_arrays()
+        # one pool a class of pages; `pool` is the context class, which is
+        # the only one a model without window layers has
+        self.kv = PagedKVCache(self.model.cache_layout(),
+                               self.config.page_size, self.config.kv_pages,
+                               self.config.kv_ring_pages,
+                               dtype=self.model.kv_dtype,
+                               slots=self.config.max_slots)
+        self.pool = self.kv.context
+        if self.kv.ring is not None and (
+                self.config.prefix_cache or self.config.role != "unified"):
+            # the prefix store shares a prompt's full pages between
+            # requests and a shipment installs a prompt's pages; a ring is
+            # a slot's own and is overwritten as its window slides
+            raise ValueError(
+                "a model with ring (window) layers runs unified and "
+                "without the prefix store: neither the prefix store nor "
+                "disaggregated prefill handles ring pages yet")
+        self._pools = self.kv.make_arrays()
         self._mp = -(-model_cfg.max_seq_len // self.config.page_size)
+        self._feed_names: Dict[Any, Any] = {}   # (phase, bucket) -> names
         self.queue = AdmissionQueue(self.config.max_queue_depth,
                                     self.config.default_deadline_ms,
                                     metric_prefix="decode")
@@ -381,7 +414,8 @@ class DecodeEngine:
                stop_at_eos: bool = True,
                request_id: Optional[str] = None,
                prior_tokens: Optional[Sequence[int]] = None,
-               rng_state: Optional[Any] = None) -> GenerationRequest:
+               rng_state: Optional[Any] = None,
+               keep_first_logits: bool = False) -> GenerationRequest:
         """Enqueue one generation (non-blocking). ``prompt`` is a 1-D
         int token-id array. Raises ValueError (malformed / over the
         model length), KVCacheExhaustedError (can never fit the KV
@@ -392,7 +426,9 @@ class DecodeEngine:
         journaled session after its replica died: the engine prefills
         prompt+prior (prefix-hit or chunked cold re-prefill — bitwise
         the same KV either way), restores the sampler RNG mid-stream
-        and generates only the remaining ``max_new_tokens``."""
+        and generates only the remaining ``max_new_tokens``.
+        ``keep_first_logits`` leaves the prefill's float32 logits row on
+        the request (``first_logits``) beside its tokens."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -411,12 +447,13 @@ class DecodeEngine:
                 f"({max_new_tokens}) exceeds the model's max_seq_len "
                 f"({self.model_cfg.max_seq_len})")
         # typed would-OOM refusal BEFORE the request enters the queue
-        self.pool.check_fits(total)
+        self.kv.check_fits(total)
         req = GenerationRequest(
             prompt, max_new_tokens, self.queue.deadline_for(deadline_ms),
             temperature=temperature, seed=seed,
             eos_id=self.model_cfg.eos_id if stop_at_eos else None,
             session_id=request_id, prior=prior)
+        req.first_logits = bool(keep_first_logits)
         if rng_state is not None:
             from .session import unpack_rng_state
 
@@ -462,7 +499,7 @@ class DecodeEngine:
         out["model_version"] = self.version
         out["status"] = self.health.state
         out["role"] = self.config.role
-        out["kv_cache"] = self.pool.stats()
+        out["kv_cache"] = self.kv.stats()
         if self.prefix_store is not None:
             out["prefix_store"] = self.prefix_store.stats()
             out["prefix_store"].update(
@@ -558,18 +595,18 @@ class DecodeEngine:
         from ..core.executor import run_block
         from .sampling import sample_tokens
 
-        cfg, cc = self.model_cfg, self.config
-        if phase == "step":
-            program, _feeds, _fetches = build_step_program(
-                cfg, bucket, cc.kv_pages, cc.page_size, cc.weight_quant)
-        elif phase == "chunk":
-            program, _feeds, _fetches = build_chunk_prefill_program(
-                cfg, 1, bucket, cc.kv_pages, cc.page_size, cc.weight_quant)
-        else:
-            program, _feeds, _fetches = build_prefill_program(
-                cfg, 1, bucket, cc.kv_pages, cc.page_size, cc.weight_quant)
+        cc = self.config
+        build = {"step": self.model.build_step_program,
+                 "chunk": self.model.build_chunk_prefill_program,
+                 "prefill": self.model.build_prefill_program}[phase]
+        program, feeds, fetches = build(bucket, self.kv, cc.weight_quant)
+        # a program's arguments are part of its compiled form: it is fed
+        # exactly the names its builder lists (and the sampler's)
+        self._feed_names[key] = tuple(feeds) + (
+            ("sampling",) if phase == "step" else ())
         block = program.global_block()
         pool_names = sorted(self._pools)
+        counted = "step_counts" in fetches
 
         def fn(params, pools, feed):
             env = dict(params)
@@ -580,6 +617,13 @@ class DecodeEngine:
             if phase == "step":
                 out = sample_tokens(out, feed["sampling"][:, 0],
                                     feed["sampling"][:, 1])
+                if counted:
+                    # the model's counters ride behind the tokens: one
+                    # int32 vector, one fetch
+                    import jax.numpy as jnp
+
+                    out = jnp.concatenate(
+                        [out, env["step_counts"].astype(jnp.int32)])
             return out, {n: env[n + "_out"] for n in pool_names}
 
         # the program's own name in the profiler's trace and in the compile
@@ -609,7 +653,7 @@ class DecodeEngine:
         # compile through a throwaway execution on zero feeds (the
         # predictor's measure-through-first-run discipline); FRESH pool
         # arrays, because donation consumes whatever is passed in
-        entry(self._params, self.pool.make_arrays(), feed)
+        entry(self._params, self.kv.make_arrays(), feed)
         ms = round((time.perf_counter() - t0) * 1e3, 3)
         telemetry.counter_add("decode.compiles", 1)
         telemetry.event("compile", "decode", ms,
@@ -619,27 +663,31 @@ class DecodeEngine:
                          "cache_size": len(self._entries)})
         return entry
 
-    def _zero_feed(self, phase: str, bucket: int):
+    def _feed(self, phase: str, bucket: int, parts: Dict[str, Any]):
+        """The program's feed: of ``parts`` (host arrays by feed name) the
+        names its builder listed, as device arrays."""
         import jax.numpy as jnp
 
+        return {n: jnp.asarray(parts[n])
+                for n in self._feed_names[(phase, bucket)]}
+
+    def _zero_feed(self, phase: str, bucket: int):
+        rows = bucket if phase == "step" else 1
+        tables = {"page_table": np.zeros((rows, self._mp), np.int32),
+                  "ring_table": np.zeros((rows, self.kv.ring_slot_pages),
+                                         np.int32)}
         if phase == "step":
-            return {"tokens": jnp.zeros((bucket,), jnp.int32),
-                    "positions": jnp.zeros((bucket,), jnp.int32),
-                    "page_table": jnp.zeros((bucket, self._mp), jnp.int32),
-                    "sampling": jnp.zeros((bucket, 2), jnp.float32)}
+            return self._feed(phase, bucket, dict(
+                tables, tokens=np.zeros((bucket,), np.int32),
+                positions=np.zeros((bucket,), np.int32),
+                sampling=np.zeros((bucket, 2), np.float32)))
         oh = np.zeros((1, bucket), np.float32)
         oh[0, 0] = 1.0
-        if phase == "chunk":
-            return {"tokens": jnp.zeros((1, bucket), jnp.int32),
-                    "positions": jnp.zeros((1, bucket), jnp.int32),
-                    "chunk_start": jnp.zeros((1,), jnp.int32),
-                    "lengths": jnp.ones((1,), jnp.int32),
-                    "last_onehot": jnp.asarray(oh),
-                    "page_table": jnp.zeros((1, self._mp), jnp.int32)}
-        return {"tokens": jnp.zeros((1, bucket), jnp.int32),
-                "lengths": jnp.ones((1,), jnp.int32),
-                "last_onehot": jnp.asarray(oh),
-                "page_table": jnp.zeros((1, self._mp), jnp.int32)}
+        return self._feed(phase, bucket, dict(
+            tables, tokens=np.zeros((1, bucket), np.int32),
+            positions=np.zeros((1, bucket), np.int32),
+            chunk_start=np.zeros((1,), np.int32),
+            lengths=np.ones((1,), np.int32), last_onehot=oh))
 
     # -- scheduler loop ------------------------------------------------------
     def _loop(self):
@@ -720,16 +768,18 @@ class DecodeEngine:
                              else ServingError(
                                  f"prefix lookup failed: {e!r}"))
                     continue
-            need = self.pool.pages_for_tokens(
-                int(req.seq.size) + req.max_new_tokens) - len(hashes)
+            need, ring_need = self.kv.pages_for_tokens(
+                int(req.seq.size) + req.max_new_tokens)
+            need -= len(hashes)
             try:
-                pages = self.pool.try_alloc(need)
-                if not pages and self.prefix_store is not None:
+                # seated only if BOTH classes of pages can seat it
+                got = self.kv.try_alloc(need, ring_need)
+                if got is None and self.prefix_store is not None:
                     # ledger pressure: reclaim idle refcount-zero
                     # chains LRU-first, then retry once
                     short = need - self.pool.free_pages()
                     if short > 0 and self.prefix_store.reclaim(short):
-                        pages = self.pool.try_alloc(need)
+                        got = self.kv.try_alloc(need, ring_need)
             except Exception as e:   # injected decode.kv_alloc fault
                 if hashes:
                     self.prefix_store.release(hashes)
@@ -738,18 +788,20 @@ class DecodeEngine:
                 req.fail(e if isinstance(e, ServingError) else ServingError(
                     f"KV page allocation failed: {e!r}"))
                 continue
-            if not pages:
+            if got is None:
                 if hashes:
                     self.prefix_store.release(hashes)
                 unseated.append(req)   # no headroom NOW — wait for frees
                 continue
+            pages, req.ring_pages = got
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
             try:
                 self._prefill(req, pages, hashes, shared)
             except BaseException as e:
-                self.pool.free(req.pages if req.pages else pages)
-                req.pages = []
+                self.kv.free(req.pages if req.pages else pages,
+                             req.ring_pages)
+                req.pages, req.ring_pages = [], []
                 if req.shared_blocks:
                     self.prefix_store.release(req.shared_blocks)
                     req.shared_blocks = []
@@ -771,23 +823,24 @@ class DecodeEngine:
         if self.prefix_store is not None:
             return self._prefill_chunked(req, pages, hashes or [],
                                          shared or [])
-        import jax.numpy as jnp
-
         L = int(req.seq.size)
         bucket = next(b for b in self.config.prefill_buckets if b >= L)
         req.pages = pages
         row = np.zeros(self._mp, np.int32)
         row[:len(pages)] = pages
         req.table_row = row
+        req.ring_row = np.zeros(self.kv.ring_slot_pages, np.int32)
+        req.ring_row[:len(req.ring_pages)] = req.ring_pages
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :L] = req.seq
         oh = np.zeros((1, bucket), np.float32)
         oh[0, L - 1] = 1.0
-        feed = {"tokens": jnp.asarray(tokens),
-                "lengths": jnp.asarray([L], jnp.int32),
-                "last_onehot": jnp.asarray(oh),
-                "page_table": jnp.asarray(row[None, :])}
         entry = self._entry("prefill", bucket)
+        feed = self._feed("prefill", bucket, {
+            "tokens": tokens, "lengths": np.asarray([L], np.int32),
+            "last_onehot": oh,
+            "positions": np.arange(bucket, dtype=np.int32)[None, :],
+            "page_table": row[None, :], "ring_table": req.ring_row[None, :]})
         with telemetry.timer("decode.prefill_ms"):
             logits, self._pools = entry(self._params, self._pools, feed)
             logits = np.asarray(logits)
@@ -810,8 +863,6 @@ class DecodeEngine:
         first-token logits — always recomputed); afterwards the store
         adopts this prompt's full pages so the next request shares
         them."""
-        import jax.numpy as jnp
-
         L = int(req.seq.size)
         P = self.config.page_size
         k = len(hashes)
@@ -821,6 +872,7 @@ class DecodeEngine:
         row[:k] = shared
         row[k:k + len(pages)] = pages
         req.table_row = row
+        req.ring_row = np.zeros(self.kv.ring_slot_pages, np.int32)
         n_chunks = -(-L // P)
         entry = self._entry("chunk", P)
         logits = None
@@ -835,12 +887,12 @@ class DecodeEngine:
                 oh = np.zeros((1, P), np.float32)
                 if ci == n_chunks - 1:
                     oh[0, L - 1 - lo] = 1.0
-                feed = {"tokens": jnp.asarray(tokens),
-                        "positions": jnp.asarray(positions[None, :]),
-                        "chunk_start": jnp.asarray([lo], jnp.int32),
-                        "lengths": jnp.asarray([n], jnp.int32),
-                        "last_onehot": jnp.asarray(oh),
-                        "page_table": jnp.asarray(row[None, :])}
+                feed = self._feed("chunk", P, {
+                    "tokens": tokens, "positions": positions[None, :],
+                    "chunk_start": np.asarray([lo], np.int32),
+                    "lengths": np.asarray([n], np.int32),
+                    "last_onehot": oh, "page_table": row[None, :],
+                    "ring_table": req.ring_row[None, :]})
                 logits, self._pools = entry(self._params, self._pools,
                                             feed)
             logits = np.asarray(logits)
@@ -869,8 +921,6 @@ class DecodeEngine:
         versioned per-page-CRC shipment, free the pages, resolve with
         the bytes. ``disagg.ship`` faults inject here — a failure is a
         per-request error; the pool stays clean."""
-        import jax.numpy as jnp
-
         from . import disagg
 
         pages: List[int] = []
@@ -890,11 +940,12 @@ class DecodeEngine:
             tokens[0, :L] = req.prompt
             oh = np.zeros((1, bucket), np.float32)
             oh[0, L - 1] = 1.0
-            feed = {"tokens": jnp.asarray(tokens),
-                    "lengths": jnp.asarray([L], jnp.int32),
-                    "last_onehot": jnp.asarray(oh),
-                    "page_table": jnp.asarray(row[None, :])}
             entry = self._entry("prefill", bucket)
+            feed = self._feed("prefill", bucket, {
+                "tokens": tokens, "lengths": np.asarray([L], np.int32),
+                "last_onehot": oh,
+                "positions": np.arange(bucket, dtype=np.int32)[None, :],
+                "page_table": row[None, :]})
             with telemetry.timer("decode.prefill_ms"):
                 logits, self._pools = entry(self._params, self._pools,
                                             feed)
@@ -953,6 +1004,7 @@ class DecodeEngine:
             row = np.zeros(self._mp, np.int32)
             row[:len(req.pages)] = req.pages
             req.table_row = row
+            req.ring_row = np.zeros(0, np.int32)
             telemetry.counter_add("disagg.installs", 1)
             telemetry.counter_add("decode.prefills", 1)
             telemetry.observe("decode.queue_wait_ms",
@@ -975,8 +1027,6 @@ class DecodeEngine:
         """DECODE phase: one fixed-shape step over the padded slot
         array; per-request deadlines checked here, at step granularity.
         The phases' times go into ``it`` (see ``_loop``)."""
-        import jax.numpy as jnp
-
         delay_ms = float(_flag("decode_step_delay_ms"))
         if delay_ms > 0:   # chaos/bench pacing knob — off by default
             time.sleep(delay_ms / 1e3)
@@ -993,6 +1043,7 @@ class DecodeEngine:
         active = self._active
         bucket = self.config.bucket(len(active))
         faults.maybe_fail("decode.step", active=len(active), bucket=bucket)
+        entry = self._entry("step", bucket)
         with telemetry.timer("decode.feed_ms", into=it):
             tokens = np.zeros(bucket, np.int32)
             positions = np.zeros(bucket, np.int32)
@@ -1001,23 +1052,37 @@ class DecodeEngine:
             # request's own stream gives one draw per sampled token, here,
             # in token order (a first token's draw came before, on the host)
             sampling = np.zeros((bucket, 2), np.float32)
+            ring = np.zeros((bucket, self.kv.ring_slot_pages), np.int32)
             for i, req in enumerate(active):
                 tokens[i] = req.last_token
                 positions[i] = req.pos_next
                 table[i] = req.table_row
+                ring[i] = req.ring_row
                 if req.temperature > 0.0:
                     sampling[i] = (req.temperature,
                                    req._rng.random_sample())
-            feed = {"tokens": jnp.asarray(tokens),
-                    "positions": jnp.asarray(positions),
-                    "page_table": jnp.asarray(table),
-                    "sampling": jnp.asarray(sampling)}
-        entry = self._entry("step", bucket)
+            feed = self._feed("step", bucket, {
+                "tokens": tokens, "positions": positions,
+                "page_table": table, "ring_table": ring,
+                "sampling": sampling})
         with telemetry.timer("decode.step_ms", into=it):
             chosen, self._pools = entry(self._params, self._pools, feed)
-            # [bucket] int32; the host waits out the step program here
+            # [bucket] int32 (and the model's counters behind them); the
+            # host waits out the step program here
             with telemetry.timer("decode.fetch_ms", into=it):
                 chosen = np.asarray(chosen)
+        for name, value in zip(self.model.step_counters, chosen[bucket:]):
+            telemetry.counter_add(name, int(value))
+        if self.kv.ring is not None:
+            # keys a step reads: a ring layer's rows read their window
+            ctx = positions[:len(active)].astype(np.int64) + 1
+            rings = len(self.kv.ring.layers)
+            telemetry.counter_add("decode.rows_past_window",
+                                  int(np.sum(ctx > self.kv.window)))
+            telemetry.counter_add(
+                "decode.kv_tokens_attended",
+                int(len(self.kv.context.layers) * ctx.sum() + rings
+                    * np.minimum(ctx, self.kv.window).sum()))
         telemetry.counter_add("decode.steps", 1)
         telemetry.counter_add("decode.tokens", len(active))
         telemetry.counter_add("decode.tokens_device_sampled", len(active))
@@ -1070,6 +1135,8 @@ class DecodeEngine:
         """A request's first token, chosen on the host from the logits row
         its prefill handed over."""
         tok = req.sample(logits_row)
+        if req.first_logits is True:
+            req.first_logits = np.array(logits_row, np.float32)
         telemetry.counter_add("decode.tokens_host_sampled", 1)
         self._accept_token(req, tok)
 
@@ -1090,9 +1157,9 @@ class DecodeEngine:
         prefix-store references and resolve/fail it — finished
         sequences leave WITHOUT draining the batch. Shared pages stay
         resident in the store (that is the cache)."""
-        if req.pages:
-            self.pool.free(req.pages)
-            req.pages = []
+        if req.pages or req.ring_pages:
+            self.kv.free(req.pages, req.ring_pages)
+            req.pages, req.ring_pages = [], []
         if req.shared_blocks:
             self.prefix_store.release(req.shared_blocks)
             req.shared_blocks = []
@@ -1118,8 +1185,10 @@ def decode_engine_from_dir(model_dir: str,
 
 
 def demo_engine(config: Optional[DecodeConfig] = None,
-                model_cfg: Optional[DecoderLMConfig] = None,
+                model_cfg: Optional[Any] = None,
                 seed: int = 0) -> DecodeEngine:
     """Deterministically-initialised small LM engine (tests/bench)."""
+    from ..models.decoder_lm import DecoderLMConfig, decoder_lm_params
+
     cfg = model_cfg or DecoderLMConfig()
     return DecodeEngine(cfg, decoder_lm_params(cfg, seed), config=config)

@@ -27,11 +27,33 @@ tools/chaos_check.py --decode).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List
+from dataclasses import dataclass
+from typing import Any, Dict, List, Optional, Tuple
 
 from ..core import costmodel, faults, telemetry
 from ..core.analysis import lockdep
 from .admission import KVCacheExhaustedError
+
+
+@dataclass(frozen=True)
+class LayerCache:
+    """What one layer keeps of a sequence: K and V of `kv_dim` a token, in
+    a context's pages (`window` 0: every token of the request, pages held
+    for its whole life) or in a ring (`window` > 0: the last `window`
+    tokens, in a fixed ring of ``window / page + 1`` pages a slot that the
+    sliding window overwrites)."""
+    kv_dim: int
+    window: int = 0
+
+    @property
+    def ring(self) -> bool:
+        return self.window > 0
+
+
+def ring_pages_per_slot(window: int, page_size: int) -> int:
+    """Pages of a slot's ring: the window, and one page more, so that the
+    page being written never holds a key the window still reaches."""
+    return -(-int(window) // int(page_size)) + 1
 
 
 class KVPagePool:
@@ -40,10 +62,21 @@ class KVPagePool:
     The jax arrays themselves (``pools``: kv_k_<l>/kv_v_<l> ->
     [num_pages, page_size, kv_dim]) are owned and threaded/donated by
     the engine's step function; this object owns the PAGE IDS and the
-    ledger accounting. Page 0 is never handed out."""
+    ledger accounting. Page 0 is never handed out.
+
+    One pool is one CLASS of pages: every layer of it shares the page ids
+    (a request's page j is page j of each of the pool's layers). A model
+    whose layers all hold a context's pages has one pool over layers
+    0..n_layers-1, as ever; `PagedKVCache` below puts a second pool beside
+    it for the layers that keep a ring. ``layers`` names the model's layer
+    indices this pool holds (the arrays' names) and ``kv_dims`` their
+    widths; ``klass`` names the class in the ledger
+    (``mem.serving.kv_pool_bytes.<klass>``) when there is more than one."""
 
     def __init__(self, n_layers: int, num_pages: int, page_size: int,
-                 kv_dim: int, dtype: str = "float32"):
+                 kv_dim: int, dtype: str = "float32",
+                 layers: Optional[List[int]] = None,
+                 kv_dims: Optional[List[int]] = None, klass: str = ""):
         if num_pages < 2:
             raise ValueError(f"KV pool needs >= 2 pages (page 0 is the "
                              f"reserved scratch page), got {num_pages}")
@@ -52,6 +85,11 @@ class KVPagePool:
         self.page_size = int(page_size)
         self.kv_dim = int(kv_dim)
         self.dtype = dtype
+        self.layers = list(range(self.n_layers)) if layers is None \
+            else [int(i) for i in layers]
+        self.kv_dims = [self.kv_dim] * self.n_layers if kv_dims is None \
+            else [int(d) for d in kv_dims]
+        self.klass = klass
         self._lock = lockdep.lock("serving.kv_pool")
         self._free: List[int] = list(range(1, self.num_pages))
         self._lent: set = set()
@@ -60,21 +98,26 @@ class KVPagePool:
 
         itemsize = np.dtype(dtype).itemsize
         # keys + values, every layer
-        self.pool_bytes = (2 * self.n_layers * self.num_pages *
-                           self.page_size * self.kv_dim * itemsize)
+        self.pool_bytes = (2 * self.num_pages * self.page_size
+                           * sum(self.kv_dims) * itemsize)
         self._page_bytes = self.pool_bytes // self.num_pages
-        telemetry.gauge_set("mem.serving.kv_pool_bytes", self.pool_bytes)
-        telemetry.gauge_set("mem.serving.kv_used_bytes", 0)
-        telemetry.gauge_set("mem.serving.kv_high_water_bytes", 0)
+        if klass:
+            telemetry.gauge_set(f"mem.serving.kv_pool_bytes.{klass}",
+                                self.pool_bytes)
+        else:
+            telemetry.gauge_set("mem.serving.kv_pool_bytes",
+                                self.pool_bytes)
+            telemetry.gauge_set("mem.serving.kv_used_bytes", 0)
+            telemetry.gauge_set("mem.serving.kv_high_water_bytes", 0)
         costmodel.refresh_ledger()
 
     def make_arrays(self) -> Dict[str, Any]:
         """Fresh zeroed device pools keyed by the program feed names."""
         import jax.numpy as jnp
 
-        shape = (self.num_pages, self.page_size, self.kv_dim)
         out = {}
-        for i in range(self.n_layers):
+        for i, dim in zip(self.layers, self.kv_dims):
+            shape = (self.num_pages, self.page_size, dim)
             out[f"kv_k_{i}"] = jnp.zeros(shape, self.dtype)
             out[f"kv_v_{i}"] = jnp.zeros(shape, self.dtype)
         return out
@@ -123,10 +166,11 @@ class KVPagePool:
             self._high_water_pages = max(self._high_water_pages, used)
             hw = self._high_water_pages
         telemetry.counter_add("decode.kv_pages_allocated", n)
-        telemetry.gauge_set("mem.serving.kv_used_bytes",
-                            used * self._page_bytes)
-        telemetry.gauge_set("mem.serving.kv_high_water_bytes",
-                            hw * self._page_bytes)
+        if not self.klass:
+            telemetry.gauge_set("mem.serving.kv_used_bytes",
+                                used * self._page_bytes)
+            telemetry.gauge_set("mem.serving.kv_high_water_bytes",
+                                hw * self._page_bytes)
         return pages
 
     def free(self, pages: List[int]):
@@ -142,8 +186,9 @@ class KVPagePool:
             self._lent.difference_update(pages)
             used = self.capacity_pages - len(self._free)
         telemetry.counter_add("decode.kv_pages_freed", len(pages))
-        telemetry.gauge_set("mem.serving.kv_used_bytes",
-                            used * self._page_bytes)
+        if not self.klass:
+            telemetry.gauge_set("mem.serving.kv_used_bytes",
+                                used * self._page_bytes)
 
     # -- invariants ----------------------------------------------------------
     def audit(self, owned: List[int] = None) -> List[str]:
@@ -204,3 +249,118 @@ class KVPagePool:
                 "used_bytes": (self.capacity_pages - free) *
                 self._page_bytes,
                 "high_water_bytes": hw * self._page_bytes}
+
+
+class PagedKVCache:
+    """The engine's cache: the owner of one pool a class of pages.
+
+    ``layout`` gives each layer's `LayerCache`. Layers that hold a
+    context's pages share the ``context`` pool (page ids, one table row a
+    request); layers that keep a ring share the ``ring`` pool, every slot
+    a fixed ring of `ring_pages_per_slot` pages. A model without ring
+    layers has ``ring is None`` and its context pool is exactly the pool
+    it had before there were classes; without ``ring_pages`` the ring
+    pool holds one ring for each of ``slots``. A request is seated only if BOTH
+    classes can seat it (`try_alloc` takes from both or from neither),
+    and `audit` holds both to their invariants."""
+
+    CONTEXT, RING = "context", "ring"
+
+    def __init__(self, layout: List[LayerCache], page_size: int,
+                 context_pages: int, ring_pages: Optional[int] = None,
+                 dtype: str = "float32", slots: int = 0):
+        self.layout = list(layout)
+        self.page_size = int(page_size)
+        ctx = [i for i, lc in enumerate(layout) if not lc.ring]
+        rings = [i for i, lc in enumerate(layout) if lc.ring]
+        if not ctx:
+            raise ValueError("a model needs at least one layer that holds "
+                             "its context's pages")
+        windows = {layout[i].window for i in rings}
+        if len(windows) > 1:
+            raise ValueError(f"one ring class, one window: got {windows}")
+        self.window = windows.pop() if windows else 0
+        self.ring_slot_pages = ring_pages_per_slot(
+            self.window, page_size) if rings else 0
+        self.context = KVPagePool(
+            len(ctx), context_pages, page_size, layout[ctx[0]].kv_dim,
+            dtype, layers=ctx, kv_dims=[layout[i].kv_dim for i in ctx],
+            klass=self.CONTEXT if rings else "")
+        self.ring: Optional[KVPagePool] = None
+        if rings:
+            if ring_pages is None:     # a ring for every slot, and page 0
+                ring_pages = slots * self.ring_slot_pages + 1
+            self.ring = KVPagePool(
+                len(rings), ring_pages, page_size, layout[rings[0]].kv_dim,
+                dtype, layers=rings,
+                kv_dims=[layout[i].kv_dim for i in rings], klass=self.RING)
+            telemetry.gauge_set("mem.serving.kv_pool_bytes",
+                                self.pool_bytes)
+
+    @property
+    def pool_bytes(self) -> int:
+        return self.context.pool_bytes + (self.ring.pool_bytes
+                                          if self.ring else 0)
+
+    def make_arrays(self) -> Dict[str, Any]:
+        out = self.context.make_arrays()
+        if self.ring is not None:
+            out.update(self.ring.make_arrays())
+        return out
+
+    def pages_for_tokens(self, tokens: int) -> Tuple[int, int]:
+        """(context pages, ring pages) a request of `tokens` needs: the
+        ring never more than a slot's ring."""
+        need = self.context.pages_for_tokens(tokens)
+        return need, min(need, self.ring_slot_pages)
+
+    def check_fits(self, tokens: int):
+        """Typed refusal of a request that could never be seated, by
+        whichever class it is that cannot hold it."""
+        self.context.check_fits(tokens)
+        ring_need = self.pages_for_tokens(tokens)[1]
+        if self.ring is not None and ring_need > self.ring.capacity_pages:
+            telemetry.counter_add("decode.kv_refusals", 1, klass=self.RING)
+            raise KVCacheExhaustedError(
+                f"request needs a ring of {ring_need} pages but the ring "
+                f"pool holds {self.ring.capacity_pages}")
+
+    def try_alloc(self, context_need: int, ring_need: int
+                  ) -> Optional[Tuple[List[int], List[int]]]:
+        """Pages of both classes, or None when either class cannot seat
+        the request now (nothing is then taken from the other)."""
+        pages = self.context.try_alloc(context_need)
+        if not pages:
+            return None
+        if self.ring is None or ring_need == 0:
+            return pages, []
+        try:
+            ring = self.ring.try_alloc(ring_need)
+        except BaseException:     # an injected decode.kv_alloc fault
+            self.context.free(pages)
+            raise
+        if not ring:
+            self.context.free(pages)
+            return None
+        return pages, ring
+
+    def free(self, pages: List[int], ring: List[int]):
+        self.context.free(pages)
+        if ring:
+            self.ring.free(ring)
+
+    def audit(self, owned: List[int] = None,
+              owned_ring: List[int] = None) -> List[str]:
+        problems = self.context.audit(owned)
+        if self.ring is not None:
+            problems += [f"ring: {p}" for p in self.ring.audit(owned_ring)]
+        return problems
+
+    def stats(self) -> Dict[str, Any]:
+        out = self.context.stats()
+        if self.ring is not None:
+            out["ring"] = dict(self.ring.stats(),
+                               pages_per_slot=self.ring_slot_pages,
+                               window=self.window)
+            out["pool_bytes_total"] = self.pool_bytes
+        return out

@@ -1,0 +1,65 @@
+"""The seam between `DecodeEngine` and the model it serves.
+
+The engine owns admission, batching, the loop, the device sampler, the
+pools and every ``decode.*`` span; a model module owns its block. What
+the engine asks of a model is this class: its three program builders
+(decode step, whole-prompt prefill, page-chunked prefill), how its
+parameters are laid out and prepared, and what each layer keeps of a
+sequence (`kv_cache.LayerCache`: the width of its K/V and whether its
+pages are a context's or a ring). models/decoder_lm.py and
+models/afmoe.py each give one; ``cfg.served()`` builds it.
+
+A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
+the program reads beside parameters and pools, out of what the engine can
+give:
+
+  step     tokens [B], positions [B], page_table [B, MP], ring_table [B, R]
+  prefill  tokens [1, S], lengths [1], last_onehot [1, S], positions [1, S],
+           page_table [1, MP], ring_table [1, R]
+  chunk    tokens, positions [1, C], chunk_start [1], lengths [1],
+           last_onehot [1, C], page_table, ring_table
+
+and the engine feeds exactly those (a program's arguments are part of its
+compiled form). Every program writes ``logits`` and ``kv_k_<l>_out`` /
+``kv_v_<l>_out`` for each layer; a step program may also write
+``step_counts``, int32 [len(step_counters)], which the engine fetches in
+the same fetch as the step's tokens and adds to the telemetry counters
+named in ``step_counters``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Tuple
+
+from .kv_cache import LayerCache, PagedKVCache
+
+
+class ServedModel:
+    kv_dtype: str = "float32"
+    step_counters: Tuple[str, ...] = ()
+
+    def __init__(self, cfg: Any):
+        self.cfg = cfg          # max_seq_len, eos_id, vocab_size
+
+    def cache_layout(self) -> List[LayerCache]:
+        raise NotImplementedError
+
+    def prepare_params(self, params: Dict[str, Any],
+                       weight_quant: str) -> Dict[str, Any]:
+        """The parameter dict as the programs read it (e.g. quantized)."""
+        if weight_quant != "none":
+            raise ValueError(f"{type(self).__name__} has no "
+                             f"weight_quant {weight_quant!r}")
+        return params
+
+    def build_step_program(self, batch: int, kv: PagedKVCache,
+                           weight_quant: str):
+        raise NotImplementedError
+
+    def build_prefill_program(self, prompt_len: int, kv: PagedKVCache,
+                              weight_quant: str):
+        raise NotImplementedError
+
+    def build_chunk_prefill_program(self, chunk_len: int, kv: PagedKVCache,
+                                    weight_quant: str):
+        raise NotImplementedError

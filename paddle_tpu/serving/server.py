@@ -373,6 +373,15 @@ class _Handler(BaseHTTPRequestHandler):
         self._reply(code, payload, headers)
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # the listen backlog. Callers connect in bursts (a closed loop's 96
+    # callers all at once); with socketserver's default of 5 the rest of
+    # a burst waits out SYN retries or is reset ~10 s later: 1 to 3
+    # requests of a run's first seconds answered with no response at all
+    # (my chip runs, PR 28). Sized past any admission queue in use.
+    request_queue_size = 1024
+
+
 class ServingHTTPServer:
     """Bound-but-not-yet-serving HTTP wrapper; start()/shutdown() own the
     acceptor thread. port=0 binds an ephemeral port (tests, CI)."""
@@ -385,7 +394,7 @@ class ServingHTTPServer:
                              "decode_engine")
         self.engine = engine
         self.decode_engine = decode_engine
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
+        self._httpd = _HTTPServer((host, port), _Handler)
         self._httpd.daemon_threads = True
         self._httpd.engine = engine
         self._httpd.decode_engine = decode_engine

@@ -90,6 +90,38 @@ def test_paged_attention_multi_chunk(one_chip, tpu_mode):
         ((pool, page, n * hd), F32), ((b, mp), I32), ((b,), I32))
 
 
+@pytest.mark.parametrize("ring,pages,table", [(False, 10241, 160),
+                                              (True, 4161, 65)])
+def test_paged_gqa_attention_at_the_trinity_share(one_chip, tpu_mode, ring,
+                                                  pages, table):
+    """6 query heads on 1 K/V head of 128, bfloat16 pages of 64 tokens, 64
+    rows: a full layer over 160 context pages a row, a window layer over
+    its ring of 65."""
+    from paddle_tpu.ops.pallas.paged_gqa_attention import \
+        paged_gqa_decode_attention
+
+    _compile(lambda q, pk, pv, t, p: paged_gqa_decode_attention(
+        q, pk, pv, t, p, num_heads=6, num_kv_heads=1, head_dim=128,
+        scale=128 ** -0.5, window=4096 if ring else 0, ring=ring),
+        one_chip, ((64, 768), F32), ((pages, 64, 128), BF16),
+        ((pages, 64, 128), BF16), ((64, table), I32), ((64,), I32))
+
+
+def test_routed_experts_grouped_product_at_the_trinity_share(one_chip):
+    """64 rows, top-4 of 256, experts 0-31 held at width 3072: the three
+    grouped products are the chip's ragged-dot kernel, not a dense product
+    over every expert."""
+    from paddle_tpu.parallel.moe import routed_experts_share
+
+    text = _compile(
+        lambda x, wr, b, w1, w3, w2: routed_experts_share(
+            x, wr, b, w1, w3, w2, top_k=4, held_lo=0, route_scale=2.448),
+        one_chip, ((64, 3072), F32), ((3072, 256), BF16), ((256,), F32),
+        ((32, 3072, 3072), BF16), ((32, 3072, 3072), BF16),
+        ((32, 3072, 3072), BF16))
+    assert "ragged-dot" in text
+
+
 def test_layer_norm_fwd_bwd(one_chip, tpu_mode):
     from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 

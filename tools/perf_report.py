@@ -414,6 +414,13 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
             gauges.get("mem.serving.kv_high_water_bytes") or 0)
         out["kv_used_bytes"] = int(
             gauges.get("mem.serving.kv_used_bytes") or 0)
+        # a model with window layers has two classes of pages
+        # (kv_cache.PagedKVCache): mem.serving.kv_pool_bytes.<class>
+        classes = {name.rsplit(".", 1)[1]: int(v)
+                   for name, v in gauges.items()
+                   if name.startswith("mem.serving.kv_pool_bytes.")}
+        if classes:
+            out["kv_pool_bytes_by_class"] = classes
     pages = cval("decode.kv_pages_allocated")
     if pages:
         out["kv_pages_allocated"] = int(pages)
@@ -1041,6 +1048,9 @@ def render(s, out=sys.stdout):
             w(f"kv page pool: {_fmt_num(dc['kv_pool_bytes'])} B "
               f"(high water {_fmt_num(dc['kv_high_water_bytes'])} B, "
               f"in use {_fmt_num(dc['kv_used_bytes'])} B)\n")
+        for klass, size in sorted(
+                dc.get("kv_pool_bytes_by_class", {}).items()):
+            w(f"  {klass} pages: {_fmt_num(size)} B\n")
         if "kv_pages_allocated" in dc:
             leak = dc["kv_pages_allocated"] - dc["kv_pages_freed"]
             w(f"kv pages: {dc['kv_pages_allocated']} allocated / "
