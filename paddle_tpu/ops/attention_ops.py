@@ -260,7 +260,10 @@ def cached_kv_attention_op(ins, attrs):
 
     The attend phase routes through the Pallas paged-attention kernel
     (ops/pallas/paged_attention.py: per-page HBM→VMEM block-gather, no
-    dense gathered context in HBM) under the PT_PALLAS dispatch; the
+    dense gathered context in HBM; a table wider than one KV chunk is
+    walked only as far as the row's ``pos``, each held page read once,
+    the next chunk copied while this one is attended; a table of one
+    chunk is read whole) under the PT_PALLAS dispatch; the
     'off' mode and untileable shapes take the counted stock
     gather+einsum lowering (``pallas.paged_attn_fallbacks``). The write
     phase is shared by every route.
@@ -274,8 +277,7 @@ def cached_kv_attention_op(ins, attrs):
     ``window`` (keys at pos - window < s <= pos only) and ``ring`` (the
     table is a slot's ring: the step's K/V land at ``pos mod (MP x P)``
     and keys are masked by their TRUE position). Without the attr this is
-    the float32 multi-head op it always was, kernel and lowering
-    unchanged."""
+    the float32 multi-head op it always was."""
     import jax.numpy as jnp
 
     from .pallas.paged_attention import paged_decode_attention

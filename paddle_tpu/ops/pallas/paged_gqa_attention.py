@@ -8,8 +8,8 @@ slot (serving/kv_cache.py): a token at position ``t`` lives at ring index
 ``t mod (ring_pages x page)``, and a key is attended iff its TRUE position
 lies in ``(pos - window, pos]``. ``paged_attention.py`` beside this file
 is the float32 multi-head kernel (``kvdim == n x hd``, one class of pages)
-and keeps its name, its lowering and its numbers; this one is a kernel of
-its own, ``name="paged_gqa_attention"``.
+under its own name; this one is a kernel of its own,
+``name="paged_gqa_attention"``.
 
 Per batch row the kernel walks the row's page table chunk by chunk and
 reads only the chunks that hold attended keys (a dynamic trip count: a

@@ -77,12 +77,15 @@ def test_int8_gemm_bias_relu(one_chip, tpu_mode, m, k, n):
              ((n,), F32))
 
 
-def test_paged_attention_multi_chunk(one_chip, tpu_mode):
-    """b8, 16 heads x 128, page 16, 128 pages per row (2048-token
-    context) at the default chunk: the online-softmax branch."""
+@pytest.mark.parametrize("mp,pool", [(128, 1024), (64, 513)])
+def test_paged_attention_multi_chunk(one_chip, tpu_mode, mp, pool):
+    """b8, 16 heads x 128, page 16, at the default chunk: 128 pages per
+    row (2048-token context), and the serving cell's own shape (64 pages
+    a row, a pool of 513). The streamed branch: a dynamic walk, two K and
+    two V buffers, a DMA semaphore each."""
     from paddle_tpu.ops.pallas import paged_attention as pa
 
-    b, n, hd, page, mp, pool = 8, 16, 128, 16, 128, 1024
+    b, n, hd, page = 8, 16, 128, 16
     assert pa._chunk_pages(page, mp, n * hd) < mp      # multi-chunk
     _compile(lambda q, pk, pv, t, p: pa.paged_decode_attention(
         q, pk, pv, t, p, n, hd, hd ** -0.5), one_chip,

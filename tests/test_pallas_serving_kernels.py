@@ -217,6 +217,14 @@ class _PagedAttnCase(OpTest):
         self.attrs = {"num_heads": n, "head_dim": hd, "scale": scale}
         self.outputs = {"Out": out, "PoolKOut": pk, "PoolVOut": pv}
 
+    def expect_from(self, pool_k, pool_v):
+        """The oracle over the case's inputs as they stand now, with these
+        pools in place of the fed ones."""
+        return _paged_attn_oracle(
+            self.inputs["Q"], self.inputs["K"], self.inputs["V"], pool_k,
+            pool_v, self.inputs["PageTable"], self.inputs["Positions"],
+            self.n, self.hd, self.attrs["scale"])
+
     def test_interpret_oracle(self):
         with _pallas("interpret"):
             before = _counter("pallas.paged_attn_dispatches")
@@ -287,6 +295,134 @@ class TestPagedAttnChunkedOnlineSoftmax(_PagedAttnCase):
         with _flags.overrides(pallas_kv_chunk_tokens=16):
             with _pallas("interpret"):
                 self.check_output(atol=2e-5, rtol=2e-5)
+
+
+class _PagedAttnWalkCase(_PagedAttnCase):
+    """The streamed path's page walk: the chunk forced to 2 pages under a
+    table of 8, so a row runs 1 to 4 chunks by its ``pos`` and copies of
+    its last chunk only the pages it holds."""
+    n, hd, page, mp, npages = 2, 8, 8, 8, 24
+    chunk_tokens = 16
+
+    def test_interpret_oracle(self):
+        from paddle_tpu.core import flags as _flags
+
+        with _flags.overrides(pallas_kv_chunk_tokens=self.chunk_tokens):
+            super().test_interpret_oracle()
+
+
+class TestPagedAttnWalkFirstMiddleLastChunk(_PagedAttnWalkCase):
+    """Rows ending in the first, a middle and the last chunk, in one
+    batch: each half of the scratch holds another row's longer chunk when
+    a shorter one lands in it."""
+    b, pos = 3, (63, 5, 30)
+
+
+class TestPagedAttnWalkEmptySlotBesideFullRow(_PagedAttnWalkCase):
+    """An empty slot (zero table, position 0: one page read) after a row
+    that fills every chunk."""
+    b, pos = 2, (63, 0)
+
+    def setup(self):
+        super().setup()
+        self.inputs["PageTable"][1] = 0
+        out, pk, pv = self.expect_from(self.inputs["PoolK"],
+                                       self.inputs["PoolV"])
+        self.outputs = {"Out": out, "PoolKOut": pk, "PoolVOut": pv}
+
+
+class TestPagedAttnWalkLastChunkOnePage(_PagedAttnWalkCase):
+    """Rows whose last chunk holds one page of its two (5 and 3 held
+    pages), the second at a page's first token."""
+    b, pos = 2, (35, 16)
+
+
+class _PagedAttnPoisonCase(_PagedAttnWalkCase):
+    """What the walk may read and must cancel, or must never read: the
+    oracle is computed on a pool whose such tokens are zero, the kernel
+    runs on the poisoned pool, and its output equals, bit for bit, its own
+    on the clean one."""
+    b, pos = 3, (63, 5, 30)
+    value = None
+
+    def poisoned(self, table, pos):
+        """bool [npages, page]: the pool's tokens to poison (a case may
+        re-point the table's unheld tail too)."""
+        raise NotImplementedError
+
+    def setup(self):
+        super().setup()
+        mask = self.poisoned(self.inputs["PageTable"],
+                             self.inputs["Positions"])[..., None]
+        self.clean = [np.where(mask, np.float32(0), self.inputs[name])
+                      for name in ("PoolK", "PoolV")]
+        out, pk, pv = self.expect_from(*self.clean)
+        for name in ("PoolK", "PoolV"):
+            self.inputs[name] = np.where(mask, np.float32(self.value),
+                                         self.inputs[name])
+        # the pools pass through the op with the step's K/V written
+        self.outputs = {
+            "Out": out,
+            "PoolKOut": np.where(mask, np.float32(self.value), pk),
+            "PoolVOut": np.where(mask, np.float32(self.value), pv)}
+
+    def test_bitwise_against_a_clean_pool(self):
+        import jax
+
+        from paddle_tpu.core import flags as _flags
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_decode_attention
+
+        self.setup()
+        table, pos = self.inputs["PageTable"], self.inputs["Positions"]
+        phys = table[np.arange(self.b), pos // self.page]
+
+        def run(pool_k, pool_v):
+            pool_k, pool_v = pool_k.copy(), pool_v.copy()
+            pool_k[phys, pos % self.page] = self.inputs["K"]
+            pool_v[phys, pos % self.page] = self.inputs["V"]
+            return np.asarray(jax.jit(
+                lambda *a: paged_decode_attention(
+                    *a, num_heads=self.n, head_dim=self.hd,
+                    scale=self.attrs["scale"]))(
+                        self.inputs["Q"], pool_k, pool_v, table, pos))
+
+        with _flags.overrides(pallas_kv_chunk_tokens=self.chunk_tokens):
+            with _pallas("interpret"):
+                got = run(self.inputs["PoolK"], self.inputs["PoolV"])
+                want = run(*self.clean)
+        assert np.isfinite(got).all()
+        assert np.array_equal(got, want)
+
+
+class TestPagedAttnWalkStaleTailIsCancelled(_PagedAttnPoisonCase):
+    """The tokens past ``pos`` in a row's last, partly held page are 1e30:
+    they are copied, masked before the softmax and multiplied to exact
+    zero after it."""
+    value = 1e30
+
+    def poisoned(self, table, pos):
+        mask = np.zeros((self.npages, self.page), bool)
+        for i in range(self.b):
+            mask[table[i, pos[i] // self.page], pos[i] % self.page + 1:] = \
+                True
+        return mask
+
+
+class TestPagedAttnWalkUnheldPagesAreNeverRead(_PagedAttnPoisonCase):
+    """Every page wholly past a row's ``pos`` is NaN, in the table (its
+    tail points at NaN pages) and in the pool (page 0 and every unowned
+    page): zero times NaN is NaN, so a walk that reads one fails, as the
+    kernel that read the table's whole width did."""
+    value = np.nan
+
+    def poisoned(self, table, pos):
+        mask = np.ones((self.npages, self.page), bool)
+        for i in range(self.b):
+            held = pos[i] // self.page + 1
+            mask[table[i, :held]] = False
+            table[i, held:] = self.npages - 1 - i
+        return mask
 
 
 # ---------------------------------------------------------------------------
