@@ -151,8 +151,8 @@ def _flash_route(cfg, size):
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     packed = jax.ShapeDtypeStruct(
         (size["batch"], size["seq"], cfg.hidden_size), jnp.bfloat16)
-    qp, kp = fa._packed_proxies(packed, packed, cfg.num_attention_heads)
-    return fa._dispatch_plan(qp, kp, None)[0]
+    return fa.attention_route(packed, packed, None,
+                              cfg.num_attention_heads)[0]
 
 
 def phase_train(size=TRAIN_SIZE, seed=0, cache=None, hidden=None):
@@ -166,8 +166,8 @@ def phase_train(size=TRAIN_SIZE, seed=0, cache=None, hidden=None):
 
     cfg, main, startup, loss_v = _ernie_program(size, hidden)
     mode, route = kernel_mode(), _flash_route(cfg, size)
-    require(route.startswith("pallas") and (mode != "tpu"
-                                            or route == "pallas"),
+    require(route in ("packed", "pallas")
+            or (route == "pallas_interpret" and mode != "tpu"),
             f"flash attention takes route {route!r} in mode {mode!r}, not "
             f"the Pallas kernels")
     exe, scope = pt.Executor(), pt.Scope()

@@ -46,6 +46,11 @@ install_thread_excepthook()
 # last FLAGS_blackbox_seconds of telemetry/span history in memory for
 # anomaly-triggered incident dumps
 from .core import incidents as _incidents  # noqa: F401,E402
+# the planes built on the hot paths' two telemetry hooks attach themselves
+# as they are imported: core/trace.py to timer(span=), core/goodput.py and
+# core/incidents.py to tick(). The executor and the decode engine import
+# none of them.
+from .core import goodput as _goodput, trace as _trace  # noqa: F401,E402
 from .param_attr import ParamAttr  # noqa: F401
 from . import dataset  # noqa: F401  (native-backed Dataset API)
 from .dataset import DatasetFactory, InMemoryDataset, QueueDataset  # noqa: F401

@@ -24,7 +24,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from . import costmodel, goodput, incidents, registry, telemetry, trace
+from . import costmodel, registry, telemetry
 from .ir import Block, OpDesc, Program, Variable, default_main_program
 from .registry import EMPTY_VAR
 from .scope import Scope, global_scope
@@ -304,9 +304,11 @@ class Executor:
                        context="executor pre-compile gate")
         self._verified.add(vkey)
 
-    def _unwrap_program(self, program, feed, mesh):
-        """Resolve (program, mesh, in_shardings): explicit mesh= arg >
-        CompiledProgram's mesh > global mesh (shared by run/run_steps)."""
+    def _resolve_run(self, program, feed, fetch_list, scope, mesh):
+        """What run and run_steps are given, resolved: (program, mesh,
+        in_shardings, scope, feed, fetch_names). Mesh: explicit mesh= arg >
+        CompiledProgram's mesh > global mesh. The program passes the
+        FLAGS_verify_program gate; the feeds' host bytes are counted."""
         from .compiler import CompiledProgram  # local: avoid cycle
 
         in_shardings = None
@@ -321,7 +323,20 @@ class Executor:
             mesh = get_mesh()
         if program is None:
             program = default_main_program()
-        return program, mesh, in_shardings
+        if scope is None:
+            scope = global_scope()
+        feed = dict(feed or {})
+        fetch_names = [f.name if isinstance(f, Variable) else str(f)
+                       for f in (fetch_list or [])]
+        self._maybe_verify(program, feed, fetch_names, scope)
+        # host→device feed traffic (bytes that actually cross: values
+        # still host-side; jax arrays are already device-resident)
+        feed_host_bytes = sum(v.nbytes for v in feed.values()
+                              if isinstance(v, np.ndarray))
+        if feed_host_bytes:
+            telemetry.counter_add("executor.feed_host_bytes",
+                                  int(feed_host_bytes))
+        return program, mesh, in_shardings, scope, feed, fetch_names
 
     def _has_ps_io(self, program) -> bool:
         """PS send/recv ops do host network IO — they force the
@@ -344,34 +359,12 @@ class Executor:
             scope: Optional[Scope] = None, return_numpy: bool = True,
             use_compiled: bool = True, mesh: Optional[Any] = None,
             sync_fetch: bool = True):
-        program, mesh, in_shardings = self._unwrap_program(program, feed,
-                                                           mesh)
-        if scope is None:
-            scope = global_scope()
-        feed = dict(feed or {})
-        fetch_names = [f.name if isinstance(f, Variable) else str(f)
-                       for f in (fetch_list or [])]
-        self._maybe_verify(program, feed, fetch_names, scope)
-
-        # host→device feed traffic (bytes that actually cross: values
-        # still host-side; jax arrays are already device-resident)
-        feed_host_bytes = sum(v.nbytes for v in feed.values()
-                              if isinstance(v, np.ndarray))
-        if feed_host_bytes:
-            telemetry.counter_add("executor.feed_host_bytes",
-                                  int(feed_host_bytes))
-
+        program, mesh, in_shardings, scope, feed, fetch_names = \
+            self._resolve_run(program, feed, fetch_list, scope, mesh)
         phases: Dict[str, float] = {}
-        with trace.span("executor.run", program=program.uid):
+        with telemetry.timer(span="executor.run", program=program.uid):
             block = program.global_block()
-            # cast feeds to declared dtypes
-            with trace.span("executor.feed", feeds=len(feed)), \
-                    telemetry.timer("executor.feed_ms", into=phases):
-                for name in list(feed):
-                    dtype = None
-                    if block.has_var(name):
-                        dtype = block.var(name).dtype
-                    feed[name] = _as_device_array(feed[name], dtype)
+            self._feeds_to_device(block, feed, phases)
 
             # PS send/recv ops do host network IO — route to the
             # interpreting (op-by-op) path, the reference's executor model
@@ -384,21 +377,35 @@ class Executor:
             telemetry.counter_add("executor.runs_compiled" if use_compiled
                                   else "executor.runs_interpreted", 1)
             if use_compiled:
-                with trace.span("executor.dispatch", compiled=True):
+                with telemetry.timer(span="executor.dispatch",
+                                     compiled=True):
                     fetched = self._run_compiled(program, block, feed,
                                                  fetch_names, scope,
                                                  mesh, in_shardings, phases)
             else:
-                with telemetry.timer("executor.interpret_ms"), \
-                        trace.span("executor.dispatch", compiled=False):
+                with telemetry.timer("executor.interpret_ms",
+                                     span="executor.dispatch",
+                                     compiled=False):
                     fetched = self._run_interpreted(program, block, feed,
                                                     fetch_names, scope, mesh)
-            with trace.span("executor.fetch", sync=sync_fetch), \
-                    telemetry.timer("executor.writeback_ms", into=phases):
+            with telemetry.timer("executor.writeback_ms", into=phases,
+                                 span="executor.fetch", sync=sync_fetch):
                 out = self._materialize_fetches(fetched, return_numpy,
                                                 sync_fetch)
             self._observe_phases(phases)
             return out
+
+    @staticmethod
+    def _feeds_to_device(block, feed, phases):
+        """Cast the feeds to their declared dtypes, on the device. Under
+        K-step fusion the leading [k] axis does not change a dtype."""
+        with telemetry.timer("executor.feed_ms", into=phases,
+                             span="executor.feed", feeds=len(feed)):
+            for name in list(feed):
+                dtype = None
+                if block.has_var(name):
+                    dtype = block.var(name).dtype
+                feed[name] = _as_device_array(feed[name], dtype)
 
     @staticmethod
     def _observe_phases(phases: Dict[str, float]):
@@ -407,7 +414,8 @@ class Executor:
         (cache key, state gathered from the scope, the donation check),
         call_ms (the jitted call RETURNING: dispatch is asynchronous, this
         is not the device's time), book_ms (cost booking, collective
-        accounting, watchdog and goodput ticks), writeback_ms (new state
+        accounting, telemetry.tick() and whoever subscribed to it:
+        the SLO watchdog, the goodput ledger), writeback_ms (new state
         into the scope, the fetches handed back: with sync_fetch the wait
         for the device is here). Histograms only: executor.run_ms is the
         run log's record of the step. An interpreted run or one that
@@ -456,15 +464,8 @@ class Executor:
         PS-IO ops (send/recv/save/...) cannot fuse — they fall back to k
         sequential runs (counted in executor.fused_fallback_steps).
         """
-        program, mesh, in_shardings = self._unwrap_program(program, feed,
-                                                           mesh)
-        if scope is None:
-            scope = global_scope()
-        feed = dict(feed or {})
-        fetch_names = [f.name if isinstance(f, Variable) else str(f)
-                       for f in (fetch_list or [])]
-        self._maybe_verify(program, feed, fetch_names, scope)
-
+        program, mesh, in_shardings, scope, feed, fetch_names = \
+            self._resolve_run(program, feed, fetch_list, scope, mesh)
         # k: explicit, else inferred from the stacked feeds' leading dim
         if k is None:
             if not feed:
@@ -483,24 +484,11 @@ class Executor:
                     f"with k={k}; got shape {shape} — stack per-step "
                     f"batches along a new leading axis (np.stack)")
 
-        feed_host_bytes = sum(v.nbytes for v in feed.values()
-                              if isinstance(v, np.ndarray))
-        if feed_host_bytes:
-            telemetry.counter_add("executor.feed_host_bytes",
-                                  int(feed_host_bytes))
-
         phases: Dict[str, float] = {}
-        with trace.span("executor.run_steps", program=program.uid, k=k):
+        with telemetry.timer(span="executor.run_steps", program=program.uid,
+                             k=k):
             block = program.global_block()
-            # cast stacked feeds to declared per-step dtypes (the leading k
-            # axis does not change dtype)
-            with trace.span("executor.feed", feeds=len(feed)), \
-                    telemetry.timer("executor.feed_ms", into=phases):
-                for name in list(feed):
-                    dtype = None
-                    if block.has_var(name):
-                        dtype = block.var(name).dtype
-                    feed[name] = _as_device_array(feed[name], dtype)
+            self._feeds_to_device(block, feed, phases)
 
             # fusion is illegal across host-IO ops: fall back to k
             # sequential single-step runs (still correct, no amortization)
@@ -525,12 +513,13 @@ class Executor:
                         for i in range(len(fetch_names))]
 
             telemetry.counter_add("executor.runs_compiled", 1)
-            with trace.span("executor.dispatch", compiled=True, k=k):
+            with telemetry.timer(span="executor.dispatch", compiled=True,
+                                 k=k):
                 fetched = self._run_compiled(program, block, feed,
                                              fetch_names, scope, mesh,
                                              in_shardings, phases, scan_k=k)
-            with trace.span("executor.fetch", sync=sync_fetch), \
-                    telemetry.timer("executor.writeback_ms", into=phases):
+            with telemetry.timer("executor.writeback_ms", into=phases,
+                                 span="executor.fetch", sync=sync_fetch):
                 out = self._materialize_fetches(fetched, return_numpy,
                                                 sync_fetch)
             self._observe_phases(phases)
@@ -809,219 +798,245 @@ class Executor:
     # -- compiling path ------------------------------------------------------
     def _run_compiled(self, program, block, feed, fetch_names, scope, mesh,
                       in_shardings, phases, scan_k=None):
-        """One dispatch of the program's jitted step. ``phases`` takes this
-        run's phase times (``executor.state_ms``, ``call_ms``, ``book_ms``,
-        the scope's part of ``writeback_ms``) for the caller to observe; a
-        run that compiles hands it back empty."""
+        """One dispatch of the program's jitted step: key, entry, gather
+        state, call, book, write back. ``phases`` takes this run's phase
+        times (``executor.state_ms``, ``call_ms``, ``book_ms``, the scope's
+        part of ``writeback_ms``) for the caller to observe; a run that
+        compiles (_compile_and_run) hands it back empty."""
         with telemetry.timer("executor.state_ms", into=phases):
-            feed_names = tuple(sorted(feed))
-            # default batch-sharding of a feed is only safe when its batch dim
-            # divides the mesh's batch axis (rule-table driven, 'dp' under the
-            # default table); partial batches compile a replicated entry.
-            # Under K-step fusion the per-step batch dim sits BEHIND the
-            # stacked [k] axis (dim 1)
-            from ..parallel import axis_rules
-
-            batch_dim = 1 if scan_k else 0
-            batch_axis = axis_rules.batch_mesh_axis(mesh)
-            dp = mesh.shape.get(batch_axis) if batch_axis else None
-            dp_ok = {}
-            if dp:
-                for n in feed_names:
-                    v = feed[n]
-                    dp_ok[n] = bool(getattr(v, "ndim", 0) >= batch_dim + 1
-                                    and v.shape[batch_dim] % dp == 0)
-            from .. import profiler as _prof
-
-            # mesh keyed by content (axes/topology), program/scope by uid —
-            # id() could alias a GC'd object (VERDICT r1 weak #8)
-            mesh_key = None
-            if mesh is not None:
-                mesh_key = (tuple(mesh.axis_names), mesh.devices.shape,
-                            tuple(d.id for d in mesh.devices.flat))
-            # the rule table resolves shardings at trace time, so its content
-            # hash MUST key the cache (a swapped table recompiles instead of
-            # reusing stale shardings); zero_stage names the ZeRO config in
-            # recompile-cause diagnostics
-            rules_fp = axis_rules.fingerprint() if mesh is not None else None
-            zero_stage = getattr(program, "_zero_stage", None)
-            # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
-            # geometry, ops/pallas.kernels_fingerprint) is read at TRACE
-            # time by the kernel dispatchers — a mid-process mode flip or
-            # chunk-flag change must recompile, not reuse an entry lowered
-            # for the other kernel variant (and the PR 10 cost capture then
-            # attributes flops/bytes per variant)
-            from ..ops import pallas as _pallas
-
-            pallas_fp = _pallas.kernels_fingerprint()
-            key = (program.uid, program.version, scope.uid, feed_names,
-                   tuple(fetch_names), mesh_key, tuple(sorted(dp_ok.items())),
-                   scan_k, rules_fp, zero_stage, pallas_fp)
+            key = self._cache_key(program, scope, feed, fetch_names, mesh,
+                                  scan_k)
             entry = self._cache.get(key)
-            compile_cause = None
-            t_compile = None
-            if entry is None:
-                # recompile-cause diagnostic: name the key component that
-                # changed vs the nearest cached entry BEFORE inserting, so a
-                # silent retrace shows up as e.g. cause="dp_divisibility"
-                compile_cause = _recompile_cause(key, self._cache)
-                telemetry.counter_add("executor.cache_misses", 1)
-                t_compile = time.perf_counter()
-                with _prof.RecordEvent("executor::compile"):
-                    entry = self._compile(program, block, feed_names,
-                                          fetch_names, scope, mesh,
-                                          in_shardings, dp_ok, scan_k=scan_k)
-                self._cache[key] = entry
-            else:
+            if entry is not None:
                 telemetry.counter_add("executor.cache_hits", 1)
-
-            state = {}
-            seen_bufs: Dict[int, str] = {}
-            for n in entry.state_names:
-                v = scope.find_var(n)
-                if v is None:
-                    raise ExecutionError(
-                        f"persistable var '{n}' not initialised in scope — "
-                        f"did you run the startup program?")
-                # state buffers are donated: two names aliasing one device
-                # buffer would fail Execute(); copy the duplicate. The buffer
-                # pointer is the key (it works on the CPU and the TPU
-                # runtime); a value without a single pointer (numpy, a
-                # sharded or deleted array) keys on object identity, which
-                # still catches same-array-two-names aliasing
-                try:
-                    bkey = v.unsafe_buffer_pointer()
-                except (AttributeError, ValueError, RuntimeError):
-                    bkey = id(v)
-                if bkey in seen_bufs:
-                    import jax.numpy as jnp
-
-                    v = jnp.copy(v)
-                    telemetry.counter_add("executor.donation_copies", 1,
-                                          var=n, aliases=seen_bufs[bkey])
-                else:
-                    seen_bufs[bkey] = n
-                state[n] = v
-            ro = {n: scope.find_var(n) for n in entry.ro_names}
-            step = scope.find_var("@STEP_COUNTER@")
-            if step is None:
-                step = _as_device_array(0, np.int32)
-
-            # per-compile cost/memory capture (core/costmodel.py): the AOT
-            # analyses run against THIS cache entry's lowering before state
-            # buffers are donated; lower() shares the trace cache with the
-            # first execution, so 'cost' level adds ~no work. Degrades by
-            # counting (costmodel.unavailable), never by raising.
-            if compile_cause is not None and \
-                    costmodel.capture_mode() != "off":
-                entry.cost = costmodel.capture(
-                    lambda: entry.jitted.lower(state, ro, feed, step),
-                    key_id=costmodel.key_id_for(key), kind="executor",
-                    program=f"{program.uid}v{program.version}",
-                    steps_per_dispatch=scan_k or 1)
-                # HBM ledger: persistable split of this program's resident
-                # state (params vs optimizer/run state)
-                names = list(entry.state_names) + list(entry.ro_names)
-                vals = [state.get(n, ro.get(n)) for n in names]
-                pb, ob = costmodel.split_persistable_bytes(block, names, vals)
-                costmodel.record_model_bytes(pb, ob)
-
+                state, ro, step = self._gather_state(entry, scope)
+        if entry is None:
+            phases.clear()
+            return self._compile_and_run(key, program, block, feed,
+                                         fetch_names, scope, mesh,
+                                         in_shardings, scan_k)
         t_run = time.perf_counter()
-        t_run_wall = time.time()
-        with telemetry.timer("executor.call_ms", into=phases):
-            try:
-                with _prof.RecordEvent("executor::run"):
-                    fetches, new_state, new_step = entry.jitted(state, ro,
-                                                                feed, step)
-            except Exception as e:
-                # allocation failure: land the OOM forensics record (ledger
-                # snapshot + top cached programs by peak bytes + this
-                # program's id) in the run log, then raise typed
-                if costmodel.is_oom_error(e):
-                    raise costmodel.oom_forensics(
-                        f"{program.uid}v{program.version}", e,
-                        where="executor.dispatch") from e
-                raise
+        with telemetry.timer("executor.call_ms", into=phases,
+                             span="executor::run"):
+            fetches, new_state, new_step = self._call(entry, program, state,
+                                                      ro, feed, step)
         with telemetry.timer("executor.book_ms", into=phases):
-            costmodel.book_dispatch(entry.cost, steps=scan_k or 1)
-            # sharded-training collective accounting: the ShardingOptimizer
-            # (fleet/meta_optimizers.py) precomputes the per-step dp-collective
-            # payloads of the program; every dispatch books them (×k under
-            # fusion) and, when tracing, a child span puts the collectives on
-            # the trace_view critical path
-            sbytes = getattr(program, "_sharding_bytes", None)
-            if sbytes:
-                k_mult = scan_k or 1
-                for cname, nbytes in sbytes.items():
-                    if nbytes:
-                        telemetry.counter_add(f"sharding.{cname}_bytes",
-                                              int(nbytes) * k_mult)
-                parent = trace.current()
-                if parent is not None:
-                    # span timebase is epoch seconds (trace._Span.start)
-                    trace.record("sharding.collectives", parent, t_run_wall,
-                                 time.time(), zero_stage=zero_stage,
-                                 steps=k_mult,
-                                 **{f"{cn}_bytes": int(nb)
-                                    for cn, nb in sbytes.items() if nb})
-            if scan_k:
-                telemetry.counter_add("executor.fused_dispatches", 1)
-                telemetry.counter_add("executor.fused_steps", scan_k)
-            if compile_cause is not None:
-                # jax.jit compiles lazily — the first execution carries the
-                # trace + XLA compile, so compile wall time is measured through
-                # it (and excluded from the run_ms step-time histogram)
-                compile_ms = (time.perf_counter() - t_compile) * 1e3
-                telemetry.counter_add("executor.compiles", 1)
-                telemetry.counter_add("executor.compile_ms",
-                                      round(compile_ms, 3))
-                telemetry.gauge_set("executor.cache_size", len(self._cache))
-                telemetry.event(
-                    "compile", "executor", round(compile_ms, 3),
-                    {"cause": compile_cause, "cache_size": len(self._cache),
-                     "program": program.uid, "program_version": program.version,
-                     "feed_names": list(feed_names),
-                     "fetch_names": list(fetch_names),
-                     "mesh": None if mesh_key is None else list(mesh_key[0]),
-                     "dp_divisibility": sorted(dp_ok.items()),
-                     "steps_per_dispatch": scan_k or 1,
-                     "axis_rules": rules_fp, "zero_stage": zero_stage,
-                     "pallas_kernels": pallas_fp})
-            else:
-                # host-side dispatch wall time (device dispatch is async —
-                # these are the step-time percentiles in the run log).
-                # Fused dispatches land in their own histogram: one sample
-                # covers scan_k device steps
-                run_ms = (time.perf_counter() - t_run) * 1e3
-                telemetry.observe(
-                    "executor.run_steps_ms" if scan_k else "executor.run_ms",
-                    run_ms, kind="timer")
-            # SLO watchdog hook: evaluates the rule set at most every
-            # FLAGS_slo_eval_s while armed, one boolean read otherwise
-            incidents.tick()
-            # goodput-ledger refresh (goodput.ratio live on /metrics) —
-            # throttled to FLAGS_goodput_publish_s, inert without a window
-            goodput.tick()
+            self._book(entry, program, scan_k)
+            # host-side dispatch wall time (device dispatch is async —
+            # these are the step-time percentiles in the run log).
+            # Fused dispatches land in their own histogram: one sample
+            # covers scan_k device steps
+            telemetry.observe(
+                "executor.run_steps_ms" if scan_k else "executor.run_ms",
+                (time.perf_counter() - t_run) * 1e3, kind="timer")
+            telemetry.tick()
         with telemetry.timer("executor.writeback_ms", into=phases):
-            from .flags import flag as _flag
+            self._write_back(entry, scope, state, fetches, new_state,
+                             new_step, scan_k)
+        return list(fetches)
 
-            if _flag("check_nan_inf"):
-                # fused on-device isfinite reduction, one host sync of the
-                # verdict vector — debug flag semantics without a full state
-                # download (reference: FLAGS_check_nan_inf,
-                # nan_inf_utils_detail.cc)
-                _assert_all_finite(
-                    list(new_state.items()) + list(zip(entry.fetch_names,
-                                                       fetches)),
-                    "run_steps" if scan_k else "run")
-            for n, v in new_state.items():
-                scope.set(n, v)
-            scope.set("@STEP_COUNTER@", new_step)
-            # the donated arrays die here, inside the phase that replaced
-            # them, not when this frame is torn down after the last timer
-            state.clear()
-        if compile_cause is not None:
-            phases.clear()     # a run that compiled stays out of the phases
+    @staticmethod
+    def _cache_key(program, scope, feed, fetch_names, mesh, scan_k):
+        """The compile cache's key, components in _KEY_COMPONENTS order."""
+        from ..ops import pallas as _pallas
+        from ..parallel import axis_rules
+
+        feed_names = tuple(sorted(feed))
+        # default batch-sharding of a feed is only safe when its batch dim
+        # divides the mesh's batch axis (rule-table driven, 'dp' under the
+        # default table); partial batches compile a replicated entry.
+        # Under K-step fusion the per-step batch dim sits BEHIND the
+        # stacked [k] axis (dim 1)
+        batch_dim = 1 if scan_k else 0
+        batch_axis = axis_rules.batch_mesh_axis(mesh)
+        dp = mesh.shape.get(batch_axis) if batch_axis else None
+        dp_ok = {}
+        if dp:
+            for n in feed_names:
+                v = feed[n]
+                dp_ok[n] = bool(getattr(v, "ndim", 0) >= batch_dim + 1
+                                and v.shape[batch_dim] % dp == 0)
+        # mesh keyed by content (axes/topology), program/scope by uid —
+        # id() could alias a GC'd object (VERDICT r1 weak #8)
+        mesh_key = None
+        if mesh is not None:
+            mesh_key = (tuple(mesh.axis_names), mesh.devices.shape,
+                        tuple(d.id for d in mesh.devices.flat))
+        # the rule table resolves shardings at trace time, so its content
+        # hash MUST key the cache (a swapped table recompiles instead of
+        # reusing stale shardings); zero_stage names the ZeRO config in
+        # recompile-cause diagnostics
+        rules_fp = axis_rules.fingerprint() if mesh is not None else None
+        # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
+        # geometry, ops/pallas.kernels_fingerprint) is read at TRACE
+        # time by the kernel dispatchers — a mid-process mode flip or
+        # chunk-flag change must recompile, not reuse an entry lowered
+        # for the other kernel variant (and the PR 10 cost capture then
+        # attributes flops/bytes per variant)
+        return (program.uid, program.version, scope.uid, feed_names,
+                tuple(fetch_names), mesh_key, tuple(sorted(dp_ok.items())),
+                scan_k, rules_fp, getattr(program, "_zero_stage", None),
+                _pallas.kernels_fingerprint())
+
+    @staticmethod
+    def _gather_state(entry, scope):
+        """(state, ro, step) for one call of ``entry``: the donated training
+        state, the read-only residents and the step counter, from the
+        scope."""
+        state = {}
+        seen_bufs: Dict[int, str] = {}
+        for n in entry.state_names:
+            v = scope.find_var(n)
+            if v is None:
+                raise ExecutionError(
+                    f"persistable var '{n}' not initialised in scope — "
+                    f"did you run the startup program?")
+            # state buffers are donated: two names aliasing one device
+            # buffer would fail Execute(); copy the duplicate. The buffer
+            # pointer is the key (it works on the CPU and the TPU
+            # runtime); a value without a single pointer (numpy, a
+            # sharded or deleted array) keys on object identity, which
+            # still catches same-array-two-names aliasing
+            try:
+                bkey = v.unsafe_buffer_pointer()
+            except (AttributeError, ValueError, RuntimeError):
+                bkey = id(v)
+            if bkey in seen_bufs:
+                import jax.numpy as jnp
+
+                v = jnp.copy(v)
+                telemetry.counter_add("executor.donation_copies", 1,
+                                      var=n, aliases=seen_bufs[bkey])
+            else:
+                seen_bufs[bkey] = n
+            state[n] = v
+        ro = {n: scope.find_var(n) for n in entry.ro_names}
+        step = scope.find_var("@STEP_COUNTER@")
+        if step is None:
+            step = _as_device_array(0, np.int32)
+        return state, ro, step
+
+    @staticmethod
+    def _call(entry, program, state, ro, feed, step):
+        try:
+            return entry.jitted(state, ro, feed, step)
+        except Exception as e:
+            # allocation failure: land the OOM forensics record (ledger
+            # snapshot + top cached programs by peak bytes + this
+            # program's id) in the run log, then raise typed
+            if costmodel.is_oom_error(e):
+                raise costmodel.oom_forensics(
+                    f"{program.uid}v{program.version}", e,
+                    where="executor.dispatch") from e
+            raise
+
+    @staticmethod
+    def _book(entry, program, scan_k):
+        """One dispatch into the counters: the captured program's flops
+        and bytes, the sharded program's collective payloads, the fused
+        steps."""
+        costmodel.book_dispatch(entry.cost, steps=scan_k or 1)
+        # sharded-training collective accounting: the ShardingOptimizer
+        # (fleet/meta_optimizers.py) precomputes the per-step dp-collective
+        # payloads of the program; every dispatch books them (×k under
+        # fusion)
+        sbytes = getattr(program, "_sharding_bytes", None)
+        if sbytes:
+            for cname, nbytes in sbytes.items():
+                if nbytes:
+                    telemetry.counter_add(f"sharding.{cname}_bytes",
+                                          int(nbytes) * (scan_k or 1))
+        if scan_k:
+            telemetry.counter_add("executor.fused_dispatches", 1)
+            telemetry.counter_add("executor.fused_steps", scan_k)
+
+    @staticmethod
+    def _write_back(entry, scope, state, fetches, new_state, new_step,
+                    scan_k):
+        from .flags import flag as _flag
+
+        if _flag("check_nan_inf"):
+            # fused on-device isfinite reduction, one host sync of the
+            # verdict vector — debug flag semantics without a full state
+            # download (reference: FLAGS_check_nan_inf,
+            # nan_inf_utils_detail.cc)
+            _assert_all_finite(
+                list(new_state.items()) + list(zip(entry.fetch_names,
+                                                   fetches)),
+                "run_steps" if scan_k else "run")
+        for n, v in new_state.items():
+            scope.set(n, v)
+        scope.set("@STEP_COUNTER@", new_step)
+        # the donated arrays die here, inside the phase that replaced
+        # them, not when the caller's frame is torn down after its last
+        # timer
+        state.clear()
+
+    def _compile_and_run(self, key, program, block, feed, fetch_names, scope,
+                         mesh, in_shardings, scan_k):
+        """The cache-miss branch of _run_compiled: everything that happens
+        once a compiled entry and never on a steady-state step. Names the
+        recompile's cause, builds the entry, captures its cost
+        (core/costmodel.py), runs the first step, through which jax.jit
+        traces and XLA compiles, and lands the ``compile`` event."""
+        named = dict(zip(_KEY_COMPONENTS, key))
+        # name the key component that changed vs the nearest cached entry
+        # BEFORE inserting, so a silent retrace shows up as e.g.
+        # cause="dp_divisibility"
+        cause = _recompile_cause(key, self._cache)
+        telemetry.counter_add("executor.cache_misses", 1)
+        t_compile = time.perf_counter()
+        with telemetry.timer(span="executor::compile"):
+            entry = self._compile(program, block, named["feed_names"],
+                                  fetch_names, scope, mesh, in_shardings,
+                                  dict(named["dp_divisibility"]),
+                                  scan_k=scan_k)
+        self._cache[key] = entry
+        state, ro, step = self._gather_state(entry, scope)
+        # per-compile cost/memory capture: the AOT analyses run against
+        # THIS cache entry's lowering before state buffers are donated;
+        # lower() shares the trace cache with the first execution, so
+        # 'cost' level adds ~no work. Degrades by counting
+        # (costmodel.unavailable), never by raising.
+        if costmodel.capture_mode() != "off":
+            entry.cost = costmodel.capture(
+                lambda: entry.jitted.lower(state, ro, feed, step),
+                key_id=costmodel.key_id_for(key), kind="executor",
+                program=f"{program.uid}v{program.version}",
+                steps_per_dispatch=scan_k or 1)
+            # HBM ledger: persistable split of this program's resident
+            # state (params vs optimizer/run state)
+            names = list(entry.state_names) + list(entry.ro_names)
+            vals = [state.get(n, ro.get(n)) for n in names]
+            pb, ob = costmodel.split_persistable_bytes(block, names, vals)
+            costmodel.record_model_bytes(pb, ob)
+        with telemetry.timer(span="executor::run"):
+            fetches, new_state, new_step = self._call(entry, program, state,
+                                                      ro, feed, step)
+        self._book(entry, program, scan_k)
+        # jax.jit compiles lazily — the first execution carries the trace +
+        # XLA compile, so compile wall time is measured through it (and
+        # excluded from the run_ms step-time histogram)
+        compile_ms = round((time.perf_counter() - t_compile) * 1e3, 3)
+        telemetry.counter_add("executor.compiles", 1)
+        telemetry.counter_add("executor.compile_ms", compile_ms)
+        telemetry.gauge_set("executor.cache_size", len(self._cache))
+        mesh_key = named["mesh"]
+        telemetry.event(
+            "compile", "executor", compile_ms,
+            {"cause": cause, "cache_size": len(self._cache),
+             "program": program.uid, "program_version": program.version,
+             "feed_names": list(named["feed_names"]),
+             "fetch_names": list(fetch_names),
+             "mesh": None if mesh_key is None else list(mesh_key[0]),
+             "dp_divisibility": sorted(named["dp_divisibility"]),
+             "steps_per_dispatch": scan_k or 1,
+             "axis_rules": named["axis_rules"],
+             "zero_stage": named["zero_stage"],
+             "pallas_kernels": named["pallas_kernels"]})
+        telemetry.tick()
+        self._write_back(entry, scope, state, fetches, new_state, new_step,
+                         scan_k)
         return list(fetches)
 
     def _compile(self, program, block, feed_names, fetch_names, scope, mesh,
@@ -1314,10 +1329,11 @@ class Executor:
             telemetry.counter_add("executor.reader_skipped_batches",
                                   start_step)
 
-        # goodput ledger (core/goodput.py): open an attribution window
-        # unless the caller already did, and time every batch fetch —
-        # the loop blocked on the data path is the data_wait phase
-        goodput.ensure_run()
+        # a training loop begins: the goodput ledger, where it is loaded,
+        # opens an attribution window unless the caller already did. Every
+        # batch fetch is timed — the loop blocked on the data path is its
+        # data_wait phase
+        telemetry.tick("loop_begin")
 
         def _timed_batches(it):
             it = iter(it)
@@ -1355,9 +1371,9 @@ class Executor:
                 "dataset produced no batches — for InMemoryDataset call "
                 "load_into_memory() before training (resuming past the "
                 "end of the stream also lands here)")
-        # land the run's goodput counters + ratio gauge (the window stays
-        # open: a caller-owned window keeps accumulating across calls)
-        goodput.publish()
+        # the ledger lands the run's counters and ratio gauge (its window
+        # stays open: a caller-owned window keeps accumulating across calls)
+        telemetry.tick("loop_end")
         if fetch_handler is not None and last is not None:
             fetch_handler(dict(zip(fetch_names, last)))
         return last

@@ -520,9 +520,11 @@ define_flag("slo_watchdog", "auto",
             "arms rule evaluation at import, 'off' disarms it "
             "everywhere, 'auto' (default) arms when a serving/metrics "
             "HTTP surface starts or incidents.arm() is called "
-            "explicitly. Armed: incidents.tick() calls sprinkled on the "
-            "executor/decode/router hot paths evaluate the rule set at "
-            "most every slo_eval_s; disarmed they cost one boolean read")
+            "explicitly. Armed: the hot loops' hook (telemetry.tick() in "
+            "the executor and the decode engine, incidents.tick() in "
+            "the router and the serving engine) evaluates the rule set "
+            "at most every slo_eval_s; disarmed it costs one boolean "
+            "read")
 define_flag("slo_eval_s", 5.0,
             "min seconds between two SLO rule evaluations (inline "
             "tick() or the pt-incidents-watchdog thread): each "

@@ -36,7 +36,8 @@ row always has *something* honest to embed.
 
 Emits ``goodput.productive_ms`` / ``goodput.badput_<phase>_ms`` /
 ``goodput.wall_ms`` counters and the ``goodput.ratio`` gauge (live on
-/metrics via :func:`tick` on the executor hot path); the flight
+/metrics via :func:`tick`, subscribed to ``telemetry.tick()`` on the
+executor hot path); the flight
 recorder's incident dumps bundle :func:`breakdown` so a postmortem
 shows where the time went *at the moment of the trip*. Rendered by
 tools/perf_report.py ("Goodput" section) and tools/fleet_report.py.
@@ -157,7 +158,7 @@ class GoodputLedger:
         return b
 
     def tick(self, now: Optional[float] = None):
-        """Hot-path hook (next to incidents.tick in the executor):
+        """Hot-path hook (telemetry.tick() after every executor dispatch):
         publish at most every FLAGS_goodput_publish_s once a window is
         open; two reads otherwise."""
         with self._lock:
@@ -196,8 +197,8 @@ def start_run():
 
 
 def ensure_run():
-    """Open a window only if none is open (train_from_dataset's hook —
-    an outer start_run() window is preserved)."""
+    """Open a window only if none is open (train_from_dataset's
+    ``loop_begin`` — an outer start_run() window is preserved)."""
     _ledger.start(reset=False)
 
 
@@ -215,3 +216,18 @@ def tick(now: Optional[float] = None):
 
 def reset():
     _ledger.reset()
+
+
+def _on_tick(event: str):
+    """telemetry.tick()'s subscriber: a training loop's begin opens a
+    window unless its caller did, every step refreshes the published
+    numbers (throttled), the loop's end lands them."""
+    if event == "step":
+        tick()
+    elif event == "loop_begin":
+        ensure_run()
+    elif event == "loop_end":
+        publish()
+
+
+telemetry.on_tick(_on_tick)

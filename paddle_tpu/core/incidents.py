@@ -483,9 +483,10 @@ def stop_watchdog():
 
 
 def tick(now: Optional[float] = None):
-    """Cheap hot-path hook (executor run, decode step, router probe):
-    evaluates the rule set at most every FLAGS_slo_eval_s while the
-    plane is armed; one boolean read otherwise."""
+    """Cheap hot-path hook (router probe, serving batch; the executor's
+    run and the decode step through telemetry.tick()): evaluates the
+    rule set at most every FLAGS_slo_eval_s while the plane is armed;
+    one boolean read otherwise."""
     if not armed():
         return
     if now is None:
@@ -497,6 +498,15 @@ def tick(now: Optional[float] = None):
         watchdog().evaluate(now=now)
     except Exception:
         telemetry.counter_quiet("slo.eval_errors")
+
+
+def _on_tick(event: str):
+    """telemetry.tick()'s subscriber: the executor and the decode engine
+    know that hook, not this module."""
+    tick()
+
+
+telemetry.on_tick(_on_tick)
 
 
 # -- the unified incident pipeline -------------------------------------------

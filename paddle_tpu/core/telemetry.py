@@ -162,6 +162,51 @@ def _annotation(name: str):
     return cls(name)
 
 
+# Who else records a timer's span: callables (span name, attrs) -> a context
+# manager, or None when they are not recording now. The layers above attach
+# themselves (core/trace.py, paddle_tpu/profiler.py); the hot paths below
+# know only timer().
+_span_recorders: List[Any] = []
+
+
+def attach_span(recorder):
+    if recorder not in _span_recorders:
+        _span_recorders.append(recorder)
+
+
+def _attached(span: str, attrs: Dict[str, Any]):
+    """What the attached recorders open for one span, as one context
+    manager; the shared null context when nobody is recording."""
+    opened = [cm for cm in (recorder(span, attrs)
+                            for recorder in _span_recorders)
+              if cm is not None]
+    if not opened:
+        return _NO_ANNOTATION
+    with contextlib.ExitStack() as stack:
+        for cm in opened:
+            stack.enter_context(cm)
+        return stack.pop_all()
+
+
+# The one per-step hook. A hot loop calls tick() once a step (the executor
+# after a dispatch, the decode engine after a step) and, around a training
+# loop, tick("loop_begin") / tick("loop_end"); the planes built on top
+# (core/incidents.py, core/goodput.py) subscribe from their own modules
+# and keep their own throttles. A subscriber must be cheap when it has
+# nothing to do and must not raise.
+_tick_subscribers: List[Any] = []
+
+
+def on_tick(subscriber):
+    if subscriber not in _tick_subscribers:
+        _tick_subscribers.append(subscriber)
+
+
+def tick(event: str = "step"):
+    for subscriber in _tick_subscribers:
+        subscriber(event)
+
+
 class _Hist:
     """Running histogram: exact count/sum/min/max + a bounded sample ring
     for percentile estimates (recent-window semantics once full) + fixed
@@ -446,25 +491,34 @@ class TelemetryRegistry:
             h.observe(value)
 
     @contextlib.contextmanager
-    def timer(self, name: str, into: Optional[Dict[str, float]] = None,
-              **attrs):
-        """Times the region into the histogram ``name`` (ms) and, while a
-        jax profiler trace is running, marks it there as a
-        ``TraceAnnotation`` of the same name, on the clock of the device's
-        own lines. ``into`` defers the sample: the ms are added to that dict
-        under ``name`` instead of the histogram, for a caller that decides
-        at the end of an iteration whether it counts (DecodeEngine._loop,
-        Executor.run)."""
-        t0 = time.perf_counter()
-        try:
-            with _annotation(name):
+    def timer(self, name: Optional[str] = None,
+              into: Optional[Dict[str, float]] = None,
+              span: Optional[str] = None, **attrs):
+        """The one span primitive of a hot path. Times the region into the
+        histogram ``name`` (ms) and, while a jax profiler trace is running,
+        marks it there as a ``TraceAnnotation`` of the same name, on the
+        clock of the device's own lines. ``into`` defers the sample: the ms
+        are added to that dict under ``name`` instead of the histogram, for
+        a caller that decides at the end of an iteration whether it counts
+        (DecodeEngine._loop, Executor.run). ``span`` names the region for
+        whoever attached with :func:`attach_span` (core/trace.py, which
+        records while a trace is sampled, paddle_tpu/profiler.py, while it
+        is on): they record it under that name with ``attrs``, outside the timed part.
+        A region with a span and no histogram leaves ``name`` out."""
+        with _attached(span, attrs) if span is not None else _NO_ANNOTATION:
+            if name is None:
                 yield
-        finally:
-            ms = (time.perf_counter() - t0) * 1e3
-            if into is None:
-                self.observe(name, ms, kind="timer", **attrs)
-            else:
-                into[name] = into.get(name, 0.0) + ms
+                return
+            t0 = time.perf_counter()
+            try:
+                with _annotation(name):
+                    yield
+            finally:
+                ms = (time.perf_counter() - t0) * 1e3
+                if into is None:
+                    self.observe(name, ms, kind="timer", **attrs)
+                else:
+                    into[name] = into.get(name, 0.0) + ms
 
     # -- snapshots -----------------------------------------------------------
     def counters(self) -> Dict[str, Any]:
@@ -774,8 +828,10 @@ def observe_quiet(name: str, value):
     return _reg().observe_quiet(name, value)
 
 
-def timer(name: str, into: Optional[Dict[str, float]] = None, **attrs):
-    return _reg().timer(name, into=into, **attrs)
+def timer(name: Optional[str] = None,
+          into: Optional[Dict[str, float]] = None,
+          span: Optional[str] = None, **attrs):
+    return _reg().timer(name, into=into, span=span, **attrs)
 
 
 def event(kind: str, name: str, value=None, attrs=None):

@@ -224,6 +224,16 @@ def span(name: str, **attrs):
                  parent.span_id, attrs)
 
 
+def _timer_span(name: str, attrs: Dict[str, Any]):
+    """What telemetry.timer(span=name) opens here: a span as span() would,
+    nothing when tracing is off or the trace is not sampled."""
+    s = span(name, **attrs)
+    return None if s is _NULL else s
+
+
+telemetry.attach_span(_timer_span)
+
+
 def root_span(name: str, trace_id: Optional[str] = None,
               force: bool = False, **attrs):
     """Start a NEW trace (ignores any active context). ``trace_id`` pins

@@ -122,7 +122,7 @@ def _attention(x, attn_bias, cfg: BertConfig, name: str, is_test=False,
     # slice/squeeze of the [3,B,n,S,hd] transpose: the stacked form
     # materialised the full 5-D transpose and then paid three strided
     # slice copies per layer fwd AND bwd (~30 ms/step measured on the
-    # b34 ERNIE profile, tools/profile_ernie.py); with per-projection
+    # b34 ERNIE profile, BASELINE.md); with per-projection
     # outputs XLA folds each [B,S,n,hd]->[B,n,S,hd] transpose into the
     # dot's output layout. Same Megatron column-parallel sharding.
     if cfg.use_flash_attention and not cfg.use_ring_attention:

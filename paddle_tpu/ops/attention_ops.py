@@ -115,16 +115,16 @@ def _flash_attention_grad_maker(op, out_grads, in_grads):
 def flash_attention_grad_op(ins, attrs):
     """d(Q,K,V,Bias) of flash_attention from the saved (Out, Lse).
 
-    Re-derives the SAME route as its forward (_dispatch_plan is a pure
-    function of shapes + env): on the pallas routes it calls the bwd
-    kernels directly — zero forward re-execution; on the xla/reference
-    routes it runs the generic vjp of the forward lowering, whose
-    re-traced standard-HLO forward XLA CSEs with the forward op's."""
+    Asks attention_route() what its forward asked (a pure function of
+    shapes, layout, bias form and kernel_mode()): on the 'packed' and
+    'pallas*' routes it calls the bwd kernels directly — zero forward
+    re-execution; on the xla/reference routes it runs the generic vjp of
+    the forward lowering, whose re-traced standard-HLO forward XLA CSEs
+    with the forward op's."""
     import jax
 
-    from .pallas.flash_attention import (_dispatch_plan, flash_attention,
-                                         flash_attention_bwd,
-                                         packed_saved_bwd_route)
+    from .pallas.flash_attention import (attention_route, flash_attention,
+                                         flash_attention_bwd)
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins["Bias"][0] if ins.get("Bias") else None
@@ -133,13 +133,9 @@ def flash_attention_grad_op(ins, attrs):
     causal = bool(attrs.get("causal", False))
     scale = attrs.get("scale", None)
     num_heads = _local_heads(q, attrs)
-    if q.ndim == 3:
-        # ONE dispatch authority shared with flash_attention_bwd:
-        # 'packed'/'bnsd' routes have saved (out, lse); 'vjp' recomputes
-        direct = packed_saved_bwd_route(q, k, bias,
-                                        int(num_heads)) != "vjp"
-    else:
-        direct = _dispatch_plan(q, k, bias)[0].startswith("pallas")
+    # these routes saved (out, lse); the others recompute
+    direct = attention_route(q, k, bias, num_heads)[0] in (
+        "packed", "pallas", "pallas_interpret")
     if direct:
         dq, dk, dv, dbias_kv = flash_attention_bwd(
             q, k, v, bias, out, lse, do, causal=causal, scale=scale,

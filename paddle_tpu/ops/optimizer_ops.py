@@ -174,33 +174,11 @@ def adamw(ins, attrs):
                 coeff=float(attrs.get("coeff", 0.01))
                 if attrs.get("with_decay", True) else 0.0)
     ins = dict(ins, Grad=[_dense_grad(ins["Grad"][0])])
-    import jax.numpy as jnp
-
     p, lr = ins["Param"][0], ins["LearningRate"][0]
     coeff = np.asarray(attrs.get("coeff", 0.01), np.float32)
-
-    import os
-
-    from .pallas import fused_adamw, kernel_mode
-
-    # measured (tools/ablate_ernie.py, v5e, round 3): one Pallas
-    # custom-call per parameter is ~18 ms/step SLOWER on ERNIE-large than
-    # letting XLA fuse the per-param update chains — the kernel is
-    # opt-in (PT_FUSED_ADAMW=1), not the default
-    if kernel_mode() != "off" and attrs.get("with_decay", True) \
-            and os.environ.get("PT_FUSED_ADAMW"):
-        g = ins["Grad"][0]
-        m1, m2 = ins["Moment1"][0], ins["Moment2"][0]
-        b1p, b2p = ins["Beta1Pow"][0], ins["Beta2Pow"][0]
-        b1 = float(attrs.get("beta1", 0.9))
-        b2 = float(attrs.get("beta2", 0.999))
-        po, mo, vo = fused_adamw(
-            p, g.astype(m1.dtype), m1, m2, lr, b1, b2,
-            float(attrs.get("epsilon", 1e-8)), float(coeff),
-            b1p.reshape(()), b2p.reshape(()))
-        return {"ParamOut": po, "Moment1Out": mo, "Moment2Out": vo,
-                "Beta1PowOut": b1p * b1, "Beta2PowOut": b2p * b2}
-
+    # plain ops on purpose: XLA fuses the per-parameter update chains, and
+    # one Pallas kernel per parameter measured 18-19 ms a step slower on
+    # ERNIE-large (BASELINE.md, v5e)
     outs = adam(ins, attrs)
     if attrs.get("with_decay", True):
         outs["ParamOut"] = (outs["ParamOut"].astype(np.float32)

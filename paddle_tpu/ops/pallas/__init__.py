@@ -7,7 +7,6 @@ math/bert_encoder_functor.cu) and fused optimizer passes
 
 * flash_attention — blockwise online-softmax attention (fwd + bwd kernels),
 * layer_norm      — fused row-normalisation,
-* fused_adamw     — single-kernel parameter/moment update,
 * int8_gemm       — weight-only int8 MXU GEMM, dequant+bias+act fused
                     into the matmul epilogue (serving hot path),
 * paged_attention — decode-step attention that walks the KV page table
@@ -94,6 +93,5 @@ def kernels_fingerprint() -> str:
 
 from .flash_attention import flash_attention  # noqa: E402,F401
 from .layer_norm import fused_layer_norm  # noqa: E402,F401
-from .fused_adam import fused_adamw  # noqa: E402,F401
 from .int8_gemm import int8_weight_only_gemm  # noqa: E402,F401
 from .paged_attention import paged_decode_attention  # noqa: E402,F401

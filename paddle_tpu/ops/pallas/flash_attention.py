@@ -17,7 +17,6 @@ constraints or no TPU/interpreter backend is selected (kernel_mode()).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -155,7 +154,7 @@ def reference_attention(q, k, v, bias_kv=None, causal=False, scale=None,
 # ---------------------------------------------------------------------------
 # XLA path with recompute backward
 #
-# Measured on v5e (tools/bench_attention.py, slope timing): at d=64,
+# Measured on v5e (BASELINE.md, slope timing): at d=64,
 # s<=512 plain XLA attention with bf16 MXU dots runs ~7x faster than the
 # Pallas flash kernels (ours AND jax's stock one — both are VPU/overhead
 # bound at small head_dim). Flash's real win at those sizes is MEMORY:
@@ -754,7 +753,7 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
     HBM traffic. This is the profile-driven fix for the north-star step:
     the XLA chunked-recompute backward's scan carried full-size f32
     dk/dv accumulators through HBM every chunk (~7.5 ms/layer measured;
-    tools/profile_ernie.py); at S<=512 everything fits on-chip."""
+    BASELINE.md); at S<=512 everything fits on-chip."""
     from jax.experimental import pallas as pl
 
     q = q_ref[0]                              # (sq, d) native dtype
@@ -918,7 +917,7 @@ def _fused_bwd_kernel_g(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 # q/k/v and ctx) cost ~13.9 ms of the ERNIE step. These kernels read the
 # projection outputs DIRECTLY: the grid cell is (batch, block of g heads),
 # the block a [sq, g*hd] column slice, and the per-head "transpose" is a
-# static column slice inside VMEM. Measured (tools/exp_packed_attn.py,
+# static column slice inside VMEM. Measured (BASELINE.md,
 # b34/h16/s512/d64 + dropout): fwd 0.80 ms/layer (g=16) vs 1.00 for
 # kernel+transposes; bwd 1.48 (g=8) vs 1.81. g=16 bwd exceeds VMEM
 # (9 io blocks x 1 MB double-buffered + f32 temporaries).
@@ -1409,9 +1408,7 @@ def _pick_block(s, prefer):
     return None
 
 
-def _supported(q, k, bias_kv):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+def _supported(b, sq, sk, d, bias_kv):
     if d > 256:
         return False
     if _pick_block(sq, DEFAULT_BLOCK_Q) is None or \
@@ -1432,7 +1429,7 @@ def _pad_head_dim(x, target):
     return jnp.pad(x, pad)
 
 
-# v5e measurements (tools/bench_attention.py, slope timing, d=64, dropout
+# v5e measurements (BASELINE.md, slope timing, d=64, dropout
 # 0.1, grads taken wrt q AND k AND v — an earlier q-only grad let XLA DCE
 # the chunked path's dk/dv accumulator scan and under-measured its
 # backward 2.7x, mis-routing the ERNIE geometry until round 4):
@@ -1455,39 +1452,42 @@ PALLAS_MIN_SCORES_BYTES = 2 << 30
 FUSED_MIN_SEQ = 256
 
 
-def _impl_choice(q, k):
-    import os
+def attention_route(q, k, bias=None, num_heads=None):
+    """The one place that decides which implementation attention takes,
+    from what it can observe: the shapes, the layout (q [B,H,Sq,D], or
+    packed [B,S,n*hd] with ``num_heads``), the bias form and
+    kernel_mode(). Returns (route, bias_kv):
 
-    env = os.environ.get("PT_FLASH_IMPL", "auto").lower()
-    if env in ("pallas", "xla"):
-        return env
-    b, h, sq, _ = q.shape
-    sk = k.shape[2]
-    if sq >= FUSED_MIN_SEQ:
-        return "pallas"
-    # Below FUSED_MIN_SEQ the head-blocked fused kernels (_fused_g) are
-    # available (PT_FLASH_IMPL=pallas) and microbenchmark well in
-    # isolation (s=128 b384: fwd 0.14 ms vs 1.65 XLA, f+b 3.14 vs 3.66)
-    # — but IN-PROGRAM the BERT-base step measured 283 ms on them vs
-    # 251 ms on the XLA path (the kernel boundary defeats XLA's fusion
-    # of attention with the surrounding bias/dropout/projection ops), so
-    # auto-routing stays XLA here. Step-level measurements win.
-    scores_bytes = 4.0 * b * h * sq * sk
-    return "pallas" if scores_bytes >= PALLAS_MIN_SCORES_BYTES else "xla"
+      'packed'            the packed fused kernels, no head transposes
+      'pallas'            the bnsd kernels (fused single-block where one
+                          tile covers the row, 2-pass online-softmax above)
+      'pallas_interpret'  the same through the Pallas interpreter
+      'xla'               plain XLA attention, recompute backward
+      'reference'         the probs-saving jnp reference
+      'reference_general' the reference on a bias that is not a key bias
 
-
-def _dispatch_plan(q, k, bias):
-    """The implementation flash_attention() will take for these shapes:
-    ('pallas'|'pallas_interpret'|'xla'|'reference'|'reference_general',
-    bias_kv). bias_kv is the [B,Sk] key-bias normal form (None when bias
-    is None, or on the reference_general route which keeps the raw bias).
-    Shared by the forward, the op layer and the flash_attention_grad
-    lowering so the grad op's route always matches its forward's."""
+    bias_kv is the [B,Sk] key-bias normal form (None when bias is None,
+    or on 'reference_general', which keeps the raw bias). A packed input
+    on any route but 'packed' is transposed to bnsd by its caller. The
+    forward, the flash_attention_grad lowering (ops/attention_ops.py)
+    and flash_attention_bwd all ask here, so a grad op's route is its
+    forward's. On the 'packed' and 'pallas*' routes the forward's
+    (out, lse) are saved and the backward runs the bwd kernels alone."""
     from . import kernel_mode, mosaic_withheld
 
+    packed = len(q.shape) == 3
+    if packed:
+        b, sq, htot = q.shape
+        sk, n = k.shape[1], int(num_heads)
+        if htot % n:
+            raise ValueError(
+                f"packed width {htot} is not a multiple of num_heads={n}")
+        hd = htot // n
+    else:
+        b, n, sq, hd = q.shape
+        sk = k.shape[2]
     bias_kv = None
     if bias is not None:
-        b, sk = q.shape[0], k.shape[2]
         bias_kv = jnp.broadcast_to(bias, (b, 1, 1, sk)).reshape(b, sk) \
             if bias.ndim == 4 and bias.shape[1] == 1 and bias.shape[2] == 1 \
             else (bias if bias.ndim == 2 else None)
@@ -1499,81 +1499,33 @@ def _dispatch_plan(q, k, bias):
         # O(S)-residual XLA recompute route stands in, not the
         # probs-saving reference
         return ("xla" if mosaic_withheld() else "reference"), bias_kv
-    if mode == "tpu" and _impl_choice(q, k) == "xla":
-        return "xla", bias_kv
-    if not _supported(q, k, bias_kv):
-        import os
-        import warnings
-
-        if os.environ.get("PT_FLASH_IMPL", "").lower() == "pallas":
-            warnings.warn(
-                f"PT_FLASH_IMPL=pallas requested but shape "
-                f"q={tuple(q.shape)} k={tuple(k.shape)} fails the kernel's "
-                f"tiling constraints — falling back to the "
-                f"{'XLA recompute' if mode == 'tpu' else 'reference'} path",
-                stacklevel=3)
+    if not _supported(b, sq, sk, hd, bias_kv):
         # pallas tiling unsupported: prefer the O(S)-residual XLA
         # recompute path on TPU over the probs-saving reference path
-        return ("xla", bias_kv) if mode == "tpu" else ("reference", bias_kv)
-    return ("pallas_interpret" if mode == "interpret" else "pallas"), bias_kv
-
-
-def _packed_proxies(q, k, n_heads):
-    """4-D shape proxies for the packed [B,S,n*hd] arrays, for the
-    shape-only dispatch helpers (_impl_choice/_supported). k gets its
-    OWN sequence length — cross-attention has sq != sk."""
-    import types
-
-    b, sq, htot = q.shape
-    sk = k.shape[1]
-    hd = htot // n_heads
-    return (types.SimpleNamespace(shape=(b, n_heads, sq, hd), ndim=4),
-            types.SimpleNamespace(shape=(b, n_heads, sk, hd), ndim=4))
-
-
-def _packed_fast_applies(q, k, bias, n_heads):
-    """Whether the packed [B,S,n*hd] inputs can run the packed fused
-    kernels directly: the pallas route at a fused-single-block geometry
-    with lane-aligned head blocks. Shared by the forward and the grad
-    op so their dispatch always agrees."""
-    b, sq, htot = q.shape
-    sk = k.shape[1]
-    if htot % n_heads:
-        return False, None, None
-    hd = htot // n_heads
-    qp, kp = _packed_proxies(q, k, n_heads)
-    route, bias_kv = _dispatch_plan(qp, kp, bias)
-    if route == "xla" and os.environ.get(
-            "PT_FLASH_IMPL", "auto").lower() != "xla":
-        # the packed kernels OVERRIDE the bnsd FUSED_MIN_SEQ=256 routing:
-        # without head transposes the round-4 "XLA wins below 256"
-        # measurement flips — BERT-base (s=128 b384) measured 219.3
-        # ms/step on the packed kernels vs 250.7 on the XLA route
-        # (62.1% vs 54.3% MFU). PT_FLASH_IMPL=xla still forces XLA.
-        from . import kernel_mode
-
-        if kernel_mode() == "tpu" and _supported(qp, kp, bias_kv):
-            route = "pallas"
-    ok = (route.startswith("pallas") and sq == sk and hd % 8 == 0
-          and (n_heads * hd) % 128 == 0
-          and _fused_bwd_applies(sq, sk)
-          and _packed_g(n_heads, hd, sq, PACKED_FWD_ELEMS)
-          and _packed_g(n_heads, hd, sq, PACKED_BWD_ELEMS))
-    return bool(ok), route, bias_kv
-
-
-def packed_saved_bwd_route(q, k, bias, n_heads):
-    """The grad op's single dispatch question for packed inputs:
-    'packed' (packed kernels directly), 'bnsd' (transpose + saved-lse
-    bnsd pallas backward) or 'vjp' (recompute route — XLA CSEs the
-    re-traced standard-HLO forward). Centralised so the grad op and
-    flash_attention_bwd can never disagree."""
-    ok, _, _ = _packed_fast_applies(q, k, bias, n_heads)
-    if ok:
-        return "packed"
-    qp, kp = _packed_proxies(q, k, n_heads)
-    route, _ = _dispatch_plan(qp, kp, bias)
-    return "bnsd" if route.startswith("pallas") else "vjp"
+        return ("xla" if mode == "tpu" else "reference"), bias_kv
+    # the packed kernels come BEFORE the bnsd FUSED_MIN_SEQ=256 routing:
+    # without head transposes the round-4 "XLA wins below 256"
+    # measurement flips — BERT-base (s=128 b384) measured 219.3 ms/step
+    # on the packed kernels vs 250.7 on the XLA route (62.1% vs 54.3%
+    # MFU). They need a fused-single-block geometry with lane-aligned
+    # head blocks.
+    if (packed and sq == sk and hd % 8 == 0 and (n * hd) % 128 == 0
+            and _fused_bwd_applies(sq, sk)
+            and _packed_g(n, hd, sq, PACKED_FWD_ELEMS)
+            and _packed_g(n, hd, sq, PACKED_BWD_ELEMS)):
+        return "packed", bias_kv
+    if mode == "interpret":
+        return "pallas_interpret", bias_kv
+    # Below FUSED_MIN_SEQ the head-blocked fused kernels (_fused_g)
+    # microbenchmark well in isolation (s=128 b384: fwd 0.14 ms vs 1.65
+    # XLA, f+b 3.14 vs 3.66) — but IN-PROGRAM the BERT-base step measured
+    # 283 ms on them vs 251 ms on the XLA path (the kernel boundary
+    # defeats XLA's fusion of attention with the surrounding
+    # bias/dropout/projection ops), so bnsd inputs stay on XLA here unless
+    # XLA cannot hold the scores at all. Step-level measurements win.
+    if sq < FUSED_MIN_SEQ and 4.0 * b * n * sq * sk < PALLAS_MIN_SCORES_BYTES:
+        return "xla", bias_kv
+    return "pallas", bias_kv
 
 
 def _packed_to_bnsd(x, n_heads):
@@ -1606,7 +1558,7 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
         sq >= FUSED_MIN_SEQ; the scores-bytes threshold
         (PALLAS_MIN_SCORES_BYTES) additionally forces pallas where XLA
         cannot even compile (e.g. s=4096).
-    Override with PT_FLASH_IMPL=pallas|xla.
+    attention_route() decides between them.
     """
     out, _ = flash_attention_fwd_lse(q, k, v, bias, causal, scale,
                                      dropout_rate, dropout_seed,
@@ -1641,7 +1593,7 @@ def flash_attention_fwd_lse(q, k, v, bias=None, causal=False, scale=None,
     rate = float(dropout_rate or 0.0)
     seed = jnp.asarray(0 if dropout_seed is None else dropout_seed,
                        jnp.uint32)
-    route, bias_kv = _dispatch_plan(q, k, bias)
+    route, bias_kv = attention_route(q, k, bias)
     if route == "reference_general":
         out = reference_attention(q, k, v, bias, causal, scale, rate, seed)
     elif route == "reference":
@@ -1672,12 +1624,14 @@ def _packed_fwd_lse(q, k, v, bias, causal, scale, dropout_rate,
     rate = float(dropout_rate or 0.0)
     seed = jnp.asarray(0 if dropout_seed is None else dropout_seed,
                        jnp.uint32)
-    ok, route, bias_kv = _packed_fast_applies(q, k, bias, n_heads)
-    if ok:
+    route, bias_kv = attention_route(q, k, bias, n_heads)
+    if route == "packed":
+        from . import interpret_mode
+
         if rate > 0.0:
             _warn_lattice_wrap(sq, sq)
         return _flash_packed(q, k, v, bias_kv, seed, causal, scale,
-                             route == "pallas_interpret", rate, n_heads)
+                             interpret_mode(), rate, n_heads)
     out4, lse = flash_attention_fwd_lse(
         _packed_to_bnsd(q, n_heads), _packed_to_bnsd(k, n_heads),
         _packed_to_bnsd(v, n_heads), bias, causal, scale, dropout_rate,
@@ -1693,19 +1647,16 @@ def flash_attention_bwd(q, k, v, bias, out, lse, dout, causal=False,
     pallas custom-call, which XLA cannot CSE with the forward op's;
     measured ~0.8 ms/layer of pure duplicate work on ERNIE-large).
 
-    Only valid on the pallas routes — callers must check
-    _dispatch_plan(q, k, bias)[0].startswith('pallas') (or, packed,
-    _packed_fast_applies) first.
+    Only valid where attention_route() says 'packed' or 'pallas*' —
+    callers check first.
     Returns (dq, dk, dv, dbias_kv); dbias_kv is [B,Sk] (the key-bias
     normal form) or None when bias is None."""
     if q.ndim == 3:
+        from . import interpret_mode
+
         n = int(num_heads)
-        kind = packed_saved_bwd_route(q, k, bias, n)
-        if kind == "vjp":
-            raise ValueError(
-                "flash_attention_bwd(packed) on a non-pallas route "
-                "— the grad op should have taken the vjp fallback")
-        if kind == "bnsd":
+        route, bias_kv = attention_route(q, k, bias, n)
+        if route.startswith("pallas"):
             # packed model at a non-packed geometry (e.g. long context
             # s >= 2048, or cross-attention sq != sk): the forward
             # transposed internally to the bnsd pallas path and its
@@ -1720,16 +1671,18 @@ def flash_attention_bwd(q, k, v, bias, out, lse, dout, causal=False,
                 dropout_seed=dropout_seed)
             return (_bnsd_to_packed(dq4), _bnsd_to_packed(dk4),
                     _bnsd_to_packed(dv4), dbias)
-        _, route, bias_kv = _packed_fast_applies(q, k, bias, n)
+        if route != "packed":
+            raise ValueError(
+                f"flash_attention_bwd(packed) on the '{route}' route "
+                f"— the grad op should have taken the vjp fallback")
         hd = q.shape[-1] // n
         scale = float(scale) if scale is not None \
             else 1.0 / float(np.sqrt(hd))
         seed = jnp.asarray(0 if dropout_seed is None else dropout_seed,
                            jnp.uint32)
         dq, dk, dv, dbias = _bwd_pallas_packed(
-            q, k, v, bias_kv, causal, scale,
-            route == "pallas_interpret", out, lse, dout, seed,
-            float(dropout_rate or 0.0), n)
+            q, k, v, bias_kv, causal, scale, interpret_mode(), out, lse,
+            dout, seed, float(dropout_rate or 0.0), n)
         if dbias is not None and bias_kv is not None:
             dbias = dbias.astype(bias_kv.dtype)
         return dq, dk, dv, dbias
@@ -1738,7 +1691,7 @@ def flash_attention_bwd(q, k, v, bias, out, lse, dout, causal=False,
     rate = float(dropout_rate or 0.0)
     seed = jnp.asarray(0 if dropout_seed is None else dropout_seed,
                        jnp.uint32)
-    route, bias_kv = _dispatch_plan(q, k, bias)
+    route, bias_kv = attention_route(q, k, bias)
     if not route.startswith("pallas"):
         raise ValueError(
             f"flash_attention_bwd called on the '{route}' route — the "

@@ -12,8 +12,9 @@ Capability mirror of the reference profiler stack:
 * device-side tracing (platform/device_tracer.cc CUPTI) maps to the jax
   profiler (XPlane/TensorBoard): ``start_trace``/``stop_trace``.
 
-The executor pushes spans automatically: per-op in the interpreting path,
-per-step (compile + run) in the compiled path.
+The executor pushes spans automatically: per-op in the interpreting path
+(RecordEvent in run_op), per-step (compile + run) in the compiled path,
+where they come from its telemetry timers (``_timer_event``).
 """
 
 from __future__ import annotations
@@ -84,6 +85,16 @@ class RecordEvent:
 def record_event(name: str):
     with RecordEvent(name):
         yield
+
+
+def _timer_event(name: str, attrs) -> Optional[RecordEvent]:
+    """What telemetry.timer(span=name) records while the profiler is on:
+    the executor's per-step events (executor::run, executor::compile)
+    come from its timers."""
+    return RecordEvent(name) if _enabled else None
+
+
+_telemetry.attach_span(_timer_event)
 
 
 def is_profiler_enabled() -> bool:

@@ -92,7 +92,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..core import costmodel, faults, incidents, telemetry
+from ..core import costmodel, faults, telemetry
 from ..core import flags as _flags
 from ..core.flags import flag as _flag
 from .admission import (AdmissionQueue, DeadlineExceededError,
@@ -718,9 +718,9 @@ class DecodeEngine:
                         self._retire(req, error=err)
                     self._active = []
                 telemetry.gauge_set("decode.active_slots", len(self._active))
-                # SLO watchdog hook (core/incidents.py): queue saturation /
-                # step-time regression rules evaluate on the step cadence
-                incidents.tick()
+                # the step's one hook: whoever subscribed (the SLO watchdog's
+                # queue-saturation and step-time rules) runs on this cadence
+                telemetry.tick()
             if "decode.step_ms" in it:
                 # what the iteration spent outside its phases: the deadline
                 # scan, the journal tick, the gauge, the watchdog, the timers

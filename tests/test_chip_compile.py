@@ -52,7 +52,6 @@ def tpu_mode(monkeypatch):
     steer them onto their compiled-kernel route for the trace."""
     import paddle_tpu.ops.pallas as pallas
 
-    monkeypatch.delenv("PT_FLASH_IMPL", raising=False)
     monkeypatch.delenv("PT_PALLAS", raising=False)
     monkeypatch.setattr(pallas, "_requested_mode", lambda: "tpu")
 
@@ -158,15 +157,6 @@ def test_flash_attention_fwd_bwd(one_chip, tpu_mode, layout):
                     (qkv, BF16), (qkv, BF16), (qkv, BF16),
                     ((_B, _S), F32), ((), jnp.uint32))
     assert text.count("tpu_custom_call") >= 2          # fwd and bwd
-
-
-def test_fused_adamw(one_chip, tpu_mode):
-    from paddle_tpu.ops.pallas.fused_adam import fused_adamw
-
-    w = ((1024, 4096), F32)
-    _compile(lambda p, g, m, v, lr, b1, b2: fused_adamw(
-        p, g, m, v, lr, 0.9, 0.999, 1e-8, 0.01, b1, b2), one_chip,
-        w, w, w, w, ((), F32), ((), F32), ((), F32))
 
 
 def test_step_sampler_at_the_xglm_vocabulary(one_chip):

@@ -172,3 +172,42 @@ def test_weight_tied_param_stays_fp(scope):
     got, = exe.run(prog, feed=feed, fetch_list=[logits], scope=scope)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-5)
+
+
+def test_weight_only_conv_reads_through_dequantize_weight(scope):
+    """A quantizable op outside the matmul family (conv2d) keeps its
+    stock lowering and reads its int8 weight through `dequantize_weight`:
+    the converted program runs and answers what the fp program answers
+    with the same weights rounded to int8."""
+    from paddle_tpu.core import ir, unique_name
+
+    ir._main_program, ir._startup_program = ir.Program(), ir.Program()
+    unique_name.switch()
+    infer, startup = pt.Program(), pt.Program()
+    with pt.program_guard(infer, startup):
+        x = layers.static_data("x", [-1, 3, 8, 8], "float32")
+        h = layers.conv2d(x, num_filters=4, filter_size=3, act="relu")
+        logits = layers.reduce_mean(h, dim=[2, 3])
+    pt.Executor().run(startup, scope=scope, use_compiled=False)
+    xs = np.random.RandomState(3).randn(6, 3, 8, 8).astype(np.float32)
+    fp, = AnalysisPredictor(AnalysisConfig(), program=infer,
+                            feed_names=["x"], fetch_names=[logits.name],
+                            scope=scope).run({"x": xs})
+
+    int8_scope = pt.Scope()
+    int8_scope._vars = {k: np.copy(v) for k, v in scope.items()}
+    prog = slim.convert_to_int8_program(infer.clone(for_test=True),
+                                        int8_scope, act_scales=None)
+    types = [op.type for op in prog.global_block().ops]
+    assert "dequantize_weight" in types and "conv2d" in types, types
+    for op in prog.global_block().ops:
+        if op.type == "dequantize_weight":
+            w8 = np.asarray(int8_scope.find_var(op.inputs["X"][0]))
+            assert w8.dtype == np.int8
+    q, = AnalysisPredictor(AnalysisConfig(), program=prog,
+                           feed_names=["x"], fetch_names=[logits.name],
+                           scope=int8_scope).run({"x": xs})
+    # int8 weights carry 1/254 of a channel's range: outputs of order 1
+    # move in the second decimal, never in the first
+    assert np.abs(q - fp).max() < 0.05 * max(1.0, np.abs(fp).max())
+    assert np.abs(q - fp).max() > 0.0

@@ -304,18 +304,110 @@ class TestFusedLayerNorm:
             np.testing.assert_allclose(a, b_, atol=5e-5)
 
 
-class TestFusedAdamW:
-    def test_matches_unfused(self, interpret_mode):
-        from paddle_tpu.ops.pallas.fused_adam import fused_adamw
+def _route_case(layout, b, n, sq, sk, hd):
+    """(q, k) shapes only: the route function reads nothing else."""
+    if layout == "packed":
+        return (jax.ShapeDtypeStruct((b, sq, n * hd), jnp.bfloat16),
+                jax.ShapeDtypeStruct((b, sk, n * hd), jnp.bfloat16), n)
+    return (jax.ShapeDtypeStruct((b, n, sq, hd), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, n, sk, hd), jnp.bfloat16), None)
 
-        p, g = _rand(300, 70, seed=0), _rand(300, 70, seed=1)
-        m, v = jnp.zeros_like(p), jnp.zeros_like(p)
-        args = (0.001, 0.9, 0.999, 1e-8, 0.01, 0.9, 0.999)
-        got = fused_adamw(p, g, m, v, *args)
-        os.environ["PT_PALLAS"] = "off"
-        want = fused_adamw(p, g, m, v, *args)
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(a, b, atol=1e-6)
+
+def _bias(form, b, sk):
+    return {None: None,
+            "key4": jnp.zeros((b, 1, 1, sk), jnp.float32),
+            "key2": jnp.zeros((b, sk), jnp.float32),
+            "general": jnp.zeros((b, 1, 8, sk), jnp.float32)}[form]
+
+
+# mode, layout, (b, n, sq, sk, hd), bias form -> route. The routes are
+# the ones the three functions this table replaced gave for these shapes.
+ROUTE_TABLE = [
+    # cell 1 of the benchmark: ERNIE-large b40 s512 16x64, packed
+    ("tpu", "packed", (40, 16, 512, 512, 64), "key4", "packed"),
+    # BERT-base s128 b384: packed kernels come before FUSED_MIN_SEQ
+    ("tpu", "packed", (384, 12, 128, 128, 64), "key4", "packed"),
+    ("interpret", "packed", (2, 4, 256, 256, 64), "key4", "packed"),
+    # bnsd below FUSED_MIN_SEQ: XLA wins in-program on a TPU
+    ("tpu", "bnsd", (384, 12, 128, 128, 64), None, "xla"),
+    ("off", "bnsd", (384, 12, 128, 128, 64), None, "reference"),
+    ("interpret", "bnsd", (2, 2, 128, 128, 64), None, "pallas_interpret"),
+    # past one tile a row: the two-pass kernels, either layout
+    ("tpu", "bnsd", (4, 16, 2048, 2048, 64), "key2", "pallas"),
+    ("tpu", "packed", (4, 16, 2048, 2048, 64), None, "pallas"),
+    # s4096: scores XLA cannot hold
+    ("tpu", "bnsd", (4, 16, 4096, 4096, 64), None, "pallas"),
+    # sq < FUSED_MIN_SEQ but 4*b*h*sq*sk >= PALLAS_MIN_SCORES_BYTES
+    ("tpu", "bnsd", (64, 16, 128, 4096, 64), None, "pallas"),
+    # cross-attention sq != sk: never the packed kernels
+    ("tpu", "packed", (2, 4, 256, 128, 64), "key4", "pallas"),
+    ("tpu", "packed", (2, 4, 128, 256, 64), "key4", "xla"),
+    ("interpret", "packed", (2, 4, 256, 128, 64), "key4",
+     "pallas_interpret"),
+    # shapes the kernels cannot tile
+    ("tpu", "packed", (2, 4, 1000, 1000, 64), None, "xla"),
+    ("interpret", "packed", (2, 4, 1000, 1000, 64), None, "reference"),
+    # tiles, but the head blocks are not lane-aligned: bnsd kernels
+    ("interpret", "packed", (2, 4, 40, 40, 16), None, "pallas_interpret"),
+    # a bias that is not a key bias
+    ("tpu", "bnsd", (2, 4, 512, 512, 64), "general", "reference_general"),
+    # a step XLA partitions itself holds no Mosaic kernel
+    ("auto_partitioned", "packed", (40, 16, 512, 512, 64), "key4", "xla"),
+]
+
+
+@pytest.mark.parametrize("mode,layout,dims,bias_form,want", ROUTE_TABLE)
+def test_flash_route_table(monkeypatch, mode, layout, dims, bias_form, want):
+    """attention_route() is the one place that picks the implementation;
+    the forward and the grad op both take its answer."""
+    import contextlib
+    import importlib
+
+    import paddle_tpu.ops.pallas as pallas
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.delenv("PT_PALLAS", raising=False)
+    scope = contextlib.nullcontext()
+    if mode == "auto_partitioned":
+        mode, scope = "tpu", pallas.auto_partitioned()
+    monkeypatch.setattr(pallas, "_requested_mode", lambda: mode)
+    b, n, sq, sk, hd = dims
+    q, k, heads = _route_case(layout, *dims)
+    bias = _bias(bias_form, b, sk)
+    with scope:
+        route, bias_kv = fa.attention_route(q, k, bias, heads)
+    assert route == want
+    if bias_form in ("key4", "key2"):
+        assert bias_kv.shape == (b, sk)
+    else:
+        assert bias_kv is None
+
+    if mode != "interpret":
+        return
+    # small enough to run: the forward op and the grad op each ask the
+    # route function first, and get this row's answer
+    from paddle_tpu.ops import attention_ops
+
+    asked = []
+    route_fn = fa.attention_route
+    monkeypatch.setattr(
+        fa, "attention_route",
+        lambda *a, **kw: asked.append(route_fn(*a, **kw)) or asked[-1])
+    rng = np.random.RandomState(0)
+    qa, ka = (jnp.asarray(rng.randn(*s.shape).astype(np.float32) * 0.3)
+              for s in (q, k))
+    ins = {"Q": [qa], "K": [ka], "V": [ka]}
+    if bias is not None:
+        ins["Bias"] = [bias]
+    attrs = {"num_heads": n, "head_dim": hd, "is_test": True}
+    fwd = attention_ops.flash_attention_op(ins, attrs)
+    assert asked[0][0] == want
+    del asked[:]
+    grads = attention_ops.flash_attention_grad_op(
+        dict(ins, Out=[fwd["Out"]], Lse=[fwd["Lse"]],
+             OutGrad=[jnp.ones_like(fwd["Out"])]), attrs)
+    assert asked[0][0] == want
+    assert grads["QGrad"].shape == q.shape
 
 
 class TestFlashAttentionInProgram:
@@ -527,7 +619,7 @@ class TestPackedLayout:
             for _ in range(3))
         bias = jnp.asarray(np.where(rng.rand(B, 1, 1, S) < 0.2,
                                     -10000.0, 0.0).astype(np.float32))
-        assert fa._packed_fast_applies(q3, k3, bias, N)[0]
+        assert fa.attention_route(q3, k3, bias, N)[0] == "packed"
         out_p, lse_p = fa.flash_attention_fwd_lse(
             q3, k3, v3, bias=bias, dropout_rate=0.1,
             dropout_seed=jnp.uint32(5), num_heads=N)
@@ -597,7 +689,7 @@ class TestPackedLayout:
         q3, k3, v3 = (jnp.asarray(
             rng.randn(B, S, N * D).astype(np.float32) * 0.3)
             for _ in range(3))
-        assert not fa._packed_fast_applies(q3, k3, None, N)[0]
+        assert fa.attention_route(q3, k3, None, N)[0] == "pallas_interpret"
         out_p, _ = fa.flash_attention_fwd_lse(q3, k3, v3, num_heads=N)
         ref = fa.reference_attention(
             fa._packed_to_bnsd(q3, N), fa._packed_to_bnsd(k3, N),
@@ -621,7 +713,7 @@ class TestPackedLayout:
         v3 = jnp.asarray(rng.randn(B, SK, N * D).astype(np.float32) * 0.3)
         bias = jnp.asarray(np.where(rng.rand(B, 1, 1, SK) < 0.2,
                                     -10000.0, 0.0).astype(np.float32))
-        assert not fa._packed_fast_applies(q3, k3, bias, N)[0]
+        assert fa.attention_route(q3, k3, bias, N)[0] == "pallas_interpret"
         out_p, _ = fa.flash_attention_fwd_lse(q3, k3, v3, bias=bias,
                                               num_heads=N)
         ref = fa.reference_attention(

@@ -252,3 +252,107 @@ def test_generate_answers_with_each_tokens_time_since_submit():
     assert doc["token_ms"][0] == doc["ttft_ms"]
     assert doc["token_ms"] == sorted(doc["token_ms"])
     assert doc["token_ms"][-1] <= doc["latency_ms"]
+
+
+# -- the hot paths know telemetry and nothing built on it -------------------
+
+PLANES_ABOVE = ("paddle_tpu.core.trace", "paddle_tpu.core.incidents",
+                "paddle_tpu.core.goodput", "paddle_tpu.profiler")
+
+
+def _imports(path, package):
+    """(absolute module name, enclosing function or None) of every import
+    in the file, at module or function level."""
+    import ast
+
+    tree = ast.parse(open(path).read())
+    found = []
+
+    def visit(node, func):
+        for child in ast.iter_child_nodes(node):
+            inside = child.name if isinstance(
+                child, (ast.FunctionDef, ast.AsyncFunctionDef)) else func
+            if isinstance(child, ast.Import):
+                found.extend((a.name, func) for a in child.names)
+            elif isinstance(child, ast.ImportFrom):
+                base = package.split(".")
+                base = base[:len(base) - (child.level - 1)] \
+                    if child.level else []
+                mod = ".".join(base + ([child.module] if child.module
+                                       else []))
+                found.append((mod, func))
+                # `from . import a, b` names modules, not attributes
+                found.extend((f"{mod}.{a.name}", func) for a in child.names)
+            visit(child, inside)
+
+    visit(tree, None)
+    return found
+
+
+@pytest.mark.parametrize("rel,package", [
+    ("paddle_tpu/core/executor.py", "paddle_tpu.core"),
+    ("paddle_tpu/serving/decode.py", "paddle_tpu.serving")])
+def test_the_hot_paths_import_none_of_the_planes_built_on_them(rel, package):
+    """core/executor.py and serving/decode.py reach trace spans, profiler
+    events, the SLO watchdog and the goodput ledger through telemetry's
+    timer(span=) and tick(), never by name. run_op's per-op RecordEvent on
+    the interpreted path is the reference's profiler capability and keeps
+    its import."""
+    found = _imports(os.path.join(REPO, rel), package)
+    assert ("paddle_tpu.core.telemetry", None) in found
+    banned = [(mod, func) for mod, func in found
+              if mod in PLANES_ABOVE and func != "run_op"]
+    assert banned == []
+    text = open(os.path.join(REPO, rel)).read()
+    for needle in ("trace.span(", "trace.record(", "trace.current(",
+                   'RecordEvent("executor', "incidents.", "goodput.tick"):
+        assert needle not in text
+
+
+@pytest.mark.parametrize("armed", [True, False])
+def test_one_tick_a_step_reaches_whoever_subscribed(armed):
+    """An armed watchdog rule set and an open goodput window see an
+    executor step and a decode step through telemetry.tick(); in a
+    process that armed nothing, a step adds no record of either."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers
+    from paddle_tpu.core import goodput, incidents, telemetry
+    from paddle_tpu.core.flags import flag, set_flags
+    from paddle_tpu.serving.decode import DecodeConfig, demo_engine
+
+    before = {k: flag(k) for k in ("slo_eval_s", "goodput_publish_s")}
+    telemetry.reset()
+    incidents.reset()
+    goodput.reset()
+    set_flags({"slo_eval_s": 0.0, "goodput_publish_s": 0.0})
+    try:
+        if armed:
+            incidents.arm()
+            goodput.start_run()
+        main, startup = pt.Program(), pt.Program()
+        with pt.program_guard(main, startup):
+            x = layers.data("x", [4], stop_gradient=True)
+            loss = layers.mean(layers.fc(x, 8))
+        scope, exe = pt.Scope(), pt.Executor()
+        exe.run(startup, scope=scope, use_compiled=False)
+        xv = np.ones((2, 4), np.float32)
+        for _ in range(3):          # one run that compiles, two that do not
+            exe.run(main, feed={"x": xv}, fetch_list=[loss], scope=scope)
+        after_exe = telemetry.counter_get("slo.evaluations")
+        assert ("goodput.ratio" in telemetry.gauges()) == armed
+        assert (after_exe >= 3) == armed and (after_exe == 0) != armed
+
+        engine = demo_engine(DecodeConfig(
+            max_slots=2, kv_pages=32, page_size=4)).start(warmup=True)
+        try:
+            engine.generate([5, 6, 7], max_new_tokens=4, stop_at_eos=False,
+                            timeout=60)
+        finally:
+            engine.close()
+        after_decode = telemetry.counter_get("slo.evaluations")
+        assert (after_decode > after_exe) == armed
+        assert (after_decode == 0) != armed
+    finally:
+        incidents.reset()
+        goodput.reset()
+        set_flags(before)

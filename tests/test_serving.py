@@ -1,8 +1,11 @@
 """Serving-engine tests: dynamic micro-batching behind admission control.
 
 Contracts under test (paddle_tpu/serving/):
-* coalesced + padded batches return responses bitwise-identical to
-  unbatched AnalysisPredictor.run of the same rows, across buckets;
+* coalesced + padded batches return responses within 2 ulp of float32 of
+  unbatched AnalysisPredictor.run of the same rows, across buckets (a
+  bucket's program and the unbatched one are two XLA programs, whose
+  matmuls may round the last bit differently), and bitwise-identical
+  ones when the same rows go through the same bucket twice;
 * partial batches flush on the batch timeout;
 * a saturated queue rejects with ServerOverloadedError (never stalls);
 * warmup pre-compiles every bucket exactly once;
@@ -62,10 +65,19 @@ def _rows(n, seed=0):
     return np.random.RandomState(seed).randn(n, IN_DIM).astype(np.float32)
 
 
+def _assert_within_2ulp(got, want):
+    """Two XLA programs of the same float32 math: equal to 2 ulp at the
+    outputs' magnitude."""
+    atol = 2 * float(np.spacing(np.float32(np.abs(want).max())))
+    np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+
+
 class TestBatchedEquivalence:
-    def test_batched_bitwise_identical_across_buckets(self, tmp_path):
+    def test_batched_matches_unbatched_across_buckets(self, tmp_path):
         """Requests of 1..8 rows — coalesced, padded to pow2 buckets —
-        must be BITWISE equal to single-request predictor runs."""
+        must equal single-request predictor runs to the last bits, and
+        the same rows through the same bucket twice must be BITWISE
+        equal."""
         model_dir = _save_mlp(tmp_path)
         reference = _predictor(model_dir)
         engine = _engine(model_dir).start(warmup=True)
@@ -77,8 +89,14 @@ class TestBatchedEquivalence:
                 got, = req.result(timeout=30)
                 want, = reference.run({"x": f})
                 assert got.shape == (f.shape[0], OUT_DIM)
-                assert np.array_equal(got, want), \
-                    "batched output differs bitwise from unbatched run"
+                _assert_within_2ulp(got, want)
+            # one request at a time is alone in its batch: the same
+            # bucket's program runs it both times
+            for f in feeds:
+                first, = engine.infer({"x": f}, timeout=30)
+                again, = engine.infer({"x": f}, timeout=30)
+                assert np.array_equal(first, again), \
+                    "one bucket's program gave two answers for the same rows"
         finally:
             engine.close(drain=True, timeout=10)
 
@@ -98,8 +116,9 @@ class TestBatchedEquivalence:
             f = _rows(1, seed=100 + i)
             got, = engine.infer({"x": f}, timeout=30)
             want, = reference.run({"x": f})
+            _assert_within_2ulp(got, want)
             with lock:
-                results[i] = np.array_equal(got, want)
+                results[i] = True
 
         try:
             # the 20 ms batch window is far wider than the thread-start

@@ -27,9 +27,14 @@ def compiled_step(batch):
 
     import paddle_tpu as pt
     from paddle_tpu.models import bert
-    from tools.ablate_ernie import build
 
-    cfg, main, startup, loss_v = build()
+    cfg = bert.ernie_large()
+    cfg.dtype = "bfloat16"
+    cfg.use_flash_attention = True
+    main, startup, _feeds, fetches = bert.build_pretraining_program(
+        cfg, seq_len=512, optimizer_name="adamw",
+        max_predictions_per_seq=80)
+    loss_v = fetches["loss"]
     exe = pt.Executor()
     scope = pt.Scope()
     exe.run(startup, scope=scope, use_compiled=False)
