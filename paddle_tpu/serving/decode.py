@@ -28,6 +28,25 @@ caching, on the repo's frozen-program stack:
   ``max_slots``, which ALSO pins the step shapes — per-row math is then
   independent of occupancy, keeping continuous-batched generations
   BITWISE-identical to sequential one-request-at-a-time decode).
+* **One step ahead.** The loop dispatches step k+1 before it fetches step
+  k's tokens, so the host's feed, launch, fetch and accept run while the
+  device has a step to work on. A step's tokens stay on the device: the
+  step program writes them to ``last_tokens`` (int32 ``[max_slots]``,
+  indexed by the slot a seated request keeps until it retires) and the next
+  step reads a continuing row's input token from there; the host feeds a
+  token only for a row's first step. Which rows step k+1 holds is decided
+  from the host's counts: a request whose ``max_new_tokens`` step k reaches
+  is left out; one that may end on ``eos_id`` or on its deadline is
+  dispatched on speculation, and if step k ended it, its token of k+1 is
+  thrown away when k+1 is accepted (``decode.rows_discarded``). An
+  admission keeps the pipe full: the prefill is dispatched behind step k,
+  step k+1 behind the prefill, k's tokens are accepted, and only then does
+  the host wait for the prefill's logits, choose the first token and seat
+  the request, under k+1; it joins at k+2. The pipe is drained (the step
+  in flight fetched and accepted) where the host must block with nothing
+  queued behind: on a second prefill of one poll, on a shipment, before
+  the thread sleeps and before it ends. A failed step fails the rows of
+  both steps. Depth is one, always.
 * **Paged KV cache.** Pages come from the preallocated
   ``KVPagePool`` (kv_cache.py); the pool arrays are threaded through
   the step program and donated to the jit so XLA updates them in place.
@@ -53,7 +72,8 @@ row's temperature and the inverse CDF at the row's uniform), and the
 the host while the step's feed is built, one per token in token order,
 from the request's own pinned ``np.random.RandomState``, so the random
 stream, the journal's ``rng_state`` and the seed contract are the host's
-as before. A request's FIRST token is still chosen on the host
+as before (the draw made for a step in flight is ahead of the accepted
+tokens: a journal record holds the state noted before it). A request's FIRST token is still chosen on the host
 (``GenerationRequest.sample``) from the logits row that the prefill,
 chunked-prefill and shipped-prefill paths hand over. Either way token
 selection is a function of the row's own logits, temperature and the
@@ -66,7 +86,13 @@ per-request error, pages are freed, the queue keeps moving) and
 
 Telemetry: decode.requests/rejects/deadline_expired (admission),
 decode.prefills / prefill_tokens / steps / tokens / retired / errors /
-kv_refusals / kv_pages_allocated / kv_pages_freed counters,
+kv_refusals / kv_pages_allocated / kv_pages_freed counters (steps, tokens
+and batch_occupancy count at accept time what was delivered),
+decode.steps_ahead (steps dispatched while the step before was not yet
+fetched: over decode.steps the share of steps that ran ahead; the rest
+went into an empty pipe: an engine's first step, one behind a second
+prefill of one poll or a shipment) and
+decode.rows_discarded (speculative rows whose token was thrown away),
 decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
 histogram; for a model with ring layers decode.rows_past_window and
 decode.kv_tokens_attended (keys read a step, over rows and layers: a ring
@@ -74,12 +100,13 @@ layer reads min(context, window)), and whatever counters the model's step
 program returns beside its tokens (``ServedModel.step_counters``: the
 routed-expert counts of models/afmoe.py), fetched in the step's one fetch; decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
-section and /v1/stats. Every loop iteration that runs a step also records
-its phases, which add up to it: decode.loop_ms = decode.admit_ms +
-decode.feed_ms + decode.step_ms (of which decode.fetch_ms is the chosen
-tokens' fetch: the host waits out the step program there) +
-decode.sample_ms (accepting the tokens row by row) + decode.retire_ms +
-decode.other_ms; per token decode.token_gap_ms, per request
+section and /v1/stats. Every accepted step also records the phases of the
+loop since the one before, which add up to it: decode.loop_ms (the step
+period, once the loop runs ahead) = decode.admit_ms + decode.feed_ms +
+decode.step_ms (the launch of the next step and the wait for this one's
+tokens: decode.fetch_ms, where the host waits out what is left of the
+step program) + decode.sample_ms (accepting the tokens row by row) +
+decode.retire_ms + decode.other_ms; per token decode.token_gap_ms, per request
 decode.queue_wait_ms (submit to the start of its prefill). Each timer is
 also a ``TraceAnnotation`` of the same name in a running profiler trace.
 """
@@ -88,7 +115,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -110,7 +137,6 @@ from .prefix_store import PrefixStore
 # accepting of the step's tokens without them)
 _LOOP_PHASES = ("decode.admit_ms", "decode.feed_ms", "decode.step_ms",
                 "decode.sample_ms", "decode.retire_ms")
-
 
 def _pow2_ladder(lo: int, hi: int) -> List[int]:
     out, b = [], lo
@@ -221,7 +247,8 @@ class GenerationRequest(InferenceRequest):
                  "eos_id", "tokens", "token_walls", "t_submit", "t_first",
                  "pages", "table_row", "pos_next", "last_token",
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
-                 "stop_at_eos", "ring_pages", "ring_row", "first_logits")
+                 "stop_at_eos", "ring_pages", "ring_row", "first_logits",
+                 "slot", "carried", "ahead", "_rng_cut")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -258,8 +285,19 @@ class GenerationRequest(InferenceRequest):
         # False, or True to keep the prefill's logits row (float32) here:
         # how a check compares logits where the engine gives them out
         self.first_logits: Any = False
+        # the position and, for a request's first step, the token its next
+        # step is fed; ``pos_next`` moves on when a step is DISPATCHED
         self.pos_next = 0
         self.last_token = 0
+        # the step entry's ``last_tokens`` index while seated; whether the
+        # request's input token lives there (it has been in a dispatched
+        # step); tokens of steps in flight, dispatched and not yet accepted
+        self.slot: Optional[int] = None
+        self.carried = False
+        self.ahead = 0
+        # a journaled request's sampler state before the draw made for a
+        # step in flight (journal_record)
+        self._rng_cut: Any = None
         # prefix-store block hashes this request holds a reference on
         # (serving/prefix_store.py) — released at retirement
         self.shared_blocks: List[str] = []
@@ -305,7 +343,9 @@ class GenerationRequest(InferenceRequest):
         generation bitwise-identically (serving/session.py): the prompt
         (plus its page-chain hash for affinity), EVERY accepted token —
         prior lives included — the sampler RNG state after those draws,
-        and the deadline remainder. Engine-thread-only (reads _rng)."""
+        and the deadline remainder. The draw made ahead for a step in
+        flight is not among them: the state is the one noted before it
+        (``_rng_cut``). Engine-thread-only (reads _rng)."""
         from .prefix_store import prefix_chain_hash
 
         rem = None
@@ -315,6 +355,10 @@ class GenerationRequest(InferenceRequest):
                       * 1e3)
         from .session import pack_rng_state
 
+        rng = self._rng
+        if self.ahead and self.temperature > 0:
+            rng = np.random.RandomState()
+            rng.set_state(self._rng_cut)
         return {
             "request_id": self.session_id,
             "prompt": [int(t) for t in self.prompt],
@@ -324,7 +368,7 @@ class GenerationRequest(InferenceRequest):
             "temperature": self.temperature,
             "seed": self.seed,
             "stop_at_eos": self.stop_at_eos,
-            "rng_state": pack_rng_state(self._rng)
+            "rng_state": pack_rng_state(rng)
             if self.temperature > 0 else None,
             "deadline_remaining_ms": rem,
         }
@@ -343,6 +387,24 @@ class ShipPrefillRequest(InferenceRequest):
     def __init__(self, prompt: np.ndarray, deadline: Optional[float]):
         super().__init__({"prompt": prompt}, 1, deadline)
         self.prompt = prompt
+
+
+class _Prefilled(NamedTuple):
+    """A request whose prefill is dispatched and whose logits row the host
+    has not fetched yet."""
+
+    req: "GenerationRequest"
+    pages: List[int]                # what _admit allocated, to free if it fails
+    seat: Any                       # () -> None: wait for the row, seat req
+
+
+class _StepInFlight(NamedTuple):
+    """A dispatched decode step whose tokens the host has not fetched."""
+
+    rows: List[GenerationRequest]   # in the program's row order
+    bucket: int
+    chosen: Any                     # device int32 [bucket] (+ step_counts)
+    positions: np.ndarray           # the rows' positions, int32 [len(rows)]
 
 
 class DecodeEngine:
@@ -396,6 +458,16 @@ class DecodeEngine:
         self.prefix_store = PrefixStore(self.pool) \
             if self.config.prefix_cache else None
         self._active: List[GenerationRequest] = []
+        # a seated request's slot: its index into ``_last_tokens``, the
+        # device's own copy of every slot's latest token (int32
+        # [max_slots]), which each step program reads its continuing rows'
+        # input from and writes its chosen tokens to
+        self._free_slots = list(range(self.config.max_slots))[::-1]
+        self._last_tokens = jnp.zeros((self.config.max_slots,), jnp.int32)
+        # the step that was dispatched and whose tokens are not fetched yet
+        self._inflight: Optional[_StepInFlight] = None
+        # the prefill that was dispatched and whose request is not seated
+        self._prefilled: Optional[_Prefilled] = None
         self._entries: Dict[Any, Any] = {}   # (phase, bucket) -> jitted fn
         self._thread: Optional[threading.Thread] = None
         self.health = HealthState()
@@ -580,17 +652,25 @@ class DecodeEngine:
 
     # -- program compilation -------------------------------------------------
     def _entry(self, phase: str, bucket: int):
-        """One jitted (params, pools, feed) -> (out, new_pools) entry
-        per (phase, bucket), pools donated so XLA updates the KV arrays
-        in place; ``out`` is the chosen tokens, int32 [bucket], for the
-        step (its logits stay on the device) and the first-token logits
-        row for the prefills. Compile wall time + XLA cost capture
-        accounted like the predictor's cache."""
+        """One jitted entry per (phase, bucket), pools donated so XLA
+        updates the KV arrays in place. A prefill is (params, pools, feed)
+        -> (first-token logits row, new_pools). The step is (params, pools,
+        feed, last_tokens) -> (chosen, new_pools, last_tokens): a row whose
+        ``carry`` says so takes its input token from ``last_tokens[slot]``,
+        where the step before left it, and not from the host's ``tokens``
+        (a request's first step: its first token was chosen on the host);
+        ``chosen`` is the sampled tokens, int32 [bucket] (the logits stay
+        on the device), which the program also writes to the rows' slots of
+        ``last_tokens``. So a step can be dispatched before the one before
+        it is fetched, and its shape does not depend on that step's bucket.
+        Compile wall time + XLA cost capture accounted like the predictor's
+        cache."""
         key = (phase, bucket)
         entry = self._entries.get(key)
         if entry is not None:
             return entry
         import jax
+        import jax.numpy as jnp
 
         from ..core.executor import run_block
         from .sampling import sample_tokens
@@ -603,28 +683,41 @@ class DecodeEngine:
         # a program's arguments are part of its compiled form: it is fed
         # exactly the names its builder lists (and the sampler's)
         self._feed_names[key] = tuple(feeds) + (
-            ("sampling",) if phase == "step" else ())
+            ("sampling", "carry") if phase == "step" else ())
         block = program.global_block()
         pool_names = sorted(self._pools)
         counted = "step_counts" in fetches
 
-        def fn(params, pools, feed):
+        def run(params, pools, feed):
             env = dict(params)
             env.update(pools)
             env.update(feed)
             run_block(block, env)
-            out = env["logits"]
-            if phase == "step":
-                out = sample_tokens(out, feed["sampling"][:, 0],
-                                    feed["sampling"][:, 1])
-                if counted:
-                    # the model's counters ride behind the tokens: one
-                    # int32 vector, one fetch
-                    import jax.numpy as jnp
+            return env, {n: env[n + "_out"] for n in pool_names}
 
-                    out = jnp.concatenate(
-                        [out, env["step_counts"].astype(jnp.int32)])
-            return out, {n: env[n + "_out"] for n in pool_names}
+        def prefill(params, pools, feed):
+            env, pools = run(params, pools, feed)
+            return env["logits"], pools
+
+        def step(params, pools, feed, last_tokens):
+            slot, carried = feed["carry"][:, 0], feed["carry"][:, 1]
+            # a padding row names no slot (max_slots): it reads the last
+            # one, unused, and its token is dropped from the scatter
+            tokens = jnp.where(carried > 0,
+                               last_tokens.at[slot].get(mode="clip"),
+                               feed["tokens"])
+            env, pools = run(params, pools, dict(feed, tokens=tokens))
+            chosen = sample_tokens(env["logits"], feed["sampling"][:, 0],
+                                   feed["sampling"][:, 1])
+            last_tokens = last_tokens.at[slot].set(chosen, mode="drop")
+            if counted:
+                # the model's counters ride behind the tokens: one int32
+                # vector, one fetch
+                chosen = jnp.concatenate(
+                    [chosen, env["step_counts"].astype(jnp.int32)])
+            return chosen, pools, last_tokens
+
+        fn = step if phase == "step" else prefill
 
         # the program's own name in the profiler's trace and in the compile
         # cache's key: decode_step_b8, prefill_p256, chunk_p128
@@ -637,7 +730,8 @@ class DecodeEngine:
         entry = jax.jit(fn, donate_argnums=(1,))
         self._entries[key] = entry
         t0 = time.perf_counter()
-        feed = self._zero_feed(phase, bucket)
+        args = (self._zero_feed(phase, bucket),) + (
+            (jnp.zeros_like(self._last_tokens),) if phase == "step" else ())
         # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
         # geometry) keys the cost capture so flops/bytes attribute to
         # the kernel VARIANT actually compiled — the roofline verdict of
@@ -646,14 +740,14 @@ class DecodeEngine:
         pallas_fp = _pallas.kernels_fingerprint()
         if costmodel.capture_mode() != "off":
             costmodel.capture(
-                lambda: entry.lower(self._params, dict(self._pools), feed),
+                lambda: entry.lower(self._params, dict(self._pools), *args),
                 key_id=costmodel.key_id_for((phase, bucket,
                                              cc.weight_quant, pallas_fp)),
                 kind="decode", program=f"{phase}_b{bucket}")
         # compile through a throwaway execution on zero feeds (the
         # predictor's measure-through-first-run discipline); FRESH pool
         # arrays, because donation consumes whatever is passed in
-        entry(self._params, self.kv.make_arrays(), feed)
+        entry(self._params, self.kv.make_arrays(), *args)
         ms = round((time.perf_counter() - t0) * 1e3, 3)
         telemetry.counter_add("decode.compiles", 1)
         telemetry.event("compile", "decode", ms,
@@ -680,7 +774,8 @@ class DecodeEngine:
             return self._feed(phase, bucket, dict(
                 tables, tokens=np.zeros((bucket,), np.int32),
                 positions=np.zeros((bucket,), np.int32),
-                sampling=np.zeros((bucket, 2), np.float32)))
+                sampling=np.zeros((bucket, 2), np.float32),
+                carry=np.zeros((bucket, 2), np.int32)))
         oh = np.zeros((1, bucket), np.float32)
         oh[0, 0] = 1.0
         return self._feed(phase, bucket, dict(
@@ -691,53 +786,92 @@ class DecodeEngine:
 
     # -- scheduler loop ------------------------------------------------------
     def _loop(self):
+        # the phase times in ms, by histogram name, since the last step
+        # whose tokens were accepted; they reach the histograms when the
+        # next is, one record a step
+        it: Dict[str, float] = {}
         while True:
-            if not self._active:
+            # a step in flight is fetched before the thread sleeps or ends
+            if not self._active and self._inflight is None:
                 has_work = self.queue.wait_for_work(0.05)
                 if not has_work:
                     if self.queue.closed:
                         return
                     continue
-            # this iteration's phase times in ms, by histogram name; they
-            # reach the histograms only if the iteration ran a step
-            it: Dict[str, float] = {}
             with telemetry.timer("decode.loop_ms", into=it):
                 try:
                     with telemetry.timer("decode.admit_ms", into=it):
-                        self._admit()
-                    if self._active:
+                        self._admit(it)
+                    if self._active or self._inflight is not None:
                         self._run_step(it)
                         self._journal_tick()
+                    # the prefill that _admit dispatched runs behind the
+                    # step that was in flight and ahead of the one just
+                    # launched: its request is seated under that one
+                    with telemetry.timer("decode.admit_ms", into=it):
+                        self._seat_prefilled()
                 except BaseException as e:   # the loop must outlive any step
-                    telemetry.counter_add("decode.errors",
-                                          max(1, len(self._active)),
-                                          exc=type(e).__name__)
-                    err = e if isinstance(e, ServingError) else ServingError(
-                        f"decode step failed: {e!r}")
-                    for req in self._active:
-                        self._retire(req, error=err)
-                    self._active = []
+                    self._fail_seated(e)
+                    it = {}
                 telemetry.gauge_set("decode.active_slots", len(self._active))
                 # the step's one hook: whoever subscribed (the SLO watchdog's
                 # queue-saturation and step-time rules) runs on this cadence
                 telemetry.tick()
-            if "decode.step_ms" in it:
-                # what the iteration spent outside its phases: the deadline
-                # scan, the journal tick, the gauge, the watchdog, the timers
-                it["decode.other_ms"] = it["decode.loop_ms"] - sum(
-                    it.get(name, 0.0) for name in _LOOP_PHASES)
-                # decode.step_ms stays in the run log as it was; the new
-                # phases are histograms only (eight more records a step
-                # would crowd the log and the flight recorder out)
-                telemetry.observe("decode.step_ms", it.pop("decode.step_ms"),
-                                  kind="timer")
-                for name, ms in it.items():
-                    telemetry.observe_quiet(name, ms)
+            if "decode.fetch_ms" in it:
+                self._observe_phases(it)
+                it = {}
+            elif self._inflight is None:
+                it = {}       # no step ran: nothing of one to record
+            # else a step was dispatched into an empty pipe and none
+            # fetched: these times join the iteration that fetches it
 
-    def _admit(self):
-        """Seat queued requests into free slots at the step boundary.
+    @staticmethod
+    def _observe_phases(it: Dict[str, float]):
+        """One accepted step's record: decode.loop_ms and the phases that
+        tile it, decode.other_ms for what lies between them (the deadline
+        scan, the journal tick, the gauge, the watchdog, the timers)."""
+        for name in _LOOP_PHASES:     # a step that only drained fed nothing
+            it.setdefault(name, 0.0)
+        it["decode.sample_ms"] -= it["decode.retire_ms"]
+        it["decode.other_ms"] = it["decode.loop_ms"] - sum(
+            it[name] for name in _LOOP_PHASES)
+        # decode.step_ms stays in the run log as it was; the other phases
+        # are histograms only (eight more records a step would crowd the
+        # log and the flight recorder out)
+        telemetry.observe("decode.step_ms", it.pop("decode.step_ms"),
+                          kind="timer")
+        for name, ms in it.items():
+            telemetry.observe_quiet(name, ms)
+
+    def _fail_seated(self, e: BaseException):
+        """A failed step fails every seated request, the rows of a step in
+        flight among them (its tokens are lost with it), and the request
+        whose prefill is dispatched and not yet seated."""
+        telemetry.counter_add("decode.errors", max(1, len(self._active)),
+                              exc=type(e).__name__)
+        err = e if isinstance(e, ServingError) else ServingError(
+            f"decode step failed: {e!r}")
+        for req in self._active:
+            self._retire(req, error=err)
+        self._active = []
+        self._inflight = None
+        pending, self._prefilled = self._prefilled, None
+        if pending is not None:
+            self._prefill_failed(pending.req, pending.pages, err)
+
+    def _admit(self, it: Dict[str, float]):
+        """Admit queued requests into free slots at the step boundary.
         Non-continuous (drain-and-refill baseline) only admits into an
-        EMPTY slot array."""
+        EMPTY slot array. A local prefill is dispatched here, behind the
+        step in flight, and left in ``_prefilled``: ``_loop`` launches the
+        next step behind it and seats the request under that step
+        (``_seat_prefilled``), so an admission leaves the device no gap.
+        One prefill at a time is left so: a second request of one poll
+        first seats the one before it, in the order (a prompt's pages enter
+        the prefix store before the next lookup) and at the cost the loop
+        had before it ran ahead. Before the host blocks there, or on a
+        shipment, the step in flight is fetched and accepted (``_drain``):
+        a finished step's tokens do not wait behind a prefill."""
         if not self.config.continuous and self._active:
             return
         free = self.config.max_slots - len(self._active)
@@ -745,15 +879,20 @@ class DecodeEngine:
             return
         unseated: List[GenerationRequest] = []
         for req in self.queue.poll(free):
+            if self._prefilled is not None:
+                self._drain(it)
+                self._seat_prefilled()
             if isinstance(req, ShipPrefillRequest):
+                self._drain(it)
                 self._ship_prefill(req)
                 continue
             # disaggregated decode role: try to install a shipped
             # prefill from the prefill tier; ANY failure (connection,
             # CRC reject) falls back to a local prefill
-            if (self.config.role == "decode" and self.config.prefill_urls
-                    and self._admit_shipped(req)):
-                continue
+            if self.config.role == "decode" and self.config.prefill_urls:
+                self._drain(it)
+                if self._admit_shipped(req):
+                    continue
             # prefix sharing: acquire the longest cached prefix chain;
             # a lookup fault is a per-request error, nothing acquired
             hashes: List[str] = []
@@ -797,24 +936,42 @@ class DecodeEngine:
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
             try:
-                self._prefill(req, pages, hashes, shared)
+                self._prefilled = _Prefilled(
+                    req, pages, self._prefill(req, pages, hashes, shared))
             except BaseException as e:
-                self.kv.free(req.pages if req.pages else pages,
-                             req.ring_pages)
-                req.pages, req.ring_pages = [], []
-                if req.shared_blocks:
-                    self.prefix_store.release(req.shared_blocks)
-                    req.shared_blocks = []
-                telemetry.counter_add("decode.errors", 1,
-                                      exc=type(e).__name__)
-                req.fail(e if isinstance(e, ServingError) else ServingError(
-                    f"prefill failed: {e!r}"))
+                self._prefill_failed(req, pages, e)
         self.queue.requeue(unseated)
+
+    def _seat_prefilled(self):
+        """Wait for the dispatched prefill's logits row, if there is one,
+        and seat its request. A prefill that fails is that request's
+        error."""
+        pending, self._prefilled = self._prefilled, None
+        if pending is None:
+            return
+        try:
+            pending.seat()
+        except BaseException as e:
+            self._prefill_failed(pending.req, pending.pages, e)
+
+    def _prefill_failed(self, req: GenerationRequest, pages: List[int],
+                        e: BaseException):
+        """A per-request error: give back what the admission took."""
+        self.kv.free(req.pages if req.pages else pages, req.ring_pages)
+        req.pages, req.ring_pages = [], []
+        if req.shared_blocks:
+            self.prefix_store.release(req.shared_blocks)
+            req.shared_blocks = []
+        telemetry.counter_add("decode.errors", 1, exc=type(e).__name__)
+        req.fail(e if isinstance(e, ServingError) else ServingError(
+            f"prefill failed: {e!r}"))
 
     def _prefill(self, req: GenerationRequest, pages: List[int],
                  hashes: Optional[List[str]] = None,
                  shared: Optional[List[int]] = None):
-        """PREFILL phase. With the prefix store on, EVERY prefill runs
+        """PREFILL phase: dispatch the request's prefill and return the
+        call that waits for its logits row and seats the request
+        (``_Prefilled.seat``). With the prefix store on, EVERY prefill runs
         page-aligned chunks through the one chunked entry (a cache hit
         just skips the cached leading chunks — bitwise identity with
         the cold run holds by construction: same program, same fixed
@@ -841,17 +998,23 @@ class DecodeEngine:
             "last_onehot": oh,
             "positions": np.arange(bucket, dtype=np.int32)[None, :],
             "page_table": row[None, :], "ring_table": req.ring_row[None, :]})
-        with telemetry.timer("decode.prefill_ms"):
+        ms: Dict[str, float] = {}
+        with telemetry.timer("decode.prefill_ms", into=ms):
             logits, self._pools = entry(self._params, self._pools, feed)
-            logits = np.asarray(logits)
+        return lambda: self._seat(req, self._logits_row(logits, ms, L))
+
+    def _logits_row(self, logits, ms: Dict[str, float],
+                    tokens: int) -> np.ndarray:
+        """Wait for a dispatched prefill's logits row. decode.prefill_ms is
+        the dispatch (``ms`` so far) and this wait, not what the loop did
+        between them."""
+        with telemetry.timer("decode.prefill_ms", into=ms):
+            row = np.asarray(logits)[0]
+        telemetry.observe("decode.prefill_ms", ms["decode.prefill_ms"],
+                          kind="timer")
         telemetry.counter_add("decode.prefills", 1)
-        telemetry.counter_add("decode.prefill_tokens", L)
-        self._first_token(req, logits[0])
-        req.pos_next = L
-        if req.finished():
-            self._retire(req)
-        else:
-            self._active.append(req)
+        telemetry.counter_add("decode.prefill_tokens", tokens)
+        return row
 
     def _prefill_chunked(self, req: GenerationRequest, pages: List[int],
                          hashes: List[str], shared: List[int]):
@@ -876,7 +1039,8 @@ class DecodeEngine:
         n_chunks = -(-L // P)
         entry = self._entry("chunk", P)
         logits = None
-        with telemetry.timer("decode.prefill_ms"):
+        ms: Dict[str, float] = {}
+        with telemetry.timer("decode.prefill_ms", into=ms):
             for ci in range(k, n_chunks):
                 lo = ci * P
                 n = min(L, lo + P) - lo
@@ -895,25 +1059,22 @@ class DecodeEngine:
                     "ring_table": req.ring_row[None, :]})
                 logits, self._pools = entry(self._params, self._pools,
                                             feed)
-            logits = np.asarray(logits)
-        telemetry.counter_add("decode.prefills", 1)
-        telemetry.counter_add("decode.prefill_tokens", L - k * P)
-        # the store adopts every FULL prompt page (strictly before the
-        # page receiving decode writes); repoint the table at the
-        # canonical pages and keep only the tail pages private
-        n_full = L // P
-        if n_full > k:
-            held, canon = self.prefix_store.insert(
-                req.seq, [int(p) for p in row[:n_full]], start_block=k)
-            row[k:n_full] = canon
-            req.shared_blocks.extend(held)
-            req.pages = pages[n_full - k:]
-        self._first_token(req, logits[0])
-        req.pos_next = L
-        if req.finished():
-            self._retire(req)
-        else:
-            self._active.append(req)
+
+        def seat():
+            logits_row = self._logits_row(logits, ms, L - k * P)
+            # the store adopts every FULL prompt page (strictly before the
+            # page receiving decode writes); repoint the table at the
+            # canonical pages and keep only the tail pages private
+            n_full = L // P
+            if n_full > k:
+                held, canon = self.prefix_store.insert(
+                    req.seq, [int(p) for p in row[:n_full]], start_block=k)
+                row[k:n_full] = canon
+                req.shared_blocks.extend(held)
+                req.pages = pages[n_full - k:]
+            self._seat(req, logits_row)
+
+        return seat
 
     def _ship_prefill(self, req: ShipPrefillRequest):
         """Prefill-tier work (serving/disagg.py): run the prompt's
@@ -1009,12 +1170,7 @@ class DecodeEngine:
             telemetry.counter_add("decode.prefills", 1)
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
-            self._first_token(req, np.asarray(ship["logits"]))
-            req.pos_next = L
-            if req.finished():
-                self._retire(req)
-            else:
-                self._active.append(req)
+            self._seat(req, np.asarray(ship["logits"]))
             return True
         except Exception as e:
             if pages:
@@ -1024,9 +1180,17 @@ class DecodeEngine:
             return False
 
     def _run_step(self, it: Dict[str, float]):
-        """DECODE phase: one fixed-shape step over the padded slot
-        array; per-request deadlines checked here, at step granularity.
-        The phases' times go into ``it`` (see ``_loop``)."""
+        """DECODE phase, one step ahead: dispatch the next fixed-shape step
+        over the padded slot array, THEN fetch and accept the step in
+        flight, so the host's feed, launch, fetch and accept run while the
+        device has a step to work on. Which rows the next step holds is
+        decided from the host's counts: a request whose ``max_new_tokens``
+        the step in flight reaches is left out; one that may end on
+        ``eos_id`` is dispatched and, if it did end, its token of the next
+        step is thrown away when that step is accepted (``_finish``).
+        Per-request deadlines are checked here, at step granularity. The
+        phases' times go into ``it`` (see ``_loop``): decode.step_ms is the
+        launch of one step and the wait for the one before."""
         delay_ms = float(_flag("decode_step_delay_ms"))
         if delay_ms > 0:   # chaos/bench pacing knob — off by default
             time.sleep(delay_ms / 1e3)
@@ -1038,11 +1202,22 @@ class DecodeEngine:
             self._retire(req, error=DeadlineExceededError(
                 f"generation deadline elapsed after {len(req.tokens)} of "
                 f"{req.max_new_tokens} tokens"))
-        if not self._active:
-            return
-        active = self._active
-        bucket = self.config.bucket(len(active))
-        faults.maybe_fail("decode.step", active=len(active), bucket=bucket)
+        flight, self._inflight = self._inflight, None
+        rows = [r for r in self._active
+                if len(r.tokens) + r.ahead < r.max_new_tokens]
+        if rows:
+            self._inflight = self._launch(rows, it)
+            if flight is not None:
+                telemetry.counter_add("decode.steps_ahead", 1)
+        if flight is not None:
+            self._finish(flight, it)
+
+    def _launch(self, rows: List[GenerationRequest],
+                it: Dict[str, float]) -> _StepInFlight:
+        """Build one step's feed from what the host knows without the
+        tokens of the step in flight, and dispatch it."""
+        bucket = self.config.bucket(len(rows))
+        faults.maybe_fail("decode.step", active=len(rows), bucket=bucket)
         entry = self._entry("step", bucket)
         with telemetry.timer("decode.feed_ms", into=it):
             tokens = np.zeros(bucket, np.int32)
@@ -1053,29 +1228,51 @@ class DecodeEngine:
             # in token order (a first token's draw came before, on the host)
             sampling = np.zeros((bucket, 2), np.float32)
             ring = np.zeros((bucket, self.kv.ring_slot_pages), np.int32)
-            for i, req in enumerate(active):
-                tokens[i] = req.last_token
+            # a row's (slot, whether its token is last_tokens[slot]); a
+            # padding row's slot is none of them
+            carry = np.zeros((bucket, 2), np.int32)
+            carry[len(rows):, 0] = self.config.max_slots
+            for i, req in enumerate(rows):
+                if not req.carried:   # its first token, chosen on the host
+                    tokens[i] = req.last_token
+                carry[i] = (req.slot, req.carried)
                 positions[i] = req.pos_next
                 table[i] = req.table_row
                 ring[i] = req.ring_row
                 if req.temperature > 0.0:
+                    if req.session_id is not None:
+                        req._rng_cut = req._rng.get_state()
                     sampling[i] = (req.temperature,
                                    req._rng.random_sample())
             feed = self._feed("step", bucket, {
                 "tokens": tokens, "positions": positions,
                 "page_table": table, "ring_table": ring,
-                "sampling": sampling})
+                "sampling": sampling, "carry": carry})
         with telemetry.timer("decode.step_ms", into=it):
-            chosen, self._pools = entry(self._params, self._pools, feed)
+            chosen, self._pools, self._last_tokens = entry(
+                self._params, self._pools, feed, self._last_tokens)
+            chosen.copy_to_host_async()
+        for req in rows:
+            req.pos_next += 1
+            req.carried = True
+            req.ahead += 1
+        return _StepInFlight(rows, bucket, chosen, positions[:len(rows)])
+
+    def _finish(self, flight: _StepInFlight, it: Dict[str, float]):
+        """Fetch a dispatched step's tokens and accept them row by row. A
+        row whose request ended meanwhile (on ``eos_id``, on its deadline)
+        was dispatched on speculation: its token is thrown away."""
+        rows, bucket = flight.rows, flight.bucket
+        with telemetry.timer("decode.step_ms", into=it):
             # [bucket] int32 (and the model's counters behind them); the
-            # host waits out the step program here
+            # host waits out what is left of the step program here
             with telemetry.timer("decode.fetch_ms", into=it):
-                chosen = np.asarray(chosen)
+                chosen = np.asarray(flight.chosen)
         for name, value in zip(self.model.step_counters, chosen[bucket:]):
             telemetry.counter_add(name, int(value))
         if self.kv.ring is not None:
             # keys a step reads: a ring layer's rows read their window
-            ctx = positions[:len(active)].astype(np.int64) + 1
+            ctx = flight.positions.astype(np.int64) + 1
             rings = len(self.kv.ring.layers)
             telemetry.counter_add("decode.rows_past_window",
                                   int(np.sum(ctx > self.kv.window)))
@@ -1083,26 +1280,49 @@ class DecodeEngine:
                 "decode.kv_tokens_attended",
                 int(len(self.kv.context.layers) * ctx.sum() + rings
                     * np.minimum(ctx, self.kv.window).sum()))
-        telemetry.counter_add("decode.steps", 1)
-        telemetry.counter_add("decode.tokens", len(active))
-        telemetry.counter_add("decode.tokens_device_sampled", len(active))
-        telemetry.observe("decode.batch_occupancy", len(active) / bucket)
         # one span for accepting the step's tokens. A request that finishes
         # is retired at once, in a child span
+        delivered, retired = 0, False
         with telemetry.timer("decode.sample_ms", into=it):
-            still = []
-            for i, req in enumerate(active):
+            for i, req in enumerate(rows):
+                req.ahead -= 1
+                if req.done():
+                    continue
+                delivered += 1
                 self._accept_token(req, int(chosen[i]))
-                req.pos_next += 1
                 if req.finished():
+                    retired = True
                     with telemetry.timer("decode.retire_ms", into=it):
                         self._retire(req)
-                else:
-                    still.append(req)
-            self._active = still
-        # the histograms hold disjoint phases: accepting less the retiring
-        it.setdefault("decode.retire_ms", 0.0)
-        it["decode.sample_ms"] -= it["decode.retire_ms"]
+            if retired:
+                self._active = [r for r in self._active if not r.done()]
+        telemetry.counter_add("decode.steps", 1)
+        telemetry.counter_add("decode.tokens", delivered)
+        telemetry.counter_add("decode.tokens_device_sampled", delivered)
+        if delivered < len(rows):
+            telemetry.counter_add("decode.rows_discarded",
+                                  len(rows) - delivered)
+        telemetry.observe("decode.batch_occupancy", delivered / bucket)
+
+    def _drain(self, it: Dict[str, float]):
+        """Fetch and accept the step in flight, if there is one, before the
+        host blocks on something queued behind it (``_admit``: a second
+        prefill of one poll, a shipment). A step that fails here
+        fails as one does in ``_loop``: every seated request, and the
+        engine goes on."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return
+        t0 = time.perf_counter()
+        try:
+            self._finish(flight, it)
+        except Exception as e:
+            self._fail_seated(e)
+        # the step was timed under its own names: the admission's span,
+        # inside which this runs, gives that time up, so that the
+        # histograms hold disjoint phases
+        it["decode.admit_ms"] = it.get("decode.admit_ms", 0.0) \
+            - (time.perf_counter() - t0) * 1e3
 
     def _journal_tick(self):
         """Replicate session snapshots to the router at step-boundary
@@ -1131,6 +1351,17 @@ class DecodeEngine:
             telemetry.counter_add("session.journal_errors", 1,
                                   exc=type(e).__name__)
 
+    def _seat(self, req: GenerationRequest, logits_row: np.ndarray):
+        """A prefilled request gets its first token and, unless that ends
+        it, a slot: the one it keeps until it retires."""
+        self._first_token(req, logits_row)
+        req.pos_next = int(req.seq.size)
+        if req.finished():
+            self._retire(req)
+        else:
+            req.slot = self._free_slots.pop()
+            self._active.append(req)
+
     def _first_token(self, req: GenerationRequest, logits_row: np.ndarray):
         """A request's first token, chosen on the host from the logits row
         its prefill handed over."""
@@ -1157,6 +1388,14 @@ class DecodeEngine:
         prefix-store references and resolve/fail it — finished
         sequences leave WITHOUT draining the batch. Shared pages stay
         resident in the store (that is the cache)."""
+        if req.slot is not None:
+            self._free_slots.append(req.slot)
+            req.slot = None
+        # Freeing here is safe with a step in flight that still holds this
+        # request as a row (dispatched on speculation past its EOS or its
+        # deadline): that step's K/V write lands on a page the request
+        # reserved at admission (pages_for_tokens(prompt + max_new_tokens)),
+        # and a later owner's prefill is queued behind it on the device.
         if req.pages or req.ring_pages:
             self.kv.free(req.pages, req.ring_pages)
             req.pages, req.ring_pages = [], []

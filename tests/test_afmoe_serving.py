@@ -115,13 +115,15 @@ def test_the_decode_step_returns_its_routing_counts_with_its_tokens(f32):
     """One fetch: [slots] tokens and the three int32 behind them."""
     cfg, engine, _ = f32
     entry = engine._entry("step", 4)
-    out, _pools = entry(engine._params, engine.kv.make_arrays(),
-                        engine._zero_feed("step", 4))
+    out, _pools, _last = entry(engine._params, engine.kv.make_arrays(),
+                               engine._zero_feed("step", 4),
+                               engine._last_tokens)
     out = np.asarray(out)
     assert out.shape == (4 + 3,) and out.dtype == np.int32
     assert list(out[4:]) == [0, 0, 0]            # no live row, no pair
     assert engine._feed_names[("step", 4)] == (
-        "tokens", "positions", "page_table", "ring_table", "sampling")
+        "tokens", "positions", "page_table", "ring_table", "sampling",
+        "carry")
 
 
 # -- bfloat16 and the lower-precision control --------------------------------

@@ -187,10 +187,11 @@ def journaled_run():
     bucket, = engine.config.buckets
     entry = engine._entries[("step", bucket)]
     shapes = jax.eval_shape(entry, engine._params, dict(engine._pools),
-                            engine._zero_feed("step", bucket))
+                            engine._zero_feed("step", bucket),
+                            engine._last_tokens)
 
-    def noting(params, pools, feed):
-        out = entry(params, pools, feed)
+    def noting(params, pools, feed, last_tokens):
+        out = entry(params, pools, feed, last_tokens)
         returned.append(jax.tree_util.tree_map(
             lambda a: (tuple(a.shape), str(a.dtype)), out))
         return out
@@ -219,13 +220,17 @@ def journaled_run():
 def test_the_step_entry_returns_tokens_and_no_vocabulary_axis(journaled_run):
     run = journaled_run
     bucket, vocab = run["bucket"], run["engine"].model_cfg.vocab_size
-    chosen, pools = run["shapes"]
+    slots = run["engine"].config.max_slots
+    chosen, pools, last_tokens = run["shapes"]
     assert chosen.shape == (bucket,) and str(chosen.dtype) == "int32"
+    assert last_tokens.shape == (slots,) \
+        and str(last_tokens.dtype) == "int32"
     assert run["returned"], "no step ran"
-    for first, new_pools in run["returned"]:
+    for first, new_pools, last in run["returned"]:
         assert first == ((bucket,), "int32")
-        # what _run_step can fetch is what the entry returns: the tokens
-        # and the KV pools, nothing [bucket, vocab]
+        assert last == ((slots,), "int32")
+        # what the loop can fetch is what the entry returns: the tokens,
+        # the KV pools and every slot's latest token, nothing [bucket, vocab]
         assert sorted(new_pools) == sorted(run["engine"]._pools)
         assert all(vocab not in shape for shape, _ in new_pools.values())
 
