@@ -314,6 +314,88 @@ def cached_kv_attention_op(ins, attrs):
     return {"Out": out, "PoolKOut": pool_k, "PoolVOut": pool_v}
 
 
+def _lane_padded(x, width):
+    """`x` with its last axis zero-padded to `width`."""
+    import jax.numpy as jnp
+
+    short = width - x.shape[-1]
+    if short < 0:
+        raise ValueError(f"a row of {x.shape[-1]} values in pages of "
+                         f"{width}")
+    return x if not short else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+@register_op("latent_cache_write",
+             non_diff_inputs=("Latent", "Pool", "PageTable", "Lengths"))
+def latent_cache_write_op(ins, attrs):
+    """Bulk-write a prompt's latent rows into a latent layer's pages: the
+    PREFILL half of the cache discipline for a layer that keeps ONE array
+    a token (serving/kv_cache.py `LayerCache(latent=True)`).
+
+    Latent [B, S, w] (the normed compressed latent, then the rotated
+    shared key); Pool [N, P, W] with W >= w (a row rides in whole lane
+    tiles, its tail zero); PageTable [B, MP]; Lengths [B]. Token s of row b
+    lands at page PageTable[b, s // P], offset s % P; positions at or past
+    the row's length go to the scratch page 0."""
+    import jax.numpy as jnp
+
+    pool = jnp.asarray(ins["Pool"][0])
+    table = jnp.asarray(ins["PageTable"][0])
+    lengths = jnp.asarray(ins["Lengths"][0]).reshape(-1)
+    lat = _lane_padded(ins["Latent"][0], pool.shape[2])
+    b, s, _ = lat.shape
+    page = int(pool.shape[1])
+    pos = jnp.arange(s, dtype=jnp.int32)
+    phys = jnp.take_along_axis(
+        table, jnp.broadcast_to((pos // page)[None, :], (b, s)), axis=1)
+    phys = jnp.where(pos[None, :] < lengths[:, None], phys, 0).reshape(-1)
+    off = jnp.broadcast_to((pos % page)[None, :], (b, s)).reshape(-1)
+    return {"PoolOut": pool.at[phys, off].set(
+        lat.reshape(b * s, -1).astype(pool.dtype))}
+
+
+@register_op("cached_latent_attention",
+             required_attrs=("num_heads", "value_dim"),
+             non_diff_inputs=("Latent", "Pool", "PageTable", "Positions"))
+def cached_latent_attention_op(ins, attrs):
+    """One DECODE step of latent attention in its absorbed form against
+    latent pages: the twin of `cached_kv_attention` for a layer that
+    caches one row a token.
+
+    Q [B, n*w] (`mla_absorb_query`: a head's query in the latent's space,
+    then its rotary part), Latent [B, w] (the new token's row), Pool
+    [N, P, W >= w], PageTable [B, MP], Positions [B]. The op writes the
+    row at (PageTable[b, pos // P], pos % P), then every head attends the
+    row's pages, positions > pos masked before the softmax, its values the
+    rows' first `value_dim` entries. Out [B, n*value_dim] float32 (still
+    in the latent's space: `mla_expand_output` follows), PoolOut.
+
+    The attend phase is ops/pallas/paged_mla_attention.py under the
+    PT_PALLAS dispatch; mode 'off' and untileable shapes take the counted
+    stock gather (``pallas.paged_attn_fallbacks``)."""
+    import jax.numpy as jnp
+
+    from .pallas.paged_mla_attention import paged_mla_decode_attention
+
+    pool = jnp.asarray(ins["Pool"][0])
+    table = jnp.asarray(ins["PageTable"][0])
+    pos = jnp.asarray(ins["Positions"][0]).reshape(-1)
+    n = int(attrs["num_heads"])
+    q, lat = ins["Q"][0], ins["Latent"][0]
+    b, w = lat.shape
+    width, page = int(pool.shape[2]), int(pool.shape[1])
+    scale = float(attrs.get("scale") or w ** -0.5)
+    phys = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0]
+    pool = pool.at[phys, pos % page].set(
+        _lane_padded(lat, width).astype(pool.dtype))
+    out = paged_mla_decode_attention(
+        _lane_padded(q.reshape(b, n, w), width).reshape(b, n * width),
+        pool, table, pos, num_heads=n, value_dim=int(attrs["value_dim"]),
+        scale=scale)
+    return {"Out": out, "PoolOut": pool}
+
+
 @register_op("chunk_cached_attention",
              required_attrs=("num_heads", "head_dim"),
              non_diff_inputs=("K", "V", "PoolK", "PoolV", "PageTable",

@@ -2,9 +2,11 @@
 models/decoder_lm.py does not have: RMS norm, a matmul over low-precision
 weights that accumulates in float32, SwiGLU, per-head QK-norm with rotary
 positions, an output gate, and whole-prompt attention for grouped heads
-with a window. models/afmoe.py builds its programs from them; the cached
-attention ops are in attention_ops.py and the routed expert layer in
-moe_ops.py.
+with a window; and the latent attention of models/kimi_k2.py around its
+cache (YaRN rotary on the rotary part, the absorbed query, the expanded
+output, whole-prompt attention in the expanded form). models/afmoe.py and
+models/kimi_k2.py build their programs from them; the cached attention ops
+are in attention_ops.py and the routed expert layer in moe_ops.py.
 
 Number format: weights and K/V pages may be bfloat16; a row's activations
 stay float32 between matmuls and are rounded to the weight's dtype where
@@ -190,3 +192,179 @@ def gqa_prefill_attention_op(ins, attrs):
     out = jax.lax.map(block, jnp.arange(0, s, bq, dtype=jnp.int32))
     # [blocks, B, bq, nkv, g, hd] -> [B, S, n*hd]
     return {"Out": jnp.moveaxis(out, 0, 1).reshape(b, s, n * hd)}
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (the DeepSeek-V3 block; models/kimi_k2.py)
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """The `dim // 2` rotary frequencies under YaRN: pair i turns by
+    ``theta^(-2i/dim)`` a position where it turns more than `beta_fast`
+    times over the original `original_max` positions, by that over `factor`
+    where it turns fewer than `beta_slow` times, and by a linear blend of
+    the two between (the ramp runs over whole pair indices, floor of the
+    one bound to ceil of the other)."""
+    import math
+
+    import numpy as np
+
+    half = dim // 2
+    plain = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
+    if factor <= 1.0:
+        return plain.astype(np.float32)
+
+    def turns_at(turns):       # the pair index that makes `turns` turns
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: the softmax scale is multiplied by its
+    square (``mscale_all_dim`` in the published config)."""
+    import math
+
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rope_pairs(x, positions, inv_freq):
+    """Rotate the interleaved pairs (x[2i], x[2i+1]) of the last axis by
+    ``positions x inv_freq[i]``; `positions` is shaped like x without its
+    last axes (heads broadcast)."""
+    import jax.numpy as jnp
+
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    while ang.ndim < x.ndim:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.reshape(x.shape[:-1] + (-1, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape)
+
+
+@register_op("mla_rope_split",
+             required_attrs=("num_heads", "nope_dim", "rope_dim"))
+def mla_rope_split_op(ins, attrs):
+    """The position-dependent middle of a latent attention layer, between
+    its down- and up-projections.
+
+    Q [..., n*(nope+rope)] (a head: its position-free part, then its
+    rotary part), KVA [..., rank+rope] (the compressed latent, then the
+    one rotary key all heads share), KVScale [rank], Positions int32
+    shaped like Q without its last axis. The latent is RMS-normed with
+    its gain; both rotary parts are rotated (YaRN frequencies from attrs
+    `theta`, `yarn_factor`, `yarn_original_max`, `yarn_beta_fast`,
+    `yarn_beta_slow`; interleaved pairs). Outputs, float32: QNope
+    [..., n*nope], QRope [..., n*rope], C [..., rank] (the normed latent)
+    and Latent [..., rank+rope] = [C, rotated key]: the row a latent page
+    holds."""
+    import jax
+    import jax.numpy as jnp
+
+    n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
+    rope = int(attrs["rope_dim"])
+    inv = yarn_inv_freq(rope, float(attrs.get("theta", 10000.0)),
+                        float(attrs.get("yarn_factor", 1.0)),
+                        int(attrs.get("yarn_original_max", 4096)),
+                        float(attrs.get("yarn_beta_fast", 32.0)),
+                        float(attrs.get("yarn_beta_slow", 1.0)))
+    pos = ins["Positions"][0]
+    q = ins["Q"][0].astype(jnp.float32)
+    kva = ins["KVA"][0].astype(jnp.float32)
+    lead = q.shape[:-1]
+    qh = q.reshape(lead + (n, nope + rope))
+    q_rope = _rope_pairs(qh[..., nope:], pos, inv)
+    c, k_rope = kva[..., :-rope], kva[..., -rope:]
+    ms = jnp.mean(jnp.square(c), axis=-1, keepdims=True)
+    c = c * jax.lax.rsqrt(ms + float(attrs.get("epsilon", 1e-5))) \
+        * ins["KVScale"][0].astype(jnp.float32)
+    k_rope = _rope_pairs(k_rope, pos, inv)
+    return {"QNope": qh[..., :nope].reshape(lead + (n * nope,)),
+            "QRope": q_rope.reshape(lead + (n * rope,)),
+            "C": c, "Latent": jnp.concatenate([c, k_rope], axis=-1)}
+
+
+def _kvb_heads(w, n):
+    """W_kvb [rank, n*(nope+v)] as [rank, n, nope+v]."""
+    return w.reshape(w.shape[0], n, w.shape[1] // n)
+
+
+@register_op("mla_absorb_query", required_attrs=("num_heads", "nope_dim"))
+def mla_absorb_query_op(ins, attrs):
+    """The decode step's query in the latent's own space: per head
+    ``[q_n W_uk (rank), q_r (rope)]`` with ``W_uk`` head h's key half of W
+    (the up-projection of latents to keys and values, [rank, n*(nope+v)]),
+    so that ``q . [c, k_r]`` is ``q_n . k_n + q_r . k_r`` without any
+    key being expanded. QNope [B, n*nope], QRope [B, n*rope] -> Q
+    [B, n*(rank+rope)] float32; the product rounds QNope to W's dtype and
+    accumulates in float32."""
+    import jax.numpy as jnp
+
+    n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
+    w = _kvb_heads(ins["W"][0], n)[:, :, :nope]            # [rank, n, nope]
+    qn, qr = ins["QNope"][0], ins["QRope"][0]
+    b = qn.shape[0]
+    qc = jnp.einsum("bhd,chd->bhc", qn.reshape(b, n, nope).astype(w.dtype),
+                    w, preferred_element_type=jnp.float32)
+    q = jnp.concatenate([qc, qr.reshape(b, n, -1).astype(jnp.float32)],
+                        axis=-1)
+    return {"Q": q.reshape(b, -1)}
+
+
+@register_op("mla_expand_output", required_attrs=("num_heads", "nope_dim"))
+def mla_expand_output_op(ins, attrs):
+    """Out [B, n*v] = per head ``o_c W_uv``: the attended latents X
+    [B, n*rank] through head h's value half of W [rank, n*(nope+v)].
+    float32 out, W's dtype in, float32 accumulation."""
+    import jax.numpy as jnp
+
+    n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
+    w = _kvb_heads(ins["W"][0], n)[:, :, nope:]            # [rank, n, v]
+    x = ins["X"][0]
+    b = x.shape[0]
+    out = jnp.einsum("bhc,chv->bhv", x.reshape(b, n, -1).astype(w.dtype), w,
+                     preferred_element_type=jnp.float32)
+    return {"Out": out.reshape(b, -1)}
+
+
+@register_op("mla_prefill_attention",
+             required_attrs=("num_heads", "nope_dim", "rope_dim"))
+def mla_prefill_attention_op(ins, attrs):
+    """Causal attention of a whole (padded) prompt over its own keys in the
+    EXPANDED form of latent attention: head h's key is ``[k_n (nope), k_r
+    (rope)]`` with k_n and its value v from KV = C W_kvb ([B, S,
+    n*(nope+v)], a head: key part, then value) and k_r the one rotated key
+    of Latent's last `rope` entries; its query ``[q_n, q_r]``. No pool is
+    read: the prefill writes Latent with `latent_cache_write` beside this.
+
+    The attend phase is ops/pallas/mla_prefill_attention.py (blockwise,
+    online softmax, the scores stay in VMEM) under the PT_PALLAS
+    dispatch; mode 'off' and untileable shapes take the counted stock
+    lowering (``pallas.mla_prefill_fallbacks``). Inputs are rounded to
+    `compute_dtype` for the products, which accumulate in float32; the
+    softmax is float32. Out float32 [B, S, n*v]."""
+    import jax.numpy as jnp
+
+    from .pallas.mla_prefill_attention import mla_prefill_attention
+
+    n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
+    rope = int(attrs["rope_dim"])
+    dt = jnp.dtype(attrs.get("compute_dtype", "float32"))
+    qn, qr, kv = ins["QNope"][0], ins["QRope"][0], ins["KV"][0]
+    b, s, _ = qn.shape
+    scale = float(attrs.get("scale") or (nope + rope) ** -0.5)
+    kvh = kv.reshape(b, s, n, -1).astype(dt)
+    k_rope = ins["Latent"][0][..., -rope:].astype(dt)
+    qn = qn.reshape(b, s, n, nope).astype(dt)
+    qr = qr.reshape(b, s, n, rope).astype(dt)
+    out = [mla_prefill_attention(qn[i], qr[i], kvh[i, :, :, :nope],
+                                 k_rope[i], kvh[i, :, :, nope:], scale)
+           for i in range(b)]
+    return {"Out": jnp.stack(out).reshape(b, s, -1)}

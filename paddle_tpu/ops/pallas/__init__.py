@@ -10,7 +10,11 @@ math/bert_encoder_functor.cu) and fused optimizer passes
 * int8_gemm       — weight-only int8 MXU GEMM, dequant+bias+act fused
                     into the matmul epilogue (serving hot path),
 * paged_attention — decode-step attention that walks the KV page table
-                    directly (serving/kv_cache.py layout).
+                    directly (serving/kv_cache.py layout); paged_gqa_attention
+                    for grouped heads, windows and rings; paged_mla_attention
+                    over latent pages (absorbed latent attention),
+* mla_prefill_attention — whole-prompt causal latent attention in its
+                    expanded form, scores kept in VMEM.
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

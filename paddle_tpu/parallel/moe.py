@@ -149,8 +149,9 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     (``jax.lax.ragged_dot``) over the sorted rows, so an expert no token
     chose costs no weight read. The products run over the leading rows
     that hold the held pairs when those are few, as they nearly always
-    are, and over every row otherwise (one ``lax.cond``). What the absent experts would have added
-    is left out; nothing stands in for their chips or the exchange.
+    are, and otherwise over that many sorted rows at a time, as far as the
+    held pairs reach (one ``lax.cond``). What the absent experts would
+    have added is left out; nothing stands in for their chips or the exchange.
 
     Returns (out [T, H] float32, counts int32 [3]): the kept pairs of live
     rows, those of them on held experts, and the held experts with at
@@ -183,9 +184,9 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     rows = (order // top_k).astype(jnp.int32)
     w_sorted = jnp.where(held, weight, 0.0).reshape(-1)[order]
 
-    def experts(n_rows):
-        """The held experts over the first `n_rows` sorted pairs."""
-        r, w = rows[:n_rows], w_sorted[:n_rows]
+    def experts(r, w, sizes):
+        """The held experts over sorted pairs: their tokens `r`, their
+        weights `w`, `sizes` of them in each expert's group."""
         xs = x[r].astype(w1.dtype)                               # [n, H]
 
         def grouped(a, wts):
@@ -205,15 +206,36 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     # of the sorted rows holds them nearly always, and the grouped
     # products, the gather and the scatter then run over that many rows
     # (on the chip a grouped product over 64 rows took 1.2 ms where 256
-    # took 1.5, PR 28); when more pairs land here than that, every row is
-    # processed: no pair is ever dropped.
+    # took 1.5, PR 28); when more pairs land here than that, the sorted
+    # rows are processed that many at a time, as far as the held pairs
+    # reach: no pair is ever dropped, and a prompt's every pair is never
+    # held at once (pairs x H floats three times over: 3 GB at 6144
+    # tokens, top-8, H 7168).
     pairs = t * top_k
     few = -(-(2 * pairs * e_held // router_w.shape[1] + 32) // 64) * 64
+
+    def every():
+        pad = -pairs % few
+        rows_p, w_p = jnp.pad(rows, (0, pad)), jnp.pad(w_sorted, (0, pad))
+        ends = jnp.cumsum(sizes)
+
+        def some(i, out):
+            lo = i * few
+            part = jnp.clip(ends, lo, lo + few) \
+                - jnp.clip(ends - sizes, lo, lo + few)
+            return out + experts(
+                jax.lax.dynamic_slice_in_dim(rows_p, lo, few),
+                jax.lax.dynamic_slice_in_dim(w_p, lo, few), part)
+
+        return jax.lax.fori_loop(0, (ends[-1] + few - 1) // few, some,
+                                 jnp.zeros((t, h), jnp.float32))
+
     if few < pairs:
-        out = jax.lax.cond(jnp.sum(sizes) <= few, lambda: experts(few),
-                           lambda: experts(pairs))
+        out = jax.lax.cond(
+            jnp.sum(sizes) <= few,
+            lambda: experts(rows[:few], w_sorted[:few], sizes), every)
     else:
-        out = experts(pairs)
+        out = experts(rows, w_sorted, sizes)
     counts = jnp.stack([jnp.sum(alive) * top_k, jnp.sum(sizes),
                         jnp.sum(sizes > 0)]).astype(jnp.int32)
     return out, counts

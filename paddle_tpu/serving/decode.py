@@ -10,9 +10,10 @@ caching, on the repo's frozen-program stack:
 * **The served model** comes through one seam (served_model.py):
   ``model_cfg.served()`` gives the three program builders, the parameter
   layout and, layer by layer, what the model keeps of a sequence — a
-  context's pages, or for a window layer a ring of pages (kv_cache.py).
-  models/decoder_lm.py and models/afmoe.py both come this way; the engine
-  has no second path.
+  context's pages, for a window layer a ring of pages, for a latent layer
+  one array a token and not K and V (kv_cache.py). models/decoder_lm.py,
+  models/afmoe.py and models/kimi_k2.py all come this way; the engine has
+  no second path.
 * **Phase split.** An admitted request first runs ONE prefill program
   (the model's ``build_prefill_program``, padded to a prompt-length
   bucket) that writes the whole prompt's K/V into its pool pages and
@@ -94,10 +95,11 @@ went into an empty pipe: an engine's first step, one behind a second
 prefill of one poll or a shipment) and
 decode.rows_discarded (speculative rows whose token was thrown away),
 decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
-histogram; for a model with ring layers decode.rows_past_window and
-decode.kv_tokens_attended (keys read a step, over rows and layers: a ring
-layer reads min(context, window)), and whatever counters the model's step
-program returns beside its tokens (``ServedModel.step_counters``: the
+histogram; for a model with ring layers decode.rows_past_window and, for
+one with ring or latent layers, decode.kv_tokens_attended (cached tokens
+read a step, over rows and layers: a ring layer reads min(context,
+window), a latent layer its context's latents), and whatever counters
+the model's step program returns beside its tokens (``ServedModel.step_counters``: the
 routed-expert counts of models/afmoe.py), fetched in the step's one fetch; decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
 section and /v1/stats. Every accepted step also records the phases of the
@@ -434,15 +436,22 @@ class DecodeEngine:
                                dtype=self.model.kv_dtype,
                                slots=self.config.max_slots)
         self.pool = self.kv.context
-        if self.kv.ring is not None and (
+        # a model that keeps other than K and V of every token in a
+        # context's pages (a window's ring, latent pages): what a step
+        # attends is counted for it (decode.kv_tokens_attended)
+        self._ring_or_latent = self.kv.ring is not None \
+            or any(self.kv.context.latent)
+        if self._ring_or_latent and (
                 self.config.prefix_cache or self.config.role != "unified"):
             # the prefix store shares a prompt's full pages between
             # requests and a shipment installs a prompt's pages; a ring is
-            # a slot's own and is overwritten as its window slides
+            # a slot's own and is overwritten as its window slides, and no
+            # chunk of a prompt attends a latent prefix yet
             raise ValueError(
-                "a model with ring (window) layers runs unified and "
-                "without the prefix store: neither the prefix store nor "
-                "disaggregated prefill handles ring pages yet")
+                "a model with ring (window) or latent layers runs unified "
+                "and without the prefix store: neither the prefix store "
+                "nor disaggregated prefill handles ring or latent pages "
+                "yet")
         self._pools = self.kv.make_arrays()
         self._mp = -(-model_cfg.max_seq_len // self.config.page_size)
         self._feed_names: Dict[Any, Any] = {}   # (phase, bucket) -> names
@@ -745,9 +754,13 @@ class DecodeEngine:
                                              cc.weight_quant, pallas_fp)),
                 kind="decode", program=f"{phase}_b{bucket}")
         # compile through a throwaway execution on zero feeds (the
-        # predictor's measure-through-first-run discipline); FRESH pool
-        # arrays, because donation consumes whatever is passed in
-        entry(self._params, self.kv.make_arrays(), *args)
+        # predictor's measure-through-first-run discipline), on the
+        # engine's OWN pools, taken back as the program returns them
+        # (donation consumes whatever is passed in): a zero feed's page
+        # tables name the scratch page 0 alone, so no request's page is
+        # written, and no second pool is held beside the first (3.4 GB of
+        # latent pages beside 7 GB of weights did not fit twice)
+        self._pools = entry(self._params, self._pools, *args)[1]
         ms = round((time.perf_counter() - t0) * 1e3, 3)
         telemetry.counter_add("decode.compiles", 1)
         telemetry.event("compile", "decode", ms,
@@ -1270,16 +1283,18 @@ class DecodeEngine:
                 chosen = np.asarray(flight.chosen)
         for name, value in zip(self.model.step_counters, chosen[bucket:]):
             telemetry.counter_add(name, int(value))
-        if self.kv.ring is not None:
-            # keys a step reads: a ring layer's rows read their window
+        if self._ring_or_latent:
+            # keys (a latent layer's cached tokens) a step reads: a ring
+            # layer's rows read their window
             ctx = flight.positions.astype(np.int64) + 1
-            rings = len(self.kv.ring.layers)
-            telemetry.counter_add("decode.rows_past_window",
-                                  int(np.sum(ctx > self.kv.window)))
-            telemetry.counter_add(
-                "decode.kv_tokens_attended",
-                int(len(self.kv.context.layers) * ctx.sum() + rings
-                    * np.minimum(ctx, self.kv.window).sum()))
+            attended = len(self.kv.context.layers) * ctx.sum()
+            if self.kv.ring is not None:
+                telemetry.counter_add("decode.rows_past_window",
+                                      int(np.sum(ctx > self.kv.window)))
+                attended += len(self.kv.ring.layers) \
+                    * np.minimum(ctx, self.kv.window).sum()
+            telemetry.counter_add("decode.kv_tokens_attended",
+                                  int(attended))
         # one span for accepting the step's tokens. A request that finishes
         # is retired at once, in a child span
         delivered, retired = 0, False

@@ -41,13 +41,30 @@ class LayerCache:
     a context's pages (`window` 0: every token of the request, pages held
     for its whole life) or in a ring (`window` > 0: the last `window`
     tokens, in a fixed ring of ``window / page + 1`` pages a slot that the
-    sliding window overwrites)."""
+    sliding window overwrites). A `latent` layer keeps ONE array of
+    `kv_dim` a token in a context's pages (multi-head latent attention:
+    the normed compressed latent, then the rotated key all heads share),
+    from which every head's key and value are expanded or absorbed; it has
+    no separate V."""
     kv_dim: int
     window: int = 0
+    latent: bool = False
+
+    def __post_init__(self):
+        if self.latent and self.window:
+            raise ValueError("a latent layer keeps a context's pages, "
+                             "not a ring")
 
     @property
     def ring(self) -> bool:
         return self.window > 0
+
+
+def pool_array_names(layer: int, latent: bool) -> Tuple[str, ...]:
+    """The pool arrays of layer `layer`, as the programs feed them (each
+    is written back as ``<name>_out``)."""
+    return (f"kv_c_{layer}",) if latent \
+        else (f"kv_k_{layer}", f"kv_v_{layer}")
 
 
 def ring_pages_per_slot(window: int, page_size: int) -> int:
@@ -59,10 +76,11 @@ def ring_pages_per_slot(window: int, page_size: int) -> int:
 class KVPagePool:
     """Free-list allocator over preallocated per-layer page arrays.
 
-    The jax arrays themselves (``pools``: kv_k_<l>/kv_v_<l> ->
-    [num_pages, page_size, kv_dim]) are owned and threaded/donated by
-    the engine's step function; this object owns the PAGE IDS and the
-    ledger accounting. Page 0 is never handed out.
+    The jax arrays themselves (``pools``: kv_k_<l>/kv_v_<l>, or the one
+    kv_c_<l> of a latent layer -> [num_pages, page_size, kv_dim]) are
+    owned and threaded/donated by the engine's step function; this object
+    owns the PAGE IDS and the ledger accounting. Page 0 is never handed
+    out.
 
     One pool is one CLASS of pages: every layer of it shares the page ids
     (a request's page j is page j of each of the pool's layers). A model
@@ -70,13 +88,17 @@ class KVPagePool:
     0..n_layers-1, as ever; `PagedKVCache` below puts a second pool beside
     it for the layers that keep a ring. ``layers`` names the model's layer
     indices this pool holds (the arrays' names) and ``kv_dims`` their
-    widths; ``klass`` names the class in the ledger
-    (``mem.serving.kv_pool_bytes.<klass>``) when there is more than one."""
+    widths; ``latent`` says which of them keep one array a layer and not
+    K and V (their bytes are also booked as
+    ``mem.serving.kv_pool_bytes.latent``); ``klass`` names the class in
+    the ledger (``mem.serving.kv_pool_bytes.<klass>``) when there is more
+    than one."""
 
     def __init__(self, n_layers: int, num_pages: int, page_size: int,
                  kv_dim: int, dtype: str = "float32",
                  layers: Optional[List[int]] = None,
-                 kv_dims: Optional[List[int]] = None, klass: str = ""):
+                 kv_dims: Optional[List[int]] = None, klass: str = "",
+                 latent: Optional[List[bool]] = None):
         if num_pages < 2:
             raise ValueError(f"KV pool needs >= 2 pages (page 0 is the "
                              f"reserved scratch page), got {num_pages}")
@@ -89,6 +111,8 @@ class KVPagePool:
             else [int(i) for i in layers]
         self.kv_dims = [self.kv_dim] * self.n_layers if kv_dims is None \
             else [int(d) for d in kv_dims]
+        self.latent = [False] * self.n_layers if latent is None \
+            else [bool(v) for v in latent]
         self.klass = klass
         self._lock = lockdep.lock("serving.kv_pool")
         self._free: List[int] = list(range(1, self.num_pages))
@@ -96,11 +120,16 @@ class KVPagePool:
         self._high_water_pages = 0
         import numpy as np
 
-        itemsize = np.dtype(dtype).itemsize
-        # keys + values, every layer
-        self.pool_bytes = (2 * self.num_pages * self.page_size
-                           * sum(self.kv_dims) * itemsize)
+        token_bytes = np.dtype(dtype).itemsize * self.page_size \
+            * self.num_pages
+        # keys + values of every layer; a latent layer keeps one array
+        latent_bytes = token_bytes * sum(
+            d for d, lat in zip(self.kv_dims, self.latent) if lat)
+        self.pool_bytes = 2 * token_bytes * sum(self.kv_dims) - latent_bytes
         self._page_bytes = self.pool_bytes // self.num_pages
+        if latent_bytes:
+            telemetry.gauge_set("mem.serving.kv_pool_bytes.latent",
+                                latent_bytes)
         if klass:
             telemetry.gauge_set(f"mem.serving.kv_pool_bytes.{klass}",
                                 self.pool_bytes)
@@ -116,10 +145,10 @@ class KVPagePool:
         import jax.numpy as jnp
 
         out = {}
-        for i, dim in zip(self.layers, self.kv_dims):
+        for i, dim, lat in zip(self.layers, self.kv_dims, self.latent):
             shape = (self.num_pages, self.page_size, dim)
-            out[f"kv_k_{i}"] = jnp.zeros(shape, self.dtype)
-            out[f"kv_v_{i}"] = jnp.zeros(shape, self.dtype)
+            for name in pool_array_names(i, lat):
+                out[name] = jnp.zeros(shape, self.dtype)
         return out
 
     # -- capacity ------------------------------------------------------------
@@ -285,7 +314,8 @@ class PagedKVCache:
         self.context = KVPagePool(
             len(ctx), context_pages, page_size, layout[ctx[0]].kv_dim,
             dtype, layers=ctx, kv_dims=[layout[i].kv_dim for i in ctx],
-            klass=self.CONTEXT if rings else "")
+            klass=self.CONTEXT if rings else "",
+            latent=[layout[i].latent for i in ctx])
         self.ring: Optional[KVPagePool] = None
         if rings:
             if ring_pages is None:     # a ring for every slot, and page 0

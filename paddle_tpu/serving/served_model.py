@@ -6,8 +6,9 @@ the engine asks of a model is this class: its three program builders
 (decode step, whole-prompt prefill, page-chunked prefill), how its
 parameters are laid out and prepared, and what each layer keeps of a
 sequence (`kv_cache.LayerCache`: the width of its K/V and whether its
-pages are a context's or a ring). models/decoder_lm.py and
-models/afmoe.py each give one; ``cfg.served()`` builds it.
+pages are a context's or a ring, or that it keeps ONE latent array a
+token and no K and V). models/decoder_lm.py, models/afmoe.py and
+models/kimi_k2.py each give one; ``cfg.served()`` builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
 the program reads beside parameters and pools, out of what the engine can
@@ -20,8 +21,14 @@ give:
            last_onehot [1, C], page_table, ring_table
 
 and the engine feeds exactly those (a program's arguments are part of its
-compiled form). Every program writes ``logits`` and ``kv_k_<l>_out`` /
-``kv_v_<l>_out`` for each layer; a step program may also write
+compiled form). Beside them a program is fed the pool arrays its layout
+names (`kv_cache.pool_array_names`: ``kv_k_<l>`` and ``kv_v_<l>``, or a
+latent layer's one ``kv_c_<l>``) and writes each back as ``<name>_out``:
+the engine threads and donates exactly `PagedKVCache.make_arrays()`'s
+names. A latent layer so feeds ``kv_c_<l>`` [pages, page, row width] and
+fetches ``kv_c_<l>_out``; it reads ``page_table`` alone (no ring) and its
+model builds no chunk program (the engine refuses the prefix store for
+it). Every program writes ``logits``; a step program may also write
 ``step_counts``, int32 [len(step_counters)], which the engine fetches in
 the same fetch as the step's tokens and adds to the telemetry counters
 named in ``step_counters``.

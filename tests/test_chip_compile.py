@@ -109,6 +109,43 @@ def test_paged_gqa_attention_at_the_trinity_share(one_chip, tpu_mode, ring,
         ((pages, 64, 128), BF16), ((64, table), I32), ((64,), I32))
 
 
+def test_paged_mla_attention_at_the_kimi_share(one_chip, tpu_mode):
+    """64 absorbed query heads on one latent row of 576 values carried in
+    640 lanes, values its first 512, bfloat16 pages of 64 tokens, 64 rows
+    over 128 pages each of a pool of 8193: the streamed walk, two halves
+    of scratch, a DMA semaphore each. A 576-wide pool is what the
+    dispatcher must refuse (Mosaic: a page's slice is no whole lane
+    tile), and the stock gather is what then compiles."""
+    from paddle_tpu.ops.pallas.paged_mla_attention import \
+        paged_mla_decode_attention
+
+    def attend(q, pool, t, p):
+        return paged_mla_decode_attention(q, pool, t, p, num_heads=64,
+                                          value_dim=512, scale=0.1447)
+
+    _compile(attend, one_chip, ((64, 64 * 640), F32),
+             ((8193, 64, 640), BF16), ((64, 128), I32), ((64,), I32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((64, 64 * 576), F32), ((8193, 64, 576), BF16), ((64, 128), I32),
+        ((64,), I32))]
+    assert "tpu_custom_call" not in jax.jit(attend).lower(
+        *args).compile().as_text()
+
+
+def test_mla_prefill_attention_at_the_kimi_share(one_chip, tpu_mode):
+    """A 4096-token prompt, 64 heads of 128 + 64 (the rotary part padded
+    to a lane tile) on keys of the same and values of 128, bfloat16: the
+    blockwise kernel, its scores in VMEM."""
+    from paddle_tpu.ops.pallas.mla_prefill_attention import \
+        mla_prefill_attention
+
+    s, n = 4096, 64
+    _compile(lambda qn, qr, kn, kr, v: mla_prefill_attention(
+        qn, qr, kn, kr, v, 0.1447), one_chip,
+        ((s, n, 128), BF16), ((s, n, 64), BF16), ((s, n, 128), BF16),
+        ((s, 64), BF16), ((s, n, 128), BF16))
+
+
 def test_routed_experts_grouped_product_at_the_trinity_share(one_chip):
     """64 rows, top-4 of 256, experts 0-31 held at width 3072: the three
     grouped products are the chip's ragged-dot kernel, not a dense product

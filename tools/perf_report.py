@@ -415,7 +415,9 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
         out["kv_used_bytes"] = int(
             gauges.get("mem.serving.kv_used_bytes") or 0)
         # a model with window layers has two classes of pages
-        # (kv_cache.PagedKVCache): mem.serving.kv_pool_bytes.<class>
+        # (kv_cache.PagedKVCache): mem.serving.kv_pool_bytes.<class>;
+        # a model with latent layers books their one array a layer as
+        # mem.serving.kv_pool_bytes.latent
         classes = {name.rsplit(".", 1)[1]: int(v)
                    for name, v in gauges.items()
                    if name.startswith("mem.serving.kv_pool_bytes.")}
@@ -432,7 +434,9 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
               ("pallas.int8_gemm_dispatches",
                "pallas.int8_gemm_fallbacks",
                "pallas.paged_attn_dispatches",
-               "pallas.paged_attn_fallbacks") if cval(key)}
+               "pallas.paged_attn_fallbacks",
+               "pallas.mla_prefill_dispatches",
+               "pallas.mla_prefill_fallbacks") if cval(key)}
     if pallas:
         out["pallas_kernels"] = pallas
     # content-addressed prefix store accounting (serving/prefix_store.py):
@@ -1063,7 +1067,9 @@ def render(s, out=sys.stdout):
               f" / {pk.get('int8_gemm_fallbacks', 0)} stock-fallback, "
               f"paged attn {pk.get('paged_attn_dispatches', 0)} "
               f"dispatched / {pk.get('paged_attn_fallbacks', 0)} "
-              f"stock-fallback\n")
+              f"stock-fallback, latent prefill attn "
+              f"{pk.get('mla_prefill_dispatches', 0)} dispatched / "
+              f"{pk.get('mla_prefill_fallbacks', 0)} stock-fallback\n")
         if "prefix_store" in dc:
             ps = dc["prefix_store"]
             looks = ps.get("prefix_hits", 0) + ps.get("prefix_misses", 0)
