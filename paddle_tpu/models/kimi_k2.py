@@ -17,7 +17,11 @@ The block (benchmark/reference_kimi_k2.py is its plain float32 statement):
   ``kv_lora_rank + qk_rope_head_dim`` values a token (carried in whole
   lane tiles: `latent_row_width`). The PREFILL attends in the expanded
   form above (`mla_prefill_attention`: a blockwise kernel on the chip,
-  ops/pallas/mla_prefill_attention.py); the DECODE STEP in the absorbed
+  ops/pallas/mla_prefill_attention.py, which reads ``q_n``, ``q_r`` and
+  ``c W_kvb`` as [S, heads x width] where the projections left them,
+  several heads and one block pair of the causal triangle a grid step,
+  masks only the diagonal blocks, and writes [S, heads x v] rounded once
+  to `dtype`, what ``W_o``'s product takes); the DECODE STEP in the absorbed
   form: with ``W_kvb`` split by head into ``W_uk`` and ``W_uv``,
   ``q_c = q_n W_uk``, ``score = (q_c . c + q_r . k_r) x scale``,
   ``o = (sum p c) W_uv`` (`mla_absorb_query`, `cached_latent_attention`,

@@ -346,10 +346,15 @@ def mla_prefill_attention_op(ins, attrs):
 
     The attend phase is ops/pallas/mla_prefill_attention.py (blockwise,
     online softmax, the scores stay in VMEM) under the PT_PALLAS
-    dispatch; mode 'off' and untileable shapes take the counted stock
+    dispatch, on these arrays as they stand: a prompt's QNope, QRope and
+    KV go in as [S, n*width] and Out comes back [S, n*v], no head-major
+    copy of anything (a head is a column block of the kernel's
+    BlockSpecs). Mode 'off' and untileable shapes take the counted stock
     lowering (``pallas.mla_prefill_fallbacks``). Inputs are rounded to
     `compute_dtype` for the products, which accumulate in float32; the
-    softmax is float32. Out float32 [B, S, n*v]."""
+    softmax is float32. Out [B, S, n*v] is the float32 result rounded
+    once to `compute_dtype`: the rounding the output projection's
+    `linear_acc32` gave it (to its weight's dtype, the same) before."""
     import jax.numpy as jnp
 
     from .pallas.mla_prefill_attention import mla_prefill_attention
@@ -357,14 +362,11 @@ def mla_prefill_attention_op(ins, attrs):
     n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
     rope = int(attrs["rope_dim"])
     dt = jnp.dtype(attrs.get("compute_dtype", "float32"))
-    qn, qr, kv = ins["QNope"][0], ins["QRope"][0], ins["KV"][0]
-    b, s, _ = qn.shape
-    scale = float(attrs.get("scale") or (nope + rope) ** -0.5)
-    kvh = kv.reshape(b, s, n, -1).astype(dt)
+    qn, qr = ins["QNope"][0].astype(dt), ins["QRope"][0].astype(dt)
+    kv = ins["KV"][0].astype(dt)
     k_rope = ins["Latent"][0][..., -rope:].astype(dt)
-    qn = qn.reshape(b, s, n, nope).astype(dt)
-    qr = qr.reshape(b, s, n, rope).astype(dt)
-    out = [mla_prefill_attention(qn[i], qr[i], kvh[i, :, :, :nope],
-                                 k_rope[i], kvh[i, :, :, nope:], scale)
-           for i in range(b)]
-    return {"Out": jnp.stack(out).reshape(b, s, -1)}
+    scale = float(attrs.get("scale") or (nope + rope) ** -0.5)
+    out = [mla_prefill_attention(qn[i], qr[i], kv[i], k_rope[i], scale,
+                                 num_heads=n, nope_dim=nope)
+           for i in range(qn.shape[0])]
+    return {"Out": jnp.stack(out)}

@@ -14,7 +14,10 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     for grouped heads, windows and rings; paged_mla_attention
                     over latent pages (absorbed latent attention),
 * mla_prefill_attention — whole-prompt causal latent attention in its
-                    expanded form, scores kept in VMEM.
+                    expanded form on the layer's own [S, heads x width]
+                    arrays: a step is one block pair of the causal
+                    triangle for several heads, scores kept in VMEM, only
+                    the diagonal masked (and attended in sub-blocks).
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

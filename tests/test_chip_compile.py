@@ -132,18 +132,36 @@ def test_paged_mla_attention_at_the_kimi_share(one_chip, tpu_mode):
         *args).compile().as_text()
 
 
-def test_mla_prefill_attention_at_the_kimi_share(one_chip, tpu_mode):
-    """A 4096-token prompt, 64 heads of 128 + 64 (the rotary part padded
-    to a lane tile) on keys of the same and values of 128, bfloat16: the
-    blockwise kernel, its scores in VMEM."""
-    from paddle_tpu.ops.pallas.mla_prefill_attention import \
-        mla_prefill_attention
+@pytest.mark.parametrize("s", [2048, 4096, 6144])
+def test_mla_prefill_attention_at_the_kimi_share(one_chip, tpu_mode, s):
+    """A prompt bucket of the Kimi cell, 64 heads of 128 + 64 on keys of
+    the same and values of 128, bfloat16, through the op on the layer's
+    own float32 [1, S, n x d] arrays: the blockwise kernel, 8 heads a
+    step, its scores in VMEM. Beside the custom call stand the roundings
+    to bfloat16 and the shared rotary key's two lane tiles, and NO
+    transpose or copy of an [S, n x d] operand or of the output."""
+    import math
+    import re
 
-    s, n = 4096, 64
-    _compile(lambda qn, qr, kn, kr, v: mla_prefill_attention(
-        qn, qr, kn, kr, v, 0.1447), one_chip,
-        ((s, n, 128), BF16), ((s, n, 64), BF16), ((s, n, 128), BF16),
-        ((s, 64), BF16), ((s, n, 128), BF16))
+    from paddle_tpu.ops.llm_ops import mla_prefill_attention_op
+    from paddle_tpu.ops.pallas import mla_prefill_attention as mpa
+
+    n, nope, rope, dv = 64, 128, 64, 128
+    assert mpa._heads_a_step(n, nope, rope, dv, mpa.BLOCK, 2) == 8
+
+    def attend(qn, qr, kv, latent):
+        return mla_prefill_attention_op(
+            {"QNope": [qn], "QRope": [qr], "KV": [kv], "Latent": [latent]},
+            {"num_heads": n, "nope_dim": nope, "rope_dim": rope,
+             "scale": 0.1447, "compute_dtype": "bfloat16"})["Out"]
+
+    text = _compile(attend, one_chip, ((1, s, n * nope), F32),
+                    ((1, s, n * rope), F32), ((1, s, n * (nope + dv)), F32),
+                    ((1, s, 512 + rope), F32))
+    assert 'custom_call_target="tpu_custom_call"' in text
+    moved = re.findall(r"= \w+\[([\d,]+)\]\S* (transpose|copy)\(", text)
+    assert all(math.prod(map(int, dims.split(","))) <= s * 2 * 128
+               for dims, _ in moved), moved
 
 
 def test_routed_experts_grouped_product_at_the_trinity_share(one_chip):

@@ -252,6 +252,31 @@ def test_the_stock_gather_is_counted(monkeypatch):
         "pallas.paged_attn_fallbacks"] == 1
 
 
+def _prompt(rng, s, n, nope, rope, dv, dtype=jnp.float32):
+    """(q_nope, q_rope, k_nope, k_rope, v), heads apart as the stock
+    lowering takes them."""
+    return tuple(jnp.asarray(rng.randn(*shape), dtype) for shape in (
+        (s, n, nope), (s, n, rope), (s, n, nope), (s, rope), (s, n, dv)))
+
+
+def _rows(q_nope, q_rope, k_nope, k_rope, v):
+    """The same prompt as the layer's own arrays: (q_nope [S, n*nope],
+    q_rope [S, n*rope], kv [S, n*(nope+dv)], k_rope)."""
+    s = q_nope.shape[0]
+    kv = jnp.concatenate([k_nope, v], axis=-1)
+    return (q_nope.reshape(s, -1), q_rope.reshape(s, -1), kv.reshape(s, -1),
+            k_rope)
+
+
+def _kernel_of(args, scale=0.1):
+    q_nope, _, _, _, v = args
+    s, n, nope = q_nope.shape
+    out = mpa.mla_prefill_attention(*_rows(*args), scale, num_heads=n,
+                                    nope_dim=nope)
+    assert out.shape == (s, n * v.shape[2]) and out.dtype == v.dtype
+    return np.asarray(out.astype(jnp.float32)).reshape(s, n, -1)
+
+
 @pytest.mark.parametrize("s, block", [(384, 128), (128, 512)])
 def test_the_prefill_kernel_against_its_stock_lowering(monkeypatch, s,
                                                        block):
@@ -260,38 +285,187 @@ def test_the_prefill_kernel_against_its_stock_lowering(monkeypatch, s,
     rotary key is one for all heads."""
     monkeypatch.setenv("PT_PALLAS", "interpret")
     monkeypatch.setattr(mpa, "BLOCK", block)
-    rng = np.random.RandomState(6)
-    n, nope, rope, dv = 3, 128, 64, 128
-
-    def arr(*shape):
-        return jnp.asarray(rng.randn(*shape), jnp.float32)
-
-    args = (arr(s, n, nope), arr(s, n, rope), arr(s, n, nope), arr(s, rope),
-            arr(s, n, dv))
+    args = _prompt(np.random.RandomState(6), s, 4, 128, 64, 128)
     telemetry.reset()
-    got = mpa.mla_prefill_attention(*args, 0.1)
+    got = _kernel_of(args)
     want = mpa.stock_mla_prefill_attention(*args, 0.1, block_q=128)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
-                               atol=1e-5)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5, atol=1e-5)
     # causal: a query's output does not move with a later key
     later = list(args)
     later[2] = args[2].at[s - 1].add(5.0)
-    np.testing.assert_array_equal(
-        np.asarray(mpa.mla_prefill_attention(*later, 0.1))[:s - 1],
-        np.asarray(got)[:s - 1])
+    np.testing.assert_array_equal(_kernel_of(later)[:s - 1], got[:s - 1])
     c = telemetry.snapshot()["counters"]
     assert c["pallas.mla_prefill_dispatches"] == 2
     assert not c.get("pallas.mla_prefill_fallbacks")
+
+
+def _dense_attention(q_nope, q_rope, k_nope, k_rope, v, scale):
+    """Every score at once, float32: the plainest statement."""
+    f = [np.asarray(a, np.float32) for a in (q_nope, q_rope, k_nope,
+                                             k_rope, v)]
+    sc = (np.einsum("qhd,khd->hqk", f[0], f[2])
+          + np.einsum("qhr,kr->hqk", f[1], f[3])) * scale
+    s = sc.shape[-1]
+    sc = np.where(np.tri(s, dtype=bool), sc, -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    return np.einsum("hqk,khv->qhv", p / p.sum(-1, keepdims=True), f[4])
+
+
+# (prompt, BLOCK, heads, rope, VMEM_BLOCKS or None, heads a step, dtype,
+# tolerance): what the dispatch tells apart. A block of 512 is attended on
+# its diagonal in two sub-blocks of SUB rows, one of 128 whole; the heads
+# a step follow from the widths, the dtype and VMEM_BLOCKS.
+_FORMS = [
+    (512, 512, 2, 64, None, 2, "float32", 1e-5),      # one block, sub-blocks
+    (1024, 512, 4, 64, None, 4, "float32", 1e-5),     # + a block below it
+    (1024, 512, 4, 64, 8 << 20, 2, "float32", 1e-5),  # two head groups
+    (512, 128, 8, 64, None, 8, "float32", 1e-5),      # 4 key blocks to one
+    (512, 128, 8, 64, 4 << 20, 4, "float32", 1e-5),
+    (384, 128, 2, 128, None, 2, "float32", 1e-5),     # rope of a lane tile
+    (384, 128, 3, 128, None, 3, "float32", 1e-5),     # so heads may be odd
+    (256, 128, 4, 32, None, 4, "float32", 1e-5),      # 4 heads a rope tile
+    (1024, 512, 8, 64, None, 8, "bfloat16", 2e-2),    # the Kimi form
+    (512, 128, 4, 64, None, 4, "bfloat16", 2e-2),
+]
+
+
+@pytest.mark.parametrize(
+    "s, block, n, rope, vmem, heads, dtype, tol", _FORMS,
+    ids=[f"s{f[0]}-b{f[1]}-n{f[2]}-r{f[3]}-g{f[5]}-{f[6]}" for f in _FORMS])
+def test_every_form_of_the_prefill_kernel(monkeypatch, s, block, n, rope,
+                                          vmem, heads, dtype, tol):
+    """Interpreted on the CPU against the stock lowering AND the plainest
+    dense statement, in every form the dispatch can pick; then causality
+    and a padded tail: whatever stands after a row moves no bit of it."""
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    monkeypatch.setattr(mpa, "BLOCK", block)
+    if vmem is not None:
+        monkeypatch.setattr(mpa, "VMEM_BLOCKS", vmem)
+    dt = jnp.dtype(dtype)
+    assert mpa._heads_a_step(n, 128, rope, 128, block, dt.itemsize) == heads
+    rng = np.random.RandomState(s + n + rope)
+    args = _prompt(rng, s, n, 128, rope, 128, dt)
+    telemetry.reset()
+    got = _kernel_of(args)
+    c = telemetry.snapshot()["counters"]
+    assert c["pallas.mla_prefill_dispatches"] == 1
+    assert not c.get("pallas.mla_prefill_fallbacks")
+    want = np.asarray(mpa.stock_mla_prefill_attention(*args, 0.1,
+                                                      block_q=128))
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(got, _dense_attention(*args, 0.1),
+                               rtol=max(tol, 2e-5), atol=max(tol, 2e-5))
+    # a padded tail: rows from `real` on are another prompt's, and large
+    real = s - block // 2 - 3
+    tail = _prompt(rng, s, n, 128, rope, 128, dt)
+    padded = [jnp.concatenate([a[:real], 7.0 * t[real:]])
+              for a, t in zip(args, tail)]
+    np.testing.assert_array_equal(_kernel_of(padded)[:real], got[:real])
+    # causality, row by row: the last key alone
+    later = list(args)
+    later[2] = args[2].at[s - 1].add(5.0)
+    later[3] = args[3].at[s - 1].add(5.0)
+    later[4] = args[4].at[s - 1].add(5.0)
+    np.testing.assert_array_equal(_kernel_of(later)[:s - 1], got[:s - 1])
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 1e-5),
+                                        ("bfloat16", 2e-2)])
+def test_the_prefill_op_on_the_layers_arrays_equals_the_parents(monkeypatch,
+                                                                dtype, tol):
+    """`run_op("mla_prefill_attention")` on [B, S, n*d] arrays, the kernel
+    interpreted, against what the op gave before PR 34: the stock products
+    on head-major slices of the rounded inputs, float32 [B, S, n*v] (which
+    W_o's product then rounded to its weight's dtype: Out's dtype now)."""
+    rng = np.random.RandomState(11)
+    b, s, n, nope, rope, dv, rank = 2, 256, 4, 128, 64, 128, 32
+    ins = {"QNope": jnp.asarray(rng.randn(b, s, n * nope), jnp.float32),
+           "QRope": jnp.asarray(rng.randn(b, s, n * rope), jnp.float32),
+           "KV": jnp.asarray(rng.randn(b, s, n * (nope + dv)), jnp.float32),
+           "Latent": jnp.asarray(rng.randn(b, s, rank + rope), jnp.float32)}
+    attrs = {"num_heads": n, "nope_dim": nope, "rope_dim": rope,
+             "scale": 0.09, "compute_dtype": dtype}
+    dt = jnp.dtype(dtype)
+    kvh = ins["KV"].reshape(b, s, n, -1).astype(dt)
+    parent = jnp.stack([mpa.stock_mla_prefill_attention(
+        ins["QNope"][i].reshape(s, n, nope).astype(dt),
+        ins["QRope"][i].reshape(s, n, rope).astype(dt), kvh[i, :, :, :nope],
+        ins["Latent"][i, :, -rope:].astype(dt), kvh[i, :, :, nope:], 0.09)
+        for i in range(b)]).reshape(b, s, -1)
+    for mode, exact in (("interpret", False), ("off", True)):
+        monkeypatch.setenv("PT_PALLAS", mode)
+        telemetry.reset()
+        out = run_op("mla_prefill_attention", ins, attrs)["Out"]
+        assert out.shape == (b, s, n * dv) and out.dtype == dt
+        c = telemetry.snapshot()["counters"]
+        assert c.get("pallas.mla_prefill_fallbacks", 0) == (b if exact
+                                                            else 0)
+        if exact:       # the stock lowering itself, rounded as W_o did
+            np.testing.assert_array_equal(
+                np.asarray(out.astype(jnp.float32)),
+                np.asarray(parent.astype(dt).astype(jnp.float32)))
+        else:
+            np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                       np.asarray(parent), rtol=tol,
+                                       atol=tol)
 
 
 def test_the_prefill_kernels_stock_lowering_is_counted(monkeypatch):
     monkeypatch.setenv("PT_PALLAS", "off")
     telemetry.reset()
     mpa.mla_prefill_attention(
-        jnp.zeros((16, 2, 8)), jnp.zeros((16, 2, 4)), jnp.zeros((16, 2, 8)),
-        jnp.zeros((16, 4)), jnp.zeros((16, 2, 8)), 1.0)
+        jnp.zeros((16, 2 * 8)), jnp.zeros((16, 2 * 4)),
+        jnp.zeros((16, 2 * 16)), jnp.zeros((16, 4)), 1.0, num_heads=2,
+        nope_dim=8)
     assert telemetry.snapshot()["counters"][
         "pallas.mla_prefill_fallbacks"] == 1
+
+
+# (s, heads, nope, rope, BLOCK, VMEM_BLOCKS, mode) -> reason
+_REFUSED = {
+    "mode_off": (256, 4, 128, 64, 128, None, "off"),
+    "length": (640, 4, 128, 64, 512, None, "interpret"),
+    "tpu_tiling-head_width": (256, 4, 64, 64, 128, None, "interpret"),
+    "tpu_tiling-block": (64, 4, 128, 64, 512, None, "interpret"),
+    "tpu_tiling-rope": (256, 4, 128, 48, 128, None, "interpret"),
+    "tpu_tiling-odd_heads_share_a_rope_tile": (256, 3, 128, 64, 128, None,
+                                               "interpret"),
+    "vmem": (256, 4, 128, 64, 128, 1 << 16, "interpret"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_REFUSED))
+def test_a_refused_shape_is_counted_with_its_reason(monkeypatch, case):
+    """A shape the kernel's form cannot take attends through the stock
+    lowering, correctly, and the counter's record says why."""
+    s, n, nope, rope, block, vmem, mode = _REFUSED[case]
+    monkeypatch.setenv("PT_PALLAS", mode)
+    monkeypatch.setattr(mpa, "BLOCK", block)
+    if vmem is not None:
+        monkeypatch.setattr(mpa, "VMEM_BLOCKS", vmem)
+    seen = []
+    add = telemetry.counter_add
+    monkeypatch.setattr(
+        mpa.telemetry, "counter_add",
+        lambda name, delta=1, **attrs: (seen.append((name, attrs)),
+                                        add(name, delta, **attrs))[1])
+    args = _prompt(np.random.RandomState(3), s, n, nope, rope, 128)
+    telemetry.reset()
+    got = _kernel_of(args)
+    assert seen == [("pallas.mla_prefill_fallbacks",
+                     {"reason": case.split("-")[0]})]
+    c = telemetry.snapshot()["counters"]
+    assert c["pallas.mla_prefill_fallbacks"] == 1
+    assert not c.get("pallas.mla_prefill_dispatches")
+    np.testing.assert_allclose(got, _dense_attention(*args, 0.1), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_a_scale_that_is_not_positive_is_refused():
+    with pytest.raises(ValueError, match="positive"):
+        mpa.mla_prefill_attention(
+            jnp.zeros((16, 16)), jnp.zeros((16, 8)), jnp.zeros((16, 32)),
+            jnp.zeros((16, 4)), 0.0, num_heads=2, nope_dim=8)
 
 
 # -- through the engine ------------------------------------------------------
