@@ -425,10 +425,12 @@ def ledger() -> Dict[str, Any]:
     # is RESIDENT for the process lifetime — its full preallocation, not
     # just the used pages, belongs in the composed total
     kv_pool = int(g.get("mem.serving.kv_pool_bytes", 0) or 0)
+    # a state-space model's per-slot states beside the pages: resident too
+    state_pool = int(g.get("mem.serving.state_pool_bytes", 0) or 0)
     out = {"param_bytes": param_bytes, "opt_state_bytes": opt_bytes,
            "peak_temp_bytes": int(peak_temp),
            "total_bytes": param_bytes + opt_bytes + int(peak_temp)
-           + kv_pool,
+           + kv_pool + state_pool,
            "programs": len(recs)}
     if opt_global is not None:
         out["opt_state_bytes_global"] = int(opt_global)
@@ -441,6 +443,10 @@ def ledger() -> Dict[str, Any]:
             g.get("mem.serving.kv_used_bytes", 0) or 0)
         out["serving_kv_high_water_bytes"] = int(
             g.get("mem.serving.kv_high_water_bytes", 0) or 0)
+    if state_pool:
+        out["serving_state_pool_bytes"] = state_pool
+        out["serving_state_used_bytes"] = int(
+            g.get("mem.serving.state_pool_bytes.used", 0) or 0)
     # cumulative pool bytes requests did NOT privately allocate thanks
     # to a prefix-cache hit (serving/prefix_store.py) — savings, not
     # residency, so it never joins total_bytes

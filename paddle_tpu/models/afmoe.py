@@ -40,9 +40,9 @@ import numpy as np
 
 from .. import layers
 from ..core.ir import Program, program_guard
-from ..layer_helper import LayerHelper
 from ..serving.kv_cache import LayerCache, PagedKVCache
 from ..serving.served_model import ServedModel
+from .program_block import Block, named_out as _named_out, op as _op
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -161,56 +161,13 @@ def afmoe_params(cfg: AfmoeConfig, seed: int = 0):
 # ---------------------------------------------------------------------------
 # program builders
 
-def _param(name, spec):
-    shape, _kind, dtype = spec
-    return layers.static_data(name, list(shape), dtype)
-
-
-def _named_out(name, dtype="float32"):
-    from ..core.ir import default_main_program
-
-    return default_main_program().current_block().create_var(
-        name=name, dtype=dtype, stop_gradient=True)
-
-
-def _op(type_, ins, outs, attrs=None, dtype="float32"):
-    """Append one op; `outs` maps slot -> a Variable, or None for a fresh
-    temporary. Returns the outputs in the order of `outs`."""
-    helper = LayerHelper(type_)
-    made = [v if v is not None
-            else helper.create_variable_for_type_inference(dtype)
-            for v in outs.values()]
-    helper.append_op(type_, {k: [v] for k, v in ins.items()},
-                     {k: [v] for k, v in zip(outs, made)}, attrs or {})
-    return made[0] if len(made) == 1 else made
-
-
-class _Block:
+class _Block(Block):
     """The layers of one program: parameters by name, the norms, the
-    projections and the MLPs, which are the same in every phase; how a
-    layer attends is the phase's own (`attend`)."""
+    projections and the MLPs (models/program_block.py), which are the same
+    in every phase; how a layer attends is the phase's own (`attend`)."""
 
     def __init__(self, cfg: AfmoeConfig, kv: PagedKVCache):
-        self.cfg, self.kv = cfg, kv
-        self.specs = param_specs(cfg)
-        self.pool_outs: List[str] = []
-        self.counts = None          # running sum of the MoE layers' Counts
-
-    def param(self, name):
-        return _param(name, self.specs[name])
-
-    def norm(self, x, name):
-        return _op("rms_norm", {"X": x, "Scale": self.param(name)},
-                   {"Y": None}, {"epsilon": self.cfg.rms_norm_eps})
-
-    def linear(self, x, name, **attrs):
-        return _op("linear_acc32", {"X": x, "W": self.param(name)},
-                   {"Out": None}, attrs)
-
-    def swiglu(self, x, p, w1, w3, w2):
-        mid = _op("swiglu", {"Gate": self.linear(x, p + w1),
-                             "Up": self.linear(x, p + w3)}, {"Out": None})
-        return self.linear(mid, p + w2)
+        super().__init__(cfg, kv, param_specs(cfg))
 
     def pools(self, i):
         """(PoolK, PoolV, PoolKOut, PoolVOut, table name) of layer i."""

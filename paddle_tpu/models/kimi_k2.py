@@ -62,8 +62,7 @@ from ..ops.llm_ops import yarn_mscale
 from ..serving.kv_cache import (LayerCache, PagedKVCache,
                                 pool_array_names)
 from ..serving.served_model import ServedModel
-from . import afmoe
-from .afmoe import _named_out, _op
+from .program_block import Block, named_out as _named_out, op as _op
 
 LANES = 128
 
@@ -200,17 +199,14 @@ def kimi_k2_params(cfg: KimiK2Config, seed: int = 0):
 # ---------------------------------------------------------------------------
 # program builders
 
-class _Block(afmoe._Block):
+class _Block(Block):
     """The layers of one program. Parameters by name, norms, projections
-    and SwiGLU are models/afmoe.py's (`param`, `norm`, `linear`,
+    and SwiGLU are models/program_block.py's (`param`, `norm`, `linear`,
     `swiglu`), the same in every phase; how a layer attends is the
     phase's own (`attend`)."""
 
     def __init__(self, cfg: KimiK2Config, kv: PagedKVCache):
-        self.cfg, self.kv = cfg, kv
-        self.specs = param_specs(cfg)
-        self.pool_outs: List[str] = []
-        self.counts = None          # running sum of the MoE layers' Counts
+        super().__init__(cfg, kv, param_specs(cfg))
 
     def pool(self, i):
         """(Pool, PoolOut) of layer i: its one latent array."""

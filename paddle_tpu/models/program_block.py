@@ -1,0 +1,59 @@
+"""What the served decoders' program builders share, whatever the model:
+one op appended by slot names, a named output, and the block's parameters
+by name with the norm, the projection and the SwiGLU every one of them
+has. models/afmoe.py, kimi_k2.py and falcon_h1.py each subclass `Block`
+with their own layers, pools and heads."""
+
+from __future__ import annotations
+
+from typing import List
+
+from .. import layers
+from ..layer_helper import LayerHelper
+
+
+def named_out(name, dtype="float32"):
+    from ..core.ir import default_main_program
+
+    return default_main_program().current_block().create_var(
+        name=name, dtype=dtype, stop_gradient=True)
+
+
+def op(type_, ins, outs, attrs=None, dtype="float32"):
+    """Append one op; `outs` maps slot -> a Variable, or None for a fresh
+    temporary. Returns the outputs in the order of `outs`."""
+    helper = LayerHelper(type_)
+    made = [v if v is not None
+            else helper.create_variable_for_type_inference(dtype)
+            for v in outs.values()]
+    helper.append_op(type_, {k: [v] for k, v in ins.items()},
+                     {k: [v] for k, v in zip(outs, made)}, attrs or {})
+    return made[0] if len(made) == 1 else made
+
+
+class Block:
+    """The layers of one program: parameters by name (`specs`: name ->
+    (shape, kind, dtype)), the norms, the projections and the SwiGLU, which
+    are the same in every phase; how a layer attends is the phase's own."""
+
+    def __init__(self, cfg, kv, specs):
+        self.cfg, self.kv, self.specs = cfg, kv, specs
+        self.pool_outs: List[str] = []
+        self.counts = None          # running sum of the MoE layers' Counts
+
+    def param(self, name):
+        shape, _kind, dtype = self.specs[name]
+        return layers.static_data(name, list(shape), dtype)
+
+    def norm(self, x, name):
+        return op("rms_norm", {"X": x, "Scale": self.param(name)},
+                  {"Y": None}, {"epsilon": self.cfg.rms_norm_eps})
+
+    def linear(self, x, name, **attrs):
+        return op("linear_acc32", {"X": x, "W": self.param(name)},
+                  {"Out": None}, attrs)
+
+    def swiglu(self, x, p, w1, w3, w2):
+        mid = op("swiglu", {"Gate": self.linear(x, p + w1),
+                            "Up": self.linear(x, p + w3)}, {"Out": None})
+        return self.linear(mid, p + w2)

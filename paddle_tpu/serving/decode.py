@@ -128,7 +128,7 @@ from .admission import (AdmissionQueue, DeadlineExceededError,
                         EngineClosedError, InferenceRequest,
                         KVCacheExhaustedError, ServingError)
 from .health import DRAINING, READY, STOPPED, HealthState
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, state_array_names
 from .prefix_store import PrefixStore
 
 
@@ -250,7 +250,7 @@ class GenerationRequest(InferenceRequest):
                  "pages", "table_row", "pos_next", "last_token",
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
                  "stop_at_eos", "ring_pages", "ring_row", "first_logits",
-                 "slot", "carried", "ahead", "_rng_cut")
+                 "slot", "carried", "ahead", "_rng_cut", "final_state")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -287,6 +287,9 @@ class GenerationRequest(InferenceRequest):
         # False, or True to keep the prefill's logits row (float32) here:
         # how a check compares logits where the engine gives them out
         self.first_logits: Any = False
+        # False, or True to keep the slot's per-slot state arrays as they
+        # stand when the request retires (a model with recurrent state)
+        self.final_state: Any = False
         # the position and, for a request's first step, the token its next
         # step is fed; ``pos_next`` moves on when a step is DISPATCHED
         self.pos_next = 0
@@ -441,6 +444,18 @@ class DecodeEngine:
         # attends is counted for it (decode.kv_tokens_attended)
         self._ring_or_latent = self.kv.ring is not None \
             or any(self.kv.context.latent)
+        if self.kv.has_state and (
+                self.config.prefix_cache or self.config.role != "unified"):
+            # a recurrent state is a slot's and has no per-token pages: the
+            # prefix store has nothing of it to share and no chunk program
+            # resumes one (a prompt's pages without the state after them
+            # are half a prefix), and a shipment carries pages alone; a
+            # snapshot store is later work
+            raise ValueError(
+                "a model with recurrent state (a state and a conv tail a "
+                "slot) runs unified and without the prefix store: a state "
+                "has no per-token pages to share or to ship, and no chunk "
+                "program carries one over")
         if self._ring_or_latent and (
                 self.config.prefix_cache or self.config.role != "unified"):
             # the prefix store shares a prompt's full pages between
@@ -496,7 +511,8 @@ class DecodeEngine:
                request_id: Optional[str] = None,
                prior_tokens: Optional[Sequence[int]] = None,
                rng_state: Optional[Any] = None,
-               keep_first_logits: bool = False) -> GenerationRequest:
+               keep_first_logits: bool = False,
+               keep_final_state: bool = False) -> GenerationRequest:
         """Enqueue one generation (non-blocking). ``prompt`` is a 1-D
         int token-id array. Raises ValueError (malformed / over the
         model length), KVCacheExhaustedError (can never fit the KV
@@ -509,7 +525,11 @@ class DecodeEngine:
         the same KV either way), restores the sampler RNG mid-stream
         and generates only the remaining ``max_new_tokens``.
         ``keep_first_logits`` leaves the prefill's float32 logits row on
-        the request (``first_logits``) beside its tokens."""
+        the request (``first_logits``) beside its tokens;
+        ``keep_final_state`` leaves its slot's per-slot state arrays as they
+        stand when it retires (``final_state``: name -> the slot's entry;
+        for a request that ends on its count, the state after its last FED
+        token, the one before its last chosen one)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -535,6 +555,7 @@ class DecodeEngine:
             eos_id=self.model_cfg.eos_id if stop_at_eos else None,
             session_id=request_id, prior=prior)
         req.first_logits = bool(keep_first_logits)
+        req.final_state = bool(keep_final_state)
         if rng_state is not None:
             from .session import unpack_rng_state
 
@@ -691,7 +712,10 @@ class DecodeEngine:
         program, feeds, fetches = build(bucket, self.kv, cc.weight_quant)
         # a program's arguments are part of its compiled form: it is fed
         # exactly the names its builder lists (and the sampler's)
-        self._feed_names[key] = tuple(feeds) + (
+        # (a step's `state_slots` are `carry`'s slots, taken on the device)
+        by_slot = phase == "step" and "state_slots" in feeds
+        self._feed_names[key] = tuple(
+            n for n in feeds if not (by_slot and n == "state_slots")) + (
             ("sampling", "carry") if phase == "step" else ())
         block = program.global_block()
         pool_names = sorted(self._pools)
@@ -715,7 +739,13 @@ class DecodeEngine:
             tokens = jnp.where(carried > 0,
                                last_tokens.at[slot].get(mode="clip"),
                                feed["tokens"])
-            env, pools = run(params, pools, dict(feed, tokens=tokens))
+            feed = dict(feed, tokens=tokens)
+            if by_slot:
+                # a model with per-slot state finds a row's state by the
+                # slot its request keeps; a padding row's is the scratch
+                # slot, max_slots
+                feed["state_slots"] = slot
+            env, pools = run(params, pools, feed)
             chosen = sample_tokens(env["logits"], feed["sampling"][:, 0],
                                    feed["sampling"][:, 1])
             last_tokens = last_tokens.at[slot].set(chosen, mode="drop")
@@ -788,13 +818,17 @@ class DecodeEngine:
                 tables, tokens=np.zeros((bucket,), np.int32),
                 positions=np.zeros((bucket,), np.int32),
                 sampling=np.zeros((bucket, 2), np.float32),
-                carry=np.zeros((bucket, 2), np.int32)))
+                # no row names a slot: a model's per-slot state is touched
+                # at the scratch slot alone, whenever this compiles
+                carry=np.full((bucket, 2), (self.config.max_slots, 0),
+                              np.int32)))
         oh = np.zeros((1, bucket), np.float32)
         oh[0, 0] = 1.0
         return self._feed(phase, bucket, dict(
             tables, tokens=np.zeros((1, bucket), np.int32),
             positions=np.zeros((1, bucket), np.int32),
             chunk_start=np.zeros((1,), np.int32),
+            state_slots=np.full((1,), self.config.max_slots, np.int32),
             lengths=np.ones((1,), np.int32), last_onehot=oh))
 
     # -- scheduler loop ------------------------------------------------------
@@ -948,6 +982,11 @@ class DecodeEngine:
             pages, req.ring_pages = got
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
+            # the slot is taken before the prefill, which writes a stateful
+            # model's state and tail there over what the slot's last owner
+            # left (a row dispatched on speculation for a request that
+            # ended ran before this prefill on the device)
+            self._take_slot(req)
             try:
                 self._prefilled = _Prefilled(
                     req, pages, self._prefill(req, pages, hashes, shared))
@@ -972,6 +1011,7 @@ class DecodeEngine:
         """A per-request error: give back what the admission took."""
         self.kv.free(req.pages if req.pages else pages, req.ring_pages)
         req.pages, req.ring_pages = [], []
+        self._release_slot(req)
         if req.shared_blocks:
             self.prefix_store.release(req.shared_blocks)
             req.shared_blocks = []
@@ -1010,6 +1050,7 @@ class DecodeEngine:
             "tokens": tokens, "lengths": np.asarray([L], np.int32),
             "last_onehot": oh,
             "positions": np.arange(bucket, dtype=np.int32)[None, :],
+            "state_slots": np.asarray([req.slot], np.int32),
             "page_table": row[None, :], "ring_table": req.ring_row[None, :]})
         ms: Dict[str, float] = {}
         with telemetry.timer("decode.prefill_ms", into=ms):
@@ -1183,11 +1224,13 @@ class DecodeEngine:
             telemetry.counter_add("decode.prefills", 1)
             telemetry.observe("decode.queue_wait_ms",
                               (time.monotonic() - req.t_submit) * 1e3)
+            self._take_slot(req)
             self._seat(req, np.asarray(ship["logits"]))
             return True
         except Exception as e:
             if pages:
                 self.pool.free(pages)
+            self._release_slot(req)
             telemetry.counter_add("disagg.fallback_prefills", 1,
                                   exc=type(e).__name__)
             return False
@@ -1283,7 +1326,10 @@ class DecodeEngine:
                 chosen = np.asarray(flight.chosen)
         for name, value in zip(self.model.step_counters, chosen[bucket:]):
             telemetry.counter_add(name, int(value))
-        if self._ring_or_latent:
+        if self.kv.has_state:
+            telemetry.counter_add("decode.state_rows_updated",
+                                  len(rows) * len(self.kv.state_layers))
+        if self._ring_or_latent or self.kv.has_state:
             # keys (a latent layer's cached tokens) a step reads: a ring
             # layer's rows read their window
             ctx = flight.positions.astype(np.int64) + 1
@@ -1368,14 +1414,27 @@ class DecodeEngine:
 
     def _seat(self, req: GenerationRequest, logits_row: np.ndarray):
         """A prefilled request gets its first token and, unless that ends
-        it, a slot: the one it keeps until it retires."""
+        it, keeps the slot its admission took until it retires."""
         self._first_token(req, logits_row)
         req.pos_next = int(req.seq.size)
         if req.finished():
             self._retire(req)
         else:
-            req.slot = self._free_slots.pop()
+            if self.kv.has_state:   # its prefill wrote the slot's state
+                telemetry.counter_add("decode.state_slots_seated", 1)
             self._active.append(req)
+
+    def _take_slot(self, req: GenerationRequest):
+        req.slot = self._free_slots.pop()
+        self.kv.note_state_slots(
+            self.config.max_slots - len(self._free_slots))
+
+    def _release_slot(self, req: GenerationRequest):
+        if req.slot is not None:
+            self._free_slots.append(req.slot)
+            req.slot = None
+            self.kv.note_state_slots(
+                self.config.max_slots - len(self._free_slots))
 
     def _first_token(self, req: GenerationRequest, logits_row: np.ndarray):
         """A request's first token, chosen on the host from the logits row
@@ -1403,9 +1462,17 @@ class DecodeEngine:
         prefix-store references and resolve/fail it — finished
         sequences leave WITHOUT draining the batch. Shared pages stay
         resident in the store (that is the cache)."""
-        if req.slot is not None:
-            self._free_slots.append(req.slot)
-            req.slot = None
+        if req.final_state is True and req.slot is not None:
+            # queued behind the steps dispatched so far, none of which
+            # holds a request that ended on its count
+            req.final_state = {
+                n: self._pools[n][req.slot] for i in self.kv.state_layers
+                for n in state_array_names(i)}
+        self._release_slot(req)
+        # A slot's recurrent state needs no clearing, here or with a step in
+        # flight that still advances it (a row dispatched on speculation):
+        # the successor's prefill is queued behind that step on the device
+        # and overwrites the slot's state and tail whole.
         # Freeing here is safe with a step in flight that still holds this
         # request as a row (dispatched on speculation past its EOS or its
         # deadline): that step's K/V write lands on a page the request

@@ -45,10 +45,20 @@ class LayerCache:
     `kv_dim` a token in a context's pages (multi-head latent attention:
     the normed compressed latent, then the rotated key all heads share),
     from which every head's key and value are expanded or absorbed; it has
-    no separate V."""
+    no separate V.
+
+    Beside its pages a layer may keep a per-SLOT state (a state-space
+    mixer: a third kind of per-request state, a fixed size a request, not a
+    size a token): `ssm_state` = (heads, head_dim, d_state), the recurrent
+    state in `state_dtype`, and `conv_tail` = (conv_dim, d_conv - 1), the
+    convolution's last inputs in the pages' dtype. A prefill WRITES the
+    request's slot of both, a step advances them in place."""
     kv_dim: int
     window: int = 0
     latent: bool = False
+    ssm_state: Tuple[int, ...] = ()
+    conv_tail: Tuple[int, ...] = ()
+    state_dtype: str = "float32"
 
     def __post_init__(self):
         if self.latent and self.window:
@@ -65,6 +75,15 @@ def pool_array_names(layer: int, latent: bool) -> Tuple[str, ...]:
     is written back as ``<name>_out``)."""
     return (f"kv_c_{layer}",) if latent \
         else (f"kv_k_{layer}", f"kv_v_{layer}")
+
+
+def state_array_names(layer: int) -> Tuple[str, str]:
+    """The per-slot state arrays of layer `layer` (written back as
+    ``<name>_out``): the recurrent state [slots + 1, heads, d_state,
+    head_dim] (d_state on sublanes, head_dim on lanes) and the conv tail
+    [slots + 1, d_conv - 1, conv_dim] (time-major); the last slot is the
+    scratch slot of padding rows and warm-up feeds."""
+    return (f"ssm_state_{layer}", f"conv_tail_{layer}")
 
 
 def ring_pages_per_slot(window: int, page_size: int) -> int:
@@ -291,7 +310,15 @@ class PagedKVCache:
     it had before there were classes; without ``ring_pages`` the ring
     pool holds one ring for each of ``slots``. A request is seated only if BOTH
     classes can seat it (`try_alloc` takes from both or from neither),
-    and `audit` holds both to their invariants."""
+    and `audit` holds both to their invariants.
+
+    Layers with a per-slot state (`LayerCache.ssm_state`) add the state
+    class: `state_array_names` arrays of ``slots + 1`` states, booked as
+    ``mem.serving.state_pool_bytes`` (``.used``: the seated requests'
+    share, `note_state_slots`). It has no page ids: a request's state is
+    its SLOT's, which the engine hands out with the seat, so a request is
+    admitted only where a slot is free, and the slot's state is the
+    prefill's to overwrite."""
 
     CONTEXT, RING = "context", "ring"
 
@@ -326,6 +353,57 @@ class PagedKVCache:
                 kv_dims=[layout[i].kv_dim for i in rings], klass=self.RING)
             telemetry.gauge_set("mem.serving.kv_pool_bytes",
                                 self.pool_bytes)
+        self.state_layers = [i for i, lc in enumerate(layout)
+                             if lc.ssm_state]
+        self.state_slots = int(slots) + 1 if self.state_layers else 0
+        self.state_slot_bytes = 0
+        if self.state_layers:
+            import numpy as np
+
+            if slots < 1:
+                raise ValueError("a model with per-slot state needs the "
+                                 "engine's slot count")
+            for i in self.state_layers:
+                lc = layout[i]
+                self.state_slot_bytes += \
+                    int(np.prod(lc.ssm_state)) \
+                    * np.dtype(lc.state_dtype).itemsize \
+                    + int(np.prod(lc.conv_tail)) * np.dtype(dtype).itemsize
+            telemetry.gauge_set("mem.serving.state_pool_bytes",
+                                self.state_pool_bytes)
+            self.note_state_slots(0)
+            costmodel.refresh_ledger()
+
+    @property
+    def has_state(self) -> bool:
+        return bool(self.state_layers)
+
+    @property
+    def state_pool_bytes(self) -> int:
+        return self.state_slots * self.state_slot_bytes
+
+    def note_state_slots(self, seated: int):
+        """The ledger's share of the state class that seated requests
+        hold."""
+        if self.state_layers:
+            self._state_seated = int(seated)
+            telemetry.gauge_set("mem.serving.state_pool_bytes.used",
+                                self._state_seated * self.state_slot_bytes)
+
+    def _state_arrays(self) -> Dict[str, Any]:
+        import jax.numpy as jnp
+
+        out = {}
+        for i in self.state_layers:
+            lc = self.layout[i]
+            heads, head_dim, d_state = lc.ssm_state
+            conv_dim, taps = lc.conv_tail
+            state, tail = state_array_names(i)
+            out[state] = jnp.zeros(
+                (self.state_slots, heads, d_state, head_dim), lc.state_dtype)
+            out[tail] = jnp.zeros((self.state_slots, taps, conv_dim),
+                                  self.context.dtype)
+        return out
 
     @property
     def pool_bytes(self) -> int:
@@ -336,6 +414,7 @@ class PagedKVCache:
         out = self.context.make_arrays()
         if self.ring is not None:
             out.update(self.ring.make_arrays())
+        out.update(self._state_arrays())
         return out
 
     def pages_for_tokens(self, tokens: int) -> Tuple[int, int]:
@@ -393,4 +472,11 @@ class PagedKVCache:
                                pages_per_slot=self.ring_slot_pages,
                                window=self.window)
             out["pool_bytes_total"] = self.pool_bytes
+        if self.state_layers:
+            out["state"] = {"layers": len(self.state_layers),
+                            "slots": self.state_slots - 1,
+                            "slot_bytes": self.state_slot_bytes,
+                            "pool_bytes": self.state_pool_bytes,
+                            "used_bytes": self._state_seated
+                            * self.state_slot_bytes}
         return out

@@ -7,16 +7,19 @@ the engine asks of a model is this class: its three program builders
 parameters are laid out and prepared, and what each layer keeps of a
 sequence (`kv_cache.LayerCache`: the width of its K/V and whether its
 pages are a context's or a ring, or that it keeps ONE latent array a
-token and no K and V). models/decoder_lm.py, models/afmoe.py and
-models/kimi_k2.py each give one; ``cfg.served()`` builds it.
+token and no K and V; and what it keeps a SLOT beside them: a recurrent
+state and a conv tail). models/decoder_lm.py, models/afmoe.py,
+models/kimi_k2.py and models/falcon_h1.py each give one; ``cfg.served()``
+builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
 the program reads beside parameters and pools, out of what the engine can
 give:
 
-  step     tokens [B], positions [B], page_table [B, MP], ring_table [B, R]
+  step     tokens [B], positions [B], page_table [B, MP], ring_table [B, R],
+           state_slots [B]
   prefill  tokens [1, S], lengths [1], last_onehot [1, S], positions [1, S],
-           page_table [1, MP], ring_table [1, R]
+           page_table [1, MP], ring_table [1, R], state_slots [1]
   chunk    tokens, positions [1, C], chunk_start [1], lengths [1],
            last_onehot [1, C], page_table, ring_table
 
@@ -28,7 +31,17 @@ the engine threads and donates exactly `PagedKVCache.make_arrays()`'s
 names. A latent layer so feeds ``kv_c_<l>`` [pages, page, row width] and
 fetches ``kv_c_<l>_out``; it reads ``page_table`` alone (no ring) and its
 model builds no chunk program (the engine refuses the prefix store for
-it). Every program writes ``logits``; a step program may also write
+it). A layer with per-slot state (`LayerCache.ssm_state`) also feeds
+``ssm_state_<l>`` [slots + 1, heads, d_state, head_dim] and
+``conv_tail_<l>`` [slots + 1, d_conv - 1, conv_dim]
+(`kv_cache.state_array_names`) and writes each back likewise;
+``state_slots`` names each row's slot (a step's from the slot its seated
+request keeps, which ``carry`` holds on the device; a padding row's and a
+warm-up feed's is the scratch slot, the arrays' last). Its prefill WRITES
+the slot's state and tail, its step advances them in place; its model
+builds no chunk program (a state has no pages to share: the engine refuses
+the prefix store and the disaggregated roles for it). Every program writes
+``logits``; a step program may also write
 ``step_counts``, int32 [len(step_counters)], which the engine fetches in
 the same fetch as the step's tokens and adds to the telemetry counters
 named in ``step_counters``.

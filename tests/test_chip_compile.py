@@ -179,6 +179,25 @@ def test_routed_experts_grouped_product_at_the_trinity_share(one_chip):
     assert "ragged-dot" in text
 
 
+def test_ssm_state_update_at_the_falcon_h1_widths(one_chip, tpu_mode):
+    """64 rows over 65 slots of 32 heads x [256, 128] float32, the state
+    donated: the kernel, and the state aliased to its output (no copy of
+    the 273 MB array beside it)."""
+    from paddle_tpu.ops.pallas.ssm_state_update import ssm_state_update
+
+    shapes = [((65, 32, 256, 128), F32), ((64,), I32), ((64, 32, 128), F32),
+              ((64, 32), F32), ((64, 2, 256), F32), ((64, 2, 256), F32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(ssm_state_update, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state = 65 * 32 * 256 * 128 * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 8
+
+
 def test_layer_norm_fwd_bwd(one_chip, tpu_mode):
     from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 

@@ -375,3 +375,43 @@ def test_a_journal_record_leaves_out_the_draw_made_ahead(engine):
         assert ahead == 1
         assert same(live, advanced(77, n + 1))
         assert same(unpack_rng_state(packed).get_state(), advanced(77, n))
+
+
+def test_a_slot_is_taken_at_admission_and_given_back_by_a_failed_prefill():
+    """Every model's request takes its slot before its prefill (a model
+    with per-slot state writes the slot there); a prefill that fails, or a
+    request that ends on its first token, gives it back."""
+    from paddle_tpu.serving.admission import ServingError
+
+    eng = _engine()
+    entry_of = eng._entry
+    taken = []
+
+    def entry(phase, bucket):
+        fn = entry_of(phase, bucket)
+        if phase != "prefill":
+            return fn
+
+        def prefill(params, pools, feed):
+            taken.append(len(eng._free_slots))
+            if len(taken) == 1:
+                raise RuntimeError("planted")
+            return fn(params, pools, feed)
+
+        return prefill
+
+    eng.start(warmup=True)
+    eng._entry = entry
+    try:
+        with pytest.raises(ServingError, match="prefill failed"):
+            eng.generate(np.arange(5, 14), max_new_tokens=4, timeout=60)
+        assert sorted(eng._free_slots) == list(range(4))
+        assert len(eng.generate(np.arange(5, 14), max_new_tokens=1,
+                                timeout=60)) == 1
+        assert len(eng.generate(np.arange(5, 14), max_new_tokens=4,
+                                timeout=60)) == 4
+    finally:
+        eng.close()
+    assert taken == [3, 3, 3]       # one slot held while each prefill ran
+    assert sorted(eng._free_slots) == list(range(4))
+    assert eng.pool.stats()["pages_used"] == 0

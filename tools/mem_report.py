@@ -88,11 +88,12 @@ def summarize_mem(recs, malformed=0):
                if n.startswith("mem.serving.bucket")
                and n.endswith("_peak_bytes")}
     kv_pool = int(_num(gauges.get("mem.serving.kv_pool_bytes")))
+    state_pool = int(_num(gauges.get("mem.serving.state_pool_bytes")))
     ledger = {"param_bytes": param_b, "opt_state_bytes": opt_b,
               "peak_temp_bytes": peak_temp,
               "total_bytes": int(_num(gauges.get("mem.hbm_total_bytes"),
                                       param_b + opt_b + peak_temp
-                                      + kv_pool))}
+                                      + kv_pool + state_pool))}
     if gauges.get("sharding.optimizer_state_bytes") is not None:
         ledger["opt_state_bytes_global"] = int(
             _num(gauges["sharding.optimizer_state_bytes"]))
@@ -105,6 +106,11 @@ def summarize_mem(recs, malformed=0):
             _num(gauges.get("mem.serving.kv_used_bytes")))
         ledger["serving_kv_high_water_bytes"] = int(
             _num(gauges.get("mem.serving.kv_high_water_bytes")))
+    if state_pool:
+        # a state-space model's per-slot recurrent states and conv tails
+        ledger["serving_state_pool_bytes"] = state_pool
+        ledger["serving_state_used_bytes"] = int(
+            _num(gauges.get("mem.serving.state_pool_bytes.used")))
     kv_saved = int(_num(gauges.get("mem.serving.kv_prefix_saved_bytes")))
     if kv_saved:
         # prefill bytes the content-addressed prefix store skipped
@@ -186,6 +192,10 @@ def render(s, out=sys.stdout):
           f"   (in use {_fmt_bytes(led['serving_kv_used_bytes'])}, "
           f"high water "
           f"{_fmt_bytes(led['serving_kv_high_water_bytes'])})\n")
+    if led.get("serving_state_pool_bytes"):
+        w(f"{'state pool (decode)':<26}"
+          f"{_fmt_bytes(led['serving_state_pool_bytes']):>16}"
+          f"   (seated {_fmt_bytes(led['serving_state_used_bytes'])})\n")
     if led.get("serving_kv_prefix_saved_bytes"):
         w(f"{'prefix cache savings':<26}"
           f"{_fmt_bytes(led['serving_kv_prefix_saved_bytes']):>16}"

@@ -436,7 +436,9 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.paged_attn_dispatches",
                "pallas.paged_attn_fallbacks",
                "pallas.mla_prefill_dispatches",
-               "pallas.mla_prefill_fallbacks") if cval(key)}
+               "pallas.mla_prefill_fallbacks",
+               "pallas.ssm_state_update_dispatches",
+               "pallas.ssm_state_update_fallbacks") if cval(key)}
     if pallas:
         out["pallas_kernels"] = pallas
     # content-addressed prefix store accounting (serving/prefix_store.py):
@@ -1069,7 +1071,10 @@ def render(s, out=sys.stdout):
               f"dispatched / {pk.get('paged_attn_fallbacks', 0)} "
               f"stock-fallback, latent prefill attn "
               f"{pk.get('mla_prefill_dispatches', 0)} dispatched / "
-              f"{pk.get('mla_prefill_fallbacks', 0)} stock-fallback\n")
+              f"{pk.get('mla_prefill_fallbacks', 0)} stock-fallback, "
+              f"ssm state update "
+              f"{pk.get('ssm_state_update_dispatches', 0)} dispatched / "
+              f"{pk.get('ssm_state_update_fallbacks', 0)} stock-fallback\n")
         if "prefix_store" in dc:
             ps = dc["prefix_store"]
             looks = ps.get("prefix_hits", 0) + ps.get("prefix_misses", 0)
