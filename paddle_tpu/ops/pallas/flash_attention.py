@@ -17,6 +17,7 @@ constraints or no TPU/interpreter backend is selected (kernel_mode()).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -48,7 +49,17 @@ NEG_INF = -1e30
 def _splitmix(x):
     """splitmix32 finalizer over a uint32 array."""
     U = jnp.uint32
-    x = (x ^ (x >> U(16))) * U(0x85EBCA6B)
+    return _splitmix_tail(x ^ (x >> U(16)))
+
+
+def _splitmix_tail(x):
+    """_splitmix after its first xor-shift. That shift is linear over xor
+    ((a ^ b) >> 16 == (a >> 16) ^ (b >> 16)), so a kernel that hashes
+    `lattice ^ seed word` for many seeds shifts the lattice ONCE and the
+    seed word as a scalar, and starts here: the same bits for two
+    operations a position less."""
+    U = jnp.uint32
+    x = x * U(0x85EBCA6B)
     x = (x ^ (x >> U(13))) * U(0xC2B2AE35)
     return x ^ (x >> U(16))
 
@@ -62,15 +73,25 @@ def _bh_seed(seed, bh):
                                            * U(0x9E3779B9)))
 
 
-def _keep_scale_from_lin(lin, seed2, rate):
-    """f32 keep/(1-rate)-or-0 multiplier from a q*Sk+k lattice index and a
-    per-(b,h) seed (shared by the XLA, Pallas and ring paths). Threshold
-    compare in uint space: drop iff hash < rate * 2^32."""
+def _keep_from_lin(lin, seed2, rate):
+    """The dropout mask's bits: True where position `lin` (a q*Sk+k
+    lattice index) of the (b,h) whose derived seed is `seed2` is KEPT.
+    Threshold compare in uint space: drop iff hash < rate * 2^32."""
     U = jnp.uint32
     x = _splitmix(lin ^ (jnp.asarray(seed2, U) * U(0x9E3779B9)))
-    thresh = U(min(int(float(rate) * 4294967296.0), 4294967295))
-    return jnp.where(x >= thresh, jnp.float32(1.0 / (1.0 - rate)),
-                     jnp.float32(0.0))
+    return x >= _drop_below(rate)
+
+
+def _drop_below(rate):
+    """The hash value under which a position is dropped."""
+    return jnp.uint32(min(int(float(rate) * 4294967296.0), 4294967295))
+
+
+def _keep_scale_from_lin(lin, seed2, rate):
+    """f32 keep/(1-rate)-or-0 multiplier of the same bits (shared by the
+    XLA, Pallas and ring paths)."""
+    return jnp.where(_keep_from_lin(lin, seed2, rate),
+                     jnp.float32(1.0 / (1.0 - rate)), jnp.float32(0.0))
 
 
 def _warn_lattice_wrap(sq_g, sk_g):
@@ -916,211 +937,357 @@ def _fused_bwd_kernel_g(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 # The model's 4 head transposes per layer ([B,S,n,hd]<->[B,n,S,hd] around
 # q/k/v and ctx) cost ~13.9 ms of the ERNIE step. These kernels read the
 # projection outputs DIRECTLY: the grid cell is (batch, block of g heads),
-# the block a [sq, g*hd] column slice, and the per-head "transpose" is a
-# static column slice inside VMEM. Measured (BASELINE.md,
-# b34/h16/s512/d64 + dropout): fwd 0.80 ms/layer (g=16) vs 1.00 for
-# kernel+transposes; bwd 1.48 (g=8) vs 1.81. g=16 bwd exceeds VMEM
-# (9 io blocks x 1 MB double-buffered + f32 temporaries).
+# the block a [sq, g*hd] column slice.
+#
+# **A step** goes through its block in UNITS, unrolled: a unit is one
+# 128-lane tile and the `128 // hd` heads whose columns share it (2 at hd
+# 64), or one head's own columns where hd does not divide 128. The heads
+# of a tile are MASKED apart, never sliced: head j's scores are the
+# product of the q tile with the other heads' lanes zeroed on the WHOLE k
+# tile (a 128-deep contraction costs the MXU the passes of a half-filled
+# 64-deep one), `P_j V_tile` puts out 128 lanes of which head j's are
+# kept by one select, and the tile is stored whole. No load or store is
+# lane-shifted or masked.
+#
+# **The forward's statistics** (row maximum, row sum, lse) are float32 and
+# lane-replicated `[sq, 128]`, widened over the score block by whole-tile
+# reuse (`pltpu.repeat`), as `mla_prefill_attention.py` keeps them. `Lse`
+# stays `[B, n, S]` in HBM (positions on lanes); the turn from the
+# kernel's columns to that row is ONE `[sq, 128]` transpose a step (a
+# step's heads side by side on the lanes), not one relayout a head: the
+# head-a-time store `lse_ref[0, i, :] = col[:, 0]` was a quarter of the old
+# forward.
+#
+# **The backward is KEY-MAJOR:** its score blocks are `[keys, queries]`
+# (`K_j Q^T`, `V_j dO^T`; the head masks go on k and v). A query's lse and
+# delta are then ROWS, as `Lse` lies in HBM: lse is a static row of the
+# `[g, sq]` block broadcast over sublanes, delta a sublane sum of ONE
+# transpose of the tile's `dO * O` for the tile's heads. `dv = P^T dO` and
+# `dk = dS^T Q` are plain products of the blocks as computed; only `dq`
+# turns one (the query-major form turned two, and picked lse and delta
+# columns out by lane sums: 1.44 against 1.38 ms a layer).
+#
+# **Off the score block:** `scale` goes into the q tile where it is a power
+# of two (exact in any float; otherwise it multiplies the scores as
+# before); dropout's `1 / (1 - rate)` multiplies the `[sq, 128]` products,
+# the mask itself is a select on the hash's compare; the bias, the
+# causal mask and the hash's position lattice with its first xor-shift
+# (`_splitmix_tail`) are built once a step.
+#
+# Precisions are the bnsd kernels': the inputs' dtype into every product,
+# float32 scores, statistics and accumulators, probabilities rounded to
+# the inputs' dtype for the second product (BEFORE dropout's scale, which
+# is where a result can differ from the bnsd route's in its last bit).
+#
+# Alone on a v5e at ERNIE-large's shape (b40 s512 16 x 64 bfloat16, key
+# bias, dropout 0.1; chip runs of PR 36, ms a layer): forward 0.75,
+# backward 1.38 (the sliced, head-a-time form before: 1.11 and 1.57).
+# With hd 64 every product half-fills the 128 x 128 MXU, so its own floor
+# is 0.44 and 1.09. What knock-outs read on the masked form: the dropout
+# hash 0.17 forward / 0.23 backward (its bits are shared with the XLA,
+# bnsd and ring routes and stay), the row maximum 0.11 and the row sum
+# 0.13 (cross-lane reductions), the backward's dbias sum 0.07.
 
-# VMEM budgets as block ELEMENTS (cols x sq), measured at s=512/h=16:
-# fwd g=16 (1024-col blocks) best; bwd g=16 exceeds VMEM, g=8 best.
-PACKED_FWD_ELEMS = 1024 * 512
-PACKED_BWD_ELEMS = 512 * 512
+_LANES = 128
+# A step's [sq, g*hd] block in ELEMENTS (cols x sq): 1 MiB in bfloat16,
+# g 16 at ERNIE-large's shape. The backward holds 8 such blocks (two
+# buffers each, 16 MiB) beside ~8 MiB of score temporaries, the forward
+# 4; the backward at g 16 against g 8 read 1.437 against 1.454 ms, the
+# forward 1.114 against 1.123 (chip runs, PR 36): a step's overhead is
+# not what bounds them.
+PACKED_ELEMS = 1024 * 512
+PACKED_VMEM_LIMIT = 64 << 20    # of v5e's 128 MiB; the default is 16
 
 
-def _packed_g(h, hd, sq, limit_elems):
+def _packed_unit(hd):
+    """(heads, columns) of a unit: the heads that share a 128-lane tile
+    where hd divides 128, else one head and its own columns."""
+    heads = _LANES // hd if _LANES % hd == 0 else 1
+    return heads, heads * hd
+
+
+def _packed_g(h, hd, sq):
     """Largest g dividing h whose [sq, g*hd] block is Mosaic-legal
     ((g*hd) % 128 == 0 or whole-width; lse block needs g % 8 == 0 or
-    whole-h) and fits the VMEM element budget; 0 if none."""
-    for g in range(h, 0, -1):
-        if h % g:
+    whole-h), is whole units, keeps a head's statistic a lane of one
+    tile and fits PACKED_ELEMS; 0 if none."""
+    unit_heads = _packed_unit(hd)[0]
+    for g in range(min(h, _LANES), 0, -1):
+        if h % g or g % unit_heads:
             continue
         if (g * hd) % 128 and g != h:
             continue
         if g % 8 and g != h:
             continue
-        if g * hd * sq <= limit_elems:
+        if g * hd * sq <= PACKED_ELEMS:
             return g
     return 0
 
 
-def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
-                       lse_ref, *, scale, causal, g, npg, hd, rate,
-                       n_heads, sq_g, sk_g):
-    from jax.experimental import pallas as pl
+def _wide(x, cols):
+    """[rows, 128], every lane alike -> [rows, cols], whole tiles reused."""
+    from jax.experimental.pallas import tpu as pltpu
 
-    c = pl.program_id(0)
-    bidx0 = (c // npg) * n_heads + (c % npg) * g
-    for i in range(g):
-        sl = slice(i * hd, (i + 1) * hd)
-        q = q_ref[0, :, sl]                    # (sq, hd)
-        k = k_ref[0, :, sl]
-        v = v_ref[0, :, sl]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-        sq_n, sk_n = s.shape
+    if cols <= _LANES:
+        return x[:, :cols]
+    if cols % _LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], cols))
+    return pltpu.repeat(x, cols // _LANES, 1)
+
+
+def _row_stat(x):
+    """A row statistic (rows, 1) lane-replicated [rows, 128]."""
+    return jnp.broadcast_to(x, (x.shape[0], _LANES))
+
+
+class _PackedStep:
+    """What the heads of a grid step share: the step's first (b,h) index,
+    the bias, the causal mask, the dropout lattice (all three [keys,
+    queries] where `key_major`) and the lane ids of a unit."""
+
+    def __init__(self, bias_ref, seed_ref, q_ref, *, scale, causal, g, npg,
+                 hd, rate, n_heads, key_major=False):
+        from jax.experimental import pallas as pl
+
+        c = pl.program_id(0)
+        self.bidx0 = (c // npg) * n_heads + (c % npg) * g
+        self.scale, self.rate, self.g = scale, rate, g
+        # a power of two: multiplying q by it rounds nothing, so the
+        # [sq, sk] scores need no multiply
+        self.fold = scale > 0 and math.frexp(scale)[0] == 0.5
+        self.heads, self.cols = _packed_unit(hd)
+        self.units = g // self.heads
+        self.sq = sq = q_ref.shape[1]
+        self.bias = None if bias_ref is None \
+            else bias_ref[0].astype(jnp.float32)              # (1, sk)
+        qd, kd = (1, 0) if key_major else (0, 1)     # the score block's axes
+        if key_major and self.bias is not None:
+            # a key's bias down the sublanes: the row turned once
+            self.bias = _wide(jnp.broadcast_to(self.bias, (_LANES, sq)).T,
+                              sq)
+        self.ok = None
         if causal:
-            rows = (sk_n - sq_n) + jax.lax.broadcasted_iota(
-                jnp.int32, (sq_n, sk_n), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (sq_n, sk_n), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
+            self.ok = jax.lax.broadcasted_iota(jnp.int32, (sq, sq), qd) \
+                >= jax.lax.broadcasted_iota(jnp.int32, (sq, sq), kd)
+        self.lin = self.seed = None
         if rate > 0.0:
-            p = p * _keep_scale_tile(seed_ref[0], rate, bidx0 + i,
-                                     n_heads, 0, 0, sq_n, sk_n,
-                                     sq_g, sk_g)
-        ln = jnp.where(l == 0.0, 1.0, l)
-        acc = jax.lax.dot(p.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32)
-        o_ref[0, :, sl] = (acc / ln).astype(o_ref.dtype)
-        lse_ref[0, i, :] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+            U = jnp.uint32
+            lin = jax.lax.broadcasted_iota(U, (sq, sq), qd) * U(sq) \
+                + jax.lax.broadcasted_iota(U, (sq, sq), kd)
+            self.lin = lin ^ (lin >> U(16))     # see _splitmix_tail
+            self.seed = seed_ref[0]
+        self.unit_lane = jax.lax.broadcasted_iota(
+            jnp.int32, (1, self.cols), 1) // hd
+
+    def lanes(self, t):
+        """Unit t's columns of a block."""
+        return slice(t * self.cols, (t + 1) * self.cols)
+
+    def only(self, xf, j, dtype):
+        """Head j's own copy of a unit's float32 values [rows, cols]: the
+        other heads' lanes zeroed, rounded to `dtype` (a product's
+        operand)."""
+        if self.heads > 1:
+            xf = jnp.where(self.unit_lane == j, xf, 0.0)
+        return xf.astype(dtype)
+
+    def merge(self, out, x, j):
+        """Head j's lanes of x into the unit's result."""
+        return x if j == 0 else jnp.where(self.unit_lane == j, x, out)
+
+    def scaled_q(self, q):
+        """The q tile in float32 with the scale in it, where that rounds
+        nothing."""
+        qf = q.astype(jnp.float32)
+        return qf * self.scale if self.fold and self.scale != 1.0 else qf
+
+    def scores(self, xf, y, j):
+        """Head j's float32 scores x_j y^T, scale, bias and causal mask
+        applied: [queries, keys] of (q float32, k), or key-major
+        [keys, queries] of (k float32, q)."""
+        s = jax.lax.dot_general(self.only(xf, j, y.dtype), y,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if not self.fold:
+            s = s * self.scale
+        if self.bias is not None:
+            s = s + self.bias
+        if self.ok is not None:
+            s = jnp.where(self.ok, s, NEG_INF)
+        return s
+
+    def keep(self, head):
+        """The dropout mask's bits for head `head` of the step (bool
+        [sq, sk]; bit-identical to _attn_keep_scale at these positions)."""
+        U = jnp.uint32
+        word = _bh_seed(self.seed, jnp.asarray(self.bidx0 + head, U)) \
+            * U(0x9E3779B9)
+        return _splitmix_tail(self.lin ^ (word ^ (word >> U(16)))) \
+            >= _drop_below(self.rate)
+
+
+def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
+                       lse_ref, **kw):
+    st = _PackedStep(bias_ref, seed_ref, q_ref, **kw)
+    sq, inv_keep = st.sq, 1.0 / (1.0 - st.rate)
+    lses = jnp.zeros((sq, _LANES), jnp.float32)        # a head a lane
+    lse_lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    for t in range(st.units):
+        cols = st.lanes(t)
+        qf = st.scaled_q(q_ref[0, :, cols])
+        k = k_ref[0, :, cols]
+        v = v_ref[0, :, cols]
+        out = None
+        for j in range(st.heads):
+            head = t * st.heads + j
+            s = st.scores(qf, k, j)
+            m = _row_stat(jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _wide(m, sq))
+            l = _row_stat(jnp.sum(p, axis=-1, keepdims=True))
+            if st.rate > 0.0:
+                p = jnp.where(st.keep(head), p, 0.0)
+            acc = jax.lax.dot(p.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32)
+            r = inv_keep / jnp.where(l == 0.0, 1.0, l)
+            out = st.merge(out, acc * _wide(r, st.cols), j)
+            lses = jnp.where(lse_lane == head,
+                             m + jnp.log(jnp.maximum(l, 1e-30)), lses)
+        o_ref[0, :, cols] = out.astype(o_ref.dtype)
+    lse_ref[0] = lses.T[:st.g]
 
 
 def _packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                        bias_ref, seed_ref, dq_ref, dk_ref, dv_ref,
-                       dbias_ref, *, scale, causal, g, npg, hd, rate,
-                       n_heads, sq_g, sk_g):
-    from jax.experimental import pallas as pl
-
-    c = pl.program_id(0)
-    bidx0 = (c // npg) * n_heads + (c % npg) * g
-    db_acc = None
-    for i in range(g):
-        sl = slice(i * hd, (i + 1) * hd)
-        q = q_ref[0, :, sl]
-        k = k_ref[0, :, sl]
-        v = v_ref[0, :, sl]
-        do = do_ref[0, :, sl]
-        o = o_ref[0, :, sl]
-        lse = lse_ref[0, i, :][:, None]
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-        sq_n, sk_n = s.shape
-        if causal:
-            rows = (sk_n - sq_n) + jax.lax.broadcasted_iota(
-                jnp.int32, (sq_n, sk_n), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (sq_n, sk_n), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        if rate > 0.0:
-            mt = _keep_scale_tile(seed_ref[0], rate, bidx0 + i, n_heads,
-                                  0, 0, sq_n, sk_n, sq_g, sk_g)
-            pd_ = p * mt
-        else:
-            mt, pd_ = None, p
-        dv_ref[0, :, sl] = jax.lax.dot_general(
-            pd_.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if mt is not None:
-            dp = dp * mt
-        ds_nos = p * (dp - delta)
-        if dbias_ref is not None:
-            db_acc = jnp.sum(ds_nos, axis=0) if db_acc is None \
-                else db_acc + jnp.sum(ds_nos, axis=0)
-        ds = (ds_nos * scale).astype(q.dtype)
-        dq_ref[0, :, sl] = jax.lax.dot(
-            ds, k, preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        dk_ref[0, :, sl] = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+                       dbias_ref, **kw):
+    # KEY-MAJOR: every score block here is [keys, queries] (see above)
+    st = _PackedStep(bias_ref, seed_ref, q_ref, key_major=True, **kw)
+    sq, keep_prob = st.sq, 1.0 - st.rate
+    inv_keep = 1.0 / keep_prob
+    hd = st.cols // st.heads
+    db = 0.0                                    # dS^T summed over the heads
+    for t in range(st.units):
+        cols = st.lanes(t)
+        k = k_ref[0, :, cols]
+        v = v_ref[0, :, cols]
+        do = do_ref[0, :, cols]
+        q = st.scaled_q(q_ref[0, :, cols]).astype(k.dtype)
+        kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+        # dO * O turned once a unit: a head's delta is a sublane sum
+        do_o_t = (do.astype(jnp.float32)
+                  * o_ref[0, :, cols].astype(jnp.float32)).T   # (cols, sq)
+        dq = dk = dv = None
+        for j in range(st.heads):
+            head = t * st.heads + j
+            p = jnp.exp(st.scores(kf, q, j) - lse_ref[0, head:head + 1, :])
+            dp = jax.lax.dot_general(st.only(vf, j, v.dtype), do,
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            pd_ = p
+            if st.rate > 0.0:
+                keep = st.keep(head)
+                pd_ = jnp.where(keep, p, 0.0)
+                dp = jnp.where(keep, dp, 0.0)
+            delta = jnp.sum(do_o_t[j * hd:(j + 1) * hd], axis=0,
+                            keepdims=True) * keep_prob         # (1, sq)
+            ds = p * (dp - delta)
+            if dbias_ref is not None:
+                db = db + ds
+            ds = ds.astype(k.dtype)
+            dv = st.merge(dv, jax.lax.dot(
+                pd_.astype(do.dtype), do,
+                preferred_element_type=jnp.float32), j)
+            dk = st.merge(dk, jax.lax.dot(
+                ds, q, preferred_element_type=jnp.float32), j)
+            dq = st.merge(dq, jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32), j)
+        for ref, x, c in ((dq_ref, dq, st.scale * inv_keep),
+                          (dk_ref, dk,
+                           (1.0 if st.fold else st.scale) * inv_keep),
+                          (dv_ref, dv, inv_keep)):
+            ref[0, :, cols] = (x if c == 1.0 else x * c).astype(ref.dtype)
     if dbias_ref is not None:
-        dbias_ref[0, 0] = db_acc
+        dbias_ref[0] = _row_stat(
+            jnp.sum(db, axis=-1, keepdims=True) * inv_keep).T[:1]
 
 
-def _fwd_pallas_packed(q3, k3, v3, bias_kv, causal, scale, interpret,
-                       seed, rate, n_heads):
+def _packed_call(kernel, name, q3, bias_kv, operands, n_outs, causal, scale,
+                 interpret, seed, rate, n_heads):
+    """`kernel` over (batch, blocks of g heads): `operands` are [B,S,n*hd]
+    arrays, or [B,n,S] (a statistic); the key bias and the seed follow
+    them. Outputs: `n_outs` [B,S,n*hd] arrays, then lse [B,n,S] from the
+    forward, or from the backward with a bias its dbias partials
+    [B*npg,1,S]. Without a bias the kernel's `bias_ref` is None and so is
+    the backward's `dbias_ref`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, htot = q3.shape
     hd = htot // n_heads
-    g = _packed_g(n_heads, hd, sq, PACKED_FWD_ELEMS)
+    g = _packed_g(n_heads, hd, sq)
     npg = n_heads // g
-    seed_arr = jnp.asarray([0 if seed is None else seed], jnp.uint32)
     cspec = pl.BlockSpec((1, sq, g * hd),
-                         lambda c, _n=npg: (c // _n, 0, c % _n))
-    in_specs = [cspec, cspec, cspec]
-    args = [q3, k3, v3]
-    kw = dict(scale=scale, causal=causal, g=g, npg=npg, hd=hd, rate=rate,
-              n_heads=n_heads, sq_g=sq, sk_g=sq)
+                         lambda c: (c // npg, 0, c % npg))
+    lspec = pl.BlockSpec((1, g, sq), lambda c: (c // npg, c % npg, 0))
+    fwd = kernel is _packed_fwd_kernel
+    in_specs = [cspec if x.shape == q3.shape else lspec for x in operands]
+    args = list(operands)
+    out_specs = [cspec] * n_outs
+    out_shape = [jax.ShapeDtypeStruct(q3.shape, q3.dtype)] * n_outs
+    if fwd:
+        out_specs.append(lspec)
+        out_shape.append(jax.ShapeDtypeStruct((b, n_heads, sq), jnp.float32))
     if bias_kv is not None:
         in_specs.append(pl.BlockSpec((1, 1, sq),
-                                     lambda c, _n=npg: (c // _n, 0, 0)))
+                                     lambda c: (c // npg, 0, 0)))
         args.append(bias_kv.reshape(b, 1, sq))
-        kernel = functools.partial(_packed_fwd_kernel, **kw)
-    else:
-        def kernel(q, k, v, seed_r, o, lse):
-            _packed_fwd_kernel(q, k, v, None, seed_r, o, lse, **kw)
+        if not fwd:
+            out_specs.append(pl.BlockSpec((1, 1, sq), lambda c: (c, 0, 0)))
+            out_shape.append(jax.ShapeDtypeStruct((b * npg, 1, sq),
+                                                  jnp.float32))
     in_specs.append(_seed_spec(pl, pltpu))
-    args.append(seed_arr)
-    o3, lse = pl.pallas_call(
-        kernel, grid=(b * npg,), in_specs=in_specs,
-        out_specs=[cspec,
-                   pl.BlockSpec((1, g, sq),
-                                lambda c, _n=npg: (c // _n, c % _n, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, sq, htot), q3.dtype),
-                   jax.ShapeDtypeStruct((b, n_heads, sq), jnp.float32)],
-        interpret=interpret, name="flash_fwd_packed")(*args)
-    return o3, lse
+    args.append(jnp.asarray(0 if seed is None else seed,
+                            jnp.uint32).reshape(1))
+    kw = dict(scale=scale, causal=causal, g=g, npg=npg, hd=hd, rate=rate,
+              n_heads=n_heads)
+
+    def body(*refs):
+        refs = list(refs)
+        if bias_kv is None:
+            refs.insert(len(operands), None)            # bias_ref
+            if not fwd:
+                refs.append(None)                       # dbias_ref
+        return kernel(*refs, **kw)
+
+    return pl.pallas_call(
+        body, grid=(b * npg,), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PACKED_VMEM_LIMIT),
+        interpret=interpret, name=name)(*args)
 
 
+# both jitted so that a program's layers share ONE trace and lowering of
+# their kernel: the IR traces an op when it is appended and again when its
+# program lowers, 48 times a step program of 24 layers
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 8, 9))
+def _fwd_pallas_packed(q3, k3, v3, bias_kv, causal, scale, interpret,
+                       seed, rate, n_heads):
+    return _packed_call(_packed_fwd_kernel, "flash_fwd_packed", q3, bias_kv,
+                        (q3, k3, v3), 1, causal, scale, interpret, seed,
+                        rate, n_heads)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 11, 12))
 def _bwd_pallas_packed(q3, k3, v3, bias_kv, causal, scale, interpret,
                        o3, lse, do3, seed, rate, n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, sq, htot = q3.shape
-    hd = htot // n_heads
-    g = _packed_g(n_heads, hd, sq, PACKED_BWD_ELEMS)
-    npg = n_heads // g
-    seed_arr = jnp.asarray([0 if seed is None else seed], jnp.uint32)
-    cspec = pl.BlockSpec((1, sq, g * hd),
-                         lambda c, _n=npg: (c // _n, 0, c % _n))
-    in_specs = [cspec] * 5 + [
-        pl.BlockSpec((1, g, sq), lambda c, _n=npg: (c // _n, c % _n, 0))]
-    args = [q3, k3, v3, do3, o3, lse]
-    kw = dict(scale=scale, causal=causal, g=g, npg=npg, hd=hd, rate=rate,
-              n_heads=n_heads, sq_g=sq, sk_g=sq)
-    out_specs = [cspec, cspec, cspec]
-    out_shape = [jax.ShapeDtypeStruct((b, sq, htot), q3.dtype)] * 3
-    has_bias = bias_kv is not None
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, sq),
-                                     lambda c, _n=npg: (c // _n, 0, 0)))
-        args.append(bias_kv.reshape(b, 1, sq))
-        in_specs.append(_seed_spec(pl, pltpu))
-        args.append(seed_arr)
-        out_specs.append(pl.BlockSpec((1, 1, sq), lambda c: (c, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b * npg, 1, sq),
-                                              jnp.float32))
-        kernel = functools.partial(_packed_bwd_kernel, **kw)
-    else:
-        in_specs.append(_seed_spec(pl, pltpu))
-        args.append(seed_arr)
-
-        def kernel(q, k, v, do, o, l, seed_r, dq, dk, dv):
-            _packed_bwd_kernel(q, k, v, do, o, l, None, seed_r,
-                               dq, dk, dv, None, **kw)
-    outs = pl.pallas_call(
-        kernel, grid=(b * npg,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret, name="flash_bwd_packed")(*args)
-    if has_bias:
-        dq3, dk3, dv3, dbias3 = outs
-        dbias = jnp.sum(dbias3.reshape(b, npg, sq), axis=1)
-    else:
-        dq3, dk3, dv3 = outs
-        dbias = None
-    return dq3, dk3, dv3, dbias
+    outs = _packed_call(_packed_bwd_kernel, "flash_bwd_packed", q3, bias_kv,
+                        (q3, k3, v3, do3, o3, lse), 3, causal, scale,
+                        interpret, seed, rate, n_heads)
+    if bias_kv is None:
+        return (*outs, None)
+    b, sq, _ = q3.shape
+    return (*outs[:3], jnp.sum(outs[3].reshape(b, -1, sq), axis=1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -1510,9 +1677,7 @@ def attention_route(q, k, bias=None, num_heads=None):
     # MFU). They need a fused-single-block geometry with lane-aligned
     # head blocks.
     if (packed and sq == sk and hd % 8 == 0 and (n * hd) % 128 == 0
-            and _fused_bwd_applies(sq, sk)
-            and _packed_g(n, hd, sq, PACKED_FWD_ELEMS)
-            and _packed_g(n, hd, sq, PACKED_BWD_ELEMS)):
+            and _fused_bwd_applies(sq, sk) and _packed_g(n, hd, sq)):
         return "packed", bias_kv
     if mode == "interpret":
         return "pallas_interpret", bias_kv

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core import telemetry
+from .flash_attention import _wide
 
 KERNEL_NAME = "mla_prefill_attention"
 BLOCK = 512        # queries and keys a block; a prompt bucket's divisor
@@ -133,15 +134,6 @@ def _heads_a_step(n, nope, rope, dv, block, itemsize):
         if blocks + scratch <= VMEM_BLOCKS:
             best = g
     return best
-
-
-def _wide(x, cols):
-    """[rows, 128], every lane alike -> [rows, cols], whole tiles reused."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    if cols <= _LANES:
-        return x[:, :cols]
-    return pltpu.repeat(x, cols // _LANES, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "nope", "dv", "sub"))
