@@ -147,11 +147,13 @@ _TRACE_ANNOTATION = [None]   # jax.profiler.TraceAnnotation, once resolved
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
-def _annotation(name: str):
+def _annotation(name: str, **attrs):
     """A profiler annotation for one timed region; a null context in a
     process that has not imported jax: a pserver or a tool that only counts
     must not start to. An annotation outside a running trace costs one
-    TraceMe check."""
+    TraceMe check. ``attrs`` are the event's stats in the trace (a
+    request's ``rid``), formatted only while one runs; its name stays
+    ``name``."""
     cls = _TRACE_ANNOTATION[0]
     if cls is None:
         if "jax" not in sys.modules:
@@ -159,7 +161,7 @@ def _annotation(name: str):
         from jax.profiler import TraceAnnotation as cls
 
         _TRACE_ANNOTATION[0] = cls
-    return cls(name)
+    return cls(name, **attrs)
 
 
 # Who else records a timer's span: callables (span name, attrs) -> a context
@@ -497,7 +499,8 @@ class TelemetryRegistry:
         """The one span primitive of a hot path. Times the region into the
         histogram ``name`` (ms) and, while a jax profiler trace is running,
         marks it there as a ``TraceAnnotation`` of the same name, on the
-        clock of the device's own lines. ``into`` defers the sample: the ms
+        clock of the device's own lines, with ``attrs`` as the event's stats
+        (the histogram takes none: one series a name). ``into`` defers the sample: the ms
         are added to that dict under ``name`` instead of the histogram, for
         a caller that decides at the end of an iteration whether it counts
         (DecodeEngine._loop, Executor.run). ``span`` names the region for
@@ -511,7 +514,7 @@ class TelemetryRegistry:
                 return
             t0 = time.perf_counter()
             try:
-                with _annotation(name):
+                with _annotation(name, **attrs):
                     yield
             finally:
                 ms = (time.perf_counter() - t0) * 1e3

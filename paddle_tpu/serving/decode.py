@@ -86,9 +86,10 @@ per-request error, pages are freed, the queue keeps moving) and
 ``decode.kv_alloc`` fails one request's page allocation.
 
 Telemetry: decode.requests/rejects/deadline_expired (admission),
-decode.prefills / prefill_tokens / steps / tokens / retired / errors /
-kv_refusals / kv_pages_allocated / kv_pages_freed counters (steps, tokens
-and batch_occupancy count at accept time what was delivered),
+decode.prefills / prefill_tokens / prefill_bucket_tokens / steps / tokens /
+retired / errors / kv_refusals / kv_pages_allocated / kv_pages_freed
+counters (steps, tokens and batch_occupancy count at accept time what was
+delivered),
 decode.steps_ahead (steps dispatched while the step before was not yet
 fetched: over decode.steps the share of steps that ran ahead; the rest
 went into an empty pipe: an engine's first step, one behind a second
@@ -111,10 +112,30 @@ step program) + decode.sample_ms (accepting the tokens row by row) +
 decode.retire_ms + decode.other_ms; per token decode.token_gap_ms, per request
 decode.queue_wait_ms (submit to the start of its prefill). Each timer is
 also a ``TraceAnnotation`` of the same name in a running profiler trace.
+
+What an admission costs, one quiet observation a prefill each, all inside
+decode.admit_ms: decode.prefill_feed_ms (the prefix lookup, the pages, the
+slot, the numpy feed, up to the dispatch), decode.prefill_wait_ms (the
+host's wait for the prefill program with no step's tokens left to accept:
+the time every live slot stands still; the second half of decode.prefill_ms)
+and decode.seat_ms (the first token's host sample, the seat or the retire);
+the counter decode.prefill_bucket_tokens beside decode.prefill_tokens (the
+tokens the prefill programs computed, padding and all, against the tokens
+asked for). With every accepted step decode.cpu_ms, the engine thread's own
+CPU time (``time.thread_time``) over the iterations decode.loop_ms covers:
+loop less cpu less the two waits for the device (decode.fetch_ms,
+decode.prefill_wait_ms) is time the thread waited for the interpreter lock
+or a core. ``stats()`` gives the shares (``admission_cost``). In a trace a
+request's spans (decode.prefill_ms both halves, with ``bucket=`` and
+``tokens=``; the feed and seat parts, which are decode.admit_ms spans with
+``part=``; decode.retire_ms) carry its ``rid=``; a step's spans belong to
+every row and stay bare.
 """
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence
@@ -140,6 +161,28 @@ from .prefix_store import PrefixStore
 _LOOP_PHASES = ("decode.admit_ms", "decode.feed_ms", "decode.step_ms",
                 "decode.sample_ms", "decode.retire_ms")
 
+# a request's process-unique integer: what its spans share in a trace
+_REQUEST_IDS = itertools.count(1)
+
+
+@contextlib.contextmanager
+def _admission_part(part: str, req: "GenerationRequest"):
+    """One request's share of an admission outside its prefill's own span:
+    ``prefill_feed`` before the dispatch, ``seat`` after the wait. Yields
+    the dict that takes its ms. In a trace it is a decode.admit_ms span
+    INSIDE the loop's (``part=``, ``rid=``), not one of a name of its own:
+    a fill's one decode.admit_ms span lasts seconds and a span that
+    straddles an edge of a traced window is not in the trace, so without
+    these the device's gaps between a fill's prefills are under no span at
+    all; under the phase's name they are where a steady loop's are, and
+    the names the device may idle under stay the ones
+    tests/benchmark_suite/test_bench_phase_readers.py holds them to. The
+    histograms are decode.<part>_ms, the caller's to observe."""
+    ms: Dict[str, float] = {}
+    with telemetry.timer("decode.admit_ms", into=ms, part=part, rid=req.rid):
+        yield ms
+
+
 def _pow2_ladder(lo: int, hi: int) -> List[int]:
     out, b = [], lo
     while b < hi:
@@ -147,6 +190,33 @@ def _pow2_ladder(lo: int, hi: int) -> List[int]:
         b *= 2
     out.append(hi)
     return sorted(set(out))
+
+
+def admission_cost(counters: Dict[str, Any],
+                   hists: Dict[str, Any]) -> Dict[str, float]:
+    """What admissions cost the loop, in % (``stats()``, /v1/stats and
+    tools/perf_report.py's "Decode" section; the benchmark's readers take
+    the same histograms over their window): ``prefill_wait_share``, the
+    share of the loop's wall time in which every live slot stood still for a
+    prefill program (decode.prefill_wait_ms over decode.loop_ms);
+    ``engine_cpu_share``, the engine thread's own CPU time over that wall
+    time (decode.cpu_ms: 100 is a loop the host bounds);
+    ``prefill_padded_token_share``, the share of the tokens the prefill
+    programs computed that were a bucket's padding. A share whose histogram
+    or counter has nothing yet is left out."""
+    out: Dict[str, float] = {}
+    loop_ms = (hists.get("decode.loop_ms") or {}).get("total")
+    for key, name in (("prefill_wait_share", "decode.prefill_wait_ms"),
+                      ("engine_cpu_share", "decode.cpu_ms")):
+        h = hists.get(name)
+        if loop_ms and h and h["count"]:
+            out[key] = round(100.0 * h["total"] / loop_ms, 2)
+    computed = counters.get("decode.prefill_bucket_tokens")
+    if computed:
+        out["prefill_padded_token_share"] = round(
+            100.0 * (1.0 - counters.get("decode.prefill_tokens", 0)
+                     / computed), 2)
+    return out
 
 
 class DecodeConfig:
@@ -250,7 +320,7 @@ class GenerationRequest(InferenceRequest):
                  "pages", "table_row", "pos_next", "last_token",
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
                  "stop_at_eos", "ring_pages", "ring_row", "first_logits",
-                 "slot", "carried", "ahead", "_rng_cut", "final_state")
+                 "slot", "carried", "ahead", "_rng_cut", "final_state", "rid")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -260,6 +330,11 @@ class GenerationRequest(InferenceRequest):
                  prior: Optional[np.ndarray] = None):
         super().__init__({"prompt": prompt}, 1, deadline, trace=trace)
         self.prompt = prompt
+        # ``rid=`` of this request's spans in a profiler trace (its prefill's
+        # two halves, what the admission does before and after them, its
+        # retiring): they tie a ``prefill_p<bucket>`` program run of the
+        # device plane to the request that caused it
+        self.rid = next(_REQUEST_IDS)
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
         self.seed = seed
@@ -622,7 +697,8 @@ class DecodeEngine:
             kernels=_pallas.kernels_fingerprint())
         hists = telemetry.snapshot()["hists"]
         for key in ("decode.step_ms", "decode.prefill_ms",
-                    "decode.request_ms"):
+                    "decode.prefill_feed_ms", "decode.prefill_wait_ms",
+                    "decode.seat_ms", "decode.request_ms"):
             h = hists.get(key)
             if h:
                 out[key.split(".", 1)[1]] = {
@@ -631,6 +707,10 @@ class DecodeEngine:
         occ = hists.get("decode.batch_occupancy")
         if occ:
             out["batch_occupancy"] = {"avg": occ["avg"], "p50": occ["p50"]}
+        # every token after a request's first is chosen by the step program
+        if "tokens" in out:
+            out["tokens_device_sampled"] = out["tokens"]
+        out.update(admission_cost(c, hists))
         win = telemetry.windowed()
         wout = {"seconds": win["window_s"]}
         for name, key in (("decode.tokens", "tokens_per_s"),
@@ -846,6 +926,7 @@ class DecodeEngine:
                         return
                     continue
             with telemetry.timer("decode.loop_ms", into=it):
+                cpu0 = time.thread_time()
                 try:
                     with telemetry.timer("decode.admit_ms", into=it):
                         self._admit(it)
@@ -864,6 +945,16 @@ class DecodeEngine:
                 # the step's one hook: whoever subscribed (the SLO watchdog's
                 # queue-saturation and step-time rules) runs on this cadence
                 telemetry.tick()
+                # this thread's own CPU time beside the loop's wall time:
+                # what is left of the wall once the waits for the device
+                # (decode.fetch_ms, decode.prefill_wait_ms) are taken off
+                # too, the thread spent off the CPU elsewhere: waiting for
+                # the interpreter lock behind the callers' threads, for a
+                # core, in a transfer or a launch. Where the thread's clock
+                # ticks coarsely (10 ms on the chip's machine) one sample
+                # says little and the sum is an estimate
+                it["decode.cpu_ms"] = it.get("decode.cpu_ms", 0.0) \
+                    + (time.thread_time() - cpu0) * 1e3
             if "decode.fetch_ms" in it:
                 self._observe_phases(it)
                 it = {}
@@ -876,7 +967,9 @@ class DecodeEngine:
     def _observe_phases(it: Dict[str, float]):
         """One accepted step's record: decode.loop_ms and the phases that
         tile it, decode.other_ms for what lies between them (the deadline
-        scan, the journal tick, the gauge, the watchdog, the timers)."""
+        scan, the journal tick, the gauge, the watchdog, the timers), and
+        beside them decode.cpu_ms, the thread's CPU time over the same
+        iterations (no phase: it overlaps them all)."""
         for name in _LOOP_PHASES:     # a step that only drained fed nothing
             it.setdefault(name, 0.0)
         it["decode.sample_ms"] -= it["decode.retire_ms"]
@@ -940,59 +1033,84 @@ class DecodeEngine:
                 self._drain(it)
                 if self._admit_shipped(req):
                     continue
-            # prefix sharing: acquire the longest cached prefix chain;
-            # a lookup fault is a per-request error, nothing acquired
-            hashes: List[str] = []
-            shared: List[int] = []
-            if self.prefix_store is not None:
-                try:
-                    hashes, shared = self.prefix_store.lookup(req.seq)
-                except Exception as e:
-                    telemetry.counter_add("decode.errors", 1,
-                                          exc=type(e).__name__)
-                    req.fail(e if isinstance(e, ServingError)
-                             else ServingError(
-                                 f"prefix lookup failed: {e!r}"))
-                    continue
-            need, ring_need = self.kv.pages_for_tokens(
-                int(req.seq.size) + req.max_new_tokens)
-            need -= len(hashes)
-            try:
-                # seated only if BOTH classes of pages can seat it
-                got = self.kv.try_alloc(need, ring_need)
-                if got is None and self.prefix_store is not None:
-                    # ledger pressure: reclaim idle refcount-zero
-                    # chains LRU-first, then retry once
-                    short = need - self.pool.free_pages()
-                    if short > 0 and self.prefix_store.reclaim(short):
-                        got = self.kv.try_alloc(need, ring_need)
-            except Exception as e:   # injected decode.kv_alloc fault
-                if hashes:
-                    self.prefix_store.release(hashes)
-                telemetry.counter_add("decode.errors", 1,
-                                      exc=type(e).__name__)
-                req.fail(e if isinstance(e, ServingError) else ServingError(
-                    f"KV page allocation failed: {e!r}"))
+            # what precedes the prefill's dispatch, from the seat (or the
+            # drained step) of the request before: with decode.prefill_ms
+            # and decode.seat_ms it tiles this request's part of the
+            # admission, the host's gap between two prefill programs
+            launch = None
+            with _admission_part("prefill_feed", req) as feed_ms:
+                got = self._reserve(req, unseated)
+                if got is not None:
+                    pages = got[0]
+                    try:
+                        launch = self._prefill(req, *got)
+                    except BaseException as e:
+                        self._prefill_failed(req, pages, e)
+            if launch is None:
                 continue
-            if got is None:
-                if hashes:
-                    self.prefix_store.release(hashes)
-                unseated.append(req)   # no headroom NOW — wait for frees
-                continue
-            pages, req.ring_pages = got
-            telemetry.observe("decode.queue_wait_ms",
-                              (time.monotonic() - req.t_submit) * 1e3)
-            # the slot is taken before the prefill, which writes a stateful
-            # model's state and tail there over what the slot's last owner
-            # left (a row dispatched on speculation for a request that
-            # ended ran before this prefill on the device)
-            self._take_slot(req)
+            telemetry.observe_quiet("decode.prefill_feed_ms",
+                                    feed_ms["decode.admit_ms"])
             try:
-                self._prefilled = _Prefilled(
-                    req, pages, self._prefill(req, pages, hashes, shared))
+                self._prefilled = _Prefilled(req, pages, launch())
             except BaseException as e:
                 self._prefill_failed(req, pages, e)
         self.queue.requeue(unseated)
+
+    def _reserve(self, req: GenerationRequest,
+                 unseated: List[GenerationRequest]):
+        """What an admission takes before its prefill: the longest cached
+        prefix, pages of both classes, a slot. -> (private pages, the shared
+        prefix's hashes, its pages), or None: the request failed (a
+        per-request error), or the pools cannot seat it now and it joins
+        ``unseated`` to wait for frees."""
+        # prefix sharing: acquire the longest cached prefix chain;
+        # a lookup fault is a per-request error, nothing acquired
+        hashes: List[str] = []
+        shared: List[int] = []
+        if self.prefix_store is not None:
+            try:
+                hashes, shared = self.prefix_store.lookup(req.seq)
+            except Exception as e:
+                telemetry.counter_add("decode.errors", 1,
+                                      exc=type(e).__name__)
+                req.fail(e if isinstance(e, ServingError)
+                         else ServingError(
+                             f"prefix lookup failed: {e!r}"))
+                return None
+        need, ring_need = self.kv.pages_for_tokens(
+            int(req.seq.size) + req.max_new_tokens)
+        need -= len(hashes)
+        try:
+            # seated only if BOTH classes of pages can seat it
+            got = self.kv.try_alloc(need, ring_need)
+            if got is None and self.prefix_store is not None:
+                # ledger pressure: reclaim idle refcount-zero
+                # chains LRU-first, then retry once
+                short = need - self.pool.free_pages()
+                if short > 0 and self.prefix_store.reclaim(short):
+                    got = self.kv.try_alloc(need, ring_need)
+        except Exception as e:   # injected decode.kv_alloc fault
+            if hashes:
+                self.prefix_store.release(hashes)
+            telemetry.counter_add("decode.errors", 1,
+                                  exc=type(e).__name__)
+            req.fail(e if isinstance(e, ServingError) else ServingError(
+                f"KV page allocation failed: {e!r}"))
+            return None
+        if got is None:
+            if hashes:
+                self.prefix_store.release(hashes)
+            unseated.append(req)   # no headroom NOW — wait for frees
+            return None
+        pages, req.ring_pages = got
+        telemetry.observe("decode.queue_wait_ms",
+                          (time.monotonic() - req.t_submit) * 1e3)
+        # the slot is taken before the prefill, which writes a stateful
+        # model's state and tail there over what the slot's last owner
+        # left (a row dispatched on speculation for a request that
+        # ended ran before this prefill on the device)
+        self._take_slot(req)
+        return pages, hashes, shared
 
     def _seat_prefilled(self):
         """Wait for the dispatched prefill's logits row, if there is one,
@@ -1022,9 +1140,12 @@ class DecodeEngine:
     def _prefill(self, req: GenerationRequest, pages: List[int],
                  hashes: Optional[List[str]] = None,
                  shared: Optional[List[int]] = None):
-        """PREFILL phase: dispatch the request's prefill and return the
-        call that waits for its logits row and seats the request
-        (``_Prefilled.seat``). With the prefix store on, EVERY prefill runs
+        """PREFILL phase: build the request's prefill and return the call
+        that dispatches it, which returns the call that waits for its logits
+        row and seats the request (``_Prefilled.seat``): what is built here
+        is the admission's host work before the device has the program
+        (decode.prefill_feed_ms), what the first call times is the dispatch
+        (decode.prefill_ms). With the prefix store on, EVERY prefill runs
         page-aligned chunks through the one chunked entry (a cache hit
         just skips the cached leading chunks — bitwise identity with
         the cold run holds by construction: same program, same fixed
@@ -1052,22 +1173,42 @@ class DecodeEngine:
             "positions": np.arange(bucket, dtype=np.int32)[None, :],
             "state_slots": np.asarray([req.slot], np.int32),
             "page_table": row[None, :], "ring_table": req.ring_row[None, :]})
-        ms: Dict[str, float] = {}
-        with telemetry.timer("decode.prefill_ms", into=ms):
-            logits, self._pools = entry(self._params, self._pools, feed)
-        return lambda: self._seat(req, self._logits_row(logits, ms, L))
+        # the program computes the bucket, padding and all
+        span = dict(rid=req.rid, bucket=bucket, tokens=L)
+
+        def launch():
+            ms: Dict[str, float] = {}
+            with telemetry.timer("decode.prefill_ms", into=ms, **span):
+                logits, self._pools = entry(self._params, self._pools, feed)
+            return lambda: self._seat(req, self._logits_row(logits, ms, span))
+
+        return launch
 
     def _logits_row(self, logits, ms: Dict[str, float],
-                    tokens: int) -> np.ndarray:
+                    span: Dict[str, int]) -> np.ndarray:
         """Wait for a dispatched prefill's logits row. decode.prefill_ms is
         the dispatch (``ms`` so far) and this wait, not what the loop did
-        between them."""
-        with telemetry.timer("decode.prefill_ms", into=ms):
+        between them. The wait alone is decode.prefill_wait_ms: the loop has
+        accepted the step that was in flight before the prefill (or drained
+        it), so the host has nothing left to do but wait for the prefill
+        program, and every live slot stands still meanwhile. It reads UNDER
+        the program's device time by the host's accept of that step
+        (decode.sample_ms: tenths of a ms against prefills of 15-175 ms),
+        during which the program already ran. ``span`` is the prefill's
+        ``rid``, the ``tokens`` it was asked for and the ``bucket`` of
+        tokens its programs computed: decode.prefill_tokens over
+        decode.prefill_bucket_tokens is the share that was no padding,
+        counted here, both for the prefills that were seated."""
+        dispatch_ms = ms["decode.prefill_ms"]
+        with telemetry.timer("decode.prefill_ms", into=ms, **span):
             row = np.asarray(logits)[0]
         telemetry.observe("decode.prefill_ms", ms["decode.prefill_ms"],
                           kind="timer")
+        telemetry.observe_quiet("decode.prefill_wait_ms",
+                                ms["decode.prefill_ms"] - dispatch_ms)
         telemetry.counter_add("decode.prefills", 1)
-        telemetry.counter_add("decode.prefill_tokens", tokens)
+        telemetry.counter_add("decode.prefill_tokens", span["tokens"])
+        telemetry.counter_add("decode.prefill_bucket_tokens", span["bucket"])
         return row
 
     def _prefill_chunked(self, req: GenerationRequest, pages: List[int],
@@ -1092,43 +1233,50 @@ class DecodeEngine:
         req.ring_row = np.zeros(self.kv.ring_slot_pages, np.int32)
         n_chunks = -(-L // P)
         entry = self._entry("chunk", P)
-        logits = None
-        ms: Dict[str, float] = {}
-        with telemetry.timer("decode.prefill_ms", into=ms):
-            for ci in range(k, n_chunks):
-                lo = ci * P
-                n = min(L, lo + P) - lo
-                tokens = np.zeros((1, P), np.int32)
-                tokens[0, :n] = req.seq[lo:lo + n]
-                positions = np.clip(lo + np.arange(P, dtype=np.int32), 0,
-                                    self.model_cfg.max_seq_len - 1)
-                oh = np.zeros((1, P), np.float32)
-                if ci == n_chunks - 1:
-                    oh[0, L - 1 - lo] = 1.0
-                feed = self._feed("chunk", P, {
-                    "tokens": tokens, "positions": positions[None, :],
-                    "chunk_start": np.asarray([lo], np.int32),
-                    "lengths": np.asarray([n], np.int32),
-                    "last_onehot": oh, "page_table": row[None, :],
-                    "ring_table": req.ring_row[None, :]})
-                logits, self._pools = entry(self._params, self._pools,
-                                            feed)
+        # the chunks that run, whole, are what the programs compute
+        span = dict(rid=req.rid, bucket=(n_chunks - k) * P, tokens=L - k * P)
 
-        def seat():
-            logits_row = self._logits_row(logits, ms, L - k * P)
-            # the store adopts every FULL prompt page (strictly before the
-            # page receiving decode writes); repoint the table at the
-            # canonical pages and keep only the tail pages private
-            n_full = L // P
-            if n_full > k:
-                held, canon = self.prefix_store.insert(
-                    req.seq, [int(p) for p in row[:n_full]], start_block=k)
-                row[k:n_full] = canon
-                req.shared_blocks.extend(held)
-                req.pages = pages[n_full - k:]
-            self._seat(req, logits_row)
+        def launch():
+            logits = None
+            ms: Dict[str, float] = {}
+            with telemetry.timer("decode.prefill_ms", into=ms, **span):
+                for ci in range(k, n_chunks):
+                    lo = ci * P
+                    n = min(L, lo + P) - lo
+                    tokens = np.zeros((1, P), np.int32)
+                    tokens[0, :n] = req.seq[lo:lo + n]
+                    positions = np.clip(lo + np.arange(P, dtype=np.int32), 0,
+                                        self.model_cfg.max_seq_len - 1)
+                    oh = np.zeros((1, P), np.float32)
+                    if ci == n_chunks - 1:
+                        oh[0, L - 1 - lo] = 1.0
+                    feed = self._feed("chunk", P, {
+                        "tokens": tokens, "positions": positions[None, :],
+                        "chunk_start": np.asarray([lo], np.int32),
+                        "lengths": np.asarray([n], np.int32),
+                        "last_onehot": oh, "page_table": row[None, :],
+                        "ring_table": req.ring_row[None, :]})
+                    logits, self._pools = entry(self._params, self._pools,
+                                                feed)
 
-        return seat
+            def seat():
+                logits_row = self._logits_row(logits, ms, span)
+                # the store adopts every FULL prompt page (strictly before
+                # the page receiving decode writes); repoint the table at
+                # the canonical pages and keep only the tail pages private
+                n_full = L // P
+                if n_full > k:
+                    held, canon = self.prefix_store.insert(
+                        req.seq, [int(p) for p in row[:n_full]],
+                        start_block=k)
+                    row[k:n_full] = canon
+                    req.shared_blocks.extend(held)
+                    req.pages = pages[n_full - k:]
+                self._seat(req, logits_row)
+
+            return seat
+
+        return launch
 
     def _ship_prefill(self, req: ShipPrefillRequest):
         """Prefill-tier work (serving/disagg.py): run the prompt's
@@ -1353,13 +1501,13 @@ class DecodeEngine:
                 self._accept_token(req, int(chosen[i]))
                 if req.finished():
                     retired = True
-                    with telemetry.timer("decode.retire_ms", into=it):
+                    with telemetry.timer("decode.retire_ms", into=it,
+                                         rid=req.rid):
                         self._retire(req)
             if retired:
                 self._active = [r for r in self._active if not r.done()]
         telemetry.counter_add("decode.steps", 1)
         telemetry.counter_add("decode.tokens", delivered)
-        telemetry.counter_add("decode.tokens_device_sampled", delivered)
         if delivered < len(rows):
             telemetry.counter_add("decode.rows_discarded",
                                   len(rows) - delivered)
@@ -1414,15 +1562,19 @@ class DecodeEngine:
 
     def _seat(self, req: GenerationRequest, logits_row: np.ndarray):
         """A prefilled request gets its first token and, unless that ends
-        it, keeps the slot its admission took until it retires."""
-        self._first_token(req, logits_row)
-        req.pos_next = int(req.seq.size)
-        if req.finished():
-            self._retire(req)
-        else:
-            if self.kv.has_state:   # its prefill wrote the slot's state
-                telemetry.counter_add("decode.state_slots_seated", 1)
-            self._active.append(req)
+        it, keeps the slot its admission took until it retires: the host's
+        work between the prefill's logits and the next dispatch
+        (decode.seat_ms, one observation a seated request)."""
+        with _admission_part("seat", req) as ms:
+            self._first_token(req, logits_row)
+            req.pos_next = int(req.seq.size)
+            if req.finished():
+                self._retire(req)
+            else:
+                if self.kv.has_state:   # its prefill wrote the slot's state
+                    telemetry.counter_add("decode.state_slots_seated", 1)
+                self._active.append(req)
+        telemetry.observe_quiet("decode.seat_ms", ms["decode.admit_ms"])
 
     def _take_slot(self, req: GenerationRequest):
         req.slot = self._free_slots.pop()

@@ -22,9 +22,11 @@ Renders the structured run log written by ``paddle_tpu.core.telemetry``
   percentiles;
 * a "Decode" section when the run used the continuous-batching
   generative engine (paddle_tpu/serving/decode.py): tokens/s, slot
-  occupancy, prefill-vs-decode-step latency percentiles, KV page-pool
-  bytes + high-water mark and the alloc/free page balance (a nonzero
-  difference prints as LEAKED);
+  occupancy, prefill-vs-decode-step latency percentiles, what admissions
+  cost the loop (the share of its time every slot stood still for a
+  prefill, the prefill tokens that were padding, the engine thread's CPU
+  share), KV page-pool bytes + high-water mark and the alloc/free page
+  balance (a nonzero difference prints as LEAKED);
 * a "Checkpointing" section when the run saved/restored through the
   crash-consistent protocol (paddle_tpu/checkpoint.py): commits, bytes,
   verification rejections + fallbacks to older checkpoints, quarantined
@@ -230,7 +232,8 @@ def summarize_log(recs, malformed=0):
     serving = _serving_summary(counter_delta, counter_last, timer_summary,
                                gauges)
     decode = _decode_summary(counter_delta, counter_last, timer_summary,
-                             gauges, hist_summary, span_s)
+                             gauges, hist_summary, span_s,
+                             (snapshot or {}).get("hists") or {})
     router = _router_summary(counter_delta, counter_last, timer_summary)
     ckpt = _ckpt_summary(counter_delta, counter_last, timer_summary)
     sharding = _sharding_summary(counter_delta, counter_last, gauges)
@@ -367,10 +370,13 @@ def _serving_summary(counter_delta, counter_last, timer_summary, gauges):
 
 
 def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
-                    hists, span_s):
+                    hists, span_s, final_hists):
     """Generative decode engine accounting (paddle_tpu/serving/decode.py
     + kv_cache.py): tokens/s, prefill-vs-decode step latency, slot-array
-    occupancy, and the KV page pool's high-water mark."""
+    occupancy, what admissions cost the loop, and the KV page pool's
+    high-water mark. ``final_hists`` are the closing snapshot's histogram
+    summaries: the loop's phases are observed quietly and leave no record
+    of their own in the log."""
 
     def cval(name):
         v = counter_delta.get(name) or counter_last.get(name) or 0
@@ -407,6 +413,18 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
         t = timer_summary.get(timer)
         if t:
             out[key] = {"p50": t["p50"], "p99": t["p99"], "max": t["max"]}
+    # what admissions cost, as DecodeEngine.stats() gives it
+    # (serving/decode.py admission_cost): shares of the loop's wall time
+    loop_ms = (final_hists.get("decode.loop_ms") or {}).get("total")
+    for key, name in (("prefill_wait_share", "decode.prefill_wait_ms"),
+                      ("engine_cpu_share", "decode.cpu_ms")):
+        h = final_hists.get(name)
+        if loop_ms and h and h.get("count"):
+            out[key] = round(100.0 * h["total"] / loop_ms, 2)
+    computed = cval("decode.prefill_bucket_tokens")
+    if computed:
+        out["prefill_padded_token_share"] = round(
+            100.0 * (1.0 - cval("decode.prefill_tokens") / computed), 2)
     kv_pool = gauges.get("mem.serving.kv_pool_bytes")
     if kv_pool is not None:
         out["kv_pool_bytes"] = int(kv_pool)
@@ -1050,6 +1068,14 @@ def render(s, out=sys.stdout):
                 t = dc[key]
                 w(f"{label} ms: p50 {t['p50']}  p99 {t['p99']}"
                   f"  max {t['max']}\n")
+        cost = [text.format(dc[key]) for key, text in (
+            ("prefill_wait_share",
+             "every slot waited for a prefill {}% of the loop's time"),
+            ("engine_cpu_share", "the engine thread ran {}% of it"),
+            ("prefill_padded_token_share",
+             "{}% of the prefilled tokens were padding")) if key in dc]
+        if cost:
+            w("admissions: " + "; ".join(cost) + "\n")
         if "kv_pool_bytes" in dc:
             w(f"kv page pool: {_fmt_num(dc['kv_pool_bytes'])} B "
               f"(high water {_fmt_num(dc['kv_high_water_bytes'])} B, "
