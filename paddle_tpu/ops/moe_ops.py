@@ -39,8 +39,10 @@ def routed_experts_op(ins, attrs):
     [H, E] over ALL experts, SelectBias [E], W1/W3 [E_held, H, F] and W2
     [E_held, F, H] of the experts `held_lo` .. held here, optional Live
     bool, one a row of X (the rows that carry a token; the rest join no
-    group). Out like X, float32; Counts int32 [3] (live pairs, those on
-    held experts, held experts hit)."""
+    group). The held experts' SwiGLUs run as one grouped kernel over the
+    pairs sorted by expert (ops/pallas/grouped_swiglu.py; three
+    ragged_dots where kernel_mode() is off). Out like X, float32; Counts
+    int32 [3] (live pairs, those on held experts, held experts hit)."""
     from ..parallel.moe import routed_experts_share
 
     live = ins["Live"][0] if ins.get("Live") else None

@@ -17,7 +17,10 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     expanded form on the layer's own [S, heads x width]
                     arrays: a step is one block pair of the causal
                     triangle for several heads, scores kept in VMEM, only
-                    the diagonal masked (and attended in sub-blocks).
+                    the diagonal masked (and attended in sub-blocks),
+* grouped_swiglu  — the routed layer's held experts over rows sorted by
+                    expert: the hit experts' weight blocks streamed once,
+                    gate, up, silu(g) * u and down under each block.
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

@@ -145,12 +145,14 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     renormalisation when ``route_norm`` is false). This chip then computes,
     without dropping a pair, ``sum_i w_i * Expert_i(x)`` over the kept
     pairs whose expert it holds: the pairs are sorted by expert and the
-    held experts' SwiGLUs run as three grouped products
-    (``jax.lax.ragged_dot``) over the sorted rows, so an expert no token
-    chose costs no weight read. The products run over the leading rows
-    that hold the held pairs when those are few, as they nearly always
-    are, and otherwise over that many sorted rows at a time, as far as the
-    held pairs reach (one ``lax.cond``). What the absent experts would
+    held experts' SwiGLUs run over the sorted rows as one grouped kernel
+    (``ops/pallas/grouped_swiglu.py``: a stream of the hit experts' weight
+    blocks with the three products under each; where ``kernel_mode()`` is
+    off or the shape cannot be tiled, three ``jax.lax.ragged_dot``,
+    counted), so an expert no token chose costs no weight read. It runs
+    over the leading rows that hold the held pairs when those are few, as
+    they nearly always are, and otherwise over that many sorted rows at a
+    time, as far as the held pairs reach (one ``lax.cond``). What the absent experts would
     have added is left out; nothing stands in for their chips or the exchange.
 
     Returns (out [T, H] float32, counts int32 [3]): the kept pairs of live
@@ -158,6 +160,8 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     least one live pair."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops.pallas.grouped_swiglu import grouped_swiglu
 
     t, h = x.shape
     e_held = w1.shape[0]
@@ -188,13 +192,7 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
         """The held experts over sorted pairs: their tokens `r`, their
         weights `w`, `sizes` of them in each expert's group."""
         xs = x[r].astype(w1.dtype)                               # [n, H]
-
-        def grouped(a, wts):
-            return jax.lax.ragged_dot(a, wts, sizes,
-                                      preferred_element_type=jnp.float32)
-
-        mid = jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)
-        ys = grouped(mid.astype(w2.dtype), w2)                   # [n, H]
+        ys = grouped_swiglu(xs, w1, w3, w2, sizes)               # [n, H]
         # rows past the groups hold nothing of a held expert: weight 0,
         # and a `where` so that whatever the grouped product left there
         # stays out
@@ -204,9 +202,10 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     # the pairs on held experts sort first, and with evenly spread routing
     # they are e_held / E of all pairs. Twice that share (and a margin)
     # of the sorted rows holds them nearly always, and the grouped
-    # products, the gather and the scatter then run over that many rows
-    # (on the chip a grouped product over 64 rows took 1.2 ms where 256
-    # took 1.5, PR 28); when more pairs land here than that, the sorted
+    # kernel, the gather and the scatter then run over that many rows
+    # (the kernel's time is its hit experts' weights whatever the rows,
+    # PR 41; the gather and the scatter-add grow with them); when more
+    # pairs land here than that, the sorted
     # rows are processed that many at a time, as far as the held pairs
     # reach: no pair is ever dropped, and a prompt's every pair is never
     # held at once (pairs x H floats three times over: 3 GB at 6144

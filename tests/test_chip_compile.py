@@ -179,6 +179,23 @@ def test_routed_experts_grouped_product_at_the_trinity_share(one_chip):
     assert "ragged-dot" in text
 
 
+@pytest.mark.parametrize("n,h,f,e", [(128, 3072, 3072, 32),
+                                     (576, 3072, 3072, 32),
+                                     (8256, 3072, 3072, 32),
+                                     (64, 7168, 2048, 12),
+                                     (3136, 7168, 2048, 12)])
+def test_grouped_swiglu_at_the_trinity_and_kimi_shares(one_chip, tpu_mode,
+                                                       n, h, f, e):
+    """A step's sorted rows and the largest prefill bucket's, at both
+    callers' widths: the weight blocks, the row and output tiles and the
+    products' temporaries fit the kernel's VMEM limit, the dynamic row
+    windows and the loop over them lower."""
+    from paddle_tpu.ops.pallas.grouped_swiglu import grouped_swiglu
+
+    _compile(grouped_swiglu, one_chip, ((n, h), BF16), ((e, h, f), BF16),
+             ((e, h, f), BF16), ((e, f, h), BF16), ((e,), I32))
+
+
 def test_ssm_state_update_at_the_falcon_h1_widths(one_chip, tpu_mode):
     """64 rows over 65 slots of 32 heads x [256, 128] float32, the state
     donated: the kernel, and the state aliased to its output (no copy of
