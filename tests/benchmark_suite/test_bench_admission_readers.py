@@ -4,15 +4,11 @@ the device's idle time between two prefills, the engine thread's CPU time
 and the wall time of its loop that neither it nor a wait for the device
 explains.
 
-BENCHMARK.json does not list them yet. A PR that changes the program may add
-entries at the END of `per_layer` alone, and test_bench_falcon_h1.py holds
-the end of that list to Falcon's four: a `benchmark` PR frees that pin and
-appends ENTRIES (PERF.md section 7). Until then the readers are held here
-against hand-made results and through the harness on the toy root, with
-ENTRIES appended as that PR would append them."""
+BENCHMARK.json lists them since PR 42, at the end of `per_layer` as it then
+stood, over the four served cells: every engine observes what they read.
+The readers are held here against hand-made results and through the harness
+on the toy root, whose metric lists are the repo's own."""
 
-import json
-import os
 import math
 import time
 
@@ -28,7 +24,8 @@ from . import toy
 REAL = Manifest(toy.REPO)
 CELLS = ["xglm_1p7b_serve_closed_c16",
          "trinity_large_tp8ep8_serve_closed_c96",
-         "kimi_k2_dp_ep32_serve_closed_c96"]
+         "kimi_k2_dp_ep32_serve_closed_c96",
+         "falcon_h1_34b_pp12_serve_closed_c96"]
 SOURCES = {                                     # metric -> source
     "prefill_wait_share.serve": "program_span",
     "prefill_padded_token_share.serve": "program_counter",
@@ -41,8 +38,8 @@ WINDOW_SHARES = {                               # metric -> histogram
     "engine_cpu_share.serve": "decode.cpu_ms",
 }
 ENTRIES = [{"name": name, "unit": "%", "better": "lower", "source": source,
-            "layer": "decode engine", "moves": "serve_tokens_per_s",
-            "workloads": CELLS} for name, source in SOURCES.items()]
+            "layer": "decode engine", "moves": "serve_tokens_per_s"}
+           for name, source in SOURCES.items()]        # less `workloads`
 
 
 def _hist(total, count=4):
@@ -54,25 +51,20 @@ def _serve(hists=None, counters=None, window_s=50.0, **fields):
         "hists": hists or {}, "counters": counters or {}}, **fields)
 
 
-def test_the_real_manifest_is_sound_with_the_five_entries_appended():
-    assert REAL.problems() == []
-    listed = [m for m in REAL.doc["per_layer"] if m["name"] in SOURCES]
-    assert listed in ([], ENTRIES)              # not yet, or as designed
-    man = Manifest(toy.REPO)
-    if not listed:
-        man.doc = dict(man.doc, per_layer=man.doc["per_layer"] + ENTRIES)
-    assert man.problems() == []
-    for entry in ENTRIES:
+def holds(man):
+    for wanted in ENTRIES:
+        entry = toy.entry(man, "per_layer", wanted["name"])
+        assert {k: v for k, v in entry.items() if k != "workloads"} == wanted
+        assert set(CELLS) <= set(entry["workloads"])
         assert callable(man.reader(entry["name"]))
-    for cell in CELLS:
-        assert man.config_doc(man.cell(cell)["config"])["kind"] == "serve"
-        reported = {m["name"] for g in ("end_to_end", "per_layer")
-                    for m in man.metrics_of(cell, g)}
-        assert set(SOURCES) | {"serve_tokens_per_s"} <= reported
-    # the fifth served cell's reported set is pinned (section 7 of PERF.md)
-    assert not set(SOURCES) & {
-        m["name"] for m in man.metrics_of(
-            "falcon_h1_34b_pp12_serve_closed_c96", "per_layer")}
+        for cell in entry["workloads"]:
+            assert man.config_doc(man.cell(cell)["config"])["kind"] == "serve"
+            assert "serve_tokens_per_s" in toy.reported(man, cell)
+
+
+def test_the_real_manifest_lists_the_five_over_the_served_cells():
+    assert REAL.problems() == []
+    holds(REAL)
 
 
 @pytest.mark.parametrize("metric", sorted(WINDOW_SHARES))
@@ -186,16 +178,9 @@ def test_the_idle_between_prefills_is_the_idle_under_the_admissions_spans(
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    root = toy.make_root(str(tmp_path_factory.mktemp("admission_root")))
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        doc = json.load(f)
-    if not any(m["name"] in SOURCES for m in doc["per_layer"]):
-        doc["per_layer"] += [dict(e, workloads=["toy_closed", "toy_open"])
-                             for e in ENTRIES]
-        with open(path, "w") as f:
-            json.dump(doc, f)
-    root = toy.keep_cells(root, {"toy_closed": "admission_closed"})
+    root = toy.keep_cells(
+        toy.make_root(str(tmp_path_factory.mktemp("admission_root"))),
+        {"toy_closed": "admission_closed"})
     assert Manifest(root).problems() == []
     return root
 

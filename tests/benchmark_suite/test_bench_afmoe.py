@@ -14,6 +14,7 @@ from benchmark.manifest import Manifest
 from . import toy
 
 PUBLISHED_ROW = "Trinity-Large-Preview"
+REAL_CELL = "trinity_large_tp8ep8_serve_closed_c96"
 CELL = "afmoe_closed"
 # the published pattern's first period and a half, at toy widths
 TOY_AFMOE = {
@@ -70,13 +71,9 @@ def afmoe_root(tmp_path_factory):
     return root
 
 
-def test_the_real_manifest_is_sound_with_the_afmoe_cell():
-    man = Manifest(toy.REPO)
-    assert man.problems() == []
-    cell = man.cell("trinity_large_tp8ep8_serve_closed_c96")
-    assert cell["chips"] == 1
-    reported = {m["name"] for g in ("end_to_end", "per_layer")
-                for m in man.metrics_of(cell["name"], g)}
+def holds(man):
+    assert man.cell(REAL_CELL)["chips"] == 1
+    reported = toy.reported(man, REAL_CELL)
     assert set(NEW_METRICS) | {"paged_gqa_attention_roofline",
                                "routed_decode_step_roofline", "setup_s",
                                "serve_tokens_per_s"} <= reported
@@ -87,7 +84,13 @@ def test_the_real_manifest_is_sound_with_the_afmoe_cell():
     # PERF.md PR 28) and is not reported, nor is any metric that moves it
     assert "tpot_p90_ms" not in reported
     assert all(m["moves"] in ("serve_tokens_per_s", "setup_s")
-               for m in man.metrics_of(cell["name"], "per_layer"))
+               for m in man.metrics_of(REAL_CELL, "per_layer"))
+
+
+def test_the_real_manifest_is_sound_with_the_afmoe_cell():
+    man = Manifest(toy.REPO)
+    assert man.problems() == []
+    holds(man)
 
 
 def test_the_configuration_carries_every_published_number():

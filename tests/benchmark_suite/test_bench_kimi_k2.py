@@ -82,13 +82,10 @@ def kimi_root(tmp_path_factory):
     return root
 
 
-def test_the_real_manifest_is_sound_with_the_kimi_cell():
-    man = Manifest(toy.REPO)
-    assert man.problems() == []
+def holds(man):
     cell = man.cell(REAL_CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "closed_c96_code")
-    reported = {m["name"] for g in ("end_to_end", "per_layer")
-                for m in man.metrics_of(cell["name"], g)}
+    reported = toy.reported(man, REAL_CELL)
     assert set(JOINED) | set(NEW_METRICS) | {
         "setup_s", "serve_tokens_per_s",
         "batch_occupancy_avg", "completed_requests_per_s",
@@ -100,32 +97,31 @@ def test_the_real_manifest_is_sound_with_the_kimi_cell():
                 "rows_past_window_share.serve"} & reported
     assert "tpot_p90_ms" not in reported        # a loop at saturation
     assert all(m["moves"] in ("serve_tokens_per_s", "setup_s")
-               for m in man.metrics_of(cell["name"], "per_layer"))
-    # the new metrics are this cell's alone
+               for m in man.metrics_of(REAL_CELL, "per_layer"))
+    # the new metrics came with this cell
     for name in NEW_METRICS:
-        entry = next(m for m in man.doc["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [REAL_CELL]
+        assert REAL_CELL in toy.entry(man, "per_layer", name)["workloads"]
+    step_ahead_lists_the_serving_cells(man)
+
+
+def step_ahead_lists_the_serving_cells(man):
+    """`step_ahead_share.serve` (PR 31) over the loops at saturation that
+    PR 33 knew, and whichever came since; the entry's fields and its
+    cells' kind are test_bench_step_ahead's to hold."""
+    entry = toy.entry(man, "per_layer", "step_ahead_share.serve")
+    assert {"xglm_1p7b_serve_closed_c16",
+            "trinity_large_tp8ep8_serve_closed_c96",
+            REAL_CELL} <= set(entry["workloads"])
+
+
+def test_the_real_manifest_is_sound_with_the_kimi_cell():
+    man = Manifest(toy.REPO)
+    assert man.problems() == []
+    holds(man)
 
 
 def test_step_ahead_share_lists_every_serving_cell_at_saturation():
-    """What test_bench_step_ahead's manifest test meant, without pinning
-    the entry to the end of the list or its cells to two: the entry as PR
-    31 wrote it, its cells the served ones, each reporting the end-to-end
-    metric it moves."""
-    real = Manifest(toy.REPO)
-    entry = next(m for m in real.doc["per_layer"]
-                 if m["name"] == "step_ahead_share.serve")
-    assert {k: v for k, v in entry.items() if k != "workloads"} == {
-        "name": "step_ahead_share.serve", "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "decode engine",
-        "moves": "serve_tokens_per_s"}
-    assert entry["workloads"] == ["xglm_1p7b_serve_closed_c16",
-                                  "trinity_large_tp8ep8_serve_closed_c96",
-                                  REAL_CELL]
-    for cell in entry["workloads"]:
-        assert real.config_doc(real.cell(cell)["config"])["kind"] == "serve"
-        assert "serve_tokens_per_s" in {
-            m["name"] for m in real.metrics_of(cell, "end_to_end")}
+    step_ahead_lists_the_serving_cells(Manifest(toy.REPO))
 
 
 def test_the_family_file_keeps_the_contract():

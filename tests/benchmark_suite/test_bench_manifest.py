@@ -1,7 +1,11 @@
 """BENCHMARK.json and the files it names: the rules the harness depends on,
-and that a cell, a configuration and a metric are added by files alone
-(a served model of another family: test_bench_families.py)."""
+that a cell, a configuration and a metric are added by files alone (a
+served model of another family: test_bench_families.py), and that no
+family's test stops the next cell: each holds beside a further one."""
 
+import copy
+import glob
+import importlib
 import json
 import os
 import re
@@ -11,6 +15,18 @@ import pytest
 from benchmark.manifest import FAMILY_FUNCTIONS, RUNNERS_DIR, Manifest
 
 from . import toy
+
+# A family's test file says what is true of ITS cell and ITS metrics in a
+# `holds(man)` (benchmark/README.md). Every file of this directory that
+# has one is a case below: a new family's file joins by being there.
+FAMILY_TESTS = {}
+for _path in sorted(glob.glob(os.path.join(os.path.dirname(__file__),
+                                           "test_bench_*.py"))):
+    _stem = os.path.splitext(os.path.basename(_path))[0]
+    if _stem != __name__.rpartition(".")[2]:
+        _module = importlib.import_module("." + _stem, __package__)
+        if hasattr(_module, "holds"):
+            FAMILY_TESTS[_stem[len("test_bench_"):]] = _module
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +84,74 @@ def test_every_named_file_exists(real):
 def test_at_most_one_cell_in_four_asks_for_four_chips(real):
     four = [w for w in real.doc["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(real.doc["workloads"]) // 4)
+
+
+class Grown(Manifest):
+    """The repo's manifest as the next served cell's PR leaves it, in
+    memory: a further configuration, a further cell at the end of
+    `workloads`, three per-layer entries of its own at the end of theirs,
+    and its name at the end of the lists of what every served loop at
+    saturation reports. The files are a served cell's that is there."""
+
+    CELL = "further_family_serve_closed"
+    OWN = ("further_kernel_roofline", "further_step_roofline",
+           "further_busy_share.serve")
+    JOINED = ("serve_tokens_per_s", "batch_occupancy_avg",
+              "completed_requests_per_s", "window_hbm_gb.serve",
+              "prefill_time_share.serve", "step_ahead_share.serve")
+
+    def __init__(self, root):
+        super().__init__(root)
+        doc = copy.deepcopy(self.doc)
+        like = next(w for w in doc["workloads"] if self.config_doc(
+            w["config"])["kind"] == "serve")
+        doc["configs"].append(dict(self.configs[like["config"]],
+                                   name="further_family"))
+        doc["workloads"].append(dict(like, name=self.CELL,
+                                     config="further_family"))
+        for m in doc["end_to_end"] + doc["per_layer"]:
+            if m["name"] in self.JOINED:
+                m["workloads"].append(self.CELL)
+        doc["per_layer"] += [
+            {"name": name, "unit": "%", "better": "higher",
+             "source": "device_trace", "layer": "kernels and step program",
+             "moves": "serve_tokens_per_s", "workloads": [self.CELL]}
+            for name in self.OWN]
+        self.doc = doc
+        self.cells = {w["name"]: w for w in doc["workloads"]}
+        self.configs = {c["name"]: c for c in doc["configs"]}
+
+    def reader_path(self, metric):
+        """The three have no file: a reader that is there stands in."""
+        return super().reader_path(
+            "batch_occupancy_avg" if metric in self.OWN else metric)
+
+
+@pytest.fixture(scope="module")
+def grown():
+    grown = Grown(toy.REPO)
+    assert grown.problems() == []
+    assert toy.reported(grown, Grown.CELL) \
+        >= set(Grown.OWN) | set(Grown.JOINED) | {"setup_s"}
+    return grown
+
+
+def test_every_family_with_a_cell_has_its_assertions_in_a_holds(real):
+    """The files PR 42 freed, and one of the same name for every family
+    whose file came with a cell of its own since."""
+    assert {"step_ahead", "afmoe", "kimi_k2", "falcon_h1",
+            "admission_readers"} <= set(FAMILY_TESTS)
+    for c in real.doc["configs"]:
+        family = real.config_doc(c["name"]).get("family", "decoder_lm")
+        assert family == "decoder_lm" or family in FAMILY_TESTS, family
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_TESTS))
+def test_a_familys_assertions_hold_beside_a_further_cell(grown, family):
+    """A test that pins a list's end or length, a cell's reported metrics
+    or a metric's cells with `==`, fails here on the day it is written and
+    not on the day of the next cell."""
+    FAMILY_TESTS[family].holds(grown)
 
 
 def test_a_cell_and_a_metric_are_added_by_files_alone(tmp_path):

@@ -137,3 +137,17 @@ def keep_cells(root, names):
     with open(path, "w") as f:
         json.dump(doc, f)
     return root
+
+
+def entry(man, group, name):
+    """The one entry of that name in a list of the manifest. A test finds
+    what it checks by name: a list's end and length are the next PR's."""
+    found, = [e for e in man.doc[group] if e["name"] == name]
+    return found
+
+
+def reported(man, cell):
+    """The names of every metric the cell reports, end to end and by layer.
+    A family's test holds its own as a subset of them."""
+    return {m["name"] for group in ("end_to_end", "per_layer")
+            for m in man.metrics_of(cell, group)}
