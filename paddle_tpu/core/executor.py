@@ -278,6 +278,9 @@ class Executor:
         self._cache: Dict[tuple, _CompiledEntry] = {}
         self._ps_programs: Dict[tuple, bool] = {}
         self._verified: set = set()
+        # (metrics, device arrays) of async steps' telemetry fetches, not
+        # yet on the host
+        self._telemetry_pending: collections.deque = collections.deque()
 
     def close(self):
         self._cache.clear()
@@ -361,6 +364,9 @@ class Executor:
             sync_fetch: bool = True):
         program, mesh, in_shardings, scope, feed, fetch_names = \
             self._resolve_run(program, feed, fetch_list, scope, mesh)
+        asked = len(fetch_names)
+        told = [n for n in program.telemetry_fetches if n not in fetch_names]
+        fetch_names = fetch_names + told
         phases: Dict[str, float] = {}
         with telemetry.timer(span="executor.run", program=program.uid):
             block = program.global_block()
@@ -388,12 +394,44 @@ class Executor:
                                      compiled=False):
                     fetched = self._run_interpreted(program, block, feed,
                                                     fetch_names, scope, mesh)
+            if told:
+                self._note_telemetry(program, told, fetched[asked:],
+                                     sync_fetch)
+                fetched = fetched[:asked]
             with telemetry.timer("executor.writeback_ms", into=phases,
                                  span="executor.fetch", sync=sync_fetch):
                 out = self._materialize_fetches(fetched, return_numpy,
                                                 sync_fetch)
             self._observe_phases(phases)
             return out
+
+    def _note_telemetry(self, program, names, values, sync_fetch):
+        """A program's `telemetry_fetches` into the registry. A step that
+        hands its fetches back unmaterialised (`sync_fetch=False`) waits
+        for nothing here either: its vectors queue, and each later run
+        publishes those the device has finished by then."""
+        self._telemetry_pending.append(
+            ([program.telemetry_fetches[n] for n in names], list(values)))
+        self.flush_telemetry(wait=sync_fetch)
+
+    def flush_telemetry(self, wait: bool = True):
+        """Publishes the queued telemetry vectors, oldest first: all of
+        them (`wait`, blocking on the device), or as far as they are
+        ready."""
+        pending = self._telemetry_pending
+        while pending:
+            metrics, values = pending[0]
+            if not wait and not all(getattr(v, "is_ready", lambda: True)()
+                                    for v in values):
+                return
+            pending.popleft()
+            for entries, value in zip(metrics, values):
+                for (name, kind), v in zip(entries,
+                                           np.asarray(value).reshape(-1)):
+                    if kind == "hist":
+                        telemetry.observe(name, int(v))
+                    else:
+                        telemetry.counter_add(name, int(v))
 
     @staticmethod
     def _feeds_to_device(block, feed, phases):

@@ -609,6 +609,11 @@ class Program:
         # process-unique, never-reused identity for executor cache keys
         # (id() can alias a GC'd program; VERDICT r1 weak #8)
         self.uid = next(Program._uid_counter)
+        # int vectors a step computes for the telemetry registry alone:
+        # var name -> a (metric name, "counter" | "hist") for each entry.
+        # Executor.run fetches them beside the caller's own fetches and
+        # publishes them (core/executor.py `_note_telemetry`)
+        self.telemetry_fetches: Dict[str, tuple] = {}
 
     def _bump_version(self):
         self._version += 1

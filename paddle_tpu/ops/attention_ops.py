@@ -41,7 +41,11 @@ def flash_attention_op(ins, attrs):
     Q [B,H,Sq,D]; K,V [B,H,Sk,D]; Bias optional, broadcastable to
     [B,1,1,Sk] (key padding mask). Attrs: causal (bool), scale (float,
     default 1/sqrt(D)), dropout_prob/is_test/seed (attention-probs
-    dropout, reference attention_probs_dropout_prob semantics).
+    dropout, reference attention_probs_dropout_prob semantics); window
+    (a query at t reads keys at t - window < s <= t) and num_kv_heads
+    (K, V hold fewer heads; query head j reads K/V head j // group): with
+    either, causal self-attention with no Bias and no dropout through
+    ops/pallas/flash_window.py.
 
     Second output Lse ([B,H,Sq] f32 log-sum-exp) feeds the saved-residual
     flash_attention_grad op so the backward never re-runs the forward
@@ -61,8 +65,19 @@ def flash_attention_op(ins, attrs):
         q, k, v, bias=bias, causal=bool(attrs.get("causal", False)),
         scale=attrs.get("scale", None),
         dropout_rate=rate, dropout_seed=seed,
-        num_heads=_local_heads(q, attrs))
+        num_heads=_local_heads(q, attrs), **_window_attrs(attrs))
     return {"Out": out, "Lse": lse}
+
+
+def _window_attrs(attrs):
+    """The window and K/V head count of a windowed / grouped call, for
+    the forward and the grad op alike; {} on descs without them."""
+    kw = {}
+    if attrs.get("window"):
+        kw["window"] = int(attrs["window"])
+    if attrs.get("num_kv_heads"):
+        kw["num_kv_heads"] = int(attrs["num_kv_heads"])
+    return kw
 
 
 def _local_heads(q, attrs):
@@ -133,13 +148,15 @@ def flash_attention_grad_op(ins, attrs):
     causal = bool(attrs.get("causal", False))
     scale = attrs.get("scale", None)
     num_heads = _local_heads(q, attrs)
+    windowed = _window_attrs(attrs)
     # these routes saved (out, lse); the others recompute
-    direct = attention_route(q, k, bias, num_heads)[0] in (
+    direct = bool(windowed) or attention_route(q, k, bias, num_heads)[0] in (
         "packed", "pallas", "pallas_interpret")
     if direct:
         dq, dk, dv, dbias_kv = flash_attention_bwd(
             q, k, v, bias, out, lse, do, causal=causal, scale=scale,
-            dropout_rate=rate, dropout_seed=seed, num_heads=num_heads)
+            dropout_rate=rate, dropout_seed=seed, num_heads=num_heads,
+            **windowed)
     else:
         args = (q, k, v) + ((bias,) if bias is not None else ())
 

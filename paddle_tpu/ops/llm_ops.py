@@ -16,7 +16,7 @@ and router scores are float32 throughout.
 
 from __future__ import annotations
 
-from ..core.registry import register_op
+from ..core.registry import register_grad_maker, register_op
 
 
 @register_op("rms_norm")
@@ -102,21 +102,34 @@ def prompt_rows_live_op(ins, attrs):
             < ins["Lengths"][0].reshape(-1, 1)}
 
 
-@register_op("qk_norm_rope", required_attrs=("head_dim",))
+@register_op("qk_norm_rope", non_diff_inputs=("Positions",),
+             required_attrs=("head_dim",))
 def qk_norm_rope_op(ins, attrs):
     """Per-head RMS norm of Q and K over `head_dim` with learned gains,
     then (attr `rope`) rotary position embedding over the whole head in
     the half-split convention: (x1, x2) -> (x1 cos - x2 sin, x2 cos +
-    x1 sin) with angle pos * theta^(-2i/head_dim).
+    x1 sin) with angle pos * theta^(-2i/head_dim). With attr
+    `yarn_factor` over 1 the frequencies are YaRN's (`yarn_inv_freq` from
+    `theta`, `yarn_original_max`, `yarn_beta_fast`, `yarn_beta_slow`), and
+    cos and sin are multiplied by attr `attention_factor`.
 
     Q [..., nq*hd], K [..., nkv*hd] float32; QScale, KScale [hd];
-    Positions int32, shaped like Q without its last axis."""
+    Positions int32, shaped like Q without its last axis; left out, a
+    row's position is its index along Q's second-to-last axis (a whole
+    unpadded sequence a row, as a trainer feeds them)."""
     import jax
     import jax.numpy as jnp
 
     hd = int(attrs["head_dim"])
     eps = float(attrs.get("epsilon", 1e-5))
-    pos = ins["Positions"][0]
+    if ins.get("Positions"):
+        pos = ins["Positions"][0]
+    else:
+        q = ins["Q"][0]
+        pos = jnp.broadcast_to(jnp.arange(q.shape[-2], dtype=jnp.int32),
+                               q.shape[:-1])
+    yarn = float(attrs.get("yarn_factor", 1.0))
+    mscale = float(attrs.get("attention_factor", 1.0))
 
     def one(x, scale):
         lead = x.shape[:-1]
@@ -125,10 +138,19 @@ def qk_norm_rope_op(ins, attrs):
         xh = xh * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
         if attrs.get("rope"):
             half = hd // 2
-            inv = float(attrs.get("theta", 10000.0)) ** (
-                -jnp.arange(half, dtype=jnp.float32) * 2.0 / hd)
+            theta = float(attrs.get("theta", 10000.0))
+            if yarn > 1.0:
+                inv = jnp.asarray(yarn_inv_freq(
+                    hd, theta, yarn, int(attrs["yarn_original_max"]),
+                    float(attrs.get("yarn_beta_fast", 32.0)),
+                    float(attrs.get("yarn_beta_slow", 1.0))))
+            else:
+                inv = theta ** (-jnp.arange(half, dtype=jnp.float32)
+                                * 2.0 / hd)
             ang = pos.astype(jnp.float32)[..., None, None] * inv
             cos, sin = jnp.cos(ang), jnp.sin(ang)
+            if mscale != 1.0:
+                cos, sin = cos * mscale, sin * mscale
             x1, x2 = xh[..., :half], xh[..., half:]
             xh = jnp.concatenate([x1 * cos - x2 * sin,
                                   x2 * cos + x1 * sin], axis=-1)
@@ -192,6 +214,96 @@ def gqa_prefill_attention_op(ins, attrs):
     out = jax.lax.map(block, jnp.arange(0, s, bq, dtype=jnp.int32))
     # [blocks, B, bq, nkv, g, hd] -> [B, S, n*hd]
     return {"Out": jnp.moveaxis(out, 0, 1).reshape(b, s, n * hd)}
+
+
+def _chunked_head_loss(x, w, labels, chunk):
+    """-> (mean cross-entropy of softmax(x @ w) against labels, its
+    gradients in x [T, H] and w [H, V]), a chunk of rows at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    t, h = x.shape
+    n = t // chunk
+    xs = x.astype(w.dtype).reshape(n, chunk, h)
+
+    def some(carry, row):
+        loss, dw = carry
+        xc, lab = row
+        logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+        top = jnp.max(logits, axis=-1, keepdims=True)
+        ex = jnp.exp(logits - top)
+        total = jnp.sum(ex, axis=-1, keepdims=True)
+        hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) \
+            == lab[:, None]
+        loss = loss + jnp.sum(jnp.log(total) + top
+                              - jnp.sum(jnp.where(hit, logits, 0.0),
+                                        axis=-1, keepdims=True))
+        dl = ((ex / total - hit.astype(jnp.float32)) / t).astype(w.dtype)
+        dx = jax.lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(xc, dl, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        return (loss, dw), dx.astype(w.dtype)
+
+    (loss, dw), dx = jax.lax.scan(
+        some, (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)),
+        (xs, labels.reshape(n, chunk).astype(jnp.int32)))
+    return loss / t, dx.reshape(t, h), dw.astype(w.dtype)
+
+
+@register_op("head_cross_entropy", non_diff_inputs=("Label",))
+def head_cross_entropy_op(ins, attrs):
+    """Loss [1] = mean over rows of the cross-entropy of softmax(X @ W)
+    against Label: the output head and its loss as one op that never holds
+    the logits whole. X [..., H] float32 (rounded to W's dtype where it
+    enters the product, float32 accumulation), W [H, V], Label int [...].
+    Rows go `chunk` at a time (attr; the row count where it does not
+    divide): a chunk's [chunk, V] float32 logits, their softmax and the
+    chunk's part of both gradients, so that a step's peak holds one
+    chunk's logits and not [tokens, V] twice over (3.2 GB at 16,384 x
+    24,576). XGrad [..., H] and WGrad [H, V] (W's dtype) are the
+    gradients of Loss itself: `head_cross_entropy_grad` scales them by
+    Loss's cotangent, and the backward computes no product again."""
+    import jax.numpy as jnp
+
+    x, w, labels = ins["X"][0], ins["W"][0], ins["Label"][0]
+    flat = x.reshape(-1, x.shape[-1])
+    t = flat.shape[0]
+    chunk = int(attrs.get("chunk", 2048))
+    if chunk <= 0 or t % chunk:
+        chunk = t
+    loss, dx, dw = _chunked_head_loss(flat, w, labels.reshape(-1), chunk)
+    return {"Loss": loss.reshape(1).astype(jnp.float32),
+            "XGrad": dx.reshape(x.shape), "WGrad": dw}
+
+
+@register_grad_maker("head_cross_entropy")
+def _head_cross_entropy_grad_maker(op, out_grads, in_grads):
+    from ..core.ir import OpDesc
+
+    og = (out_grads.get("Loss") or [None])[0]
+    grads = {s: (in_grads.get(s) or [None])[0] for s in ("X", "W")}
+    if og is None or all(g is None for g in grads.values()):
+        return []
+    return [OpDesc(
+        "head_cross_entropy_grad",
+        {"XGrad": list(op.outputs["XGrad"]),
+         "WGrad": list(op.outputs["WGrad"]), "LossGrad": [og]},
+        {s + "Grad": [g] for s, g in grads.items() if g is not None}, {})]
+
+
+@register_op("head_cross_entropy_grad",
+             non_diff_inputs=("XGrad", "WGrad", "LossGrad"),
+             skip_infer_shape=True)
+def head_cross_entropy_grad_op(ins, attrs):
+    """d(X, W) of head_cross_entropy: its saved unit gradients times the
+    cotangent of Loss. XGrad in float32 (X's dtype), WGrad in W's."""
+    import jax.numpy as jnp
+
+    g = ins["LossGrad"][0].reshape(()).astype(jnp.float32)
+    dx, dw = ins["XGrad"][0], ins["WGrad"][0]
+    return {"XGrad": dx.astype(jnp.float32) * g,
+            "WGrad": (dw.astype(jnp.float32) * g).astype(dw.dtype)}
 
 
 # ---------------------------------------------------------------------------

@@ -31,26 +31,33 @@ def switch_moe_op(ins, attrs):
     return {"Out": out.reshape(x.shape), "AuxLoss": aux}
 
 
-@register_op("routed_experts",
+@register_op("routed_experts", non_diff_inputs=("SelectBias", "Live"),
              required_attrs=("top_k", "held_lo"))
 def routed_experts_op(ins, attrs):
-    """One chip's share of a dropless top-k sigmoid-routed expert layer
-    (parallel/moe.py routed_experts_share): X [..., H] float32, RouterW
+    """One chip's share of a dropless top-k routed expert layer
+    (parallel/moe.py routed_experts_share; attr `score_func` "sigmoid" or
+    "softmax" over all experts; attr `trainable` gives the held experts'
+    part its backward and Counts a fourth entry, the largest group's
+    rows): X [..., H] float32, RouterW
     [H, E] over ALL experts, SelectBias [E], W1/W3 [E_held, H, F] and W2
     [E_held, F, H] of the experts `held_lo` .. held here, optional Live
     bool, one a row of X (the rows that carry a token; the rest join no
     group). The held experts' SwiGLUs run as one grouped kernel over the
     pairs sorted by expert (ops/pallas/grouped_swiglu.py; three
     ragged_dots where kernel_mode() is off). Out like X, float32; Counts
-    int32 [3] (live pairs, those on held experts, held experts hit)."""
+    int32 [3] (live pairs, those on held experts, held experts hit);
+    Chosen int32 [..., top_k], the experts each row chose."""
     from ..parallel.moe import routed_experts_share
 
     live = ins["Live"][0] if ins.get("Live") else None
     x = ins["X"][0]
-    out, counts = routed_experts_share(
+    out, counts, chosen = routed_experts_share(
         x.reshape(-1, x.shape[-1]), ins["RouterW"][0], ins["SelectBias"][0],
         ins["W1"][0], ins["W3"][0], ins["W2"][0],
         top_k=int(attrs["top_k"]), held_lo=int(attrs["held_lo"]),
         route_scale=float(attrs.get("route_scale", 1.0)),
-        route_norm=bool(attrs.get("route_norm", True)), live=live)
-    return {"Out": out.reshape(x.shape), "Counts": counts}
+        route_norm=bool(attrs.get("route_norm", True)), live=live,
+        score_func=attrs.get("score_func", "sigmoid"),
+        trainable=bool(attrs.get("trainable", False)), with_chosen=True)
+    return {"Out": out.reshape(x.shape), "Counts": counts,
+            "Chosen": chosen.reshape(x.shape[:-1] + (-1,))}

@@ -1703,9 +1703,45 @@ def _bnsd_to_packed(x4):
     return jnp.swapaxes(x4, 1, 2).reshape(b, s, n * hd)
 
 
+def _windowed(window, num_kv_heads):
+    """A window or a K/V head count of its own asks for flash_window.py's
+    kernels; the kernels here know neither."""
+    return bool(window) or num_kv_heads is not None
+
+
+def _window_call(q, k, v, bias, causal, dropout_rate, num_heads,
+                 num_kv_heads, window, scale):
+    """A windowed / grouped call, which is causal self-attention with no
+    bias and no dropout, as flash_window.py takes it -> ((q, k, v) packed,
+    its keyword arguments, `back`: an array of `heads` heads returned to
+    the caller's layout)."""
+    if not causal or bias is not None or float(dropout_rate or 0.0):
+        raise ValueError("window / num_kv_heads attention is causal, with "
+                         "no bias and no dropout")
+    if q.ndim == 4:
+        n, nkv = q.shape[1], k.shape[1]
+        q, k, v = (_bnsd_to_packed(x) for x in (q, k, v))
+        back = _packed_to_bnsd
+    elif not num_heads:
+        raise ValueError("packed flash attention needs num_heads")
+    else:
+        n, nkv = int(num_heads), int(num_kv_heads or num_heads)
+
+        def back(x, heads):
+            return x
+    return (q, k, v), dict(num_heads=n, num_kv_heads=nkv,
+                           window=int(window or 0), scale=scale), back
+
+
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
-                    dropout_rate=0.0, dropout_seed=None, num_heads=None):
+                    dropout_rate=0.0, dropout_seed=None, num_heads=None,
+                    window=None, num_kv_heads=None):
     """softmax(q k^T * scale + bias) v, O(S)-memory in the backward.
+
+    With ``window`` (a query at t reads keys at t - window < s <= t) or
+    ``num_kv_heads`` (query head j reads K/V head j // group; k, v
+    [B,Hkv,S,D] or packed [B,S,Hkv*hd]) the call is causal
+    self-attention through ops/pallas/flash_window.py, differentiable.
 
     q [B,H,Sq,D]; k,v [B,H,Sk,D] — or packed [B,S,n*hd] with num_heads
     (see flash_attention_fwd_lse); bias None or broadcastable to
@@ -1725,6 +1761,14 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
         cannot even compile (e.g. s=4096).
     attention_route() decides between them.
     """
+    if _windowed(window, num_kv_heads):
+        from .flash_window import flash_window_attention
+
+        qkv, kw, back = _window_call(q, k, v, bias, causal, dropout_rate,
+                                     num_heads, num_kv_heads, window, scale)
+        return back(flash_window_attention(
+            *qkv, kw["num_heads"], kw["num_kv_heads"], kw["window"], scale),
+            kw["num_heads"])
     out, _ = flash_attention_fwd_lse(q, k, v, bias, causal, scale,
                                      dropout_rate, dropout_seed,
                                      num_heads=num_heads)
@@ -1733,7 +1777,7 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
 
 def flash_attention_fwd_lse(q, k, v, bias=None, causal=False, scale=None,
                             dropout_rate=0.0, dropout_seed=None,
-                            num_heads=None):
+                            num_heads=None, window=None, num_kv_heads=None):
     """flash_attention returning (out, lse).
 
     lse [B,H,Sq] f32 is the log-sum-exp residual the saved-residual
@@ -1748,6 +1792,13 @@ def flash_attention_fwd_lse(q, k, v, bias=None, causal=False, scale=None,
     [B,S,n*hd] — no head transposes in the program (~13.9 ms/step of
     the round-4 ERNIE profile). Shapes outside the packed fused regime
     transpose internally and take the standard dispatch."""
+    if _windowed(window, num_kv_heads):
+        from .flash_window import flash_window_fwd_lse
+
+        qkv, kw, back = _window_call(q, k, v, bias, causal, dropout_rate,
+                                     num_heads, num_kv_heads, window, scale)
+        out, lse = flash_window_fwd_lse(*qkv, **kw)
+        return back(out, kw["num_heads"]), lse
     if q.ndim == 3:
         if not num_heads:
             raise ValueError("packed flash attention needs num_heads")
@@ -1806,7 +1857,7 @@ def _packed_fwd_lse(q, k, v, bias, causal, scale, dropout_rate,
 
 def flash_attention_bwd(q, k, v, bias, out, lse, dout, causal=False,
                         scale=None, dropout_rate=0.0, dropout_seed=None,
-                        num_heads=None):
+                        num_heads=None, window=None, num_kv_heads=None):
     """Backward from the SAVED forward (out, lse): runs only the bwd
     kernels — no forward re-execution (the vjp path re-runs the fwd
     pallas custom-call, which XLA cannot CSE with the forward op's;
@@ -1815,7 +1866,20 @@ def flash_attention_bwd(q, k, v, bias, out, lse, dout, causal=False,
     Only valid where attention_route() says 'packed' or 'pallas*' —
     callers check first.
     Returns (dq, dk, dv, dbias_kv); dbias_kv is [B,Sk] (the key-bias
-    normal form) or None when bias is None."""
+    normal form) or None when bias is None. A windowed / grouped call
+    (flash_window.py) is valid on every route: its reference route
+    differentiates the masked form."""
+    if _windowed(window, num_kv_heads):
+        from .flash_window import flash_window_bwd
+
+        packed = q.ndim == 3
+        qkv, kw, back = _window_call(q, k, v, bias, causal, dropout_rate,
+                                     num_heads, num_kv_heads, window, scale)
+        if not packed:
+            out, dout = _bnsd_to_packed(out), _bnsd_to_packed(dout)
+        grads = flash_window_bwd(*qkv, out, lse, dout, **kw)
+        return tuple(back(g_, kw[h_]) for g_, h_ in zip(grads, (
+            "num_heads", "num_kv_heads", "num_kv_heads"))) + (None,)
     if q.ndim == 3:
         from . import interpret_mode
 

@@ -13,6 +13,8 @@ all_to_alls drop out — same math, no comm.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from ..ops.collective_ops import _in_spmd
@@ -121,12 +123,162 @@ def switch_moe(x, gate_w, w1, b1, w2, b2, capacity_factor: float = 1.25,
     return out.astype(x.dtype), aux.astype(jnp.float32)
 
 
+def _held_experts_fwd(x, w_sorted, w1, w3, w2, rows, sizes, few):
+    out = _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few)
+    return out, (x, w_sorted, w1, w3, w2, rows, sizes)
+
+
+def _held_experts_bwd(few, res, dout):
+    """The backward of the held experts' part, over the same sorted rows
+    the forward ran over and as far as the held pairs reach: the gate and
+    up products again (a sorted row's [F] float32 pair is not kept: 2 x
+    470 MB a layer at 65,536 rows x 896), then the six products of the
+    gradients, each a ragged product over the groups. Nothing is dropped
+    at any imbalance: past `few` rows the chunks go on."""
+    import jax
+    import jax.numpy as jnp
+
+    x, w_sorted, w1, w3, w2, rows, sizes = res
+    t, h = x.shape
+    dt = w1.dtype
+    f32 = jnp.float32
+    pairs = rows.shape[0]
+    # rows by a group's TRANSPOSED matrix go through the plain ragged
+    # product over a transposed copy (66 MB a matrix at 16 x 2304 x 896):
+    # the ragged product that contracts the matrix's last axis instead
+    # came back 97% off at [rows, 2304] x [16, 896, 2304] over 20,480 rows
+    # and more on the chip, and right at 16,448 (my chip runs, PR 43)
+    w1_t, w3_t, w2_t = (jnp.swapaxes(w, 1, 2) for w in (w1, w3, w2))
+    dn_w = jax.lax.RaggedDotDimensionNumbers(       # a[group]^T @ b[group]
+        dot_dimension_numbers=(([0], [0]), ([], [])),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+    def rd(a, w, part, dn=None):
+        if dn is None:
+            return jax.lax.ragged_dot(a, w, part, preferred_element_type=f32)
+        return jax.lax.ragged_dot_general(a, w, part, dn,
+                                          preferred_element_type=f32)
+
+    def back(r, w, part):
+        """One run of sorted rows -> (dx scattered [T, H], dw [n], dW1,
+        dW3, dW2 in float32)."""
+        mine = (w > 0)[:, None]
+        xs = x[r].astype(dt)
+        dy = jnp.where(mine, dout[r], 0.0)
+        gate, up = rd(xs, w1, part), rd(xs, w3, part)
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig
+        mid = act * up
+        dmid = rd(dy.astype(dt), w2_t, part)                # per unit weight
+        dw = jnp.sum(jnp.where(mine, dmid * mid, 0.0), axis=1)
+        dmid = dmid * w[:, None]
+        dgate = (dmid * up * (sig + act * (1.0 - sig))).astype(dt)
+        dup = (dmid * act).astype(dt)
+        dxs = rd(dgate, w1_t, part) + rd(dup, w3_t, part)
+        dx = jnp.zeros((t, h), f32).at[r].add(jnp.where(mine, dxs, 0.0))
+        return (dx, dw, rd(xs, dgate, part, dn_w), rd(xs, dup, part, dn_w),
+                rd(mid.astype(dt), (dy * w[:, None]).astype(dt), part, dn_w))
+
+    def every():
+        pad = -pairs % few
+        rows_p, w_p = jnp.pad(rows, (0, pad)), jnp.pad(w_sorted, (0, pad))
+        ends = jnp.cumsum(sizes)
+
+        def some(i, acc):
+            lo = i * few
+            part = jnp.clip(ends, lo, lo + few) \
+                - jnp.clip(ends - sizes, lo, lo + few)
+            dx, dw, d1, d3, d2 = back(
+                jax.lax.dynamic_slice_in_dim(rows_p, lo, few),
+                jax.lax.dynamic_slice_in_dim(w_p, lo, few), part)
+            return (acc[0] + dx,
+                    jax.lax.dynamic_update_slice_in_dim(acc[1], dw, lo, 0),
+                    acc[2] + d1, acc[3] + d3, acc[4] + d2)
+
+        zero = (jnp.zeros((t, h), f32), jnp.zeros((pairs + pad,), f32),
+                jnp.zeros(w1.shape, f32), jnp.zeros(w3.shape, f32),
+                jnp.zeros(w2.shape, f32))
+        dx, dw, d1, d3, d2 = jax.lax.fori_loop(
+            0, (ends[-1] + few - 1) // few, some, zero)
+        return dx, dw[:pairs], d1, d3, d2
+
+    def leading():
+        dx, dw, d1, d3, d2 = back(rows[:few], w_sorted[:few], sizes)
+        return dx, jnp.pad(dw, (0, pairs - few)), d1, d3, d2
+
+    if few < pairs:
+        grads = jax.lax.cond(jnp.sum(sizes) <= few, leading, every)
+    else:
+        grads = back(rows, w_sorted, sizes)
+    dx, dw, d1, d3, d2 = grads
+    return (dx.astype(x.dtype), dw.astype(w_sorted.dtype), d1.astype(dt),
+            d3.astype(dt), d2.astype(dt), None, None)
+
+
+def _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few):
+    """sum_i w_i * Expert_i(x) over the pairs sorted by held expert: their
+    tokens `rows`, their weights `w_sorted` (0 past the groups), `sizes`
+    of them in each expert's group. Out [T, H] float32. Differentiable in
+    x, the weights and the three matrices (`_held_experts_bwd`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.pallas.grouped_swiglu import grouped_swiglu
+
+    t, h = x.shape
+    pairs = rows.shape[0]
+
+    def experts(r, w, sizes):
+        xs = x[r].astype(w1.dtype)                               # [n, H]
+        ys = grouped_swiglu(xs, w1, w3, w2, sizes)               # [n, H]
+        # rows past the groups hold nothing of a held expert: weight 0,
+        # and a `where` so that whatever the grouped product left there
+        # stays out
+        ys = jnp.where(w[:, None] > 0, ys * w[:, None], 0.0)
+        return jnp.zeros((t, h), jnp.float32).at[r].add(ys)
+
+    def every():
+        pad = -pairs % few
+        rows_p, w_p = jnp.pad(rows, (0, pad)), jnp.pad(w_sorted, (0, pad))
+        ends = jnp.cumsum(sizes)
+
+        def some(i, out):
+            lo = i * few
+            part = jnp.clip(ends, lo, lo + few) \
+                - jnp.clip(ends - sizes, lo, lo + few)
+            return out + experts(
+                jax.lax.dynamic_slice_in_dim(rows_p, lo, few),
+                jax.lax.dynamic_slice_in_dim(w_p, lo, few), part)
+
+        return jax.lax.fori_loop(0, (ends[-1] + few - 1) // few, some,
+                                 jnp.zeros((t, h), jnp.float32))
+
+    if few < pairs:
+        return jax.lax.cond(
+            jnp.sum(sizes) <= few,
+            lambda: experts(rows[:few], w_sorted[:few], sizes), every)
+    return experts(rows, w_sorted, sizes)
+
+
+@functools.lru_cache(maxsize=None)
+def _trained_held_experts():
+    """`_held_experts` with its hand-written backward, made once."""
+    import jax
+
+    trained = jax.custom_vjp(_held_experts, nondiff_argnums=(7,))
+    trained.defvjp(_held_experts_fwd, _held_experts_bwd)
+    return trained
+
+
 def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
                          top_k: int, held_lo: int, route_scale: float = 1.0,
-                         route_norm: bool = True, live=None):
-    """One chip's share of a dropless top-k routed expert layer with
-    sigmoid scores (the serving form; `switch_moe` above is the trained
-    top-1 layer with a capacity).
+                         route_norm: bool = True, live=None,
+                         score_func: str = "sigmoid",
+                         trainable: bool = False, with_chosen: bool = False):
+    """One chip's share of a dropless top-k routed expert layer
+    (`switch_moe` above is the top-1 layer with a capacity). Scores are
+    ``sigmoid(x Wr)`` or, with ``score_func="softmax"``, the softmax over
+    all E experts.
 
     x           [T, H]   tokens (any float dtype)
     router_w    [H, E]   the router over ALL E experts, as published
@@ -155,20 +307,31 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     time, as far as the held pairs reach (one ``lax.cond``). What the absent experts would
     have added is left out; nothing stands in for their chips or the exchange.
 
+    ``trainable`` makes the result differentiable in x, the router and
+    the held experts' three matrices (the selection is discrete and takes
+    no gradient): the forward is the same, the held experts' part carries
+    a hand-written backward over the same sorted rows
+    (``_held_experts_bwd``), dropless too.
+
     Returns (out [T, H] float32, counts int32 [3]): the kept pairs of live
     rows, those of them on held experts, and the held experts with at
-    least one live pair."""
+    least one live pair; ``trainable`` adds a fourth, the rows of the
+    largest held group; ``with_chosen`` a third result, the chosen
+    experts int32 [T, top_k]."""
     import jax
     import jax.numpy as jnp
-
-    from ..ops.pallas.grouped_swiglu import grouped_swiglu
 
     t, h = x.shape
     e_held = w1.shape[0]
     xf = x.astype(jnp.float32)
-    scores = jax.nn.sigmoid(jnp.matmul(
-        xf, router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))                    # [T, E]
+    logits = jnp.matmul(xf, router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)     # [T, E]
+    if score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score_func {score_func!r}")
     _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
     kept = jnp.take_along_axis(scores, idx, axis=1)              # [T, k]
     weight = kept
@@ -188,17 +351,6 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     rows = (order // top_k).astype(jnp.int32)
     w_sorted = jnp.where(held, weight, 0.0).reshape(-1)[order]
 
-    def experts(r, w, sizes):
-        """The held experts over sorted pairs: their tokens `r`, their
-        weights `w`, `sizes` of them in each expert's group."""
-        xs = x[r].astype(w1.dtype)                               # [n, H]
-        ys = grouped_swiglu(xs, w1, w3, w2, sizes)               # [n, H]
-        # rows past the groups hold nothing of a held expert: weight 0,
-        # and a `where` so that whatever the grouped product left there
-        # stays out
-        ys = jnp.where(w[:, None] > 0, ys * w[:, None], 0.0)
-        return jnp.zeros((t, h), jnp.float32).at[r].add(ys)
-
     # the pairs on held experts sort first, and with evenly spread routing
     # they are e_held / E of all pairs. Twice that share (and a margin)
     # of the sorted rows holds them nearly always, and the grouped
@@ -212,29 +364,23 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     # tokens, top-8, H 7168).
     pairs = t * top_k
     few = -(-(2 * pairs * e_held // router_w.shape[1] + 32) // 64) * 64
+    if trainable:
+        # a trained layer's held pairs are thousands a group, the ragged
+        # products of its backward cost by the rows they run over (and
+        # four times less over a multiple of 4,096 rows than over 65,600:
+        # 3.6 ms against 14.2, my chip run, PR 43), and routing that is
+        # not even is the `every` chunks' to catch: a quarter over the
+        # even share, in whole tiles
+        even = pairs * e_held / router_w.shape[1]
+        tile = 4096 if even >= 4096 else 64
+        few = int(-(-(1.25 * even) // tile) * tile)
 
-    def every():
-        pad = -pairs % few
-        rows_p, w_p = jnp.pad(rows, (0, pad)), jnp.pad(w_sorted, (0, pad))
-        ends = jnp.cumsum(sizes)
-
-        def some(i, out):
-            lo = i * few
-            part = jnp.clip(ends, lo, lo + few) \
-                - jnp.clip(ends - sizes, lo, lo + few)
-            return out + experts(
-                jax.lax.dynamic_slice_in_dim(rows_p, lo, few),
-                jax.lax.dynamic_slice_in_dim(w_p, lo, few), part)
-
-        return jax.lax.fori_loop(0, (ends[-1] + few - 1) // few, some,
-                                 jnp.zeros((t, h), jnp.float32))
-
-    if few < pairs:
-        out = jax.lax.cond(
-            jnp.sum(sizes) <= few,
-            lambda: experts(rows[:few], w_sorted[:few], sizes), every)
-    else:
-        out = experts(rows, w_sorted, sizes)
-    counts = jnp.stack([jnp.sum(alive) * top_k, jnp.sum(sizes),
-                        jnp.sum(sizes > 0)]).astype(jnp.int32)
+    held_experts = _trained_held_experts() if trainable else _held_experts
+    out = held_experts(x, w_sorted, w1, w3, w2, rows, sizes, min(few, pairs))
+    counts = [jnp.sum(alive) * top_k, jnp.sum(sizes), jnp.sum(sizes > 0)]
+    if trainable:
+        counts.append(jnp.max(sizes))
+    counts = jnp.stack(counts).astype(jnp.int32)
+    if with_chosen:
+        return out, counts, idx.astype(jnp.int32)
     return out, counts

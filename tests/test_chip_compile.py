@@ -250,6 +250,56 @@ def test_flash_attention_fwd_bwd(one_chip, tpu_mode, layout):
     assert text.count("tpu_custom_call") >= 2          # fwd and bwd
 
 
+@pytest.mark.parametrize("window", [1024, 0], ids=["sliding", "full"])
+def test_flash_window_fwd_bwd_at_the_mellum_share(one_chip, tpu_mode,
+                                                  window):
+    """2 x 8,192 tokens, 8 query heads of 128 on one K/V head, bfloat16:
+    the three window / grouped-head kernels; a sliding layer's grid is 3
+    K blocks a query block deep, the full layer's 16."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.flash_window import reach_of
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, num_heads=8,
+                              num_kv_heads=1, window=window)
+        return jnp.sum(out.astype(F32))
+
+    text = _compile(jax.grad(loss, (0, 1, 2)), one_chip,
+                    ((2, 8192, 1024), BF16), ((2, 8192, 128), BF16),
+                    ((2, 8192, 128), BF16))
+    for name in ("flash_fwd_window", "flash_bwd_window_dkv",
+                 "flash_bwd_window_dq"):
+        assert name in text
+    assert reach_of(8192, 512, window) == (3 if window else 16)
+
+
+def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
+    """16,384 tokens, top-8 of 64 by softmax, experts 0-15 held at 2304 x
+    896, forward and the hand-written backward: the forward's grouped
+    kernel once (the backward's trace of it is dead code and gone), the
+    backward's products the chip's ragged-dot kernel in all three modes
+    (rows by group, rows by a group's transposed matrix, a group's rows
+    contracted)."""
+    from paddle_tpu.parallel.moe import routed_experts_share
+
+    def loss(x, wr, w1, w3, w2):
+        out, _counts = routed_experts_share(
+            x, wr, jnp.zeros((64,), F32), w1, w3, w2, top_k=8, held_lo=0,
+            score_func="softmax", trainable=True)
+        return jnp.sum(out)
+
+    text = _compile(jax.value_and_grad(loss, (0, 1, 2, 3, 4)), one_chip,
+                    ((16384, 2304), F32), ((2304, 64), BF16),
+                    ((16, 2304, 896), BF16), ((16, 2304, 896), BF16),
+                    ((16, 896, 2304), BF16))
+    assert "ragged-dot" in text and "f32[16,2304,896]" in text
+    # the leading rows' branch and the chunked one, of the forward only
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "grouped_swiglu" in line]
+    assert len(calls) == 2
+
+
 def test_step_sampler_at_the_xglm_vocabulary(one_chip):
     """The decode step's last stage (serving/sampling.py) over the b8 x
     256,008 logits of the serving cell: plain XLA, no temporaries beyond

@@ -1,0 +1,120 @@
+"""The trained routed layer on the chip, forward and forward + backward,
+at the Mellum cell's shapes (16,384 tokens, hidden 2304, 16 of 64 experts
+of width 896 held, top-8, bfloat16): `routed_experts_share(trainable=True)`
+with its held experts' forward as the `grouped_swiglu` kernel and as the
+three stock `ragged_dot`s (the hand-written backward is the same), and the
+stock forward under JAX's own differentiation rules over the leading rows.
+ms a call, and % of the bf16 peak for the nine products of the held
+pairs. Lines in chiprun_out/routed_train_bench.jsonl (a call's file
+replaces the last one's). ~2 min.
+
+    python tools/bench_routed_train.py [--tokens 16384]
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+PEAK = 197e12
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tokens", type=int, default=16384)
+    ap.add_argument("--iters", type=int, default=10)
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_swiglu as gs
+    from paddle_tpu.parallel import moe
+
+    t, h, f, e, eh, k = args.tokens, 2304, 896, 64, 16, 8
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    bf = jnp.bfloat16
+    x = jax.random.normal(keys[0], (t, h), jnp.float32)
+    rw = (jax.random.normal(keys[1], (h, e)) * h ** -0.5).astype(bf)
+    w1 = (jax.random.normal(keys[2], (eh, h, f)) * h ** -0.5).astype(bf)
+    w3 = (jax.random.normal(keys[3], (eh, h, f)) * h ** -0.5).astype(bf)
+    w2 = (jax.random.normal(keys[4], (eh, f, h)) * f ** -0.5).astype(bf)
+    co = jax.random.normal(keys[5], (t, h), jnp.float32)
+    bias = jnp.zeros((e,), jnp.float32)
+
+    def layer(x, rw, w1, w3, w2):
+        return moe.routed_experts_share(
+            x, rw, bias, w1, w3, w2, top_k=k, held_lo=0,
+            score_func="softmax", trainable=True)
+
+    def loss(x, rw, w1, w3, w2):
+        out, counts = layer(x, rw, w1, w3, w2)
+        return jnp.sum(out * co), counts
+
+    def own_rules(x, rw, w1, w3, w2):
+        """The stock products under jax's own rules, over the leading
+        rows only (no branch: what a cond would keep twice)."""
+        logits = x @ rw.astype(jnp.float32)
+        p = jax.nn.softmax(logits, -1)
+        top, idx = jax.lax.top_k(p, k)
+        w = top / jnp.sum(top, -1, keepdims=True)
+        held = idx < eh
+        key = jnp.where(held, idx, eh).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, eh + 1, dtype=jnp.int32), 0)[:eh]
+        even = t * k * eh / e          # the trained layer's leading rows
+        tile = 4096 if even >= 4096 else 64
+        few = int(-(-(1.25 * even) // tile) * tile)
+        rows = (order // k)[:few]
+        ws = jnp.where(held, w, 0.0).reshape(-1)[order][:few]
+        ys = gs.stock_grouped_swiglu(x[rows].astype(bf), w1, w3, w2, sizes)
+        ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)
+        out = jnp.zeros((t, h), jnp.float32).at[rows].add(ys)
+        return jnp.sum(out * co), sizes
+
+    def timed(fn, *a):
+        fn = jax.jit(fn)
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*a))
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / args.iters * 1e3, first, out
+
+    stock_kernel = gs.grouped_swiglu
+    rows = []
+    a = (x, rw, w1, w3, w2)
+    for name, forward in (("kernel", stock_kernel),
+                          ("stock", gs.stock_grouped_swiglu)):
+        gs.grouped_swiglu = forward
+        # a function object of its own a variant: jit caches by it
+        ms_f, c_f, out = timed(lambda *v: layer(*v), *a)
+        held = int(out[1][1])
+        ms_fb, c_fb, _ = timed(jax.value_and_grad(
+            lambda *v: loss(*v), argnums=(0, 1, 2, 3, 4), has_aux=True), *a)
+        rows.append(dict(forward=name, held_pairs=held,
+                         max_group=int(out[1][3]), fwd_ms=ms_f,
+                         fwd_bwd_ms=ms_fb, compile_s=[c_f, c_fb]))
+    gs.grouped_swiglu = stock_kernel
+    ms_fb, c_fb, out = timed(jax.value_and_grad(
+        own_rules, argnums=(0, 1, 2, 3, 4), has_aux=True), *a)
+    rows.append(dict(forward="stock, jax's own rules, leading rows",
+                     held_pairs=int(jnp.sum(out[0][1])), fwd_bwd_ms=ms_fb,
+                     compile_s=[c_fb]))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/routed_train_bench.jsonl", "w") as fh:
+        for r in rows:
+            nine = 9 * r["held_pairs"] * 2 * h * f
+            r["nine_products_pct_of_peak"] = round(
+                100 * nine / PEAK / (r["fwd_bwd_ms"] / 1e3), 2)
+            r["device"] = jax.devices()[0].device_kind
+            print(json.dumps(r), flush=True)
+            fh.write(json.dumps(r) + "\n")
+
+
+if __name__ == "__main__":
+    main()

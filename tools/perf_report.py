@@ -458,7 +458,9 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.ssm_state_update_dispatches",
                "pallas.ssm_state_update_fallbacks",
                "pallas.grouped_swiglu_dispatches",
-               "pallas.grouped_swiglu_fallbacks") if cval(key)}
+               "pallas.grouped_swiglu_fallbacks",
+               "pallas.flash_window_dispatches",
+               "pallas.flash_window_fallbacks") if cval(key)}
     if pallas:
         out["pallas_kernels"] = pallas
     # content-addressed prefix store accounting (serving/prefix_store.py):
