@@ -20,7 +20,10 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     the diagonal masked (and attended in sub-blocks),
 * grouped_swiglu  — the routed layer's held experts over rows sorted by
                     expert: the hit experts' weight blocks streamed once,
-                    gate, up, silu(g) * u and down under each block.
+                    gate, up, silu(g) * u and down under each block;
+                    grouped_swiglu_bwd its backward for the trainer, two
+                    kernels over the same rows (the rows' gradients, then
+                    the three matrices').
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

@@ -9,6 +9,13 @@ ICI. Reverse AD flows through (all_to_all transposes to all_to_all).
 
 Outside an SPMD region every expert lives on the one device and the
 all_to_alls drop out — same math, no comm.
+
+`routed_experts_share` is one chip's share of a dropless top-k routed
+layer, served and (``trainable=True``) trained: the pairs sorted by held
+expert, the forward one grouped kernel over the sorted rows
+(ops/pallas/grouped_swiglu.py), the hand-written backward two
+(ops/pallas/grouped_swiglu_bwd.py: rows-side, weights-side); ragged
+products wherever a kernel cannot run, counted.
 """
 
 from __future__ import annotations
@@ -133,51 +140,35 @@ def _held_experts_bwd(few, res, dout):
     the forward ran over and as far as the held pairs reach: the gate and
     up products again (a sorted row's [F] float32 pair is not kept: 2 x
     470 MB a layer at 65,536 rows x 896), then the six products of the
-    gradients, each a ragged product over the groups. Nothing is dropped
-    at any imbalance: past `few` rows the chunks go on."""
+    gradients, as two grouped kernels
+    (``ops/pallas/grouped_swiglu_bwd.py``: rows-side and weights-side;
+    the gathers and the scatter-add around them are XLA's), or as eight
+    ragged products over the groups (`stock_grouped_swiglu_bwd`) where
+    ``kernel_mode()`` is off, the dtypes are mixed or the kernels cannot
+    tile the shape, counted. Nothing is dropped at any imbalance: past
+    `few` rows the chunks go on."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops.pallas.grouped_swiglu_bwd import grouped_swiglu_bwd
 
     x, w_sorted, w1, w3, w2, rows, sizes = res
     t, h = x.shape
     dt = w1.dtype
     f32 = jnp.float32
     pairs = rows.shape[0]
-    # rows by a group's TRANSPOSED matrix go through the plain ragged
-    # product over a transposed copy (66 MB a matrix at 16 x 2304 x 896):
-    # the ragged product that contracts the matrix's last axis instead
-    # came back 97% off at [rows, 2304] x [16, 896, 2304] over 20,480 rows
-    # and more on the chip, and right at 16,448 (my chip runs, PR 43)
-    w1_t, w3_t, w2_t = (jnp.swapaxes(w, 1, 2) for w in (w1, w3, w2))
-    dn_w = jax.lax.RaggedDotDimensionNumbers(       # a[group]^T @ b[group]
-        dot_dimension_numbers=(([0], [0]), ([], [])),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-
-    def rd(a, w, part, dn=None):
-        if dn is None:
-            return jax.lax.ragged_dot(a, w, part, preferred_element_type=f32)
-        return jax.lax.ragged_dot_general(a, w, part, dn,
-                                          preferred_element_type=f32)
 
     def back(r, w, part):
         """One run of sorted rows -> (dx scattered [T, H], dw [n], dW1,
         dW3, dW2 in float32)."""
-        mine = (w > 0)[:, None]
-        xs = x[r].astype(dt)
-        dy = jnp.where(mine, dout[r], 0.0)
-        gate, up = rd(xs, w1, part), rd(xs, w3, part)
-        sig = jax.nn.sigmoid(gate)
-        act = gate * sig
-        mid = act * up
-        dmid = rd(dy.astype(dt), w2_t, part)                # per unit weight
-        dw = jnp.sum(jnp.where(mine, dmid * mid, 0.0), axis=1)
-        dmid = dmid * w[:, None]
-        dgate = (dmid * up * (sig + act * (1.0 - sig))).astype(dt)
-        dup = (dmid * act).astype(dt)
-        dxs = rd(dgate, w1_t, part) + rd(dup, w3_t, part)
-        dx = jnp.zeros((t, h), f32).at[r].add(jnp.where(mine, dxs, 0.0))
-        return (dx, dw, rd(xs, dgate, part, dn_w), rd(xs, dup, part, dn_w),
-                rd(mid.astype(dt), (dy * w[:, None]).astype(dt), part, dn_w))
+        mine = w > 0
+        dy = jnp.where(mine[:, None], dout[r], 0.0)
+        dxs, dw, d1, d3, d2 = grouped_swiglu_bwd(
+            x[r].astype(dt), dy.astype(dt), (dy * w[:, None]).astype(dt), w,
+            w1, w3, w2, part)
+        dx = jnp.zeros((t, h), f32).at[r].add(
+            jnp.where(mine[:, None], dxs, 0.0))
+        return dx, jnp.where(mine, dw, 0.0), d1, d3, d2
 
     def every():
         pad = -pairs % few
@@ -311,7 +302,14 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     the held experts' three matrices (the selection is discrete and takes
     no gradient): the forward is the same, the held experts' part carries
     a hand-written backward over the same sorted rows
-    (``_held_experts_bwd``), dropless too.
+    (``_held_experts_bwd``), dropless too: two grouped kernels
+    (``ops/pallas/grouped_swiglu_bwd.py``: the rows' gradients with gate
+    and up made again, then the three matrices' gradients) between XLA's
+    gathers and scatter-add; where ``kernel_mode()`` is off, the dtypes
+    are mixed or the kernels cannot tile the shape (rows not a multiple
+    of the sublane tile, H or F not of 128, an expert's three matrices
+    and a row tile over the kernel's VMEM), eight ragged products,
+    counted (``pallas.grouped_swiglu_bwd_fallbacks``).
 
     Returns (out [T, H] float32, counts int32 [3]): the kept pairs of live
     rows, those of them on held experts, and the held experts with at

@@ -276,10 +276,13 @@ def test_flash_window_fwd_bwd_at_the_mellum_share(one_chip, tpu_mode,
 def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
     """16,384 tokens, top-8 of 64 by softmax, experts 0-15 held at 2304 x
     896, forward and the hand-written backward: the forward's grouped
-    kernel once (the backward's trace of it is dead code and gone), the
-    backward's products the chip's ragged-dot kernel in all three modes
-    (rows by group, rows by a group's transposed matrix, a group's rows
-    contracted)."""
+    kernel once a branch (the backward's trace of it is dead code and
+    gone), the backward's eight products the two `grouped_swiglu_bwd`
+    kernels a branch (40,960 rows in tiles of 512 with an expert's
+    three matrices whole in VMEM) and no ragged product. The instruction
+    names are the kernels' names, which `trace_reduce.op_family` prints
+    and `routed_experts_train_roofline` sums by their first letters."""
+    from benchmark.trace_reduce import op_family
     from paddle_tpu.parallel.moe import routed_experts_share
 
     def loss(x, wr, w1, w3, w2):
@@ -292,12 +295,13 @@ def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
                     ((16384, 2304), F32), ((2304, 64), BF16),
                     ((16, 2304, 896), BF16), ((16, 2304, 896), BF16),
                     ((16, 896, 2304), BF16))
-    assert "ragged-dot" in text and "f32[16,2304,896]" in text
-    # the leading rows' branch and the chunked one, of the forward only
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line
-             and "grouped_swiglu" in line]
-    assert len(calls) == 2
+    assert "ragged-dot" not in text and "f32[16,2304,896]" in text
+    # the leading rows' branch and the chunked one
+    calls = [op_family(line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == ["grouped_swiglu"] * 2 \
+        + ["grouped_swiglu_bwd_rows"] * 2 \
+        + ["grouped_swiglu_bwd_weights"] * 2
 
 
 def test_step_sampler_at_the_xglm_vocabulary(one_chip):

@@ -21,14 +21,14 @@ PARENT_SIGMOID_SHA256 = \
     "a733c5815b593c3b02f784d109116e6e24f5f2654e31c47ef20b33b88a03540b"
 
 
-def _weights(seed=1):
+def _weights(seed=1, h=H, f=F):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    return (jax.random.normal(ks[0], (T, H)),
-            jax.random.normal(ks[1], (H, E)) * 0.5,
-            jax.random.normal(ks[2], (EH, H, F)) * 0.2,
-            jax.random.normal(ks[3], (EH, H, F)) * 0.2,
-            jax.random.normal(ks[4], (EH, F, H)) * 0.2,
-            jax.random.normal(ks[5], (T, H)))
+    return (jax.random.normal(ks[0], (T, h)),
+            jax.random.normal(ks[1], (h, E)) * 0.5 * (H / h) ** 0.5,
+            jax.random.normal(ks[2], (EH, h, f)) * 0.2 * (H / h) ** 0.5,
+            jax.random.normal(ks[3], (EH, h, f)) * 0.2 * (H / h) ** 0.5,
+            jax.random.normal(ks[4], (EH, f, h)) * 0.2 * (F / f) ** 0.5,
+            jax.random.normal(ks[5], (T, h)))
 
 
 def dense(x, rw, w1, w3, w2, held_lo, score_func):
@@ -75,6 +75,40 @@ def test_gradients_against_a_dense_loop(score_func, branch, held_lo):
     for got, ref in zip(grads, want_vjp(co)):
         assert float(jnp.max(jnp.abs(got - ref))) \
             <= 2e-6 * float(jnp.max(jnp.abs(ref)))
+
+
+@pytest.mark.parametrize("branch", ["leading", "every"])
+def test_gradients_through_the_backward_kernels(monkeypatch, branch):
+    """The same dense-loop gradients at widths the kernels tile (128 x
+    128), in interpret mode: forward and backward kernels in the branch
+    the `cond` takes, none of the backward's ragged products."""
+    from paddle_tpu.core import telemetry
+
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    telemetry.reset()
+    x, rw, w1, w3, w2, co = _weights(seed=3, h=128, f=128)
+    if branch == "every":
+        x = jnp.abs(x) + 0.5
+        rw = jnp.where(jnp.arange(E)[None, :] < EH, jnp.abs(rw),
+                       -jnp.abs(rw))
+
+    def layer(x, rw, w1, w3, w2):
+        return routed_experts_share(
+            x, rw, jnp.zeros((E,)), w1, w3, w2, top_k=K, held_lo=0,
+            score_func="softmax", trainable=True)
+
+    (out, counts), vjp = jax.vjp(layer, x, rw, w1, w3, w2)
+    want, want_vjp = jax.vjp(
+        lambda *a: dense(*a, 0, "softmax"), x, rw, w1, w3, w2)
+    assert (int(counts[1]) > 128) == (branch == "every")
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    grads = vjp((co, np.zeros(counts.shape, jax.dtypes.float0)))
+    # one site a `cond` branch, at trace time
+    assert telemetry.counter_get("pallas.grouped_swiglu_bwd_dispatches") == 2
+    assert telemetry.counter_get("pallas.grouped_swiglu_bwd_fallbacks") == 0
+    for got, ref in zip(grads, want_vjp(co)):
+        assert float(jnp.max(jnp.abs(got - ref))) \
+            <= 1e-5 * float(jnp.max(jnp.abs(ref)))
 
 
 def test_the_trained_forward_is_the_served_forward():

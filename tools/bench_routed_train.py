@@ -2,11 +2,13 @@
 at the Mellum cell's shapes (16,384 tokens, hidden 2304, 16 of 64 experts
 of width 896 held, top-8, bfloat16): `routed_experts_share(trainable=True)`
 with its held experts' forward as the `grouped_swiglu` kernel and as the
-three stock `ragged_dot`s (the hand-written backward is the same), and the
-stock forward under JAX's own differentiation rules over the leading rows.
-ms a call, and % of the bf16 peak for the nine products of the held
-pairs. Lines in chiprun_out/routed_train_bench.jsonl (a call's file
-replaces the last one's). ~2 min.
+three stock `ragged_dot`s, its backward as the two `grouped_swiglu_bwd`
+kernels and as the eight ragged products, and the stock forward under
+JAX's own differentiation rules over the leading rows. ms a call, and %
+of the bf16 peak for the nine products of the held pairs. Then the two
+backward kernels alone over the layer's 40,960 leading rows beside the
+eight ragged products. Lines in chiprun_out/routed_train_bench.jsonl (a
+call's file replaces the last one's). ~3 min.
 
     python tools/bench_routed_train.py [--tokens 16384]
 """
@@ -30,7 +32,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.pallas import kernel_mode
     from paddle_tpu.ops.pallas import grouped_swiglu as gs
+    from paddle_tpu.ops.pallas import grouped_swiglu_bwd as gb
     from paddle_tpu.parallel import moe
 
     t, h, f, e, eh, k = args.tokens, 2304, 896, 64, 16, 8
@@ -85,12 +89,17 @@ def main():
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / args.iters * 1e3, first, out
 
-    stock_kernel = gs.grouped_swiglu
+    stock_kernel, bwd_kernels = gs.grouped_swiglu, gb.grouped_swiglu_bwd
     rows = []
     a = (x, rw, w1, w3, w2)
-    for name, forward in (("kernel", stock_kernel),
-                          ("stock", gs.stock_grouped_swiglu)):
-        gs.grouped_swiglu = forward
+
+    for name, forward, backward in (
+            ("kernel", stock_kernel, bwd_kernels),
+            ("kernel, ragged backward", stock_kernel,
+             gb.stock_grouped_swiglu_bwd),
+            ("stock, ragged backward", gs.stock_grouped_swiglu,
+             gb.stock_grouped_swiglu_bwd)):
+        gs.grouped_swiglu, gb.grouped_swiglu_bwd = forward, backward
         # a function object of its own a variant: jit caches by it
         ms_f, c_f, out = timed(lambda *v: layer(*v), *a)
         held = int(out[1][1])
@@ -99,18 +108,54 @@ def main():
         rows.append(dict(forward=name, held_pairs=held,
                          max_group=int(out[1][3]), fwd_ms=ms_f,
                          fwd_bwd_ms=ms_fb, compile_s=[c_f, c_fb]))
-    gs.grouped_swiglu = stock_kernel
+    gs.grouped_swiglu, gb.grouped_swiglu_bwd = stock_kernel, bwd_kernels
     ms_fb, c_fb, out = timed(jax.value_and_grad(
         own_rules, argnums=(0, 1, 2, 3, 4), has_aux=True), *a)
     rows.append(dict(forward="stock, jax's own rules, leading rows",
                      held_pairs=int(jnp.sum(out[0][1])), fwd_bwd_ms=ms_fb,
                      compile_s=[c_fb]))
+    for r in rows:
+        nine = 9 * r["held_pairs"] * 2 * h * f
+        r["nine_products_pct_of_peak"] = round(
+            100 * nine / PEAK / (r["fwd_bwd_ms"] / 1e3), 2)
+
+    # the backward's products alone, over the leading rows of the layer
+    n = int(-(-(1.25 * t * k * eh / e) // 4096) * 4096)
+    sizes = jnp.asarray(jax.random.randint(        # ~2,048 a group of 2,560
+        keys[0], (eh,), n * 5 // (8 * eh), n * 39 // (40 * eh)), jnp.int32)
+    held = int(jnp.sum(sizes))
+    w = jnp.where(jnp.arange(n) < held,
+                  jax.random.uniform(keys[1], (n,), minval=0.05), 0.0)
+    xs = jax.random.normal(keys[2], (n, h), jnp.float32).astype(bf)
+    dy = jnp.where((w > 0)[:, None],
+                   jax.random.normal(keys[5], (n, h), jnp.float32), 0.0)
+    ops = (xs, dy.astype(bf), (dy * w[:, None]).astype(bf), w, w1, w3, w2,
+           sizes)
+    ms, first, _ = timed(lambda *v: gb.stock_grouped_swiglu_bwd(*v), *ops)
+    rows.append(dict(piece="the eight ragged products", rows=n, held=held,
+                     ms=ms, compile_s=[first],
+                     pct_of_peak=100 * 8 * held * 2 * h * f / PEAK / ms * 1e3))
+    tile = gb._tile(n, h, f, bf)
+    interpret = kernel_mode() == "interpret"        # a rehearsal on the CPU
+    ms_r, c_r, hand = timed(
+        lambda xs, dy, w, w1, w3, w2, sizes: gb._pallas_bwd_rows(
+            xs, dy, w, w1, w3, w2, sizes, tile=tile, window=gb.WINDOW_ROWS,
+            interpret=interpret), *ops[:2], *ops[3:])
+    ms_w, c_w, _ = timed(
+        lambda *v: gb._pallas_bwd_weights(
+            *v, tile=tile, window=gb.WINDOW_ROWS_WEIGHTS,
+            interpret=interpret), xs, ops[2], *hand[2:], sizes)
+    rows.append(dict(
+        piece="the two backward kernels", rows=n, held=held, tile=tile,
+        rows_side=dict(window=gb.WINDOW_ROWS, ms=ms_r, pct_of_peak=100 * 5
+                       * held * 2 * h * f / PEAK / ms_r * 1e3),
+        weights_side=dict(window=gb.WINDOW_ROWS_WEIGHTS, ms=ms_w,
+                          pct_of_peak=100 * 3 * held * 2 * h * f / PEAK
+                          / ms_w * 1e3),
+        compile_s=[c_r, c_w]))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/routed_train_bench.jsonl", "w") as fh:
         for r in rows:
-            nine = 9 * r["held_pairs"] * 2 * h * f
-            r["nine_products_pct_of_peak"] = round(
-                100 * nine / PEAK / (r["fwd_bwd_ms"] / 1e3), 2)
             r["device"] = jax.devices()[0].device_kind
             print(json.dumps(r), flush=True)
             fh.write(json.dumps(r) + "\n")

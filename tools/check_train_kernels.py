@@ -1,6 +1,13 @@
 """On the chip: the pieces of the trained routed layer and the window
-flash kernels against dense forms, and the time of each ragged product.
-Prints one JSON line a piece."""
+flash kernels against dense forms, and the time of each ragged product;
+the two `grouped_swiglu_bwd` kernels against a dense loop over the
+experts, their times beside the eight ragged products'; the whole trained
+layer (forward + backward inside `jax.grad`, under its `cond`) against a
+dense loop. Prints one JSON line a piece.
+
+    python tools/check_train_kernels.py [rows H F experts lo hi seq_len]
+    (CHECK_BATCH=2 ... 40960 2304 896 16 1600 2500 8192: the Mellum
+    cell's 40,960 leading rows and 16,384 tokens)"""
 import json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax, jax.numpy as jnp, numpy as np
@@ -75,6 +82,46 @@ def main():
         ms, _ = timed(fn)
         print(json.dumps({"piece": "dn_w with sizes summing to every row", "ms": round(ms, 3)}), flush=True)
 
+    # the backward's two kernels against a dense loop over the experts
+    # with the same rounding points, beside the eight ragged products
+    from paddle_tpu.ops.pallas import grouped_swiglu_bwd as gb
+    w3 = (jax.random.normal(ks[5], (e, h, f)) * h ** -0.5).astype(bf)
+    wt = jnp.where(inside[:, 0], jax.random.uniform(ks[6], (n,), minval=0.05), 0.0)
+    dy32 = jnp.where(inside, dy.astype(f32), 0.0)
+    ops = (xs, dy32.astype(bf), (dy32 * wt[:, None]).astype(bf), wt, w1, w3, w2, sizes)
+
+    def dense_bwd(xs, dy, dyw, wt, w1, w3, w2, sizes):
+        def dot(a, b, dims=(((1,), (0,)), ((), ()))):
+            return jax.lax.dot_general(a, b, dims, preferred_element_type=f32)
+        nt, tn = (((1,), (1,)), ((), ())), (((0,), (0,)), ((), ()))
+        dxs, dw, d1, d3, d2 = jnp.zeros((n, h), f32), jnp.zeros((n,), f32), [], [], []
+        for i in range(e):
+            m = (gid == i)[:, None] & inside
+            gate, up = dot(xs, w1[i]), dot(xs, w3[i])
+            sig = jax.nn.sigmoid(gate)
+            act = gate * sig
+            mid = act * up
+            dmid = dot(dy, w2[i], nt)
+            dw = dw + jnp.where(m[:, 0], jnp.sum(dmid * mid, axis=1), 0.0)
+            dmid = dmid * wt[:, None]
+            dgate = (dmid * up * (sig + act * (1.0 - sig))).astype(bf)
+            dup = (dmid * act).astype(bf)
+            dxs = dxs + jnp.where(m, dot(dgate, w1[i], nt) + dot(dup, w3[i], nt), 0.0)
+            xm = jnp.where(m, xs, 0)
+            d1.append(dot(xm, dgate, tn)); d3.append(dot(xm, dup, tn))
+            d2.append(dot(jnp.where(m, mid, 0).astype(bf), dyw, tn))
+        return dxs, dw, jnp.stack(d1), jnp.stack(d3), jnp.stack(d2)
+
+    def held_rows(out):
+        return (jnp.where(inside, out[0], 0.0), jnp.where(inside[:, 0], out[1], 0.0)) + tuple(out[2:])
+
+    want = jax.jit(dense_bwd)(*ops)
+    for name, fn in (("the two grouped_swiglu_bwd kernels", jax.jit(lambda *a: held_rows(gb.grouped_swiglu_bwd(*a)))),
+                     ("the eight ragged products", jax.jit(lambda *a: held_rows(gb.stock_grouped_swiglu_bwd(*a))))):
+        ms, got = timed(fn, *ops)
+        print(json.dumps({"piece": name, "ms": round(ms, 3), "pct_of_peak": round(100 * 8 * 2 * total * h * f / 197e12 / (ms / 1e3), 1), "rel_err dxs dw dW1 dW3 dW2": [rel(a, c) for a, c in zip(got, want)], "finite": all(bool(jnp.isfinite(a).all()) for a in got), "held_rows": total, "rows": n}), flush=True)
+    del want, got
+
     from paddle_tpu.ops.pallas import flash_window as fw
     b, s, hq, hd = int(os.environ.get("CHECK_BATCH", 1)), s_len, 8, 128
     q = jax.random.normal(ks[5], (b, s, hq * hd), f32).astype(bf)
@@ -118,9 +165,13 @@ def main():
             out = out + mine[:, None] * jnp.dot(mid.astype(bf), a2[i], preferred_element_type=f32)
         return jnp.sum(out * co)
 
-    got = jax.jit(jax.value_and_grad(layer, (0, 1, 2, 3, 4)))(x, rw, a1, a3, a2)
-    want = jax.jit(jax.value_and_grad(dense, (0, 1, 2, 3, 4)))(x, rw, a1, a3, a2)
-    print(json.dumps({"piece": f"routed layer, {t} tokens", "value": [float(got[0]), float(want[0])], "rel_err dx drouter dw1 dw3 dw2": [rel(a, c) for a, c in zip(got[1], want[1])]}), flush=True)
+    # as routed, the leading rows hold the held pairs; with every token's
+    # top 8 among the 16 held experts the chunks past them run (`every`)
+    skew = jnp.where(jnp.arange(ne)[None, :] < e, jnp.abs(rw), -jnp.abs(rw))
+    for name, xx, router in (("leading rows", x, rw), ("every chunk", jnp.abs(x) + 0.5, skew)):
+        got = jax.jit(jax.value_and_grad(layer, (0, 1, 2, 3, 4)))(xx, router, a1, a3, a2)
+        want = jax.jit(jax.value_and_grad(dense, (0, 1, 2, 3, 4)))(xx, router, a1, a3, a2)
+        print(json.dumps({"piece": f"routed layer, {t} tokens, {name}", "value": [float(got[0]), float(want[0])], "rel_err dx drouter dw1 dw3 dw2": [rel(a, c) for a, c in zip(got[1], want[1])]}), flush=True)
 
 
 if __name__ == "__main__":
