@@ -255,27 +255,18 @@ class _Block(Block):
             return x
         return _op("scale", {"X": x}, {"Out": None}, {"scale": float(by)})
 
-    def _arrays(self, names, shapes, dtypes):
-        """Fed pool or state arrays and the names they are written back
-        under."""
-        ins = [layers.static_data(n, list(s), d)
-               for n, s, d in zip(names, shapes, dtypes)]
-        outs = [_named_out(n + "_out", d) for n, d in zip(names, dtypes)]
-        self.pool_outs += [o.name for o in outs]
-        return ins, outs
-
     def pools(self, i):
         """(PoolK, PoolV), (PoolKOut, PoolVOut) of layer i."""
         cfg, pool = self.cfg, self.kv.context
         shape = [pool.num_pages, pool.page_size,
                  cfg.num_kv_heads * cfg.head_dim]
-        return self._arrays(pool_array_names(i, False), [shape, shape],
+        return self.arrays(pool_array_names(i, False), [shape, shape],
                             [cfg.dtype, cfg.dtype])
 
     def states(self, i):
         """(State, ConvTail), (StateOut, ConvTailOut) of layer i."""
         cfg, slots = self.cfg, self.kv.state_slots
-        return self._arrays(
+        return self.arrays(
             state_array_names(i),
             [[slots, cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_head],
              [slots, cfg.mamba_d_conv - 1, cfg.conv_dim]],
@@ -370,9 +361,9 @@ class FalconH1Served(ServedModel):
         cfg = self.cfg
         return [LayerCache(
             cfg.num_kv_heads * cfg.head_dim,
-            ssm_state=(cfg.mamba_n_heads, cfg.mamba_d_head,
-                       cfg.mamba_d_state),
-            conv_tail=(cfg.conv_dim, cfg.mamba_d_conv - 1),
+            ssm_state=(cfg.mamba_n_heads, cfg.mamba_d_state,
+                       cfg.mamba_d_head),
+            conv_tail=(cfg.mamba_d_conv - 1, cfg.conv_dim),
             state_dtype=cfg.ssm_state_dtype) for _ in range(cfg.n_layers)]
 
     def _table(self, kv, batch):

@@ -45,6 +45,15 @@ class Block:
         shape, _kind, dtype = self.specs[name]
         return layers.static_data(name, list(shape), dtype)
 
+    def arrays(self, names, shapes, dtypes):
+        """Fed pool or state arrays and the variables they are written back
+        under (``<name>_out``, which join `pool_outs`)."""
+        ins = [layers.static_data(n, list(s), d)
+               for n, s, d in zip(names, shapes, dtypes)]
+        outs = [named_out(n + "_out", d) for n, d in zip(names, dtypes)]
+        self.pool_outs += [o.name for o in outs]
+        return ins, outs
+
     def norm(self, x, name):
         return op("rms_norm", {"X": x, "Scale": self.param(name)},
                   {"Y": None}, {"epsilon": self.cfg.rms_norm_eps})

@@ -1,0 +1,258 @@
+"""Ops of a Gated-DeltaNet layer behind the decode engine
+(models/qwen3_next.py): the gated delta rule in two forms over the same
+per-SLOT matrix state (serving/kv_cache.py: a state and a conv tail a slot,
+not a token), the by-key-head split of the two input projections, the
+per-head gated RMS norm, and the split of a projection that gives each head
+two vectors. The causal convolution before the rule is ops/ssm_ops.py's
+(`ssm_conv_update` / `ssm_conv_prefill`, without a bias).
+
+The rule, a value head, in float32 (q, k [K] l2-normed, q scaled by
+K^-0.5; v [V]; S [K, V]; value heads ``r i .. r i + r - 1`` read key head
+``i``):
+
+    beta_t = sigmoid(b_t)     g_t = -exp(A_log) softplus(a_t + dt_bias)
+    S = exp(g_t) S;   u = k_t^T S;   S = S + k_t (x) (beta_t (v_t - u))
+    o_t = q_t^T S
+
+* decode step: `gated_delta_state_update` advances a row's state by one
+  token IN PLACE at the row's slot (``Slots``; a padding row names the
+  scratch slot, the arrays' last): ops/pallas/gated_delta_state_update.py
+  on the chip; its stock lowering is the kernel's oracle and counted
+  fallback.
+* whole prompt: `gated_delta_chunk_scan`, the chunked (WY) form: inside a
+  chunk of C tokens the rule's C rank-one corrections are solved at once
+  (a unit lower-triangular system, by forward substitution), a chunk's
+  state handed to the next; it WRITES the slot's state, overwriting what
+  its last owner left. Past the prompt's length ``g = 0`` and ``beta = 0``:
+  a padded bucket's tail neither decays nor feeds the state.
+
+State arrays: ``ssm_state_<l>`` [slots + 1, value heads, K, V] float32 (K
+on sublanes, V on lanes: v, u and o are lane rows as the projections leave
+them) and ``conv_tail_<l>`` [slots + 1, d_conv - 1, 2 x key dim + value
+dim] in the model's dtype (q | k | v, each head by head).
+"""
+
+from __future__ import annotations
+
+from ..core import telemetry
+from ..core.registry import register_op
+
+L2_EPS = 1e-6       # the family's l2norm: x * rsqrt(sum(x^2) + 1e-6)
+_GDN_ATTRS = ("key_heads", "key_dim", "value_heads", "value_dim")
+
+
+def _gdn_sizes(attrs):
+    return tuple(int(attrs[name]) for name in _GDN_ATTRS)
+
+
+@register_op("gdn_split", required_attrs=_GDN_ATTRS)
+def gdn_split_op(ins, attrs):
+    """The two input projections of a Gated-DeltaNet layer, laid out BY KEY
+    HEAD as published: of QKVZ each key head holds its q (key_dim), its k
+    (key_dim), its value heads' v (r x value_dim) and z (r x value_dim); of
+    BA its value heads' b (r) and a (r); r = value_heads / key_heads.
+    -> QKV [..., 2 x key_heads x key_dim + value_heads x value_dim]: q of
+    every head, then k, then v (the convolution's channels); Z [...,
+    value_heads x value_dim]; B and A [..., value_heads]."""
+    import jax.numpy as jnp
+
+    nk, dk, nv, dv = _gdn_sizes(attrs)
+    r = nv // nk
+    qkvz, ba = ins["QKVZ"][0], ins["BA"][0]
+    lead = qkvz.shape[:-1]
+    by_head = qkvz.reshape(lead + (nk, 2 * dk + 2 * r * dv))
+    q, k = by_head[..., :dk], by_head[..., dk:2 * dk]
+    v = by_head[..., 2 * dk:2 * dk + r * dv]
+    z = by_head[..., 2 * dk + r * dv:]
+    ba = ba.reshape(lead + (nk, 2 * r))
+    flat = lead + (-1,)
+    return {"QKV": jnp.concatenate([q.reshape(flat), k.reshape(flat),
+                                    v.reshape(flat)], axis=-1),
+            "Z": z.reshape(flat), "B": ba[..., :r].reshape(flat),
+            "A": ba[..., r:].reshape(flat)}
+
+
+@register_op("split_head_pairs", required_attrs=("head_dim",))
+def split_head_pairs_op(ins, attrs):
+    """X [..., n x 2 x head_dim], each head two vectors side by side (a
+    query and its output gate) -> First, Second [..., n x head_dim]."""
+    hd = int(attrs["head_dim"])
+    x = ins["X"][0]
+    pairs = x.reshape(x.shape[:-1] + (-1, 2 * hd))
+    flat = x.shape[:-1] + (-1,)
+    return {"First": pairs[..., :hd].reshape(flat),
+            "Second": pairs[..., hd:].reshape(flat)}
+
+
+def _l2norm(x):
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                             + L2_EPS)
+
+
+def delta_rule_terms(q, k, v, a, b, a_log, dt_bias, nk, dk, nv, dv):
+    """What the rule multiplies, position and value head, from the
+    convolution's outputs and the raw gates: q, k [..., nk x dk], v [...,
+    nv x dv], a and b [..., nv] -> (q [..., nv, dk] l2-normed and scaled,
+    k [..., nv, dk] l2-normed, v [..., nv, dv], g [..., nv] (log decay, <=
+    0), beta [..., nv]), float32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    lead = q.shape[:-1]
+    r = nv // nk
+    qh = _l2norm(q.astype(f32).reshape(lead + (nk, dk))) * dk ** -0.5
+    kh = _l2norm(k.astype(f32).reshape(lead + (nk, dk)))
+    g = -jnp.exp(a_log.astype(f32)) \
+        * jax.nn.softplus(a.astype(f32) + dt_bias.astype(f32))
+    return (jnp.repeat(qh, r, axis=-2), jnp.repeat(kh, r, axis=-2),
+            v.astype(f32).reshape(lead + (nv, dv)), g,
+            jax.nn.sigmoid(b.astype(f32)))
+
+
+@register_op("gated_delta_state_update", required_attrs=_GDN_ATTRS)
+def gated_delta_state_update_op(ins, attrs):
+    """One token of the gated delta rule a row, the row's state read and
+    written once, in place, at its slot. Q, K [B, nk x dk], V [B, nv x dv]
+    (the convolution's outputs), A, B [B, nv] (raw), ALog, DtBias [nv],
+    State [slots + 1, nv, dk, dv] float32, Slots [B] int32 -> Y [B, nv x
+    dv], StateOut. The kernel under the PT_PALLAS dispatch
+    (ops/pallas/gated_delta_state_update.py); 'off' and untileable shapes
+    take the counted stock lowering."""
+    import jax.numpy as jnp
+
+    from .pallas.gated_delta_state_update import gated_delta_state_update
+
+    nk, dk, nv, dv = _gdn_sizes(attrs)
+    q, k, v, g, beta = delta_rule_terms(
+        ins["Q"][0], ins["K"][0], ins["V"][0], ins["A"][0], ins["B"][0],
+        ins["ALog"][0], ins["DtBias"][0], nk, dk, nv, dv)
+    y, state = gated_delta_state_update(
+        ins["State"][0], ins["Slots"][0].reshape(-1).astype(jnp.int32),
+        q, k, v, jnp.exp(g), beta, heads_per_key=nv // nk)
+    return {"Y": y.reshape(y.shape[0], nv * dv), "StateOut": state}
+
+
+def chunk_delta_rule(q, k, v, g, beta, chunk):
+    """The chunked (WY) form of the rule from a zero state. q, k [B, S, H,
+    K], v [B, S, H, V], g and beta [B, S, H] (both 0 where a position is
+    padding) -> (o [B, S, H, V], the state after the last position [B, H,
+    K, V]). Float32 at 'highest'.
+
+    Inside a chunk, with G the running sum of g, D[l, s] = exp(G_l - G_s)
+    for s <= l, and N = strictly lower (beta k k^T * D): the C corrected
+    values are T (beta v) and the keys that read the carried state
+    T (beta k exp(G)), T = (I + N)^-1, a unit lower-triangular inverse made
+    row by row (forward substitution: stable whatever the keys' overlap,
+    where the nilpotent series is not)."""
+    import jax
+    import jax.numpy as jnp
+
+    hi = jax.lax.Precision.HIGHEST
+    b, s, h, kd = q.shape
+    vd = v.shape[-1]
+    ln = min(int(chunk), s)
+    if s % ln:
+        raise ValueError(f"prompt length {s} is no multiple of the chunk "
+                         f"{ln}")
+    nc = s // ln
+
+    def chunks(x):          # [B, S, H, ...] -> [B, H, nc, ln, ...]
+        x = x.reshape((b, nc, ln) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    qc, kc, vc = chunks(q), chunks(k), chunks(v)
+    gc = jnp.cumsum(chunks(g), axis=-1)              # [B, H, nc, ln], <= 0
+    bc = chunks(beta)
+    lower = jnp.tril(jnp.ones((ln, ln), bool))
+    seg = gc[..., :, None] - gc[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
+    k_beta = kc * bc[..., None]
+    n = jnp.einsum("bhclk,bhcsk->bhcls", k_beta, kc, precision=hi) * decay
+    n = jnp.where(jnp.tril(jnp.ones((ln, ln), bool), -1), n, 0.0)
+
+    def solve_row(i, t):
+        # rows < i of T are final; N[i, j >= i] is 0
+        row = jax.lax.dynamic_slice_in_dim(n, i, 1, axis=-2)
+        new = jnp.einsum("bhcls,bhcsj->bhclj", row, t, precision=hi)
+        old = jax.lax.dynamic_slice_in_dim(t, i, 1, axis=-2)
+        return jax.lax.dynamic_update_slice_in_dim(t, old - new, i, axis=-2)
+
+    eye = jnp.broadcast_to(jnp.eye(ln, dtype=jnp.float32), n.shape)
+    t = jax.lax.fori_loop(1, ln, solve_row, eye)
+    u = jnp.einsum("bhcls,bhcsv->bhclv", t, vc * bc[..., None],
+                   precision=hi)
+    w = jnp.einsum("bhcls,bhcsk->bhclk", t,
+                   k_beta * jnp.exp(gc)[..., None], precision=hi)
+    qk = jnp.einsum("bhclk,bhcsk->bhcls", qc, kc, precision=hi) * decay
+    q_in = qc * jnp.exp(gc)[..., None]               # reads the carried state
+    k_out = kc * jnp.exp(gc[..., -1:] - gc)[..., None]   # decays to the end
+    whole = jnp.exp(gc[..., -1])                     # [B, H, nc]
+
+    def carry(state, c):
+        u_c, w_c, qk_c, q_c, k_c, whole_c = c
+        v_new = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, state,
+                                 precision=hi)
+        o_c = jnp.einsum("bhlk,bhkv->bhlv", q_c, state, precision=hi) \
+            + jnp.einsum("bhls,bhsv->bhlv", qk_c, v_new, precision=hi)
+        state = state * whole_c[..., None, None] \
+            + jnp.einsum("bhlk,bhlv->bhkv", k_c, v_new, precision=hi)
+        return state, o_c
+
+    last, o = jax.lax.scan(
+        carry, jnp.zeros((b, h, kd, vd), jnp.float32),
+        tuple(jnp.moveaxis(x, 2, 0) for x in (u, w, qk, q_in, k_out, whole)))
+    # [nc, B, H, ln, V] -> [B, S, H, V]
+    o = jnp.moveaxis(o, 0, 2)
+    return jnp.moveaxis(o, 1, 3).reshape(b, s, h, vd), last
+
+
+@register_op("gated_delta_chunk_scan",
+             required_attrs=_GDN_ATTRS + ("chunk",))
+def gated_delta_chunk_scan_op(ins, attrs):
+    """A whole (padded) prompt through the gated delta rule in chunks of
+    `chunk`, from a zero state, and the slot's state WRITTEN with the state
+    after the prompt's last real token (``g = 0`` and ``beta = 0`` past
+    ``Lengths``). Q, K [B, S, nk x dk], V [B, S, nv x dv], A, B [B, S, nv]
+    raw, Lengths [B], Slots [B] -> Y [B, S, nv x dv], StateOut."""
+    import jax.numpy as jnp
+
+    # trace-time, as the kernels' dispatch counters are: the scan is XLA
+    # products, so there is no fallback to count beside it
+    telemetry.counter_add("ops.gated_delta_chunk_scan_dispatches", 1)
+    nk, dk, nv, dv = _gdn_sizes(attrs)
+    q, k, v, g, beta = delta_rule_terms(
+        ins["Q"][0], ins["K"][0], ins["V"][0], ins["A"][0], ins["B"][0],
+        ins["ALog"][0], ins["DtBias"][0], nk, dk, nv, dv)
+    b, s = g.shape[0], g.shape[1]
+    pool = ins["State"][0]
+    slots = ins["Slots"][0].reshape(-1).astype(jnp.int32)
+    lengths = ins["Lengths"][0].reshape(-1).astype(jnp.int32)
+    real = (jnp.arange(s, dtype=jnp.int32)[None, :]
+            < lengths[:, None])[..., None]
+    y, last = chunk_delta_rule(q, k, v, jnp.where(real, g, 0.0),
+                               jnp.where(real, beta, 0.0),
+                               int(attrs["chunk"]))
+    return {"Y": y.reshape(b, s, nv * dv),
+            "StateOut": pool.at[slots].set(last.astype(pool.dtype))}
+
+
+@register_op("gated_head_rms_norm", required_attrs=("head_dim",))
+def gated_head_rms_norm_op(ins, attrs):
+    """Y = Scale * RMSNorm_head(X) * silu(Gate): each `head_dim` of the
+    last axis normed alone (the norm first, then the gate), one gain
+    [head_dim] for every head, applied as it is stored; float32."""
+    import jax
+    import jax.numpy as jnp
+
+    hd = int(attrs["head_dim"])
+    x = ins["X"][0].astype(jnp.float32)
+    heads = x.reshape(x.shape[:-1] + (-1, hd))
+    ms = jnp.mean(jnp.square(heads), axis=-1, keepdims=True)
+    heads = heads * jax.lax.rsqrt(ms + float(attrs.get("epsilon", 1e-6))) \
+        * ins["Scale"][0].astype(jnp.float32)
+    return {"Y": heads.reshape(x.shape)
+            * jax.nn.silu(ins["Gate"][0].astype(jnp.float32))}

@@ -21,12 +21,16 @@ from ..core.registry import register_grad_maker, register_op
 
 @register_op("rms_norm")
 def rms_norm_op(ins, attrs):
-    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale, in float32."""
+    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * (scale_offset +
+    Scale), in float32. Attr `scale_offset` (0 by default) is 1 for a gain
+    stored around zero."""
     import jax
     import jax.numpy as jnp
 
     x = ins["X"][0].astype(jnp.float32)
     scale = ins["Scale"][0].astype(jnp.float32)
+    if attrs.get("scale_offset"):
+        scale = scale + float(attrs["scale_offset"])
     ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return {"Y": x * jax.lax.rsqrt(ms + float(attrs.get("epsilon", 1e-5)))
             * scale}
@@ -111,7 +115,12 @@ def qk_norm_rope_op(ins, attrs):
     x1 sin) with angle pos * theta^(-2i/head_dim). With attr
     `yarn_factor` over 1 the frequencies are YaRN's (`yarn_inv_freq` from
     `theta`, `yarn_original_max`, `yarn_beta_fast`, `yarn_beta_slow`), and
-    cos and sin are multiplied by attr `attention_factor`.
+    cos and sin are multiplied by attr `attention_factor`. Attr
+    `rotary_dim` (the whole head by default) turns the FIRST that many
+    dimensions of a head alone, pairs (i, i + rotary_dim / 2) at
+    theta^(-2i/rotary_dim), and leaves the rest as the norm gave them;
+    attr `scale_offset` (0 by default) is added to both gains (1: gains
+    stored around zero).
 
     Q [..., nq*hd], K [..., nkv*hd] float32; QScale, KScale [hd];
     Positions int32, shaped like Q without its last axis; left out, a
@@ -130,30 +139,35 @@ def qk_norm_rope_op(ins, attrs):
                                q.shape[:-1])
     yarn = float(attrs.get("yarn_factor", 1.0))
     mscale = float(attrs.get("attention_factor", 1.0))
+    rd = int(attrs.get("rotary_dim", hd))
+    offset = float(attrs.get("scale_offset", 0.0))
 
     def one(x, scale):
         lead = x.shape[:-1]
         xh = x.astype(jnp.float32).reshape(lead + (-1, hd))
         ms = jnp.mean(jnp.square(xh), axis=-1, keepdims=True)
-        xh = xh * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
+        gain = scale.astype(jnp.float32)
+        xh = xh * jax.lax.rsqrt(ms + eps) * (gain + offset if offset
+                                             else gain)
         if attrs.get("rope"):
-            half = hd // 2
+            half = rd // 2
             theta = float(attrs.get("theta", 10000.0))
             if yarn > 1.0:
                 inv = jnp.asarray(yarn_inv_freq(
-                    hd, theta, yarn, int(attrs["yarn_original_max"]),
+                    rd, theta, yarn, int(attrs["yarn_original_max"]),
                     float(attrs.get("yarn_beta_fast", 32.0)),
                     float(attrs.get("yarn_beta_slow", 1.0))))
             else:
                 inv = theta ** (-jnp.arange(half, dtype=jnp.float32)
-                                * 2.0 / hd)
+                                * 2.0 / rd)
             ang = pos.astype(jnp.float32)[..., None, None] * inv
             cos, sin = jnp.cos(ang), jnp.sin(ang)
             if mscale != 1.0:
                 cos, sin = cos * mscale, sin * mscale
-            x1, x2 = xh[..., :half], xh[..., half:]
-            xh = jnp.concatenate([x1 * cos - x2 * sin,
-                                  x2 * cos + x1 * sin], axis=-1)
+            x1, x2 = xh[..., :half], xh[..., half:rd]
+            xh = jnp.concatenate(
+                [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+                + ([xh[..., rd:]] if rd < hd else []), axis=-1)
         return xh.reshape(x.shape)
 
     return {"QOut": one(ins["Q"][0], ins["QScale"][0]),

@@ -39,20 +39,26 @@ def routed_experts_op(ins, attrs):
     "softmax" over all experts; attr `trainable` gives the held experts'
     part its backward and Counts a fourth entry, the largest group's
     rows): X [..., H] float32, RouterW
-    [H, E] over ALL experts, SelectBias [E], W1/W3 [E_held, H, F] and W2
-    [E_held, F, H] of the experts `held_lo` .. held here, optional Live
+    [H, E] over ALL experts, SelectBias [E] (optional: a router without
+    one), W1/W3 [E_held, H, F] and W2 [E_held, F, H] of the experts `held_lo` .. held here, optional Live
     bool, one a row of X (the rows that carry a token; the rest join no
     group). The held experts' SwiGLUs run as one grouped kernel over the
     pairs sorted by expert (ops/pallas/grouped_swiglu.py; three
     ragged_dots where kernel_mode() is off). Out like X, float32; Counts
     int32 [3] (live pairs, those on held experts, held experts hit);
     Chosen int32 [..., top_k], the experts each row chose."""
+    import jax.numpy as jnp
+
     from ..parallel.moe import routed_experts_share
 
     live = ins["Live"][0] if ins.get("Live") else None
     x = ins["X"][0]
+    router_w = ins["RouterW"][0]
+    # a router without a selection bias selects by its scores alone
+    bias = ins["SelectBias"][0] if ins.get("SelectBias") \
+        else jnp.zeros((router_w.shape[1],), jnp.float32)
     out, counts, chosen = routed_experts_share(
-        x.reshape(-1, x.shape[-1]), ins["RouterW"][0], ins["SelectBias"][0],
+        x.reshape(-1, x.shape[-1]), router_w, bias,
         ins["W1"][0], ins["W3"][0], ins["W2"][0],
         top_k=int(attrs["top_k"]), held_lo=int(attrs["held_lo"]),
         route_scale=float(attrs.get("route_scale", 1.0)),

@@ -36,6 +36,13 @@ from __future__ import annotations
 from ..core.registry import register_op
 
 
+def _bias(ins):
+    """The convolution's bias, or 0 for a layer without one."""
+    import jax.numpy as jnp
+
+    return ins["Bias"][0].astype(jnp.float32) if ins.get("Bias") else 0.0
+
+
 def _split_xbc(y, attrs):
     """The convolution's channels: x, then B and C of every group."""
     d_ssm = int(attrs["n_heads"]) * int(attrs["head_dim"])
@@ -62,7 +69,10 @@ def ssm_conv_update_op(ins, attrs):
     tail (the last ``K - 1`` inputs, at its slot) and this input give
     ``silu(sum_k W[k] window[k] + Bias)``, split X | B | C; the tail shifts
     by one, in place. XBC [B, conv_dim] float32, ConvTail [slots + 1,
-    K - 1, conv_dim], Slots [B] int32, W [K, conv_dim], Bias [conv_dim]."""
+    K - 1, conv_dim], Slots [B] int32, W [K, conv_dim], Bias [conv_dim]
+    (optional: a convolution without one). The three parts are whatever
+    the attrs' widths cut the channels into (a Gated-DeltaNet layer's
+    q | k | v: `n_heads` x `head_dim` of q, `n_groups` x `d_state` of k)."""
     import jax
     import jax.numpy as jnp
 
@@ -71,8 +81,7 @@ def ssm_conv_update_op(ins, attrs):
     w = ins["W"][0].astype(jnp.float32)
     win = jnp.concatenate([pool[slots].astype(jnp.float32),
                            xbc[:, None, :]], axis=1)          # [B, K, C]
-    y = jax.nn.silu(jnp.sum(win * w[None], axis=1)
-                    + ins["Bias"][0].astype(jnp.float32))
+    y = jax.nn.silu(jnp.sum(win * w[None], axis=1) + _bias(ins))
     out = _split_xbc(y, attrs)
     out["ConvTailOut"] = pool.at[slots].set(win[:, 1:].astype(pool.dtype))
     return out
@@ -94,7 +103,7 @@ def ssm_conv_prefill_op(ins, attrs):
     k, s = w.shape[0], xbc.shape[1]
     xp = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
     y = sum(w[j] * xp[:, j:j + s] for j in range(k))
-    y = jax.nn.silu(y + ins["Bias"][0].astype(jnp.float32))
+    y = jax.nn.silu(y + _bias(ins))
     # token t sits at xp[t + K - 1]: the last K - 1 real ones start at L
     idx = lengths[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
     tail = jnp.take_along_axis(xp, idx[:, :, None], axis=1)

@@ -320,7 +320,8 @@ class GenerationRequest(InferenceRequest):
                  "pages", "table_row", "pos_next", "last_token",
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
                  "stop_at_eos", "ring_pages", "ring_row", "first_logits",
-                 "slot", "carried", "ahead", "_rng_cut", "final_state", "rid")
+                 "slot", "carried", "ahead", "_rng_cut", "final_state",
+                 "final_pages", "rid")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -365,6 +366,10 @@ class GenerationRequest(InferenceRequest):
         # False, or True to keep the slot's per-slot state arrays as they
         # stand when the request retires (a model with recurrent state)
         self.final_state: Any = False
+        # False, or True to keep the request's own pages of the context
+        # class as they stand when it retires: how a check compares what
+        # the cache holds, not only what was computed from it
+        self.final_pages: Any = False
         # the position and, for a request's first step, the token its next
         # step is fed; ``pos_next`` moves on when a step is DISPATCHED
         self.pos_next = 0
@@ -587,7 +592,8 @@ class DecodeEngine:
                prior_tokens: Optional[Sequence[int]] = None,
                rng_state: Optional[Any] = None,
                keep_first_logits: bool = False,
-               keep_final_state: bool = False) -> GenerationRequest:
+               keep_final_state: bool = False,
+               keep_final_pages: bool = False) -> GenerationRequest:
         """Enqueue one generation (non-blocking). ``prompt`` is a 1-D
         int token-id array. Raises ValueError (malformed / over the
         model length), KVCacheExhaustedError (can never fit the KV
@@ -604,7 +610,10 @@ class DecodeEngine:
         ``keep_final_state`` leaves its slot's per-slot state arrays as they
         stand when it retires (``final_state``: name -> the slot's entry;
         for a request that ends on its count, the state after its last FED
-        token, the one before its last chosen one)."""
+        token, the one before its last chosen one); ``keep_final_pages``
+        leaves its own pages of the context class likewise
+        (``final_pages``: pool array name -> [its pages, page, kv_dim] in
+        the order of its page table, token t at ``[t // page, t % page]``)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -631,6 +640,7 @@ class DecodeEngine:
             session_id=request_id, prior=prior)
         req.first_logits = bool(keep_first_logits)
         req.final_state = bool(keep_final_state)
+        req.final_pages = bool(keep_final_pages)
         if rng_state is not None:
             from .session import unpack_rng_state
 
@@ -1620,6 +1630,11 @@ class DecodeEngine:
             req.final_state = {
                 n: self._pools[n][req.slot] for i in self.kv.state_layers
                 for n in state_array_names(i)}
+        if req.final_pages is True:
+            # a device gather of its own pages, queued like the state's
+            pages = np.asarray(req.pages, np.int32)
+            req.final_pages = {n: self._pools[n][pages]
+                               for n in self.pool.array_names()}
         self._release_slot(req)
         # A slot's recurrent state needs no clearing, here or with a step in
         # flight that still advances it (a row dispatched on speculation):

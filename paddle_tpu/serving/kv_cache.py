@@ -47,12 +47,20 @@ class LayerCache:
     from which every head's key and value are expanded or absorbed; it has
     no separate V.
 
-    Beside its pages a layer may keep a per-SLOT state (a state-space
-    mixer: a third kind of per-request state, a fixed size a request, not a
-    size a token): `ssm_state` = (heads, head_dim, d_state), the recurrent
-    state in `state_dtype`, and `conv_tail` = (conv_dim, d_conv - 1), the
-    convolution's last inputs in the pages' dtype. A prefill WRITES the
-    request's slot of both, a step advances them in place."""
+    Beside its pages a layer may keep a per-SLOT state (a state-space or
+    linear-attention mixer: a third kind of per-request state, a fixed size
+    a request, not a size a token): `ssm_state` = (heads, d_state,
+    head_dim), the axes in the order the arrays hold them (d_state, a
+    delta rule's key axis, on sublanes; head_dim, its value axis, on
+    lanes), the recurrent state in `state_dtype`; and `conv_tail` =
+    (d_conv - 1, conv_dim), the convolution's last inputs, time-major, in
+    the pages' dtype. A prefill WRITES the request's slot of both, a step
+    advances them in place.
+
+    A STATE-ONLY layer (`kv_dim` 0 with a state: a linear-attention layer
+    of a model whose other layers attend) keeps no K or V at all: it is in
+    no class of pages, no pool array is made for it, and its bytes are the
+    state class's alone."""
     kv_dim: int
     window: int = 0
     latent: bool = False
@@ -64,10 +72,19 @@ class LayerCache:
         if self.latent and self.window:
             raise ValueError("a latent layer keeps a context's pages, "
                              "not a ring")
+        if self.kv_dim == 0 and (self.window or self.latent
+                                 or not self.ssm_state):
+            raise ValueError("a layer without K/V (kv_dim 0) is a "
+                             "state-only layer: a state, no window, no "
+                             "latent")
 
     @property
     def ring(self) -> bool:
         return self.window > 0
+
+    @property
+    def state_only(self) -> bool:
+        return self.kv_dim == 0
 
 
 def pool_array_names(layer: int, latent: bool) -> Tuple[str, ...]:
@@ -158,6 +175,11 @@ class KVPagePool:
             telemetry.gauge_set("mem.serving.kv_used_bytes", 0)
             telemetry.gauge_set("mem.serving.kv_high_water_bytes", 0)
         costmodel.refresh_ledger()
+
+    def array_names(self) -> List[str]:
+        """The program feed names of this pool's arrays."""
+        return [name for i, lat in zip(self.layers, self.latent)
+                for name in pool_array_names(i, lat)]
 
     def make_arrays(self) -> Dict[str, Any]:
         """Fresh zeroed device pools keyed by the program feed names."""
@@ -313,7 +335,8 @@ class PagedKVCache:
     and `audit` holds both to their invariants.
 
     Layers with a per-slot state (`LayerCache.ssm_state`) add the state
-    class: `state_array_names` arrays of ``slots + 1`` states, booked as
+    class, and a state-only layer is in that class alone (the context pool
+    holds the arrays of the layers that attend, ``context.layers``): `state_array_names` arrays of ``slots + 1`` states, booked as
     ``mem.serving.state_pool_bytes`` (``.used``: the seated requests'
     share, `note_state_slots`). It has no page ids: a request's state is
     its SLOT's, which the engine hands out with the seat, so a request is
@@ -327,7 +350,8 @@ class PagedKVCache:
                  dtype: str = "float32", slots: int = 0):
         self.layout = list(layout)
         self.page_size = int(page_size)
-        ctx = [i for i, lc in enumerate(layout) if not lc.ring]
+        ctx = [i for i, lc in enumerate(layout)
+               if not lc.ring and not lc.state_only]
         rings = [i for i, lc in enumerate(layout) if lc.ring]
         if not ctx:
             raise ValueError("a model needs at least one layer that holds "
@@ -396,12 +420,10 @@ class PagedKVCache:
         out = {}
         for i in self.state_layers:
             lc = self.layout[i]
-            heads, head_dim, d_state = lc.ssm_state
-            conv_dim, taps = lc.conv_tail
             state, tail = state_array_names(i)
-            out[state] = jnp.zeros(
-                (self.state_slots, heads, d_state, head_dim), lc.state_dtype)
-            out[tail] = jnp.zeros((self.state_slots, taps, conv_dim),
+            out[state] = jnp.zeros((self.state_slots,) + tuple(lc.ssm_state),
+                                   lc.state_dtype)
+            out[tail] = jnp.zeros((self.state_slots,) + tuple(lc.conv_tail),
                                   self.context.dtype)
         return out
 

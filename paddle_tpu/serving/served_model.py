@@ -7,10 +7,10 @@ the engine asks of a model is this class: its three program builders
 parameters are laid out and prepared, and what each layer keeps of a
 sequence (`kv_cache.LayerCache`: the width of its K/V and whether its
 pages are a context's or a ring, or that it keeps ONE latent array a
-token and no K and V; and what it keeps a SLOT beside them: a recurrent
-state and a conv tail). models/decoder_lm.py, models/afmoe.py,
-models/kimi_k2.py and models/falcon_h1.py each give one; ``cfg.served()``
-builds it.
+token and no K and V; and what it keeps a SLOT beside them, or INSTEAD
+of them: a recurrent state and a conv tail). models/decoder_lm.py,
+models/afmoe.py, models/kimi_k2.py, models/falcon_h1.py and
+models/qwen3_next.py each give one; ``cfg.served()`` builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
 the program reads beside parameters and pools, out of what the engine can
@@ -34,7 +34,12 @@ model builds no chunk program (the engine refuses the prefix store for
 it). A layer with per-slot state (`LayerCache.ssm_state`) also feeds
 ``ssm_state_<l>`` [slots + 1, heads, d_state, head_dim] and
 ``conv_tail_<l>`` [slots + 1, d_conv - 1, conv_dim]
-(`kv_cache.state_array_names`) and writes each back likewise;
+(`kv_cache.state_array_names`) and writes each back likewise; a
+STATE-ONLY layer (`LayerCache(0, ssm_state=..., conv_tail=...)`: a
+linear-attention layer of a model whose other layers attend,
+models/qwen3_next.py) feeds and fetches those two ALONE: no ``kv_*_<l>``
+array exists for it, the context pool holds the attending layers' arrays
+only, and ``page_table`` is read by those layers;
 ``state_slots`` names each row's slot (a step's from the slot its seated
 request keeps, which ``carry`` holds on the device; a padding row's and a
 warm-up feed's is the scratch slot, the arrays' last). Its prefill WRITES

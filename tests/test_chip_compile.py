@@ -215,6 +215,56 @@ def test_ssm_state_update_at_the_falcon_h1_widths(one_chip, tpu_mode):
     assert mem.temp_size_in_bytes < state // 8
 
 
+def test_gated_delta_state_update_at_the_qwen3_next_share(one_chip,
+                                                          tpu_mode):
+    """64 rows over 65 slots of 8 value heads x [128, 128] float32, the
+    state donated: the kernel, and the state aliased to its output (no
+    copy of the 34 MB array beside it)."""
+    from paddle_tpu.ops.pallas.gated_delta_state_update import \
+        gated_delta_state_update
+
+    shapes = [((65, 8, 128, 128), F32), ((64,), I32), ((64, 8, 128), F32),
+              ((64, 8, 128), F32), ((64, 8, 128), F32), ((64, 8), F32),
+              ((64, 8), F32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(gated_delta_state_update, donate_argnums=(0,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_state_update" in text
+    mem = compiled.memory_analysis()
+    state = 65 * 8 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 8
+
+
+@pytest.mark.parametrize("n", [384, 81984])
+def test_grouped_swiglu_at_the_qwen3_next_share(one_chip, tpu_mode, n):
+    """A step's sorted rows (64 rows x top-10, a quarter held) and the
+    16,384 bucket's, 128 held experts of 2048 x 512: F in one block, 128
+    visits and the row tiles'."""
+    from paddle_tpu.ops.pallas.grouped_swiglu import _tiles, grouped_swiglu
+
+    assert _tiles(n, 2048, 512, BF16)[2] == 512
+    _compile(grouped_swiglu, one_chip, ((n, 2048), BF16),
+             ((128, 2048, 512), BF16), ((128, 2048, 512), BF16),
+             ((128, 512, 2048), BF16), ((128,), I32))
+
+
+def test_paged_gqa_attention_at_the_qwen3_next_share(one_chip, tpu_mode):
+    """4 query heads on 1 K/V head of 256, bfloat16 pages of 64 tokens, 64
+    rows over 288 context pages each (18,432 positions) of a pool of
+    18,433."""
+    from paddle_tpu.ops.pallas.paged_gqa_attention import \
+        paged_gqa_decode_attention
+
+    _compile(lambda q, pk, pv, t, p: paged_gqa_decode_attention(
+        q, pk, pv, t, p, num_heads=4, num_kv_heads=1, head_dim=256,
+        scale=256 ** -0.5), one_chip, ((64, 1024), F32),
+        ((18433, 64, 256), BF16), ((18433, 64, 256), BF16),
+        ((64, 288), I32), ((64,), I32))
+
+
 def test_layer_norm_fwd_bwd(one_chip, tpu_mode):
     from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 

@@ -59,7 +59,7 @@ def engine_state(req, cfg):
 
 def test_a_state_layer_keeps_a_state_and_a_tail_a_slot_beside_its_pages():
     telemetry.reset()
-    layout = [LayerCache(16, ssm_state=(H, P, N), conv_tail=(CONV, K - 1))] * 2
+    layout = [LayerCache(16, ssm_state=(H, N, P), conv_tail=(K - 1, CONV))] * 2
     assert state_array_names(1) == ("ssm_state_1", "conv_tail_1")
     kv = PagedKVCache(layout, page_size=8, context_pages=9, dtype="bfloat16",
                       slots=3)
@@ -102,8 +102,8 @@ def test_a_model_without_state_has_no_state_class():
     assert "mem.serving.state_pool_bytes" not in \
         telemetry.snapshot()["gauges"]
     with pytest.raises(ValueError, match="slot count"):
-        PagedKVCache([LayerCache(16, ssm_state=(H, P, N),
-                                 conv_tail=(CONV, K - 1))], 8, 9)
+        PagedKVCache([LayerCache(16, ssm_state=(H, N, P),
+                                 conv_tail=(K - 1, CONV))], 8, 9)
 
 
 # -- the ops -----------------------------------------------------------------

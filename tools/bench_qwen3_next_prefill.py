@@ -1,0 +1,145 @@
+"""Time the parts of one Qwen3-Next prefill alone, on the chip, at the cell's
+share (PERF.md, PR 45): what a bucket's `while`s and `fusion`s hold.
+
+    chiprun -- python tools/bench_qwen3_next_prefill.py [BUCKET ...]
+
+For each padded bucket (4096 and 16384 by default), ms a call of: the
+chunked gated delta rule of one layer (`chunk_delta_rule`, 8 value heads x
+[128, 128], chunks of 64) and its two sequential parts alone (the
+row-by-row triangular solve; the scan over chunks), whole-prompt attention
+of one layer (`gqa_prefill_attention`, 4 query heads on one K/V head of
+256, bfloat16 products), one routed layer (`routed_experts_share`, 128 of
+512 experts held at 2048 x 512, top-10 by softmax), and one decode step's
+state kernel over 64 rows beside its stock form. One JSON line a bucket on
+stdout and in ``chiprun_out/qwen3_next_prefill_bench.jsonl``.
+"""
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core import registry
+from paddle_tpu.ops import linear_attention_ops as la
+from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
+from paddle_tpu.parallel.moe import routed_experts_share
+
+H, DK, DV, CHUNK = 8, 128, 128, 64
+HIDDEN, EXPERTS, HELD, WIDTH, TOP_K = 2048, 512, 128, 512, 10
+
+
+def ms_a_call(fn, args, reps=5):
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t) / reps * 1e3, 3)
+
+
+def rule_inputs(key, s):
+    ks = jax.random.split(key, 5)
+    q = la._l2norm(jax.random.normal(ks[0], (1, s, H, DK))) * DK ** -0.5
+    k = la._l2norm(jax.random.normal(ks[1], (1, s, H, DK)))
+    v = jax.random.normal(ks[2], (1, s, H, DV))
+    g = -0.05 * jax.random.uniform(ks[3], (1, s, H))
+    beta = jax.random.uniform(ks[4], (1, s, H))
+    return q, k, v, g, beta
+
+
+def solve_alone(n):
+    """The forward substitution of `chunk_delta_rule` over [1, H, nc, C, C]."""
+    ln = n.shape[-1]
+
+    def row(i, t):
+        r = jax.lax.dynamic_slice_in_dim(n, i, 1, axis=-2)
+        new = jnp.einsum("bhcls,bhcsj->bhclj", r, t,
+                         precision=jax.lax.Precision.HIGHEST)
+        old = jax.lax.dynamic_slice_in_dim(t, i, 1, axis=-2)
+        return jax.lax.dynamic_update_slice_in_dim(t, old - new, i, axis=-2)
+
+    eye = jnp.broadcast_to(jnp.eye(ln, dtype=jnp.float32), n.shape)
+    return jax.lax.fori_loop(1, ln, row, eye)
+
+
+def scan_alone(u, w, q, k):
+    """The carry over chunks of `chunk_delta_rule`: four products a chunk
+    against the [H, DK, DV] state."""
+    hi = jax.lax.Precision.HIGHEST
+
+    def carry(state, c):
+        u_c, w_c, q_c, k_c = c
+        v_new = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, state, precision=hi)
+        o_c = jnp.einsum("bhlk,bhkv->bhlv", q_c, state, precision=hi)
+        state = state * 0.97 + jnp.einsum("bhlk,bhlv->bhkv", k_c, v_new,
+                                          precision=hi)
+        return state, o_c + v_new
+
+    return jax.lax.scan(carry, jnp.zeros((1, H, DK, DV), jnp.float32),
+                        (u, w, q, k))
+
+
+def main():
+    buckets = [int(a) for a in sys.argv[1:]] or [4096, 16384]
+    key = jax.random.PRNGKey(0)
+    os.makedirs("chiprun_out", exist_ok=True)
+    out = open("chiprun_out/qwen3_next_prefill_bench.jsonl", "w")
+    bf = jnp.bfloat16
+    w1 = jax.random.normal(key, (HELD, HIDDEN, WIDTH), bf) * HIDDEN ** -0.5
+    w2 = jax.random.normal(key, (HELD, WIDTH, HIDDEN), bf) * WIDTH ** -0.5
+    router = (jax.random.normal(key, (HIDDEN, EXPERTS)) * 3
+              * HIDDEN ** -0.5).astype(bf)
+    attend = registry.lookup("gqa_prefill_attention").forward
+    for s in buckets:
+        q, k, v, g, beta = rule_inputs(jax.random.fold_in(key, s), s)
+        nc = s // CHUNK
+        line = {"bucket": s, "device": jax.devices()[0].device_kind}
+        line["chunk_delta_rule_ms"] = ms_a_call(
+            jax.jit(lambda *a: la.chunk_delta_rule(*a, CHUNK)),
+            (q, k, v, g, beta))
+        n = 0.1 * jax.random.normal(key, (1, H, nc, CHUNK, CHUNK))
+        line["solve_alone_ms"] = ms_a_call(jax.jit(solve_alone), (n,))
+        parts = [jax.random.normal(key, (nc, 1, H, CHUNK, d))
+                 for d in (DV, DK, DK, DK)]
+        line["scan_alone_ms"] = ms_a_call(jax.jit(scan_alone), tuple(parts))
+        qa = jax.random.normal(key, (1, s, 4 * 256))
+        ka = jax.random.normal(key, (1, s, 256))
+        line["gqa_prefill_attention_ms"] = ms_a_call(
+            jax.jit(lambda q_, k_, v_: attend(
+                {"Q": [q_], "K": [k_], "V": [v_]},
+                {"num_heads": 4, "num_kv_heads": 1, "head_dim": 256,
+                 "compute_dtype": "bfloat16", "block_q": 512})["Out"]),
+            (qa, ka, ka))
+        x = jax.random.normal(key, (s, HIDDEN))
+        line["routed_experts_ms"] = ms_a_call(
+            jax.jit(lambda x_: routed_experts_share(
+                x_, router, jnp.zeros((EXPERTS,)), w1, w1, w2, top_k=TOP_K,
+                held_lo=0, score_func="softmax")[0]), (x,))
+        print(json.dumps(line), flush=True)
+        out.write(json.dumps(line) + "\n")
+    # the decode step's state kernel over 64 rows of 65 slots
+    state = jax.random.normal(key, (65, H, DK, DV))
+    slots = jnp.arange(64, dtype=jnp.int32)
+    qs, ks_, vs, gs, bs = (a[0, :64] for a in rule_inputs(key, 64))
+    args = (state, slots, qs, ks_, vs, jnp.exp(gs), bs)
+    line = {"state_kernel_ms": ms_a_call(
+        jax.jit(lambda *a: gdu.gated_delta_state_update(
+            *a, heads_per_key=2)), args, 20),
+        "state_kernel_unshared_ms": ms_a_call(
+        jax.jit(gdu.gated_delta_state_update), args, 20),
+        "state_stock_ms": ms_a_call(
+        jax.jit(gdu.stock_gated_delta_state_update), args, 20),
+        "state_bytes_ms_at_peak": round(
+            64 * 2 * H * DK * DV * 4 / 819e9 * 1e3, 4)}
+    print(json.dumps(line), flush=True)
+    out.write(json.dumps(line) + "\n")
+
+
+if __name__ == "__main__":
+    main()

@@ -457,6 +457,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.mla_prefill_fallbacks",
                "pallas.ssm_state_update_dispatches",
                "pallas.ssm_state_update_fallbacks",
+               "pallas.gated_delta_state_update_dispatches",
+               "pallas.gated_delta_state_update_fallbacks",
                "pallas.grouped_swiglu_dispatches",
                "pallas.grouped_swiglu_fallbacks",
                "pallas.grouped_swiglu_bwd_dispatches",
