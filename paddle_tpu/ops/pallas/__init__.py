@@ -23,7 +23,10 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     gate, up, silu(g) * u and down under each block;
                     grouped_swiglu_bwd its backward for the trainer, two
                     kernels over the same rows (the rows' gradients, then
-                    the three matrices').
+                    the three matrices'),
+* routed_combine  — the same layer's sorted rows weighed and summed into
+                    their tokens: a token tile's contiguous runs staged
+                    by DMA, the sum a one-hot product in float32.
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

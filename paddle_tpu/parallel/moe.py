@@ -14,8 +14,10 @@ all_to_alls drop out — same math, no comm.
 layer, served and (``trainable=True``) trained: the pairs sorted by held
 expert, the forward one grouped kernel over the sorted rows
 (ops/pallas/grouped_swiglu.py), the hand-written backward two
-(ops/pallas/grouped_swiglu_bwd.py: rows-side, weights-side); ragged
-products wherever a kernel cannot run, counted.
+(ops/pallas/grouped_swiglu_bwd.py: rows-side, weights-side), the sorted
+rows summed into their tokens by a fourth, forward and backward
+(ops/pallas/routed_combine.py); ragged products and a scatter-add
+wherever a kernel cannot run, counted.
 """
 
 from __future__ import annotations
@@ -142,15 +144,18 @@ def _held_experts_bwd(few, res, dout):
     470 MB a layer at 65,536 rows x 896), then the six products of the
     gradients, as two grouped kernels
     (``ops/pallas/grouped_swiglu_bwd.py``: rows-side and weights-side;
-    the gathers and the scatter-add around them are XLA's), or as eight
+    the gathers around them are XLA's), or as eight
     ragged products over the groups (`stock_grouped_swiglu_bwd`) where
     ``kernel_mode()`` is off, the dtypes are mixed or the kernels cannot
-    tile the shape, counted. Nothing is dropped at any imbalance: past
+    tile the shape, counted; the rows' gradients summed into their
+    tokens as the forward sums its rows (``routed_combine``, the held
+    rows weighing 1). Nothing is dropped at any imbalance: past
     `few` rows the chunks go on."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.pallas.grouped_swiglu_bwd import grouped_swiglu_bwd
+    from ..ops.pallas.routed_combine import routed_combine
 
     x, w_sorted, w1, w3, w2, rows, sizes = res
     t, h = x.shape
@@ -159,15 +164,14 @@ def _held_experts_bwd(few, res, dout):
     pairs = rows.shape[0]
 
     def back(r, w, part):
-        """One run of sorted rows -> (dx scattered [T, H], dw [n], dW1,
+        """One run of sorted rows -> (dx combined [T, H], dw [n], dW1,
         dW3, dW2 in float32)."""
         mine = w > 0
         dy = jnp.where(mine[:, None], dout[r], 0.0)
         dxs, dw, d1, d3, d2 = grouped_swiglu_bwd(
             x[r].astype(dt), dy.astype(dt), (dy * w[:, None]).astype(dt), w,
             w1, w3, w2, part)
-        dx = jnp.zeros((t, h), f32).at[r].add(
-            jnp.where(mine[:, None], dxs, 0.0))
+        dx = routed_combine(dxs, r, mine.astype(f32), part, t)
         return dx, jnp.where(mine, dw, 0.0), d1, d3, d2
 
     def every():
@@ -215,6 +219,7 @@ def _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few):
     import jax.numpy as jnp
 
     from ..ops.pallas.grouped_swiglu import grouped_swiglu
+    from ..ops.pallas.routed_combine import routed_combine
 
     t, h = x.shape
     pairs = rows.shape[0]
@@ -223,10 +228,8 @@ def _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few):
         xs = x[r].astype(w1.dtype)                               # [n, H]
         ys = grouped_swiglu(xs, w1, w3, w2, sizes)               # [n, H]
         # rows past the groups hold nothing of a held expert: weight 0,
-        # and a `where` so that whatever the grouped product left there
-        # stays out
-        ys = jnp.where(w[:, None] > 0, ys * w[:, None], 0.0)
-        return jnp.zeros((t, h), jnp.float32).at[r].add(ys)
+        # and whatever the grouped product left there stays out
+        return routed_combine(ys, r, w, sizes, t)
 
     def every():
         pad = -pairs % few
@@ -292,7 +295,12 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     (``ops/pallas/grouped_swiglu.py``: a stream of the hit experts' weight
     blocks with the three products under each; where ``kernel_mode()`` is
     off or the shape cannot be tiled, three ``jax.lax.ragged_dot``,
-    counted), so an expert no token chose costs no weight read. It runs
+    counted), so an expert no token chose costs no weight read; the
+    sorted rows are then weighed and summed into their tokens in float32
+    by one kernel over the contiguous runs a token tile holds in each
+    group (``ops/pallas/routed_combine.py``; a scatter-add where
+    ``kernel_mode()`` is off, the tokens are not two tiles of 256 or more
+    or H is not of 128, counted). It runs
     over the leading rows that hold the held pairs when those are few, as
     they nearly always are, and otherwise over that many sorted rows at a
     time, as far as the held pairs reach (one ``lax.cond``). What the absent experts would
@@ -305,7 +313,8 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     (``_held_experts_bwd``), dropless too: two grouped kernels
     (``ops/pallas/grouped_swiglu_bwd.py``: the rows' gradients with gate
     and up made again, then the three matrices' gradients) between XLA's
-    gathers and scatter-add; where ``kernel_mode()`` is off, the dtypes
+    gathers and the ``routed_combine`` kernel; where ``kernel_mode()`` is
+    off, the dtypes
     are mixed or the kernels cannot tile the shape (rows not a multiple
     of the sublane tile, H or F not of 128, an expert's three matrices
     and a row tile over the kernel's VMEM), eight ragged products,

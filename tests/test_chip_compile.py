@@ -12,6 +12,7 @@ runs at import, in ``skipif`` or in ``parametrize``.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -329,7 +330,9 @@ def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
     kernel once a branch (the backward's trace of it is dead code and
     gone), the backward's eight products the two `grouped_swiglu_bwd`
     kernels a branch (40,960 rows in tiles of 512 with an expert's
-    three matrices whole in VMEM) and no ragged product. The instruction
+    three matrices whole in VMEM) and no ragged product, the combine the
+    `routed_combine` kernel forward and backward a branch and no scatter
+    of `[16384, 2304]`. The instruction
     names are the kernels' names, which `trace_reduce.op_family` prints
     and `routed_experts_train_roofline` sums by their first letters."""
     from benchmark.trace_reduce import op_family
@@ -351,7 +354,28 @@ def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(calls) == ["grouped_swiglu"] * 2 \
         + ["grouped_swiglu_bwd_rows"] * 2 \
-        + ["grouped_swiglu_bwd_weights"] * 2
+        + ["grouped_swiglu_bwd_weights"] * 2 + ["routed_combine"] * 4
+    assert not re.search(r"= f32\[16384,2304\]\S* scatter\(", text)
+
+
+@pytest.mark.parametrize("t,n,h,e", [(16384, 40960, 2304, 16),
+                                     (16384, 81984, 2048, 128),
+                                     (512, 2624, 2048, 128),
+                                     (4096, 2112, 7168, 12),
+                                     (4096, 8256, 3072, 32)])
+def test_routed_combine_at_the_four_cells_widths(one_chip, tpu_mode, t, n,
+                                                 h, e):
+    """Mellum's leading rows, Qwen3-Next's largest and smallest prefill
+    buckets, Kimi's and Trinity's 4,096 buckets: the two halves of the
+    staging buffer and the output tile fit the kernel's VMEM limit, the
+    steps' block numbers fit the scalar memory, the transpose of the
+    staged tokens and the per-piece copies lower."""
+    from paddle_tpu.ops.pallas.routed_combine import routed_combine
+
+    text = _compile(lambda ys, r, w, s: routed_combine(ys, r, w, s, t),
+                    one_chip, ((n, h), F32), ((n,), I32), ((n,), F32),
+                    ((e,), I32))
+    assert "routed_combine" in text and "scatter(" not in text
 
 
 def test_step_sampler_at_the_xglm_vocabulary(one_chip):

@@ -77,14 +77,22 @@ def test_gradients_against_a_dense_loop(score_func, branch, held_lo):
             <= 2e-6 * float(jnp.max(jnp.abs(ref)))
 
 
+@pytest.mark.parametrize("combine", ["scatter_add", "kernel"])
 @pytest.mark.parametrize("branch", ["leading", "every"])
-def test_gradients_through_the_backward_kernels(monkeypatch, branch):
+def test_gradients_through_the_backward_kernels(monkeypatch, branch,
+                                                combine):
     """The same dense-loop gradients at widths the kernels tile (128 x
     128), in interpret mode: forward and backward kernels in the branch
-    the `cond` takes, none of the backward's ragged products."""
+    the `cond` takes, none of the backward's ragged products; the
+    combine as the scatter-add (64 tokens are one tile of 256) and, at
+    token tiles of 32, as the `routed_combine` kernel forward and
+    backward."""
     from paddle_tpu.core import telemetry
+    from paddle_tpu.ops.pallas import routed_combine as rc
 
     monkeypatch.setenv("PT_PALLAS", "interpret")
+    if combine == "kernel":
+        monkeypatch.setattr(rc, "TOKEN_TILE", 32)
     telemetry.reset()
     x, rw, w1, w3, w2, co = _weights(seed=3, h=128, f=128)
     if branch == "every":
@@ -106,6 +114,11 @@ def test_gradients_through_the_backward_kernels(monkeypatch, branch):
     # one site a `cond` branch, at trace time
     assert telemetry.counter_get("pallas.grouped_swiglu_bwd_dispatches") == 2
     assert telemetry.counter_get("pallas.grouped_swiglu_bwd_fallbacks") == 0
+    # forward and backward, a `cond` branch each
+    kernel = 4 * (combine == "kernel")
+    assert telemetry.counter_get("pallas.routed_combine_dispatches") == kernel
+    assert telemetry.counter_get("pallas.routed_combine_fallbacks") \
+        == 4 - kernel
     for got, ref in zip(grads, want_vjp(co)):
         assert float(jnp.max(jnp.abs(got - ref))) \
             <= 1e-5 * float(jnp.max(jnp.abs(ref)))

@@ -9,9 +9,11 @@ chunked gated delta rule of one layer (`chunk_delta_rule`, 8 value heads x
 row-by-row triangular solve; the scan over chunks), whole-prompt attention
 of one layer (`gqa_prefill_attention`, 4 query heads on one K/V head of
 256, bfloat16 products), one routed layer (`routed_experts_share`, 128 of
-512 experts held at 2048 x 512, top-10 by softmax), and one decode step's
-state kernel over 64 rows beside its stock form. One JSON line a bucket on
-stdout and in ``chiprun_out/qwen3_next_prefill_bench.jsonl``.
+512 experts held at 2048 x 512, top-10 by softmax) and its combine alone
+(`routed_combine`, the kernel, beside the scatter-add it replaces, over the
+layer's leading sorted rows), and one decode step's state kernel over 64
+rows beside its stock form. One JSON line a bucket on stdout and in
+``chiprun_out/qwen3_next_prefill_bench.jsonl``.
 """
 
 import json
@@ -28,6 +30,8 @@ from paddle_tpu.core import registry
 from paddle_tpu.ops import linear_attention_ops as la
 from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
 from paddle_tpu.parallel.moe import routed_experts_share
+
+from bench_routed_train import combine_alone
 
 H, DK, DV, CHUNK = 8, 128, 128, 64
 HIDDEN, EXPERTS, HELD, WIDTH, TOP_K = 2048, 512, 128, 512, 10
@@ -121,6 +125,10 @@ def main():
             jax.jit(lambda x_: routed_experts_share(
                 x_, router, jnp.zeros((EXPERTS,)), w1, w1, w2, top_k=TOP_K,
                 held_lo=0, score_func="softmax")[0]), (x,))
+        pairs = s * TOP_K               # the served layer's leading rows
+        few = -(-(2 * pairs * HELD // EXPERTS + 32) // 64) * 64
+        line["combine_alone"] = combine_alone(
+            s, TOP_K, EXPERTS, HELD, HIDDEN, few, 5)
         print(json.dumps(line), flush=True)
         out.write(json.dumps(line) + "\n")
     # the decode step's state kernel over 64 rows of 65 slots

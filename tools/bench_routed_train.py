@@ -7,8 +7,11 @@ kernels and as the eight ragged products, and the stock forward under
 JAX's own differentiation rules over the leading rows. ms a call, and %
 of the bf16 peak for the nine products of the held pairs. Then the two
 backward kernels alone over the layer's 40,960 leading rows beside the
-eight ragged products. Lines in chiprun_out/routed_train_bench.jsonl (a
-call's file replaces the last one's). ~3 min.
+eight ragged products, and the combine alone (`routed_combine`, the
+kernel) beside the scatter-add it replaces (`stock_routed_combine`)
+over the same leading rows of a real routing. Lines in
+chiprun_out/routed_train_bench.jsonl (a call's file replaces the last
+one's). ~3 min.
 
     python tools/bench_routed_train.py [--tokens 16384]
 """
@@ -22,6 +25,64 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 PEAK = 197e12
+
+
+def combine_alone(t, k, e, eh, h, n, iters=10):
+    """The combine over the n leading sorted rows of t tokens' top-k of e
+    experts (softmax of a random router), eh of them held, at width h:
+    ms a call of the scatter-add (with the weigh-and-select pass in front
+    of it, and alone) and of the `routed_combine` kernel with its plan,
+    the largest difference between the two, and the kernel's steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import kernel_mode
+    from paddle_tpu.ops.pallas import routed_combine as rc
+
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    top, idx = jax.lax.top_k(
+        jax.nn.softmax(jax.random.normal(keys[0], (t, e)), -1), k)
+    held = idx < eh
+    key = jnp.where(held, idx, eh).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, eh + 1, dtype=jnp.int32), 0)[:eh]
+    rows = (order // k).astype(jnp.int32)[:n]
+    w = jnp.where(held, top, 0.0).reshape(-1)[order][:n]
+    in_groups = int(jnp.sum(sizes))
+    assert in_groups <= n, "the leading rows do not hold the pairs"
+    # what lies past the groups may be anything
+    ys = jnp.where((jnp.arange(n) < in_groups)[:, None],
+                   jax.random.normal(keys[1], (n, h), jnp.float32), jnp.nan)
+
+    def timed(fn):
+        fn = jax.jit(fn)
+        out = jax.block_until_ready(fn(ys, rows, w, sizes))
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(ys, rows, w, sizes)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / iters * 1e3, out
+
+    stock_ms, want = timed(lambda ys, r, w, s: rc.stock_routed_combine(
+        ys, r, w, t))
+    add_ms, _ = timed(lambda ys, r, w, s: jnp.zeros((t, h), jnp.float32)
+                      .at[r].add(ys))
+    line = dict(piece="the combine alone", tokens=t, rows=n, width=h,
+                held_experts=eh, in_groups=in_groups,
+                weigh_and_scatter_add_ms=stock_ms, scatter_add_ms=add_ms,
+                bytes_ms_at_peak=(in_groups + t) * h * 4 / 819e9 * 1e3)
+    tiling = rc._tiles(t, n, h)
+    if tiling is not None and kernel_mode() != "off":
+        tile, piece, stage, _lanes = tiling
+        ms, got = timed(lambda ys, r, w, s: rc.routed_combine(ys, r, w, s, t))
+        _tid, count, total, _block = rc._plan(rows, sizes, t, tile, piece,
+                                              stage // piece)
+        line.update(
+            kernel_ms=ms, tile=tile, piece_rows=piece, stage=stage,
+            steps=int(total[0]), pieces=int(jnp.sum(count[:int(total[0])])),
+            max_diff_of_scale=float(jnp.max(jnp.abs(got - want))
+                                    / jnp.max(jnp.abs(want))))
+    return line
 
 
 def main():
@@ -153,6 +214,7 @@ def main():
                           pct_of_peak=100 * 3 * held * 2 * h * f / PEAK
                           / ms_w * 1e3),
         compile_s=[c_r, c_w]))
+    rows.append(combine_alone(t, k, e, eh, h, n, args.iters))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/routed_train_bench.jsonl", "w") as fh:
         for r in rows:

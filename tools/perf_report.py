@@ -463,6 +463,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.grouped_swiglu_fallbacks",
                "pallas.grouped_swiglu_bwd_dispatches",
                "pallas.grouped_swiglu_bwd_fallbacks",
+               "pallas.routed_combine_dispatches",
+               "pallas.routed_combine_fallbacks",
                "pallas.flash_window_dispatches",
                "pallas.flash_window_fallbacks") if cval(key)}
     if pallas:
