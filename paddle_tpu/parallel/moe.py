@@ -12,7 +12,8 @@ all_to_alls drop out — same math, no comm.
 
 `routed_experts_share` is one chip's share of a dropless top-k routed
 layer, served and (``trainable=True``) trained: the pairs sorted by held
-expert, the forward one grouped kernel over the sorted rows
+expert (their plan compares and sorts, and gathers no single float),
+the forward one grouped kernel over the sorted rows
 (ops/pallas/grouped_swiglu.py), the hand-written backward two
 (ops/pallas/grouped_swiglu_bwd.py: rows-side, weights-side), the sorted
 rows summed into their tokens by a fourth, forward and backward
@@ -264,6 +265,87 @@ def _trained_held_experts():
     return trained
 
 
+def _sort_pairs(key, w):
+    import jax.numpy as jnp
+    from jax import lax
+
+    # `jnp.argsort(key, stable=True)` with the weights as one more operand
+    iota = lax.iota(jnp.int32, key.shape[0])
+    _, order, w_sorted = lax.sort((key, iota, w), num_keys=1, is_stable=True)
+    return order, w_sorted
+
+
+def _sort_pairs_fwd(key, w):
+    order, w_sorted = _sort_pairs(key, w)
+    return (order, w_sorted), order
+
+
+def _sort_pairs_bwd(order, cts):
+    from jax import lax
+
+    # a sort by the permutation itself puts each sorted pair's cotangent
+    # back at its pair; the key and the order take no gradient
+    return None, lax.sort((order, cts[1]), num_keys=1)[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _sorted_pairs():
+    """(key int [P], w [P]) -> (order int32 [P], w_sorted [P]): the stable
+    order of the pairs by `key` and the weights in that order, from one
+    sort that carries the weights along (no gather by `order`), with its
+    transpose by hand, a second sort (no scatter by `order`; JAX's own
+    rule for a sort of several operands gathers the tangents). Made
+    once; the served path runs the same function."""
+    import jax
+
+    sorted_pairs = jax.custom_vjp(_sort_pairs)
+    sorted_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
+    return sorted_pairs
+
+
+def _kept_scores(scores, idx):
+    """scores[t, idx[t, j]] by comparison: a top-k's indices are distinct,
+    so each sum over E has one term that is not zero and is exact. JAX's
+    transpose is the same comparison summed over k, where a gather's is a
+    scatter-add into [T, E]; [T, k, E] lives inside one fusion, forward
+    and transposed, and is never written out."""
+    import jax.numpy as jnp
+
+    chosen = idx[:, :, None] == jnp.arange(scores.shape[1], dtype=idx.dtype)
+    return jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=2)
+
+
+def _pair_plan(scores, select_bias, alive, *, top_k, held_lo, e_held,
+               route_scale, route_norm):
+    """The plan over the T x k pairs of a routed layer, with no gather and
+    no scatter of single elements, forward or transposed: scores float32
+    [T, E], `alive` bool [T] -> (idx int32 [T, k] the chosen experts,
+    kept [T, k] their scores, order [T*k] the pairs sorted by held
+    expert, rows int32 [T*k] the sorted pairs' tokens, sizes int32
+    [e_held] the pairs in each held expert's group, w_sorted [T*k] the
+    sorted pairs' weights, 0 past the groups)."""
+    import jax
+    import jax.numpy as jnp
+
+    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    kept = _kept_scores(scores, idx)                             # [T, k]
+    weight = kept
+    if route_norm:
+        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + 1e-20)
+    weight = weight * route_scale
+    local = idx - held_lo
+    held = (local >= 0) & (local < e_held) & alive[:, None]
+    # pairs sorted by held expert; pairs of absent experts and of dead
+    # rows sort behind every group and lie outside the groups' rows
+    key = jnp.where(held, local, e_held).reshape(-1)             # [T*k]
+    order, w_sorted = _sorted_pairs()(
+        key, jnp.where(held, weight, 0.0).reshape(-1))
+    sizes = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
+                    axis=0)[:e_held]
+    rows = (order // top_k).astype(jnp.int32)
+    return idx, kept, order, rows, sizes, w_sorted
+
+
 def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
                          top_k: int, held_lo: int, route_scale: float = 1.0,
                          route_norm: bool = True, live=None,
@@ -288,7 +370,13 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     Every token scores all E experts in float32, keeps the ``top_k``
     largest of ``score + select_bias`` and weighs them by
     ``score / (sum of the kept scores + 1e-20) * route_scale`` (no
-    renormalisation when ``route_norm`` is false). This chip then computes,
+    renormalisation when ``route_norm`` is false). The plan over the T x k
+    pairs (``_pair_plan``: kept scores -> weights -> sorted order ->
+    sorted weights) gathers and scatters no single element, forward or
+    transposed: a kept score is the sum over E of the scores where the
+    chosen index equals the expert (``_kept_scores``), and the one sort
+    of the pairs by held expert carries the weights along, its transpose
+    a sort by the permutation (``_sorted_pairs``). This chip then computes,
     without dropping a pair, ``sum_i w_i * Expert_i(x)`` over the kept
     pairs whose expert it holds: the pairs are sorted by expert and the
     held experts' SwiGLUs run over the sorted rows as one grouped kernel
@@ -339,24 +427,11 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"score_func {score_func!r}")
-    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
-    kept = jnp.take_along_axis(scores, idx, axis=1)              # [T, k]
-    weight = kept
-    if route_norm:
-        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + 1e-20)
-    weight = weight * route_scale
-    local = idx - held_lo
     alive = jnp.ones((t,), bool) if live is None \
         else live.reshape(-1).astype(bool)
-    held = (local >= 0) & (local < e_held) & alive[:, None]
-    # pairs sorted by held expert; pairs of absent experts and of dead
-    # rows sort behind every group and lie outside the groups' rows
-    key = jnp.where(held, local, e_held).reshape(-1)             # [T*k]
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
-                    axis=0)[:e_held]
-    rows = (order // top_k).astype(jnp.int32)
-    w_sorted = jnp.where(held, weight, 0.0).reshape(-1)[order]
+    idx, _kept, _order, rows, sizes, w_sorted = _pair_plan(
+        scores, select_bias, alive, top_k=top_k, held_lo=held_lo,
+        e_held=e_held, route_scale=route_scale, route_norm=route_norm)
 
     # the pairs on held experts sort first, and with evenly spread routing
     # they are e_held / E of all pairs. Twice that share (and a margin)

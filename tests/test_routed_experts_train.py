@@ -1,8 +1,11 @@
 """`routed_experts_share` as the trainer uses it: the softmax scoring, the
 gradients of `trainable=True` against a dense loop over the held experts
 on both branches of the `few` split (the leading rows; the chunks that go
-on past them), nothing dropped at any imbalance, and the sigmoid serving
-path bit-identical to the parent commit's."""
+on past them), nothing dropped at any imbalance, the sigmoid serving path bit-identical
+to the parent commit's, and the plan over the pairs (PR 47: kept scores by
+comparison, the sorted weights carried by the sort) equal to the parent's
+gathers bit for bit, in its gradients, and free of any gather or scatter
+of one float a pair."""
 
 import hashlib
 
@@ -11,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.moe import routed_experts_share
 
 T, H, F, E, EH, K = 64, 32, 16, 16, 4, 4
@@ -169,3 +173,173 @@ def test_the_sigmoid_path_is_bit_identical_to_the_parents(monkeypatch, mode):
         digest.update(np.asarray(out).tobytes())
         digest.update(np.asarray(counts).tobytes())
     assert digest.hexdigest() == PARENT_SIGMOID_SHA256
+
+
+def parent_plan(scores, select_bias, alive, *, top_k, held_lo, e_held,
+                route_scale, route_norm):
+    """`moe._pair_plan` as the parent commit 802ff6f wrote it inside
+    `routed_experts_share`: the kept scores and the sorted weights by a
+    gather each."""
+    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    kept = jnp.take_along_axis(scores, idx, axis=1)
+    weight = kept
+    if route_norm:
+        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + 1e-20)
+    weight = weight * route_scale
+    local = idx - held_lo
+    held = (local >= 0) & (local < e_held) & alive[:, None]
+    key = jnp.where(held, local, e_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
+                    axis=0)[:e_held]
+    rows = (order // top_k).astype(jnp.int32)
+    w_sorted = jnp.where(held, weight, 0.0).reshape(-1)[order]
+    return idx, kept, order, rows, sizes, w_sorted
+
+
+# (E, top_k, held experts, H, F): Mellum's routing, and Qwen3-Next's many
+# small experts at a small H
+PLAN_SHAPES = {"e64_top8_held16": (64, 8, 16, 32, 16),
+               "e512_top10_held128": (512, 10, 128, 16, 8)}
+PLAN_T = 48
+
+
+def _plan_case(shape, variant, seed=7):
+    e, k, eh, h, f = PLAN_SHAPES[shape]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(ks[0], (PLAN_T, h))
+    if variant == "tied_scores":
+        # a row of zeros scores every expert alike, and half of another
+        # row's experts score alike in pairs
+        x = x.at[5].set(0.0)
+    rw = jax.random.normal(ks[1], (h, e)) * 0.5
+    if variant == "tied_scores":
+        rw = rw.at[:, 1::2].set(rw[:, 0::2])
+    live = jnp.arange(PLAN_T) % 5 != 2 if variant == "live_mask" else None
+    bias = jax.random.normal(ks[5], (e,)) * 0.05 \
+        if variant == "select_bias" else jnp.zeros((e,))
+    return dict(
+        x=x, router_w=rw, select_bias=bias,
+        w1=jax.random.normal(ks[2], (eh, h, f)) * 0.2,
+        w3=jax.random.normal(ks[3], (eh, h, f)) * 0.2,
+        w2=jax.random.normal(ks[4], (eh, f, h)) * 0.2, top_k=k,
+        held_lo=eh if variant == "held_lo" else 0, route_scale=1.5,
+        live=live)
+
+
+@pytest.mark.parametrize("variant", ["plain", "live_mask", "select_bias",
+                                     "held_lo", "tied_scores"])
+@pytest.mark.parametrize("shape", list(PLAN_SHAPES))
+@pytest.mark.parametrize("score_func", ["sigmoid", "softmax"])
+def test_the_plan_is_the_parents_bit_for_bit(monkeypatch, score_func, shape,
+                                             variant):
+    """Op by op (no `jit` around the layer: under one XLA may fuse the
+    softmax's division another way): the kept scores, the order, the
+    sorted weights, the groups, the layer's output and its counts."""
+    case = _plan_case(shape, variant)
+    _e, k, eh, _h, _f = PLAN_SHAPES[shape]
+    logits = jnp.matmul(case["x"], case["router_w"],
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    alive = jnp.ones((PLAN_T,), bool) if case["live"] is None \
+        else case["live"]
+    kw = dict(top_k=k, held_lo=case["held_lo"], e_held=eh, route_scale=1.5,
+              route_norm=True)
+    got = moe._pair_plan(scores, case["select_bias"], alive, **kw)
+    want = parent_plan(scores, case["select_bias"], alive, **kw)
+    for name, a, b in zip(("idx", "kept", "order", "rows", "sizes",
+                           "w_sorted"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
+    assert 0 < int(jnp.sum(got[4])) < PLAN_T * k    # some held, some not
+    if variant == "tied_scores":
+        assert len(np.unique(np.asarray(scores[5]))) == 1
+
+    out, counts = routed_experts_share(**case, score_func=score_func)
+    monkeypatch.setattr(moe, "_pair_plan", parent_plan)
+    want_out, want_counts = routed_experts_share(**case,
+                                                 score_func=score_func)
+    assert np.asarray(out).tobytes() == np.asarray(want_out).tobytes()
+    assert np.asarray(counts).tobytes() == np.asarray(want_counts).tobytes()
+    assert int(counts[1]) == int(jnp.sum(got[4]))
+
+
+@pytest.mark.parametrize("shape", list(PLAN_SHAPES))
+@pytest.mark.parametrize("score_func", ["sigmoid", "softmax"])
+def test_the_plans_gradients_are_the_parents(monkeypatch, score_func, shape):
+    """A trained layer's gradients in x, the router and the three
+    matrices, the plan's transpose by comparison and by a sort, against
+    JAX's own differentiation of the parent's gathers."""
+    case = _plan_case(shape, "select_bias")
+    co = jax.random.normal(jax.random.PRNGKey(11), case["x"].shape)
+
+    def grads():
+        # a function of its own a call: a trace is cached by it
+        def loss(x, rw, w1, w3, w2):
+            out, _counts = routed_experts_share(
+                x, rw, case["select_bias"], w1, w3, w2,
+                top_k=case["top_k"], held_lo=0, route_scale=1.5,
+                score_func=score_func, trainable=True)
+            return jnp.sum(out * co)
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
+            *(case[n] for n in ("x", "router_w", "w1", "w3", "w2")))
+
+    got = grads()
+    monkeypatch.setattr(moe, "_pair_plan", parent_plan)
+    for name, a, b in zip(("x", "router_w", "w1", "w3", "w2"), got, grads()):
+        largest = float(jnp.max(jnp.abs(b)))
+        assert largest > 0, name
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * largest, name
+
+
+def _single_float_moves(jaxpr, shapes):
+    """The gathers and scatters of `jaxpr`, through every sub-jaxpr, whose
+    operand or update has one of `shapes`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("gather", "scatter", "scatter-add",
+                                  "scatter_add"):
+            moved = [v.aval.shape for v in eqn.invars[:1] + eqn.invars[2:]]
+            if any(s in shapes for s in moved):
+                found.append((eqn.primitive.name, moved))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _single_float_moves(sub, shapes)
+    return found
+
+
+@pytest.mark.parametrize("program", ["forward", "gradient"])
+def test_the_plan_moves_no_single_floats(monkeypatch, program):
+    """Neither the forward's jaxpr nor the gradient's holds a gather or a
+    scatter over one float a pair ([T*k], [T, k], [T, E]); the parent's
+    plan under the same walk holds both (the row gathers of [n, H]
+    around the kernels stay, and are not this test's)."""
+    case = _plan_case("e64_top8_held16", "plain")
+    e, k = 64, 8
+    shapes = {(PLAN_T * k,), (PLAN_T, k), (PLAN_T, e)}
+
+    def moves():
+        # a function of its own a call: a trace is cached by it
+        def layer(x, rw, w1, w3, w2):
+            out, _counts = routed_experts_share(
+                x, rw, case["select_bias"], w1, w3, w2, top_k=k, held_lo=0,
+                score_func="softmax", trainable=True)
+            return jnp.sum(out * out)
+
+        fn = layer if program == "forward" \
+            else jax.grad(layer, argnums=(0, 1, 2, 3, 4))
+        jaxpr = jax.make_jaxpr(fn)(
+            *(case[n] for n in ("x", "router_w", "w1", "w3", "w2")))
+        return _single_float_moves(jaxpr.jaxpr, shapes)
+
+    assert moves() == []
+    monkeypatch.setattr(moe, "_pair_plan", parent_plan)
+    parents = {name for name, _ in moves()}
+    assert "gather" in parents
+    if program == "gradient":
+        assert parents & {"scatter-add", "scatter_add"}

@@ -9,9 +9,11 @@ of the bf16 peak for the nine products of the held pairs. Then the two
 backward kernels alone over the layer's 40,960 leading rows beside the
 eight ragged products, and the combine alone (`routed_combine`, the
 kernel) beside the scatter-add it replaces (`stock_routed_combine`)
-over the same leading rows of a real routing. Lines in
-chiprun_out/routed_train_bench.jsonl (a call's file replaces the last
-one's). ~3 min.
+over the same leading rows of a real routing, and the plan over the
+pairs alone (`moe._pair_plan`: scores -> rows, sizes, sorted weights;
+PR 47) beside the gathers it replaces, whole and as its two pieces.
+Lines in chiprun_out/routed_train_bench.jsonl (a call's file replaces
+the last one's). ~3 min.
 
     python tools/bench_routed_train.py [--tokens 16384]
 """
@@ -25,6 +27,19 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 PEAK = 197e12
+
+
+def _timed(fn, iters, *a):
+    """(ms a call of jit(fn)(*a) after one call that compiles, its result)."""
+    import jax
+
+    fn = jax.jit(fn)
+    out = jax.block_until_ready(fn(*a))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*a)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3, out
 
 
 def combine_alone(t, k, e, eh, h, n, iters=10):
@@ -55,13 +70,7 @@ def combine_alone(t, k, e, eh, h, n, iters=10):
                    jax.random.normal(keys[1], (n, h), jnp.float32), jnp.nan)
 
     def timed(fn):
-        fn = jax.jit(fn)
-        out = jax.block_until_ready(fn(ys, rows, w, sizes))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(ys, rows, w, sizes)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e3, out
+        return _timed(fn, iters, ys, rows, w, sizes)
 
     stock_ms, want = timed(lambda ys, r, w, s: rc.stock_routed_combine(
         ys, r, w, t))
@@ -82,6 +91,80 @@ def combine_alone(t, k, e, eh, h, n, iters=10):
             steps=int(total[0]), pieces=int(jnp.sum(count[:int(total[0])])),
             max_diff_of_scale=float(jnp.max(jnp.abs(got - want))
                                     / jnp.max(jnp.abs(want))))
+    return line
+
+
+def plan_alone(t, k, e, eh, iters=50):
+    """The plan over t tokens' top-k of e experts (softmax of a random
+    router), eh of them held: ms a call of `moe._pair_plan` (scores ->
+    rows, sizes, w_sorted) forward, and forward + backward of a weighted
+    sum of w_sorted, beside the reference form (the same plan with its
+    two pieces as they stood until PR 47: the kept scores and the sorted
+    weights by a gather each), and the largest difference between the
+    two; then the two pieces alone in both forms (the kept scores of
+    given indices; the sorted weights of given keys) and the argsort both
+    forms hold. `four_ops_ms`: the kept scores and the sorted weights,
+    forward + backward, less the argsort."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    scores = jax.nn.softmax(jax.random.normal(keys[0], (t, e)), -1)
+    co = jax.random.uniform(keys[1], (t * k,), minval=0.5)
+    w = jax.random.uniform(keys[2], (t * k,), minval=0.05)
+    idx = jax.lax.top_k(scores, k)[1]
+    key = jnp.where(idx < eh, idx, eh).reshape(-1)
+    args = (jnp.zeros((e,)), jnp.ones((t,), bool))
+    kw = dict(top_k=k, held_lo=0, e_held=eh, route_scale=1.0,
+              route_norm=True)
+
+    def gathered(key, w):
+        order = jnp.argsort(key, stable=True)
+        return order, w[order]
+
+    line = dict(piece="the plan alone", tokens=t, pairs=t * k, experts=e,
+                held_experts=eh)
+    # (the indices and the keys are arguments: a closed-over key's sort is
+    # folded at compile time)
+    line["argsort_ms"], _ = _timed(
+        lambda key: jnp.argsort(key, stable=True), iters, key)
+    own = moe._kept_scores, moe._sorted_pairs
+    outs = []
+    for name, kept, sorted_pairs in (
+            ("form", moe._kept_scores, moe._sorted_pairs()),
+            ("reference_form",
+             lambda s, idx: jnp.take_along_axis(s, idx, axis=1), gathered)):
+        moe._kept_scores, moe._sorted_pairs = kept, lambda: sorted_pairs
+        try:
+            # a function object of its own a form: jit caches by it
+            ms_f, fwd = _timed(
+                lambda s: moe._pair_plan(s, *args, **kw)[3:], iters, scores)
+            ms_fb, (_, grad) = _timed(jax.value_and_grad(lambda s: jnp.sum(
+                moe._pair_plan(s, *args, **kw)[5] * co)), iters, scores)
+        finally:
+            moe._kept_scores, moe._sorted_pairs = own
+        kept_f, _ = _timed(kept, iters, scores, idx)
+        kept_fb, _ = _timed(jax.value_and_grad(lambda s, idx: jnp.sum(
+            kept(s, idx) * co.reshape(t, k))), iters, scores, idx)
+        sort_f, _ = _timed(lambda key, w: sorted_pairs(key, w)[1], iters,
+                           key, w)
+        sort_fb, _ = _timed(jax.value_and_grad(lambda w, key: jnp.sum(
+            sorted_pairs(key, w)[1] * co)), iters, w, key)
+        line[name] = dict(
+            fwd_ms=ms_f, fwd_bwd_ms=ms_fb, kept_fwd_ms=kept_f,
+            kept_fwd_bwd_ms=kept_fb, sorted_weights_fwd_ms=sort_f,
+            sorted_weights_fwd_bwd_ms=sort_fb,
+            four_ops_ms=kept_fb + sort_fb - line["argsort_ms"])
+        outs.append((fwd, grad))
+    ((rows, sizes, ws), grad), ((rows_r, sizes_r, ws_r), grad_r) = outs
+    line.update(
+        rows_and_sizes_equal=bool(jnp.all(rows == rows_r)
+                                  & jnp.all(sizes == sizes_r)),
+        w_sorted_max_diff=float(jnp.max(jnp.abs(ws - ws_r))),
+        grad_max_diff_of_scale=float(jnp.max(jnp.abs(grad - grad_r))
+                                     / jnp.max(jnp.abs(grad_r))))
     return line
 
 
@@ -215,6 +298,7 @@ def main():
                           / ms_w * 1e3),
         compile_s=[c_r, c_w]))
     rows.append(combine_alone(t, k, e, eh, h, n, args.iters))
+    rows.append(plan_alone(t, k, e, eh))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/routed_train_bench.jsonl", "w") as fh:
         for r in rows:
