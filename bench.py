@@ -58,18 +58,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 def main():
     import argparse
 
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--profile", default="",
-                    help="tuned profile (tools/autotune.py offline) to "
-                         "apply before the run — the next chip run "
-                         "starts from the tuned point, and the row's "
-                         "extra.tuned_profile records the provenance")
-    args = ap.parse_args()
-    if args.profile:
-        from paddle_tpu.core import tuner
-
-        tuner.apply_profile(tuner.load_profile(args.profile),
-                            origin_path=args.profile)
+    argparse.ArgumentParser(description=__doc__.split("\n\n")[0]).parse_args()
 
     from tools.bench_models import bench_ernie_large, finalize_bench_result
 

@@ -91,21 +91,20 @@ def all_flags() -> Dict[str, Any]:
 
 
 # -- typed snapshot / apply / scoped-override API ----------------------------
-# (the config surface the autotuner searches over: candidate application
-# and rollback must be validated and exactly reversible — no ad-hoc
+# (application and rollback are validated and exactly reversible — no ad-hoc
 # monkeypatching of flag values)
 
 def snapshot() -> Dict[str, Any]:
     """Copy of every flag's CURRENT value, keyed by bare name — the
-    incumbent config an autotune trial (core/tuner.py) or a test rolls
-    back to. ``apply(snapshot())`` is an exact restore."""
+    config a test rolls back to. ``apply(snapshot())`` is an exact
+    restore."""
     return {n: f.value for n, f in _REGISTRY.items()}
 
 
 def apply(overrides: Dict[str, Any]) -> Dict[str, Any]:
     """Validated bulk override: every name is resolved (typed
     UnknownFlagError on a typo) and every value coerced BEFORE any flag
-    changes, so a half-applied candidate config is impossible. Returns
+    changes, so a half-applied config is impossible. Returns
     {bare_name: prior_value} of the touched flags — feed it back to
     ``apply`` to roll back."""
     resolved: Dict[str, Any] = {}
@@ -145,8 +144,7 @@ def parse_buckets(spec, name: str = "buckets",
     string or a sequence of ints). Boundaries must be POSITIVE integers
     in STRICTLY increasing order — a zero-valued or non-monotonic list
     raises a typed BucketConfigError instead of being silently
-    reordered/deduped (a config surface the autotuner searches must
-    reject malformed points loudly). ``cover`` demands the last boundary
+    reordered/deduped. ``cover`` demands the last boundary
     reach it (``cover_exact`` demands equality — the decode engine's
     fixed-step-shape contract). Returns None for an empty spec (caller
     default applies)."""
@@ -193,16 +191,19 @@ define_flag("check_nan_inf", False,
             "after each executor run (reference: flags.cc:44, "
             "details/nan_inf_utils_detail.cc)")
 define_flag("benchmark", False, "sync + time every executor run")
-define_flag("eager_delete_tensor_gb", 0.0,
-            "GC threshold (XLA owns buffer lifetime; API compat)")
-define_flag("fraction_of_gpu_memory_to_use", 0.92,
-            "accelerator memory fraction (XLA preallocation; API compat)")
-define_flag("paddle_num_threads", 1, "intra-op host threads (API compat)")
-define_flag("use_pinned_memory", True, "host staging buffers (API compat)")
-define_flag("cudnn_deterministic", False,
-            "deterministic kernels (XLA is deterministic by default)")
-define_flag("max_inplace_grad_add", 0,
-            "grad accumulation chunking (API compat)")
+# Reference flags a ported script may still set: accepted by set_flags and
+# the environment, readable by get_flags, read by nothing (XLA owns buffer
+# lifetime, preallocation, host threads and determinism).
+ACCEPTED_AND_IGNORED = {
+    "eager_delete_tensor_gb": 0.0,
+    "fraction_of_gpu_memory_to_use": 0.92,
+    "paddle_num_threads": 1,
+    "use_pinned_memory": True,
+    "cudnn_deterministic": False,
+    "max_inplace_grad_add": 0,
+}
+for _name, _default in ACCEPTED_AND_IGNORED.items():
+    define_flag(_name, _default, "reference flag: accepted and ignored")
 define_flag("verify_program", False,
             "static program verification gate (core/verify.py): every "
             "program an Executor runs is checked once per (program, "
@@ -429,13 +430,6 @@ define_flag("disagg_prefill_urls", "",
             "usually the ROUTER url — the router forwards /v1/prefill "
             "to a ready prefill-tier replica, so tier membership "
             "changes never strand a decode replica")
-define_flag("decode_journal_stride", 1,
-            "decode steps between session-journal snapshots replicated "
-            "to the router (serving/session.py): 1 journals every "
-            "accepted token (a failover never replays more than the "
-            "in-flight step), larger strides trade replication traffic "
-            "for re-generated tokens on decode-replica death; <= 0 "
-            "disables journaling")
 define_flag("decode_step_delay_ms", 0.0,
             "deliberate per-decode-step host-side delay — a chaos/bench "
             "pacing knob (tools/chaos_check.py --orchestrator, "
@@ -452,38 +446,6 @@ define_flag("router_health_interval_s", 0.2,
             "seconds between router health/stats probes of each replica "
             "(GET /healthz + /v1/stats): readiness gates routing, scraped "
             "queue_depth drives least-loaded balancing")
-define_flag("router_max_retries", 4,
-            "max retry/failover attempts per routed request beyond the "
-            "first — each retry prefers a replica not yet tried for the "
-            "request (router.retries / router.failovers count them)")
-define_flag("router_backoff", 0.02,
-            "base seconds for the router's exponential retry backoff "
-            "(core/retry.py schedule: doubles per attempt, +/-50% "
-            "jitter, capped at 1s, clipped to the request deadline)")
-define_flag("router_timeout_s", 30.0,
-            "total per-request budget in seconds when the client sends "
-            "no deadline_ms — retries and failovers all stop when it "
-            "elapses; <= 0 disables")
-define_flag("router_dispatch_timeout_s", 10.0,
-            "cap on a SINGLE dispatch attempt's socket timeout (the "
-            "request's remaining deadline still applies when smaller) — "
-            "bounds how long one dead-but-accepting replica can stall a "
-            "request before failover")
-define_flag("router_dedup_capacity", 1024,
-            "bound on the router's request-id dedup cache: a client retry "
-            "carrying an X-Request-Id already answered replays the cached "
-            "response (router.dedup_hits) instead of re-dispatching — "
-            "exactly-once serving under client retries (/v1/infer AND "
-            "/v1/generate); <= 0 disables")
-define_flag("router_session_capacity", 4096,
-            "bound on the router's decode-session journal "
-            "(serving/session.py SessionJournal): completed sessions are "
-            "popped at response time, abandoned ones age out LRU at this "
-            "capacity (session.evicted); <= 0 disables the bound")
-define_flag("serving_model_poll_s", 0.5,
-            "seconds between cluster-controller polls of the published-"
-            "models root (checkpoint.ModelWatcher): a new verified COMMIT "
-            "manifest triggers the rolling zero-downtime swap")
 define_flag("cluster_max_restarts", 5,
             "respawn budget per replica process: a replica that dies is "
             "relaunched (router.replica_restarts) up to this many times "
@@ -544,9 +506,6 @@ define_flag("incident_rate_limit_s", 30.0,
             "dumps (per-rule cooldowns apply on top): a storm of trips "
             "books incidents.rate_limited instead of flooding the log; "
             "legacy oom/stall/thread_error records are never suppressed")
-define_flag("incident_ring_records", 256,
-            "max flight-recorder records embedded in one incident dump "
-            "(newest kept) — bounds the dump's JSONL line size")
 
 define_flag("sanitize_locks", False,
             "runtime concurrency sanitizer (core/analysis/lockdep.py, "
@@ -570,42 +529,6 @@ define_flag("lock_stall_s", 30.0,
             "stack, held locks and waited lock into the run log as one "
             "kind:'stall' record (lock.stalls counts them) — wedged-"
             "process forensics captured while it is still wedged")
-# -- cost-model-guided autotuner (core/tuner.py + tools/autotune.py:
-#    offline replay search + online A/B promotion over this very flag
-#    surface; reference analogs: the hand-tuned ExecutionStrategy/
-#    BuildStrategy heuristics + DistributedStrategy auto mode) ----------------
-
-define_flag("tuner_traffic_fraction", 0.25,
-            "bounded traffic slice the router steers onto the trial "
-            "replica during an online A/B trial (core/tuner.py "
-            "OnlineTrial): every ~1/fraction-th routed request goes to "
-            "the trial arm, the rest stay on the control fleet; clamped "
-            "to (0, 0.5] so the control arm always carries the majority")
-define_flag("tuner_eval_interval_s", 1.0,
-            "seconds between two online-trial evaluation ticks (arm "
-            "stats scrape + SLO check + promote/abort decision)")
-define_flag("tuner_min_requests", 8,
-            "min requests the TRIAL arm must have served before a "
-            "promote/abort verdict is reached on latency deltas (an SLO "
-            "trip aborts immediately regardless)")
-define_flag("tuner_promote_ratio", 0.95,
-            "promotion gate: the trial arm's windowed p99 must be <= "
-            "control p99 * this ratio (i.e. at least a 5% win by "
-            "default) for the candidate to be promoted fleet-wide")
-define_flag("tuner_abort_ratio", 1.25,
-            "abort gate: a trial arm whose windowed p99 exceeds control "
-            "p99 * this ratio is rolled back without waiting for the "
-            "full trial budget")
-define_flag("tuner_max_evals", 10,
-            "evaluation ticks an online trial runs before it gives a "
-            "final verdict (undecided trials roll back — the incumbent "
-            "keeps the fleet)")
-define_flag("tuner_hbm_capacity_bytes", 0,
-            "per-device HBM capacity the offline tuner's headroom "
-            "constraint gates batch-size candidates against (candidate "
-            "rejected when its projected ledger total exceeds capacity * "
-            "0.92); 0 disables the gate when no measured ledger capacity "
-            "is available (CPU container)")
 
 # -- fleet observatory + goodput ledger (core/fleetobs.py, core/goodput.py;
 #    reference analogs: heart_beat_monitor.h fleet liveness, monitor.h stat
@@ -633,20 +556,6 @@ define_flag("fleet_min_members", 3,
             "minimum members with fresh latency evidence before "
             "straggler z-scores are computed — outlier math on 2 "
             "members is a coin flip")
-define_flag("fleet_straggler_metric",
-            "serving.request_ms,router.dispatch_ms,executor.run_ms,"
-            "executor.run_steps_ms",
-            "comma list of latency histograms tried in order as the "
-            "per-member straggler/step-time evidence (first one a "
-            "member exposes wins)")
-define_flag("fleet_qps_floor", 0.0,
-            "fleet-level SLO: aggregate request throughput (fleet.qps) "
-            "below this floor trips the fleet_qps_floor rule; 0 "
-            "disables the rule")
-define_flag("fleet_queue_saturation", 0.9,
-            "fleet-level SLO: fraction of the per-replica admission "
-            "bound (FLAGS_serving_max_queue_depth) the fleet-AVERAGE "
-            "queue depth may reach before fleet_queue_saturation trips")
 define_flag("goodput_publish_s", 2.0,
             "seconds between goodput-ledger publishes on the executor "
             "hot path (goodput.* counters + the goodput.ratio gauge "
@@ -672,34 +581,6 @@ define_flag("elastic_restart_window_s", 0.0,
             "budget: only restarts inside the window count against "
             "max_restarts, so sustained progress refunds the crash "
             "budget. 0 keeps the legacy lifetime counter")
-define_flag("elastic_drain_timeout_s", 30.0,
-            "bound on joining the async checkpoint writer when an "
-            "ElasticRunner drains under SIGTERM (distributed/elastic.py "
-            "request_drain): the final force-save is awaited at most "
-            "this long so a wedged writer cannot stall process "
-            "termination past the supervisor's kill escalation; the "
-            "atomic rename commit still guarantees no torn checkpoint "
-            "is ever restored")
-define_flag("orch_max_restarts", 3,
-            "per-child respawn budget of the supervising launcher "
-            "(distributed/launch.py Orchestrator): a trainer/pserver "
-            "subprocess that dies is relaunched up to this many times "
-            "inside orch_restart_window_s; exhaustion raises the typed "
-            "RestartBudgetExhaustedError instead of respawn-looping")
-define_flag("orch_restart_window_s", 0.0,
-            "sliding window (seconds) for the orchestrator's per-child "
-            "restart budget — same refund semantics as "
-            "elastic_restart_window_s (orch.restart_budget_refunds); "
-            "0 = lifetime counter")
-define_flag("orch_ready_timeout_s", 30.0,
-            "seconds the orchestrator waits for a child's "
-            "PT_ORCH_READY announce line before treating the spawn as "
-            "failed; <= 0 skips the ready wait (children that never "
-            "announce are supervised from spawn)")
-define_flag("orch_drain_timeout_s", 15.0,
-            "seconds between the orchestrator's SIGTERM drain command "
-            "and SIGKILL escalation — the child's window to finish its "
-            "bounded final checkpoint and exit 0")
 define_flag("scaler_min_world", 1,
             "lower bound on the world size a ScalerPolicy may target — "
             "ScaleDown decisions clamp here (scaler.clamped counter)")

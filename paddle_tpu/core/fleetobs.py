@@ -150,6 +150,15 @@ def counts_from_cumulative(buckets: List[Tuple[float, int]]) -> List[int]:
     return out
 
 
+# latency histograms tried in order as a member's straggler / step-time
+# evidence: the first one it exposes wins
+STRAGGLER_METRICS = ("serving.request_ms", "router.dispatch_ms",
+                     "executor.run_ms", "executor.run_steps_ms")
+# share of the per-replica admission bound (FLAGS_serving_max_queue_depth)
+# the fleet-AVERAGE queue depth may reach before fleet_queue_saturation trips
+QUEUE_SATURATION = 0.9
+
+
 def detect_stragglers(latency_by_member: Dict[str, float],
                       zscore: Optional[float] = None,
                       min_members: Optional[int] = None) -> List[str]:
@@ -179,7 +188,7 @@ def fleet_rules() -> List[incidents.Rule]:
     """The fleet-level SLO rule set (PR 14 Rule engine over the fleet.*
     gauges this aggregator publishes). Evaluated by the aggregator's OWN
     Watchdog — the per-process default rule set stays untouched."""
-    rules = [
+    return [
         # any member past the staleness horizon (a stale burst after a
         # kill/partition; the episode clears when the member recovers
         # or is deregistered, so one kill trips exactly once)
@@ -193,16 +202,9 @@ def fleet_rules() -> List[incidents.Rule]:
         # fleet-average queue depth saturating the admission bound
         incidents.Rule("fleet_queue_saturation", "fleet.queue_frac",
                        kind="gauge",
-                       threshold=float(_flags.flag(
-                           "fleet_queue_saturation")),
+                       threshold=QUEUE_SATURATION,
                        direction="above", cooldown_s=60.0),
     ]
-    qps_floor = float(_flags.flag("fleet_qps_floor"))
-    if qps_floor > 0:
-        rules.append(incidents.Rule(
-            "fleet_qps_floor", "fleet.qps", kind="gauge",
-            threshold=qps_floor, direction="below", cooldown_s=60.0))
-    return rules
 
 
 # ---------------------------------------------------------------------------
@@ -366,11 +368,8 @@ class FleetAggregator:
         (falling back to lifetime mean on the first)."""
         if member.metrics is None:
             return None
-        names = [n.strip() for n in
-                 str(_flags.flag("fleet_straggler_metric")).split(",")
-                 if n.strip()]
         prev_h = (member.prev[1]["hists"] if member.prev else {})
-        for name in names:
+        for name in STRAGGLER_METRICS:
             key = "pt_" + re.sub(r"[^a-zA-Z0-9_]", "_", name)
             h = member.metrics["hists"].get(key)
             if not h or not h["count"]:
@@ -442,9 +441,7 @@ class FleetAggregator:
         # fleet percentile from exactly-merged bucket counts
         p99 = None
         merged = self.merged_buckets()
-        for name in [n.strip() for n in
-                     str(_flags.flag("fleet_straggler_metric")).split(",")
-                     if n.strip()]:
+        for name in STRAGGLER_METRICS:
             key = "pt_" + re.sub(r"[^a-zA-Z0-9_]", "_", name)
             if key in merged and sum(merged[key]) > 0:
                 p99 = telemetry.bucket_quantile(merged[key], 0.99)

@@ -136,6 +136,9 @@ def flight_recorder() -> FlightRecorder:
 
 # -- SLO rules ----------------------------------------------------------------
 
+# flight-recorder records embedded in one incident dump (newest kept):
+# bounds the dump's JSONL line
+RING_RECORDS = 256
 _RULE_KINDS = ("counter", "hist", "gauge")
 _DIRECTIONS = ("above", "below")
 
@@ -585,16 +588,12 @@ def report_incident(source: str, name: str, value=None,
         goodput_view = _goodput.breakdown()
     except Exception:
         pass
-    try:
-        ring_cap = int(_flags.flag("incident_ring_records"))
-    except Exception:
-        ring_cap = 256
     attrs: Dict[str, Any] = {
         "id": incident_id,
         "source": source,
         "trip_ts": round(now, 6),
         "context": dict(context or {}),
-        "ring": _recorder.snapshot(limit=ring_cap, now=now),
+        "ring": _recorder.snapshot(limit=RING_RECORDS, now=now),
         "ring_dropped": _recorder.dropped,
         "ledger": ledger,
         "traces": traces,

@@ -138,6 +138,11 @@ class RestartBudget:
 # ProgramVerifyError (a RuntimeError) names a corrupt PROGRAM: restoring
 # a checkpoint and re-running the same program would fail identically
 # forever, so it must re-raise (tests/test_verify.py pins this).
+# bound on joining the async checkpoint writer when a runner drains under
+# SIGTERM: a wedged writer cannot stall termination past the supervisor's
+# kill escalation (the atomic rename commit still means no torn checkpoint
+# is ever restored)
+DRAIN_TIMEOUT_S = 30.0
 RECOVERABLE = (RpcError, ConnectionError, OSError, TimeoutError)
 
 
@@ -242,23 +247,22 @@ class ElasticRunner:
         return self
 
     def _execute_drain(self, step: int) -> bool:
-        """Force-save and BOUND-join the async writer (FLAGS_elastic_
-        drain_timeout_s): a SIGTERM'd trainer must make its checkpoint
+        """Force-save and BOUND-join the async writer
+        (DRAIN_TIMEOUT_S): a SIGTERM'd trainer must make its checkpoint
         durable before the supervisor's kill-escalation deadline, and a
         wedged writer must not turn a drain into a hang. Returns True
         when the writer fully drained."""
-        timeout = float(_flags.flag("elastic_drain_timeout_s"))
         try:
             self.mgr.save(step, self.program, self.scope,
                           extras=self._extras(), force=True)
         except self.recoverable as e:
             _LOG.warning("elastic: drain checkpoint at step %d failed: "
                          "%r", step, e)
-        ok = self.mgr.wait_until_finished(timeout=timeout)
+        ok = self.mgr.wait_until_finished(timeout=DRAIN_TIMEOUT_S)
         if not ok:
             telemetry.counter_add("elastic.drain_timeouts", 1, step=step)
             _LOG.error("elastic: async writer still busy after %.1fs "
-                       "drain timeout at step %d", timeout, step)
+                       "drain timeout at step %d", DRAIN_TIMEOUT_S, step)
         telemetry.counter_add("elastic.drains", 1, step=step)
         self.drained_at = int(step)
         return ok
@@ -408,7 +412,7 @@ class ElasticRunner:
             # drain exit unboundedly on top of that.
             if self._drain.is_set():
                 self.mgr.wait_until_finished(
-                    timeout=float(_flags.flag("elastic_drain_timeout_s")))
+                    timeout=DRAIN_TIMEOUT_S)
             else:
                 self.mgr.wait_until_finished()
         return result

@@ -49,9 +49,18 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from ..core import chips
-from ..core import flags as _flags
 from ..core import telemetry
 
+# a child's respawn budget, counted over a sliding window of seconds
+# (0 = its lifetime; RestartBudget refunds as elastic_restart_window_s does):
+# exhaustion raises RestartBudgetExhaustedError instead of respawn-looping
+MAX_RESTARTS = 3
+RESTART_WINDOW_S = 0.0
+# seconds to wait for a child's PT_ORCH_READY line before the spawn counts
+# as failed (<= 0 supervises from spawn), and between the SIGTERM drain
+# command and SIGKILL: the child's window for its bounded final checkpoint
+READY_TIMEOUT_S = 30.0
+DRAIN_TIMEOUT_S = 15.0
 READY_MARK = "PT_ORCH_READY"
 HB_MARK = "PT_ORCH_HB"
 
@@ -197,10 +206,10 @@ class Orchestrator:
                  pserver_argv: Optional[List[str]] = None,
                  n_pservers: int = 0,
                  env: Optional[Dict[str, str]] = None,
-                 max_restarts: Optional[int] = None,
-                 restart_window_s: Optional[float] = None,
-                 ready_timeout_s: Optional[float] = None,
-                 drain_timeout_s: Optional[float] = None,
+                 max_restarts: int = MAX_RESTARTS,
+                 restart_window_s: float = RESTART_WINDOW_S,
+                 ready_timeout_s: float = READY_TIMEOUT_S,
+                 drain_timeout_s: float = DRAIN_TIMEOUT_S,
                  schedule=None,
                  on_line: Optional[Callable[[str, str], None]] = None):
         from .elastic import RestartBudget
@@ -211,18 +220,10 @@ class Orchestrator:
         self.pserver_argv = list(pserver_argv) if pserver_argv else None
         self.n_pservers = int(n_pservers) if pserver_argv else 0
         self.env = dict(os.environ if env is None else env)
-        self.max_restarts = int(
-            _flags.flag("orch_max_restarts") if max_restarts is None
-            else max_restarts)
-        self.restart_window_s = float(
-            _flags.flag("orch_restart_window_s")
-            if restart_window_s is None else restart_window_s)
-        self.ready_timeout_s = float(
-            _flags.flag("orch_ready_timeout_s")
-            if ready_timeout_s is None else ready_timeout_s)
-        self.drain_timeout_s = float(
-            _flags.flag("orch_drain_timeout_s")
-            if drain_timeout_s is None else drain_timeout_s)
+        self.max_restarts = int(max_restarts)
+        self.restart_window_s = float(restart_window_s)
+        self.ready_timeout_s = float(ready_timeout_s)
+        self.drain_timeout_s = float(drain_timeout_s)
         self.budget = RestartBudget(
             self.max_restarts, self.restart_window_s,
             on_refund=lambda n: telemetry.counter_add(
@@ -452,11 +453,11 @@ def main(argv=None):
                         help="supervise children: crash detection + "
                              "respawn under the windowed restart budget, "
                              "SIGTERM-drain stop, scheduled resizes")
-    parser.add_argument("--max-restarts", type=int, default=-1,
-                        help="crash budget (< 0 = FLAGS_orch_max_restarts)")
-    parser.add_argument("--restart-window-s", type=float, default=-1.0,
-                        help="sliding budget window (< 0 = "
-                             "FLAGS_orch_restart_window_s; 0 = lifetime)")
+    parser.add_argument("--max-restarts", type=int, default=MAX_RESTARTS,
+                        help="crash budget of each child")
+    parser.add_argument("--restart-window-s", type=float,
+                        default=RESTART_WINDOW_S,
+                        help="sliding budget window (0 = lifetime)")
     parser.add_argument("--resize-schedule", default="",
                         help="'step:world,step:world' — execute_scale to "
                              "WORLD once any trainer reports STEP "
@@ -486,10 +487,8 @@ def main(argv=None):
             pserver_argv=[sys.executable, args.pserver_script]
             if args.pserver_script else None,
             n_pservers=args.npserver,
-            max_restarts=args.max_restarts
-            if args.max_restarts >= 0 else None,
-            restart_window_s=args.restart_window_s
-            if args.restart_window_s >= 0 else None,
+            max_restarts=args.max_restarts,
+            restart_window_s=args.restart_window_s,
             schedule=schedule,
             on_line=lambda name, line: print(f"[{name}] {line}",
                                              flush=True))

@@ -59,6 +59,12 @@ from ..core.flags import flag as _flag
 from .router import Router, RouterHTTPServer, _http_json
 
 
+# seconds between polls of the published-models root
+# (checkpoint.ModelWatcher): a new verified COMMIT manifest starts the
+# rolling swap
+MODEL_POLL_S = 0.5
+
+
 class ClusterError(RuntimeError):
     """Control-plane failure (replica never came up, swap never took)."""
 
@@ -296,7 +302,7 @@ class ClusterController:
                  replica_env: Optional[Dict[str, str]] = None,
                  router: Optional[Router] = None,
                  host: str = "127.0.0.1", router_port: int = 0,
-                 model_poll_s: Optional[float] = None,
+                 model_poll_s: float = MODEL_POLL_S,
                  max_restarts: Optional[int] = None,
                  replica_telemetry_dir: str = "",
                  auto_swap: bool = True,
@@ -310,9 +316,7 @@ class ClusterController:
         self.inprocess = bool(inprocess)
         self.serving_config = serving_config
         self.replica_env = replica_env
-        self.model_poll_s = float(
-            _flag("serving_model_poll_s") if model_poll_s is None
-            else model_poll_s)
+        self.model_poll_s = float(model_poll_s)
         self.max_restarts = int(
             _flag("cluster_max_restarts") if max_restarts is None
             else max_restarts)
@@ -677,60 +681,6 @@ class ClusterController:
                 raise ClusterError(
                     f"rolling swap to v{version}: replicas {failed} "
                     f"failed to swap (still serving their old version)")
-
-    # -- autotune trial support ---------------------------------------------
-    def replica_named(self, name: str):
-        return next((r for r in self.replicas if r.name == name), None)
-
-    def current_model_path(self) -> Optional[str]:
-        """Path of the fleet's CURRENT model version (None when it was
-        unpublished behind our back)."""
-        with self._swap_lock:
-            version = self.current_version
-        for v, path in _ckpt.list_model_versions(self.model_root):
-            if v == version:
-                return path
-        return None
-
-    def retune_replica(self, name: str, timeout: float = 120.0) -> bool:
-        """Re-swap ONE replica onto the fleet's CURRENT model version
-        with a ServingConfig rebuilt from the live flag surface
-        (POST /v1/admin/swap {reload_config: true}) — the online
-        autotuner's candidate-application lever (core/tuner.py): a
-        serving-config flip rides the exact zero-downtime warm-then-flip
-        machinery a model swap does, on one replica only. Returns
-        success; on failure the replica keeps its old config."""
-        replica = self.replica_named(name)
-        if replica is None or not replica.alive():
-            return False
-        path = self.current_model_path()
-        if path is None:
-            return False
-        with self._swap_lock:
-            version = self.current_version
-        try:
-            code, doc = _http_json(
-                "POST", replica.url, "/v1/admin/swap",
-                body=json.dumps({"model_dir": path, "version": version,
-                                 "reload_config": True}).encode(),
-                timeout=timeout)
-        except (ConnectionError, OSError) as e:
-            code, doc = -1, {"error": repr(e)}
-        ok = code == 200
-        telemetry.counter_add("router.swaps" if ok else "router.swap_errors",
-                              1, replica=name, version=version,
-                              reason="retune")
-        if ok:
-            # wait for readiness to return so the caller's next dispatch
-            # can already land on the retuned replica
-            handle = self._handles.get(name)
-            deadline = time.monotonic() + timeout
-            while handle is not None and time.monotonic() < deadline:
-                self.router.probe(handle)
-                if handle.ready:
-                    break
-                time.sleep(0.05)
-        return ok
 
     # -- elastic replica scaling --------------------------------------------
     def scale_to(self, n: int, reason: str = "manual",

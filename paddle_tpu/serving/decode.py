@@ -581,7 +581,6 @@ class DecodeEngine:
         # SessionJournal.update, cross-process an HTTP POST. None (the
         # default) disables journaling entirely.
         self.journal_sink = None
-        self._journal_stride = int(_flag("decode_journal_stride"))
 
     # -- client surface ------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -1544,24 +1543,20 @@ class DecodeEngine:
             - (time.perf_counter() - t0) * 1e3
 
     def _journal_tick(self):
-        """Replicate session snapshots to the router at step-boundary
-        cadence (serving/session.py). Runs on the worker thread right
-        after a step — the snapshot is a consistent cut: every accepted
-        token is in it, the RNG state has consumed exactly those draws.
-        A sink failure (router briefly down) only costs replay depth,
-        never the generation (session.journal_errors)."""
+        """Replicate session snapshots to the router after every step
+        (serving/session.py): a failover never replays more than the
+        step in flight. Runs on the worker thread right after a step —
+        the snapshot is a consistent cut: every accepted token is in it,
+        the RNG state has consumed exactly those draws. A sink failure
+        (router briefly down) only costs replay depth, never the
+        generation (session.journal_errors)."""
         sink = self.journal_sink
-        stride = self._journal_stride
-        if sink is None or stride <= 0:
+        if sink is None:
             return
         now = time.monotonic()
-        records = []
-        for req in self._active:
-            if req.session_id is None or not req.tokens:
-                continue
-            if (int(req.prior.size) + len(req.tokens)) % stride == 0:
-                records.append(
-                    req.journal_record(self.config.page_size, now))
+        records = [req.journal_record(self.config.page_size, now)
+                   for req in self._active
+                   if req.session_id is not None and req.tokens]
         if not records:
             return
         try:

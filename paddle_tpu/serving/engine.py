@@ -74,8 +74,7 @@ class ServingConfig:
             else default_deadline_ms)
         # strict typed parse (core/flags.py): a zero-valued or
         # non-monotonic bucket list raises BucketConfigError instead of
-        # being silently reordered — the autotuner searches this surface
-        # and malformed points must be loud
+        # being silently reordered
         if buckets is None:
             buckets = _flags.parse_buckets(_flag("serving_buckets"),
                                            "FLAGS_serving_buckets")
@@ -178,9 +177,6 @@ class ServingEngine:
         out["model_version"] = self.version
         out["status"] = self.health.state
         out["ready"] = self.health.is_ready()
-        # the live serving config (an autotune trial flips it via
-        # swap_predictor(config=...) — visible here so the trial can
-        # verify the candidate actually took)
         out["serving_config"] = {
             "max_batch_size": self.config.max_batch_size,
             "batch_timeout_ms": self.config.batch_timeout_ms,
@@ -246,15 +242,12 @@ class ServingEngine:
         self._publish_bucket_costs(costs)
         return fresh
 
-    def _warm(self, predictor, locked: bool = False, config=None):
+    def _warm(self, predictor, locked: bool = False):
         """Run every bucket through ``predictor`` once; returns (fresh
         compile count, {bucket: ProgramCost}). ``locked`` guards runs of
         the LIVE predictor with the infer lock; a swap candidate is
         private until the flip, and warming it unlocked keeps the old
-        predictor serving (zero downtime) while the new one compiles.
-        ``config`` warms a swap CANDIDATE's bucket set (a config flip
-        rides the same machinery as a model flip)."""
-        config = config or self.config
+        predictor serving (zero downtime) while the new one compiles."""
         specs = predictor.feed_specs()
         for n, (shape, _dtype) in specs.items():
             if any(d is None or d < 0 for d in shape[1:]):
@@ -263,7 +256,7 @@ class ServingEngine:
         before = telemetry.counter_get("predictor.compiles")
         costs: Dict[int, Any] = {}
         with telemetry.timer("serving.warmup_ms"):
-            for b in config.buckets:
+            for b in self.config.buckets:
                 feed = {n: np.zeros((b,) + tuple(shape[1:]), dtype=dtype)
                         for n, (shape, dtype) in specs.items()}
                 if locked:
@@ -296,7 +289,7 @@ class ServingEngine:
         costmodel.refresh_ledger()
 
     def swap_predictor(self, predictor, version: Optional[int] = None,
-                       warmup: bool = True, config=None) -> int:
+                       warmup: bool = True) -> int:
         """Zero-downtime model swap: warm every bucket on the NEW
         predictor while the old one keeps serving, then flip atomically
         under the infer lock (the in-flight batch completes on the old
@@ -305,14 +298,7 @@ class ServingEngine:
         duration so a router drains new traffic away from the warming
         replica. Returns the number of fresh warmup compiles; on any
         failure the old predictor stays live and readiness is restored.
-        ``replica.swap`` is a fault-injection site (core/faults.py).
-
-        ``config`` flips the ServingConfig (bucket set / batch bounds)
-        together with the predictor — the autotuner's online A/B trial
-        (core/tuner.py) rides this to apply a candidate serving config
-        to ONE replica with the same warm-then-flip safety as a model
-        swap. Admission-queue bounds (max_queue_depth, default deadline)
-        are fixed at engine construction and are NOT flipped."""
+        ``replica.swap`` is a fault-injection site (core/faults.py)."""
         with self._swap_lock:
             faults.maybe_fail("replica.swap", version=version)
             # clients feed by NAME and read outputs by the engine's stable
@@ -329,13 +315,10 @@ class ServingEngine:
             with ReadyGate(self.health, SWAPPING), \
                     telemetry.timer("serving.swap_ms"):
                 # pt-lint: disable=blocking-call-under-lock(the swap lock serialises SWAPS only — warmup compiles run unlocked while the old predictor keeps serving; that is the zero-downtime design)
-                fresh, costs = self._warm(predictor, locked=False,
-                                          config=config) \
+                fresh, costs = self._warm(predictor, locked=False) \
                     if warmup else (0, {})
                 with self._infer_lock:
                     self.predictor = predictor
-                    if config is not None:
-                        self.config = config
                     if version is not None:
                         self.version = int(version)
                 self._publish_bucket_costs(costs)

@@ -17,7 +17,7 @@ and monitors them; this module never owns a process). Three jobs:
   next pick skips it without waiting for the probe;
 * **dedup** — every request carries an id (client ``X-Request-Id`` or
   router-minted). Successful responses are cached in a bounded map for
-  ``FLAGS_router_dedup_capacity`` ids, so a CLIENT retry of an
+  ``DEDUP_CAPACITY`` ids, so a CLIENT retry of an
   already-answered id replays the response (``router.dedup_hits``)
   instead of re-dispatching — with the replica hop being pure inference,
   this closes the exactly-once loop end to end: one accepted request id,
@@ -44,13 +44,28 @@ import json
 import threading
 import time
 import zlib
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..core import faults, incidents, retry, telemetry, trace
 from ..core.analysis import lockdep
 from ..core.flags import flag as _flag
 from .admission import ServingError
+
+
+# attempts per routed request beyond the first (each prefers a replica not
+# yet tried) and the base of their backoff (core/retry.py: doubles per
+# attempt, +/-50% jitter, capped at 1 s, clipped to the request's deadline)
+MAX_RETRIES = 4
+BACKOFF_S = 0.02
+# a request's whole budget when the client sends no deadline_ms, and the
+# cap on ONE attempt's socket timeout, which bounds how long a
+# dead-but-accepting replica stalls a request before failover
+TIMEOUT_S = 30.0
+DISPATCH_TIMEOUT_S = 10.0
+# answered request ids kept for replay to a client's retry (exactly-once
+# on /v1/infer and /v1/generate)
+DEDUP_CAPACITY = 1024
 
 
 class NoReplicaAvailableError(ServingError):
@@ -76,11 +91,6 @@ class ReplicaHandle:
         self.model_version: Optional[int] = None
         self.last_probe_t = 0.0
         self.consecutive_failures = 0
-        # bounded ring of (epoch_ts, ms) of successful dispatches: the
-        # per-ARM latency evidence an online autotune trial compares
-        # (router-side so it works for the in-process cluster backend,
-        # whose replicas share one telemetry registry)
-        self.dispatch_samples: "deque" = deque(maxlen=512)
 
     # -- state updates (probe thread + dispatch path) ------------------------
     def mark_probe(self, ready: bool, stats: Optional[Dict[str, Any]] = None):
@@ -124,15 +134,6 @@ class ReplicaHandle:
             self.queue_depth = 0
             self.inflight = 0
             self.consecutive_failures = 0
-
-    def record_dispatch(self, ms: float):
-        with self._lock:
-            self.dispatch_samples.append((time.time(), float(ms)))
-
-    def dispatch_latencies(self, since_ts: float = 0.0) -> List[float]:
-        with self._lock:
-            return [ms for ts, ms in self.dispatch_samples
-                    if ts >= since_ts]
 
     # -- balancing -----------------------------------------------------------
     def probe_age_s(self) -> Optional[float]:
@@ -208,8 +209,7 @@ class Router:
     def __init__(self, policy: Optional[retry.RetryPolicy] = None,
                  health_interval_s: Optional[float] = None):
         self.policy = policy or retry.RetryPolicy(
-            max_retries=int(_flag("router_max_retries")),
-            backoff=float(_flag("router_backoff")),
+            max_retries=MAX_RETRIES, backoff=BACKOFF_S,
             deadline=None)   # per-request deadline is applied per call
         self.health_interval_s = float(
             _flag("router_health_interval_s") if health_interval_s is None
@@ -222,14 +222,8 @@ class Router:
         # payload). Bounded FIFO over done entries.
         self._dedup: "OrderedDict[str, tuple]" = OrderedDict()
         self._dedup_lock = lockdep.lock("router.dedup")
-        self._dedup_cap = int(_flag("router_dedup_capacity"))
         self._ids = 0
         self._rr = 0   # rotating tie-break offset for equal load scores
-        # online A/B traffic split (core/tuner.py OnlineTrial): when set,
-        # every period-th pick steers to the trial replica and every
-        # other pick EXCLUDES it, so each arm's latency evidence is pure
-        self._trial: Optional[Tuple[str, float]] = None
-        self._trial_count = 0
         # fleet observatory tap (core/fleetobs.FleetAggregator): when
         # attached, pick() deprioritises flagged stragglers and the
         # front end serves /fleet/status + /fleet/metrics
@@ -298,30 +292,6 @@ class Router:
             self._probe_thread = None
         incidents.disarm()
 
-    # -- A/B traffic split (online autotune trials) --------------------------
-    def set_trial(self, replica_name: str, fraction: Optional[float] = None):
-        """Steer a bounded slice of traffic onto `replica_name`: every
-        ~1/fraction-th routed request dispatches there, the rest stay on
-        the control fleet (and skip the trial replica, keeping both
-        arms' latency samples pure). Fraction clamps to (0, 0.5] — the
-        control arm always carries the majority."""
-        if fraction is None:
-            fraction = float(_flag("tuner_traffic_fraction"))
-        fraction = min(max(float(fraction), 0.01), 0.5)
-        with self._lock:
-            self._trial = (replica_name, fraction)
-            self._trial_count = 0
-        telemetry.counter_add("router.trial_split_set", 1,
-                              replica=replica_name, fraction=fraction)
-
-    def clear_trial(self):
-        with self._lock:
-            self._trial = None
-
-    def trial(self) -> Optional[Tuple[str, float]]:
-        with self._lock:
-            return self._trial
-
     # -- fleet observatory ----------------------------------------------------
     def attach_fleet(self, aggregator):
         """Wire a core/fleetobs.FleetAggregator into the router: pick()
@@ -346,44 +316,13 @@ class Router:
         """READY replica with the lowest load score, skipping `exclude`;
         None when nothing is routable. Equal scores round-robin (a
         rotating start offset), so an idle fleet shares work instead of
-        hammering the first replica.
-
-        With a trial traffic split active (set_trial), the steering
-        schedule decides the arm first: a steered pick returns the trial
-        replica (when ready), any other pick excludes it — unless the
-        trial replica is the ONLY routable one, where availability beats
-        arm purity."""
+        hammering the first replica."""
         handles = self.handles()
         if not handles:
             return None
         with self._lock:
             self._rr += 1
             offset = self._rr
-            trial = self._trial
-            steer = False
-            if trial is not None:
-                self._trial_count += 1
-                period = max(2, int(round(1.0 / trial[1])))
-                steer = (self._trial_count % period) == 0
-        if trial is not None:
-            trial_handle = next((h for h in handles
-                                 if h.name == trial[0]), None)
-            if trial_handle is not None and trial_handle not in exclude:
-                if steer and trial_handle.ready:
-                    telemetry.counter_quiet("router.trial_dispatches")
-                    return trial_handle
-                if not steer:
-                    control = self._pick_from(handles, offset,
-                                              set(exclude) | {trial_handle})
-                    if control is not None:
-                        telemetry.counter_quiet(
-                            "router.trial_control_dispatches")
-                        return control
-                    # no control replica routable: fall through and let
-                    # the trial replica carry the request
-        return self._pick_from(handles, offset, exclude)
-
-    def _pick_from(self, handles, offset, exclude) -> Optional[ReplicaHandle]:
         best = None
         best_score = None
         # fleet-flagged stragglers lose the first pass: with an attached
@@ -425,8 +364,6 @@ class Router:
         """None -> this caller owns the id (dispatch it). Otherwise the
         cached ("done", code, payload) to replay — waiting out an
         in-flight original first, like the PS server's dedup."""
-        if self._dedup_cap <= 0:
-            return None
         while True:
             with self._dedup_lock:
                 entry = self._dedup.get(request_id)
@@ -441,13 +378,11 @@ class Router:
 
     def _dedup_publish(self, request_id: str, code: int,
                        payload: Dict[str, Any]):
-        if self._dedup_cap <= 0:
-            return
         with self._dedup_lock:
             entry = self._dedup.get(request_id)
             if code == 200:
                 self._dedup[request_id] = ("done", code, payload)
-                while len(self._dedup) > self._dedup_cap:
+                while len(self._dedup) > DEDUP_CAPACITY:
                     # evict the oldest DONE entry; in-flight ones are live
                     for key in self._dedup:
                         if self._dedup[key][0] == "done":
@@ -494,7 +429,7 @@ class Router:
         """Route one inference request: returns (http_code, payload).
 
         Retries transport failures and retryable replica statuses on the
-        surviving fleet under min(deadline_ms, FLAGS_router_timeout_s);
+        surviving fleet under min(deadline_ms, TIMEOUT_S);
         replays the cached response for an already-answered request id.
         Never raises — the answer is always an HTTP-shaped (code, doc)."""
         t0 = time.perf_counter()
@@ -511,17 +446,15 @@ class Router:
             payload["deduped"] = True
             return cached[1], payload
 
-        budget_s = float(_flag("router_timeout_s"))
+        budget_s = TIMEOUT_S
         if deadline_ms is not None and deadline_ms > 0:
-            budget_s = min(budget_s, deadline_ms / 1e3) \
-                if budget_s > 0 else deadline_ms / 1e3
+            budget_s = min(budget_s, deadline_ms / 1e3)
         policy = retry.RetryPolicy(
             max_retries=self.policy.max_retries,
             backoff=self.policy.backoff,
-            deadline=budget_s if budget_s > 0 else None,
+            deadline=budget_s,
             max_delay=self.policy.max_delay, jitter=self.policy.jitter)
         sched = policy.start()
-        per_try_cap = float(_flag("router_dispatch_timeout_s"))
 
         tried: set = set()
         prev_handle: Optional[ReplicaHandle] = None
@@ -549,15 +482,9 @@ class Router:
                 telemetry.counter_add("router.failovers", 1,
                                       frm=prev_handle.name, to=handle.name)
             prev_handle = handle
-            attempt_timeout = sched.remaining(default=per_try_cap)
-            if attempt_timeout is None:
-                attempt_timeout = per_try_cap
-            else:
-                attempt_timeout = min(attempt_timeout, per_try_cap)
+            attempt_timeout = min(sched.remaining(), DISPATCH_TIMEOUT_S)
             body_doc = {"inputs": inputs}
-            rem_ms = sched.remaining(default=None)
-            if rem_ms is not None:
-                body_doc["deadline_ms"] = max(rem_ms * 1e3, 1.0)
+            body_doc["deadline_ms"] = max(sched.remaining() * 1e3, 1.0)
             headers = {}
             if forward_request_id:
                 # the replica pins its root span to this id -> one trace
@@ -572,17 +499,11 @@ class Router:
                     with handle._lock:
                         handle.inflight += 1
                     try:
-                        t_disp = time.perf_counter()
                         with telemetry.timer("router.dispatch_ms"):
                             code, payload = _http_json(
                                 "POST", handle.url, "/v1/infer",
                                 body=json.dumps(body_doc).encode(),
                                 headers=headers, timeout=attempt_timeout)
-                        if code == 200:
-                            # per-arm latency evidence for online
-                            # autotune trials (core/tuner.py)
-                            handle.record_dispatch(
-                                (time.perf_counter() - t_disp) * 1e3)
                     finally:
                         with handle._lock:
                             handle.inflight -= 1
@@ -708,17 +629,15 @@ class Router:
             payload["deduped"] = True
             return cached[1], payload
 
-        budget_s = float(_flag("router_timeout_s"))
+        budget_s = TIMEOUT_S
         if deadline_ms is not None and deadline_ms > 0:
-            budget_s = min(budget_s, deadline_ms / 1e3) \
-                if budget_s > 0 else deadline_ms / 1e3
+            budget_s = min(budget_s, deadline_ms / 1e3)
         policy = retry.RetryPolicy(
             max_retries=self.policy.max_retries,
             backoff=self.policy.backoff,
-            deadline=budget_s if budget_s > 0 else None,
+            deadline=budget_s,
             max_delay=self.policy.max_delay, jitter=self.policy.jitter)
         sched = policy.start()
-        per_try_cap = float(_flag("router_dispatch_timeout_s"))
         body_doc: Dict[str, Any] = {
             "prompt_ids": [int(t) for t in prompt_ids],
             "temperature": float(temperature),
@@ -751,12 +670,8 @@ class Router:
                     "error": "no generate-capable replica available",
                     "request_id": rid}
                 break
-            attempt_timeout = sched.remaining(default=per_try_cap)
-            attempt_timeout = per_try_cap if attempt_timeout is None \
-                else min(attempt_timeout, per_try_cap)
-            rem = sched.remaining(default=None)
-            if rem is not None:
-                body_doc["deadline_ms"] = max(rem * 1e3, 1.0)
+            attempt_timeout = min(sched.remaining(), DISPATCH_TIMEOUT_S)
+            body_doc["deadline_ms"] = max(sched.remaining() * 1e3, 1.0)
             retryable_exc: Optional[BaseException] = None
             try:
                 faults.maybe_fail("router.dispatch", replica=handle.name)
@@ -842,8 +757,8 @@ class Router:
         URLs, so prefill-tier membership changes (respawn, scale)
         never strand them. Returns (status, body_bytes, content_type);
         CRC verification stays end-to-end in the decode replica."""
-        cap = float(_flag("router_dispatch_timeout_s"))
-        timeout = cap if timeout is None else min(timeout, cap)
+        timeout = DISPATCH_TIMEOUT_S if timeout is None \
+            else min(timeout, DISPATCH_TIMEOUT_S)
         tier = sorted((h for h in self.handles()
                        if h.ready and h.role == "prefill"),
                       key=lambda h: h.score())
@@ -897,9 +812,6 @@ class Router:
                if k.startswith("router.") and isinstance(v, (int, float))}
         out["replicas"] = [h.snapshot() for h in self.handles()]
         out["ready"] = self.ready()
-        t = self.trial()
-        if t is not None:
-            out["trial"] = {"replica": t[0], "fraction": t[1]}
         hists = telemetry.snapshot()["hists"]
         for key in ("router.request_ms", "router.dispatch_ms"):
             h = hists.get(key)

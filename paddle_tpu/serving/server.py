@@ -174,17 +174,7 @@ class _Handler(BaseHTTPRequestHandler):
                 if version is None:
                     version = manifest.get("version")
             predictor = create_predictor(AnalysisConfig(model_dir))
-            # reload_config: rebuild the ServingConfig from the CURRENT
-            # flag surface and flip it with the predictor — the
-            # autotuner's online A/B applies a candidate config to one
-            # replica through the same warm-then-flip machinery
-            config = None
-            if doc.get("reload_config"):
-                from .engine import ServingConfig
-
-                config = ServingConfig()
-            fresh = engine.swap_predictor(predictor, version=version,
-                                          config=config)
+            fresh = engine.swap_predictor(predictor, version=version)
         except Exception as e:   # verify/build/warm/injected failure:
             # the old predictor is still live — report, don't die
             self._reply(500, {"error": f"{type(e).__name__}: {e}"})

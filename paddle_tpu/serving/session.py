@@ -17,8 +17,8 @@ replicates into.
 Protocol (reference analog: the Fluid pserver re-sends a dead trainer's
 params — here the ROUTER is the survivor that re-seeds the work):
 
-* The engine snapshots every session-carrying request at step-boundary
-  cadence (FLAGS_decode_journal_stride) and hands the batch to its
+* The engine snapshots every session-carrying request after every step
+  and hands the batch to its
   ``journal_sink`` — in-process a plain callable, cross-process an HTTP
   POST to the router's ``/v1/session/journal``.
 * On decode-replica death the router rebuilds the submit from the last
@@ -46,7 +46,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..core import telemetry
-from ..core.flags import flag as _flag
 
 
 def pack_rng_state(rng: Optional[np.random.RandomState]) -> Optional[list]:
@@ -93,15 +92,19 @@ def resume_args(record: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+# sessions the router's journal keeps: a completed one is popped at
+# response time, an abandoned one ages out here (session.evicted)
+SESSION_CAPACITY = 4096
+
+
 class SessionJournal:
     """Router-side store of the latest snapshot per request id. Bounded
-    LRU (FLAGS_router_session_capacity): completed sessions are popped
+    LRU (SESSION_CAPACITY): completed sessions are popped
     by the router; abandoned ones age out at the capacity edge
     (session.evicted)."""
 
-    def __init__(self, capacity: Optional[int] = None):
-        self.capacity = int(_flag("router_session_capacity")
-                            if capacity is None else capacity)
+    def __init__(self, capacity: int = SESSION_CAPACITY):
+        self.capacity = int(capacity)
         self._records: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
         self._lock = threading.Lock()
 

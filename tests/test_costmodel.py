@@ -513,7 +513,7 @@ def _emitted_metric_names():
                     name = m.group(1).split("{", 1)[0]
                     if name.startswith(("cost.", "mem.", "costmodel.",
                                         "pallas.", "incidents.",
-                                        "slo.", "tuner.",
+                                        "slo.",
                                         "goodput.", "fleet.",
                                         "scaler.", "elastic.",
                                         "kv.", "disagg.",
@@ -548,11 +548,6 @@ class TestMetricDriftGuard:
         assert "incidents.rate_limited" in names
         assert "slo.trips" in names
         assert "slo.evaluations" in names
-        # the cost-model-guided autotuner (core/tuner.py)
-        assert "tuner.trials" in names
-        assert "tuner.promotions" in names
-        assert "tuner.rollbacks" in names
-        assert "tuner.constraint_rejections" in names
         # the goodput ledger (core/goodput.py) — badput_<phase> emits
         # via an f-string, so the scraped name is the static prefix
         assert "goodput.productive_ms" in names

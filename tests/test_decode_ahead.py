@@ -359,15 +359,14 @@ def test_a_journal_record_leaves_out_the_draw_made_ahead(engine):
             seen.append((len(rec["accepted"]), rec["rng_state"],
                          req._rng.get_state(), req.ahead))
 
-    stride = engine._journal_stride
-    engine._journal_stride, engine.journal_sink = 1, sink
+    engine.journal_sink = sink
     try:
         req = engine.submit(np.arange(50, 58), max_new_tokens=9, seed=77,
                             temperature=0.8, stop_at_eos=False,
                             request_id="ahead")
         req.result(60)
     finally:
-        engine._journal_stride, engine.journal_sink = stride, None
+        engine.journal_sink = None
     # cut after every accepted token but the last, which retires the request
     assert [n for n, _, _, _ in seen] == list(range(1, 9))
     for n, packed, live, ahead in seen:

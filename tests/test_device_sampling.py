@@ -180,7 +180,6 @@ def journaled_run():
     from paddle_tpu.serving.decode import DecodeConfig, demo_engine
 
     engine = demo_engine(DecodeConfig(**ENGINE_KW))
-    engine._journal_stride = 1
     records, returned = [], []
     engine.journal_sink = records.extend
     engine.warmup()

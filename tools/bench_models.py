@@ -329,13 +329,6 @@ def finalize_bench_result(out):
                     "sharding.optimizer_state_bytes_per_device"):
             if g.get(key) is not None:
                 ex[key.replace(".", "_")] = int(g[key])
-    # tuned-profile provenance (core/tuner.py): every row records which
-    # tuned profile (hash + origin run) produced its config — or the
-    # literal "hand-picked" — so BENCH history separates tuned rows from
-    # defaults and slo_check only compares like with like
-    from paddle_tpu.core import tuner
-
-    ex["tuned_profile"] = tuner.profile_provenance()
     # goodput ledger (core/goodput.py): every BENCH row embeds where the
     # run's wall-clock went — productive device compute vs the badput
     # phases — so a throughput regression is attributable (data stall?
@@ -384,16 +377,7 @@ def main():
     ap.add_argument("--workload", default="ernie_large")
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--batch", type=int, default=None)
-    ap.add_argument("--profile", default="",
-                    help="tuned profile (tools/autotune.py offline) to "
-                         "apply before the run; the row's "
-                         "extra.tuned_profile records its provenance")
     args = ap.parse_args()
-    if args.profile:
-        from paddle_tpu.core import tuner
-
-        tuner.apply_profile(tuner.load_profile(args.profile),
-                            origin_path=args.profile)
     kw = {}
     if args.steps:
         kw["steps"] = args.steps

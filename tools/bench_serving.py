@@ -1072,16 +1072,7 @@ def main():
                     help="seconds-fast CI row (64 requests)")
     ap.add_argument("--telemetry-log", default="",
                     help="also write the JSONL run log here")
-    ap.add_argument("--profile", default="",
-                    help="tuned profile (tools/autotune.py offline) to "
-                         "apply before the run; extra.tuned_profile "
-                         "records the provenance in the BENCH row")
     args = ap.parse_args()
-    if args.profile:
-        from paddle_tpu.core import tuner
-
-        tuner.apply_profile(tuner.load_profile(args.profile),
-                            origin_path=args.profile)
     if args.smoke:
         args.requests = min(args.requests, 64)
         args.gen_requests = min(args.gen_requests, 10)

@@ -2054,210 +2054,6 @@ def run_fleet(args) -> int:
     return 0
 
 
-def run_autotune(args) -> int:
-    """--autotune mode: the online-tuner safety gate. Two legs over one
-    in-process cluster (published MLP model, synthetic closed-loop
-    load):
-
-    1. **apply-fault** — ``replica.swap:@1`` kills the FIRST swap, i.e.
-       the candidate application itself: the trial must fail its start,
-       roll back immediately (the rollback's re-tune retries past the
-       one-shot fault) and leave zero residual flag overrides;
-    2. **slo-trip** — ``router.dispatch:%N`` dispatch faults drive real
-       failovers through the real metrics window into an armed
-       failover-burst SLO rule: the rule trips mid-trial and the trial
-       must abort within ONE evaluation tick.
-
-    Both legs assert: flags.snapshot() identical to the pre-trial
-    snapshot, the fleet still on the incumbent model version, and
-    exactly one ``tuner.rollbacks`` increment per trial."""
-    import tempfile
-    import threading
-    import urllib.request
-
-    import numpy as np
-
-    import paddle_tpu as pt
-    from paddle_tpu import checkpoint as _ckpt
-    from paddle_tpu import io as _io
-    from paddle_tpu import layers
-    from paddle_tpu.core import faults, incidents, telemetry, tuner
-    from paddle_tpu.core import flags as _flags
-    from paddle_tpu.serving.cluster import ClusterController
-
-    if args.telemetry_log:
-        telemetry.configure(args.telemetry_log)
-
-    with tempfile.TemporaryDirectory(prefix="pt_chaos_autotune_") as tmp:
-        model_dir = os.path.join(tmp, "mlp")
-        main_prog, startup = pt.Program(), pt.Program()
-        with pt.program_guard(main_prog, startup):
-            x = layers.data("x", [6])
-            h = layers.fc(x, 8, act="relu")
-            y = layers.fc(h, 4)
-        scope = pt.Scope()
-        pt.Executor().run(startup, scope=scope, use_compiled=False)
-        _io.save_inference_model(model_dir, ["x"], [y],
-                                 main_program=main_prog, scope=scope)
-        root = os.path.join(tmp, "models")
-        _ckpt.publish_model(root, model_dir)
-
-        cluster = ClusterController(root, replicas=2,
-                                    inprocess=True).start()
-        incumbent_version = cluster.current_version
-        stop = threading.Event()
-
-        def load_loop(i):
-            rng = np.random.RandomState(i)
-            while not stop.is_set():
-                doc = {"inputs": {
-                    "x": rng.randn(1, 6).astype("float32").tolist()}}
-                req = urllib.request.Request(
-                    cluster.url + "/v1/infer",
-                    data=json.dumps(doc).encode(),
-                    headers={"Content-Type": "application/json"})
-                try:
-                    urllib.request.urlopen(req, timeout=30).read()
-                except Exception:
-                    pass
-                stop.wait(0.005)
-
-        threads = [threading.Thread(target=load_loop, args=(i,),
-                                    name=f"pt-chaos-autotune-load-{i}",
-                                    daemon=True) for i in range(2)]
-        failures = []
-        # fast watchdog cadence so the slo-trip leg resolves in a couple
-        # of trial ticks instead of the 5 s production default
-        prior_eval = _flags.apply({"slo_eval_s": 0.2})
-        try:
-            for t in threads:
-                t.start()
-            candidate = {"serving_buckets": "4,8",
-                         "serving_batch_timeout_ms": 1.0}
-
-            def leg(name, fault_spec, trial_fn):
-                print(f"== autotune chaos leg: {name} "
-                      f"(spec {fault_spec!r}) ==")
-                pre = _flags.snapshot()
-                rb0 = int(telemetry.counters().get("tuner.rollbacks", 0))
-                faults.configure(fault_spec, seed=args.seed)
-                try:
-                    trial_fn()
-                finally:
-                    faults.configure("")
-                post = _flags.snapshot()
-                residual = {k: post[k] for k in post
-                            if k in pre and post[k] != pre[k]
-                            and k not in ("fault_spec", "fault_seed")}
-                rb = int(telemetry.counters().get("tuner.rollbacks", 0)) \
-                    - rb0
-                if residual:
-                    failures.append(f"{name}: residual flag overrides "
-                                    f"after rollback: {residual}")
-                if rb != 1:
-                    failures.append(f"{name}: expected exactly one "
-                                    f"tuner.rollbacks, got {rb}")
-                if cluster.current_version != incumbent_version:
-                    failures.append(f"{name}: fleet left the incumbent "
-                                    f"version ({cluster.current_version} "
-                                    f"!= {incumbent_version})")
-
-            # -- leg 1: candidate application dies on the swap ---------------
-            def apply_fault_trial():
-                trial = tuner.OnlineTrial(
-                    cluster, candidate, fraction=0.25,
-                    eval_interval_s=0.2, min_requests=4, max_evals=4,
-                    label="chaos-apply")
-                try:
-                    trial.start()
-                except tuner.TunerError as e:
-                    print(f"  candidate application failed as injected "
-                          f"({e}) -> rolled back")
-                else:
-                    # @1 fired on a warmup/monitor swap instead: finish
-                    # the trial; any verdict must still leave the fleet
-                    # clean (promoted would keep flags -> force abort
-                    # by SLO base manipulation is overkill; just run)
-                    while trial.evaluate_once() is None:
-                        time.sleep(0.2)
-                    if trial.result.status == "promoted":
-                        # undo the promotion for leg accounting
-                        failures.append("apply-fault: trial promoted "
-                                        "despite injected swap fault")
-
-            leg("apply-fault", "replica.swap:@1", apply_fault_trial)
-
-            # -- leg 2: dispatch faults -> failovers -> SLO rule trip --------
-            def slo_trip_trial():
-                incidents.reset()
-                incidents.arm([incidents.Rule(
-                    "chaos_failover_burst", "router.failovers",
-                    kind="counter", stat="delta", window_s=30.0,
-                    threshold=1, cooldown_s=0.0)])
-                try:
-                    trial = tuner.OnlineTrial(
-                        cluster, candidate, fraction=0.25,
-                        eval_interval_s=0.2,
-                        min_requests=10_000,   # latency can never decide
-                        max_evals=50, label="chaos-slo")
-                    trial.start()
-                    result = None
-                    while result is None:
-                        time.sleep(0.2)
-                        result = trial.evaluate_once()
-                    print(f"  trial verdict: {result.status} "
-                          f"({result.reason}) after {result.evals} "
-                          f"tick(s)")
-                    if result.status != "rolled_back":
-                        failures.append(f"slo-trip: expected rollback, "
-                                        f"got {result.status}")
-                    elif result.reason not in ("slo_trip",):
-                        failures.append(f"slo-trip: rolled back for "
-                                        f"{result.reason!r}, not the "
-                                        f"SLO trip")
-                finally:
-                    incidents.stop_watchdog()
-                    incidents.reset()
-
-            leg("slo-trip", "router.dispatch:%4", slo_trip_trial)
-
-            # post-chaos liveness: the fleet must still serve cleanly
-            code = None
-            try:
-                req = urllib.request.Request(
-                    cluster.url + "/v1/infer",
-                    data=json.dumps({"inputs": {
-                        "x": [[0.0] * 6]}}).encode(),
-                    headers={"Content-Type": "application/json"})
-                code = urllib.request.urlopen(req, timeout=30).status
-            except Exception as e:
-                failures.append(f"post-chaos request failed: {e!r}")
-            if code is not None and code != 200:
-                failures.append(f"post-chaos request got HTTP {code}")
-        finally:
-            _flags.apply(prior_eval)
-            stop.set()
-            for t in threads:
-                t.join(timeout=5)
-            cluster.close()
-
-    counters = telemetry.counters()
-    print("-- autotune chaos tally " + "-" * 25)
-    for key in ("faults.injected", "tuner.trials", "tuner.rollbacks",
-                "tuner.promotions", "tuner.slo_aborts",
-                "tuner.rollback_errors", "router.failovers",
-                "router.trial_split_set", "slo.trips"):
-        print(f"{key:28s} {int(counters.get(key, 0))}")
-    if failures:
-        for f in failures:
-            print(f"CHAOS FAIL: {f}")
-        return 2
-    print("CHAOS OK: every faulted trial rolled back to the incumbent "
-          "config (zero residual overrides, fleet version unchanged, "
-          "one rollback booked per trial)")
-    return 0
-
-
 def main():
     ap = argparse.ArgumentParser(
         description="run a short PS training loop under fault injection "
@@ -2298,13 +2094,6 @@ def main():
                          "step_time, mfu_drop, serving_queue, "
                          "decode_queue, pallas_gemm, pallas_attn, "
                          "router_failover, ckpt_verify, clean")
-    ap.add_argument("--autotune", action="store_true",
-                    help="chaos-test the online autotuner (core/"
-                         "tuner.py): an A/B trial under injected swap/"
-                         "dispatch faults must ALWAYS roll back to the "
-                         "incumbent config — zero residual flag "
-                         "overrides, fleet on the incumbent version, "
-                         "exactly one tuner.rollbacks per trial")
     ap.add_argument("--cluster", action="store_true",
                     help="chaos-test the cluster serving control plane "
                          "(replica processes + router): SIGKILL a "
@@ -2375,8 +2164,6 @@ def main():
         sys.exit(run_prefix(args))
     if args.checkpoint:
         sys.exit(run_checkpoint(args))
-    if args.autotune:
-        sys.exit(run_autotune(args))
     if args.cluster:
         sys.exit(run_cluster(args))
     if args.fleet:

@@ -134,7 +134,6 @@ def summarize_log(recs, malformed=0):
     stall_events = []
     thread_errors = []
     incident_events = []
-    tuner_events = []
     scale_events = []
     spans = defaultdict(list)
     span_traces = set()
@@ -194,9 +193,6 @@ def summarize_log(recs, malformed=0):
                 "id": attrs.get("id"), "source": attrs.get("source"),
                 "rule": (attrs.get("rule") or {}).get("name"),
                 "ring_records": len(attrs.get("ring") or [])})
-        elif kind == "tuner":
-            tuner_events.append({"name": name, "ts": r.get("ts"),
-                                 "value": v, **attrs})
         elif kind == "scale":
             scale_events.append({
                 "name": name, "ts": r.get("ts"),
@@ -245,8 +241,6 @@ def summarize_log(recs, malformed=0):
                                        thread_errors)
     incidents = _incidents_summary(counter_delta, counter_last, gauges,
                                    incident_events)
-    autotune = _autotune_summary(counter_delta, counter_last,
-                                 tuner_events)
     goodput = _goodput_summary(counter_delta, counter_last, gauges)
     fleet = _fleet_summary(counter_delta, counter_last, gauges)
     scaler = _scaler_summary(counter_delta, counter_last, scale_events)
@@ -274,7 +268,6 @@ def summarize_log(recs, malformed=0):
         "memcost": memcost,
         "concurrency": concurrency,
         "incidents": incidents,
-        "autotune": autotune,
         "goodput": goodput,
         "fleet": fleet,
         "scaler": scaler,
@@ -769,45 +762,6 @@ def _incidents_summary(counter_delta, counter_last, gauges,
     return out
 
 
-def _autotune_summary(counter_delta, counter_last, tuner_events):
-    """Cost-model-guided autotuner accounting (core/tuner.py): how many
-    candidates were enumerated vs constraint-rejected, the replay
-    evidence volume, and the online-trial ledger — trials started,
-    promotions, rollbacks (with SLO-trip aborts broken out) and
-    profiles loaded into bench runs."""
-
-    def cval(name):
-        v = counter_delta.get(name) or counter_last.get(name) or 0
-        try:
-            return float(v)
-        except (TypeError, ValueError):
-            return 0.0
-
-    trials = cval("tuner.trials")
-    promotions = cval("tuner.promotions")
-    rollbacks = cval("tuner.rollbacks")
-    rejections = cval("tuner.constraint_rejections")
-    candidates = cval("tuner.candidates")
-    profiles = cval("tuner.profiles_loaded")
-    observations = cval("tuner.replay_observations")
-    if not (trials or promotions or rollbacks or rejections or candidates
-            or profiles or observations or tuner_events):
-        return None
-    return {
-        "candidates": int(candidates),
-        "constraint_rejections": int(rejections),
-        "replay_observations": int(observations),
-        "insufficient_evidence": int(cval("tuner.insufficient_evidence")),
-        "profiles_loaded": int(profiles),
-        "trials": int(trials),
-        "promotions": int(promotions),
-        "rollbacks": int(rollbacks),
-        "slo_aborts": int(cval("tuner.slo_aborts")),
-        "rollback_errors": int(cval("tuner.rollback_errors")),
-        "events": tuner_events[-10:],
-    }
-
-
 def _goodput_summary(counter_delta, counter_last, gauges):
     """Goodput ledger accounting (core/goodput.py): wall-clock
     attribution of the run into productive device compute vs the badput
@@ -1281,26 +1235,6 @@ def render(s, out=sys.stdout):
               + (f", rule {ev['rule']}" if ev.get("rule") else "")
               + f", {ev['ring_records']} ring records — "
                 f"tools/incident_report.py)\n")
-
-    if s.get("autotune"):
-        at = s["autotune"]
-        w("\n-- autotune (cost-model-guided search, core/tuner.py) --\n")
-        w(f"candidates: {at['candidates']}  constraint rejections: "
-          f"{at['constraint_rejections']}  replay observations: "
-          f"{at['replay_observations']}  insufficient evidence: "
-          f"{at['insufficient_evidence']}\n")
-        w(f"online trials: {at['trials']}  promotions: "
-          f"{at['promotions']}  rollbacks: {at['rollbacks']}"
-          + (f" (slo aborts: {at['slo_aborts']})"
-             if at.get("slo_aborts") else "")
-          + (f"  ROLLBACK ERRORS: {at['rollback_errors']}"
-             if at.get("rollback_errors") else "")
-          + f"  profiles loaded: {at['profiles_loaded']}\n")
-        for ev in at.get("events", []):
-            detail = ev.get("profile_hash") or ev.get("candidate") or ""
-            w(f"  {ev['name']}: {detail}"
-              + (f" (reason {ev['reason']})" if ev.get("reason") else "")
-              + "\n")
 
     if s.get("goodput"):
         gp = s["goodput"]

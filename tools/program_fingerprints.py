@@ -1,13 +1,31 @@
-"""sha256 of the jaxprs the served families' step and prefill programs trace
-to at their default (toy) configurations, and of the shared ops a trainer
-reads, so that a PR that grows a shared op's attributes can show that the
-other families' programs did not change: run it from the parent's checkout
-and from the change's and compare the lines.
+"""sha256 of the jaxprs the served families' step and prefill programs and the
+two trainers' step programs trace to at toy widths, and of the shared ops a
+trainer reads, so that a PR that touches shared code can show that no
+program changed. The committed table is `tests/program_fingerprints.json`
+(`tests/test_program_fingerprints.py` holds every line of it); a PR that
+changes a program on purpose rewrites the table and so says it in its diff.
 
-    JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tools/program_fingerprints.py
+    JAX_PLATFORMS=cpu python tools/program_fingerprints.py --check
+    JAX_PLATFORMS=cpu python tools/program_fingerprints.py --write
 """
 
+import argparse
+import functools
 import hashlib
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TABLE = os.path.join(REPO, "tests", "program_fingerprints.json")
+WRITE_COMMAND = "JAX_PLATFORMS=cpu python tools/program_fingerprints.py --write"
+
+# family -> (config class, seeded parameters) of its module in models/
+SERVED = {"decoder_lm": ("DecoderLMConfig", "decoder_lm_params"),
+          "afmoe": ("AfmoeConfig", "afmoe_params"),
+          "kimi_k2": ("KimiK2Config", "kimi_k2_params"),
+          "falcon_h1": ("FalconH1Config", "falcon_h1_params"),
+          "qwen3_next": ("Qwen3NextConfig", "qwen3_next_params")}
 
 
 def _digest(fn, *args):
@@ -50,6 +68,28 @@ def program_fingerprints(cfg, params):
     return out
 
 
+def train_fingerprint(main):
+    """A trainer's whole step (forward, backward, optimizer) from what its
+    block reads to everything it writes."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.core.executor import _analyze_block, run_block
+
+    block = main.global_block()
+    reads, writes = _analyze_block(block)
+
+    def run(env, step):
+        env = dict(env)
+        run_block(block, env, step=step)
+        return [env[n] for n in writes]
+
+    env = {n: jnp.zeros(tuple(block.vars[n].shape),
+                        jax.dtypes.canonicalize_dtype(block.vars[n].dtype))
+           for n in reads}
+    return _digest(run, env, jnp.zeros((), jnp.int32))
+
+
 def op_fingerprint(name, ins, attrs):
     from paddle_tpu.core import registry
 
@@ -57,40 +97,109 @@ def op_fingerprint(name, ins, attrs):
         {k: [v] for k, v in ins.items()}, attrs), ins)
 
 
-def main():
+@functools.lru_cache(maxsize=None)
+def _served_pair(family):
+    import importlib
+
+    import paddle_tpu.ops  # noqa: F401
+
+    module = importlib.import_module(f"paddle_tpu.models.{family}")
+    config, make = SERVED[family]
+    cfg = getattr(module, config)()
+    return program_fingerprints(cfg, getattr(module, make)(cfg, 0))
+
+
+def _served(family, which):
+    return _served_pair(family)[("step", "prefill").index(which)]
+
+
+def _train_bert():
+    from paddle_tpu.models import bert
+
+    cfg = bert.BertConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2,
+                          num_attention_heads=2, intermediate_size=64,
+                          max_position_embeddings=32)
+    return train_fingerprint(bert.build_pretraining_program(
+        cfg, seq_len=16, batch_size=2)[0])
+
+
+def _train_mellum():
+    from paddle_tpu.models import mellum
+
+    return train_fingerprint(mellum.build_pretraining_program(
+        mellum.MellumConfig(loss_chunk=16), 2, 32)[0])
+
+
+def _shared_op(label):
     import jax.numpy as jnp
 
     import paddle_tpu.ops  # noqa: F401
-    from paddle_tpu.models import afmoe, decoder_lm, falcon_h1, kimi_k2
 
-    for module, config, make in (
-            (afmoe, "AfmoeConfig", "afmoe_params"),
-            (falcon_h1, "FalconH1Config", "falcon_h1_params"),
-            (kimi_k2, "KimiK2Config", "kimi_k2_params"),
-            (decoder_lm, "DecoderLMConfig", "decoder_lm_params")):
-        cfg = getattr(module, config)()
-        print(module.__name__.rpartition(".")[2],
-              *program_fingerprints(cfg, getattr(module, make)(cfg, 0)))
     qk = {"Q": jnp.ones((2, 16, 128)), "K": jnp.ones((2, 16, 32)),
           "QScale": jnp.ones(32), "KScale": jnp.ones(32)}
     routed = {"X": jnp.ones((32, 64)), "RouterW": jnp.ones((64, 16)),
               "SelectBias": jnp.zeros(16), "W1": jnp.ones((4, 64, 32)),
               "W3": jnp.ones((4, 64, 32)), "W2": jnp.ones((4, 32, 64))}
-    for label, name, ins, attrs in (
-            ("rms_norm", "rms_norm",
-             {"X": jnp.ones((2, 16, 64)), "Scale": jnp.ones(64)},
-             {"epsilon": 1e-5}),
-            ("qk_norm_rope.yarn", "qk_norm_rope", qk,
-             {"head_dim": 32, "rope": True, "theta": 5e5,
-              "yarn_factor": 16.0, "yarn_original_max": 8192,
-              "attention_factor": 1.2}),
-            ("routed_experts.softmax.trainable", "routed_experts", routed,
-             {"top_k": 4, "held_lo": 0, "score_func": "softmax",
-              "trainable": True}),
-            ("routed_experts.sigmoid", "routed_experts", routed,
-             {"top_k": 4, "held_lo": 0, "route_scale": 2.4})):
-        print(label, op_fingerprint(name, ins, attrs))
+    name, ins, attrs = {
+        "rms_norm": (
+            "rms_norm", {"X": jnp.ones((2, 16, 64)), "Scale": jnp.ones(64)},
+            {"epsilon": 1e-5}),
+        "qk_norm_rope.yarn": (
+            "qk_norm_rope", qk,
+            {"head_dim": 32, "rope": True, "theta": 5e5,
+             "yarn_factor": 16.0, "yarn_original_max": 8192,
+             "attention_factor": 1.2}),
+        "routed_experts.softmax.trainable": (
+            "routed_experts", routed,
+            {"top_k": 4, "held_lo": 0, "score_func": "softmax",
+             "trainable": True}),
+        "routed_experts.sigmoid": (
+            "routed_experts", routed,
+            {"top_k": 4, "held_lo": 0, "route_scale": 2.4})}[label]
+    return op_fingerprint(name, ins, attrs)
+
+
+# line of the table -> its digest, computed when called
+CASES = {}
+for _family in SERVED:
+    for _which in ("step", "prefill"):
+        CASES[f"{_family}.{_which}"] = functools.partial(
+            _served, _family, _which)
+CASES["train.bert"] = _train_bert
+CASES["train.mellum"] = _train_mellum
+for _label in ("rms_norm", "qk_norm_rope.yarn",
+               "routed_experts.softmax.trainable", "routed_experts.sigmoid"):
+    CASES[f"op.{_label}"] = functools.partial(_shared_op, _label)
+
+
+def read_table():
+    with open(TABLE) as f:
+        return json.load(f)
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    mode = parser.add_mutually_exclusive_group(required=True)
+    mode.add_argument("--write", action="store_true",
+                      help=f"compute every line and rewrite {TABLE}")
+    mode.add_argument("--check", action="store_true",
+                      help="compare every line with the committed table; "
+                           "exit 1 on a difference")
+    args = parser.parse_args(argv)
+    sys.path.insert(0, REPO)
+    now = {name: case() for name, case in CASES.items()}
+    if args.write:
+        with open(TABLE, "w") as f:
+            json.dump(now, f, indent=0)
+            f.write("\n")
+        return 0
+    table = read_table()
+    moved = [n for n in sorted(set(now) | set(table))
+             if now.get(n) != table.get(n)]
+    for n in moved:
+        print(f"{n}: table {table.get(n)} now {now.get(n)}")
+    return 1 if moved else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -96,17 +96,6 @@ def _value_direction(row):
     return "lower" if "ms" in unit else "higher"
 
 
-def _provenance_key(row):
-    """Tuned-profile provenance of a row (extra.tuned_profile, embedded
-    by finalize_bench_result): rows produced under an autotuned profile
-    are only comparable with rows of the SAME profile hash; rows without
-    the field (pre-autotuner history) are "hand-picked"."""
-    tp = (row.get("extra") or {}).get("tuned_profile")
-    if isinstance(tp, dict):
-        return str(tp.get("profile_hash") or "tuned")
-    return "hand-picked"
-
-
 def _get(row, key, where):
     src = row.get("extra") or {} if where == "extra" else row
     # dotted keys traverse nested dicts ("goodput.ratio" ->
@@ -120,16 +109,13 @@ def _get(row, key, where):
 
 def slo_verdict(row, prior_rows, tolerances=None):
     """Judge one row against the best prior rows of the SAME metric
-    name AND the same tuned-profile provenance (a tuned row must not be
-    judged against hand-picked history, or vice versa). Returns
+    name. Returns
     {"verdict": "pass"|"regress"|"no_baseline", "checks": [...]}: a
     check regresses when the row is worse than the best prior value by
     more than its tolerance."""
     tolerances = tolerances or {}
-    prov = _provenance_key(row)
     peers = [r for r in prior_rows
-             if r.get("metric") == row.get("metric")
-             and _provenance_key(r) == prov]
+             if r.get("metric") == row.get("metric")]
     if not peers:
         return {"verdict": "no_baseline", "checks": [],
                 "peers": 0}
