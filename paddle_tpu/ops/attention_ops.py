@@ -354,7 +354,10 @@ def latent_cache_write_op(ins, attrs):
     shared key); Pool [N, P, W] with W >= w (a row rides in whole lane
     tiles, its tail zero); PageTable [B, MP]; Lengths [B]. Token s of row b
     lands at page PageTable[b, s // P], offset s % P; positions at or past
-    the row's length go to the scratch page 0."""
+    the row's length go to the scratch page 0. Attr ``ring``: the table is
+    a slot's latent ring of ``cap = MP x P`` rows, token s lands at index
+    ``s mod cap`` and of a longer prompt only the last `cap` tokens are
+    written (`_kv_cache_write_classed`)."""
     import jax.numpy as jnp
 
     pool = jnp.asarray(ins["Pool"][0])
@@ -363,10 +366,16 @@ def latent_cache_write_op(ins, attrs):
     lat = _lane_padded(ins["Latent"][0], pool.shape[2])
     b, s, _ = lat.shape
     page = int(pool.shape[1])
-    pos = jnp.arange(s, dtype=jnp.int32)
+    at = pos = jnp.arange(s, dtype=jnp.int32)
+    cap = int(table.shape[1]) * page
+    if attrs.get("ring"):
+        pos = at % cap
     phys = jnp.take_along_axis(
         table, jnp.broadcast_to((pos // page)[None, :], (b, s)), axis=1)
-    phys = jnp.where(pos[None, :] < lengths[:, None], phys, 0).reshape(-1)
+    valid = at[None, :] < lengths[:, None]
+    if attrs.get("ring"):
+        valid &= at[None, :] >= lengths[:, None] - cap
+    phys = jnp.where(valid, phys, 0).reshape(-1)
     off = jnp.broadcast_to((pos % page)[None, :], (b, s)).reshape(-1)
     return {"PoolOut": pool.at[phys, off].set(
         lat.reshape(b * s, -1).astype(pool.dtype))}
@@ -390,7 +399,13 @@ def cached_latent_attention_op(ins, attrs):
 
     The attend phase is ops/pallas/paged_mla_attention.py under the
     PT_PALLAS dispatch; mode 'off' and untileable shapes take the counted
-    stock gather (``pallas.paged_attn_fallbacks``)."""
+    stock gather (``pallas.paged_attn_fallbacks``).
+
+    Attr ``window`` (a window layer's LATENT RING): the table is the slot's
+    ring of ``cap = MP x P`` rows, the row lands at index ``pos mod cap``,
+    and every head attends the rows whose TRUE position lies in
+    ``pos - window < s <= pos``: the ring alone is read, whatever the
+    context's length."""
     import jax.numpy as jnp
 
     from .pallas.paged_mla_attention import paged_mla_decode_attention
@@ -403,13 +418,15 @@ def cached_latent_attention_op(ins, attrs):
     b, w = lat.shape
     width, page = int(pool.shape[2]), int(pool.shape[1])
     scale = float(attrs.get("scale") or w ** -0.5)
-    phys = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0]
-    pool = pool.at[phys, pos % page].set(
+    window = int(attrs.get("window", 0))
+    idx = pos % (int(table.shape[1]) * page) if window else pos
+    phys = jnp.take_along_axis(table, (idx // page)[:, None], axis=1)[:, 0]
+    pool = pool.at[phys, idx % page].set(
         _lane_padded(lat, width).astype(pool.dtype))
     out = paged_mla_decode_attention(
         _lane_padded(q.reshape(b, n, w), width).reshape(b, n * width),
         pool, table, pos, num_heads=n, value_dim=int(attrs["value_dim"]),
-        scale=scale)
+        scale=scale, window=window)
     return {"Out": out, "PoolOut": pool}
 
 

@@ -430,15 +430,24 @@ def mla_absorb_query_op(ins, attrs):
     so that ``q . [c, k_r]`` is ``q_n . k_n + q_r . k_r`` without any
     key being expanded. QNope [B, n*nope], QRope [B, n*rope] -> Q
     [B, n*(rank+rope)] float32; the product rounds QNope to W's dtype and
-    accumulates in float32."""
+    accumulates in float32. Attr `num_kv_heads` (grouped heads: W holds
+    that many K/V heads, query head h reads head h // (n / num_kv_heads))."""
     import jax.numpy as jnp
 
     n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
-    w = _kvb_heads(ins["W"][0], n)[:, :, :nope]            # [rank, n, nope]
+    nkv = int(attrs.get("num_kv_heads", n))
+    w = _kvb_heads(ins["W"][0], nkv)[:, :, :nope]        # [rank, nkv, nope]
     qn, qr = ins["QNope"][0], ins["QRope"][0]
     b = qn.shape[0]
-    qc = jnp.einsum("bhd,chd->bhc", qn.reshape(b, n, nope).astype(w.dtype),
-                    w, preferred_element_type=jnp.float32)
+    if nkv != n:
+        qc = jnp.einsum(
+            "bkgd,ckd->bkgc",
+            qn.reshape(b, nkv, n // nkv, nope).astype(w.dtype), w,
+            preferred_element_type=jnp.float32).reshape(b, n, -1)
+    else:
+        qc = jnp.einsum("bhd,chd->bhc",
+                        qn.reshape(b, n, nope).astype(w.dtype), w,
+                        preferred_element_type=jnp.float32)
     q = jnp.concatenate([qc, qr.reshape(b, n, -1).astype(jnp.float32)],
                         axis=-1)
     return {"Q": q.reshape(b, -1)}
@@ -448,13 +457,22 @@ def mla_absorb_query_op(ins, attrs):
 def mla_expand_output_op(ins, attrs):
     """Out [B, n*v] = per head ``o_c W_uv``: the attended latents X
     [B, n*rank] through head h's value half of W [rank, n*(nope+v)].
-    float32 out, W's dtype in, float32 accumulation."""
+    float32 out, W's dtype in, float32 accumulation. Attr `num_kv_heads`
+    as `mla_absorb_query`'s: the n rows of X read the value half of K/V
+    head h // (n / num_kv_heads)."""
     import jax.numpy as jnp
 
     n, nope = int(attrs["num_heads"]), int(attrs["nope_dim"])
-    w = _kvb_heads(ins["W"][0], n)[:, :, nope:]            # [rank, n, v]
+    nkv = int(attrs.get("num_kv_heads", n))
+    w = _kvb_heads(ins["W"][0], nkv)[:, :, nope:]          # [rank, nkv, v]
     x = ins["X"][0]
     b = x.shape[0]
+    if nkv != n:
+        out = jnp.einsum(
+            "bkgc,ckv->bkgv",
+            x.reshape(b, nkv, n // nkv, -1).astype(w.dtype), w,
+            preferred_element_type=jnp.float32)
+        return {"Out": out.reshape(b, -1)}
     out = jnp.einsum("bhc,chv->bhv", x.reshape(b, n, -1).astype(w.dtype), w,
                      preferred_element_type=jnp.float32)
     return {"Out": out.reshape(b, -1)}
@@ -480,7 +498,12 @@ def mla_prefill_attention_op(ins, attrs):
     `compute_dtype` for the products, which accumulate in float32; the
     softmax is float32. Out [B, S, n*v] is the float32 result rounded
     once to `compute_dtype`: the rounding the output projection's
-    `linear_acc32` gave it (to its weight's dtype, the same) before."""
+    `linear_acc32` gave it (to its weight's dtype, the same) before.
+
+    Attr `num_kv_heads` (grouped heads: KV is [B, S, nkv*(nope+v)] and
+    query head h reads K/V head h // (n / nkv)) and attr `window` (a query
+    at t reads keys at t - window < s <= t only, and the kernel visits
+    only the block pairs that band touches)."""
     import jax.numpy as jnp
 
     from .pallas.mla_prefill_attention import mla_prefill_attention
@@ -493,6 +516,110 @@ def mla_prefill_attention_op(ins, attrs):
     k_rope = ins["Latent"][0][..., -rope:].astype(dt)
     scale = float(attrs.get("scale") or (nope + rope) ** -0.5)
     out = [mla_prefill_attention(qn[i], qr[i], kv[i], k_rope[i], scale,
-                                 num_heads=n, nope_dim=nope)
+                                 num_heads=n, nope_dim=nope,
+                                 num_kv_heads=int(attrs.get("num_kv_heads",
+                                                            n)),
+                                 window=int(attrs.get("window", 0)))
            for i in range(qn.shape[0])]
     return {"Out": jnp.stack(out)}
+
+
+# ---------------------------------------------------------------------------
+# the Motif-3 block (models/motif3.py): differential heads, a residual of
+# several streams, PolyNorm
+
+@register_op("diff_head_combine", required_attrs=("num_groups", "width"))
+def diff_head_combine_op(ins, attrs):
+    """Grouped differential attention's subtraction, in float32: X
+    [..., G*(s+1)*w] holds, a group, its s signal heads' outputs and then
+    its noise head's, each `width` wide; LambdaLogits [..., G*s] a token
+    and signal head. Out [..., G*s*w]: ``x_{g,j} - sigmoid(l_{g,j})
+    x_{g,s}``. The width is the value's in the expanded prefill and the
+    LATENT's in the absorbed decode step, where the one up-projection of
+    the group follows the subtraction (`mla_expand_output`)."""
+    import jax
+    import jax.numpy as jnp
+
+    g, w = int(attrs["num_groups"]), int(attrs["width"])
+    x = ins["X"][0].astype(jnp.float32)
+    lead = x.shape[:-1]
+    xh = x.reshape(lead + (g, -1, w))                    # [..., G, s+1, w]
+    lam = jax.nn.sigmoid(ins["LambdaLogits"][0].astype(jnp.float32)
+                         ).reshape(lead + (g, -1, 1))
+    out = xh[..., :-1, :] - lam * xh[..., -1:, :]
+    return {"Out": out.reshape(lead + (-1,))}
+
+
+@register_op("embed_streams", required_attrs=("n_streams",))
+def embed_streams_op(ins, attrs):
+    """Out [..., n*C] float32: the embedding row W[Ids] copied into each
+    of `n_streams` streams (stream i at columns [iC, (i+1)C))."""
+    import jax.numpy as jnp
+
+    row = ins["W"][0][ins["Ids"][0]].astype(jnp.float32)
+    return {"Out": jnp.tile(row, (1,) * (row.ndim - 1)
+                            + (int(attrs["n_streams"]),))}
+
+
+@register_op("sum_streams", required_attrs=("n_streams",))
+def sum_streams_op(ins, attrs):
+    """Out [..., C] = the sum of X's `n_streams` streams [..., n*C]."""
+    x = ins["X"][0]
+    n = int(attrs["n_streams"])
+    return {"Out": x.reshape(x.shape[:-1] + (n, -1)).sum(axis=-2)}
+
+
+@register_op("polyglu")
+def polyglu_op(ins, attrs):
+    """Out = PN(Gate) * Up in float32: PolyNorm over Gate's whole last
+    axis (ops/pallas/grouped_swiglu.py `poly_norm`; PN [4]: three weights
+    and a bias; attrs `epsilon`, `out_scale`, `bias_clamp`)."""
+    import jax.numpy as jnp
+
+    from .pallas.grouped_swiglu import poly_norm
+
+    gate = ins["Gate"][0].astype(jnp.float32)
+    return {"Out": poly_norm(
+        gate, ins["PN"][0].astype(jnp.float32),
+        eps=float(attrs.get("epsilon", 1e-5)),
+        out_scale=float(attrs.get("out_scale", 1.0)),
+        bias_clamp=float(attrs.get("bias_clamp", 0.5)))
+        * ins["Up"][0].astype(jnp.float32)}
+
+
+@register_op("mhc_pre", required_attrs=("n_streams", "sinkhorn_iters"))
+def mhc_pre_op(ins, attrs):
+    """The residual path of `n_streams` streams BEFORE a sublayer
+    (ops/pallas/mhc_mix.py): X [..., n*C] float32 (stream i at columns
+    [iC, (i+1)C)), Gamma [n*C], Phi [n*C, 2n+n^2], Scale [3], Bias
+    [2n+n^2] -> U [..., C] (the sublayer's input before its norm) and Maps
+    [..., 128] (H_pre, H_post and the doubly stochastic H_res of each
+    token, a lane tile; `mhc_post` reads it). The kernel ``mhc_pre``
+    under the PT_PALLAS dispatch, its stock lowering where the mode is
+    off or the shape cannot be tiled (``pallas.mhc_fallbacks``)."""
+    from .pallas.mhc_mix import mhc_pre
+
+    x = ins["X"][0]
+    lead = x.shape[:-1]
+    u, maps = mhc_pre(
+        x.reshape(-1, x.shape[-1]), ins["Gamma"][0], ins["Phi"][0],
+        ins["Scale"][0], ins["Bias"][0], n=int(attrs["n_streams"]),
+        iters=int(attrs["sinkhorn_iters"]),
+        eps=float(attrs.get("epsilon", 1e-5)))
+    return {"U": u.reshape(lead + (-1,)), "Maps": maps.reshape(lead + (-1,))}
+
+
+@register_op("mhc_post", required_attrs=("n_streams",))
+def mhc_post_op(ins, attrs):
+    """The residual path AFTER a sublayer: Out[i] = sum_j H_res[i, j] X[j]
+    + H_post[i] Y, clamped to +-`clamp`; X [..., n*C], Y [..., C], Maps
+    as `mhc_pre` wrote them. Kernel ``mhc_post``, or its counted stock
+    lowering."""
+    from .pallas.mhc_mix import mhc_post
+
+    x, y, maps = ins["X"][0], ins["Y"][0], ins["Maps"][0]
+    out = mhc_post(x.reshape(-1, x.shape[-1]), y.reshape(-1, y.shape[-1]),
+                   maps.reshape(-1, maps.shape[-1]),
+                   n=int(attrs["n_streams"]),
+                   clamp=float(attrs.get("clamp", 3e38)))
+    return {"Out": out.reshape(x.shape)}

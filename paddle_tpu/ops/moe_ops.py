@@ -31,7 +31,19 @@ def switch_moe_op(ins, attrs):
     return {"Out": out.reshape(x.shape), "AuxLoss": aux}
 
 
-@register_op("routed_experts", non_diff_inputs=("SelectBias", "Live"),
+def _poly_experts(ins, attrs):
+    """`routed_experts_share`'s `poly` from the op's attr `activation` and
+    its PolyNorm input and attrs; None for SwiGLU experts."""
+    activation = attrs.get("activation", "silu")
+    if activation == "silu":
+        return None
+    if activation != "poly_norm":
+        raise ValueError(f"routed_experts: activation {activation!r}")
+    return (ins["PN"][0], float(attrs["pn_eps"]),
+            float(attrs["pn_out_scale"]), float(attrs["pn_bias_clamp"]))
+
+
+@register_op("routed_experts", non_diff_inputs=("SelectBias", "Live", "PN"),
              required_attrs=("top_k", "held_lo"))
 def routed_experts_op(ins, attrs):
     """One chip's share of a dropless top-k routed expert layer
@@ -46,7 +58,12 @@ def routed_experts_op(ins, attrs):
     pairs sorted by expert (ops/pallas/grouped_swiglu.py; three
     ragged_dots where kernel_mode() is off). Out like X, float32; Counts
     int32 [3] (live pairs, those on held experts, held experts hit);
-    Chosen int32 [..., top_k], the experts each row chose."""
+    Chosen int32 [..., top_k], the experts each row chose.
+
+    Attr `activation` "poly_norm" (default "silu") makes the held experts
+    PolyNorm ones: input PN [E_held, 4] float32 (three weights and a bias
+    an expert) and attrs `pn_eps`, `pn_out_scale`, `pn_bias_clamp`; the
+    grouped kernel is then `grouped_polyglu` of the same module."""
     import jax.numpy as jnp
 
     from ..parallel.moe import routed_experts_share
@@ -64,6 +81,7 @@ def routed_experts_op(ins, attrs):
         route_scale=float(attrs.get("route_scale", 1.0)),
         route_norm=bool(attrs.get("route_norm", True)), live=live,
         score_func=attrs.get("score_func", "sigmoid"),
-        trainable=bool(attrs.get("trainable", False)), with_chosen=True)
+        trainable=bool(attrs.get("trainable", False)), with_chosen=True,
+        poly=_poly_experts(ins, attrs))
     return {"Out": out.reshape(x.shape), "Counts": counts,
             "Chosen": chosen.reshape(x.shape[:-1] + (-1,))}

@@ -12,12 +12,15 @@ math/bert_encoder_functor.cu) and fused optimizer passes
 * paged_attention — decode-step attention that walks the KV page table
                     directly (serving/kv_cache.py layout); paged_gqa_attention
                     for grouped heads, windows and rings; paged_mla_attention
-                    over latent pages (absorbed latent attention),
+                    over latent pages and latent rings (absorbed latent
+                    attention),
 * mla_prefill_attention — whole-prompt causal latent attention in its
                     expanded form on the layer's own [S, heads x width]
                     arrays: a step is one block pair of the causal
                     triangle for several heads, scores kept in VMEM, only
-                    the diagonal masked (and attended in sub-blocks),
+                    the diagonal masked (and attended in sub-blocks);
+                    grouped heads on fewer K/V heads, and with a window
+                    the band's block pairs alone,
 * grouped_swiglu  — the routed layer's held experts over rows sorted by
                     expert: the hit experts' weight blocks streamed once,
                     gate, up, silu(g) * u and down under each block;
@@ -27,6 +30,15 @@ math/bert_encoder_functor.cu) and fused optimizer passes
 * routed_combine  — the same layer's sorted rows weighed and summed into
                     their tokens: a token tile's contiguous runs staged
                     by DMA, the sum a one-hot product in float32.
+* grouped_polyglu — (in grouped_swiglu.py) the same stream of weight
+                    blocks for experts whose activation has a ROW
+                    statistic (PolyNorm): gate and up of all of F kept in
+                    VMEM, the statistics, then the down blocks,
+* mhc_mix         — a residual path of several streams around a sublayer:
+                    mhc_pre reads a token tile's streams once (norm, the
+                    maps' logits, sigmoid and 20 Sinkhorn iterations by
+                    lane rotations, the mixed input), mhc_post reads
+                    streams and output once and writes the streams once.
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,

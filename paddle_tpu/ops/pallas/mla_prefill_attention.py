@@ -57,6 +57,25 @@ scale x log2 e)``, so no product's input is scaled or re-rounded.
 Products take the inputs' dtype and accumulate in float32; the output is
 ``acc / l`` rounded once to the inputs' dtype (where ``W_o``'s product
 rounded the float32 output before). ``name="mla_prefill_attention"``.
+
+**Grouped heads** (``num_kv_heads`` < ``num_heads``; models/motif3.py:
+80 query heads on 16 K/V heads). ``kv`` is ``[S, nkv x (nope + dv)]`` and
+query head h reads K/V head ``h // group``. A step's heads are a multiple
+of the group as well, its K/V block the ``heads / group`` K/V heads under
+them, and a rotary tile's operand is put together from its heads' own K/V
+columns (lane-tile slices at traced offsets).
+
+**A window** (``window`` > 0: a query at t reads keys at ``t - window < s
+<= t``; whole lane tiles, a divisor of the block). Only the block pairs the
+band touches are enumerated: a query block's own diagonal pair and, past
+the first, the pair with the block before it. Both go in sub-blocks of
+``window`` query rows: on the diagonal each over the ``2 x window`` keys up
+to its own last, masked on both edges of the band; of the block before,
+the first ``window`` rows alone over its last ``window`` keys (the other
+rows' running statistics pass through). So a window layer computes
+``2 x window`` keys a query at any length, where the triangle computes
+S / 2. With ``window=0`` and ``group=1`` the lowered kernel is the one it
+always was.
 """
 
 from __future__ import annotations
@@ -81,11 +100,12 @@ VMEM_LIMIT = 64 << 20       # those + the score temporaries; v5e has 128 MiB
 
 
 def stock_mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, scale,
-                                block_q=256):
+                                block_q=256, window=0):
     """The stock lowering, and the kernel's oracle. q_nope [S, n, nope],
     q_rope [S, n, rope], k_nope [S, n, nope], k_rope [S, rope], v
     [S, n, dv] -> float32 [S, n, dv]. Queries go in blocks of `block_q`,
-    each over the keys at or before its last query (static slices)."""
+    each over the keys at or before its last query and, with `window`,
+    within the band (static slices)."""
     s, n, _ = q_nope.shape
     bq = min(block_q, s)
     if s % bq:
@@ -93,14 +113,21 @@ def stock_mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, scale,
     outs = []
     for q0 in range(0, s, bq):
         end = q0 + bq
-        sc = (jnp.einsum("qhd,shd->hqs", q_nope[q0:end], k_nope[:end],
+        k0 = max(0, q0 - window + 1) if window else 0
+        sc = (jnp.einsum("qhd,shd->hqs", q_nope[q0:end], k_nope[k0:end],
                          preferred_element_type=jnp.float32)
-              + jnp.einsum("qhr,sr->hqs", q_rope[q0:end], k_rope[:end],
+              + jnp.einsum("qhr,sr->hqs", q_rope[q0:end], k_rope[k0:end],
                            preferred_element_type=jnp.float32)) * scale
-        ok = jnp.arange(end, dtype=jnp.int32)[None, :] \
-            <= q0 + jnp.arange(bq, dtype=jnp.int32)[:, None]
+        if window:
+            gap = q0 + jnp.arange(bq, dtype=jnp.int32)[:, None] \
+                - jnp.arange(k0, end, dtype=jnp.int32)[None, :]
+            ok = (gap >= 0) & (gap < window)
+        else:
+            ok = jnp.arange(end, dtype=jnp.int32)[None, :] \
+                <= q0 + jnp.arange(bq, dtype=jnp.int32)[:, None]
         p = jax.nn.softmax(jnp.where(ok, sc, -1e9), axis=-1)
-        outs.append(jnp.einsum("hqs,shv->qhv", p.astype(v.dtype), v[:end],
+        outs.append(jnp.einsum("hqs,shv->qhv", p.astype(v.dtype),
+                               v[k0:end],
                                preferred_element_type=jnp.float32))
     return jnp.concatenate(outs, axis=0)
 
@@ -118,26 +145,48 @@ def _rope_key_tiles(k_rope):
          for i in range(_LANES // rope)], axis=1)
 
 
-def _heads_a_step(n, nope, rope, dv, block, itemsize):
+def _heads_a_step(n, nope, rope, dv, block, itemsize, group=1):
     """The most heads (a divisor of n whose rotary queries fill whole lane
-    tiles) whose blocks, double-buffered, and float32 scratch fit
-    VMEM_BLOCKS; 0 if not even the least does."""
+    tiles, and whole groups of `group` query heads a K/V head) whose
+    blocks, double-buffered, and float32 scratch fit VMEM_BLOCKS; 0 if not
+    even the least does."""
     share = max(1, _LANES // rope)
     best = 0
     for g in range(share, n + 1, share):
-        if n % g:
+        if n % g or g % group:
             continue
         blocks = 2 * itemsize * block * (
-            g * (nope + rope) + g * (nope + dv) + share * max(rope, _LANES)
-            + g * dv)
+            g * (nope + rope) + g // group * (nope + dv)
+            + share * max(rope, _LANES) + g * dv)
         scratch = 4 * g * block * (2 * _LANES + dv)
         if blocks + scratch <= VMEM_BLOCKS:
             best = g
     return best
 
 
-@functools.partial(jax.jit, static_argnames=("c", "nope", "dv", "sub"))
-def _attend_tile(qn, qr, kv, kr, m, l, acc, *, c, nope, dv, sub):
+def _spans(rows, keys, sub, window, before):
+    """The (first row, last row, first key, last key, mask) spans one
+    block pair is attended in: the whole pair below the diagonal; on the
+    diagonal sub-blocks of `sub` rows over the keys up to their own last,
+    masked key <= query; with `window` sub-blocks of `window` rows over
+    the band's keys (mask "band"), and of the block `before` the first
+    `window` rows over its last `window` keys alone (mask "before": key i
+    of those reaches rows < i)."""
+    if window and before:
+        return [(0, window, keys - window, keys, "before")]
+    if window:
+        return [(r0, r0 + window, max(0, r0 - window), r0 + window, "band")
+                for r0 in range(0, rows, window)]
+    if sub is None:
+        return [(0, rows, 0, keys, None)]
+    return [(r0, r0 + sub, 0, r0 + sub, "causal")
+            for r0 in range(0, rows, sub)]
+
+
+@functools.partial(jax.jit, static_argnames=("c", "nope", "dv", "sub",
+                                             "window", "before"))
+def _attend_tile(qn, qr, kv, kr, m, l, acc, *, c, nope, dv, sub, window=0,
+                 before=False):
     """The heads of ONE rotary lane tile on one (query block, key block)
     pair, on values the kernel has loaded: qn [rows, t x nope], qr
     [rows, 128 or rope] (the tile the t heads' rotary queries share), kv
@@ -146,7 +195,9 @@ def _attend_tile(qn, qr, kv, kr, m, l, acc, *, c, nope, dv, sub):
     the diagonal `sub` is None: every key counts. On the diagonal the
     block goes in sub-blocks of `sub` query rows, each on the keys up to
     its own last and masked key <= query, and the heads' finished output
-    [rows, t x dv] float32 comes back as well.
+    [rows, t x dv] float32 comes back as well. With `window` the pair is
+    the diagonal's band, or (`before`) the corner of the block before it
+    (`_spans`); rows no span covers keep their statistics.
 
     A jitted function of values so that its trace is made once a process
     and shape, not once a program: the kernel's own trace is a few loads,
@@ -156,43 +207,52 @@ def _attend_tile(qn, qr, kv, kr, m, l, acc, *, c, nope, dv, sub):
     heads, rows = m.shape[0], qn.shape[0]
     rw, kw = qr.shape[1], nope + dv
     ms, ls, accs = [], [], []
+    spans = _spans(rows, kv.shape[0], sub, window, before)
     for j in range(heads):
         parts = []
-        for r0 in range(0, rows, sub or rows):
-            r1 = r0 + (sub or rows)
-            keys = kv.shape[0] if sub is None else r1
+        for r0, r1, k0, keys, mask in spans:
             q = jnp.concatenate([qn[r0:r1, j * nope:(j + 1) * nope],
                                  qr[r0:r1]], axis=1)
-            k = jnp.concatenate([kv[:keys, j * kw:j * kw + nope],
-                                 kr[:keys, j * rw:(j + 1) * rw]], axis=1)
+            k = jnp.concatenate([kv[k0:keys, j * kw:j * kw + nope],
+                                 kr[k0:keys, j * rw:(j + 1) * rw]], axis=1)
             s = jax.lax.dot_general(q, k, nt,
                                     preferred_element_type=jnp.float32)
-            if sub is not None:
+            if mask is not None:
                 row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + r0
                 col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = jnp.where(col <= row, s, _NEG)
+                if mask == "causal":
+                    ok = col <= row
+                elif mask == "band":
+                    ok = (col + k0 <= row) & (col + k0 > row - window)
+                else:       # the block before: key i lies window - i back
+                    ok = col > row
+                s = jnp.where(ok, s, _NEG)
             m_old = m[j, r0:r1]                              # [rows, 128]
             m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
             corr = jnp.exp2((m_old - m_new) * c)
             e = jnp.exp2((s - _wide(m_new, s.shape[1])) * c)
-            v = kv[:keys, j * kw + nope:(j + 1) * kw]
+            v = kv[k0:keys, j * kw + nope:(j + 1) * kw]
             parts.append((
                 m_new,
                 l[j, r0:r1] * corr + jnp.sum(e, axis=-1, keepdims=True),
                 acc[j, r0:r1] * _wide(corr, dv) + jnp.dot(
                     e.astype(v.dtype), v,
                     preferred_element_type=jnp.float32)))
+        if spans[-1][1] < rows:         # rows the spans leave as they are
+            done = spans[-1][1]
+            parts.append((m[j, done:], l[j, done:], acc[j, done:]))
         for out, i in ((ms, 0), (ls, 1), (accs, 2)):
             out.append(jnp.concatenate([part[i] for part in parts]))
     stats = jnp.stack(ms), jnp.stack(ls), jnp.stack(accs)
-    if sub is None:
+    if before or (sub is None and not window):
         return stats
     return stats + (jnp.concatenate(
         [a / _wide(d, dv) for a, d in zip(accs, ls)], axis=1),)
 
 
 def _kernel(qi_ref, kj_ref, qn_ref, qr_ref, kv_ref, kr_ref, o_ref, m_ref,
-            l_ref, acc_ref, *, c, heads, nope, dv, rope, sub):
+            l_ref, acc_ref, *, c, heads, nope, dv, rope, sub, group=1,
+            window=0):
     from jax.experimental import pallas as pl
 
     t = pl.program_id(1)
@@ -200,13 +260,15 @@ def _kernel(qi_ref, kj_ref, qn_ref, qr_ref, kv_ref, kr_ref, o_ref, m_ref,
     share = max(1, _LANES // rope)          # heads to a rotary lane tile
     rw = max(rope, _LANES)                  # lanes of a rotary operand
 
-    @pl.when(kj == 0)
+    # a query block's first pair: key block 0, or with a window the block
+    # before its own
+    @pl.when(kj == (jnp.maximum(qi - 1, 0) if window else 0))
     def _():
         m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def tile(i, sub):
+    def tile(i, sub, **band):
         """Rotary tile i of the step (traced inside the loop): load,
         attend, store."""
         def lanes(width):
@@ -214,34 +276,50 @@ def _kernel(qi_ref, kj_ref, qn_ref, qr_ref, kv_ref, kr_ref, o_ref, m_ref,
                 return slice(i * width, (i + 1) * width)
             return pl.ds(pl.multiple_of(i * width, _LANES), width)
 
+        if group == 1:
+            kv = kv_ref[:, lanes(share * (nope + dv))]
+        else:       # each head's own K/V head's columns, side by side
+            kv = jnp.concatenate(
+                [kv_ref[:, pl.ds(pl.multiple_of(
+                    (i * share + j) // group * (nope + dv), _LANES),
+                    nope + dv)] for j in range(share)], axis=1)
         mine = pl.ds(i * share, share)
         m, l, acc, *out = _attend_tile(
             qn_ref[:, lanes(share * nope)], qr_ref[:, lanes(rw)],
-            kv_ref[:, lanes(share * (nope + dv))], kr_ref[...], m_ref[mine],
-            l_ref[mine], acc_ref[mine], c=c, nope=nope, dv=dv, sub=sub)
+            kv, kr_ref[...], m_ref[mine],
+            l_ref[mine], acc_ref[mine], c=c, nope=nope, dv=dv, sub=sub,
+            **band)
         m_ref[mine], l_ref[mine], acc_ref[mine] = m, l, acc
         if out:
             o_ref[:, lanes(share * dv)] = out[0].astype(o_ref.dtype)
 
-    def every_tile(sub):
+    def every_tile(sub, **band):
         if heads == share:
-            tile(0, sub)
+            tile(0, sub, **band)
         else:
             jax.lax.fori_loop(
-                0, heads // share, lambda i, _: (tile(i, sub), 0)[1], 0)
+                0, heads // share,
+                lambda i, _: (tile(i, sub, **band), 0)[1], 0)
 
     @pl.when(kj < qi)
     def _():
-        every_tile(None)
+        if window:
+            every_tile(None, window=window, before=True)
+        else:
+            every_tile(None)
 
     @pl.when(kj == qi)
     def _():
-        every_tile(sub)
+        if window:
+            every_tile(None, window=window)
+        else:
+            every_tile(sub)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _pallas_mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, n,
-                                  nope, block, heads, interpret):
+                                  nope, block, heads, interpret, group=1,
+                                  window=0):
     # jitted so that a program's layers share ONE trace and lowering of
     # the kernel
     from jax.experimental import pallas as pl
@@ -249,12 +327,14 @@ def _pallas_mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, n,
 
     s = q_nope.shape[0]
     rope = q_rope.shape[1] // n
-    dv = kv.shape[1] // n - nope
+    dv = kv.shape[1] // (n // group) - nope
     kr = _rope_key_tiles(k_rope)
     # the causal triangle's (query block, key block) pairs, a query
-    # block's in key order and its diagonal last
+    # block's in key order and its diagonal last; with a window (no wider
+    # than a block) the diagonal and the block before it
     pairs = np.array([(i, j) for i in range(s // block)
-                      for j in range(i + 1)], np.int32)
+                      for j in range(max(0, i - 1) if window else 0,
+                                     i + 1)], np.int32)
 
     def of_query(h, t, qi, kj):
         return (qi[t], h)
@@ -267,7 +347,8 @@ def _pallas_mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, n,
         grid=(n // heads, len(pairs)),
         in_specs=[pl.BlockSpec((block, heads * nope), of_query),
                   pl.BlockSpec((block, heads * rope), of_query),
-                  pl.BlockSpec((block, heads * (nope + dv)), of_key),
+                  pl.BlockSpec((block, heads // group * (nope + dv)),
+                               of_key),
                   pl.BlockSpec((block, kr.shape[1]),
                                lambda h, t, qi, kj: (kj[t], 0))],
         out_specs=pl.BlockSpec((block, heads * dv), of_query),
@@ -277,7 +358,8 @@ def _pallas_mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, n,
     return pl.pallas_call(
         functools.partial(_kernel, c=scale * math.log2(math.e), heads=heads,
                           nope=nope, dv=dv, rope=rope,
-                          sub=SUB if block % SUB == 0 else block),
+                          sub=SUB if block % SUB == 0 else block,
+                          group=group, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, n * dv), kv.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -288,13 +370,18 @@ def _pallas_mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, n,
 
 
 def mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, *, num_heads,
-                          nope_dim):
+                          nope_dim, num_kv_heads=None, window=0):
     """Causal attention of one prompt in the layer's own layout. q_nope
     [S, n x nope]; q_rope [S, n x rope]; kv [S, n x (nope + dv)] (a head:
     key part, then value); k_rope [S, rope] (one key for all heads).
-    Returns [S, n x dv] in kv's dtype. Routed per ``kernel_mode()``; a
+    Returns [S, n x dv] in kv's dtype. With `num_kv_heads` kv is
+    [S, nkv x (nope + dv)] and query head h reads K/V head h // (n / nkv);
+    with `window` a query reads its last `window` keys only (module
+    docstring). Routed per ``kernel_mode()``; a
     stock fallback is counted in ``pallas.mla_prefill_fallbacks`` by its
     reason: ``mode_off``; ``length`` (S no multiple of its block);
+    ``window`` (a window that is no whole lane tiles or no divisor of
+    the block);
     ``tpu_tiling`` (a head width or the block no whole lane tiles, a rope
     that neither divides a lane tile nor is a multiple of one, or heads
     that do not fill their shared rotary tiles); ``vmem`` (one step's
@@ -305,8 +392,12 @@ def mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, *, num_heads,
         raise ValueError(f"softmax scale {scale} is not positive: the "
                          f"running maximum is taken of the raw scores")
     s, n, nope = q_nope.shape[0], num_heads, nope_dim
+    nkv = num_kv_heads or n
+    group = n // nkv
+    if group * nkv != n:
+        raise ValueError(f"{n} query heads on {nkv} K/V heads")
     rope = q_rope.shape[1] // n
-    dv = kv.shape[1] // n - nope
+    dv = kv.shape[1] // nkv - nope
     block = min(BLOCK, s)
     mode = kernel_mode()
     heads = 0
@@ -315,24 +406,29 @@ def mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, *, num_heads,
         reason = "mode_off"
     elif s % block:
         reason = "length"
+    elif window and (window % _LANES or block % window):
+        reason = "window"
     elif (nope % _LANES or dv % _LANES or block % _LANES
           or (rope % _LANES and _LANES % rope)
           or n % max(1, _LANES // rope)):
         reason = "tpu_tiling"
     else:
-        heads = _heads_a_step(n, nope, rope, dv, block, kv.dtype.itemsize)
+        heads = _heads_a_step(n, nope, rope, dv, block, kv.dtype.itemsize,
+                              group)
         if not heads:
             reason = "vmem"
     if reason is not None:
         telemetry.counter_add("pallas.mla_prefill_fallbacks", 1,
                               reason=reason)
-        kvh = kv.reshape(s, n, nope + dv)
+        kvh = kv.reshape(s, nkv, nope + dv)
+        if group > 1:
+            kvh = jnp.repeat(kvh, group, axis=1)
         out = stock_mla_prefill_attention(
             q_nope.reshape(s, n, nope), q_rope.reshape(s, n, rope),
             kvh[:, :, :nope], k_rope, kvh[:, :, nope:], scale,
-            block_q=math.gcd(s, SUB))
+            block_q=math.gcd(s, SUB), window=window)
         return out.reshape(s, n * dv).astype(kv.dtype)
     telemetry.counter_add("pallas.mla_prefill_dispatches", 1, mode=mode)
     return _pallas_mla_prefill_attention(
         q_nope, q_rope, kv, k_rope, float(scale), n, nope, block, heads,
-        interpret=mode == "interpret")
+        mode == "interpret", group, window)

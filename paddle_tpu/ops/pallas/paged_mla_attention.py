@@ -34,6 +34,13 @@ scratch is zeroed before the first row, so it is finite). Chunks join by
 online-softmax accumulation. Scores, softmax and accumulation are float32;
 the two dots take the pages' dtype.
 
+**A latent ring** (``window`` > 0; a window layer of models/motif3.py):
+the table is a slot's ring of ``cap = MP x P`` rows, the row of position s
+lies at index ``s mod cap``, and a head attends the rows whose TRUE
+position is in ``pos - window < s <= pos``. The walk is the same and never
+longer than the ring (3 pages of 64 at window 128); only the mask differs:
+index i of the ring holds position ``pos - ((pos - i) mod cap)``.
+
 Dispatch and fallback counts land in the same counters as the other two
 paged kernels' (``pallas.paged_attn_dispatches`` / ``_fallbacks``).
 """
@@ -53,10 +60,18 @@ KERNEL_NAME = "paged_mla_attention"
 CHUNK_TOKENS = 1024
 
 
-def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale):
+def _ring_valid(idx, pos, cap, window):
+    """Which ring indices `idx` hold a key row `pos` attends: the true
+    position of index i is ``pos - ((pos - i) mod cap)``."""
+    true = pos - jnp.remainder(pos - idx, cap)
+    return (true >= 0) & (pos - true < window)
+
+
+def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
+                              window=0):
     """The counted stock lowering, and the kernel's oracle: dense page
-    gather, scores in float32, positions past the row's own masked before
-    the softmax."""
+    gather, scores in float32, positions past the row's own (outside its
+    window, in a ring) masked before the softmax."""
     b = q.shape[0]
     page, width = int(pool.shape[1]), int(pool.shape[2])
     cap = int(table.shape[1]) * page
@@ -64,7 +79,9 @@ def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale):
     qh = q.reshape(b, n, width).astype(rows.dtype)
     scores = jnp.einsum("bhw,bsw->bhs", qh, rows,
                         preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(cap, dtype=jnp.int32)[None, :] <= pos[:, None]
+    idx = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    valid = _ring_valid(idx, pos[:, None], cap, window) if window \
+        else idx <= pos[:, None]
     probs = jax.nn.softmax(jnp.where(valid[:, None, :], scores, -1e9),
                            axis=-1)
     out = jnp.einsum("bhs,bsv->bhv", probs.astype(rows.dtype),
@@ -74,7 +91,7 @@ def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale):
 
 
 def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
-            slot_ref, *, value_dim, page, mp, chunk_pages, scale):
+            slot_ref, *, value_dim, page, mp, chunk_pages, scale, window):
     """Grid (B,), sequential: row i attends its (heads, width) queries over
     its own pages, ``chunk_pages`` pages at a time. ``cs_ref`` is (2, chunk
     tokens, width) and persists across rows, as do the half in turn
@@ -144,7 +161,8 @@ def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
             s += jax.lax.dot_general(q_r, cs_ref[slot, :, value_dim:], nt,
                                      preferred_element_type=jnp.float32)
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, ct), 1) + c * ct
-        valid = idx <= pos
+        valid = _ring_valid(idx, pos, mp * page, window) if window \
+            else idx <= pos
         s = jnp.where(valid, s * scale, -1e9)                   # (n, ct)
         m_new = jnp.maximum(m_run, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_run - m_new)
@@ -163,7 +181,7 @@ def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
 
 
 def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
-                                interpret):
+                                interpret, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -184,7 +202,8 @@ def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
             pltpu.SMEM((1,), jnp.int32)])
     out = pl.pallas_call(
         functools.partial(_kernel, value_dim=value_dim, page=page, mp=mp,
-                          chunk_pages=chunk_pages, scale=scale),
+                          chunk_pages=chunk_pages, scale=scale,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, value_dim), jnp.float32),
         # rows run in order: the scratch, the half in turn and the copies
@@ -197,15 +216,16 @@ def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
 
 
 def paged_mla_decode_attention(q, pool, table, positions, num_heads,
-                               value_dim, scale):
+                               value_dim, scale, window=0):
     """Attend each row's absorbed queries over its own latent pages.
 
     q [B, n*width] (a head: the absorbed latent query, then the rotated
     part); pool [N, P, width] (already holding the step's row); table
     [B, MP] int32, a context's pages in order; positions [B] int32.
     Returns float32 [B, n*value_dim]: a head's probabilities over the
-    rows' first ``value_dim`` entries. Routed per ``kernel_mode()``; every
-    stock fallback is counted."""
+    rows' first ``value_dim`` entries. With ``window`` the table is a
+    slot's latent ring (module docstring). Routed per ``kernel_mode()``;
+    every stock fallback is counted."""
     from . import kernel_mode
 
     n, value_dim = int(num_heads), int(value_dim)
@@ -229,9 +249,9 @@ def paged_mla_decode_attention(q, pool, table, positions, num_heads,
         telemetry.counter_add("pallas.paged_attn_fallbacks", 1,
                               reason=reason)
         return stock_paged_mla_attention(q, pool, table, pos, n, value_dim,
-                                         scale)
+                                         scale, window)
     telemetry.counter_add("pallas.paged_attn_dispatches", 1, mode=mode,
                           kernel=KERNEL_NAME)
     return _pallas_paged_mla_attention(
         q, pool, jnp.asarray(table, jnp.int32), pos, n, value_dim,
-        float(scale), interpret=mode == "interpret")
+        float(scale), interpret=mode == "interpret", window=int(window))

@@ -212,14 +212,22 @@ def _held_experts_bwd(few, res, dout):
 
 
 def _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few):
+    """SwiGLU experts: the eight arguments `_held_experts_fwd` / `_bwd`
+    are written for (`jax.custom_vjp` hands them a default too)."""
+    return _held_experts_of(x, w_sorted, w1, w3, w2, rows, sizes, few, None)
+
+
+def _held_experts_of(x, w_sorted, w1, w3, w2, rows, sizes, few, poly):
     """sum_i w_i * Expert_i(x) over the pairs sorted by held expert: their
     tokens `rows`, their weights `w_sorted` (0 past the groups), `sizes`
     of them in each expert's group. Out [T, H] float32. Differentiable in
-    x, the weights and the three matrices (`_held_experts_bwd`)."""
+    x, the weights and the three matrices (`_held_experts_bwd`). `poly`
+    (pn [E_held, 4], eps, out_scale, bias_clamp) makes the experts
+    PolyNorm ones (``grouped_polyglu``), served only."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.pallas.grouped_swiglu import grouped_swiglu
+    from ..ops.pallas.grouped_swiglu import grouped_polyglu, grouped_swiglu
     from ..ops.pallas.routed_combine import routed_combine
 
     t, h = x.shape
@@ -227,7 +235,13 @@ def _held_experts(x, w_sorted, w1, w3, w2, rows, sizes, few):
 
     def experts(r, w, sizes):
         xs = x[r].astype(w1.dtype)                               # [n, H]
-        ys = grouped_swiglu(xs, w1, w3, w2, sizes)               # [n, H]
+        if poly is None:
+            ys = grouped_swiglu(xs, w1, w3, w2, sizes)           # [n, H]
+        else:
+            pn, eps, out_scale, bias_clamp = poly
+            ys = grouped_polyglu(xs, w1, w3, w2, pn, sizes, eps=eps,
+                                 out_scale=out_scale,
+                                 bias_clamp=bias_clamp)
         # rows past the groups hold nothing of a held expert: weight 0,
         # and whatever the grouped product left there stays out
         return routed_combine(ys, r, w, sizes, t)
@@ -350,7 +364,8 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
                          top_k: int, held_lo: int, route_scale: float = 1.0,
                          route_norm: bool = True, live=None,
                          score_func: str = "sigmoid",
-                         trainable: bool = False, with_chosen: bool = False):
+                         trainable: bool = False, with_chosen: bool = False,
+                         poly=None):
     """One chip's share of a dropless top-k routed expert layer
     (`switch_moe` above is the top-1 layer with a capacity). Scores are
     ``sigmoid(x Wr)`` or, with ``score_func="softmax"``, the softmax over
@@ -393,6 +408,14 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     they nearly always are, and otherwise over that many sorted rows at a
     time, as far as the held pairs reach (one ``lax.cond``). What the absent experts would
     have added is left out; nothing stands in for their chips or the exchange.
+
+    ``poly`` left out means SwiGLU experts, as above; ``poly`` = (pn
+    [E_held, 4] float32, eps, out_scale, bias_clamp) means PolyNorm ones:
+    every expert ``(PN_e(x W1) * (x W3)) W2`` with a row statistic over
+    its whole width; the grouped kernel is then
+    ``grouped_polyglu`` beside ``grouped_swiglu`` in the same module, its
+    fallback three ragged products and the norm, counted
+    (``pallas.grouped_polyglu_fallbacks``). Served only.
 
     ``trainable`` makes the result differentiable in x, the router and
     the held experts' three matrices (the selection is discrete and takes
@@ -457,8 +480,16 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
         tile = 4096 if even >= 4096 else 64
         few = int(-(-(1.25 * even) // tile) * tile)
 
-    held_experts = _trained_held_experts() if trainable else _held_experts
-    out = held_experts(x, w_sorted, w1, w3, w2, rows, sizes, min(few, pairs))
+    if poly is not None:
+        if trainable:
+            raise ValueError("PolyNorm experts have no hand-written backward")
+        out = _held_experts_of(x, w_sorted, w1, w3, w2, rows, sizes,
+                               min(few, pairs), poly)
+    else:
+        held_experts = _trained_held_experts() if trainable \
+            else _held_experts
+        out = held_experts(x, w_sorted, w1, w3, w2, rows, sizes,
+                           min(few, pairs))
     counts = [jnp.sum(alive) * top_k, jnp.sum(sizes), jnp.sum(sizes > 0)]
     if trainable:
         counts.append(jnp.max(sizes))

@@ -99,7 +99,8 @@ decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
 histogram; for a model with ring layers decode.rows_past_window and, for
 one with ring or latent layers, decode.kv_tokens_attended (cached tokens
 read a step, over rows and layers: a ring layer reads min(context,
-window), a latent layer its context's latents), and whatever counters
+window), a latent layer its context's latents; with latent RINGS
+decode.ring_latent_rows_attended is the rings' part), and whatever counters
 the model's step program returns beside its tokens (``ServedModel.step_counters``: the
 routed-expert counts of models/afmoe.py), fetched in the step's one fetch; decode.active_slots + decode.queue_depth +
 mem.serving.kv_* gauges — rendered by tools/perf_report.py's "Decode"
@@ -612,7 +613,9 @@ class DecodeEngine:
         token, the one before its last chosen one); ``keep_final_pages``
         leaves its own pages of the context class likewise
         (``final_pages``: pool array name -> [its pages, page, kv_dim] in
-        the order of its page table, token t at ``[t // page, t % page]``)."""
+        the order of its page table, token t at ``[t // page, t % page]``;
+        for a model with rings also its ring's pages, in the ring's order:
+        token t at index ``t mod (ring pages x page)``)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -1494,8 +1497,14 @@ class DecodeEngine:
             if self.kv.ring is not None:
                 telemetry.counter_add("decode.rows_past_window",
                                       int(np.sum(ctx > self.kv.window)))
-                attended += len(self.kv.ring.layers) \
+                in_rings = len(self.kv.ring.layers) \
                     * np.minimum(ctx, self.kv.window).sum()
+                attended += in_rings
+                if any(self.kv.ring.latent):
+                    # latent rows read from rings, apart from those read
+                    # from a context's latent pages
+                    telemetry.counter_add(
+                        "decode.ring_latent_rows_attended", int(in_rings))
             telemetry.counter_add("decode.kv_tokens_attended",
                                   int(attended))
         # one span for accepting the step's tokens. A request that finishes
@@ -1630,6 +1639,11 @@ class DecodeEngine:
             pages = np.asarray(req.pages, np.int32)
             req.final_pages = {n: self._pools[n][pages]
                                for n in self.pool.array_names()}
+            if self.kv.ring is not None:    # and its ring, in ring order
+                ring = np.asarray(req.ring_pages, np.int32)
+                req.final_pages.update(
+                    (n, self._pools[n][ring])
+                    for n in self.kv.ring.array_names())
         self._release_slot(req)
         # A slot's recurrent state needs no clearing, here or with a step in
         # flight that still advances it (a row dispatched on speculation):

@@ -69,9 +69,6 @@ class LayerCache:
     state_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.latent and self.window:
-            raise ValueError("a latent layer keeps a context's pages, "
-                             "not a ring")
         if self.kv_dim == 0 and (self.window or self.latent
                                  or not self.ssm_state):
             raise ValueError("a layer without K/V (kv_dim 0) is a "
@@ -122,7 +119,8 @@ class KVPagePool:
     (a request's page j is page j of each of the pool's layers). A model
     whose layers all hold a context's pages has one pool over layers
     0..n_layers-1, as ever; `PagedKVCache` below puts a second pool beside
-    it for the layers that keep a ring. ``layers`` names the model's layer
+    it for the layers that keep a ring (K and V, or a latent ring's one
+    array). ``layers`` names the model's layer
     indices this pool holds (the arrays' names) and ``kv_dims`` their
     widths; ``latent`` says which of them keep one array a layer and not
     K and V (their bytes are also booked as
@@ -159,13 +157,14 @@ class KVPagePool:
         token_bytes = np.dtype(dtype).itemsize * self.page_size \
             * self.num_pages
         # keys + values of every layer; a latent layer keeps one array
-        latent_bytes = token_bytes * sum(
+        self.latent_bytes = token_bytes * sum(
             d for d, lat in zip(self.kv_dims, self.latent) if lat)
-        self.pool_bytes = 2 * token_bytes * sum(self.kv_dims) - latent_bytes
+        self.pool_bytes = 2 * token_bytes * sum(self.kv_dims) \
+            - self.latent_bytes
         self._page_bytes = self.pool_bytes // self.num_pages
-        if latent_bytes:
+        if self.latent_bytes:
             telemetry.gauge_set("mem.serving.kv_pool_bytes.latent",
-                                latent_bytes)
+                                self.latent_bytes)
         if klass:
             telemetry.gauge_set(f"mem.serving.kv_pool_bytes.{klass}",
                                 self.pool_bytes)
@@ -374,9 +373,14 @@ class PagedKVCache:
             self.ring = KVPagePool(
                 len(rings), ring_pages, page_size, layout[rings[0]].kv_dim,
                 dtype, layers=rings,
-                kv_dims=[layout[i].kv_dim for i in rings], klass=self.RING)
+                kv_dims=[layout[i].kv_dim for i in rings], klass=self.RING,
+                latent=[layout[i].latent for i in rings])
             telemetry.gauge_set("mem.serving.kv_pool_bytes",
                                 self.pool_bytes)
+            if self.ring.latent_bytes:      # latent rows of both classes
+                telemetry.gauge_set(
+                    "mem.serving.kv_pool_bytes.latent",
+                    self.context.latent_bytes + self.ring.latent_bytes)
         self.state_layers = [i for i, lc in enumerate(layout)
                              if lc.ssm_state]
         self.state_slots = int(slots) + 1 if self.state_layers else 0

@@ -9,8 +9,9 @@ sequence (`kv_cache.LayerCache`: the width of its K/V and whether its
 pages are a context's or a ring, or that it keeps ONE latent array a
 token and no K and V; and what it keeps a SLOT beside them, or INSTEAD
 of them: a recurrent state and a conv tail). models/decoder_lm.py,
-models/afmoe.py, models/kimi_k2.py, models/falcon_h1.py and
-models/qwen3_next.py each give one; ``cfg.served()`` builds it.
+models/afmoe.py, models/kimi_k2.py, models/falcon_h1.py,
+models/qwen3_next.py and models/motif3.py each give one; ``cfg.served()``
+builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
 the program reads beside parameters and pools, out of what the engine can
@@ -29,9 +30,11 @@ names (`kv_cache.pool_array_names`: ``kv_k_<l>`` and ``kv_v_<l>``, or a
 latent layer's one ``kv_c_<l>``) and writes each back as ``<name>_out``:
 the engine threads and donates exactly `PagedKVCache.make_arrays()`'s
 names. A latent layer so feeds ``kv_c_<l>`` [pages, page, row width] and
-fetches ``kv_c_<l>_out``; it reads ``page_table`` alone (no ring) and its
-model builds no chunk program (the engine refuses the prefix store for
-it). A layer with per-slot state (`LayerCache.ssm_state`) also feeds
+fetches ``kv_c_<l>_out``; it reads ``page_table``, or with a window
+(`LayerCache(latent=True, window=w)`, a LATENT RING: models/motif3.py)
+its ``kv_c_<l>`` is of the ring class, [ring pages, page, row width], and
+it reads ``ring_table``; its model builds no chunk program (the engine
+refuses the prefix store for it). A layer with per-slot state (`LayerCache.ssm_state`) also feeds
 ``ssm_state_<l>`` [slots + 1, heads, d_state, head_dim] and
 ``conv_tail_<l>`` [slots + 1, d_conv - 1, conv_dim]
 (`kv_cache.state_array_names`) and writes each back likewise; a

@@ -266,6 +266,91 @@ def test_paged_gqa_attention_at_the_qwen3_next_share(one_chip, tpu_mode):
         ((64, 288), I32), ((64,), I32))
 
 
+@pytest.mark.parametrize("rows", [64, 16384])
+def test_mhc_kernels_at_the_motif3_share(one_chip, tpu_mode, rows):
+    """Four streams of 4096 float32 a token, 24 maps in a lane tile, 20
+    Sinkhorn iterations of lane rotations; a step's 64 rows and the largest
+    bucket's 16,384 in tiles of 64."""
+    from paddle_tpu.ops.pallas import mhc_mix
+
+    def pre(x, gamma, phi, scale, bias):
+        return mhc_mix.mhc_pre(x, gamma, phi, scale, bias, n=4, iters=20,
+                               eps=1e-5)
+
+    text = _compile(pre, one_chip, ((rows, 16384), F32), ((16384,), F32),
+                    ((16384, 24), BF16), ((3,), F32), ((24,), F32))
+    assert "mhc_pre" in text
+
+    def post(x, y, maps):
+        return mhc_mix.mhc_post(x, y, maps, n=4, clamp=1e6)
+
+    text = _compile(post, one_chip, ((rows, 16384), F32), ((rows, 4096), F32),
+                    ((rows, 128), F32))
+    assert "mhc_post" in text
+
+
+@pytest.mark.parametrize("n", [192, 32832])
+def test_grouped_polyglu_at_the_motif3_share(one_chip, tpu_mode, n):
+    """48 held PolyNorm experts of 4096 x 1280 in bfloat16, their four
+    parameters in SMEM, gate and up of all of F in float32 scratch: a
+    step's 192 sorted rows and a 16,384 bucket's 32,832 in tiles of
+    1,024."""
+    from paddle_tpu.ops.pallas import grouped_swiglu as gs
+
+    assert gs._tiles(n, 4096, 1280, BF16) == (min(n, 1024), 128, 640)
+
+    def experts(xs, w1, w3, w2, pn, sizes):
+        return gs.grouped_polyglu(xs, w1, w3, w2, pn, sizes, eps=1e-5,
+                                  out_scale=0.5, bias_clamp=0.5)
+
+    text = _compile(experts, one_chip, ((n, 4096), BF16),
+                    ((48, 4096, 1280), BF16), ((48, 4096, 1280), BF16),
+                    ((48, 1280, 4096), BF16), ((48, 4), F32), ((48,), I32))
+    assert "grouped_polyglu" in text
+
+
+@pytest.mark.parametrize("window", [0, 128])
+def test_mla_prefill_attention_at_the_motif3_share(one_chip, tpu_mode,
+                                                   window):
+    """80 query heads on 16 K/V heads (ten heads, two K/V heads a step), a
+    4,096 bucket: the triangle of a full layer and the band of a window
+    layer through the op on the layer's own arrays."""
+    from paddle_tpu.ops.llm_ops import mla_prefill_attention_op
+    from paddle_tpu.ops.pallas import mla_prefill_attention as mpa
+
+    n, nkv, nope, rope, dv, s = 80, 16, 128, 64, 128, 4096
+    assert mpa._heads_a_step(n, nope, rope, dv, mpa.BLOCK, 2, group=5) == 10
+
+    def attend(qn, qr, kv, latent):
+        attrs = {"num_heads": n, "num_kv_heads": nkv, "nope_dim": nope,
+                 "rope_dim": rope, "scale": 192 ** -0.5,
+                 "compute_dtype": "bfloat16"}
+        if window:
+            attrs["window"] = window
+        return mla_prefill_attention_op(
+            {"QNope": [qn], "QRope": [qr], "KV": [kv], "Latent": [latent]},
+            attrs)["Out"]
+
+    _compile(attend, one_chip, ((1, s, n * nope), F32),
+             ((1, s, n * rope), F32), ((1, s, nkv * (nope + dv)), F32),
+             ((1, s, 512 + rope), F32))
+
+
+def test_paged_mla_attention_over_a_latent_ring(one_chip, tpu_mode):
+    """80 absorbed heads over a slot's ring of 3 pages of a pool of 193:
+    the walk of the paged kernel with the ring's mask."""
+    from paddle_tpu.ops.pallas.paged_mla_attention import \
+        paged_mla_decode_attention
+
+    def attend(q, pool, t, p):
+        return paged_mla_decode_attention(q, pool, t, p, num_heads=80,
+                                          value_dim=512, scale=0.0722,
+                                          window=128)
+
+    _compile(attend, one_chip, ((64, 80 * 640), F32),
+             ((193, 64, 640), BF16), ((64, 3), I32), ((64,), I32))
+
+
 def test_layer_norm_fwd_bwd(one_chip, tpu_mode):
     from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
 

@@ -94,8 +94,36 @@ def test_a_pool_of_k_and_v_layers_reads_as_before_beside_a_latent_one():
 
 
 def test_a_latent_layer_has_no_ring():
-    with pytest.raises(ValueError, match="ring"):
-        LayerCache(640, window=64, latent=True)
+    """It has one since models/motif3.py: `LayerCache(latent=True,
+    window=w)` is a LATENT RING, one array a layer in the ring class,
+    `ring_pages_per_slot` pages a slot, its bytes booked as latent AND as
+    ring."""
+    telemetry.reset()
+    layout = [LayerCache(640, window=128, latent=True),
+              LayerCache(640, latent=True),
+              LayerCache(640, window=128, latent=True)]
+    assert all(lc.latent for lc in layout) and layout[0].ring
+    kv = PagedKVCache(layout, page_size=64, context_pages=9, ring_pages=7,
+                      dtype="bfloat16")
+    assert kv.ring_slot_pages == 3 and kv.window == 128
+    assert kv.ring.layers == [0, 2] and kv.context.layers == [1]
+    assert kv.ring.array_names() == ["kv_c_0", "kv_c_2"]
+    arrays = kv.make_arrays()
+    assert sorted(arrays) == ["kv_c_0", "kv_c_1", "kv_c_2"]
+    assert arrays["kv_c_0"].shape == (7, 64, 640)
+    assert arrays["kv_c_1"].shape == (9, 64, 640)
+    one_page = 64 * 640 * 2
+    gauges = telemetry.snapshot()["gauges"]
+    assert gauges["mem.serving.kv_pool_bytes.ring"] == 2 * 7 * one_page
+    assert gauges["mem.serving.kv_pool_bytes.context"] == 9 * one_page
+    assert gauges["mem.serving.kv_pool_bytes.latent"] \
+        == (2 * 7 + 9) * one_page == kv.pool_bytes
+    # a request past the window takes a whole ring and no more
+    assert kv.pages_for_tokens(1000) == (16, 3)
+    got = kv.try_alloc(4, 3)
+    assert got is not None and len(got[1]) == 3
+    kv.free(*got)
+    assert not kv.audit([], [])
 
 
 # -- rotary ------------------------------------------------------------------

@@ -454,6 +454,10 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.gated_delta_state_update_fallbacks",
                "pallas.grouped_swiglu_dispatches",
                "pallas.grouped_swiglu_fallbacks",
+               "pallas.grouped_polyglu_dispatches",
+               "pallas.grouped_polyglu_fallbacks",
+               "pallas.mhc_dispatches",
+               "pallas.mhc_fallbacks",
                "pallas.grouped_swiglu_bwd_dispatches",
                "pallas.grouped_swiglu_bwd_fallbacks",
                "pallas.routed_combine_dispatches",
