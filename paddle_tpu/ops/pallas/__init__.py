@@ -29,7 +29,11 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     the three matrices'),
 * routed_combine  — the same layer's sorted rows weighed and summed into
                     their tokens: a token tile's contiguous runs staged
-                    by DMA, the sum a one-hot product in float32.
+                    by DMA, the sum a one-hot product in float32;
+                    routed_spread its transpose over the same runs: a
+                    token tile's rows to their sorted places, forward,
+                    and the backward's cotangent rows with their weighed
+                    copy, each rounded once.
 * grouped_polyglu — (in grouped_swiglu.py) the same stream of weight
                     blocks for experts whose activation has a ROW
                     statistic (PolyNorm): gate and up of all of F kept in

@@ -13,12 +13,13 @@ all_to_alls drop out — same math, no comm.
 `routed_experts_share` is one chip's share of a dropless top-k routed
 layer, served and (``trainable=True``) trained: the pairs sorted by held
 expert (their plan compares and sorts, and gathers no single float),
-the forward one grouped kernel over the sorted rows
-(ops/pallas/grouped_swiglu.py), the hand-written backward two
+the tokens' rows spread to their sorted places by one kernel
+(ops/pallas/routed_spread.py), the forward one grouped kernel over the
+sorted rows (ops/pallas/grouped_swiglu.py), the hand-written backward two
 (ops/pallas/grouped_swiglu_bwd.py: rows-side, weights-side), the sorted
 rows summed into their tokens by a fourth, forward and backward
-(ops/pallas/routed_combine.py); ragged products and a scatter-add
-wherever a kernel cannot run, counted.
+(ops/pallas/routed_combine.py); a gather, ragged products and a
+scatter-add wherever a kernel cannot run, counted.
 """
 
 from __future__ import annotations
@@ -144,8 +145,10 @@ def _held_experts_bwd(few, res, dout):
     up products again (a sorted row's [F] float32 pair is not kept: 2 x
     470 MB a layer at 65,536 rows x 896), then the six products of the
     gradients, as two grouped kernels
-    (``ops/pallas/grouped_swiglu_bwd.py``: rows-side and weights-side;
-    the gathers around them are XLA's), or as eight
+    (``ops/pallas/grouped_swiglu_bwd.py``: rows-side and weights-side)
+    over what ``routed_spread`` makes of `x` (again: the sorted rows are
+    not kept) and of `dout` (its rows and their weighed copy, each
+    rounded once from the float32 row), or as eight
     ragged products over the groups (`stock_grouped_swiglu_bwd`) where
     ``kernel_mode()`` is off, the dtypes are mixed or the kernels cannot
     tile the shape, counted; the rows' gradients summed into their
@@ -157,6 +160,7 @@ def _held_experts_bwd(few, res, dout):
 
     from ..ops.pallas.grouped_swiglu_bwd import grouped_swiglu_bwd
     from ..ops.pallas.routed_combine import routed_combine
+    from ..ops.pallas.routed_spread import routed_spread
 
     x, w_sorted, w1, w3, w2, rows, sizes = res
     t, h = x.shape
@@ -168,10 +172,11 @@ def _held_experts_bwd(few, res, dout):
         """One run of sorted rows -> (dx combined [T, H], dw [n], dW1,
         dW3, dW2 in float32)."""
         mine = w > 0
-        dy = jnp.where(mine[:, None], dout[r], 0.0)
+        # the rows again (they are not kept), and the cotangent's rows
+        # with their weighed copy, each rounded once
+        dy, dyw = routed_spread(dout, r, w, part, dt, weighted=True)
         dxs, dw, d1, d3, d2 = grouped_swiglu_bwd(
-            x[r].astype(dt), dy.astype(dt), (dy * w[:, None]).astype(dt), w,
-            w1, w3, w2, part)
+            routed_spread(x, r, w, part, dt), dy, dyw, w, w1, w3, w2, part)
         dx = routed_combine(dxs, r, mine.astype(f32), part, t)
         return dx, jnp.where(mine, dw, 0.0), d1, d3, d2
 
@@ -229,12 +234,13 @@ def _held_experts_of(x, w_sorted, w1, w3, w2, rows, sizes, few, poly):
 
     from ..ops.pallas.grouped_swiglu import grouped_polyglu, grouped_swiglu
     from ..ops.pallas.routed_combine import routed_combine
+    from ..ops.pallas.routed_spread import routed_spread
 
     t, h = x.shape
     pairs = rows.shape[0]
 
     def experts(r, w, sizes):
-        xs = x[r].astype(w1.dtype)                               # [n, H]
+        xs = routed_spread(x, r, w, sizes, w1.dtype)             # [n, H]
         if poly is None:
             ys = grouped_swiglu(xs, w1, w3, w2, sizes)           # [n, H]
         else:
@@ -406,7 +412,10 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     or H is not of 128, counted). It runs
     over the leading rows that hold the held pairs when those are few, as
     they nearly always are, and otherwise over that many sorted rows at a
-    time, as far as the held pairs reach (one ``lax.cond``). What the absent experts would
+    time, as far as the held pairs reach (one ``lax.cond``); the tokens'
+    rows reach their sorted places by the combine's transpose over the
+    same runs (``ops/pallas/routed_spread.py``; XLA's gather wherever the
+    combine keeps its scatter-add, counted). What the absent experts would
     have added is left out; nothing stands in for their chips or the exchange.
 
     ``poly`` left out means SwiGLU experts, as above; ``poly`` = (pn
@@ -423,9 +432,9 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
     a hand-written backward over the same sorted rows
     (``_held_experts_bwd``), dropless too: two grouped kernels
     (``ops/pallas/grouped_swiglu_bwd.py``: the rows' gradients with gate
-    and up made again, then the three matrices' gradients) between XLA's
-    gathers and the ``routed_combine`` kernel; where ``kernel_mode()`` is
-    off, the dtypes
+    and up made again, then the three matrices' gradients) between the
+    ``routed_spread`` and the ``routed_combine`` kernels; where
+    ``kernel_mode()`` is off, the dtypes
     are mixed or the kernels cannot tile the shape (rows not a multiple
     of the sublane tile, H or F not of 128, an expert's three matrices
     and a row tile over the kernel's VMEM), eight ragged products,

@@ -417,7 +417,9 @@ def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
     kernels a branch (40,960 rows in tiles of 512 with an expert's
     three matrices whole in VMEM) and no ragged product, the combine the
     `routed_combine` kernel forward and backward a branch and no scatter
-    of `[16384, 2304]`. The instruction
+    of `[16384, 2304]`, the sorted rows the `routed_spread` kernel (the
+    forward's `x`, the backward's `x` again and `dout` with its weighed
+    copy, a branch) and no gather of `[40960, 2304]`. The instruction
     names are the kernels' names, which `trace_reduce.op_family` prints
     and `routed_experts_train_roofline` sums by their first letters."""
     from benchmark.trace_reduce import op_family
@@ -439,8 +441,10 @@ def test_trained_routed_experts_at_the_mellum_share(one_chip, tpu_mode):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(calls) == ["grouped_swiglu"] * 2 \
         + ["grouped_swiglu_bwd_rows"] * 2 \
-        + ["grouped_swiglu_bwd_weights"] * 2 + ["routed_combine"] * 4
+        + ["grouped_swiglu_bwd_weights"] * 2 + ["routed_combine"] * 4 \
+        + ["routed_spread"] * 6
     assert not re.search(r"= f32\[16384,2304\]\S* scatter\(", text)
+    assert not re.search(r"= \w+\[40960,2304\]\S* gather\(", text)
 
 
 @pytest.mark.parametrize("t,n,h,e", [(16384, 40960, 2304, 16),
@@ -461,6 +465,41 @@ def test_routed_combine_at_the_four_cells_widths(one_chip, tpu_mode, t, n,
                     one_chip, ((n, h), F32), ((n,), I32), ((n,), F32),
                     ((e,), I32))
     assert "routed_combine" in text and "scatter(" not in text
+
+
+@pytest.mark.parametrize("t,n,h,e", [(16384, 40960, 2304, 16),
+                                     (16384, 81984, 2048, 128),
+                                     (512, 2624, 2048, 128),
+                                     (4096, 2112, 7168, 12),
+                                     (4096, 8256, 3072, 32)])
+def test_routed_spread_at_the_four_cells_widths(one_chip, tpu_mode, t, n, h,
+                                                e):
+    """The combine's five shapes turned round, from the float32 tokens
+    every routed layer holds: the source tile's two buffers, its
+    bfloat16 parts, the staged rows' two halves and 2 E carried pieces fit
+    the kernel's VMEM limit, the per-piece tables fit the scalar memory
+    beside the steps' block numbers, and an 8-row piece of bfloat16 is a
+    DMA and a dynamic slice. The kernel itself: the dispatcher hands it
+    Mellum's runs alone (`RUN_PIECES`), plain and weighted (float32
+    cotangent, two outputs), and no gather of `[40960, 2304]` is left."""
+    from paddle_tpu.ops.pallas import routed_combine as rc
+    from paddle_tpu.ops.pallas import routed_spread as rs
+
+    shapes = (((t, h), F32), ((n,), I32), ((n,), F32), ((e,), I32))
+    tile, piece, stage, lanes = rc._tiles(t, n, h)
+    text = _compile(lambda x, r, w, s: rs._pallas_routed_spread(
+        x, r, w, s, dtype=jnp.dtype(BF16), weighted=False, tile=tile,
+        piece=piece, stage=stage, lanes=lanes, interpret=False),
+        one_chip, *shapes)
+    assert "routed_spread" in text
+    if e == 16:
+        for weighted in (False, True):
+            text = _compile(
+                lambda x, r, w, s: rs.routed_spread(x, r, w, s, BF16,
+                                                    weighted),
+                one_chip, *shapes)
+            assert "routed_spread" in text
+            assert not re.search(rf"= \w+\[{n},{h}\]\S* gather\(", text)
 
 
 def test_step_sampler_at_the_xglm_vocabulary(one_chip):

@@ -11,7 +11,9 @@ of one layer (`gqa_prefill_attention`, 4 query heads on one K/V head of
 256, bfloat16 products), one routed layer (`routed_experts_share`, 128 of
 512 experts held at 2048 x 512, top-10 by softmax) and its combine alone
 (`routed_combine`, the kernel, beside the scatter-add it replaces, over the
-layer's leading sorted rows) and its plan over the pairs alone
+layer's leading sorted rows), its spread alone (`routed_spread` beside the
+gather it replaces: `spread_ms` against `gather_ms`, `rows_equal`) and its
+plan over the pairs alone
 (`moe._pair_plan` beside the gathers it replaces), and one decode step's
 state kernel over 64 rows beside its stock form. One JSON line a bucket on
 stdout and in
@@ -33,7 +35,7 @@ from paddle_tpu.ops import linear_attention_ops as la
 from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
 from paddle_tpu.parallel.moe import routed_experts_share
 
-from bench_routed_train import combine_alone, plan_alone
+from bench_routed_train import combine_alone, plan_alone, spread_alone
 
 H, DK, DV, CHUNK = 8, 128, 128, 64
 HIDDEN, EXPERTS, HELD, WIDTH, TOP_K = 2048, 512, 128, 512, 10
@@ -131,6 +133,8 @@ def main():
         few = -(-(2 * pairs * HELD // EXPERTS + 32) // 64) * 64
         line["combine_alone"] = combine_alone(
             s, TOP_K, EXPERTS, HELD, HIDDEN, few, 5)
+        line["spread_alone"] = spread_alone(
+            s, TOP_K, EXPERTS, HELD, HIDDEN, few, False, 5)
         line["plan_alone"] = plan_alone(s, TOP_K, EXPERTS, HELD, 20)
         print(json.dumps(line), flush=True)
         out.write(json.dumps(line) + "\n")

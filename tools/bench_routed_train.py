@@ -9,7 +9,10 @@ of the bf16 peak for the nine products of the held pairs. Then the two
 backward kernels alone over the layer's 40,960 leading rows beside the
 eight ragged products, and the combine alone (`routed_combine`, the
 kernel) beside the scatter-add it replaces (`stock_routed_combine`)
-over the same leading rows of a real routing, and the plan over the
+over the same leading rows of a real routing, the spread alone
+(`routed_spread`, PR 50) beside the gather and the rounding passes it
+replaces (`spread_ms` against `gather_ms`, `rows_equal`), plain and
+weighted, and the plan over the
 pairs alone (`moe._pair_plan`: scores -> rows, sizes, sorted weights;
 PR 47) beside the gathers it replaces, whole and as its two pieces.
 Lines in chiprun_out/routed_train_bench.jsonl (a call's file replaces
@@ -42,6 +45,26 @@ def _timed(fn, iters, *a):
     return (time.perf_counter() - t0) / iters * 1e3, out
 
 
+def _leading_rows(t, k, e, eh, n):
+    """(rows, w, sizes, pairs in the groups) of the n leading sorted rows
+    of t tokens' top-k of e experts (softmax of a random router), eh of
+    them held."""
+    import jax
+    import jax.numpy as jnp
+
+    top, idx = jax.lax.top_k(jax.nn.softmax(
+        jax.random.normal(jax.random.PRNGKey(1), (t, e)), -1), k)
+    held = idx < eh
+    key = jnp.where(held, idx, eh).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, eh + 1, dtype=jnp.int32), 0)[:eh]
+    rows = (order // k).astype(jnp.int32)[:n]
+    w = jnp.where(held, top, 0.0).reshape(-1)[order][:n]
+    in_groups = int(jnp.sum(sizes))
+    assert in_groups <= n, "the leading rows do not hold the pairs"
+    return rows, w, sizes, in_groups
+
+
 def combine_alone(t, k, e, eh, h, n, iters=10):
     """The combine over the n leading sorted rows of t tokens' top-k of e
     experts (softmax of a random router), eh of them held, at width h:
@@ -54,20 +77,11 @@ def combine_alone(t, k, e, eh, h, n, iters=10):
     from paddle_tpu.ops.pallas import kernel_mode
     from paddle_tpu.ops.pallas import routed_combine as rc
 
-    keys = jax.random.split(jax.random.PRNGKey(1), 2)
-    top, idx = jax.lax.top_k(
-        jax.nn.softmax(jax.random.normal(keys[0], (t, e)), -1), k)
-    held = idx < eh
-    key = jnp.where(held, idx, eh).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(jax.nn.one_hot(key, eh + 1, dtype=jnp.int32), 0)[:eh]
-    rows = (order // k).astype(jnp.int32)[:n]
-    w = jnp.where(held, top, 0.0).reshape(-1)[order][:n]
-    in_groups = int(jnp.sum(sizes))
-    assert in_groups <= n, "the leading rows do not hold the pairs"
+    rows, w, sizes, in_groups = _leading_rows(t, k, e, eh, n)
     # what lies past the groups may be anything
     ys = jnp.where((jnp.arange(n) < in_groups)[:, None],
-                   jax.random.normal(keys[1], (n, h), jnp.float32), jnp.nan)
+                   jax.random.normal(jax.random.PRNGKey(2), (n, h),
+                                     jnp.float32), jnp.nan)
 
     def timed(fn):
         return _timed(fn, iters, ys, rows, w, sizes)
@@ -91,6 +105,59 @@ def combine_alone(t, k, e, eh, h, n, iters=10):
             steps=int(total[0]), pieces=int(jnp.sum(count[:int(total[0])])),
             max_diff_of_scale=float(jnp.max(jnp.abs(got - want))
                                     / jnp.max(jnp.abs(want))))
+    return line
+
+
+def spread_alone(t, k, e, eh, h, n, weighted, iters=10):
+    """The spread over the same leading rows as `combine_alone`, from
+    float32 tokens as every routed layer holds them: ms a call
+    of the reference gather (`stock_routed_spread`: `src[rows]` rounded to
+    bfloat16, or with `weighted` the float32 cotangent's rows selected,
+    rounded, and weighed and rounded again) and of the `routed_spread`
+    kernel with its plan (the kernel itself; `dispatched` says whether
+    `routed_spread` hands it this shape or keeps the gather); whether the
+    rows inside the groups are equal bit for bit and the kernel's rows
+    past them zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import kernel_mode
+    from paddle_tpu.ops.pallas import routed_combine as rc
+    from paddle_tpu.ops.pallas import routed_spread as rs
+
+    rows, w, sizes, in_groups = _leading_rows(t, k, e, eh, n)
+    src = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.float32)
+    bf = jnp.bfloat16
+
+    def timed(fn):
+        return _timed(fn, iters, src, rows, w, sizes)
+
+    gather_ms, want = timed(lambda x, r, w, s: rs.stock_routed_spread(
+        x, r, w, bf, weighted))
+    outs = 1 + weighted
+    line = dict(piece="the spread alone", tokens=t, rows=n, width=h,
+                held_experts=eh, in_groups=in_groups, weighted=weighted,
+                gather_ms=gather_ms,
+                bytes_ms_at_peak=(t * h * 4 + outs * n * h * 2) / 819e9
+                * 1e3)
+    tiling = rc._tiles(t, n, h)
+    if tiling is not None and kernel_mode() != "off":
+        tile, piece, stage, lanes = tiling
+        # the kernel itself, also where the dispatcher keeps the gather
+        ms, got = timed(lambda x, r, w, s: rs._pallas_routed_spread(
+            x, r, w, s, dtype=jnp.dtype(bf), weighted=weighted, tile=tile,
+            piece=piece, stage=stage, lanes=lanes,
+            interpret=kernel_mode() == "interpret"))
+        line["dispatched"] = rs._tiling(src.dtype, bf, weighted, t, n, eh,
+                                        h) is not None
+        inside = (jnp.arange(n) < in_groups)[:, None]
+        pairs = list(zip(got, want)) if weighted else [(got, want)]
+        line.update(
+            spread_ms=ms,
+            rows_equal=all(bool(jnp.all(jnp.where(inside, g == v, True)))
+                           for g, v in pairs),
+            zero_past_the_groups=all(bool(jnp.all(jnp.where(
+                inside, True, g == 0))) for g, _ in pairs))
     return line
 
 
@@ -298,6 +365,9 @@ def main():
                           / ms_w * 1e3),
         compile_s=[c_r, c_w]))
     rows.append(combine_alone(t, k, e, eh, h, n, args.iters))
+    # the forward's and the backward's `x`, then `dout` with its weighed copy
+    rows.append(spread_alone(t, k, e, eh, h, n, False, args.iters))
+    rows.append(spread_alone(t, k, e, eh, h, n, True, args.iters))
     rows.append(plan_alone(t, k, e, eh))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/routed_train_bench.jsonl", "w") as fh:

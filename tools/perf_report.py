@@ -462,6 +462,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.grouped_swiglu_bwd_fallbacks",
                "pallas.routed_combine_dispatches",
                "pallas.routed_combine_fallbacks",
+               "pallas.routed_spread_dispatches",
+               "pallas.routed_spread_fallbacks",
                "pallas.flash_window_dispatches",
                "pallas.flash_window_fallbacks") if cval(key)}
     if pallas:
