@@ -174,6 +174,28 @@ def qk_norm_rope_op(ins, attrs):
             "KOut": one(ins["K"][0], ins["KScale"][0])}
 
 
+# `gqa_prefill_attention`'s shape rule: the float32 scores ONE query block
+# holds in the XLA form (B x n x block_q x the keys it reads) from which the
+# prompt goes to the flash forward kernel instead: 64 MiB of them. Both
+# forms on the chip, ms a layer in bfloat16 products (v5e, my chip run, PR
+# 51: `tools/bench_qwen3_next_prefill.py --attention`), kernel / XLA form,
+# with the scores a block holds in Mi:
+#   4 + 1 heads of 256, no window (Qwen3-Next's share):
+#     2,048: 0.229 / 0.225 (4)    4,096: 0.428 / 0.557 (8)
+#     8,192: 1.290 / 2.174 (16)  16,384: 4.595 / 20.205 (32)
+#   6 + 1 heads of 128 (Trinity's share), window 4,096 | no window:
+#     2,048: 0.255 / 0.239 (6)   | 0.247 / 0.227 (6)
+#     4,096: 0.531 / 0.486 (12)  | 0.536 / 0.491 (12)
+#     8,192: 1.434 / 1.009 (13.5) | 1.779 / 2.030 (24)
+# The kernel takes ~2.2 us a (head, 512 x 512 block) it visits at either
+# head size, 8.4 ps a score; the XLA form 5 ps a score at head 128 and 8 at
+# 256, over every key a block can reach, and 19 once a block's scores pass
+# 128 MiB. So the kernel wins where it skips half the keys AND the scores
+# are large: at 16 Mi it is ahead at both head sizes, under 14 Mi it loses
+# at head 128, and what it wins under 16 Mi at head 256 is 0.13 ms a layer.
+GQA_PREFILL_KERNEL_FROM = 1 << 24
+
+
 @register_op("gqa_prefill_attention",
              required_attrs=("num_heads", "num_kv_heads", "head_dim"))
 def gqa_prefill_attention_op(ins, attrs):
@@ -183,14 +205,28 @@ def gqa_prefill_attention_op(ins, attrs):
     keys at t - window < s <= t only. No pool is read: the prefill writes
     its K/V with `kv_cache_write` beside this op.
 
-    Q [B, S, n*hd], K, V [B, S, nkv*hd]. Queries go in blocks of
-    `block_q`; a block reads the keys its window can reach (all of them
-    without a window), so the scores never hold more than
-    block_q x (block_q + window) entries a head. Inputs are rounded to
+    Q [B, S, n*hd], K, V [B, S, nkv*hd]. Inputs are rounded to
     `compute_dtype` for the two products, which accumulate in float32;
-    the softmax is float32. Out float32 [B, S, n*hd]."""
+    the softmax is float32. Out float32 [B, S, n*hd].
+
+    Two forms of the one statement, chosen by the scores a query block
+    of the first would hold (GQA_PREFILL_KERNEL_FROM, with the chip's
+    readings that set it). Under it, XLA products: queries go in blocks
+    of `block_q`, a block reads the keys its window can reach (all of
+    them without a window) and keeps its float32 scores, block_q x
+    (block_q + window) a head, in HBM. From it on, where the kernel can
+    tile the shape (`flash_window.window_route`), the trainer's forward
+    kernel (`flash_fwd_window`), which visits only the blocks under the
+    diagonal and inside the window and keeps every score in VMEM; it
+    has no backward here (a trainer calls `flash_attention`). Counted at
+    trace time: `pallas.gqa_prefill_dispatches`, and
+    `pallas.gqa_prefill_fallbacks` with `reason=` `mode` (kernels off),
+    `short` (under the rule), `shape` (nothing the kernel can tile)."""
     import jax
     import jax.numpy as jnp
+
+    from ..core import telemetry
+    from .pallas import flash_window, kernel_mode
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     n, nkv = int(attrs["num_heads"]), int(attrs["num_kv_heads"])
@@ -205,6 +241,19 @@ def gqa_prefill_attention_op(ins, attrs):
         raise ValueError(f"prompt length {s} is no multiple of block_q {bq}")
     # keys a block of queries can reach
     kw = s if not window else min(s, bq + -(-window // bq) * bq)
+    if kernel_mode() == "off":
+        reason = "mode"
+    elif b * n * bq * kw < GQA_PREFILL_KERNEL_FROM:
+        reason = "short"
+    else:
+        qc, kc, vc = q.astype(dt), k.astype(dt), v.astype(dt)
+        if flash_window.window_route(qc, kc, n, nkv)[0] != "reference":
+            telemetry.counter_add("pallas.gqa_prefill_dispatches", 1)
+            return {"Out": flash_window.flash_window_fwd_lse(
+                qc, kc, vc, num_heads=n, num_kv_heads=nkv, window=window,
+                scale=scale, out_dtype=jnp.float32)[0]}
+        reason = "shape"
+    telemetry.counter_add("pallas.gqa_prefill_fallbacks", 1, reason=reason)
     qh = q.reshape(b, s, nkv, g, hd).astype(dt)
     kh = k.reshape(b, s, nkv, hd).astype(dt)
     vh = v.reshape(b, s, nkv, hd).astype(dt)

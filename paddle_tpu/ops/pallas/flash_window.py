@@ -43,9 +43,9 @@ DQ_NAME = "flash_bwd_window_dq"
 
 
 def masked_attention(q, k, v, *, num_heads, num_kv_heads, window=0,
-                     scale=None):
+                     scale=None, out_dtype=None):
     """The plain form: q [B, S, H*hd], k, v [B, S, Hkv*hd] -> [B, S, H*hd]
-    in q's dtype; scores and softmax in float32."""
+    in `out_dtype` (q's by default); scores and softmax in float32."""
     b, s, _ = q.shape
     hd = q.shape[-1] // num_heads
     g = num_heads // num_kv_heads
@@ -62,7 +62,7 @@ def masked_attention(q, k, v, *, num_heads, num_kv_heads, window=0,
     p = jax.nn.softmax(jnp.where(ok, sc, NEG_INF), axis=-1)
     out = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), vh,
                      preferred_element_type=jnp.float32)
-    return out.reshape(q.shape).astype(q.dtype)
+    return out.reshape(q.shape).astype(out_dtype or q.dtype)
 
 
 def reach_of(s, block, window):
@@ -229,9 +229,10 @@ def _specs(block, g, hd, reach):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_heads", "num_kv_heads", "window", "scale", "block", "interpret"))
+    "num_heads", "num_kv_heads", "window", "scale", "block", "interpret",
+    "out_dtype"))
 def _fwd_pallas(q, k, v, *, num_heads, num_kv_heads, window, scale, block,
-                interpret):
+                interpret, out_dtype=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -244,7 +245,7 @@ def _fwd_pallas(q, k, v, *, num_heads, num_kv_heads, window, scale, block,
         grid=(b, num_kv_heads, nq, reach),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, stat_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((b, num_kv_heads, g, s),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((g, block, 128), jnp.float32),
@@ -341,22 +342,26 @@ def _scale(q, num_heads, scale):
 
 
 def flash_window_fwd_lse(q, k, v, *, num_heads, num_kv_heads, window=0,
-                         scale=None, block=None):
+                         scale=None, block=None, out_dtype=None):
     """(out [B, S, H*hd], lse [B, H, S] float32). On the reference route
-    lse is zeros: its backward differentiates the masked form."""
+    lse is zeros: its backward differentiates the masked form. `out_dtype`
+    (q's by default) is what the float32 accumulator is written as: a
+    caller that wants it unrounded asks for float32; the saved forward of
+    the backward kernels is q's."""
     route, blk = window_route(q, k, num_heads, num_kv_heads, block)
     if route == "reference":
         telemetry.counter_add("pallas.flash_window_fallbacks", 1)
         out = masked_attention(q, k, v, num_heads=num_heads,
                                num_kv_heads=num_kv_heads, window=window,
-                               scale=scale)
+                               scale=scale, out_dtype=out_dtype)
         return out, jnp.zeros((q.shape[0], num_heads, q.shape[1]),
                               jnp.float32)
     telemetry.counter_add("pallas.flash_window_dispatches", 1)
     return _fwd_pallas(q, k, v, num_heads=num_heads,
                        num_kv_heads=num_kv_heads, window=int(window),
                        scale=_scale(q, num_heads, scale), block=blk,
-                       interpret=route == "pallas_interpret")
+                       interpret=route == "pallas_interpret",
+                       out_dtype=out_dtype)
 
 
 def flash_window_bwd(q, k, v, out, lse, dout, *, num_heads, num_kv_heads,

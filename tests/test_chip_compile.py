@@ -386,6 +386,37 @@ def test_flash_attention_fwd_bwd(one_chip, tpu_mode, layout):
     assert text.count("tpu_custom_call") >= 2          # fwd and bwd
 
 
+@pytest.mark.parametrize("n,hd,window,s", [(4, 256, 0, 16384),
+                                           (4, 256, 0, 8192),
+                                           (6, 128, 0, 8192)])
+def test_gqa_prefill_attention_at_the_long_buckets(one_chip, tpu_mode, n, hd,
+                                                   window, s):
+    """The prompt buckets the op's shape rule hands the kernel: the two
+    longest of the Qwen3-Next cell (4 + 1 heads of 256) and the full
+    layers' longest of the Trinity cell (6 + 1 heads of 128), bfloat16
+    products, through the op on the layer's own float32 [1, S, n x hd]
+    arrays: the trainer's forward kernel with a float32 output, no loop
+    of XLA products and no [.., 512, S] float32 scores."""
+    import math
+
+    from paddle_tpu.ops import llm_ops
+
+    def attend(q, k, v):
+        return llm_ops.gqa_prefill_attention_op(
+            {"Q": [q], "K": [k], "V": [v]},
+            {"num_heads": n, "num_kv_heads": 1, "head_dim": hd,
+             "window": window, "compute_dtype": "bfloat16",
+             "block_q": 512})["Out"]
+
+    text = _compile(attend, one_chip, ((1, s, n * hd), F32),
+                    ((1, s, hd), F32), ((1, s, hd), F32))
+    assert "flash_fwd_window" in text and " while(" not in text
+    assert re.search(r"ROOT \S+ = \(?f32\[1,%d,%d\]" % (s, n * hd), text)
+    held = re.findall(r"= f32\[([\d,]+)\]", text)
+    assert all(math.prod(map(int, dims.split(","))) <= s * n * hd
+               for dims in held), held
+
+
 @pytest.mark.parametrize("window", [1024, 0], ids=["sliding", "full"])
 def test_flash_window_fwd_bwd_at_the_mellum_share(one_chip, tpu_mode,
                                                   window):

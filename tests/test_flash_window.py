@@ -4,6 +4,7 @@ gradients, over windows under, equal to and over a block and over the
 sequence, for groups of 1 and 8; the routes; the `flash_attention` op and
 its grad op with `window` / `num_kv_heads` through the Executor."""
 
+import hashlib
 import importlib
 
 import jax
@@ -57,6 +58,47 @@ def test_the_kernels_match_the_masked_form(monkeypatch, window, group,
     for got, ref in zip(vjp(do), want_vjp(do)):
         assert float(jnp.max(jnp.abs(got - ref))) \
             <= 3e-6 * float(jnp.max(jnp.abs(ref)))
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("window", [0, 100])
+def test_the_forward_writes_its_accumulator_in_the_dtype_asked_for(
+        monkeypatch, mode, window):
+    """bfloat16 operands, `out_dtype` float32: the float32 accumulator
+    unrounded (the whole-prompt attention op's contract), whose rounding
+    to bfloat16 is, bit for bit, what the forward gives without the
+    argument; lse is the same array either way."""
+    monkeypatch.setenv("PT_PALLAS", mode)
+    q, k, v, _do, n = _inputs(2, 1, dtype=jnp.bfloat16)
+    kw = dict(num_heads=n, num_kv_heads=1, window=window, block=BLOCK)
+    out, lse = fw.flash_window_fwd_lse(q, k, v, **kw)
+    wide, lse32 = fw.flash_window_fwd_lse(q, k, v, out_dtype=jnp.float32,
+                                          **kw)
+    assert out.dtype == jnp.bfloat16 and wide.dtype == jnp.float32
+    assert bool(jnp.any(wide != out.astype(jnp.float32)))
+    np.testing.assert_array_equal(wide.astype(jnp.bfloat16), out)
+    np.testing.assert_array_equal(lse32, lse)
+
+
+# sha256 of the jaxpr `flash_window_fwd_lse` traced to at the parent of the
+# PR that gave it `out_dtype` (474886e, interpret mode, the shapes below)
+_FWD_JAXPR_BEFORE_OUT_DTYPE = {0: "76683cc4524958f4", 100: "b204f25f90762206"}
+
+
+@pytest.mark.parametrize("window", sorted(_FWD_JAXPR_BEFORE_OUT_DTYPE))
+def test_the_forward_without_out_dtype_traces_to_what_it_did(monkeypatch,
+                                                             window):
+    """The trainer calls the forward without the argument: its program
+    does not move (the trainers' fingerprints are traced with kernels
+    off and cannot say so)."""
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    q = jax.ShapeDtypeStruct((1, 256, 2 * HD), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, HD), jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda q, k, v: fw.flash_window_fwd_lse(
+        q, k, v, num_heads=2, num_kv_heads=1, window=window, block=BLOCK))(
+            q, k, k))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _FWD_JAXPR_BEFORE_OUT_DTYPE[window]
 
 
 def test_a_block_is_visited_only_if_the_window_reaches_it():

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401  (registers the ops)
-from paddle_tpu.core import registry
+from paddle_tpu.core import registry, telemetry
+from paddle_tpu.ops import llm_ops
 
 
 def fwd(op, ins, attrs=None):
@@ -135,6 +136,124 @@ def test_gqa_prefill_attention_is_masked_softmax_over_grouped_heads(
         want[:, j] = p / p.sum(-1, keepdims=True) @ vh[:, j // 2]
     np.testing.assert_allclose(out[0], want.reshape(s, n * hd), rtol=2e-4,
                                atol=2e-5)
+
+
+def _whole_prompt(rng, n, nkv, hd, s, live):
+    """Q, K, V of a padded prompt whose tail past `live` holds numbers far
+    larger than any real row's."""
+    q, k, v = (rng.randn(1, s, heads * hd).astype(np.float32)
+               for heads in (n, nkv, nkv))
+    for a in (q, k, v):
+        a[:, live:] *= 40.0
+    return {"Q": q, "K": k, "V": v}
+
+
+def _pallas_counters():
+    return {k: v for k, v in telemetry.snapshot()["counters"].items()
+            if k.startswith(("pallas.gqa_prefill", "pallas.flash_window"))}
+
+
+# query heads, K/V heads, head, window, padded length (three blocks of 128
+# in interpret mode), real tokens
+_KERNEL_ROUTE = {
+    "qwen3_next_4+1_heads_of_256": (4, 1, 256, 0, 384, 384),
+    "trinity_6+1_heads_of_128_window": (6, 1, 128, 100, 384, 384),
+    "trinity_6+1_heads_of_128_full": (6, 1, 128, 0, 384, 384),
+    "padded_tail": (4, 1, 256, 0, 384, 200),
+}
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-6),
+                                        ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("case", sorted(_KERNEL_ROUTE))
+def test_gqa_prefill_attention_through_the_kernel_is_the_xla_form(
+        rng, monkeypatch, case, dtype, tol):
+    """Over its shape rule the op hands the prompt to the flash forward
+    kernel: the XLA form's rows within the kernel's own tolerance
+    (float32 products to rounding; bfloat16 ones to the rounding of a
+    block's weights before or after they are normalised), `Out` float32
+    on both routes, and a real row untouched by what the padded tail
+    holds."""
+    n, nkv, hd, window, s, live = _KERNEL_ROUTE[case]
+    ins = _whole_prompt(rng, n, nkv, hd, s, live)
+    attrs = {"num_heads": n, "num_kv_heads": nkv, "head_dim": hd,
+             "window": window, "compute_dtype": dtype, "block_q": s}
+    monkeypatch.setattr(llm_ops, "GQA_PREFILL_KERNEL_FROM", 1)
+    monkeypatch.setenv("PT_PALLAS", "off")
+    want = fwd("gqa_prefill_attention", ins, attrs)["Out"]
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    telemetry.reset()
+    got = fwd("gqa_prefill_attention", ins, attrs)["Out"]
+    assert _pallas_counters() == {"pallas.gqa_prefill_dispatches": 1,
+                                  "pallas.flash_window_dispatches": 1}
+    assert got.dtype == want.dtype == np.float32
+    assert got.shape == want.shape == (1, s, n * hd)
+    scale = np.abs(want[:, :live]).max()
+    assert np.abs(got - want)[:, :live].max() <= tol * scale
+    if live < s:
+        other = {name: np.where(np.arange(s)[None, :, None] < live, a, 0.0)
+                 .astype(np.float32) for name, a in ins.items()}
+        np.testing.assert_array_equal(
+            fwd("gqa_prefill_attention", other, attrs)["Out"][:, :live],
+            got[:, :live])
+
+
+@pytest.mark.parametrize("reason, mode, kernel_from, s", [
+    ("mode", "off", 1, 384),
+    ("short", "interpret", None, 384),      # the constant as it stands
+    ("shape", "interpret", 1, 200),         # no block divides 200
+])
+def test_gqa_prefill_attention_says_why_it_kept_the_xla_form(
+        rng, monkeypatch, reason, mode, kernel_from, s):
+    """The shape rule is the op's own: a prompt whose query block would
+    hold fewer scores than the rule asks is a
+    `pallas.gqa_prefill_fallbacks` with `reason=short`, and neither it
+    nor a shape the kernel cannot tile reaches the kernel's dispatcher
+    (no `pallas.flash_window_fallbacks`)."""
+    monkeypatch.setenv("PT_PALLAS", mode)
+    if kernel_from is not None:
+        monkeypatch.setattr(llm_ops, "GQA_PREFILL_KERNEL_FROM", kernel_from)
+    seen = []
+    add = telemetry.counter_add
+    monkeypatch.setattr(
+        telemetry, "counter_add",
+        lambda name, delta=1, **attrs: (seen.append((name, attrs)),
+                                        add(name, delta, **attrs))[1])
+    telemetry.reset()
+    ins = _whole_prompt(rng, 2, 1, 128, s, s)
+    attrs = {"num_heads": 2, "num_kv_heads": 1, "head_dim": 128,
+             "block_q": s}
+    out = fwd("gqa_prefill_attention", ins, attrs)["Out"]
+    assert seen == [("pallas.gqa_prefill_fallbacks", {"reason": reason})]
+    assert _pallas_counters() == {"pallas.gqa_prefill_fallbacks": 1}
+    monkeypatch.setenv("PT_PALLAS", "off")
+    np.testing.assert_array_equal(
+        out, fwd("gqa_prefill_attention", ins, attrs)["Out"])
+
+
+@pytest.mark.parametrize("n, nkv, hd, window, s, kernel", [
+    (4, 1, 256, 0, 4096, False), (4, 1, 256, 0, 8192, True),
+    (4, 1, 256, 0, 16384, True),                # Qwen3-Next's share
+    (6, 1, 128, 4096, 4096, False), (6, 1, 128, 4096, 8192, False),
+    (6, 1, 128, 0, 4096, False), (6, 1, 128, 0, 8192, True),  # Trinity's
+    (20, 4, 128, 0, 1024, False),               # Falcon-H1's longest bucket
+])
+def test_the_shape_rule_at_the_served_families_buckets(monkeypatch, n, nkv,
+                                                       hd, window, s, kernel):
+    """Which form each served family's long buckets take as the constant
+    stands (its comment has the chip's readings): traced, nothing run."""
+    import jax
+
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    telemetry.reset()
+    q = jax.ShapeDtypeStruct((1, s, n * hd), np.float32)
+    k = jax.ShapeDtypeStruct((1, s, nkv * hd), np.float32)
+    jax.eval_shape(lambda q, k, v: llm_ops.gqa_prefill_attention_op(
+        {"Q": [q], "K": [k], "V": [v]},
+        {"num_heads": n, "num_kv_heads": nkv, "head_dim": hd,
+         "window": window, "compute_dtype": "bfloat16", "block_q": 512}),
+        q, k, k)
+    assert ("pallas.gqa_prefill_dispatches" in _pallas_counters()) == kernel
 
 
 def chunk_inputs(rng, n, nkv, hd, page, start, c):

@@ -19,6 +19,7 @@ from benchmark.families import qwen3_next as family
 from paddle_tpu.core import registry, telemetry
 from paddle_tpu.models import qwen3_next
 from paddle_tpu.ops import linear_attention_ops as la
+from paddle_tpu.ops import llm_ops
 from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
 from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
 from paddle_tpu.serving.kv_cache import LayerCache, PagedKVCache
@@ -560,6 +561,45 @@ def test_prefill_then_decode_through_pages_and_state_is_the_reference(
     assert rq.kv_error(held[:fed], pages[0][:fed]) < 2e-5
     np.testing.assert_allclose(held[:fed], pages[0][:fed], rtol=2e-4,
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype, near", [("float32", 1e-4),
+                                         ("bfloat16", rq.LOGIT_ERR / 6)])
+def test_a_prefill_through_the_attention_kernel_is_the_stock_routes(
+        monkeypatch, dtype, near):
+    """A 128-token bucket (one block of the kernel's smallest) with the
+    shape rule out of the way, the program built in interpret mode: its
+    attention layer goes through the flash forward kernel (counted) and
+    the prefill's logits are the stock route's, far inside the limit the
+    cell's check holds them to against the reference."""
+    monkeypatch.setattr(llm_ops, "GQA_PREFILL_KERNEL_FROM", 1)
+    cfg = small(dtype=dtype)
+    params = qwen3_next.qwen3_next_params(cfg, 3)
+    prompt = np.random.RandomState(50).randint(3, cfg.vocab_size, 100)
+    logits = {}
+    for mode in ("off", "interpret"):
+        monkeypatch.setenv("PT_PALLAS", mode)
+        telemetry.reset()
+        engine = engine_for(cfg, params, prefill_buckets=[128]).start(
+            warmup=False)
+        try:
+            req = engine.submit(prompt, max_new_tokens=1, stop_at_eos=False,
+                                keep_first_logits=True)
+            req.result(120)
+        finally:
+            engine.close(drain=False, timeout=30)
+        logits[mode] = np.asarray(req.first_logits)
+        c = telemetry.snapshot()["counters"]
+        # (an op is traced once when its program is built and once when
+        # it is lowered)
+        assert (c.get("pallas.gqa_prefill_dispatches"),
+                c.get("pallas.gqa_prefill_fallbacks")) \
+            == ((None, 2) if mode == "off" else (2, None))
+    assert rq.logit_error(logits["interpret"], logits["off"]) < near
+    rows, _, _ = reference_for(cfg, params).rows(
+        np.concatenate([prompt, [3]]), 128, 99, 1)
+    assert rq.logit_error(logits["interpret"], rows[0]) < (
+        2e-4 if dtype == "float32" else rq.LOGIT_ERR)
 
 
 def test_the_engine_counts_state_rows_keys_and_routed_pairs():

@@ -2,13 +2,17 @@
 share (PERF.md, PR 45): what a bucket's `while`s and `fusion`s hold.
 
     chiprun -- python tools/bench_qwen3_next_prefill.py [BUCKET ...]
+    chiprun -- python tools/bench_qwen3_next_prefill.py --attention
 
-For each padded bucket (4096 and 16384 by default), ms a call of: the
+For each padded bucket (4096, 8192 and 16384 by default), ms a call of: the
 chunked gated delta rule of one layer (`chunk_delta_rule`, 8 value heads x
 [128, 128], chunks of 64) and its two sequential parts alone (the
 row-by-row triangular solve; the scan over chunks), whole-prompt attention
 of one layer (`gqa_prefill_attention`, 4 query heads on one K/V head of
-256, bfloat16 products), one routed layer (`routed_experts_share`, 128 of
+256, bfloat16 products) in both of the op's forms (`attention_alone`:
+`attention_ms` the flash forward kernel, `attention_stock_ms` the XLA
+products, `rows_equal`, and `dispatched`: which of them the op's shape
+rule hands this length), one routed layer (`routed_experts_share`, 128 of
 512 experts held at 2048 x 512, top-10 by softmax) and its combine alone
 (`routed_combine`, the kernel, beside the scatter-add it replaces, over the
 layer's leading sorted rows), its spread alone (`routed_spread` beside the
@@ -18,6 +22,13 @@ plan over the pairs alone
 state kernel over 64 rows beside its stock form. One JSON line a bucket on
 stdout and in
 ``chiprun_out/qwen3_next_prefill_bench.jsonl``.
+
+`--attention` times the attention line alone, at every shape a served
+family hands the op above 1,024 (`ATTENTION_SHAPES`: Qwen3-Next's 4 + 1
+heads of 256 and Trinity's 6 + 1 heads of 128 with its window of 4,096 and
+without): the table `llm_ops.GQA_PREFILL_KERNEL_FROM` was set from, a line
+a shape in ``chiprun_out/gqa_prefill_attention_bench.jsonl`` (~1.5 min of
+the chip).
 """
 
 import json
@@ -30,15 +41,21 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.core import registry
+from paddle_tpu.core import registry, telemetry
 from paddle_tpu.ops import linear_attention_ops as la
+from paddle_tpu.ops import llm_ops
 from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
+from paddle_tpu.ops.pallas import kernel_mode
 from paddle_tpu.parallel.moe import routed_experts_share
 
 from bench_routed_train import combine_alone, plan_alone, spread_alone
 
 H, DK, DV, CHUNK = 8, 128, 128, 64
 HIDDEN, EXPERTS, HELD, WIDTH, TOP_K = 2048, 512, 128, 512, 10
+# (query heads, K/V heads, head, window) -> the padded lengths timed
+ATTENTION_SHAPES = {(4, 1, 256, 0): (2048, 4096, 8192, 16384),
+                    (6, 1, 128, 4096): (2048, 4096, 8192),
+                    (6, 1, 128, 0): (2048, 4096, 8192)}
 
 
 def ms_a_call(fn, args, reps=5):
@@ -49,6 +66,58 @@ def ms_a_call(fn, args, reps=5):
         out = fn(*args)
     jax.block_until_ready(out)
     return round((time.perf_counter() - t) / reps * 1e3, 3)
+
+
+def attention_alone(s, n=4, nkv=1, hd=256, window=0, reps=20):
+    """One layer's `gqa_prefill_attention` over a padded prompt of s in
+    bfloat16 products, ms a call of each form (the shape rule's constant
+    moved out of the way), the largest difference between the two (the
+    kernel rounds a block's unnormalised weights where the XLA form rounds
+    the softmax: both are one bfloat16 rounding of numbers under 1) and
+    which form the op as it stands hands this length."""
+    key = jax.random.PRNGKey(s)
+    q = jax.random.normal(key, (1, s, n * hd))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, s, nkv * hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, s, nkv * hd))
+    attend = registry.lookup("gqa_prefill_attention").forward
+    attrs = {"num_heads": n, "num_kv_heads": nkv, "head_dim": hd,
+             "window": window, "compute_dtype": "bfloat16",
+             "block_q": min(512, s)}
+
+    def form(kernel_from):
+        rule = llm_ops.GQA_PREFILL_KERNEL_FROM
+        llm_ops.GQA_PREFILL_KERNEL_FROM = kernel_from
+        try:
+            fn = jax.jit(lambda q_, k_, v_: attend(
+                {"Q": [q_], "K": [k_], "V": [v_]}, attrs)["Out"])
+            return ms_a_call(fn, (q, k, v), reps), fn(q, k, v)
+        finally:
+            llm_ops.GQA_PREFILL_KERNEL_FROM = rule
+
+    stock_ms, want = form(1 << 62)
+    sent = telemetry.counter_get("pallas.gqa_prefill_dispatches")
+    jax.eval_shape(lambda: attend({"Q": [q], "K": [k], "V": [v]}, attrs))
+    line = dict(piece="attention alone", bucket=s, heads=n, kv_heads=nkv,
+                head_dim=hd, window=window, attention_stock_ms=stock_ms,
+                dispatched=telemetry.counter_get(
+                    "pallas.gqa_prefill_dispatches") > sent)
+    if kernel_mode() != "off":
+        ms, got = form(0)
+        diff = float(jnp.max(jnp.abs(got - want)))
+        line.update(attention_ms=ms, max_abs_diff=round(diff, 5),
+                    rows_equal=diff <= 2e-2 * float(jnp.max(jnp.abs(want))))
+    return line
+
+
+def attention_table():
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/gqa_prefill_attention_bench.jsonl", "w") as out:
+        for (n, nkv, hd, window), lengths in ATTENTION_SHAPES.items():
+            for s in lengths:
+                line = attention_alone(s, n, nkv, hd, window)
+                line["device"] = jax.devices()[0].device_kind
+                print(json.dumps(line), flush=True)
+                out.write(json.dumps(line) + "\n")
 
 
 def rule_inputs(key, s):
@@ -94,7 +163,9 @@ def scan_alone(u, w, q, k):
 
 
 def main():
-    buckets = [int(a) for a in sys.argv[1:]] or [4096, 16384]
+    if sys.argv[1:] == ["--attention"]:
+        return attention_table()
+    buckets = [int(a) for a in sys.argv[1:]] or [4096, 8192, 16384]
     key = jax.random.PRNGKey(0)
     os.makedirs("chiprun_out", exist_ok=True)
     out = open("chiprun_out/qwen3_next_prefill_bench.jsonl", "w")
@@ -103,7 +174,6 @@ def main():
     w2 = jax.random.normal(key, (HELD, WIDTH, HIDDEN), bf) * WIDTH ** -0.5
     router = (jax.random.normal(key, (HIDDEN, EXPERTS)) * 3
               * HIDDEN ** -0.5).astype(bf)
-    attend = registry.lookup("gqa_prefill_attention").forward
     for s in buckets:
         q, k, v, g, beta = rule_inputs(jax.random.fold_in(key, s), s)
         nc = s // CHUNK
@@ -116,14 +186,7 @@ def main():
         parts = [jax.random.normal(key, (nc, 1, H, CHUNK, d))
                  for d in (DV, DK, DK, DK)]
         line["scan_alone_ms"] = ms_a_call(jax.jit(scan_alone), tuple(parts))
-        qa = jax.random.normal(key, (1, s, 4 * 256))
-        ka = jax.random.normal(key, (1, s, 256))
-        line["gqa_prefill_attention_ms"] = ms_a_call(
-            jax.jit(lambda q_, k_, v_: attend(
-                {"Q": [q_], "K": [k_], "V": [v_]},
-                {"num_heads": 4, "num_kv_heads": 1, "head_dim": 256,
-                 "compute_dtype": "bfloat16", "block_q": 512})["Out"]),
-            (qa, ka, ka))
+        line["attention_alone"] = attention_alone(s)
         x = jax.random.normal(key, (s, HIDDEN))
         line["routed_experts_ms"] = ms_a_call(
             jax.jit(lambda x_: routed_experts_share(

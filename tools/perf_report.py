@@ -448,6 +448,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.paged_attn_fallbacks",
                "pallas.mla_prefill_dispatches",
                "pallas.mla_prefill_fallbacks",
+               "pallas.gqa_prefill_dispatches",
+               "pallas.gqa_prefill_fallbacks",
                "pallas.ssm_state_update_dispatches",
                "pallas.ssm_state_update_fallbacks",
                "pallas.gated_delta_state_update_dispatches",
