@@ -54,7 +54,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Tuple
 
-import numpy as np
 
 from .. import layers
 from ..core.ir import Program, program_guard
@@ -62,7 +61,8 @@ from ..ops.llm_ops import yarn_mscale
 from ..serving.kv_cache import (LayerCache, PagedKVCache,
                                 pool_array_names)
 from ..serving.served_model import ServedModel
-from .program_block import Block, named_out as _named_out, op as _op
+from .program_block import (Block, named_out as _named_out, op as _op,
+                            seeded_params)
 
 LANES = 128
 
@@ -133,67 +133,66 @@ class KimiK2Config:
         return KimiK2Served(self)
 
 
+def layer_specs(cfg, p: str, moe: bool) -> Dict[str, Tuple[tuple, str, str]]:
+    """One layer's parameters under the prefix `p` (``k2_l3_``): the latent
+    attention's norms and five matrices, then a dense SwiGLU or, `moe`, the
+    router, its selection bias, the shared expert and the held experts
+    (models/xing4.py takes its layers' and its draft module's from here)."""
+    d, n, dt = cfg.hidden_size, cfg.num_heads, cfg.dtype
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    specs = {}
+    for norm, width in (("norm_in", d), ("norm_mlp", d),
+                        ("q_a_norm", cfg.q_lora_rank),
+                        ("kv_a_norm", cfg.kv_lora_rank)):
+        specs[p + norm] = ((width,), 1.0, "float32")
+    for name, shape in (
+            ("q_a_w", (d, cfg.q_lora_rank)),
+            ("q_b_w", (cfg.q_lora_rank, n * qk)),
+            ("kv_a_w", (d, cfg.latent_dim)),
+            ("kv_b_w", (cfg.kv_lora_rank,
+                        n * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            ("o_w", (n * cfg.v_head_dim, d))):
+        specs[p + name] = (shape, "normal", dt)
+    if not moe:
+        f = cfg.intermediate_size
+        for name, shape in (("w1", (d, f)), ("w3", (d, f)),
+                            ("w2", (f, d))):
+            specs[p + name] = (shape, "normal", dt)
+        return specs
+    f, eh = cfg.moe_intermediate_size, cfg.experts_held[1]
+    fs = f * cfg.n_shared_experts
+    specs[p + "router_w"] = ((d, cfg.num_experts), "normal", dt)
+    specs[p + "select_bias"] = ((cfg.num_experts,), 0.0, "float32")
+    for name, shape in (("sh_w1", (d, fs)), ("sh_w3", (d, fs)),
+                        ("sh_w2", (fs, d)), ("ex_w1", (eh, d, f)),
+                        ("ex_w3", (eh, d, f)), ("ex_w2", (eh, f, d))):
+        specs[p + name] = (shape, "normal", dt)
+    return specs
+
+
 def param_specs(cfg: KimiK2Config) -> Dict[str, Tuple[tuple, str, str]]:
     """name -> (shape, kind, dtype). Kind: ``normal`` (`init_std`) or the
     constant that fills it. Matrices are in ``cfg.dtype``; norm gains and
     the selection bias are float32."""
-    d, n, dt = cfg.hidden_size, cfg.num_heads, cfg.dtype
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    d, dt = cfg.hidden_size, cfg.dtype
     specs = {"k2_tok_emb": ((cfg.vocab_size, d), "normal", dt),
              "k2_head_w": ((d, cfg.vocab_size), "normal", dt),
              "k2_norm_f": ((d,), 1.0, "float32")}
     for i in range(cfg.n_layers):
-        p = f"k2_l{i}_"
-        for norm, width in (("norm_in", d), ("norm_mlp", d),
-                            ("q_a_norm", cfg.q_lora_rank),
-                            ("kv_a_norm", cfg.kv_lora_rank)):
-            specs[p + norm] = ((width,), 1.0, "float32")
-        for name, shape in (
-                ("q_a_w", (d, cfg.q_lora_rank)),
-                ("q_b_w", (cfg.q_lora_rank, n * qk)),
-                ("kv_a_w", (d, cfg.latent_dim)),
-                ("kv_b_w", (cfg.kv_lora_rank,
-                            n * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
-                ("o_w", (n * cfg.v_head_dim, d))):
-            specs[p + name] = (shape, "normal", dt)
-        if not cfg.is_moe(i):
-            f = cfg.intermediate_size
-            for name, shape in (("w1", (d, f)), ("w3", (d, f)),
-                                ("w2", (f, d))):
-                specs[p + name] = (shape, "normal", dt)
-            continue
-        f, eh = cfg.moe_intermediate_size, cfg.experts_held[1]
-        fs = f * cfg.n_shared_experts
-        specs[p + "router_w"] = ((d, cfg.num_experts), "normal", dt)
-        specs[p + "select_bias"] = ((cfg.num_experts,), 0.0, "float32")
-        for name, shape in (("sh_w1", (d, fs)), ("sh_w3", (d, fs)),
-                            ("sh_w2", (fs, d)), ("ex_w1", (eh, d, f)),
-                            ("ex_w3", (eh, d, f)), ("ex_w2", (eh, f, d))):
-            specs[p + name] = (shape, "normal", dt)
+        specs.update(layer_specs(cfg, f"k2_l{i}_", cfg.is_moe(i)))
     return specs
 
 
 def init_std(name: str, shape: tuple) -> float:
     """Standard deviation of a seeded ``normal`` parameter: fan_in^-0.5
     (the fan-in is the second-to-last axis, or the last of the embedding)."""
-    return shape[-1 if name == "k2_tok_emb" else -2] ** -0.5
+    return shape[-1 if name.endswith("_tok_emb") else -2] ** -0.5
 
 
 def kimi_k2_params(cfg: KimiK2Config, seed: int = 0):
     """Deterministic parameters for tests and demos, as numpy arrays in
     the dtypes `param_specs` states."""
-    import ml_dtypes
-
-    rng = np.random.RandomState(seed)
-    out = {}
-    for name, (shape, kind, dtype) in sorted(param_specs(cfg).items()):
-        if kind == "normal":
-            v = rng.normal(0.0, init_std(name, shape), shape)
-        else:
-            v = np.full(shape, kind)
-        out[name] = v.astype(ml_dtypes.bfloat16 if dtype == "bfloat16"
-                             else dtype)
-    return out
+    return seeded_params(param_specs(cfg), init_std, seed)
 
 
 # ---------------------------------------------------------------------------
@@ -205,8 +204,8 @@ class _Block(Block):
     `swiglu`), the same in every phase; how a layer attends is the
     phase's own (`attend`)."""
 
-    def __init__(self, cfg: KimiK2Config, kv: PagedKVCache):
-        super().__init__(cfg, kv, param_specs(cfg))
+    def __init__(self, cfg: KimiK2Config, kv: PagedKVCache, specs=None):
+        super().__init__(cfg, kv, specs or param_specs(cfg))
 
     def pool(self, i):
         """(Pool, PoolOut) of layer i: its one latent array."""
@@ -225,9 +224,11 @@ class _Block(Block):
                 "nope_dim": cfg.qk_nope_head_dim,
                 "rope_dim": cfg.qk_rope_head_dim}
 
-    def layer(self, x, i, positions, attend, live=None):
-        cfg, p = self.cfg, f"k2_l{i}_"
-        a_in = self.norm(x, p + "norm_in")
+    def attention(self, a_in, p, i, positions, attend):
+        """The latent-attention sublayer of the layer whose parameters
+        start with `p` and whose pool is layer `i`'s, over its normed
+        input; how it attends is `attend`'s."""
+        cfg = self.cfg
         c_q = self.norm(self.linear(a_in, p + "q_a_w"), p + "q_a_norm")
         q_nope, q_rope, c, latent = _op(
             "mla_rope_split",
@@ -242,10 +243,15 @@ class _Block(Block):
                  yarn_beta_fast=cfg.rope_beta_fast,
                  yarn_beta_slow=cfg.rope_beta_slow))
         o = attend(i, q_nope, q_rope, c, latent, self.param(p + "kv_b_w"))
-        x = x + self.linear(o, p + "o_w")
-        m_in = self.norm(x, p + "norm_mlp")
-        if not cfg.is_moe(i):
-            return x + self.swiglu(m_in, p, "w1", "w3", "w2")
+        return self.linear(o, p + "o_w")
+
+    def mlp(self, m_in, p, moe, live=None):
+        """The MLP sublayer's terms over its normed input, in the order
+        they are added: a dense SwiGLU, or the shared expert and the routed
+        layer (whose counts join `counts`)."""
+        cfg = self.cfg
+        if not moe:
+            return [self.swiglu(m_in, p, "w1", "w3", "w2")]
         ins = {"X": m_in, "RouterW": self.param(p + "router_w"),
                "SelectBias": self.param(p + "select_bias"),
                "W1": self.param(p + "ex_w1"), "W3": self.param(p + "ex_w3"),
@@ -260,7 +266,16 @@ class _Block(Block):
              "route_norm": cfg.norm_topk_prob})
         self.counts = counts if self.counts is None \
             else self.counts + counts
-        return x + self.swiglu(m_in, p, "sh_w1", "sh_w3", "sh_w2") + routed
+        return [self.swiglu(m_in, p, "sh_w1", "sh_w3", "sh_w2"), routed]
+
+    def layer(self, x, i, positions, attend, live=None):
+        cfg, p = self.cfg, f"k2_l{i}_"
+        x = x + self.attention(self.norm(x, p + "norm_in"), p, i, positions,
+                               attend)
+        for term in self.mlp(self.norm(x, p + "norm_mlp"), p, cfg.is_moe(i),
+                             live):
+            x = x + term
+        return x
 
     def embed(self, tokens):
         return _op("embed_scaled",

@@ -61,14 +61,14 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Tuple
 
-import numpy as np
 
 from .. import layers
 from ..core.ir import Program, program_guard
 from ..serving.kv_cache import (LayerCache, PagedKVCache,
                                 pool_array_names)
 from ..serving.served_model import ServedModel
-from .program_block import Block, named_out as _named_out, op as _op
+from .program_block import (Block, named_out as _named_out, op as _op,
+                            seeded_params)
 
 LANES = 128
 
@@ -232,20 +232,7 @@ def init_std(name: str, shape: tuple) -> float:
 def motif3_params(cfg: Motif3Config, seed: int = 0):
     """Deterministic parameters for tests and demos, as numpy arrays in
     the dtypes `param_specs` states."""
-    import ml_dtypes
-
-    rng = np.random.RandomState(seed)
-    out = {}
-    for name, (shape, kind, dtype) in sorted(param_specs(cfg).items()):
-        if kind == "normal":
-            v = rng.normal(0.0, init_std(name, shape), shape)
-        elif isinstance(kind, tuple):
-            v = rng.normal(kind[0], kind[1], shape)
-        else:
-            v = np.full(shape, kind)
-        out[name] = v.astype(ml_dtypes.bfloat16 if dtype == "bfloat16"
-                             else dtype)
-    return out
+    return seeded_params(param_specs(cfg), init_std, seed)
 
 
 # ---------------------------------------------------------------------------
@@ -290,21 +277,12 @@ class _Block(Block):
                   self.poly_attrs())
         return self.linear(mid, p + w2)
 
-    def around(self, xs, p, norm, sublayer):
-        """One sublayer inside the streams' residual path."""
+    def mhc_attrs(self):
         cfg = self.cfg
-        u, maps = _op(
-            "mhc_pre",
-            {"X": xs, "Gamma": self.param(p + "norm"),
-             "Phi": self.param(p + "phi"), "Scale": self.param(p + "scale"),
-             "Bias": self.param(p + "bias")}, {"U": None, "Maps": None},
-            {"n_streams": cfg.n_streams,
-             "sinkhorn_iters": cfg.mhc_sinkhorn_iters,
-             "epsilon": cfg.rms_norm_eps})
-        y = sublayer(self.norm(u, norm))
-        return _op("mhc_post", {"X": xs, "Y": y, "Maps": maps},
-                   {"Out": None},
-                   {"n_streams": cfg.n_streams, "clamp": cfg.hidden_clamp})
+        return ({"n_streams": cfg.n_streams,
+                 "sinkhorn_iters": cfg.mhc_sinkhorn_iters,
+                 "epsilon": cfg.rms_norm_eps},
+                {"n_streams": cfg.n_streams, "clamp": cfg.hidden_clamp})
 
     def attention(self, x, i, positions, attend):
         cfg, p = self.cfg, f"m3_l{i}_"

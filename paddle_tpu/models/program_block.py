@@ -1,8 +1,10 @@
 """What the served decoders' program builders share, whatever the model:
 one op appended by slot names, a named output, and the block's parameters
 by name with the norm, the projection and the SwiGLU every one of them
-has. models/afmoe.py, kimi_k2.py and falcon_h1.py each subclass `Block`
-with their own layers, pools and heads."""
+has, and for those with several residual streams (models/motif3.py,
+models/xing4.py) the path around a sublayer. models/afmoe.py, kimi_k2.py
+and falcon_h1.py each subclass `Block` with their own layers, pools and
+heads."""
 
 from __future__ import annotations
 
@@ -29,6 +31,28 @@ def op(type_, ins, outs, attrs=None, dtype="float32"):
     helper.append_op(type_, {k: [v] for k, v in ins.items()},
                      {k: [v] for k, v in zip(outs, made)}, attrs or {})
     return made[0] if len(made) == 1 else made
+
+
+def seeded_params(specs, init_std, seed: int):
+    """Deterministic parameters for tests and demos, as numpy arrays in the
+    dtypes `specs` states (name -> (shape, kind, dtype)): a ``normal`` kind
+    at ``init_std(name, shape)``, a ``(mean, std)`` kind as that normal
+    draw, any other the constant that fills it; drawn in the names' order."""
+    import ml_dtypes
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name, (shape, kind, dtype) in sorted(specs.items()):
+        if kind == "normal":
+            v = rng.normal(0.0, init_std(name, shape), shape)
+        elif isinstance(kind, tuple):
+            v = rng.normal(kind[0], kind[1], shape)
+        else:
+            v = np.full(shape, kind)
+        out[name] = v.astype(ml_dtypes.bfloat16 if dtype == "bfloat16"
+                             else dtype)
+    return out
 
 
 class Block:
@@ -66,3 +90,25 @@ class Block:
         mid = op("swiglu", {"Gate": self.linear(x, p + w1),
                             "Up": self.linear(x, p + w3)}, {"Out": None})
         return self.linear(mid, p + w2)
+
+    def mhc_attrs(self):
+        """(attrs of `mhc_pre`, attrs of `mhc_post`) of a model whose
+        residual is several streams."""
+        raise NotImplementedError
+
+    def streams_in(self, xs, p):
+        """`mhc_pre` with the maps' parameters that start with `p`: (u,
+        the sublayer's input before its norm; the three maps)."""
+        return op(
+            "mhc_pre",
+            {"X": xs, "Gamma": self.param(p + "norm"),
+             "Phi": self.param(p + "phi"), "Scale": self.param(p + "scale"),
+             "Bias": self.param(p + "bias")}, {"U": None, "Maps": None},
+            self.mhc_attrs()[0])
+
+    def around(self, xs, p, norm, sublayer):
+        """One sublayer inside the streams' residual path."""
+        u, maps = self.streams_in(xs, p)
+        y = sublayer(self.norm(u, norm))
+        return op("mhc_post", {"X": xs, "Y": y, "Maps": maps},
+                  {"Out": None}, self.mhc_attrs()[1])

@@ -405,7 +405,14 @@ def cached_latent_attention_op(ins, attrs):
     ring of ``cap = MP x P`` rows, the row lands at index ``pos mod cap``,
     and every head attends the rows whose TRUE position lies in
     ``pos - window < s <= pos``: the ring alone is read, whatever the
-    context's length."""
+    context's length.
+
+    Attr ``queries`` (a step that verifies a draft, models/xing4.py): the B
+    rows are ``queries`` consecutive positions of B / queries slots (row
+    ``queries x s + j`` is slot s at position ``pos_s + j``, with slot s's
+    table). Every row's latent is written first; then a slot's latents are
+    read ONCE for all its positions, position j attending ``<= pos_s + j``
+    (so it sees the fresh rows before it)."""
     import jax.numpy as jnp
 
     from .pallas.paged_mla_attention import paged_mla_decode_attention
@@ -419,14 +426,22 @@ def cached_latent_attention_op(ins, attrs):
     width, page = int(pool.shape[2]), int(pool.shape[1])
     scale = float(attrs.get("scale") or w ** -0.5)
     window = int(attrs.get("window", 0))
+    queries = int(attrs.get("queries", 1))
     idx = pos % (int(table.shape[1]) * page) if window else pos
     phys = jnp.take_along_axis(table, (idx // page)[:, None], axis=1)[:, 0]
     pool = pool.at[phys, idx % page].set(
         _lane_padded(lat, width).astype(pool.dtype))
+    q = _lane_padded(q.reshape(b, n, w), width)
+    if queries > 1:
+        out = paged_mla_decode_attention(
+            q.reshape(b // queries, queries * n * width), pool,
+            table[::queries], pos[::queries], num_heads=n,
+            value_dim=int(attrs["value_dim"]), scale=scale,
+            queries=queries)
+        return {"Out": out.reshape(b, -1), "PoolOut": pool}
     out = paged_mla_decode_attention(
-        _lane_padded(q.reshape(b, n, w), width).reshape(b, n * width),
-        pool, table, pos, num_heads=n, value_dim=int(attrs["value_dim"]),
-        scale=scale, window=window)
+        q.reshape(b, n * width), pool, table, pos, num_heads=n,
+        value_dim=int(attrs["value_dim"]), scale=scale, window=window)
     return {"Out": out, "PoolOut": pool}
 
 

@@ -610,6 +610,18 @@ def embed_streams_op(ins, attrs):
                             + (int(attrs["n_streams"]),))}
 
 
+@register_op("tile_streams", required_attrs=("n_streams",))
+def tile_streams_op(ins, attrs):
+    """Out [..., n*C] float32: X [..., C] copied into each of `n_streams`
+    streams, as `embed_streams` copies an embedding row (the input of a
+    draft module, models/xing4.py)."""
+    import jax.numpy as jnp
+
+    x = ins["X"][0].astype(jnp.float32)
+    return {"Out": jnp.tile(x, (1,) * (x.ndim - 1)
+                            + (int(attrs["n_streams"]),))}
+
+
 @register_op("sum_streams", required_attrs=("n_streams",))
 def sum_streams_op(ins, attrs):
     """Out [..., C] = the sum of X's `n_streams` streams [..., n*C]."""
@@ -645,16 +657,25 @@ def mhc_pre_op(ins, attrs):
     [..., 128] (H_pre, H_post and the doubly stochastic H_res of each
     token, a lane tile; `mhc_post` reads it). The kernel ``mhc_pre``
     under the PT_PALLAS dispatch, its stock lowering where the mode is
-    off or the shape cannot be tiled (``pallas.mhc_fallbacks``)."""
+    off or the shape cannot be tiled (``pallas.mhc_fallbacks``). Attrs
+    `res_clamp_min` / `res_clamp_max` (both or neither) clamp H_res's
+    logits before the exponential; `sinkhorn_eps` joins the Sinkhorn
+    denominators."""
     from .pallas.mhc_mix import mhc_pre
 
     x = ins["X"][0]
     lead = x.shape[:-1]
+    clamp = {}
+    if "res_clamp_min" in attrs:
+        clamp["res_clamp"] = (float(attrs["res_clamp_min"]),
+                              float(attrs["res_clamp_max"]))
+    if attrs.get("sinkhorn_eps"):
+        clamp["sinkhorn_eps"] = float(attrs["sinkhorn_eps"])
     u, maps = mhc_pre(
         x.reshape(-1, x.shape[-1]), ins["Gamma"][0], ins["Phi"][0],
         ins["Scale"][0], ins["Bias"][0], n=int(attrs["n_streams"]),
         iters=int(attrs["sinkhorn_iters"]),
-        eps=float(attrs.get("epsilon", 1e-5)))
+        eps=float(attrs.get("epsilon", 1e-5)), **clamp)
     return {"U": u.reshape(lead + (-1,)), "Maps": maps.reshape(lead + (-1,))}
 
 

@@ -9,6 +9,7 @@ stream i at columns ``[i C, (i + 1) C)``). Around a sublayer F:
              H_pre = sigmoid(a_pre l_pre + b_pre)            [n]
              H_post = 2 sigmoid(a_post l_post + b_post)      [n]
              H_res = Sinkhorn(exp(a_res l_res + B_res))      [n x n]
+               (`res_clamp`: the logits clamped first, models/xing4.py)
              u = sum_i H_pre[i] X[i]                          -> F(RMS(u))
   mhc_post   X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y, clamped
 
@@ -63,10 +64,14 @@ def maps_layout(n: int):
     return slice(0, n), slice(n, 2 * n), slice(2 * n, 2 * n + n * n)
 
 
-def stock_mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps):
+def stock_mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps,
+                  res_clamp=None, sinkhorn_eps=0.0):
     """x [T, nC] float32, gamma [nC], phi [nC, 2n + n^2] (the product
     rounds the normed row to its dtype, float32 accumulation), scale [3]
-    (a_pre, a_post, a_res), bias [2n + n^2] -> (u [T, C], maps [T, 128])."""
+    (a_pre, a_post, a_res), bias [2n + n^2] -> (u [T, C], maps [T, 128]).
+    `res_clamp` (lo, hi) clamps the residual map's logits before the
+    exponential and `sinkhorn_eps` is added to every Sinkhorn denominator
+    (models/xing4.py)."""
     t = x.shape[0]
     c = x.shape[1] // n
     ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
@@ -78,10 +83,14 @@ def stock_mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps):
     bias = bias.astype(jnp.float32)
     h_pre = jax.nn.sigmoid(scale[0] * logits[:, pre] + bias[pre])
     h_post = 2.0 * jax.nn.sigmoid(scale[1] * logits[:, post] + bias[post])
-    m = jnp.exp(scale[2] * logits[:, res] + bias[res]).reshape(t, n, n)
+    z_res = scale[2] * logits[:, res] + bias[res]
+    if res_clamp is not None:
+        z_res = jnp.clip(z_res, res_clamp[0], res_clamp[1])
+    m = jnp.exp(z_res).reshape(t, n, n)
     for _ in range(iters):
-        m = m / jnp.sum(m, axis=2, keepdims=True)
-        m = m / jnp.sum(m, axis=1, keepdims=True)
+        for axis in (2, 1):
+            total = jnp.sum(m, axis=axis, keepdims=True)
+            m = m / (total + sinkhorn_eps if sinkhorn_eps else total)
     # weighted sums stream by stream: float32 multiply-adds on any backend
     # (an einsum is a product, which a TPU rounds to bfloat16 by default)
     u = sum(h_pre[:, i:i + 1] * x[:, i * c:(i + 1) * c] for i in range(n))
@@ -114,7 +123,7 @@ def _butterfly(v, rel, span):
 
 
 def _pre_kernel(x_ref, gamma_ref, phi_ref, sb_ref, u_ref, maps_ref, *, n,
-                iters, eps):
+                iters, eps, res_clamp, sinkhorn_eps):
     x = x_ref[...]                                         # [tile, nC]
     c = x.shape[1] // n
     ms = jnp.sum(x * x, axis=1, keepdims=True) * (1.0 / x.shape[1])
@@ -127,7 +136,9 @@ def _pre_kernel(x_ref, gamma_ref, phi_ref, sb_ref, u_ref, maps_ref, *, n,
     rel = lane + (MAPS_WIDTH - 2 * n)
     # lanes outside the n x n block hold 1: their sums stay finite and
     # never reach the block (a butterfly stays inside aligned groups)
-    m = jnp.where(is_res, jnp.exp(z), 1.0)
+    z_res = z if res_clamp is None else jnp.clip(z, res_clamp[0],
+                                                 res_clamp[1])
+    m = jnp.where(is_res, jnp.exp(z_res), 1.0)
 
     def sinkhorn(_, m):
         for first in (1, n):            # rows, then columns, to sum 1
@@ -135,7 +146,7 @@ def _pre_kernel(x_ref, gamma_ref, phi_ref, sb_ref, u_ref, maps_ref, *, n,
             while span < first * n:
                 total = _butterfly(total, rel, span)
                 span *= 2
-            m = m / total
+            m = m / (total + sinkhorn_eps if sinkhorn_eps else total)
         return m
 
     m = jax.lax.fori_loop(0, iters, sinkhorn, m)
@@ -178,9 +189,10 @@ def _padded_phi(phi, scale, bias, n):
 
 
 @functools.partial(jax.jit, static_argnames=("n", "iters", "eps", "tile",
-                                             "interpret"))
+                                             "interpret", "res_clamp",
+                                             "sinkhorn_eps"))
 def _pallas_mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps, tile,
-                    interpret):
+                    interpret, res_clamp=None, sinkhorn_eps=0.0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -188,7 +200,8 @@ def _pallas_mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps, tile,
     c = nc // n
     phi, sb = _padded_phi(phi, scale, bias, n)
     return pl.pallas_call(
-        functools.partial(_pre_kernel, n=n, iters=iters, eps=eps),
+        functools.partial(_pre_kernel, n=n, iters=iters, eps=eps,
+                          res_clamp=res_clamp, sinkhorn_eps=sinkhorn_eps),
         grid=(t // tile,),
         in_specs=[pl.BlockSpec((tile, nc), lambda i: (i, 0)),
                   pl.BlockSpec((1, nc), lambda i: (0, 0)),
@@ -251,16 +264,22 @@ def _route(kernel, t, c, n):
     return mode, tile
 
 
-def mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps):
+def mhc_pre(x, gamma, phi, scale, bias, *, n, iters, eps, res_clamp=None,
+            sinkhorn_eps=0.0):
     """(u [T, C], maps [T, 128]) of x [T, nC] float32 (module docstring).
-    Routed per ``kernel_mode()``; a stock fallback is counted."""
+    Routed per ``kernel_mode()``; a stock fallback is counted. `res_clamp`
+    (lo, hi): the residual map's logits clamped before the exponential;
+    `sinkhorn_eps` joins every Sinkhorn denominator."""
     mode, tile = _route(PRE_KERNEL, x.shape[0], x.shape[1] // n, n)
     if mode is None:
         return stock_mhc_pre(x, gamma, phi, scale, bias, n=n, iters=iters,
-                             eps=eps)
+                             eps=eps, res_clamp=res_clamp,
+                             sinkhorn_eps=sinkhorn_eps)
     return _pallas_mhc_pre(x, gamma, phi, scale, bias, n=n, iters=iters,
                            eps=float(eps), tile=tile,
-                           interpret=mode == "interpret")
+                           interpret=mode == "interpret",
+                           res_clamp=res_clamp,
+                           sinkhorn_eps=float(sinkhorn_eps))
 
 
 def mhc_post(x, y, maps, *, n, clamp):

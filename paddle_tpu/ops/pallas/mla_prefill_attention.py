@@ -399,6 +399,11 @@ def mla_prefill_attention(q_nope, q_rope, kv, k_rope, scale, *, num_heads,
     rope = q_rope.shape[1] // n
     dv = kv.shape[1] // nkv - nope
     block = min(BLOCK, s)
+    if s % block:
+        # a bucket halfway between two powers of two (768 = 2 x 384): the
+        # largest whole-lane-tile block under BLOCK that divides it
+        block = next((b for b in range(BLOCK - _LANES, 0, -_LANES)
+                      if s % b == 0), block)
     mode = kernel_mode()
     heads = 0
     reason = None

@@ -41,6 +41,13 @@ position is in ``pos - window < s <= pos``. The walk is the same and never
 longer than the ring (3 pages of 64 at window 128); only the mask differs:
 index i of the ring holds position ``pos - ((pos - i) mod cap)``.
 
+**Several query positions a row** (``queries`` > 1; a step that verifies a
+draft, models/xing4.py): a row's query holds ``queries x heads`` heads, the
+heads of its position ``pos + j`` at rows ``[j x heads, (j + 1) x heads)``,
+and the pages already hold the rows of all its positions. The walk reaches
+``pos + queries - 1`` and reads every chunk ONCE for all of them; a head of
+position j attends ``s <= pos + j``: only the mask knows of j. No ring.
+
 Dispatch and fallback counts land in the same counters as the other two
 paged kernels' (``pallas.paged_attn_dispatches`` / ``_fallbacks``).
 """
@@ -68,10 +75,11 @@ def _ring_valid(idx, pos, cap, window):
 
 
 def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
-                              window=0):
+                              window=0, queries=1):
     """The counted stock lowering, and the kernel's oracle: dense page
     gather, scores in float32, positions past the row's own (outside its
-    window, in a ring) masked before the softmax."""
+    window, in a ring) masked before the softmax. `n` counts every head of
+    a row, `queries` positions of ``n // queries`` heads each."""
     b = q.shape[0]
     page, width = int(pool.shape[1]), int(pool.shape[2])
     cap = int(table.shape[1]) * page
@@ -80,10 +88,13 @@ def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
     scores = jnp.einsum("bhw,bsw->bhs", qh, rows,
                         preferred_element_type=jnp.float32) * scale
     idx = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    valid = _ring_valid(idx, pos[:, None], cap, window) if window \
-        else idx <= pos[:, None]
-    probs = jax.nn.softmax(jnp.where(valid[:, None, :], scores, -1e9),
-                           axis=-1)
+    if queries > 1:
+        ahead = jnp.arange(n, dtype=jnp.int32) // (n // queries)
+        valid = idx[:, None, :] <= (pos[:, None] + ahead[None, :])[..., None]
+    else:
+        valid = (_ring_valid(idx, pos[:, None], cap, window) if window
+                 else idx <= pos[:, None])[:, None, :]
+    probs = jax.nn.softmax(jnp.where(valid, scores, -1e9), axis=-1)
     out = jnp.einsum("bhs,bsv->bhv", probs.astype(rows.dtype),
                      rows[..., :value_dim],
                      preferred_element_type=jnp.float32)
@@ -91,7 +102,8 @@ def stock_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
 
 
 def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
-            slot_ref, *, value_dim, page, mp, chunk_pages, scale, window):
+            slot_ref, *, value_dim, page, mp, chunk_pages, scale, window,
+            queries):
     """Grid (B,), sequential: row i attends its (heads, width) queries over
     its own pages, ``chunk_pages`` pages at a time. ``cs_ref`` is (2, chunk
     tokens, width) and persists across rows, as do the half in turn
@@ -105,7 +117,7 @@ def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
     n, width = q_ref.shape
 
     def held_pages(r):
-        return jnp.minimum(pos_ref[r] // page + 1, mp)
+        return jnp.minimum((pos_ref[r] + queries - 1) // page + 1, mp)
 
     def each_copy(r, c, slot, act):
         """``act`` on the copy of every held page of row r's chunk c, into
@@ -161,8 +173,13 @@ def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
             s += jax.lax.dot_general(q_r, cs_ref[slot, :, value_dim:], nt,
                                      preferred_element_type=jnp.float32)
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, ct), 1) + c * ct
-        valid = _ring_valid(idx, pos, mp * page, window) if window \
-            else idx <= pos
+        if queries > 1:     # heads of position pos + j see one row more
+            ahead = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) \
+                // (n // queries)
+            valid = idx <= pos + ahead                          # (n, ct)
+        else:
+            valid = _ring_valid(idx, pos, mp * page, window) if window \
+                else idx <= pos
         s = jnp.where(valid, s * scale, -1e9)                   # (n, ct)
         m_new = jnp.maximum(m_run, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_run - m_new)
@@ -181,7 +198,7 @@ def _kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, cs_ref, sem,
 
 
 def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
-                                interpret, window=0):
+                                interpret, window=0, queries=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -203,7 +220,7 @@ def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
     out = pl.pallas_call(
         functools.partial(_kernel, value_dim=value_dim, page=page, mp=mp,
                           chunk_pages=chunk_pages, scale=scale,
-                          window=window),
+                          window=window, queries=queries),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, value_dim), jnp.float32),
         # rows run in order: the scratch, the half in turn and the copies
@@ -216,7 +233,7 @@ def _pallas_paged_mla_attention(q, pool, table, pos, n, value_dim, scale,
 
 
 def paged_mla_decode_attention(q, pool, table, positions, num_heads,
-                               value_dim, scale, window=0):
+                               value_dim, scale, window=0, queries=1):
     """Attend each row's absorbed queries over its own latent pages.
 
     q [B, n*width] (a head: the absorbed latent query, then the rotated
@@ -224,11 +241,17 @@ def paged_mla_decode_attention(q, pool, table, positions, num_heads,
     [B, MP] int32, a context's pages in order; positions [B] int32.
     Returns float32 [B, n*value_dim]: a head's probabilities over the
     rows' first ``value_dim`` entries. With ``window`` the table is a
-    slot's latent ring (module docstring). Routed per ``kernel_mode()``;
-    every stock fallback is counted."""
+    slot's latent ring (module docstring). With ``queries`` > 1 a row's q
+    holds that many positions' heads, position ``positions[b] + j`` at
+    heads ``[j x num_heads, (j + 1) x num_heads)``, and the result likewise.
+    Routed per ``kernel_mode()``; every stock fallback is counted."""
     from . import kernel_mode
 
-    n, value_dim = int(num_heads), int(value_dim)
+    queries = int(queries)
+    if queries > 1 and window:
+        raise ValueError("several query positions a row over a latent ring "
+                         "are not built")
+    n, value_dim = int(num_heads) * queries, int(value_dim)
     pos = jnp.asarray(positions).reshape(-1).astype(jnp.int32)
     page, width = int(pool.shape[1]), int(pool.shape[2])
     mode = kernel_mode()
@@ -249,9 +272,10 @@ def paged_mla_decode_attention(q, pool, table, positions, num_heads,
         telemetry.counter_add("pallas.paged_attn_fallbacks", 1,
                               reason=reason)
         return stock_paged_mla_attention(q, pool, table, pos, n, value_dim,
-                                         scale, window)
+                                         scale, window, queries)
     telemetry.counter_add("pallas.paged_attn_dispatches", 1, mode=mode,
                           kernel=KERNEL_NAME)
     return _pallas_paged_mla_attention(
         q, pool, jnp.asarray(table, jnp.int32), pos, n, value_dim,
-        float(scale), interpret=mode == "interpret", window=int(window))
+        float(scale), interpret=mode == "interpret", window=int(window),
+        queries=queries)

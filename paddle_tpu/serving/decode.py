@@ -48,6 +48,25 @@ caching, on the repo's frozen-program stack:
   queued behind: on a second prefill of one poll, on a shipment, before
   the thread sleeps and before it ends. A failed step fails the rows of
   both steps. Depth is one, always.
+* **A draft module** (``ServedModel.draft``: models/xing4.py's
+  multi-token-prediction module; no flag turns it on, a model that has one
+  drafts). The step program then runs TWO positions a slot, the slot's last
+  accepted token and the module's draft of the next, verifies the draft by
+  the exact rule of speculative sampling (sampling.verify_tokens), runs the
+  module on both positions and draws the next draft (`draft_step`): a row
+  advances by one or two tokens a step and the host learns by which only
+  when it fetches. So a slot's position, its draft and the distribution the
+  draft was drawn from live on the device beside ``last_tokens``; the host
+  feeds a token and a position for a row's first step alone (which has no
+  draft and yields one token), dispatches a row while its count MAY still
+  fall short (one token a step in flight at the least), and on the fetch
+  (``[slots, 2]`` tokens, ``[slots]`` counts) takes a row's tokens in order
+  until the request ends: the surplus TOKENS are thrown away
+  (``decode.tokens_discarded``). A rejected draft's latent row is
+  overwritten by the next step, which starts at its position; a step
+  dispatched ahead may write up to two rows past a request's last kept
+  token, which land in its own reserved pages or, behind them, in the
+  scratch page (the page tables are that much wider).
 * **Paged KV cache.** Pages come from the preallocated
   ``KVPagePool`` (kv_cache.py); the pool arrays are threaded through
   the step program and donated to the jit so XLA updates them in place.
@@ -65,7 +84,8 @@ caching, on the repo's frozen-program stack:
   mid-flight with ``DeadlineExceededError`` and frees its pages without
   draining the batch.
 
-Who samples what: the decode-step program ends in ``sampling.
+Who samples what (a model with a draft module: two paragraphs down): the
+decode-step program ends in ``sampling.
 sample_tokens`` and returns ``[slots]`` int32 — every token after a
 request's first is chosen on the device (greedy argmax, or softmax at the
 row's temperature and the inverse CDF at the row's uniform), and the
@@ -79,6 +99,24 @@ tokens: a journal record holds the state noted before it). A request's FIRST tok
 chunked-prefill and shipped-prefill paths hand over. Either way token
 selection is a function of the row's own logits, temperature and the
 request's own seed — scheduling cannot perturb it.
+
+With a draft module the step program ends in ``sampling.verify_tokens``
+and ``sampling.draft_tokens`` and returns ``[slots, 2]`` tokens and
+``[slots]`` counts. A row a step takes FOUR uniforms of the request's
+stream, drawn on the host while the feed is built, in the order accept,
+redraw, second position, next draft, whichever of them the step comes to
+use: so the stream's state after a step does not depend on its outcome, and
+a request's tokens are a function of its own logits, temperature and seed.
+The delivered tokens are distributed exactly as the one-token sampler's
+(the rule is exact); greedy rows accept a draft where it is the model's own
+argmax and deliver the model's argmaxes either way. A journal record of a
+drafting request holds, as before, every accepted token and the stream's
+state before the draws of the step in flight, and ``last_step_tokens``, how
+many of them the last whole step delivered; the survivor prefills them,
+chooses its next token on the host from the restored stream and drafts on
+from there: the record decides the resumed tail (greedy: the uninterrupted
+one), which is a sample of the same distribution, not the dead replica's
+own continuation, whose pending draft died with it.
 
 Fault sites (core/faults.py, tools/chaos_check.py --decode):
 ``decode.step`` fails the in-flight step (every affected request gets a
@@ -94,12 +132,21 @@ decode.steps_ahead (steps dispatched while the step before was not yet
 fetched: over decode.steps the share of steps that ran ahead; the rest
 went into an empty pipe: an engine's first step, one behind a second
 prefill of one poll or a shipment) and
-decode.rows_discarded (speculative rows whose token was thrown away),
+decode.rows_discarded (speculative rows whose token was thrown away); for a
+model with a draft module decode.draft_proposed and decode.draft_accepted
+(drafts verified for rows that were still live, and those the model
+accepted), decode.rows_stepped (live rows a step: decode.tokens over it is
+the tokens a row took a step, whose per-step mean is the histogram
+decode.tokens_per_row_step), decode.tokens_discarded (tokens past
+``max_new_tokens`` or ``eos_id``, and every token of a row whose request had
+ended), while decode.tokens, decode.token_gap_ms and decode.batch_occupancy
+keep their meaning (delivered tokens; rows a step),
 decode.prefill_ms + decode.step_ms timers, decode.batch_occupancy
 histogram; for a model with ring layers decode.rows_past_window and, for
 one with ring or latent layers, decode.kv_tokens_attended (cached tokens
 read a step, over rows and layers: a ring layer reads min(context,
-window), a latent layer its context's latents; with latent RINGS
+window), a latent layer its context's latents, ONCE a row a step whatever
+the positions it verifies; with latent RINGS
 decode.ring_latent_rows_attended is the rings' part), and whatever counters
 the model's step program returns beside its tokens (``ServedModel.step_counters``: the
 routed-expert counts of models/afmoe.py), fetched in the step's one fetch; decode.active_slots + decode.queue_depth +
@@ -136,6 +183,7 @@ every row and stay bare.
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import threading
 import time
@@ -151,6 +199,7 @@ from .admission import (AdmissionQueue, DeadlineExceededError,
                         KVCacheExhaustedError, ServingError)
 from .health import DRAINING, READY, STOPPED, HealthState
 from .kv_cache import PagedKVCache, state_array_names
+from .served_model import DRAFT_SPARE_TOKENS
 from .prefix_store import PrefixStore
 
 
@@ -322,7 +371,8 @@ class GenerationRequest(InferenceRequest):
                  "shared_blocks", "_rng", "session_id", "prior", "seq",
                  "stop_at_eos", "ring_pages", "ring_row", "first_logits",
                  "slot", "carried", "ahead", "_rng_cut", "final_state",
-                 "final_pages", "rid")
+                 "final_pages", "rid", "steps", "last_step_tokens",
+                 "step_outputs")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  deadline: Optional[float], temperature: float = 0.0,
@@ -371,6 +421,12 @@ class GenerationRequest(InferenceRequest):
         # class as they stand when it retires: how a check compares what
         # the cache holds, not only what was computed from it
         self.final_pages: Any = False
+        # a drafting model's: the steps accepted so far (the first has no
+        # draft), the tokens the last of them delivered, and False or the
+        # list that takes a record of every step (`_keep_step`)
+        self.steps = 0
+        self.last_step_tokens = 0
+        self.step_outputs: Any = False
         # the position and, for a request's first step, the token its next
         # step is fed; ``pos_next`` moves on when a step is DISPATCHED
         self.pos_next = 0
@@ -457,6 +513,10 @@ class GenerationRequest(InferenceRequest):
             "rng_state": pack_rng_state(rng)
             if self.temperature > 0 else None,
             "deadline_remaining_ms": rem,
+            # a drafting model's step delivers one or two tokens: how many
+            # of `accepted` the last whole step brought (0: no such model)
+            **({"last_step_tokens": self.last_step_tokens}
+               if self.last_step_tokens else {}),
         }
 
 
@@ -491,6 +551,110 @@ class _StepInFlight(NamedTuple):
     bucket: int
     chosen: Any                     # device int32 [bucket] (+ step_counts)
     positions: np.ndarray           # the rows' positions, int32 [len(rows)]
+    kept: Any = None                # a drafting step's device outputs, if a
+    #                                 row keeps them, and its uniforms
+    uniforms: Any = None
+
+
+def run_program(block, params, pools, feed):
+    """Run one program's block over the parameters, the pools and its feed:
+    (its environment, the pools with every array the program wrote back,
+    ``<name>_out``, in the place of the one it read)."""
+    from ..core.executor import run_block
+
+    env = dict(params)
+    env.update(pools)
+    env.update(feed)
+    run_block(block, env)
+    return env, dict(pools, **{n: env[n + "_out"] for n in pools
+                               if n + "_out" in env})
+
+
+def draft_step(model, kv, weight_quant: str, bucket: int, step_block):
+    """The step of a model with a draft module, one jitted program:
+    (params, pools, feed, last_tokens, spec) -> (fetch, pools,
+    last_tokens, spec, kept). A row is fed its last accepted token and
+    the draft of the next (both from the device once the row is
+    carried; its first step has a token and a position from the host
+    and no draft), the held layers run both positions, the acceptance
+    rule (sampling.verify_tokens) yields one or two tokens, and the
+    module runs on both positions with the tokens AFTER them, so that
+    its own latent layer has a row for every accepted position, and
+    draws the next draft from its distribution at the newest accepted
+    one (a first step's module pass starts one position back, on the
+    prefill's last hidden state and the first token). ``fetch`` is
+    int32: the tokens [bucket x 2], the counts [bucket], the two
+    programs' counters; ``kept`` stays on the device unless a row asked
+    for it (``keep_step_outputs``). ``step_block`` is the block of the
+    model's step program at this bucket (tools/rehearse_served.py compiles
+    this same step for a described chip)."""
+    import jax.numpy as jnp
+
+    from .sampling import draft_tokens, verify_tokens
+
+    program, _feeds, _fetches = model.build_draft_program(bucket, kv,
+                                                          weight_quant)
+    draft_block = program.global_block()
+
+    def pairs(a, b):                # [B, ...] x 2 -> [2B, ...]
+        return jnp.stack([a, b], axis=1).reshape((-1,) + a.shape[1:])
+
+    def step(params, pools, feed, last_tokens, spec):
+        slot, carried = feed["carry"][:, 0], feed["carry"][:, 1] > 0
+
+        def of_slot(a):
+            return a.at[slot].get(mode="clip")
+
+        token = jnp.where(carried, of_slot(last_tokens), feed["tokens"])
+        pos = jnp.where(carried, of_slot(spec["pos"]), feed["positions"])
+        draft = jnp.where(carried, of_slot(spec["draft"]), 0)
+        q = of_slot(spec["q"])
+        table = jnp.repeat(feed["page_table"], 2, axis=0)
+        live = feed["page_table"][:, 0] > 0
+        env, pools = run_program(step_block, params, pools, {
+            "tokens": pairs(token, draft), "positions": pairs(pos, pos + 1),
+            "page_table": table, "live": pairs(live, live & carried)})
+        logits = env["logits"].reshape(bucket, 2, -1)
+        hidden = env["hidden"].reshape(bucket, 2, -1)
+        temperature = feed["sampling"][:, 0]
+        tokens, count = verify_tokens(
+            logits[:, 0], logits[:, 1], q, draft, carried, temperature,
+            feed["sampling"][:, 1:4])
+        # the module: rows (pos, pos + 1) with the tokens after them; a
+        # first step's (pos - 1, pos), from the prefill's hidden state
+        first = ~carried
+        start = pos - first.astype(jnp.int32)
+        fed = jnp.where(first[:, None],
+                        jnp.stack([token, tokens[:, 0]], axis=1), tokens)
+        state = jnp.where(
+            first[:, None, None],
+            jnp.stack([of_slot(spec["hidden"]), hidden[:, 0]], axis=1),
+            hidden)
+        newest = jnp.where(first, 1, count - 1)
+        env2, pools = run_program(draft_block, params, pools, {
+            "tokens": fed.reshape(-1), "positions": pairs(start, start + 1),
+            "page_table": table, "live": pairs(live, live & (newest > 0)),
+            "hidden": state.reshape(2 * bucket, -1),
+            "pick": 2 * jnp.arange(bucket, dtype=jnp.int32) + newest})
+        next_draft, next_q = draft_tokens(
+            env2["draft_logits"], temperature, feed["sampling"][:, 4])
+        last = jnp.take_along_axis(tokens, (count - 1)[:, None],
+                                   axis=1)[:, 0]
+        last_tokens = last_tokens.at[slot].set(last, mode="drop")
+        spec = dict(
+            spec, pos=spec["pos"].at[slot].set(pos + count, mode="drop"),
+            draft=spec["draft"].at[slot].set(next_draft, mode="drop"),
+            q=spec["q"].at[slot].set(next_q, mode="drop"))
+        fetch = jnp.concatenate([
+            tokens.reshape(-1), count,
+            env["step_counts"].astype(jnp.int32),
+            env2["step_counts"].astype(jnp.int32)])
+        kept = {"logits": logits, "draft": draft, "pos": pos,
+                "q": jnp.where(carried[:, None], q, 0.0),
+                "draft_logits": env2["draft_logits"]}
+        return fetch, pools, last_tokens, spec, kept
+
+    return step
 
 
 class DecodeEngine:
@@ -549,7 +713,14 @@ class DecodeEngine:
                 "nor disaggregated prefill handles ring or latent pages "
                 "yet")
         self._pools = self.kv.make_arrays()
-        self._mp = -(-model_cfg.max_seq_len // self.config.page_size)
+        # a model with a draft module is stepped two positions a slot and
+        # advances by one or two tokens; what the host can no longer count
+        # lives on the device (``_spec``), and its page tables reach the
+        # rows a step ahead may write past a request's last kept token
+        self._draft = bool(self.model.draft)
+        self._mp = -(-(model_cfg.max_seq_len + (
+            DRAFT_SPARE_TOKENS if self._draft else 0))
+            // self.config.page_size)
         self._feed_names: Dict[Any, Any] = {}   # (phase, bucket) -> names
         self.queue = AdmissionQueue(self.config.max_queue_depth,
                                     self.config.default_deadline_ms,
@@ -569,6 +740,21 @@ class DecodeEngine:
         # input from and writes its chosen tokens to
         self._free_slots = list(range(self.config.max_slots))[::-1]
         self._last_tokens = jnp.zeros((self.config.max_slots,), jnp.int32)
+        # a drafting model's slots, beside ``_last_tokens`` (by slot, on the
+        # device, read and written by the step program): the position the
+        # slot's last accepted token is fed at, the draft of the token after
+        # it and the distribution q it was drawn from; ``hidden`` is the
+        # prefill's, the held layers' output at the prompt's last position,
+        # which the module's pass of the slot's first step reads
+        self._spec: Dict[str, Any] = {}
+        if self._draft:
+            n = self.config.max_slots
+            self._spec = {
+                "pos": jnp.zeros((n,), jnp.int32),
+                "draft": jnp.zeros((n,), jnp.int32),
+                "q": jnp.zeros((n, model_cfg.vocab_size), jnp.float32),
+                "hidden": jnp.zeros((n, model_cfg.hidden_size),
+                                    jnp.float32)}
         # the step that was dispatched and whose tokens are not fetched yet
         self._inflight: Optional[_StepInFlight] = None
         # the prefill that was dispatched and whose request is not seated
@@ -593,7 +779,8 @@ class DecodeEngine:
                rng_state: Optional[Any] = None,
                keep_first_logits: bool = False,
                keep_final_state: bool = False,
-               keep_final_pages: bool = False) -> GenerationRequest:
+               keep_final_pages: bool = False,
+               keep_step_outputs: bool = False) -> GenerationRequest:
         """Enqueue one generation (non-blocking). ``prompt`` is a 1-D
         int token-id array. Raises ValueError (malformed / over the
         model length), KVCacheExhaustedError (can never fit the KV
@@ -615,7 +802,11 @@ class DecodeEngine:
         (``final_pages``: pool array name -> [its pages, page, kv_dim] in
         the order of its page table, token t at ``[t // page, t % page]``;
         for a model with rings also its ring's pages, in the ring's order:
-        token t at index ``t mod (ring pages x page)``)."""
+        token t at index ``t mod (ring pages x page)``);
+        ``keep_step_outputs`` (a model with a draft module) leaves a record
+        of each of its steps (``step_outputs``: the position, the draft and
+        the q it was drawn from, the logits of both positions, the module's
+        logits, the step's uniforms and tokens)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -643,6 +834,8 @@ class DecodeEngine:
         req.first_logits = bool(keep_first_logits)
         req.final_state = bool(keep_final_state)
         req.final_pages = bool(keep_final_pages)
+        if keep_step_outputs and self._draft:
+            req.step_outputs = []
         if rng_state is not None:
             from .session import unpack_rng_state
 
@@ -722,6 +915,16 @@ class DecodeEngine:
         # every token after a request's first is chosen by the step program
         if "tokens" in out:
             out["tokens_device_sampled"] = out["tokens"]
+        if out.get("draft_proposed"):
+            # a model with a draft module: the share of drafts the model
+            # accepted, and the tokens a stepped row took a step
+            out["draft_accept_share"] = round(
+                100.0 * out.get("draft_accepted", 0) / out["draft_proposed"],
+                2)
+            per = hists.get("decode.tokens_per_row_step")
+            if per:
+                out["tokens_per_row_step"] = {"avg": per["avg"],
+                                              "p50": per["p50"]}
         out.update(admission_cost(c, hists))
         win = telemetry.windowed()
         wout = {"seconds": win["window_s"]}
@@ -794,7 +997,6 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..core.executor import run_block
         from .sampling import sample_tokens
 
         cc = self.config
@@ -810,15 +1012,8 @@ class DecodeEngine:
             n for n in feeds if not (by_slot and n == "state_slots")) + (
             ("sampling", "carry") if phase == "step" else ())
         block = program.global_block()
-        pool_names = sorted(self._pools)
         counted = "step_counts" in fetches
-
-        def run(params, pools, feed):
-            env = dict(params)
-            env.update(pools)
-            env.update(feed)
-            run_block(block, env)
-            return env, {n: env[n + "_out"] for n in pool_names}
+        run = functools.partial(run_program, block)
 
         def prefill(params, pools, feed):
             env, pools = run(params, pools, feed)
@@ -848,6 +1043,25 @@ class DecodeEngine:
                     [chosen, env["step_counts"].astype(jnp.int32)])
             return chosen, pools, last_tokens
 
+        donate = (1,)
+        if self._draft:
+            donate = (1, 4) if phase == "step" else (1, 3)
+            if phase == "step":
+                self._feed_names[key] = ("tokens", "positions", "page_table",
+                                         "sampling", "carry")
+                step = self._draft_step(bucket, block)
+            else:
+                self._feed_names[key] += ("state_slots",)
+
+                def prefill(params, pools, feed, spec):
+                    # the module's rows were written beside the layers';
+                    # the last position's hidden state waits in the slot
+                    # for the first step's module pass
+                    env, pools = run(params, pools, feed)
+                    hidden = spec["hidden"].at[feed["state_slots"]].set(
+                        env["hidden"], mode="drop")
+                    return env["logits"], pools, dict(spec, hidden=hidden)
+
         fn = step if phase == "step" else prefill
 
         # the program's own name in the profiler's trace and in the compile
@@ -858,11 +1072,13 @@ class DecodeEngine:
 
         from ..ops import pallas as _pallas
 
-        entry = jax.jit(fn, donate_argnums=(1,))
+        entry = jax.jit(fn, donate_argnums=donate)
         self._entries[key] = entry
         t0 = time.perf_counter()
         args = (self._zero_feed(phase, bucket),) + (
             (jnp.zeros_like(self._last_tokens),) if phase == "step" else ())
+        if self._draft:
+            args += (self._spec,)
         # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
         # geometry) keys the cost capture so flops/bytes attribute to
         # the kernel VARIANT actually compiled — the roofline verdict of
@@ -882,7 +1098,10 @@ class DecodeEngine:
         # tables name the scratch page 0 alone, so no request's page is
         # written, and no second pool is held beside the first (3.4 GB of
         # latent pages beside 7 GB of weights did not fit twice)
-        self._pools = entry(self._params, self._pools, *args)[1]
+        out = entry(self._params, self._pools, *args)
+        self._pools = out[1]
+        if self._draft:     # donated like the pools: a zero feed names no slot
+            self._spec = out[3 if phase == "step" else 2]
         ms = round((time.perf_counter() - t0) * 1e3, 3)
         telemetry.counter_add("decode.compiles", 1)
         telemetry.event("compile", "decode", ms,
@@ -891,6 +1110,12 @@ class DecodeEngine:
                          "pallas_kernels": pallas_fp,
                          "cache_size": len(self._entries)})
         return entry
+
+    def _draft_step(self, bucket: int, step_block):
+        """`draft_step` for this engine's model (a method so that a check
+        can plant a fault around it)."""
+        return draft_step(self.model, self.kv, self.config.weight_quant,
+                          bucket, step_block)
 
     def _feed(self, phase: str, bucket: int, parts: Dict[str, Any]):
         """The program's feed: of ``parts`` (host arrays by feed name) the
@@ -909,7 +1134,8 @@ class DecodeEngine:
             return self._feed(phase, bucket, dict(
                 tables, tokens=np.zeros((bucket,), np.int32),
                 positions=np.zeros((bucket,), np.int32),
-                sampling=np.zeros((bucket, 2), np.float32),
+                sampling=np.zeros((bucket, 5 if self._draft else 2),
+                                  np.float32),
                 # no row names a slot: a model's per-slot state is touched
                 # at the scratch slot alone, whenever this compiles
                 carry=np.full((bucket, 2), (self.config.max_slots, 0),
@@ -921,6 +1147,8 @@ class DecodeEngine:
             positions=np.zeros((1, bucket), np.int32),
             chunk_start=np.zeros((1,), np.int32),
             state_slots=np.full((1,), self.config.max_slots, np.int32),
+            next_tokens=np.zeros((1, bucket), np.int32),
+            next_lengths=np.zeros((1,), np.int32),
             lengths=np.ones((1,), np.int32), last_onehot=oh))
 
     # -- scheduler loop ------------------------------------------------------
@@ -1179,19 +1407,29 @@ class DecodeEngine:
         oh = np.zeros((1, bucket), np.float32)
         oh[0, L - 1] = 1.0
         entry = self._entry("prefill", bucket)
-        feed = self._feed("prefill", bucket, {
+        parts = {
             "tokens": tokens, "lengths": np.asarray([L], np.int32),
             "last_onehot": oh,
             "positions": np.arange(bucket, dtype=np.int32)[None, :],
             "state_slots": np.asarray([req.slot], np.int32),
-            "page_table": row[None, :], "ring_table": req.ring_row[None, :]})
+            "page_table": row[None, :], "ring_table": req.ring_row[None, :]}
+        if self._draft:
+            # the module's row of position i is made of token i + 1
+            parts["next_tokens"] = np.roll(tokens, -1, axis=1)
+            parts["next_lengths"] = np.asarray([L - 1], np.int32)
+        feed = self._feed("prefill", bucket, parts)
         # the program computes the bucket, padding and all
         span = dict(rid=req.rid, bucket=bucket, tokens=L)
 
         def launch():
             ms: Dict[str, float] = {}
             with telemetry.timer("decode.prefill_ms", into=ms, **span):
-                logits, self._pools = entry(self._params, self._pools, feed)
+                if self._draft:
+                    logits, self._pools, self._spec = entry(
+                        self._params, self._pools, feed, self._spec)
+                else:
+                    logits, self._pools = entry(self._params, self._pools,
+                                                feed)
             return lambda: self._seat(req, self._logits_row(logits, ms, span))
 
         return launch
@@ -1428,6 +1666,14 @@ class DecodeEngine:
         if flight is not None:
             self._finish(flight, it)
 
+    def _finish(self, flight: _StepInFlight, it: Dict[str, float]):
+        """Fetch and accept a dispatched step: one token a row, or a
+        drafting model's one or two."""
+        if self._draft:
+            self._finish_drafted(flight, it)
+        else:
+            self._finish_one(flight, it)
+
     def _launch(self, rows: List[GenerationRequest],
                 it: Dict[str, float]) -> _StepInFlight:
         """Build one step's feed from what the host knows without the
@@ -1442,7 +1688,8 @@ class DecodeEngine:
             # a row's (temperature, uniform) for the program's sampler: the
             # request's own stream gives one draw per sampled token, here,
             # in token order (a first token's draw came before, on the host)
-            sampling = np.zeros((bucket, 2), np.float32)
+            sampling = np.zeros((bucket, 5 if self._draft else 2),
+                                np.float32)
             ring = np.zeros((bucket, self.kv.ring_slot_pages), np.int32)
             # a row's (slot, whether its token is last_tokens[slot]); a
             # padding row's slot is none of them
@@ -1458,23 +1705,39 @@ class DecodeEngine:
                 if req.temperature > 0.0:
                     if req.session_id is not None:
                         req._rng_cut = req._rng.get_state()
-                    sampling[i] = (req.temperature,
-                                   req._rng.random_sample())
+                    if self._draft:
+                        # a drafting step's four: accept, redraw, second
+                        # position, next draft (sampling.verify_tokens)
+                        sampling[i, 0] = req.temperature
+                        sampling[i, 1:] = req._rng.random_sample(4)
+                    else:
+                        sampling[i] = (req.temperature,
+                                       req._rng.random_sample())
             feed = self._feed("step", bucket, {
                 "tokens": tokens, "positions": positions,
                 "page_table": table, "ring_table": ring,
                 "sampling": sampling, "carry": carry})
+        kept = None
         with telemetry.timer("decode.step_ms", into=it):
-            chosen, self._pools, self._last_tokens = entry(
-                self._params, self._pools, feed, self._last_tokens)
+            if self._draft:
+                chosen, self._pools, self._last_tokens, self._spec, kept = \
+                    entry(self._params, self._pools, feed, self._last_tokens,
+                          self._spec)
+            else:
+                chosen, self._pools, self._last_tokens = entry(
+                    self._params, self._pools, feed, self._last_tokens)
             chosen.copy_to_host_async()
         for req in rows:
             req.pos_next += 1
             req.carried = True
             req.ahead += 1
-        return _StepInFlight(rows, bucket, chosen, positions[:len(rows)])
+        if kept is not None and not any(r.step_outputs is not False
+                                        for r in rows):
+            kept = None
+        return _StepInFlight(rows, bucket, chosen, positions[:len(rows)],
+                             kept, sampling if kept is not None else None)
 
-    def _finish(self, flight: _StepInFlight, it: Dict[str, float]):
+    def _finish_one(self, flight: _StepInFlight, it: Dict[str, float]):
         """Fetch a dispatched step's tokens and accept them row by row. A
         row whose request ended meanwhile (on ``eos_id``, on its deadline)
         was dispatched on speculation: its token is thrown away."""
@@ -1530,6 +1793,91 @@ class DecodeEngine:
             telemetry.counter_add("decode.rows_discarded",
                                   len(rows) - delivered)
         telemetry.observe("decode.batch_occupancy", delivered / bucket)
+
+    def _finish_drafted(self, flight: _StepInFlight, it: Dict[str, float]):
+        """`_finish_one` for a model with a draft module: the fetch brings
+        [bucket, 2] tokens and a count of 1 or 2 a row, and the host learns
+        only now how far each row went. A row takes its tokens in order
+        until it ends (``max_new_tokens``, ``eos_id``): what is left of the
+        step's, and every token of a row whose request had ended before
+        (dispatched on speculation), is thrown away
+        (decode.tokens_discarded; the latter rows also count in
+        decode.rows_discarded). A row's first step had no draft."""
+        rows, bucket = flight.rows, flight.bucket
+        with telemetry.timer("decode.step_ms", into=it):
+            with telemetry.timer("decode.fetch_ms", into=it):
+                fetched = np.asarray(flight.chosen)
+        tokens = fetched[:2 * bucket].reshape(bucket, 2)
+        counts = fetched[2 * bucket:3 * bucket]
+        for name, value in zip(self.model.step_counters,
+                               fetched[3 * bucket:]):
+            telemetry.counter_add(name, int(value))
+        # a row's latents are read once a step for both positions, up to
+        # the second one: its prompt, its tokens but the last, the pair
+        telemetry.counter_add(
+            "decode.kv_tokens_attended", len(self.kv.context.layers) * sum(
+                int(r.seq.size) + len(r.tokens) + 1 for r in rows))
+        delivered = stepped = proposed = accepted = discarded = 0
+        retired = False
+        with telemetry.timer("decode.sample_ms", into=it):
+            for i, req in enumerate(rows):
+                req.ahead -= 1
+                n = int(counts[i])
+                if req.done():
+                    discarded += n
+                    continue
+                stepped += 1
+                if req.steps:
+                    proposed += 1
+                    accepted += n - 1
+                req.steps += 1
+                took = 0
+                for tok in tokens[i, :n]:
+                    self._accept_token(req, int(tok))
+                    took += 1
+                    if req.finished():
+                        break
+                req.last_step_tokens = took
+                delivered += took
+                discarded += n - took
+                if req.step_outputs is not False:
+                    self._keep_step(req, flight, i, tokens[i, :n], took)
+                if req.finished():
+                    retired = True
+                    with telemetry.timer("decode.retire_ms", into=it,
+                                         rid=req.rid):
+                        self._retire(req)
+            if retired:
+                self._active = [r for r in self._active if not r.done()]
+        telemetry.counter_add("decode.steps", 1)
+        telemetry.counter_add("decode.tokens", delivered)
+        telemetry.counter_add("decode.rows_stepped", stepped)
+        telemetry.counter_add("decode.draft_proposed", proposed)
+        telemetry.counter_add("decode.draft_accepted", accepted)
+        if discarded:
+            telemetry.counter_add("decode.tokens_discarded", discarded)
+        if stepped < len(rows):
+            telemetry.counter_add("decode.rows_discarded",
+                                  len(rows) - stepped)
+        telemetry.observe("decode.batch_occupancy", stepped / bucket)
+        if stepped:
+            telemetry.observe_quiet("decode.tokens_per_row_step",
+                                    delivered / stepped)
+
+    @staticmethod
+    def _keep_step(req: GenerationRequest, flight: _StepInFlight, i: int,
+                   tokens: np.ndarray, took: int):
+        """One step's record of a request that asked for them
+        (``keep_step_outputs``): what the acceptance rule read and gave."""
+        kept = flight.kept
+        req.step_outputs.append({
+            "position": int(kept["pos"][i]), "draft": int(kept["draft"][i]),
+            "had_draft": req.steps > 1,
+            "logits": np.asarray(kept["logits"][i]),
+            "q": np.asarray(kept["q"][i]),
+            "draft_logits": np.asarray(kept["draft_logits"][i]),
+            "uniforms": np.array(flight.uniforms[i, 1:], np.float32),
+            "tokens": [int(t) for t in tokens], "delivered": took})
 
     def _drain(self, it: Dict[str, float]):
         """Fetch and accept the step in flight, if there is one, before the

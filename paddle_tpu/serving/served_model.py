@@ -10,8 +10,8 @@ pages are a context's or a ring, or that it keeps ONE latent array a
 token and no K and V; and what it keeps a SLOT beside them, or INSTEAD
 of them: a recurrent state and a conv tail). models/decoder_lm.py,
 models/afmoe.py, models/kimi_k2.py, models/falcon_h1.py,
-models/qwen3_next.py and models/motif3.py each give one; ``cfg.served()``
-builds it.
+models/qwen3_next.py, models/motif3.py and models/xing4.py each give one;
+``cfg.served()`` builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
 the program reads beside parameters and pools, out of what the engine can
@@ -53,6 +53,26 @@ the prefix store and the disaggregated roles for it). Every program writes
 ``step_counts``, int32 [len(step_counters)], which the engine fetches in
 the same fetch as the step's tokens and adds to the telemetry counters
 named in ``step_counters``.
+
+A model with a DRAFT MODULE (``draft = True``; models/xing4.py) is stepped
+two positions a slot: its step program's rows are pairs (row ``2s`` slot s
+at its position, row ``2s + 1`` the position after it, fed the slot's last
+accepted token and the draft of the next), it is fed ``live`` [2B] bool
+beside ``tokens``, ``positions`` and ``page_table`` (each [2B, ...]) and
+also writes ``hidden`` [2B, hidden]; ``build_draft_program`` gives the
+module's program over the same pairs (fed ``hidden``, the tokens AFTER the
+rows' positions, and ``pick`` [B], the row of each pair whose
+``draft_logits`` [B, vocab] it writes; its ``step_counts`` follow the step
+program's in ``step_counters``). The engine runs both in ONE jitted step
+with the acceptance rule between them (serving/sampling.py) and carries a
+slot's position, draft and draft distribution on the device. Its prefill
+is also fed ``next_tokens`` [1, S] (the prompt moved left by one) and
+``next_lengths`` [1] (its length less one), fills the module's own latent
+layer and also writes ``hidden`` [1, hidden] of the last real position. A
+step may write latent rows up to DRAFT_SPARE_TOKENS positions past what a
+request may reach (tokens that are thrown away): its page tables are that
+much wider than ``max_seq_len``, and the columns behind a request's pages
+name the scratch page.
 """
 
 from __future__ import annotations
@@ -62,9 +82,16 @@ from typing import Any, Dict, List, Tuple
 from .kv_cache import LayerCache, PagedKVCache
 
 
+# positions past `max_seq_len` that a drafting model's page tables hold: a
+# step dispatched before the one before it was fetched may write this far
+# beyond a request's last kept token
+DRAFT_SPARE_TOKENS = 2
+
+
 class ServedModel:
     kv_dtype: str = "float32"
     step_counters: Tuple[str, ...] = ()
+    draft: bool = False         # has a draft module: `build_draft_program`
 
     def __init__(self, cfg: Any):
         self.cfg = cfg          # max_seq_len, eos_id, vocab_size
@@ -82,6 +109,10 @@ class ServedModel:
 
     def build_step_program(self, batch: int, kv: PagedKVCache,
                            weight_quant: str):
+        raise NotImplementedError
+
+    def build_draft_program(self, batch: int, kv: PagedKVCache,
+                            weight_quant: str):
         raise NotImplementedError
 
     def build_prefill_program(self, prompt_len: int, kv: PagedKVCache,

@@ -32,6 +32,15 @@ params — here the ROUTER is the survivor that re-seeds the work):
 * The router concatenates journaled accepted tokens with the resumed
   tail, so the client sees ONE uninterrupted token stream.
 
+A model with a draft module (serving/decode.py) delivers one or two tokens a
+step and draws four uniforms a row a step: its record is the same cut (every
+accepted token, the stream's state before the draws of the step in flight)
+plus ``last_step_tokens``, how many tokens the last whole step delivered;
+records of other models are unchanged. The resumed tail is decided by the
+record and distributed as the uninterrupted one; it is bitwise the
+uninterrupted one for greedy sessions only, since the dead replica's
+pending draft is not in the record.
+
 Telemetry: session.journaled / session.failovers / session.resumed /
 session.resumed_tokens / session.journal_errors / session.evicted —
 rendered by tools/perf_report.py's "Sessions" section.

@@ -452,7 +452,7 @@ def test_the_prefill_kernels_stock_lowering_is_counted(monkeypatch):
 # (s, heads, nope, rope, BLOCK, VMEM_BLOCKS, mode) -> reason
 _REFUSED = {
     "mode_off": (256, 4, 128, 64, 128, None, "off"),
-    "length": (640, 4, 128, 64, 512, None, "interpret"),
+    "length": (576, 4, 128, 64, 512, None, "interpret"),
     "tpu_tiling-head_width": (256, 4, 64, 64, 128, None, "interpret"),
     "tpu_tiling-block": (64, 4, 128, 64, 512, None, "interpret"),
     "tpu_tiling-rope": (256, 4, 128, 48, 128, None, "interpret"),
@@ -485,6 +485,22 @@ def test_a_refused_shape_is_counted_with_its_reason(monkeypatch, case):
     c = telemetry.snapshot()["counters"]
     assert c["pallas.mla_prefill_fallbacks"] == 1
     assert not c.get("pallas.mla_prefill_dispatches")
+    np.testing.assert_allclose(got, _dense_attention(*args, 0.1), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_a_length_halfway_between_two_blocks_takes_a_smaller_block(
+        monkeypatch):
+    """768 is no multiple of BLOCK 512: the kernel attends it in blocks of
+    384, the largest whole-lane-tile divisor, and not by the stock
+    lowering (a prefill bucket halfway between two powers of two)."""
+    monkeypatch.setenv("PT_PALLAS", "interpret")
+    args = _prompt(np.random.RandomState(4), 768, 4, 128, 64, 128)
+    telemetry.reset()
+    got = _kernel_of(args)
+    c = telemetry.snapshot()["counters"]
+    assert c["pallas.mla_prefill_dispatches"] == 1
+    assert not c.get("pallas.mla_prefill_fallbacks")
     np.testing.assert_allclose(got, _dense_attention(*args, 0.1), rtol=2e-5,
                                atol=2e-5)
 

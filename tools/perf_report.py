@@ -400,6 +400,16 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
     occ = hists.get("decode.batch_occupancy")
     if occ:
         out["batch_occupancy"] = occ
+    # a model with a draft module (serving/decode.py): the share of drafts
+    # accepted, the tokens a stepped row took a step, what was thrown away
+    proposed = cval("decode.draft_proposed")
+    if proposed:
+        out["draft_accept_share"] = round(
+            100.0 * cval("decode.draft_accepted") / proposed, 2)
+        if cval("decode.rows_stepped"):
+            out["tokens_per_row_step"] = round(
+                tokens / cval("decode.rows_stepped"), 3)
+        out["tokens_discarded"] = int(cval("decode.tokens_discarded"))
     for timer, key in (("decode.prefill_ms", "prefill_ms"),
                        ("decode.step_ms", "step_ms"),
                        ("decode.request_ms", "request_ms")):
@@ -1029,6 +1039,10 @@ def render(s, out=sys.stdout):
                 f"mean (p50 {dc['batch_occupancy']['p50']:.1%})")
         if occ_line:
             w("  ".join(occ_line) + "\n")
+        if "draft_accept_share" in dc:
+            w(f"drafts accepted: {dc['draft_accept_share']}%  tokens a row "
+              f"a step: {dc.get('tokens_per_row_step', '-')}  tokens thrown "
+              f"away: {dc['tokens_discarded']}\n")
         w(f"retired: {dc['retired']}  rejected: {dc['rejects']}  "
           f"kv refusals: {dc['kv_refusals']}  deadline-expired: "
           f"{dc['deadline_expired']}  errors: {dc['errors']}  "

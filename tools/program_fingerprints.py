@@ -26,7 +26,8 @@ SERVED = {"decoder_lm": ("DecoderLMConfig", "decoder_lm_params"),
           "kimi_k2": ("KimiK2Config", "kimi_k2_params"),
           "falcon_h1": ("FalconH1Config", "falcon_h1_params"),
           "qwen3_next": ("Qwen3NextConfig", "qwen3_next_params"),
-          "motif3": ("Motif3Config", "motif3_params")}
+          "motif3": ("Motif3Config", "motif3_params"),
+          "xing4": ("Xing4Config", "xing4_params")}
 
 
 def _digest(fn, *args):

@@ -85,6 +85,12 @@ def main(argv=None) -> int:
                 for f in feeds}
         if phase == "step":
             feed["sampling"] = shape((bucket, 2), jnp.float32)
+        if model.draft and phase == "step":
+            fn, args = drafting_step(model, kv, eng, cfg, block, bucket,
+                                     shape)
+            report(label, jax.jit(fn, donate_argnums=(1, 4)),
+                   (params, pools) + args)
+            continue
 
         def fn(params, pools, feed, block=block, phase=phase):
             env = {**params, **pools,
@@ -96,25 +102,55 @@ def main(argv=None) -> int:
                                     feed["sampling"][:, 1])
             return out, {n: env[n + "_out"] for n in sorted(pools)}
 
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-            params, pools, feed).compile()
-        mem = compiled.memory_analysis()
-        text = compiled.as_text()
-        print(json.dumps({
-            "compiled_for": "described v5e:2x2, one chip (not a run)",
-            "program": label,
-            "compile_s": round(time.perf_counter() - t0, 1),
-            "tpu_custom_calls": text.count("tpu_custom_call"),
-            "argument_gb": round(mem.argument_size_in_bytes / 1e9, 3),
-            "alias_gb": round(mem.alias_size_in_bytes / 1e9, 3),
-            "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
-            "total_gb": round((mem.argument_size_in_bytes
-                               + mem.output_size_in_bytes
-                               - mem.alias_size_in_bytes
-                               + mem.temp_size_in_bytes) / 1e9, 3)}),
-            flush=True)
+        report(label, jax.jit(fn, donate_argnums=(1,)),
+               (params, pools, feed))
     return 0
+
+
+def report(label, jitted, args):
+    """Compile and print one program's line."""
+    t0 = time.perf_counter()
+    compiled = jitted.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    print(json.dumps({
+        "compiled_for": "described v5e:2x2, one chip (not a run)",
+        "program": label,
+        "compile_s": round(time.perf_counter() - t0, 1),
+        "tpu_custom_calls": text.count("tpu_custom_call"),
+        "argument_gb": round(mem.argument_size_in_bytes / 1e9, 3),
+        "alias_gb": round(mem.alias_size_in_bytes / 1e9, 3),
+        "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
+        "total_gb": round((mem.argument_size_in_bytes
+                           + mem.output_size_in_bytes
+                           - mem.alias_size_in_bytes
+                           + mem.temp_size_in_bytes) / 1e9, 3)}),
+        flush=True)
+
+
+def drafting_step(model, kv, eng, cfg, block, bucket, shape):
+    """The engine's own step of a model with a draft module
+    (serving/decode.py `draft_step`: the held layers on two positions a
+    slot, the acceptance rule, the module, the next draft) and the shapes
+    of its arguments behind the parameters and the pools."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.decode import draft_step
+    from paddle_tpu.serving.served_model import DRAFT_SPARE_TOKENS
+
+    slots = eng["max_slots"]
+    mp = -(-(cfg.max_seq_len + DRAFT_SPARE_TOKENS) // eng["page_size"])
+    feed = {"tokens": shape((bucket,), jnp.int32),
+            "positions": shape((bucket,), jnp.int32),
+            "page_table": shape((bucket, mp), jnp.int32),
+            "sampling": shape((bucket, 5), jnp.float32),
+            "carry": shape((bucket, 2), jnp.int32)}
+    spec = {"pos": shape((slots,), jnp.int32),
+            "draft": shape((slots,), jnp.int32),
+            "q": shape((slots, cfg.vocab_size), jnp.float32),
+            "hidden": shape((slots, cfg.hidden_size), jnp.float32)}
+    return (draft_step(model, kv, eng["weight_quant"], bucket, block),
+            (feed, shape((slots,), jnp.int32), spec))
 
 
 if __name__ == "__main__":
