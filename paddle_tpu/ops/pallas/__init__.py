@@ -43,6 +43,13 @@ math/bert_encoder_functor.cu) and fused optimizer passes
                     maps' logits, sigmoid and 20 Sinkhorn iterations by
                     lane rotations, the mixed input), mhc_post reads
                     streams and output once and writes the streams once.
+* draft_tail      — what a drafting decode step does after its head
+                    products (serving/decode.py draft_step): draft_verify
+                    reads a slot's two logits rows and q's row at the slot
+                    once and gives speculative sampling's one or two
+                    tokens; draft_next writes the next q in place at the
+                    slot and draws the draft; the two-level inverse CDF
+                    by log-step scans.
 
 Mode selection (``kernel_mode()``):
   'tpu'       compiled Pallas on a real TPU backend,
@@ -116,11 +123,12 @@ def kernels_fingerprint() -> str:
     RECOMPILE (the lowering changed), not reuse a stale entry. Named
     'pallas_kernels' in the executor's recompile-cause diagnostics and
     the decode engine's cost-capture keys."""
+    from .draft_tail import draft_tail_fingerprint
     from .int8_gemm import int8_gemm_fingerprint
     from .paged_attention import paged_attn_fingerprint
 
     return (f"{kernel_mode()}|{int8_gemm_fingerprint()}"
-            f"|{paged_attn_fingerprint()}")
+            f"|{paged_attn_fingerprint()}|{draft_tail_fingerprint()}")
 
 
 from .flash_attention import flash_attention  # noqa: E402,F401

@@ -101,12 +101,14 @@ selection is a function of the row's own logits, temperature and the
 request's own seed — scheduling cannot perturb it.
 
 With a draft module the step program ends in ``sampling.verify_tokens``
-and ``sampling.draft_tokens`` and returns ``[slots, 2]`` tokens and
-``[slots]`` counts. A row a step takes FOUR uniforms of the request's
-stream, drawn on the host while the feed is built, in the order accept,
-redraw, second position, next draft, whichever of them the step comes to
-use: so the stream's state after a step does not depend on its outcome, and
-a request's tokens are a function of its own logits, temperature and seed.
+and ``sampling.draft_tokens`` (on the chip as ops/pallas/draft_tail.py's two
+kernels, which read each logits row once and keep q in place by slot) and
+returns ``[slots, 2]`` tokens and ``[slots]`` counts. A row a step takes
+FOUR uniforms of the request's stream, drawn on the host while the feed is
+built, in the order accept, redraw, second position, next draft, whichever
+of them the step comes to use: so the stream's state after a step does not
+depend on its outcome, and a request's tokens are a function of its own
+logits, temperature and seed.
 The delivered tokens are distributed exactly as the one-token sampler's
 (the rule is exact); greedy rows accept a draft where it is the model's own
 argmax and deliver the model's argmaxes either way. A journal record of a
@@ -590,7 +592,7 @@ def draft_step(model, kv, weight_quant: str, bucket: int, step_block):
     this same step for a described chip)."""
     import jax.numpy as jnp
 
-    from .sampling import draft_tokens, verify_tokens
+    from ..ops.pallas.draft_tail import draft_next, draft_verify
 
     program, _feeds, _fetches = model.build_draft_program(bucket, kv,
                                                           weight_quant)
@@ -608,17 +610,16 @@ def draft_step(model, kv, weight_quant: str, bucket: int, step_block):
         token = jnp.where(carried, of_slot(last_tokens), feed["tokens"])
         pos = jnp.where(carried, of_slot(spec["pos"]), feed["positions"])
         draft = jnp.where(carried, of_slot(spec["draft"]), 0)
-        q = of_slot(spec["q"])
         table = jnp.repeat(feed["page_table"], 2, axis=0)
         live = feed["page_table"][:, 0] > 0
         env, pools = run_program(step_block, params, pools, {
             "tokens": pairs(token, draft), "positions": pairs(pos, pos + 1),
             "page_table": table, "live": pairs(live, live & carried)})
-        logits = env["logits"].reshape(bucket, 2, -1)
         hidden = env["hidden"].reshape(bucket, 2, -1)
         temperature = feed["sampling"][:, 0]
-        tokens, count = verify_tokens(
-            logits[:, 0], logits[:, 1], q, draft, carried, temperature,
+        # the rule on the logits as the head left them, q read at the slot
+        tokens, count, q_read = draft_verify(
+            env["logits"], spec["q"], slot, draft, carried, temperature,
             feed["sampling"][:, 1:4])
         # the module: rows (pos, pos + 1) with the tokens after them; a
         # first step's (pos - 1, pos), from the prefill's hidden state
@@ -636,22 +637,24 @@ def draft_step(model, kv, weight_quant: str, bucket: int, step_block):
             "page_table": table, "live": pairs(live, live & (newest > 0)),
             "hidden": state.reshape(2 * bucket, -1),
             "pick": 2 * jnp.arange(bucket, dtype=jnp.int32) + newest})
-        next_draft, next_q = draft_tokens(
-            env2["draft_logits"], temperature, feed["sampling"][:, 4])
+        # q written where the slot's last one was read
+        next_draft, q = draft_next(env2["draft_logits"], spec["q"], slot,
+                                   temperature, feed["sampling"][:, 4])
         last = jnp.take_along_axis(tokens, (count - 1)[:, None],
                                    axis=1)[:, 0]
         last_tokens = last_tokens.at[slot].set(last, mode="drop")
         spec = dict(
             spec, pos=spec["pos"].at[slot].set(pos + count, mode="drop"),
-            draft=spec["draft"].at[slot].set(next_draft, mode="drop"),
-            q=spec["q"].at[slot].set(next_q, mode="drop"))
+            draft=spec["draft"].at[slot].set(next_draft, mode="drop"), q=q)
         fetch = jnp.concatenate([
             tokens.reshape(-1), count,
             env["step_counts"].astype(jnp.int32),
             env2["step_counts"].astype(jnp.int32)])
-        kept = {"logits": logits, "draft": draft, "pos": pos,
-                "q": jnp.where(carried[:, None], q, 0.0),
-                "draft_logits": env2["draft_logits"]}
+        # arrays the step has anyway, as it has them (`_keep_step` indexes
+        # them for the rows that asked): the logits [2B, V], q's rows as
+        # the rule read them, in the state's order
+        kept = {"logits": env["logits"], "draft": draft, "pos": pos,
+                "q": q_read, "draft_logits": env2["draft_logits"]}
         return fetch, pools, last_tokens, spec, kept
 
     return step
@@ -748,11 +751,13 @@ class DecodeEngine:
         # which the module's pass of the slot's first step reads
         self._spec: Dict[str, Any] = {}
         if self._draft:
+            from ..ops.pallas.draft_tail import q_state
+
             n = self.config.max_slots
             self._spec = {
                 "pos": jnp.zeros((n,), jnp.int32),
                 "draft": jnp.zeros((n,), jnp.int32),
-                "q": jnp.zeros((n, model_cfg.vocab_size), jnp.float32),
+                "q": q_state(n, model_cfg.vocab_size),
                 "hidden": jnp.zeros((n, model_cfg.hidden_size),
                                     jnp.float32)}
         # the step that was dispatched and whose tokens are not fetched yet
@@ -1869,12 +1874,14 @@ class DecodeEngine:
                    tokens: np.ndarray, took: int):
         """One step's record of a request that asked for them
         (``keep_step_outputs``): what the acceptance rule read and gave."""
+        from ..ops.pallas.draft_tail import vocab_rows
+
         kept = flight.kept
+        logits = np.asarray(kept["logits"][2 * i:2 * i + 2])
         req.step_outputs.append({
             "position": int(kept["pos"][i]), "draft": int(kept["draft"][i]),
-            "had_draft": req.steps > 1,
-            "logits": np.asarray(kept["logits"][i]),
-            "q": np.asarray(kept["q"][i]),
+            "had_draft": req.steps > 1, "logits": logits,
+            "q": vocab_rows(np.asarray(kept["q"][i]), logits.shape[1]),
             "draft_logits": np.asarray(kept["draft_logits"][i]),
             "uniforms": np.array(flight.uniforms[i, 1:], np.float32),
             "tokens": [int(t) for t in tokens], "delivered": took})

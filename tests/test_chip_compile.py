@@ -550,6 +550,39 @@ def test_step_sampler_at_the_xglm_vocabulary(one_chip):
     assert "f64" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("kernel", ["draft_verify", "draft_next"])
+def test_draft_tail_at_the_xing4_vocabulary(one_chip, tpu_mode, kernel):
+    """The drafting step's tail (ops/pallas/draft_tail.py) at the serving
+    cell's 64 slots x 131,072 entries: the logits reach the kernel as a
+    bitcast of the head's own array (no copy, no reshape), q is read and
+    written at the slot (no gather, no scatter, the state aliased through
+    `draft_next`), and no running sum is a loop."""
+    from paddle_tpu.ops.pallas import draft_tail as dt
+
+    b, v = 64, 131072
+    state = (dt.q_state_shape(b, v), F32)
+    if kernel == "draft_verify":
+        fn = dt.draft_verify
+        shapes = (((2 * b, v), F32), state, ((b,), I32), ((b,), I32),
+                  ((b,), jnp.bool_), ((b,), F32), ((b, 3), F32))
+    else:
+        fn = dt.draft_next
+        shapes = (((b, v), F32), state, ((b,), I32), ((b,), F32),
+                  ((b,), F32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=(1,) if kernel == "draft_next"
+                       else ()).lower(*args).compile()
+    text = compiled.as_text()
+    assert kernel in text and "tpu_custom_call" in text
+    assert " while(" not in text
+    assert not re.search(r"= f32\S+ (gather|scatter|copy|reshape)\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    if kernel == "draft_next":
+        assert mem.alias_size_in_bytes >= (b + 1) * v * 4
+
+
 def test_sharded_step_compiles_for_four_chips(topo, tpu_mode):
     """The step jit partitions over a dp=2 x mp=2 mesh of the described
     chips: Mosaic kernels cannot be partitioned automatically, so the

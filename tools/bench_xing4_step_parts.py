@@ -13,9 +13,20 @@ them:
   all of them); ``module``: the draft program (the module's layer over 128
   rows, the head on the 64 picked); ``head_128`` / ``head_64``: the final
   norm and the head's product alone at those rows (inside the two above);
-* ``acceptance``: `sampling.verify_tokens` and `draft_tokens` on [64,
-  131072] logits; ``step``: `serving/decode.py draft_step`, all of it as
-  the engine runs it.
+* ``acceptance``: the step's tail as `draft_step` runs it
+  (ops/pallas/draft_tail.py `draft_verify` and `draft_next`: the rule on
+  [128, 131072] logits, q read and written at the slot, the next draft;
+  under ``PT_PALLAS=off`` the plain form, `sampling.verify_tokens` and
+  `draft_tokens` with q gathered and scattered), ten tails a call so that
+  the host's dispatch is not what is read, and ``hbm_gb``, the bytes a
+  tail moves by the compiled program's ``cost_analysis()``, beside the
+  budget of 0.30 GB; ``step``: `serving/decode.py draft_step`, all of it
+  as the engine runs it.
+
+``--only acceptance step`` times those parts alone. The tail's other form:
+``PT_PALLAS=off python tools/bench_xing4_step_parts.py --only acceptance``
+(every kernel is then the stock lowering, so its ``step`` is not the
+parent's).
 """
 
 import json
@@ -31,12 +42,15 @@ import numpy as np
 
 OUT = os.path.join("chiprun_out", "xing4_step_parts.jsonl")
 CONTEXT = 1500
+TAIL_BUDGET_GB = 0.30
+TAILS_A_CALL = 10
 
 
 def main():
     import paddle_tpu.ops  # noqa: F401
     from benchmark.manifest import Manifest
-    from paddle_tpu.serving import sampling
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas import draft_tail
     from paddle_tpu.serving.decode import draft_step, run_program
     from paddle_tpu.serving.kv_cache import PagedKVCache
     from paddle_tpu.serving.served_model import DRAFT_SPARE_TOKENS
@@ -86,8 +100,12 @@ def main():
                            preferred_element_type=jnp.float32)
         return fn
 
+    only = set(sys.argv[sys.argv.index("--only") + 1:]) \
+        if "--only" in sys.argv else None
+
     def timed(name, fn, feed, donate=True, reps=20):
         nonlocal pools
+        reps = reps if not only or name in only else 1
         jitted = jax.jit(fn, donate_argnums=(1,) if donate else ())
         for _ in range(2):
             out, pools = jitted(params, pools, feed)
@@ -99,14 +117,19 @@ def main():
         emit(name, (time.perf_counter() - t) / reps * 1e3)
         return out
 
-    def emit(name, ms):
-        line = json.dumps({"part": name, "ms": round(ms, 3),
+    def emit(name, ms, **more):
+        if only and name not in only:
+            return
+        line = json.dumps({"part": name, "ms": round(ms, 3), **more,
+                           "kernels": pallas.kernel_mode(),
                            "device": jax.devices()[0].device_kind})
         print(line, flush=True)
         with open(OUT, "a") as f:
             f.write(line + "\n")
 
     def plain(name, fn, *args, reps=20):
+        if only and name not in only:
+            return
         jitted = jax.jit(fn)
         jax.block_until_ready(jitted(*args))
         t = time.perf_counter()
@@ -123,17 +146,43 @@ def main():
         pick=2 * jnp.arange(slots, dtype=jnp.int32) + 1))
     plain("head_128", head("x4_norm_f"), params, hidden)
     plain("head_64", head("x4_mtp_norm"), params, hidden[::2])
-    two = logits.reshape(slots, 2, -1)
     temp = jnp.full((slots,), 2.8, jnp.float32)
     u = jnp.asarray(rng.random_sample((slots, 4)), jnp.float32)
+    by_slot = jnp.asarray(rng.permutation(slots).astype(np.int32))
+    carried = jnp.ones((slots,), bool)
+    q = draft_tail.state_rows(jnp.pad(
+        jax.nn.softmax(logits[1::2] / 2.8, axis=-1), ((0, 1), (0, 0))))
 
-    def acceptance(first, second, module_logits, temp, u):
-        draft, q = sampling.draft_tokens(module_logits, temp, u[:, 3])
-        return sampling.verify_tokens(first, second, q, draft,
-                                      jnp.ones((slots,), bool), temp,
-                                      u[:, :3])
+    def tail(q, logits, module_logits, draft):
+        tokens, count, read = draft_tail.draft_verify(
+            logits, q, by_slot, draft, carried, temp, u[:, :3])
+        draft, q = draft_tail.draft_next(module_logits, q, by_slot, temp,
+                                         u[:, 3])
+        return q, tokens, count, read, draft
 
-    plain("acceptance", acceptance, two[:, 0], two[:, 1], two[:, 1], temp, u)
+    def tails(q, logits, module_logits, draft):
+        def body(_, carry):
+            return tail(carry[0], logits, module_logits, carry[4])
+        return jax.lax.fori_loop(0, TAILS_A_CALL - 1, body, tail(
+            q, logits, module_logits, draft))
+
+    if not only or "acceptance" in only:
+        draft = jnp.asarray(tokens[1::2])
+        cost = jax.jit(tail, donate_argnums=(0,)).lower(
+            q, logits, logits[::2], draft).compile().cost_analysis()
+        jitted = jax.jit(tails, donate_argnums=(0,))
+        q = jitted(q, logits, logits[::2], draft)[0]
+        jax.block_until_ready(q)
+        t = time.perf_counter()
+        for _ in range(10):
+            q = jitted(q, logits, logits[::2], draft)[0]
+        jax.block_until_ready(q)
+        emit("acceptance",
+             (time.perf_counter() - t) / 10 / TAILS_A_CALL * 1e3,
+             hbm_gb=round(cost["bytes accessed"] / 1e9, 3),
+             hbm_budget_gb=TAIL_BUDGET_GB)
+    if only and "step" not in only:
+        return
 
     step = draft_step(model, kv, "none", slots, held_block)
     feed = {"tokens": jnp.asarray(tokens[::2]), "positions": jnp.asarray(pos),
@@ -144,8 +193,7 @@ def main():
     last = jnp.asarray(tokens[::2])
     spec = {"pos": jnp.asarray(pos),
             "draft": jnp.asarray(tokens[1::2]),
-            "q": jax.nn.softmax(two[:, 1] / 2.8, axis=-1),
-            "hidden": hidden[::2]}
+            "q": q, "hidden": hidden[::2]}
     jitted = jax.jit(step, donate_argnums=(1, 4))
     for _ in range(2):
         fetch, pools, last, spec, _ = jitted(params, pools, feed, last, spec)

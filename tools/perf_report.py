@@ -470,6 +470,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.grouped_polyglu_fallbacks",
                "pallas.mhc_dispatches",
                "pallas.mhc_fallbacks",
+               "pallas.draft_tail_dispatches",
+               "pallas.draft_tail_fallbacks",
                "pallas.grouped_swiglu_bwd_dispatches",
                "pallas.grouped_swiglu_bwd_fallbacks",
                "pallas.routed_combine_dispatches",

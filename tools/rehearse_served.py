@@ -135,6 +135,7 @@ def drafting_step(model, kv, eng, cfg, block, bucket, shape):
     of its arguments behind the parameters and the pools."""
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.pallas.draft_tail import q_state_shape
     from paddle_tpu.serving.decode import draft_step
     from paddle_tpu.serving.served_model import DRAFT_SPARE_TOKENS
 
@@ -147,7 +148,7 @@ def drafting_step(model, kv, eng, cfg, block, bucket, shape):
             "carry": shape((bucket, 2), jnp.int32)}
     spec = {"pos": shape((slots,), jnp.int32),
             "draft": shape((slots,), jnp.int32),
-            "q": shape((slots, cfg.vocab_size), jnp.float32),
+            "q": shape(q_state_shape(slots, cfg.vocab_size), jnp.float32),
             "hidden": shape((slots, cfg.hidden_size), jnp.float32)}
     return (draft_step(model, kv, eng["weight_quant"], bucket, block),
             (feed, shape((slots,), jnp.int32), spec))
