@@ -19,6 +19,7 @@ ParallelExecutor (parallel_executor.cc:461), re-designed for XLA:
 from __future__ import annotations
 
 import collections
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -278,6 +279,9 @@ class Executor:
         self._cache: Dict[tuple, _CompiledEntry] = {}
         self._ps_programs: Dict[tuple, bool] = {}
         self._verified: set = set()
+        # (uid, version) of the programs that have run interpreted: the
+        # first run of each leaves a set-up record, a loop's later ones none
+        self._interpreted: set = set()
         # (metrics, device arrays) of async steps' telemetry fetches, not
         # yet on the host
         self._telemetry_pending: collections.deque = collections.deque()
@@ -389,11 +393,22 @@ class Executor:
                                                  fetch_names, scope,
                                                  mesh, in_shardings, phases)
             else:
+                first = (program.uid, program.version)
+                setup = contextlib.nullcontext()
+                if first not in self._interpreted:
+                    self._interpreted.add(first)
+                    setup = telemetry.CompileRecord(
+                        "interpreted", f"{program.uid}v{program.version}")
                 with telemetry.timer("executor.interpret_ms",
                                      span="executor.dispatch",
-                                     compiled=False):
+                                     compiled=False), setup as record:
                     fetched = self._run_interpreted(program, block, feed,
                                                     fetch_names, scope, mesh)
+                    if record is not None:
+                        # jax's durations inside (one small compile an
+                        # op) went to the record's trace_s / compile_s
+                        record.ops = len(block.ops)
+                        record.close()
             if told:
                 self._note_telemetry(program, told, fetched[asked:],
                                      sync_fetch)
@@ -1024,33 +1039,43 @@ class Executor:
         cause = _recompile_cause(key, self._cache)
         telemetry.counter_add("executor.cache_misses", 1)
         t_compile = time.perf_counter()
-        with telemetry.timer(span="executor::compile"):
-            entry = self._compile(program, block, named["feed_names"],
-                                  fetch_names, scope, mesh, in_shardings,
-                                  dict(named["dp_divisibility"]),
-                                  scan_k=scan_k)
-        self._cache[key] = entry
-        state, ro, step = self._gather_state(entry, scope)
-        # per-compile cost/memory capture: the AOT analyses run against
-        # THIS cache entry's lowering before state buffers are donated;
-        # lower() shares the trace cache with the first execution, so
-        # 'cost' level adds ~no work. Degrades by counting
-        # (costmodel.unavailable), never by raising.
-        if costmodel.capture_mode() != "off":
-            entry.cost = costmodel.capture(
-                lambda: entry.jitted.lower(state, ro, feed, step),
-                key_id=costmodel.key_id_for(key), kind="executor",
-                program=f"{program.uid}v{program.version}",
-                steps_per_dispatch=scan_k or 1)
-            # HBM ledger: persistable split of this program's resident
-            # state (params vs optimizer/run state)
-            names = list(entry.state_names) + list(entry.ro_names)
-            vals = [state.get(n, ro.get(n)) for n in names]
-            pb, ob = costmodel.split_persistable_bytes(block, names, vals)
-            costmodel.record_model_bytes(pb, ob)
-        with telemetry.timer(span="executor::run"):
-            fetches, new_state, new_step = self._call(entry, program, state,
-                                                      ro, feed, step)
+        # the set-up record of this program (telemetry.CompileRecord):
+        # from before the entry's construction to the first call's return
+        with telemetry.CompileRecord(
+                "executor", f"{program.uid}v{program.version}",
+                pallas_kernels=named["pallas_kernels"]) as record:
+            record.ops = len(block.ops)
+            with record.phase("build_s"), \
+                    telemetry.timer(span="executor::compile"):
+                entry = self._compile(program, block, named["feed_names"],
+                                      fetch_names, scope, mesh, in_shardings,
+                                      dict(named["dp_divisibility"]),
+                                      scan_k=scan_k)
+            self._cache[key] = entry
+            state, ro, step = self._gather_state(entry, scope)
+            # per-compile cost/memory capture: the AOT analyses run against
+            # THIS cache entry's lowering before state buffers are donated;
+            # lower() shares the trace cache with the first execution, so
+            # 'cost' level adds ~no work. Degrades by counting
+            # (costmodel.unavailable), never by raising.
+            if costmodel.capture_mode() != "off":
+                with record.phase("capture_s"):
+                    entry.cost = costmodel.capture(
+                        lambda: entry.jitted.lower(state, ro, feed, step),
+                        key_id=costmodel.key_id_for(key), kind="executor",
+                        program=f"{program.uid}v{program.version}",
+                        steps_per_dispatch=scan_k or 1)
+                    # HBM ledger: persistable split of this program's
+                    # resident state (params vs optimizer/run state)
+                    names = list(entry.state_names) + list(entry.ro_names)
+                    vals = [state.get(n, ro.get(n)) for n in names]
+                    pb, ob = costmodel.split_persistable_bytes(block, names,
+                                                               vals)
+                    costmodel.record_model_bytes(pb, ob)
+            with telemetry.timer(span="executor::run"):
+                fetches, new_state, new_step = self._call(
+                    entry, program, state, ro, feed, step)
+            setup = record.close()
         self._book(entry, program, scan_k)
         # jax.jit compiles lazily — the first execution carries the trace +
         # XLA compile, so compile wall time is measured through it (and
@@ -1062,16 +1087,15 @@ class Executor:
         mesh_key = named["mesh"]
         telemetry.event(
             "compile", "executor", compile_ms,
-            {"cause": cause, "cache_size": len(self._cache),
-             "program": program.uid, "program_version": program.version,
-             "feed_names": list(named["feed_names"]),
-             "fetch_names": list(fetch_names),
-             "mesh": None if mesh_key is None else list(mesh_key[0]),
-             "dp_divisibility": sorted(named["dp_divisibility"]),
-             "steps_per_dispatch": scan_k or 1,
-             "axis_rules": named["axis_rules"],
-             "zero_stage": named["zero_stage"],
-             "pallas_kernels": named["pallas_kernels"]})
+            dict(setup, cause=cause, cache_size=len(self._cache),
+                 program=program.uid, program_version=program.version,
+                 feed_names=list(named["feed_names"]),
+                 fetch_names=list(fetch_names),
+                 mesh=None if mesh_key is None else list(mesh_key[0]),
+                 dp_divisibility=sorted(named["dp_divisibility"]),
+                 steps_per_dispatch=scan_k or 1,
+                 axis_rules=named["axis_rules"],
+                 zero_stage=named["zero_stage"]))
         telemetry.tick()
         self._write_back(entry, scope, state, fetches, new_state, new_step,
                          scan_k)

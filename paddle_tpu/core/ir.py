@@ -22,13 +22,14 @@ from __future__ import annotations
 import contextlib
 import copy
 import itertools
+import time
 import traceback
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
 
 import numpy as np
 
-from . import unique_name
+from . import telemetry, unique_name
 from .types import VarType, convert_dtype
 
 # Op role classes (reference: framework/op_proto_maker.h OpRole)
@@ -421,7 +422,9 @@ class Block:
             op.attrs["__device__"] = dev
         self.ops.append(op)
         if infer_shape:
+            t0 = time.perf_counter()
             self._infer_op_shapes(op)
+            telemetry.note_infer_shape(time.perf_counter() - t0)
         self.program._bump_version()
         return op
 

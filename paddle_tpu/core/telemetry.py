@@ -22,7 +22,9 @@ JSONL schema (one object per line)::
 kinds emitted by the framework: ``counter`` (value = new cumulative,
 attrs.delta = increment), ``gauge``, ``timer``/``hist`` (value = sample,
 ms for timers), ``compile`` (value = wall ms, attrs.cause = recompile
-cause), ``step`` (hapi per-step metrics), ``metric`` (bench results),
+cause; the attrs also hold the program's set-up record, CompileRecord
+below: build, trace, lower, compile, cache read, capture, first run),
+``step`` (hapi per-step metrics), ``metric`` (bench results),
 ``fallback`` (degraded-path latches), ``fault`` (one injected fault from
 the core/faults.py harness: name = site, value = per-site injection
 count, attrs.exc = raised type — pairs with the ``faults.injected``
@@ -207,6 +209,211 @@ def on_tick(subscriber):
 def tick(event: str = "step"):
     for subscriber in _tick_subscribers:
         subscriber(event)
+
+
+# -- set-up records: one a compiled program ----------------------------------
+# Where a program's first life goes: its construction, jax's trace, the
+# lowering, the backend's compile or the read of the persistent cache, the
+# cost capture and the first execution. Executor._compile_and_run,
+# Executor._run_interpreted (a program's first interpreted run) and
+# DecodeEngine._entry each open one around that whole life and hand it to
+# the ``compile`` event as its attrs. Always made: a handful of clock reads
+# a compiled program, none on a steady-state step.
+
+_COMPILE_RECORD_CAP = 512
+_compile_records: deque = deque(maxlen=_COMPILE_RECORD_CAP)
+_setup_lock = threading.Lock()     # the list and the shape-inference pair
+_open_records = threading.local()  # .stack: the records open on this thread
+_infer_shape = [0.0, 0]            # seconds, calls: the process's lifetime
+_jax_listening = [False]
+
+# jax.monitoring's durations of one compile, by the record's field. They
+# nest (the backend's holds the cache retrieval; a jitted function traced
+# inside another's trace reports its own), so each is stored less what
+# ended inside it: the parts are disjoint.
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s"}
+_PHASES = ("build_s", "trace_s", "lower_s", "compile_s", "cache_read_s",
+           "capture_s", "first_run_s")
+#: a record's fields in seconds that add up over records (the phases tile
+#: ``total_s``; ``infer_shape_s`` is a part of ``build_s``)
+COMPILE_RECORD_SECONDS = ("total_s",) + _PHASES + ("infer_shape_s",)
+
+
+def _open_record():
+    stack = getattr(_open_records, "stack", None)
+    return stack[-1] if stack else None
+
+
+def _on_jax_duration(event, duration, **_):
+    field = _JAX_DURATIONS.get(event)
+    rec = _open_record() if field else None
+    if rec is not None:
+        rec._jax(field, float(duration))
+
+
+def _on_jax_event(event, **_):
+    rec = _open_record()
+    if rec is None:
+        return
+    if event == "/jax/compilation_cache/cache_hits":
+        rec.cache_hit = rec.cache_hit is not False
+    elif event == "/jax/compilation_cache/cache_misses":
+        rec.cache_hit = False
+
+
+class CompileRecord:
+    """One program's first life, on ``time.perf_counter()`` (``t0``/``t1``;
+    every other field in seconds). ``build_s``: the Program's construction
+    and the Python function around it, shape inference (``infer_shape_s``,
+    a part of it) and whatever jax traced for it included. ``trace_s``,
+    ``lower_s``, ``compile_s``, ``cache_read_s``: jax's own phases, disjoint
+    (``compile_s`` is the backend's time less the cache retrieval).
+    ``capture_s``: ``costmodel.capture`` less the jax phases inside it.
+    ``first_run_s``: what is left of ``total_s``: the first execution, its
+    transfers, the rest. So the seven tile ``total_s``. ``cache_hit``: every
+    program of the record was read from jax's persistent cache (True), one
+    was not (False), or the cache said neither (None: it is off, or the
+    program is under its thresholds). A record opened inside another on
+    one thread takes the durations; the outer's parts and ``total_s`` leave
+    the inner's wall time out. Opened by ``with`` on the thread that does
+    the work; ``fields`` ride along as they are (``pallas_kernels``);
+    ``close()`` inside the block ends and keeps it, and a block left by an
+    exception keeps nothing."""
+
+    def __init__(self, kind: str, name: str, **fields):
+        self.kind, self.name, self.fields = kind, name, fields
+        self.ops = 0
+        self.infer_shape_s = 0.0
+        self.infer_shape_calls = 0
+        self.backend_compiles = 0
+        self.cache_hit: Optional[bool] = None
+        for f in _PHASES:
+            setattr(self, f, 0.0)
+        self._region = None      # "build_s" / "capture_s" while inside one
+        self._inside = 0.0       # seconds of the region that are not its own
+        self._nested = 0.0       # records that closed inside this one
+        self._spans: list = []   # (start, end): finished intervals, newest last
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self):
+        if not _jax_listening[0]:
+            with _setup_lock:
+                if not _jax_listening[0]:
+                    import jax.monitoring
+
+                    jax.monitoring.register_event_listener(_on_jax_event)
+                    jax.monitoring.register_event_duration_secs_listener(
+                        _on_jax_duration)
+                    _jax_listening[0] = True
+        stack = getattr(_open_records, "stack", None)
+        if stack is None:
+            stack = _open_records.stack = []
+        stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        # a program that failed on the way leaves no record
+        stack = _open_records.stack
+        if self in stack:
+            stack.remove(self)
+        return False
+
+    @contextlib.contextmanager
+    def phase(self, field: str):
+        """Times ``build_s`` or ``capture_s``: the region's wall time less
+        what jax's phases and inner records took of it."""
+        t, self._region, self._inside = time.perf_counter(), field, 0.0
+        try:
+            yield self
+        finally:
+            own = time.perf_counter() - t - self._inside
+            setattr(self, field, getattr(self, field) + max(own, 0.0))
+            self._region = None
+
+    def _span(self, start: float, end: float) -> float:
+        """Books one finished interval and returns its own part: its
+        length less the intervals that ended inside it."""
+        own = end - start
+        while self._spans and self._spans[-1][1] > start:
+            inner = self._spans.pop()
+            own -= inner[1] - inner[0]
+        self._spans.append((start, end))
+        return max(own, 0.0)
+
+    def _jax(self, field: str, duration: float):
+        if field == "compile_s":
+            self.backend_compiles += 1
+        if self._region == "build_s":
+            return      # a trace for a shape: the build's own seconds
+        now = time.perf_counter()
+        own = self._span(now - duration, now)
+        setattr(self, field, getattr(self, field) + own)
+        if self._region is not None:
+            self._inside += own
+
+    def close(self) -> Dict[str, Any]:
+        """Ends the record at the first execution's return, keeps it in
+        the list and returns it as the ``compile`` event's attrs."""
+        self.t1 = time.perf_counter()
+        self.__exit__()
+        wall = self.t1 - self.t0
+        total = wall - self._nested
+        self.first_run_s = max(0.0, total - sum(
+            getattr(self, f) for f in _PHASES if f != "first_run_s"))
+        doc = {"kind": self.kind, "name": self.name, "ops": int(self.ops),
+               "total_s": total, "infer_shape_s": self.infer_shape_s,
+               "infer_shape_calls": self.infer_shape_calls,
+               "backend_compiles": self.backend_compiles,
+               "cache_hit": self.cache_hit, "t0": self.t0, "t1": self.t1}
+        doc.update((f, getattr(self, f)) for f in _PHASES)
+        doc.update(self.fields)
+        outer = _open_record()
+        if outer is not None:
+            outer._span(self.t0, self.t1)
+            outer._nested += wall
+            if outer._region is not None:
+                outer._inside += wall
+        with _setup_lock:
+            _compile_records.append(doc)
+        return doc
+
+
+def note_infer_shape(seconds: float):
+    """One shape inference of ``Block.append_op``: into the process's
+    pair and into the record open on this thread, if there is one."""
+    with _setup_lock:
+        _infer_shape[0] += seconds
+        _infer_shape[1] += 1
+    rec = _open_record()
+    if rec is not None:
+        rec.infer_shape_s += seconds
+        rec.infer_shape_calls += 1
+
+
+def infer_shape_totals():
+    """(seconds, calls) of every shape inference since the process began:
+    inside a record (then a part of its ``build_s``) or before one (a
+    trainer's Program is built by the user's script). ``reset()`` leaves
+    it alone."""
+    with _setup_lock:
+        return _infer_shape[0], _infer_shape[1]
+
+
+def compile_records() -> List[Dict[str, Any]]:
+    """The newest 512 set-up records, oldest first. ``reset()`` does not
+    clear them: set-up is over when a run resets its window's counters."""
+    with _setup_lock:
+        return [dict(r) for r in _compile_records]
+
+
+def clear_compile_records():
+    with _setup_lock:
+        _compile_records.clear()
 
 
 class _Hist:
@@ -547,7 +754,10 @@ class TelemetryRegistry:
             return {n: list(h.buckets) for n, h in self._hists.items()}
 
     def reset(self):
-        """Clear all in-memory aggregates (tests). Leaves the sink alone."""
+        """Clear all in-memory aggregates (tests, a benchmark's window).
+        Leaves the sink alone, and the set-up records and the shape-
+        inference pair (``compile_records()``, ``infer_shape_totals()``):
+        they are read after the window that a reset opens."""
         with self._lock:
             self._counters.clear()
             self._gauges.clear()

@@ -765,6 +765,9 @@ class DecodeEngine:
         # the prefill that was dispatched and whose request is not seated
         self._prefilled: Optional[_Prefilled] = None
         self._entries: Dict[Any, Any] = {}   # (phase, bucket) -> jitted fn
+        # the set-up record of each entry (telemetry.CompileRecord), as
+        # stats()["warmup"] shows them
+        self._warmup: List[Dict[str, Any]] = []
         self._thread: Optional[threading.Thread] = None
         self.health = HealthState()
         self.version = int(version)
@@ -878,7 +881,8 @@ class DecodeEngine:
 
     def stats(self) -> Dict[str, Any]:
         """decode.* counters + KV pool accounting + latency percentiles
-        + rolling-window token rate — the /v1/stats "decode" payload."""
+        + rolling-window token rate + ``warmup`` (where the engine's start
+        went, by program) — the /v1/stats "decode" payload."""
         c = telemetry.counters()
         out = {k.split(".", 1)[1]: int(v) for k, v in c.items()
                if k.startswith("decode.") and isinstance(v, (int, float))}
@@ -931,6 +935,14 @@ class DecodeEngine:
                 out["tokens_per_row_step"] = {"avg": per["avg"],
                                               "p50": per["p50"]}
         out.update(admission_cost(c, hists))
+        # where this engine's start went: the set-up record of each of its
+        # programs, and their sum by phase
+        programs = list(self._warmup)
+        if programs:
+            out["warmup"] = {
+                "programs": programs,
+                "total": {f: round(sum(r[f] for r in programs), 3)
+                          for f in telemetry.COMPILE_RECORD_SECONDS}}
         win = telemetry.windowed()
         wout = {"seconds": win["window_s"]}
         for name, key in (("decode.tokens", "tokens_per_s"),
@@ -1002,119 +1014,137 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        from ..ops import pallas as _pallas
         from .sampling import sample_tokens
 
-        cc = self.config
-        build = {"step": self.model.build_step_program,
-                 "chunk": self.model.build_chunk_prefill_program,
-                 "prefill": self.model.build_prefill_program}[phase]
-        program, feeds, fetches = build(bucket, self.kv, cc.weight_quant)
-        # a program's arguments are part of its compiled form: it is fed
-        # exactly the names its builder lists (and the sampler's)
-        # (a step's `state_slots` are `carry`'s slots, taken on the device)
-        by_slot = phase == "step" and "state_slots" in feeds
-        self._feed_names[key] = tuple(
-            n for n in feeds if not (by_slot and n == "state_slots")) + (
-            ("sampling", "carry") if phase == "step" else ())
-        block = program.global_block()
-        counted = "step_counts" in fetches
-        run = functools.partial(run_program, block)
-
-        def prefill(params, pools, feed):
-            env, pools = run(params, pools, feed)
-            return env["logits"], pools
-
-        def step(params, pools, feed, last_tokens):
-            slot, carried = feed["carry"][:, 0], feed["carry"][:, 1]
-            # a padding row names no slot (max_slots): it reads the last
-            # one, unused, and its token is dropped from the scatter
-            tokens = jnp.where(carried > 0,
-                               last_tokens.at[slot].get(mode="clip"),
-                               feed["tokens"])
-            feed = dict(feed, tokens=tokens)
-            if by_slot:
-                # a model with per-slot state finds a row's state by the
-                # slot its request keeps; a padding row's is the scratch
-                # slot, max_slots
-                feed["state_slots"] = slot
-            env, pools = run(params, pools, feed)
-            chosen = sample_tokens(env["logits"], feed["sampling"][:, 0],
-                                   feed["sampling"][:, 1])
-            last_tokens = last_tokens.at[slot].set(chosen, mode="drop")
-            if counted:
-                # the model's counters ride behind the tokens: one int32
-                # vector, one fetch
-                chosen = jnp.concatenate(
-                    [chosen, env["step_counts"].astype(jnp.int32)])
-            return chosen, pools, last_tokens
-
-        donate = (1,)
-        if self._draft:
-            donate = (1, 4) if phase == "step" else (1, 3)
-            if phase == "step":
-                self._feed_names[key] = ("tokens", "positions", "page_table",
-                                         "sampling", "carry")
-                step = self._draft_step(bucket, block)
-            else:
-                self._feed_names[key] += ("state_slots",)
-
-                def prefill(params, pools, feed, spec):
-                    # the module's rows were written beside the layers';
-                    # the last position's hidden state waits in the slot
-                    # for the first step's module pass
-                    env, pools = run(params, pools, feed)
-                    hidden = spec["hidden"].at[feed["state_slots"]].set(
-                        env["hidden"], mode="drop")
-                    return env["logits"], pools, dict(spec, hidden=hidden)
-
-        fn = step if phase == "step" else prefill
-
-        # the program's own name in the profiler's trace and in the compile
-        # cache's key: decode_step_b8, prefill_p256, chunk_p128
-        fn.__name__ = fn.__qualname__ = {
-            "step": "decode_step_b", "prefill": "prefill_p",
-            "chunk": "chunk_p"}[phase] + str(bucket)
-
-        from ..ops import pallas as _pallas
-
-        entry = jax.jit(fn, donate_argnums=donate)
-        self._entries[key] = entry
-        t0 = time.perf_counter()
-        args = (self._zero_feed(phase, bucket),) + (
-            (jnp.zeros_like(self._last_tokens),) if phase == "step" else ())
-        if self._draft:
-            args += (self._spec,)
+        # the program's own name in the profiler's trace, in the compile
+        # cache's key and on its set-up record: decode_step_b8,
+        # prefill_p256, chunk_p128
+        name = {"step": "decode_step_b", "prefill": "prefill_p",
+                "chunk": "chunk_p"}[phase] + str(bucket)
         # the Pallas kernel fingerprint (PT_PALLAS mode + tile/chunk
         # geometry) keys the cost capture so flops/bytes attribute to
         # the kernel VARIANT actually compiled — the roofline verdict of
         # the stock gather+einsum lowering and the paged kernel are
         # different programs, not one blurred row
         pallas_fp = _pallas.kernels_fingerprint()
-        if costmodel.capture_mode() != "off":
-            costmodel.capture(
-                lambda: entry.lower(self._params, dict(self._pools), *args),
-                key_id=costmodel.key_id_for((phase, bucket,
-                                             cc.weight_quant, pallas_fp)),
-                kind="decode", program=f"{phase}_b{bucket}")
-        # compile through a throwaway execution on zero feeds (the
-        # predictor's measure-through-first-run discipline), on the
-        # engine's OWN pools, taken back as the program returns them
-        # (donation consumes whatever is passed in): a zero feed's page
-        # tables name the scratch page 0 alone, so no request's page is
-        # written, and no second pool is held beside the first (3.4 GB of
-        # latent pages beside 7 GB of weights did not fit twice)
-        out = entry(self._params, self._pools, *args)
-        self._pools = out[1]
-        if self._draft:     # donated like the pools: a zero feed names no slot
-            self._spec = out[3 if phase == "step" else 2]
-        ms = round((time.perf_counter() - t0) * 1e3, 3)
-        telemetry.counter_add("decode.compiles", 1)
-        telemetry.event("compile", "decode", ms,
-                        {"cause": "decode_bucket", "phase": phase,
-                         "bucket": bucket,
-                         "pallas_kernels": pallas_fp,
-                         "cache_size": len(self._entries)})
-        return entry
+        # the set-up record (telemetry.CompileRecord) of this program:
+        # from before its construction to the throw-away run's return
+        with telemetry.CompileRecord("decode", name,
+                                      pallas_kernels=pallas_fp) as record:
+            with record.phase("build_s"):
+                cc = self.config
+                build = {"step": self.model.build_step_program,
+                         "chunk": self.model.build_chunk_prefill_program,
+                         "prefill": self.model.build_prefill_program}[phase]
+                program, feeds, fetches = build(bucket, self.kv,
+                                                cc.weight_quant)
+                # a program's arguments are part of its compiled form: it
+                # is fed exactly the names its builder lists (and the
+                # sampler's) (a step's `state_slots` are `carry`'s slots,
+                # taken on the device)
+                by_slot = phase == "step" and "state_slots" in feeds
+                self._feed_names[key] = tuple(
+                    n for n in feeds
+                    if not (by_slot and n == "state_slots")) + (
+                    ("sampling", "carry") if phase == "step" else ())
+                block = program.global_block()
+                counted = "step_counts" in fetches
+                run = functools.partial(run_program, block)
+
+                def prefill(params, pools, feed):
+                    env, pools = run(params, pools, feed)
+                    return env["logits"], pools
+
+                def step(params, pools, feed, last_tokens):
+                    slot, carried = feed["carry"][:, 0], feed["carry"][:, 1]
+                    # a padding row names no slot (max_slots): it reads the
+                    # last one, unused, and its token is dropped from the
+                    # scatter
+                    tokens = jnp.where(carried > 0,
+                                       last_tokens.at[slot].get(mode="clip"),
+                                       feed["tokens"])
+                    feed = dict(feed, tokens=tokens)
+                    if by_slot:
+                        # a model with per-slot state finds a row's state
+                        # by the slot its request keeps; a padding row's is
+                        # the scratch slot, max_slots
+                        feed["state_slots"] = slot
+                    env, pools = run(params, pools, feed)
+                    chosen = sample_tokens(env["logits"],
+                                           feed["sampling"][:, 0],
+                                           feed["sampling"][:, 1])
+                    last_tokens = last_tokens.at[slot].set(chosen,
+                                                           mode="drop")
+                    if counted:
+                        # the model's counters ride behind the tokens: one
+                        # int32 vector, one fetch
+                        chosen = jnp.concatenate(
+                            [chosen, env["step_counts"].astype(jnp.int32)])
+                    return chosen, pools, last_tokens
+
+                donate = (1,)
+                if self._draft:
+                    donate = (1, 4) if phase == "step" else (1, 3)
+                    if phase == "step":
+                        self._feed_names[key] = (
+                            "tokens", "positions", "page_table", "sampling",
+                            "carry")
+                        step = self._draft_step(bucket, block)
+                    else:
+                        self._feed_names[key] += ("state_slots",)
+
+                        def prefill(params, pools, feed, spec):
+                            # the module's rows were written beside the
+                            # layers'; the last position's hidden state
+                            # waits in the slot for the first step's module
+                            # pass
+                            env, pools = run(params, pools, feed)
+                            hidden = spec["hidden"].at[
+                                feed["state_slots"]].set(env["hidden"],
+                                                         mode="drop")
+                            return (env["logits"], pools,
+                                    dict(spec, hidden=hidden))
+
+                fn = step if phase == "step" else prefill
+                fn.__name__ = fn.__qualname__ = name
+                entry = jax.jit(fn, donate_argnums=donate)
+                self._entries[key] = entry
+                record.ops = len(block.ops)
+            t0 = time.perf_counter()
+            args = (self._zero_feed(phase, bucket),)
+            if phase == "step":
+                args += (jnp.zeros_like(self._last_tokens),)
+            if self._draft:
+                args += (self._spec,)
+            if costmodel.capture_mode() != "off":
+                with record.phase("capture_s"):
+                    costmodel.capture(
+                        lambda: entry.lower(self._params, dict(self._pools),
+                                            *args),
+                        key_id=costmodel.key_id_for(
+                            (phase, bucket, cc.weight_quant, pallas_fp)),
+                        kind="decode", program=f"{phase}_b{bucket}")
+            # compile through a throwaway execution on zero feeds (the
+            # predictor's measure-through-first-run discipline), on the
+            # engine's OWN pools, taken back as the program returns them
+            # (donation consumes whatever is passed in): a zero feed's page
+            # tables name the scratch page 0 alone, so no request's page is
+            # written, and no second pool is held beside the first (3.4 GB of
+            # latent pages beside 7 GB of weights did not fit twice)
+            out = entry(self._params, self._pools, *args)
+            self._pools = out[1]
+            if self._draft:
+                # donated like the pools: a zero feed names no slot
+                self._spec = out[3 if phase == "step" else 2]
+            setup = record.close()
+            ms = round((time.perf_counter() - t0) * 1e3, 3)
+            telemetry.counter_add("decode.compiles", 1)
+            telemetry.event("compile", "decode", ms,
+                            dict(setup, cause="decode_bucket", phase=phase,
+                                 bucket=bucket, cache_size=len(self._entries)))
+            self._warmup.append(setup)
+            return entry
 
     def _draft_step(self, bucket: int, step_block):
         """`draft_step` for this engine's model (a method so that a check

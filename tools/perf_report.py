@@ -10,6 +10,10 @@ Renders the structured run log written by ``paddle_tpu.core.telemetry``
 * every compile event with its wall time and recompile CAUSE (which
   cache-key component changed: program / program_version / feed_names /
   fetch_names / mesh / dp_divisibility);
+* set-up by program: each compiled program's first life as its compile
+  event carries it (telemetry.CompileRecord): build with its shape
+  inference, jax's trace and lowering, the backend's compile or the read
+  of the persistent cache (hit / miss), the cost capture, the first run;
 * counter deltas over the log (compiles, cache hits, donation copies,
   feed/fetch bytes, RPC traffic) and final gauges;
 * a fused-dispatch section when the run used K-step pipelined execution
@@ -119,6 +123,14 @@ def _pct(sorted_vals, q):
     return sorted_vals[i]
 
 
+# a program's set-up record as its ``compile`` event carries it
+# (paddle_tpu/core/telemetry.py CompileRecord); the table's columns are
+# the seconds, from "total_s" on
+SETUP_FIELDS = ("name", "kind", "ops", "cache_hit", "total_s", "build_s",
+                "infer_shape_s", "trace_s", "lower_s", "compile_s",
+                "cache_read_s", "capture_s", "first_run_s")
+
+
 def summarize_log(recs, malformed=0):
     timers = defaultdict(list)
     hists = defaultdict(list)
@@ -156,7 +168,11 @@ def summarize_log(recs, malformed=0):
                              "cause": attrs.get("cause"),
                              "cache_size": attrs.get("cache_size"),
                              "feed_names": attrs.get("feed_names"),
-                             "fetch_names": attrs.get("fetch_names")})
+                             "fetch_names": attrs.get("fetch_names"),
+                             # the program's set-up record (telemetry.
+                             # CompileRecord), where the log carries it
+                             "setup": {k: attrs.get(k) for k in SETUP_FIELDS}
+                             if "total_s" in attrs else None})
         elif kind == "counter":
             if attrs.get("set"):
                 counter_last[name] = v
@@ -984,6 +1000,20 @@ def render(s, out=sys.stdout):
             ms = c.get("ms")
             w(f"{off:>8.2f}  {ms if ms is not None else '?':>10}  "
               f"{c.get('cache_size') or '?':>5}  {c.get('cause')}\n")
+
+    setups = [c["setup"] for c in s["compiles"] if c.get("setup")]
+    if setups:
+        w(f"\n-- set-up by program (s): {len(setups)} --\n")
+        cols = SETUP_FIELDS[4:]
+        w(f"{'program':<24}{'ops':>6}" + "".join(
+            f"{c[:-2]:>12}" for c in cols) + "  cache\n")
+        total = {c: sum(r.get(c) or 0.0 for r in setups) for c in cols}
+        total.update(name="sum", ops=sum(r.get("ops") or 0 for r in setups))
+        for r in setups + [total]:
+            hit = {True: "hit", False: "miss"}.get(r.get("cache_hit"), "-")
+            w(f"{str(r.get('name'))[:23]:<24}{r.get('ops') or 0:>6}"
+              + "".join(f"{r.get(c) or 0.0:>12.3f}" for c in cols)
+              + f"  {hit}\n")
 
     if s.get("fused"):
         f = s["fused"]
