@@ -22,9 +22,12 @@ K^-0.5; v [V]; S [K, V]; value heads ``r i .. r i + r - 1`` read key head
 * whole prompt: `gated_delta_chunk_scan`, the chunked (WY) form: inside a
   chunk of C tokens the rule's C rank-one corrections are solved at once
   (a unit lower-triangular system, by forward substitution), a chunk's
-  state handed to the next; it WRITES the slot's state, overwriting what
-  its last owner left. Past the prompt's length ``g = 0`` and ``beta = 0``:
-  a padded bucket's tail neither decays nor feeds the state.
+  state handed to the next (ops/pallas/gated_delta_chunk_scan.py on the
+  chip: the solve and the scan over chunks in VMEM; its stock lowering,
+  two sequential XLA loops, is the oracle and counted fallback); it
+  WRITES the slot's state, overwriting what its last owner left. Past the
+  prompt's length ``g = 0`` and ``beta = 0``: a padded bucket's tail
+  neither decays nor feeds the state.
 
 State arrays: ``ssm_state_<l>`` [slots + 1, value heads, K, V] float32 (K
 on sublanes, V on lanes: v, u and o are lane rows as the projections leave
@@ -136,80 +139,6 @@ def gated_delta_state_update_op(ins, attrs):
     return {"Y": y.reshape(y.shape[0], nv * dv), "StateOut": state}
 
 
-def chunk_delta_rule(q, k, v, g, beta, chunk):
-    """The chunked (WY) form of the rule from a zero state. q, k [B, S, H,
-    K], v [B, S, H, V], g and beta [B, S, H] (both 0 where a position is
-    padding) -> (o [B, S, H, V], the state after the last position [B, H,
-    K, V]). Float32 at 'highest'.
-
-    Inside a chunk, with G the running sum of g, D[l, s] = exp(G_l - G_s)
-    for s <= l, and N = strictly lower (beta k k^T * D): the C corrected
-    values are T (beta v) and the keys that read the carried state
-    T (beta k exp(G)), T = (I + N)^-1, a unit lower-triangular inverse made
-    row by row (forward substitution: stable whatever the keys' overlap,
-    where the nilpotent series is not)."""
-    import jax
-    import jax.numpy as jnp
-
-    hi = jax.lax.Precision.HIGHEST
-    b, s, h, kd = q.shape
-    vd = v.shape[-1]
-    ln = min(int(chunk), s)
-    if s % ln:
-        raise ValueError(f"prompt length {s} is no multiple of the chunk "
-                         f"{ln}")
-    nc = s // ln
-
-    def chunks(x):          # [B, S, H, ...] -> [B, H, nc, ln, ...]
-        x = x.reshape((b, nc, ln) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-
-    qc, kc, vc = chunks(q), chunks(k), chunks(v)
-    gc = jnp.cumsum(chunks(g), axis=-1)              # [B, H, nc, ln], <= 0
-    bc = chunks(beta)
-    lower = jnp.tril(jnp.ones((ln, ln), bool))
-    seg = gc[..., :, None] - gc[..., None, :]
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
-    k_beta = kc * bc[..., None]
-    n = jnp.einsum("bhclk,bhcsk->bhcls", k_beta, kc, precision=hi) * decay
-    n = jnp.where(jnp.tril(jnp.ones((ln, ln), bool), -1), n, 0.0)
-
-    def solve_row(i, t):
-        # rows < i of T are final; N[i, j >= i] is 0
-        row = jax.lax.dynamic_slice_in_dim(n, i, 1, axis=-2)
-        new = jnp.einsum("bhcls,bhcsj->bhclj", row, t, precision=hi)
-        old = jax.lax.dynamic_slice_in_dim(t, i, 1, axis=-2)
-        return jax.lax.dynamic_update_slice_in_dim(t, old - new, i, axis=-2)
-
-    eye = jnp.broadcast_to(jnp.eye(ln, dtype=jnp.float32), n.shape)
-    t = jax.lax.fori_loop(1, ln, solve_row, eye)
-    u = jnp.einsum("bhcls,bhcsv->bhclv", t, vc * bc[..., None],
-                   precision=hi)
-    w = jnp.einsum("bhcls,bhcsk->bhclk", t,
-                   k_beta * jnp.exp(gc)[..., None], precision=hi)
-    qk = jnp.einsum("bhclk,bhcsk->bhcls", qc, kc, precision=hi) * decay
-    q_in = qc * jnp.exp(gc)[..., None]               # reads the carried state
-    k_out = kc * jnp.exp(gc[..., -1:] - gc)[..., None]   # decays to the end
-    whole = jnp.exp(gc[..., -1])                     # [B, H, nc]
-
-    def carry(state, c):
-        u_c, w_c, qk_c, q_c, k_c, whole_c = c
-        v_new = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, state,
-                                 precision=hi)
-        o_c = jnp.einsum("bhlk,bhkv->bhlv", q_c, state, precision=hi) \
-            + jnp.einsum("bhls,bhsv->bhlv", qk_c, v_new, precision=hi)
-        state = state * whole_c[..., None, None] \
-            + jnp.einsum("bhlk,bhlv->bhkv", k_c, v_new, precision=hi)
-        return state, o_c
-
-    last, o = jax.lax.scan(
-        carry, jnp.zeros((b, h, kd, vd), jnp.float32),
-        tuple(jnp.moveaxis(x, 2, 0) for x in (u, w, qk, q_in, k_out, whole)))
-    # [nc, B, H, ln, V] -> [B, S, H, V]
-    o = jnp.moveaxis(o, 0, 2)
-    return jnp.moveaxis(o, 1, 3).reshape(b, s, h, vd), last
-
-
 @register_op("gated_delta_chunk_scan",
              required_attrs=_GDN_ATTRS + ("chunk",))
 def gated_delta_chunk_scan_op(ins, attrs):
@@ -217,11 +146,15 @@ def gated_delta_chunk_scan_op(ins, attrs):
     `chunk`, from a zero state, and the slot's state WRITTEN with the state
     after the prompt's last real token (``g = 0`` and ``beta = 0`` past
     ``Lengths``). Q, K [B, S, nk x dk], V [B, S, nv x dv], A, B [B, S, nv]
-    raw, Lengths [B], Slots [B] -> Y [B, S, nv x dv], StateOut."""
+    raw, Lengths [B], Slots [B] -> Y [B, S, nv x dv], StateOut. The kernel
+    under the PT_PALLAS dispatch (ops/pallas/gated_delta_chunk_scan.py);
+    'off' and untileable shapes take the counted stock lowering."""
     import jax.numpy as jnp
 
-    # trace-time, as the kernels' dispatch counters are: the scan is XLA
-    # products, so there is no fallback to count beside it
+    from .pallas.gated_delta_chunk_scan import gated_delta_chunk_scan
+
+    # trace-time, as the kernels' dispatch counters are (which beside this
+    # one say whether the kernel or its stock form was traced)
     telemetry.counter_add("ops.gated_delta_chunk_scan_dispatches", 1)
     nk, dk, nv, dv = _gdn_sizes(attrs)
     q, k, v, g, beta = delta_rule_terms(
@@ -233,9 +166,9 @@ def gated_delta_chunk_scan_op(ins, attrs):
     lengths = ins["Lengths"][0].reshape(-1).astype(jnp.int32)
     real = (jnp.arange(s, dtype=jnp.int32)[None, :]
             < lengths[:, None])[..., None]
-    y, last = chunk_delta_rule(q, k, v, jnp.where(real, g, 0.0),
-                               jnp.where(real, beta, 0.0),
-                               int(attrs["chunk"]))
+    y, last = gated_delta_chunk_scan(
+        q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0),
+        int(attrs["chunk"]), heads_per_key=nv // nk)
     return {"Y": y.reshape(b, s, nv * dv),
             "StateOut": pool.at[slots].set(last.astype(pool.dtype))}
 

@@ -239,6 +239,29 @@ def test_gated_delta_state_update_at_the_qwen3_next_share(one_chip,
     assert mem.temp_size_in_bytes < state // 8
 
 
+@pytest.mark.parametrize("s", [1024, 16384])
+def test_gated_delta_chunk_scan_at_the_qwen3_next_share(one_chip, tpu_mode,
+                                                        s):
+    """A whole prompt of the shortest and of the longest bucket, 8 value
+    heads on 4 key heads x [128, 128], chunks of 64, float32 'highest':
+    the kernel, and neither of the stock form's two loops beside it."""
+    from paddle_tpu.core import telemetry
+    from paddle_tpu.ops.pallas.gated_delta_chunk_scan import \
+        gated_delta_chunk_scan
+
+    telemetry.reset()
+    shapes = [((1, s, 8, 128), F32)] * 3 + [((1, s, 8), F32)] * 2
+    args = [jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+            for shape, d in shapes]
+    compiled = jax.jit(lambda *a: gated_delta_chunk_scan(
+        *a, 64, heads_per_key=2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_chunk_scan" in text
+    assert "while" not in text
+    assert telemetry.counter_get(
+        "pallas.gated_delta_chunk_scan_fallbacks") == 0
+
+
 @pytest.mark.parametrize("n", [384, 81984])
 def test_grouped_swiglu_at_the_qwen3_next_share(one_chip, tpu_mode, n):
     """A step's sorted rows (64 rows x top-10, a quarter held) and the

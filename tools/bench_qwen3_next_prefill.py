@@ -3,11 +3,14 @@ share (PERF.md, PR 45): what a bucket's `while`s and `fusion`s hold.
 
     chiprun -- python tools/bench_qwen3_next_prefill.py [BUCKET ...]
     chiprun -- python tools/bench_qwen3_next_prefill.py --attention
+    chiprun -- python tools/bench_qwen3_next_prefill.py --delta-rule
 
 For each padded bucket (4096, 8192 and 16384 by default), ms a call of: the
-chunked gated delta rule of one layer (`chunk_delta_rule`, 8 value heads x
-[128, 128], chunks of 64) and its two sequential parts alone (the
-row-by-row triangular solve; the scan over chunks), whole-prompt attention
+chunked gated delta rule of one layer as the op dispatches it
+(`gated_delta_chunk_scan`, 8 value heads on 4 key heads x [128, 128],
+chunks of 64: the kernel on the chip) beside its stock form and the stock
+form's two sequential parts alone (the row-by-row triangular solve; the
+scan over chunks), whole-prompt attention
 of one layer (`gqa_prefill_attention`, 4 query heads on one K/V head of
 256, bfloat16 products) in both of the op's forms (`attention_alone`:
 `attention_ms` the flash forward kernel, `attention_stock_ms` the XLA
@@ -29,6 +32,14 @@ heads of 256 and Trinity's 6 + 1 heads of 128 with its window of 4,096 and
 without): the table `llm_ops.GQA_PREFILL_KERNEL_FROM` was set from, a line
 a shape in ``chiprun_out/gqa_prefill_attention_bench.jsonl`` (~1.5 min of
 the chip).
+
+`--delta-rule [chunks_a_step ...]` times the rule alone at the cell's five
+buckets: the kernel (`ops/pallas/gated_delta_chunk_scan.py`) beside the
+stock form, ms a layer (`kernel_padded_ms`: the same bucket holding a
+prompt of 5/8 of it), and the largest difference between the two; each
+`chunks_a_step` (the kernel's tile constant; by default the one it ships
+with) is a column. A line a bucket in
+``chiprun_out/gated_delta_chunk_scan_bench.jsonl`` (~2 min of the chip).
 """
 
 import json
@@ -44,6 +55,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import registry, telemetry
 from paddle_tpu.ops import linear_attention_ops as la
 from paddle_tpu.ops import llm_ops
+from paddle_tpu.ops.pallas import gated_delta_chunk_scan as gdc
 from paddle_tpu.ops.pallas import gated_delta_state_update as gdu
 from paddle_tpu.ops.pallas import kernel_mode
 from paddle_tpu.parallel.moe import routed_experts_share
@@ -51,6 +63,8 @@ from paddle_tpu.parallel.moe import routed_experts_share
 from bench_routed_train import combine_alone, plan_alone, spread_alone
 
 H, DK, DV, CHUNK = 8, 128, 128, 64
+KEY_HEADS = 4
+BUCKETS = (1024, 2048, 4096, 8192, 16384)
 HIDDEN, EXPERTS, HELD, WIDTH, TOP_K = 2048, 512, 128, 512, 10
 # (query heads, K/V heads, head, window) -> the padded lengths timed
 ATTENTION_SHAPES = {(4, 1, 256, 0): (2048, 4096, 8192, 16384),
@@ -121,17 +135,67 @@ def attention_table():
 
 
 def rule_inputs(key, s):
+    """A layer's terms as `delta_rule_terms` leaves them: each key head's q
+    and k repeated over its value heads."""
     ks = jax.random.split(key, 5)
-    q = la._l2norm(jax.random.normal(ks[0], (1, s, H, DK))) * DK ** -0.5
-    k = la._l2norm(jax.random.normal(ks[1], (1, s, H, DK)))
+    r = H // KEY_HEADS
+    q = la._l2norm(jax.random.normal(ks[0], (1, s, KEY_HEADS, DK))) \
+        * DK ** -0.5
+    k = la._l2norm(jax.random.normal(ks[1], (1, s, KEY_HEADS, DK)))
+    q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
     v = jax.random.normal(ks[2], (1, s, H, DV))
     g = -0.05 * jax.random.uniform(ks[3], (1, s, H))
     beta = jax.random.uniform(ks[4], (1, s, H))
     return q, k, v, g, beta
 
 
+def rule_kernel(*terms):
+    return gdc.gated_delta_chunk_scan(*terms, CHUNK,
+                                      heads_per_key=H // KEY_HEADS)
+
+
+def delta_rule_table(steps):
+    """The rule alone, kernel beside stock, at the cell's buckets."""
+    shipped = gdc.CHUNKS_A_STEP
+    steps = steps or [shipped]
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/gated_delta_chunk_scan_bench.jsonl", "w") as out:
+        for s in BUCKETS:
+            terms = rule_inputs(jax.random.fold_in(jax.random.PRNGKey(0), s),
+                                s)
+            stock = jax.jit(lambda *a: gdc.stock_gated_delta_chunk_scan(
+                *a, CHUNK))
+            line = {"piece": "delta rule alone", "bucket": s,
+                    "device": jax.devices()[0].device_kind,
+                    "mode": kernel_mode(),
+                    "stock_ms": ms_a_call(stock, terms)}
+            want = stock(*terms)
+            for step in steps:
+                gdc.CHUNKS_A_STEP = step
+                sent = telemetry.counter_get(
+                    "pallas.gated_delta_chunk_scan_dispatches")
+                fn = jax.jit(lambda *a: rule_kernel(*a))    # a trace a column
+                got = fn(*terms)
+                line[f"kernel_ms_{step}"] = ms_a_call(fn, terms, 10)
+                line[f"dispatched_{step}"] = telemetry.counter_get(
+                    "pallas.gated_delta_chunk_scan_dispatches") > sent
+                line[f"max_abs_diff_{step}"] = max(
+                    float(jnp.max(jnp.abs(a - b)))
+                    for a, b in zip(got, want))
+                # a prompt of 5/8 of the bucket: the tail's grid steps only
+                # read the state
+                line[f"kernel_padded_ms_{step}"] = ms_a_call(
+                    fn, terms[:3] + tuple(
+                        x * (jnp.arange(s) < s * 5 // 8)[None, :, None]
+                        for x in terms[3:]), 10)
+            gdc.CHUNKS_A_STEP = shipped
+            line["max_abs"] = float(jnp.max(jnp.abs(want[1])))
+            print(json.dumps(line), flush=True)
+            out.write(json.dumps(line) + "\n")
+
+
 def solve_alone(n):
-    """The forward substitution of `chunk_delta_rule` over [1, H, nc, C, C]."""
+    """The forward substitution of the stock rule over [1, H, nc, C, C]."""
     ln = n.shape[-1]
 
     def row(i, t):
@@ -146,7 +210,7 @@ def solve_alone(n):
 
 
 def scan_alone(u, w, q, k):
-    """The carry over chunks of `chunk_delta_rule`: four products a chunk
+    """The carry over chunks of the stock rule: four products a chunk
     against the [H, DK, DV] state."""
     hi = jax.lax.Precision.HIGHEST
 
@@ -165,6 +229,8 @@ def scan_alone(u, w, q, k):
 def main():
     if sys.argv[1:] == ["--attention"]:
         return attention_table()
+    if sys.argv[1:2] == ["--delta-rule"]:
+        return delta_rule_table([int(a) for a in sys.argv[2:]])
     buckets = [int(a) for a in sys.argv[1:]] or [4096, 8192, 16384]
     key = jax.random.PRNGKey(0)
     os.makedirs("chiprun_out", exist_ok=True)
@@ -179,7 +245,9 @@ def main():
         nc = s // CHUNK
         line = {"bucket": s, "device": jax.devices()[0].device_kind}
         line["chunk_delta_rule_ms"] = ms_a_call(
-            jax.jit(lambda *a: la.chunk_delta_rule(*a, CHUNK)),
+            jax.jit(rule_kernel), (q, k, v, g, beta))
+        line["chunk_delta_rule_stock_ms"] = ms_a_call(
+            jax.jit(lambda *a: gdc.stock_gated_delta_chunk_scan(*a, CHUNK)),
             (q, k, v, g, beta))
         n = 0.1 * jax.random.normal(key, (1, H, nc, CHUNK, CHUNK))
         line["solve_alone_ms"] = ms_a_call(jax.jit(solve_alone), (n,))

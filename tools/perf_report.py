@@ -480,6 +480,8 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
                "pallas.ssm_state_update_fallbacks",
                "pallas.gated_delta_state_update_dispatches",
                "pallas.gated_delta_state_update_fallbacks",
+               "pallas.gated_delta_chunk_scan_dispatches",
+               "pallas.gated_delta_chunk_scan_fallbacks",
                "pallas.grouped_swiglu_dispatches",
                "pallas.grouped_swiglu_fallbacks",
                "pallas.grouped_polyglu_dispatches",
@@ -1108,17 +1110,17 @@ def render(s, out=sys.stdout):
               + (f"  (LEAKED {leak})\n" if leak else "\n"))
         if "pallas_kernels" in dc:
             pk = dc["pallas_kernels"]
-            w("pallas kernels (per lowering): "
-              f"int8 gemm {pk.get('int8_gemm_dispatches', 0)} dispatched"
-              f" / {pk.get('int8_gemm_fallbacks', 0)} stock-fallback, "
-              f"paged attn {pk.get('paged_attn_dispatches', 0)} "
-              f"dispatched / {pk.get('paged_attn_fallbacks', 0)} "
-              f"stock-fallback, latent prefill attn "
-              f"{pk.get('mla_prefill_dispatches', 0)} dispatched / "
-              f"{pk.get('mla_prefill_fallbacks', 0)} stock-fallback, "
-              f"ssm state update "
-              f"{pk.get('ssm_state_update_dispatches', 0)} dispatched / "
-              f"{pk.get('ssm_state_update_fallbacks', 0)} stock-fallback\n")
+            w("pallas kernels (per lowering): " + ", ".join(
+                f"{label} {pk.get(key + '_dispatches', 0)} dispatched / "
+                f"{pk.get(key + '_fallbacks', 0)} stock-fallback"
+                for label, key in (
+                    ("int8 gemm", "int8_gemm"),
+                    ("paged attn", "paged_attn"),
+                    ("latent prefill attn", "mla_prefill"),
+                    ("ssm state update", "ssm_state_update"),
+                    ("gated delta state update", "gated_delta_state_update"),
+                    ("gated delta chunk scan", "gated_delta_chunk_scan")))
+              + "\n")
         if "prefix_store" in dc:
             ps = dc["prefix_store"]
             looks = ps.get("prefix_hits", 0) + ps.get("prefix_misses", 0)
