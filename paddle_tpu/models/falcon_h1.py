@@ -54,7 +54,7 @@ import numpy as np
 from .. import layers
 from ..core.ir import Program, program_guard
 from ..serving.kv_cache import (LayerCache, PagedKVCache,
-                                pool_array_names, state_array_names)
+                                state_array_names)
 from ..serving.served_model import ServedModel
 from .program_block import Block, named_out as _named_out, op as _op
 
@@ -254,14 +254,6 @@ class _Block(Block):
         if by == 1.0:
             return x
         return _op("scale", {"X": x}, {"Out": None}, {"scale": float(by)})
-
-    def pools(self, i):
-        """(PoolK, PoolV), (PoolKOut, PoolVOut) of layer i."""
-        cfg, pool = self.cfg, self.kv.context
-        shape = [pool.num_pages, pool.page_size,
-                 cfg.num_kv_heads * cfg.head_dim]
-        return self.arrays(pool_array_names(i, False), [shape, shape],
-                            [cfg.dtype, cfg.dtype])
 
     def states(self, i):
         """(State, ConvTail), (StateOut, ConvTailOut) of layer i."""

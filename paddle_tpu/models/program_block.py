@@ -78,6 +78,17 @@ class Block:
         self.pool_outs += [o.name for o in outs]
         return ins, outs
 
+    def pools(self, i):
+        """(PoolK, PoolV), (PoolKOut, PoolVOut) of layer i, whose K and V of
+        ``num_kv_heads x head_dim`` lie in a context's pages."""
+        from ..serving.kv_cache import pool_array_names
+
+        cfg, pool = self.cfg, self.kv.context
+        shape = [pool.num_pages, pool.page_size,
+                 cfg.num_kv_heads * cfg.head_dim]
+        return self.arrays(pool_array_names(i, False), [shape, shape],
+                            [cfg.dtype, cfg.dtype])
+
     def norm(self, x, name):
         return op("rms_norm", {"X": x, "Scale": self.param(name)},
                   {"Y": None}, {"epsilon": self.cfg.rms_norm_eps})

@@ -64,7 +64,7 @@ import numpy as np
 from .. import layers
 from ..core.ir import Program, program_guard
 from ..serving.kv_cache import (LayerCache, PagedKVCache,
-                                pool_array_names, state_array_names)
+                                state_array_names)
 from ..serving.served_model import ServedModel
 from .program_block import Block, named_out as _named_out, op as _op
 
@@ -265,14 +265,6 @@ class _Block(Block):
         return _op("rms_norm", {"X": x, "Scale": self.param(name)},
                    {"Y": None}, {"epsilon": self.cfg.rms_norm_eps,
                                  "scale_offset": 1.0})
-
-    def pools(self, i):
-        """(PoolK, PoolV), (PoolKOut, PoolVOut) of attention layer i."""
-        cfg, pool = self.cfg, self.kv.context
-        shape = [pool.num_pages, pool.page_size,
-                 cfg.num_kv_heads * cfg.head_dim]
-        return self.arrays(pool_array_names(i, False), [shape, shape],
-                            [cfg.dtype, cfg.dtype])
 
     def states(self, i):
         """(State, ConvTail), (StateOut, ConvTailOut) of DeltaNet layer i."""

@@ -18,6 +18,7 @@ from . import quant_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import llm_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
+from . import short_conv_ops  # noqa: F401
 from . import linear_attention_ops  # noqa: F401
 from . import ps_ops  # noqa: F401
 from . import beam_search_ops  # noqa: F401

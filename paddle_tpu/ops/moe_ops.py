@@ -48,7 +48,9 @@ def _poly_experts(ins, attrs):
 def routed_experts_op(ins, attrs):
     """One chip's share of a dropless top-k routed expert layer
     (parallel/moe.py routed_experts_share; attr `score_func` "sigmoid" or
-    "softmax" over all experts; attr `trainable` gives the held experts'
+    "softmax" over all experts; attr `norm_eps`, 1e-20 by default, is what
+    the kept scores' sum is added to before it divides them; attr
+    `trainable` gives the held experts'
     part its backward and Counts a fourth entry, the largest group's
     rows): X [..., H] float32, RouterW
     [H, E] over ALL experts, SelectBias [E] (optional: a router without
@@ -82,6 +84,7 @@ def routed_experts_op(ins, attrs):
         route_norm=bool(attrs.get("route_norm", True)), live=live,
         score_func=attrs.get("score_func", "sigmoid"),
         trainable=bool(attrs.get("trainable", False)), with_chosen=True,
-        poly=_poly_experts(ins, attrs))
+        poly=_poly_experts(ins, attrs),
+        norm_eps=float(attrs.get("norm_eps", 1e-20)))
     return {"Out": out.reshape(x.shape), "Counts": counts,
             "Chosen": chosen.reshape(x.shape[:-1] + (-1,))}

@@ -22,6 +22,19 @@ left in the rest of the chunk's scratch are masked by position before the
 softmax and multiplied to exact zero after it (the scratch is zeroed
 before the first row, so what is multiplied by zero is always finite).
 
+A head narrower than a lane tile (64: models/lfm2.py) is scored under the
+kernel's own rules by PACKING: ``128 / hd`` neighbouring K/V heads share the
+128 lanes of a cached row as they lie in the pool, and the kernel is handed
+them as ONE K/V head of 128 whose group is the packed heads' query heads,
+each query laid into its own head's lanes with zeros in the others'
+(`_pack_queries`). A packed head's scores are then exactly its own (the
+zeros add nothing), every load and slice stays on a lane-tile boundary, the
+pool is read as it is and once, and of the (group, 128) output each row's
+own lanes are kept (`_unpack_output`). The kernel's body is the same
+instructions at ``nkv / pack`` heads of 128; heads of 128 and more do not
+pass through the packing at all. Its bytes are counted the same way (K and
+V of the keys attended, once), so it keeps its ``name=``.
+
 Dispatch and fallback counts land in the same counters as the float32
 kernel's (``pallas.paged_attn_dispatches`` / ``pallas.paged_attn_fallbacks``).
 """
@@ -42,6 +55,7 @@ KERNEL_NAME = "paged_gqa_attention"
 # five layers took 2.23 ms at 512 and 1.60 ms at 2048
 CHUNK_TOKENS = 2048
 _SUBLANES = 8      # a group of query heads is padded to whole sublanes
+_LANES = 128       # a K/V head narrower than this shares its lane tile
 
 
 def true_positions(slot, pos, cap, ring):
@@ -173,6 +187,36 @@ def _kernel(table_ref, pos_ref, q_ref, pk_ref, pv_ref, o_ref, ks_ref,
     o_ref[...] = acc / l_run
 
 
+def lane_pack(nkv: int, hd: int) -> int:
+    """K/V heads that share a lane tile: 1 for a head of a whole tile or
+    more; ``_LANES / hd`` for a narrower one that divides it, where the K/V
+    heads come in whole packs; 0 where neither holds (no kernel form)."""
+    if hd % _LANES == 0:
+        return 1
+    pack = _LANES // hd if _LANES % hd == 0 else 0
+    return pack if pack and nkv % pack == 0 else 0
+
+
+def _pack_queries(qh, pack):
+    """[B, nkv, g, hd] -> [B, nkv / pack, pack * g, pack * hd]: the query of
+    packed head j in lanes j * hd .. (j + 1) * hd of its row, zeros in the
+    lanes of the heads it shares the tile with."""
+    b, nkv, g, hd = qh.shape
+    own = jnp.eye(pack, dtype=qh.dtype)
+    qp = qh.reshape(b, nkv // pack, pack, g, 1, hd) \
+        * own[None, None, :, None, :, None]
+    return qp.reshape(b, nkv // pack, pack * g, pack * hd)
+
+
+def _unpack_output(out, pack, g, hd):
+    """[B, nkv / pack, pack * g, pack * hd] -> [B, nkv, g, hd]: of each row
+    the lanes of its own head."""
+    b, tiles = out.shape[:2]
+    own = jnp.einsum("btjgjh->btjgh",
+                     out.reshape(b, tiles, pack, g, pack, hd))
+    return own.reshape(b, tiles * pack, g, hd)
+
+
 def _pallas_paged_gqa_attention(q, pool_k, pool_v, table, pos, n, nkv, hd,
                                 scale, window, ring, interpret):
     from jax.experimental import pallas as pl
@@ -182,9 +226,19 @@ def _pallas_paged_gqa_attention(q, pool_k, pool_v, table, pos, n, nkv, hd,
     page = int(pool_k.shape[1])
     mp = int(table.shape[1])
     g = n // nkv
+    qh = q.reshape(b, nkv, g, hd)
+    pack = lane_pack(nkv, hd)
+    if pack > 1:
+        # `pack` K/V heads a lane tile: one head of `pack * hd` to the kernel
+        out = _pallas_paged_gqa_attention(
+            _pack_queries(qh, pack).reshape(b, -1), pool_k, pool_v, table,
+            pos, n, nkv // pack, pack * hd, scale, window, ring,
+            interpret)
+        return _unpack_output(
+            out.reshape(b, nkv // pack, pack * g, pack * hd), pack, g,
+            hd).reshape(b, n * hd)
     g8 = -(-g // _SUBLANES) * _SUBLANES
     chunk_pages = max(1, min(CHUNK_TOKENS // page, mp))
-    qh = q.reshape(b, nkv, g, hd)
     if g8 != g:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, g8 - g), (0, 0)))
     row = pl.BlockSpec((None, nkv, g8, hd), lambda i, t, p: (i, 0, 0, 0))
@@ -232,9 +286,10 @@ def paged_gqa_decode_attention(q, pool_k, pool_v, table, positions,
     elif n % nkv or int(pool_k.shape[2]) != nkv * hd:
         reason = "kvdim_mismatch"
     elif mode == "tpu" and (
-            hd % 128 or page % (32 // pool_k.dtype.itemsize)
+            not lane_pack(nkv, hd) or page % (32 // pool_k.dtype.itemsize)
             or CHUNK_TOKENS % page):
-        # Mosaic lane / sublane alignment of a page's VMEM block
+        # Mosaic lane / sublane alignment of a page's VMEM block (a head
+        # under a lane tile goes packed, `lane_pack`)
         reason = "tpu_tiling"
     if reason is not None:
         telemetry.counter_add("pallas.paged_attn_fallbacks", 1,

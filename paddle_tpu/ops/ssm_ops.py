@@ -29,6 +29,18 @@ State arrays: ``ssm_state_<l>`` [slots + 1, heads, d_state, head_dim]
 float32 (d_state on sublanes, head_dim on lanes: a head's x and y are lane
 rows as the projections leave them) and ``conv_tail_<l>`` [slots + 1,
 d_conv - 1, conv_dim] in the model's dtype (time-major, channels on lanes).
+
+Which ops own which convolution: `ssm_conv_update` / `ssm_conv_prefill`
+here are the convolution INSIDE a recurrent mixer (a bias or none, SiLU
+after it, its channels split in three by the attrs: Mamba-2's x | B | C,
+a Gated-DeltaNet layer's q | k | v); `gated_short_conv_update` /
+`gated_short_conv_prefill` (ops/short_conv_ops.py) are a layer whose whole
+mixer is the convolution (no activation, a gate before and a gate after,
+no split, and the tail the layer's only state). What a tail is, is one
+thing and lives here: `conv_window` (a row's tail at its slot joined to its
+new input), `conv_prompt` / `conv_prompt_tail` (a padded prompt, and the
+inputs of its last REAL tokens) and `conv_tail_write` (the slot's tail
+written in place) serve both pairs.
 """
 
 from __future__ import annotations
@@ -63,6 +75,52 @@ def ssm_split_op(ins, attrs):
 _SSM_ATTRS = ("n_heads", "head_dim", "n_groups", "d_state")
 
 
+def row_slots(ins):
+    """Slots [B] int32: the slot of each row's state."""
+    import jax.numpy as jnp
+
+    return ins["Slots"][0].reshape(-1).astype(jnp.int32)
+
+
+def conv_window(pool, slots, x):
+    """A decode step's convolution window, a row: the row's tail (the last
+    ``K - 1`` inputs, at its slot of `pool` [slots + 1, K - 1, C]) and its
+    new input x [B, C] -> [B, K, C] float32, oldest first."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([pool[slots].astype(jnp.float32),
+                            x[:, None, :]], axis=1)
+
+
+def conv_prompt(x, w):
+    """A whole padded prompt x [B, S, C] through the causal depthwise
+    convolution W [K, C], before any bias or activation -> (the padded
+    inputs [B, S + K - 1, C], where token t sits at index t + K - 1; the
+    sums [B, S, C])."""
+    import jax.numpy as jnp
+
+    k, s = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return xp, sum(w[j] * xp[:, j:j + s] for j in range(k))
+
+
+def conv_prompt_tail(xp, lengths, k):
+    """Of `conv_prompt`'s padded inputs, those of the last ``K - 1`` REAL
+    tokens of each row (zeros before a prompt shorter than that), not the
+    padded bucket's end -> [B, K - 1, C]."""
+    import jax.numpy as jnp
+
+    # token t sits at xp[t + K - 1]: the last K - 1 real ones start at L
+    idx = lengths[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+    return jnp.take_along_axis(xp, idx[:, :, None], axis=1)
+
+
+def conv_tail_write(pool, slots, tail):
+    """`pool` with the rows' slots holding `tail` [B, K - 1, C], in the
+    pool's dtype, in place (a padding row names the scratch slot)."""
+    return pool.at[slots].set(tail.astype(pool.dtype))
+
+
 @register_op("ssm_conv_update", required_attrs=_SSM_ATTRS)
 def ssm_conv_update_op(ins, attrs):
     """One token a row through the causal depthwise convolution: the row's
@@ -77,13 +135,12 @@ def ssm_conv_update_op(ins, attrs):
     import jax.numpy as jnp
 
     xbc, pool = ins["XBC"][0].astype(jnp.float32), ins["ConvTail"][0]
-    slots = ins["Slots"][0].reshape(-1).astype(jnp.int32)
+    slots = row_slots(ins)
     w = ins["W"][0].astype(jnp.float32)
-    win = jnp.concatenate([pool[slots].astype(jnp.float32),
-                           xbc[:, None, :]], axis=1)          # [B, K, C]
+    win = conv_window(pool, slots, xbc)                       # [B, K, C]
     y = jax.nn.silu(jnp.sum(win * w[None], axis=1) + _bias(ins))
     out = _split_xbc(y, attrs)
-    out["ConvTailOut"] = pool.at[slots].set(win[:, 1:].astype(pool.dtype))
+    out["ConvTailOut"] = conv_tail_write(pool, slots, win[:, 1:])
     return out
 
 
@@ -97,18 +154,14 @@ def ssm_conv_prefill_op(ins, attrs):
     import jax.numpy as jnp
 
     xbc, pool = ins["XBC"][0].astype(jnp.float32), ins["ConvTail"][0]
-    slots = ins["Slots"][0].reshape(-1).astype(jnp.int32)
+    slots = row_slots(ins)
     lengths = ins["Lengths"][0].reshape(-1).astype(jnp.int32)
     w = ins["W"][0].astype(jnp.float32)
-    k, s = w.shape[0], xbc.shape[1]
-    xp = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(w[j] * xp[:, j:j + s] for j in range(k))
+    xp, y = conv_prompt(xbc, w)
     y = jax.nn.silu(y + _bias(ins))
-    # token t sits at xp[t + K - 1]: the last K - 1 real ones start at L
-    idx = lengths[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
-    tail = jnp.take_along_axis(xp, idx[:, :, None], axis=1)
+    tail = conv_prompt_tail(xp, lengths, w.shape[0])
     out = _split_xbc(y, attrs)
-    out["ConvTailOut"] = pool.at[slots].set(tail.astype(pool.dtype))
+    out["ConvTailOut"] = conv_tail_write(pool, slots, tail)
     return out
 
 

@@ -336,7 +336,7 @@ def _kept_scores(scores, idx):
 
 
 def _pair_plan(scores, select_bias, alive, *, top_k, held_lo, e_held,
-               route_scale, route_norm):
+               route_scale, route_norm, norm_eps=1e-20):
     """The plan over the T x k pairs of a routed layer, with no gather and
     no scatter of single elements, forward or transposed: scores float32
     [T, E], `alive` bool [T] -> (idx int32 [T, k] the chosen experts,
@@ -351,7 +351,7 @@ def _pair_plan(scores, select_bias, alive, *, top_k, held_lo, e_held,
     kept = _kept_scores(scores, idx)                             # [T, k]
     weight = kept
     if route_norm:
-        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + 1e-20)
+        weight = kept / (jnp.sum(kept, axis=1, keepdims=True) + norm_eps)
     weight = weight * route_scale
     local = idx - held_lo
     held = (local >= 0) & (local < e_held) & alive[:, None]
@@ -371,7 +371,7 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
                          route_norm: bool = True, live=None,
                          score_func: str = "sigmoid",
                          trainable: bool = False, with_chosen: bool = False,
-                         poly=None):
+                         poly=None, norm_eps: float = 1e-20):
     """One chip's share of a dropless top-k routed expert layer
     (`switch_moe` above is the top-1 layer with a capacity). Scores are
     ``sigmoid(x Wr)`` or, with ``score_func="softmax"``, the softmax over
@@ -390,8 +390,10 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
 
     Every token scores all E experts in float32, keeps the ``top_k``
     largest of ``score + select_bias`` and weighs them by
-    ``score / (sum of the kept scores + 1e-20) * route_scale`` (no
-    renormalisation when ``route_norm`` is false). The plan over the T x k
+    ``score / (sum of the kept scores + norm_eps) * route_scale`` (no
+    renormalisation when ``route_norm`` is false; ``norm_eps`` is 1e-20
+    unless the model's published rule states another: models/lfm2.py's is
+    1e-6). The plan over the T x k
     pairs (``_pair_plan``: kept scores -> weights -> sorted order ->
     sorted weights) gathers and scatters no single element, forward or
     transposed: a kept score is the sum over E of the scores where the
@@ -463,7 +465,8 @@ def routed_experts_share(x, router_w, select_bias, w1, w3, w2, *,
         else live.reshape(-1).astype(bool)
     idx, _kept, _order, rows, sizes, w_sorted = _pair_plan(
         scores, select_bias, alive, top_k=top_k, held_lo=held_lo,
-        e_held=e_held, route_scale=route_scale, route_norm=route_norm)
+        e_held=e_held, route_scale=route_scale, route_norm=route_norm,
+        norm_eps=norm_eps)
 
     # the pairs on held experts sort first, and with evenly spread routing
     # they are e_held / E of all pairs. Twice that share (and a margin)

@@ -200,7 +200,7 @@ from .admission import (AdmissionQueue, DeadlineExceededError,
                         EngineClosedError, InferenceRequest,
                         KVCacheExhaustedError, ServingError)
 from .health import DRAINING, READY, STOPPED, HealthState
-from .kv_cache import PagedKVCache, state_array_names
+from .kv_cache import PagedKVCache
 from .served_model import DRAFT_SPARE_TOKENS
 from .prefix_store import PrefixStore
 
@@ -692,18 +692,31 @@ class DecodeEngine:
         # attends is counted for it (decode.kv_tokens_attended)
         self._ring_or_latent = self.kv.ring is not None \
             or any(self.kv.context.latent)
+        # what a step advances a live row, by kind of state-class layer:
+        # rows of a RECURRENT state, and rows of a conv tail that is a
+        # layer's whole state (a gated short convolution)
+        tails = len(self.kv.tail_layers)
+        self._state_row_counters = [
+            (name, n) for name, n in (
+                ("decode.state_rows_updated",
+                 len(self.kv.state_layers) - tails),
+                ("decode.conv_rows_updated", tails)) if n]
         if self.kv.has_state and (
                 self.config.prefix_cache or self.config.role != "unified"):
-            # a recurrent state is a slot's and has no per-token pages: the
-            # prefix store has nothing of it to share and no chunk program
-            # resumes one (a prompt's pages without the state after them
-            # are half a prefix), and a shipment carries pages alone; a
-            # snapshot store is later work
+            # what a slot keeps (a recurrent state, a conv tail, or a tail
+            # alone) has no per-token pages: the prefix store has nothing
+            # of it to share and no chunk program resumes one (a prompt's
+            # pages without the state after them are half a prefix), and a
+            # shipment carries pages alone. A tail alone is a few rows a
+            # layer, so a snapshot of it beside a prefix's pages and a
+            # chunk program fed the tail its predecessor left are the
+            # shorter way there; both are later work
             raise ValueError(
-                "a model with recurrent state (a state and a conv tail a "
-                "slot) runs unified and without the prefix store: a state "
-                "has no per-token pages to share or to ship, and no chunk "
-                "program carries one over")
+                "a model with per-slot state (a recurrent state and a conv "
+                "tail a slot, or a conv tail alone) runs unified and "
+                "without the prefix store: what a slot keeps has no "
+                "per-token pages to share or to ship, and no chunk program "
+                "carries a state or a tail over")
         if self._ring_or_latent and (
                 self.config.prefix_cache or self.config.role != "unified"):
             # the prefix store shares a prompt's full pages between
@@ -721,6 +734,10 @@ class DecodeEngine:
         # lives on the device (``_spec``), and its page tables reach the
         # rows a step ahead may write past a request's last kept token
         self._draft = bool(self.model.draft)
+        # a model whose step hands its logits out beside the tokens, for a
+        # request that keeps a record of its steps (`keep_step_outputs`)
+        self._keeps_logits = bool(self.model.keeps_step_logits) \
+            and not self._draft
         self._mp = -(-(model_cfg.max_seq_len + (
             DRAFT_SPARE_TOKENS if self._draft else 0))
             // self.config.page_size)
@@ -814,7 +831,10 @@ class DecodeEngine:
         ``keep_step_outputs`` (a model with a draft module) leaves a record
         of each of its steps (``step_outputs``: the position, the draft and
         the q it was drawn from, the logits of both positions, the module's
-        logits, the step's uniforms and tokens)."""
+        logits, the step's uniforms and tokens); of a model whose step
+        program hands its logits out (``ServedModel.keeps_step_logits``)
+        the record is the step's position, its float32 logits row and the
+        token chosen from it."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt needs at least one token")
@@ -842,7 +862,7 @@ class DecodeEngine:
         req.first_logits = bool(keep_first_logits)
         req.final_state = bool(keep_final_state)
         req.final_pages = bool(keep_final_pages)
-        if keep_step_outputs and self._draft:
+        if keep_step_outputs and (self._draft or self._keeps_logits):
             req.step_outputs = []
         if rng_state is not None:
             from .session import unpack_rng_state
@@ -1050,6 +1070,7 @@ class DecodeEngine:
                     ("sampling", "carry") if phase == "step" else ())
                 block = program.global_block()
                 counted = "step_counts" in fetches
+                keeps_logits = self._keeps_logits
                 run = functools.partial(run_program, block)
 
                 def prefill(params, pools, feed):
@@ -1081,6 +1102,10 @@ class DecodeEngine:
                         # int32 vector, one fetch
                         chosen = jnp.concatenate(
                             [chosen, env["step_counts"].astype(jnp.int32)])
+                    if keeps_logits:
+                        # the array the sampler read, as the head left it
+                        return (chosen, pools, last_tokens,
+                                {"logits": env["logits"]})
                     return chosen, pools, last_tokens
 
                 donate = (1,)
@@ -1758,6 +1783,9 @@ class DecodeEngine:
                 chosen, self._pools, self._last_tokens, self._spec, kept = \
                     entry(self._params, self._pools, feed, self._last_tokens,
                           self._spec)
+            elif self._keeps_logits:
+                chosen, self._pools, self._last_tokens, kept = entry(
+                    self._params, self._pools, feed, self._last_tokens)
             else:
                 chosen, self._pools, self._last_tokens = entry(
                     self._params, self._pools, feed, self._last_tokens)
@@ -1784,9 +1812,8 @@ class DecodeEngine:
                 chosen = np.asarray(flight.chosen)
         for name, value in zip(self.model.step_counters, chosen[bucket:]):
             telemetry.counter_add(name, int(value))
-        if self.kv.has_state:
-            telemetry.counter_add("decode.state_rows_updated",
-                                  len(rows) * len(self.kv.state_layers))
+        for name, layers_ in self._state_row_counters:
+            telemetry.counter_add(name, len(rows) * layers_)
         if self._ring_or_latent or self.kv.has_state:
             # keys (a latent layer's cached tokens) a step reads: a ring
             # layer's rows read their window
@@ -1814,6 +1841,11 @@ class DecodeEngine:
                 if req.done():
                     continue
                 delivered += 1
+                if req.step_outputs is not False:
+                    req.step_outputs.append({
+                        "position": int(flight.positions[i]),
+                        "logits": np.asarray(flight.kept["logits"][i]),
+                        "token": int(chosen[i])})
                 self._accept_token(req, int(chosen[i]))
                 if req.finished():
                     retired = True
@@ -2016,9 +2048,8 @@ class DecodeEngine:
         if req.final_state is True and req.slot is not None:
             # queued behind the steps dispatched so far, none of which
             # holds a request that ended on its count
-            req.final_state = {
-                n: self._pools[n][req.slot] for i in self.kv.state_layers
-                for n in state_array_names(i)}
+            req.final_state = {n: self._pools[n][req.slot]
+                               for n in self.kv.state_names()}
         if req.final_pages is True:
             # a device gather of its own pages, queued like the state's
             pages = np.asarray(req.pages, np.int32)

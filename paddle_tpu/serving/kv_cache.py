@@ -60,7 +60,10 @@ class LayerCache:
     A STATE-ONLY layer (`kv_dim` 0 with a state: a linear-attention layer
     of a model whose other layers attend) keeps no K or V at all: it is in
     no class of pages, no pool array is made for it, and its bytes are the
-    state class's alone."""
+    state class's alone. Its state may be a TAIL ALONE (`conv_tail` set,
+    no `ssm_state`: a layer whose only mixer is a gated short convolution,
+    models/lfm2.py): then no recurrent-state array exists for it either,
+    no state kernel runs, and its bytes are the tail's."""
     kv_dim: int
     window: int = 0
     latent: bool = False
@@ -69,11 +72,11 @@ class LayerCache:
     state_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.kv_dim == 0 and (self.window or self.latent
-                                 or not self.ssm_state):
+        if self.kv_dim == 0 and (self.window or self.latent or not (
+                self.ssm_state or self.conv_tail)):
             raise ValueError("a layer without K/V (kv_dim 0) is a "
-                             "state-only layer: a state, no window, no "
-                             "latent")
+                             "state-only layer: a recurrent state or a "
+                             "conv tail, no window, no latent")
 
     @property
     def ring(self) -> bool:
@@ -83,6 +86,11 @@ class LayerCache:
     def state_only(self) -> bool:
         return self.kv_dim == 0
 
+    @property
+    def tail_only(self) -> bool:
+        """In the state class with a conv tail and no recurrent state."""
+        return bool(self.conv_tail) and not self.ssm_state
+
 
 def pool_array_names(layer: int, latent: bool) -> Tuple[str, ...]:
     """The pool arrays of layer `layer`, as the programs feed them (each
@@ -91,13 +99,16 @@ def pool_array_names(layer: int, latent: bool) -> Tuple[str, ...]:
         else (f"kv_k_{layer}", f"kv_v_{layer}")
 
 
-def state_array_names(layer: int) -> Tuple[str, str]:
+def state_array_names(layer: int, tail_only: bool = False
+                      ) -> Tuple[str, ...]:
     """The per-slot state arrays of layer `layer` (written back as
     ``<name>_out``): the recurrent state [slots + 1, heads, d_state,
     head_dim] (d_state on sublanes, head_dim on lanes) and the conv tail
     [slots + 1, d_conv - 1, conv_dim] (time-major); the last slot is the
-    scratch slot of padding rows and warm-up feeds."""
-    return (f"ssm_state_{layer}", f"conv_tail_{layer}")
+    scratch slot of padding rows and warm-up feeds. A `tail_only` layer
+    (`LayerCache.tail_only`) has the tail alone."""
+    tail = f"conv_tail_{layer}"
+    return (tail,) if tail_only else (f"ssm_state_{layer}", tail)
 
 
 def ring_pages_per_slot(window: int, page_size: int) -> int:
@@ -333,7 +344,9 @@ class PagedKVCache:
     classes can seat it (`try_alloc` takes from both or from neither),
     and `audit` holds both to their invariants.
 
-    Layers with a per-slot state (`LayerCache.ssm_state`) add the state
+    Layers with a per-slot state (`LayerCache.ssm_state`, or a
+    `conv_tail` alone: ``tail_layers``, of which no recurrent-state array
+    is made) add the state
     class, and a state-only layer is in that class alone (the context pool
     holds the arrays of the layers that attend, ``context.layers``): `state_array_names` arrays of ``slots + 1`` states, booked as
     ``mem.serving.state_pool_bytes`` (``.used``: the seated requests'
@@ -382,7 +395,10 @@ class PagedKVCache:
                     "mem.serving.kv_pool_bytes.latent",
                     self.context.latent_bytes + self.ring.latent_bytes)
         self.state_layers = [i for i, lc in enumerate(layout)
-                             if lc.ssm_state]
+                             if lc.ssm_state or lc.conv_tail]
+        # of them, those that keep a conv tail and NO recurrent state
+        self.tail_layers = [i for i in self.state_layers
+                            if layout[i].tail_only]
         self.state_slots = int(slots) + 1 if self.state_layers else 0
         self.state_slot_bytes = 0
         if self.state_layers:
@@ -393,10 +409,11 @@ class PagedKVCache:
                                  "engine's slot count")
             for i in self.state_layers:
                 lc = layout[i]
+                if lc.ssm_state:
+                    self.state_slot_bytes += int(np.prod(lc.ssm_state)) \
+                        * np.dtype(lc.state_dtype).itemsize
                 self.state_slot_bytes += \
-                    int(np.prod(lc.ssm_state)) \
-                    * np.dtype(lc.state_dtype).itemsize \
-                    + int(np.prod(lc.conv_tail)) * np.dtype(dtype).itemsize
+                    int(np.prod(lc.conv_tail)) * np.dtype(dtype).itemsize
             telemetry.gauge_set("mem.serving.state_pool_bytes",
                                 self.state_pool_bytes)
             self.note_state_slots(0)
@@ -418,15 +435,22 @@ class PagedKVCache:
             telemetry.gauge_set("mem.serving.state_pool_bytes.used",
                                 self._state_seated * self.state_slot_bytes)
 
+    def state_names(self) -> List[str]:
+        """The program feed names of the state class's arrays."""
+        return [n for i in self.state_layers
+                for n in state_array_names(i, self.layout[i].tail_only)]
+
     def _state_arrays(self) -> Dict[str, Any]:
         import jax.numpy as jnp
 
         out = {}
         for i in self.state_layers:
             lc = self.layout[i]
-            state, tail = state_array_names(i)
-            out[state] = jnp.zeros((self.state_slots,) + tuple(lc.ssm_state),
-                                   lc.state_dtype)
+            *state, tail = state_array_names(i, lc.tail_only)
+            for name in state:
+                out[name] = jnp.zeros(
+                    (self.state_slots,) + tuple(lc.ssm_state),
+                    lc.state_dtype)
             out[tail] = jnp.zeros((self.state_slots,) + tuple(lc.conv_tail),
                                   self.context.dtype)
         return out

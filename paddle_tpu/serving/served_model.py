@@ -10,7 +10,8 @@ pages are a context's or a ring, or that it keeps ONE latent array a
 token and no K and V; and what it keeps a SLOT beside them, or INSTEAD
 of them: a recurrent state and a conv tail). models/decoder_lm.py,
 models/afmoe.py, models/kimi_k2.py, models/falcon_h1.py,
-models/qwen3_next.py, models/motif3.py and models/xing4.py each give one;
+models/qwen3_next.py, models/motif3.py, models/xing4.py and models/lfm2.py
+each give one;
 ``cfg.served()`` builds it.
 
 A builder returns ``(program, feeds, fetches)``. ``feeds`` are the names
@@ -43,6 +44,12 @@ linear-attention layer of a model whose other layers attend,
 models/qwen3_next.py) feeds and fetches those two ALONE: no ``kv_*_<l>``
 array exists for it, the context pool holds the attending layers' arrays
 only, and ``page_table`` is read by those layers;
+a TAIL-ONLY layer (`LayerCache(0, conv_tail=...)` and no `ssm_state`: a
+layer whose whole mixer is a gated short convolution, models/lfm2.py)
+feeds and fetches ``conv_tail_<l>`` ALONE
+(`kv_cache.state_array_names(l, tail_only=True)`), and the engine counts
+its rows as ``decode.conv_rows_updated``, not as
+``decode.state_rows_updated`` (rows of a RECURRENT state);
 ``state_slots`` names each row's slot (a step's from the slot its seated
 request keeps, which ``carry`` holds on the device; a padding row's and a
 warm-up feed's is the scratch slot, the arrays' last). Its prefill WRITES
@@ -92,6 +99,12 @@ class ServedModel:
     kv_dtype: str = "float32"
     step_counters: Tuple[str, ...] = ()
     draft: bool = False         # has a draft module: `build_draft_program`
+    # the jitted step returns its float32 logits [B, vocab] beside the
+    # tokens (the array the sampler read: no work more), and a request
+    # submitted with `keep_step_outputs` keeps its row of every step: how a
+    # check reads the logits of STEPS where greedy tokens say nothing (a
+    # head tied to a unit-scale embedding repeats the last token)
+    keeps_step_logits: bool = False
 
     def __init__(self, cfg: Any):
         self.cfg = cfg          # max_seq_len, eos_id, vocab_size

@@ -289,6 +289,21 @@ def test_paged_gqa_attention_at_the_qwen3_next_share(one_chip, tpu_mode):
         ((64, 288), I32), ((64,), I32))
 
 
+def test_paged_gqa_attention_at_the_lfm2_stage(one_chip, tpu_mode):
+    """32 query heads on 8 K/V heads of 64 (two K/V heads a lane tile: the
+    kernel's packed form, four heads of 128 with eight query rows each),
+    bfloat16 pages of 64 tokens, 128 rows over 128 context pages each
+    (8,192 positions) of a pool of 16,385."""
+    from paddle_tpu.ops.pallas.paged_gqa_attention import \
+        paged_gqa_decode_attention
+
+    _compile(lambda q, pk, pv, t, p: paged_gqa_decode_attention(
+        q, pk, pv, t, p, num_heads=32, num_kv_heads=8, head_dim=64,
+        scale=64 ** -0.5), one_chip, ((128, 2048), F32),
+        ((16385, 64, 512), BF16), ((16385, 64, 512), BF16),
+        ((128, 128), I32), ((128,), I32))
+
+
 @pytest.mark.parametrize("rows", [64, 16384])
 def test_mhc_kernels_at_the_motif3_share(one_chip, tpu_mode, rows):
     """Four streams of 4096 float32 a token, 24 maps in a lane tile, 20

@@ -176,10 +176,12 @@ def test_the_sigmoid_path_is_bit_identical_to_the_parents(monkeypatch, mode):
 
 
 def parent_plan(scores, select_bias, alive, *, top_k, held_lo, e_held,
-                route_scale, route_norm):
+                route_scale, route_norm, norm_eps=1e-20):
     """`moe._pair_plan` as the parent commit 802ff6f wrote it inside
     `routed_experts_share`: the kept scores and the sorted weights by a
-    gather each."""
+    gather each. That commit had one denominator; `norm_eps` (PR 58) is
+    handed on at its default, which is that one."""
+    assert norm_eps == 1e-20
     _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
     kept = jnp.take_along_axis(scores, idx, axis=1)
     weight = kept

@@ -426,6 +426,12 @@ def _decode_summary(counter_delta, counter_last, timer_summary, gauges,
             out["tokens_per_row_step"] = round(
                 tokens / cval("decode.rows_stepped"), 3)
         out["tokens_discarded"] = int(cval("decode.tokens_discarded"))
+    # a model with per-slot state: the rows a step advanced, a layer, by
+    # kind (a recurrent state; a conv tail that is a layer's whole state)
+    for counter, key in (("decode.state_rows_updated", "state_rows"),
+                         ("decode.conv_rows_updated", "conv_rows")):
+        if cval(counter):
+            out[key] = int(cval(counter))
     for timer, key in (("decode.prefill_ms", "prefill_ms"),
                        ("decode.step_ms", "step_ms"),
                        ("decode.request_ms", "request_ms")):
@@ -1077,6 +1083,10 @@ def render(s, out=sys.stdout):
             w(f"drafts accepted: {dc['draft_accept_share']}%  tokens a row "
               f"a step: {dc.get('tokens_per_row_step', '-')}  tokens thrown "
               f"away: {dc['tokens_discarded']}\n")
+        if "state_rows" in dc or "conv_rows" in dc:
+            w(f"per-slot state, rows x layers advanced: recurrent state "
+              f"{dc.get('state_rows', 0)}  conv tail alone "
+              f"{dc.get('conv_rows', 0)}\n")
         w(f"retired: {dc['retired']}  rejected: {dc['rejects']}  "
           f"kv refusals: {dc['kv_refusals']}  deadline-expired: "
           f"{dc['deadline_expired']}  errors: {dc['errors']}  "

@@ -27,7 +27,8 @@ SERVED = {"decoder_lm": ("DecoderLMConfig", "decoder_lm_params"),
           "falcon_h1": ("FalconH1Config", "falcon_h1_params"),
           "qwen3_next": ("Qwen3NextConfig", "qwen3_next_params"),
           "motif3": ("Motif3Config", "motif3_params"),
-          "xing4": ("Xing4Config", "xing4_params")}
+          "xing4": ("Xing4Config", "xing4_params"),
+          "lfm2": ("Lfm2Config", "lfm2_params")}
 
 
 def _digest(fn, *args):

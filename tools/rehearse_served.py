@@ -39,7 +39,7 @@ def main(argv=None) -> int:
     import paddle_tpu.ops.pallas as pallas
     from benchmark.manifest import Manifest
     from paddle_tpu.core.executor import run_block
-    from paddle_tpu.serving.kv_cache import PagedKVCache
+    from paddle_tpu.serving.kv_cache import PagedKVCache, state_array_names
     from paddle_tpu.serving.sampling import sample_tokens
 
     man = Manifest(REPO)
@@ -67,6 +67,14 @@ def main(argv=None) -> int:
             for n in (f"kv_c_{i}",) if lat else (f"kv_k_{i}", f"kv_v_{i}"):
                 pools[n] = shape((pool.num_pages, pool.page_size, dim),
                                  pool.dtype)
+    for i in kv.state_layers:       # what a slot keeps beside its pages
+        lc = kv.layout[i]
+        *state, tail = state_array_names(i, lc.tail_only)
+        for n in state:
+            pools[n] = shape((kv.state_slots,) + tuple(lc.ssm_state),
+                             lc.state_dtype)
+        pools[tail] = shape((kv.state_slots,) + tuple(lc.conv_tail),
+                            kv.context.dtype)
     held = sum(v.size * v.dtype.itemsize
                for v in list(params.values()) + list(pools.values()))
     print(json.dumps({"config": name, "weights_and_pools_gb":
