@@ -304,6 +304,21 @@ def test_paged_gqa_attention_at_the_lfm2_stage(one_chip, tpu_mode):
         ((128, 128), I32), ((128,), I32))
 
 
+def test_paged_gqa_attention_at_the_falcon_share(one_chip, tpu_mode):
+    """20 query heads on 4 K/V heads of 128 (a group of 5, padded to 8
+    sublanes), bfloat16 pages of 64 tokens, 64 rows over 32 context pages
+    each (2,048 positions: the table is one chunk, so every copy in flight
+    is the next row's) of a pool of 2,049."""
+    from paddle_tpu.ops.pallas.paged_gqa_attention import \
+        paged_gqa_decode_attention
+
+    _compile(lambda q, pk, pv, t, p: paged_gqa_decode_attention(
+        q, pk, pv, t, p, num_heads=20, num_kv_heads=4, head_dim=128,
+        scale=128 ** -0.5), one_chip, ((64, 2560), F32),
+        ((2049, 64, 512), BF16), ((2049, 64, 512), BF16),
+        ((64, 32), I32), ((64,), I32))
+
+
 @pytest.mark.parametrize("rows", [64, 16384])
 def test_mhc_kernels_at_the_motif3_share(one_chip, tpu_mode, rows):
     """Four streams of 4096 float32 a token, 24 maps in a lane tile, 20
